@@ -39,6 +39,11 @@ def main():
     trainer = JaxTrainer(
         train_loop,
         train_loop_config={"steps": 10},
+        # On a TPU host give the worker the chip(s) it trains on — the
+        # driver stays off JAX, and the worker checks at set-up that it
+        # sees that many TPU devices (chip_smoke.py drives this variant
+        # at the 125M size):
+        #   resources_per_worker={"CPU": 1, "TPU": 1}
         scaling_config=ScalingConfig(num_workers=1,
                                      resources_per_worker={"CPU": 1}),
         run_config=RunConfig(name="gpt2_tiny_demo"))

@@ -234,7 +234,8 @@ class Transformer:
         cdt = jnp.dtype(cfg.dtype)
         constrain = functools.partial(
             with_logical_constraint, mesh=mesh, rules=rules)
-        attn_fn = Transformer._make_attention(cfg, mesh, rules)
+        attn_fn = Transformer._make_attention(cfg, mesh, rules,
+                                              seq_len=cos.shape[-2])
         scale = cfg.head_dim ** -0.5
 
         def layer(x, lp):
@@ -420,18 +421,40 @@ class Transformer:
         return run(staged, x_micro, y_micro, extras)
 
     @staticmethod
-    def _make_attention(cfg: TransformerConfig, mesh, rules: ShardingRules):
+    def resolve_attention_impl(cfg: TransformerConfig, mesh=None,
+                               seq_len: Optional[int] = None) -> str:
+        """The implementation cfg.attention_impl runs as: explicit names
+        pass through, and this is the one place "auto" is decided — ring
+        when the seq axis is sharded, else the pallas flash kernel where
+        the devices are TPUs and the kernel tiles the shape, else dense.
+        Callers print it to say what a step compiled with."""
+        import jax
+
+        from ray_tpu.ops.attention import flash_shape_ok
+
+        impl = cfg.attention_impl
+        if impl not in ("auto", "dense", "flash", "ring", "ulysses"):
+            raise ValueError(f"unknown attention_impl {impl!r}")
+        if impl != "auto":
+            return impl
+        if mesh is not None and mesh.shape.get(AXIS_SEQ, 1) > 1:
+            return "ring"
+        device = mesh.devices.flat[0] if mesh is not None \
+            else jax.devices()[0]
+        t = cfg.max_seq_len if seq_len is None else seq_len
+        return "flash" if device.platform == "tpu" and \
+            flash_shape_ok(t, cfg.head_dim) else "dense"
+
+    @staticmethod
+    def _make_attention(cfg: TransformerConfig, mesh, rules: ShardingRules,
+                        seq_len: Optional[int] = None):
         import jax
         from jax.sharding import PartitionSpec as P
 
         from ray_tpu.ops.attention import dense_attention, flash_attention
 
-        impl = cfg.attention_impl
-        if impl not in ("auto", "dense", "flash", "ring", "ulysses"):
-            raise ValueError(f"unknown attention_impl {impl!r}")
+        impl = Transformer.resolve_attention_impl(cfg, mesh, seq_len)
         seq_unsharded = mesh is None or mesh.shape.get(AXIS_SEQ, 1) == 1
-        if impl == "auto":
-            impl = "flash" if seq_unsharded else "ring"
         if impl == "flash" and not seq_unsharded:
             raise ValueError("attention_impl='flash' requires an unsharded "
                              "seq axis; use ring/ulysses for SP")

@@ -71,30 +71,32 @@ def dense_attention(q, k, v, *, causal: bool = True,
     return gqa_pv(p, v).astype(q.dtype)
 
 
-def _flash_supported(t: int, head_dim: int) -> bool:
-    # The pallas kernel tiles seq into >=128 blocks and puts head_dim on
-    # the lane dim; tiny test shapes fall back to the dense path.
+def flash_shape_ok(t: int, head_dim: int) -> bool:
+    """Whether the pallas TPU flash kernel can tile this shape: seq in
+    blocks of >=128, head_dim on the lane dim."""
     return t >= 128 and t % 128 == 0 and head_dim % 64 == 0
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None):
-    """Fused flash attention on [batch, seq, heads, head_dim].
+    """Fused flash attention on [batch, seq, heads, head_dim]: the
+    pallas TPU flash kernel (O(T) memory — never materializes the
+    [B,H,T,T] score matrix), f32 accumulation inside the kernel.
 
-    On TPU this runs the pallas flash kernel (O(T) memory — never
-    materializes the [B,H,T,T] score matrix, the round-1 throughput
-    bottleneck); off-TPU or for kernel-unfriendly shapes it falls back
-    to dense_attention. Accumulation is f32 inside the kernel.
+    Raises ValueError for a shape the kernel cannot tile; off the TPU
+    the pallas lowering itself refuses. There is no dense fallback —
+    callers that want one say attention_impl="auto" or "dense".
     """
-    import jax
     import jax.numpy as jnp
 
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, t, h, d = q.shape
-    platform = jax.devices()[0].platform
-    if platform != "tpu" or not _flash_supported(t, d):
-        return dense_attention(q, k, v, causal=causal, scale=scale)
+    if not flash_shape_ok(t, d):
+        raise ValueError(
+            f"flash attention needs seq a multiple of 128 and head_dim a "
+            f"multiple of 64, got seq={t}, head_dim={d}; use "
+            f"attention_impl='auto' or 'dense' for this shape")
     if k.shape[2] != h:
         # the pallas kernel wants equal head counts; materialize the
         # GQA repeat only on this (single-device-local) path
@@ -106,7 +108,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         BlockSizes, flash_attention as _pallas_flash)
 
     # largest block <=512 that divides t (the kernel requires exact
-    # divisibility; _flash_supported guarantees t % 128 == 0)
+    # divisibility; flash_shape_ok guarantees t % 128 == 0)
     blk = next(b for b in (512, 256, 128) if t % b == 0)
     sizes = BlockSizes(
         block_q=blk, block_k_major=blk, block_k=blk, block_b=1,

@@ -90,8 +90,8 @@ def make_mesh(config: Optional[MeshConfig] = None,
 
     On real TPU slices this delegates to `mesh_utils.create_device_mesh`,
     which arranges devices so that inner mesh axes ride contiguous ICI
-    rings; on CPU (the chip-free test ladder, SURVEY.md §4) it falls back
-    to a simple reshape of the flat device list.
+    rings; on CPU (the chip-free test ladder, SURVEY.md §4) it is a
+    simple reshape of the flat device list.
     """
     import jax
     import numpy as np
@@ -111,22 +111,18 @@ def make_mesh(config: Optional[MeshConfig] = None,
 def arrange_devices(shape: Tuple[int, ...], devices: Sequence, *,
                     allow_split_physical_axes: bool = True):
     """Arrange devices into `shape`: ICI-aware on TPU via
-    mesh_utils.create_device_mesh, plain reshape elsewhere. Shared by
-    single-slice and per-slice (multislice) mesh construction."""
+    mesh_utils.create_device_mesh (which raises for a shape the slice's
+    topology cannot carry — there is no flat-order fallback, inner-axis
+    collectives would silently cross slow links), plain reshape
+    elsewhere. Shared by single-slice and per-slice (multislice) mesh
+    construction."""
     import numpy as np
 
     if devices and getattr(devices[0], "platform", "cpu") == "tpu":
-        try:
-            from jax.experimental import mesh_utils
-            return mesh_utils.create_device_mesh(
-                shape, devices=list(devices),
-                allow_split_physical_axes=allow_split_physical_axes)
-        except Exception as e:
-            import logging
-            logging.getLogger(__name__).warning(
-                "ICI-aware device mesh construction failed (%s); falling "
-                "back to flat device order — inner-axis collectives may "
-                "cross slow links", e)
+        from jax.experimental import mesh_utils
+        return mesh_utils.create_device_mesh(
+            shape, devices=list(devices),
+            allow_split_physical_axes=allow_split_physical_axes)
     return np.asarray(devices).reshape(shape)
 
 

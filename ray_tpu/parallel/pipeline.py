@@ -40,12 +40,7 @@ def make_pipeline_fn(stage_fn: Callable[[Any, Any], Any],
                          "returns the differentiable scalar objective")
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map  # jax >= 0.8
-        _relax_kwargs = {"check_vma": False}
-    except ImportError:  # older jax (kwarg was named check_rep there)
-        from jax.experimental.shard_map import shard_map
-        _relax_kwargs = {"check_rep": False}
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     fwd_perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -107,7 +102,7 @@ def make_pipeline_fn(stage_fn: Callable[[Any, Any], Any],
             in_specs = (P(AXIS_PIPE), P(), P(), P())
             args = (params_stacked, x_micro, y_micro, extras)
         pipelined = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=P(AXIS_PIPE), **_relax_kwargs)
+                              out_specs=P(AXIS_PIPE), check_vma=False)
         out = pipelined(*args)
         return out.mean()  # identical replicated per-stage values
 

@@ -36,7 +36,7 @@ def make_train_step(
 
     loss_fn(params, batch) -> scalar loss (or (loss, aux dict)).
     init_state(params) -> state dict; train_step(state, batch) ->
-    (state, metrics).
+    (state, metrics); train_step.lower(state, batch) -> jax Lowered.
     """
     import jax
     import jax.numpy as jnp
@@ -137,8 +137,7 @@ def make_train_step(
 
     _cache: Dict[Any, Callable] = {}
 
-    def train_step(state, batch):
-        from ray_tpu.util import jax_sentinel
+    def _jitted(state):
         key = jax.tree.structure(state)
         fn = _cache.get(key)
         if fn is None:
@@ -149,7 +148,16 @@ def make_train_step(
                 out_shardings=(state_shardings, None),
                 donate_argnums=(0,) if donate else ())
             _cache[key] = fn
+        return fn
+
+    def train_step(state, batch):
+        from ray_tpu.util import jax_sentinel
         with jax_sentinel.step_region("train.step"):
-            return fn(state, batch)
+            return _jitted(state)(state, batch)
+
+    # like jit's own .lower: ahead-of-time lowering of the same program
+    # (arrays or ShapeDtypeStructs), to read what the step compiles to
+    train_step.lower = lambda state, batch: _jitted(state).lower(
+        state, batch)
 
     return init_state, train_step

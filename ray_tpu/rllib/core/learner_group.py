@@ -57,22 +57,13 @@ class _MeshLearnerActor:
             if hb.start():
                 self._heartbeat = hb
                 hb.set_phase("rendezvous")
-        # Honor an explicit platform pin (the chip-free test ladder sets
-        # JAX_PLATFORMS=cpu): device plugins can re-assert themselves over
-        # the env var, so pin through jax.config like tests/conftest.py.
-        plat = os.environ.get("JAX_PLATFORMS")
-        if plat:
-            jax.config.update("jax_platforms", plat)
-        if plat == "cpu":
+        if os.environ.get("JAX_PLATFORMS") == "cpu":
             # XLA's CPU backend refuses cross-process computations
             # ("Multiprocess computations aren't implemented on the CPU
             # backend") unless collectives go through gloo — required
             # for the chip-free ladder to exercise real gang updates.
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # noqa: BLE001 - older jax: no such knob
-                pass
+            jax.config.update(
+                "jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=world, process_id=rank)
         self.rank = rank

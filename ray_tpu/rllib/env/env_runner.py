@@ -213,6 +213,12 @@ class SingleAgentEnvRunner:
         """Health probe for FaultTolerantActorManager."""
         return "pong"
 
+    def backend(self) -> str:
+        """The JAX backend this runner's process defaults to: "cpu" for
+        every actor runner, whatever chip the learner's process holds."""
+        import jax
+        return jax.default_backend()
+
     # ---- weight sync (reference worker_set.py:365 sync_weights) -----
     def set_weights(self, weights) -> None:
         self.params = weights

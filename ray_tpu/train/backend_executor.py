@@ -109,7 +109,13 @@ class BackendExecutor:
     # ---- lifecycle --------------------------------------------------
     def start(self) -> None:
         self._form_group()
-        self._mesh_init()
+        try:
+            self._mesh_init()
+        except BaseException:
+            # a gang that failed its set-up (a worker without the chips
+            # it was given) must not keep its bundles and actors
+            self._teardown_group()
+            raise
 
     def _form_group(self, settle_s: Optional[float] = None) -> None:
         """Create the worker gang + rank contexts (+ TPU visibility).

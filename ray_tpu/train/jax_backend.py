@@ -59,25 +59,36 @@ def _init_jax_distributed(coordinator_address: str, num_processes: int,
     import os
 
     import jax
-    # Honor an explicit platform pin (the chip-free test ladder sets
-    # JAX_PLATFORMS=cpu): device plugins can re-assert themselves over
-    # the env var, so pin through jax.config like tests/conftest.py.
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
-    if plat == "cpu":
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
         # XLA's CPU backend refuses cross-process computations unless
         # collectives go through gloo — needed for the chip-free ladder
         # to run real multi-process gang collectives.
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - older jax: no such knob
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id)
+
+
+def _setup_worker(num_tpus: int) -> None:
+    """Last step of gang set-up on every worker, before the train loop
+    jits anything: place the compile cache, and hold the worker to the
+    chips its ScalingConfig asked for. TPU visibility env is applied to
+    a live process, so a worker whose JAX was already pinned elsewhere
+    (a reused pool worker, a missing libtpu) would otherwise train on
+    the CPU and nobody would notice."""
+    from ray_tpu._private.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if not num_tpus:
+        return
+    import jax
+    local = jax.local_devices()
+    if len(local) != num_tpus or \
+            any(d.platform != "tpu" for d in local):
+        raise RuntimeError(
+            f"train worker was given TPU: {num_tpus} but its JAX sees "
+            f"{len(local)} local device(s) of platform "
+            f"{sorted({d.platform for d in local})}: {local}")
 
 
 from ray_tpu.train.elastic import free_port as _free_port
@@ -114,9 +125,17 @@ class _JaxBackend(Backend):
             # CPU-only head node while workers hold the TPU slice.
             distributed = len(worker_group) > 1 and \
                 worker_group.execute_single(0, _worker_has_tpu)
-        if not distributed:
+        if distributed:
+            self._init_distributed(worker_group, backend_config)
+        else:
             logger.debug("JaxBackend: single-process mode, no coordinator")
-            return
+        worker_group.execute(
+            _setup_worker,
+            int(worker_group.resources_per_worker.get("TPU", 0)))
+
+    @staticmethod
+    def _init_distributed(worker_group: WorkerGroup,
+                          backend_config: JaxConfig) -> None:
         # Rank 0's node hosts the coordinator (reference
         # torch/config.py:106-112 picks MASTER_ADDR from worker 0).
         ip = worker_group.execute_single(0, _get_node_ip)

@@ -108,7 +108,9 @@ class WorkerGroup:
                 remove_placement_group(pg)
                 raise TimeoutError(
                     f"placement group for {num_workers} x "
-                    f"{resources_per_worker} not schedulable within 120s")
+                    f"{resources_per_worker} not schedulable within 120s; "
+                    f"the cluster offers {ray_tpu.cluster_resources()} "
+                    f"(TPU counts come from /dev/accel* or /dev/vfio/*)")
             self._pg = pg
             self._pgs = [pg]
             bundle_slots = [(pg, i) for i in range(num_workers)]
@@ -208,6 +210,10 @@ class WorkerGroup:
     @property
     def placement_group(self):
         return self._pg
+
+    @property
+    def resources_per_worker(self) -> Dict[str, float]:
+        return dict(self._resources)
 
     # ---- elastic probes ---------------------------------------------
     def probe_ready(self) -> bool:

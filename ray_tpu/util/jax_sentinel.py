@@ -167,9 +167,9 @@ def install() -> bool:
         try:
             import jax
             import jax.monitoring
-            from jaxlib.xla_extension import ArrayImpl
-        except Exception:  # noqa: BLE001 - no jax in this process
+        except ImportError:  # no jax in this process
             return False
+        from jax._src.array import ArrayImpl
         from ray_tpu._private import metrics_plane
         from ray_tpu.util.metrics import Counter, get_or_create
         _compile_counter = get_or_create(
@@ -268,7 +268,7 @@ def uninstall() -> None:
         if not _installed:
             return
         import jax
-        from jaxlib.xla_extension import ArrayImpl
+        from jax._src.array import ArrayImpl
         from ray_tpu._private import metrics_plane
         ArrayImpl.item = _orig["item"]
         ArrayImpl.__array__ = _orig["__array__"]

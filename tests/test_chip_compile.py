@@ -1,0 +1,104 @@
+"""Main-path kernels and steps compiled for a TPU v5e that is described,
+not attached (on-chip-measurement guide, section 2): what the chip's
+compiler refuses is found here, at no chip time. A compile is not a run."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device, with the persistent compile cache off:
+    an entry written for a described chip cannot be read back without
+    one, and the next compile would warn about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_flash_kernel_fwd_bwd_at_the_125m_shape(v5e):
+    from ray_tpu.ops.attention import flash_attention
+
+    assert v5e.device_kind == "TPU v5 lite"
+    # GPT2_125M at batch 16: [B, T, H, D] = [16, 1024, 6, 128] bf16
+    qkv = jax.ShapeDtypeStruct((16, 1024, 6, 128), jnp.bfloat16,
+                               sharding=SingleDeviceSharding(v5e))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile()
+    # forward, dq and dkv kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_impala_learner_update_at_the_minipong_shape(v5e):
+    from ray_tpu.rllib.algorithms.impala import ImpalaConfig
+    from ray_tpu.rllib.algorithms.impala.impala import ImpalaLearner
+    from ray_tpu.rllib.core.catalog import default_module_for
+    from ray_tpu.rllib.env.base import make_env
+
+    env = make_env("MiniPong-v0", {})
+    assert env.observation_space.shape == (84, 84, 4)
+    config = ImpalaConfig().training(train_batch_size=256, lr=6e-4)
+    learner = ImpalaLearner(
+        default_module_for(env.observation_space, env.action_space,
+                           config.model_hiddens), config)
+    learner.build(seed=0)
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=SingleDeviceSharding(v5e))
+
+    def like(tree):
+        return jax.tree.map(
+            lambda x: described(jnp.shape(x), jnp.result_type(x)), tree)
+
+    t, b = 32, 8  # fragment 32 x (2 runners x 4 envs) = batch 256
+    batch = {
+        "obs": described((t, b, 84, 84, 4), jnp.uint8),
+        "actions": described((t, b), jnp.int32),
+        "rewards": described((t, b), jnp.float32),
+        "dones": described((t, b), jnp.bool_),
+        "behaviour_logp": described((t, b), jnp.float32),
+        "bootstrap_value": described((b,), jnp.float32),
+    }
+    compiled = learner._update_fn.lower(
+        like(learner._params), like(learner._opt_state), batch,
+        learner.extra_inputs()).compile()
+    used = compiled.memory_analysis()
+    assert used.temp_size_in_bytes + used.argument_size_in_bytes < 16e9
+    assert "convolution" in compiled.as_text()
+
+
+def test_explicit_flash_on_an_unsupported_shape_raises():
+    """No silent dense fallback for attention_impl="flash": TINY's
+    head_dim 16 is a shape the kernel cannot tile."""
+    from ray_tpu.models import TINY, Transformer
+
+    cfg = TINY.replace(attention_impl="flash")
+    params = Transformer.init(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((2, cfg.max_seq_len), jnp.int32)
+    with pytest.raises(ValueError, match="flash attention needs"):
+        Transformer.apply(params, tokens, cfg)
+    # "auto" off the TPU is dense, decided in one place and readable
+    auto = cfg.replace(attention_impl="auto")
+    assert Transformer.resolve_attention_impl(auto) == "dense"
+    assert jnp.isfinite(Transformer.apply(params, tokens, auto)).all()

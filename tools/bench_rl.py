@@ -1,6 +1,7 @@
 """RL north-star on the real chip (BASELINE.md measurement configs #1/#3).
 
-Run with JAX_PLATFORMS *unset* so the Learner jits to the real TPU:
+Run on a machine with a chip (JAX_PLATFORMS unset, or naming the TPU) so
+the Learner jits to the real TPU; this driver process holds the chip:
 
     python tools/bench_rl.py [--out BENCH_RL_r05.json] [--seconds 180]
 
@@ -222,6 +223,9 @@ def main() -> None:
     args = ap.parse_args()
 
     import jax
+
+    from ray_tpu._private.compile_cache import enable_compile_cache
+    enable_compile_cache()
     results = {
         "suite": "rl_north_star_on_chip",
         "round": 5,
