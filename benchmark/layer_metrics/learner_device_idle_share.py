@@ -1,0 +1,9 @@
+"""Device (RL cells): 1 - union of the operations' intervals over the
+traced seconds of `train()` calls. Device trace."""
+
+
+def read(record):
+    trace = record.get("trace") or {}
+    if not trace.get("window_s"):
+        return None
+    return 100.0 * trace["idle_s"] / trace["window_s"]
