@@ -1,0 +1,16 @@
+"""Kernels: device time under the scope `attention` that is NOT one of the
+flash kernels' own events (the configuration's `kernels.attn` patterns, as
+`attn_kernel_share` counts them) over the device's busy time: the GQA
+`jnp.repeat` of k and v, the transposes into `[B,H,T,D]` and back, and
+whatever else XLA puts around the calls. Device trace."""
+
+
+def read(record):
+    from benchlib import scope_reduce
+    reduced = scope_reduce.for_record(record)
+    kinds = ((record.get("trace") or {}).get("kernel_s") or {}).get("attn")
+    if not reduced or not kinds or not reduced["busy_s"]:
+        return None
+    glue = reduced["bucket_s"].get("attention", 0.0) \
+        - sum(seconds for seconds, _ in kinds.values())
+    return 100.0 * glue / reduced["busy_s"]

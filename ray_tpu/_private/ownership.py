@@ -94,7 +94,11 @@ class TransitionRing:
     def __init__(self, maxlen: int = 2048):
         self._ring: "collections.deque" = collections.deque(maxlen=maxlen)
         self._seq = itertools.count(1)
-        self._lock = threading.Lock()
+        # re-entrant: the append below can drop the last reference to an
+        # ObjectRef (or trip the cyclic GC), whose __del__ records a
+        # transition on this same thread — with a plain Lock that is a
+        # self-deadlock (seen in tests/test_misc_parity.py under xdist)
+        self._lock = threading.RLock()
         self.anomalies: Dict[str, int] = {}
 
     def record(self, kind: str, key: str, event: str, old: Any,

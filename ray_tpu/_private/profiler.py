@@ -126,11 +126,15 @@ class Sampler:
         self._stacks: Dict[Tuple, int] = {}
         self.samples_total = 0
         self.dropped = 0          # samples lost to the stack-table cap
-        self.sample_cost_s = 0.0  # cumulative in-situ walk time
+        # cumulative in-situ walk cost, in the sampling thread's own
+        # CPU time (time.thread_time): a walk that waits for the GIL,
+        # the control lock or a core is then not charged time in which
+        # the workload was actually running, which a wall clock does
+        # under any contention
+        self.sample_cost_s = 0.0
         # last-256 per-sample walk costs: the overhead bound uses the
-        # MEDIAN — a walk preempted mid-flight measures GIL wait (time
-        # the workload was actually running), and that preemption tail
-        # would otherwise dominate the mean under load
+        # MEDIAN, so one walk that had to rebuild the thread-name table
+        # does not set it
         from collections import deque
         self._cost_ring: "deque" = deque(maxlen=256)
         self._started_mono = 0.0
@@ -179,7 +183,7 @@ class Sampler:
         period = 1.0 / hz
         next_t = time.monotonic()
         while not stop_ev.is_set():
-            t0 = time.perf_counter()
+            t0 = time.thread_time()
             # under the control lock: snapshot() iterates the stacks
             # table and the cost ring, and an unlocked insert mid-copy
             # raises "changed size during iteration", losing the whole
@@ -190,7 +194,7 @@ class Sampler:
                     self._sample_once()
                 except Exception:  # noqa: BLE001 - a torn frame walk
                     pass           # loses one sample, never the sampler
-                cost = time.perf_counter() - t0
+                cost = time.thread_time() - t0
                 self.sample_cost_s += cost
                 self._cost_ring.append(cost)
                 self.samples_total += 1

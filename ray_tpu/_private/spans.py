@@ -26,6 +26,7 @@ ph "X" = complete span, "i" = instant event (Chrome trace phases).
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import uuid
 from _thread import get_ident as _get_ident
@@ -234,6 +235,43 @@ def span(name: str, /, **attrs: Any):
     if not _enabled:
         return _NOOP
     return _Span(name, attrs)
+
+
+class _TracedSpan(_Span):
+    """A span that is also a `jax.profiler.TraceAnnotation`: the same
+    name lands on the calling thread's line of `/host:CPU` in whatever
+    device trace is being taken, on that trace's own clock."""
+
+    __slots__ = ("annotation",)
+
+    def __enter__(self) -> Dict[str, Any]:
+        self.annotation.__enter__()
+        return _Span.__enter__(self)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        _Span.__exit__(self, exc_type, exc, tb)
+        self.annotation.__exit__(exc_type, exc, tb)
+
+
+def traced(name: str, /, **attrs: Any):
+    """span() for the few program spans a device trace should show too
+    (train.step, train.report, the learner's): in a process that has
+    already imported JAX the span also enters a
+    `jax.profiler.TraceAnnotation(name)`, dormant (about a microsecond)
+    unless a profiler session is running — the benchmark's `--trace 1`
+    or an operator's `ray_tpu profile --device`. The annotation is on
+    the profiler's clock by construction: no offset is estimated.
+
+    Never imports JAX: a process that stays off it (the train driver)
+    gets exactly span()."""
+    if not _enabled:
+        return _NOOP
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return _Span(name, attrs)
+    sp = _TracedSpan(name, attrs)
+    sp.annotation = profiler.TraceAnnotation(name)
+    return sp
 
 
 def start_span(name: str, /, **attrs: Any):

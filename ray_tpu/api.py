@@ -168,16 +168,34 @@ def timeline(filename: Optional[str] = None, *, spans: bool = False,
     paths — see _private/spans.py), aligns per-process clocks, and
     interleaves them with the task events plus CHAOS_FAULT_INJECTED
     cluster events. trace_id filters the dump to one `start_trace`
-    block's task records and span records."""
+    block's task records and span records. Without a cluster (after
+    `shutdown()`), spans=True serves this process's own ring alone."""
     import json
 
+    from ray_tpu._private import spans as spans_mod
+    if spans and not is_initialized():
+        # after shutdown() (or before init()) this process's own ring is
+        # what is left: the driver's spans of a finished fit()
+        # (train.gang.*), in the same event form
+        events = spans_mod.merge_snapshots([spans_mod.snapshot()],
+                                           trace_id=trace_id)
+    else:
+        events = _cluster_timeline(spans, trace_id)
+    if filename:
+        with open(filename, "w") as f:
+            json.dump(events, f)
+    return events
+
+
+def _cluster_timeline(spans: bool, trace_id: Optional[str]
+                      ) -> List[Dict[str, Any]]:
+    from ray_tpu._private import spans as spans_mod
     from ray_tpu._private.task_events import timeline_events
     from ray_tpu.util import state as state_api
     records = state_api.list_tasks(
         filters={"trace_id": trace_id} if trace_id else None)
     events = timeline_events(records)
     if spans:
-        from ray_tpu._private import spans as spans_mod
         w = worker_mod.global_worker()
         snaps = w.core_worker._gcs.call("spans_collect")
         events.extend(spans_mod.merge_snapshots(snaps, trace_id=trace_id))
@@ -196,9 +214,6 @@ def timeline(filename: Optional[str] = None, *, spans: bool = False,
                              "message": ev.get("message")},
                 })
         events.sort(key=lambda e: e.get("ts", 0.0))
-    if filename:
-        with open(filename, "w") as f:
-            json.dump(events, f)
     return events
 
 

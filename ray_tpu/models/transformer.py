@@ -9,6 +9,17 @@ inserts the collectives. Layers are stacked and iterated with `lax.scan`
 (one compiled layer body regardless of depth — fast compiles, and the
 stacked leading dim is the natural pipeline-parallel axis).
 
+Every block names itself with `jax.named_scope`, and the names are an
+interface (PERF.md section 3; the benchmark's per-layer metrics and an
+operator's `ray_tpu profile --device` read them off each op's op_name):
+`embed`, `layers` (the scan's own stacking, slicing and carries),
+`attn_norm`, `qkv` (projections and RoPE), `attention` (kernels, GQA
+repeat, layout transposes), `attn_out`, `mlp_norm`, `mlp/gate_up`,
+`mlp/down` (`moe` on the MoE branch), `final_norm`, `head`, `loss`; the
+train step adds `optimizer` (parallel/train_step.py). Scopes are metadata
+only. Forward, backward and recomputation need none: JAX wraps the path
+in `jvp(...)`, `transpose(jvp(...))` and remat's `rematted_computation`.
+
 Reference parity note: the reference has no in-tree LM (SURVEY.md §2.3,
 §5.7); its model math arrives via user torch code over NCCL groups. This
 module is the TPU-native replacement for that entire delegated stack.
@@ -191,11 +202,13 @@ class Transformer:
         # to reshard d->batch/seq. This is the FSDP gather-at-use
         # pattern: fwd all-gathers the table's d shards, bwd
         # reduce-scatters the grad.
-        emb = constrain(params["embed"], ("vocab", "act_embed"))
-        x = jnp.take(emb, tokens, axis=0).astype(cdt)
-        x = constrain(x, ("batch", "seq", "act_embed"))
+        with jax.named_scope("embed"):
+            emb = constrain(params["embed"], ("vocab", "act_embed"))
+            x = jnp.take(emb, tokens, axis=0).astype(cdt)
+            x = constrain(x, ("batch", "seq", "act_embed"))
 
-        cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+        with jax.named_scope("qkv"):
+            cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
         layer = Transformer._make_layer_fn(cfg, mesh, rules, cos, sin)
 
         if cfg.remat:
@@ -213,11 +226,15 @@ class Transformer:
             x, aux = layer(x, lp)
             return (x, aux_tot + aux), None
 
-        (x, aux_total), _ = lax.scan(
-            scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"],
-            unroll=cfg.scan_unroll)
+        # the scan's own work (stacking and slicing saved activations,
+        # carries) is "layers"; each block inside names itself
+        with jax.named_scope("layers"):
+            (x, aux_total), _ = lax.scan(
+                scan_body, (x, jnp.zeros((), jnp.float32)),
+                params["layers"], unroll=cfg.scan_unroll)
 
-        out = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        with jax.named_scope("final_norm"):
+            out = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
         if with_aux:
             return out, aux_total
         return out
@@ -239,54 +256,67 @@ class Transformer:
         scale = cfg.head_dim ** -0.5
 
         def layer(x, lp):
-            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-            if cfg.kv_heads == cfg.n_heads:
-                qkv = jnp.einsum("btd,dghk->btghk", h,
-                                 lp["wqkv"].astype(cdt))
-                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            else:
-                q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(cdt))
-                kv = jnp.einsum("btd,dghk->btghk", h,
-                                lp["wkv"].astype(cdt))
-                k, v = kv[:, :, 0], kv[:, :, 1]
-            q = _rope(q, cos, sin)
-            k = _rope(k, cos, sin)
-            # GQA: k/v keep their true kv_heads width end-to-end — the
-            # attention ops broadcast per group internally (ring then
-            # rotates Hkv-wide tensors over ICI, not Hq-wide repeats)
-            q = constrain(q, ("batch", "seq", "heads", "head_dim"))
-            k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
-            v = constrain(v, ("batch", "seq", "kv_heads", "head_dim"))
-            o = attn_fn(q, k, v, scale)
-            # name the (pallas) attention output so the "dots" remat
-            # policy can save it — it isn't a dot, and recomputing the
-            # kernel in bwd costs a full extra attention pass
-            from jax.ad_checkpoint import checkpoint_name
-            o = checkpoint_name(o, "attn_out")
-            o = constrain(o, ("batch", "seq", "heads", "head_dim"))
-            o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cdt))
-            x = x + constrain(o, ("batch", "seq", "act_embed"))
+            # one jax.named_scope per block (module docstring): the names
+            # reach every op's op_name, and so the device trace
+            with jax.named_scope("attn_norm"):
+                h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            with jax.named_scope("qkv"):
+                if cfg.kv_heads == cfg.n_heads:
+                    qkv = jnp.einsum("btd,dghk->btghk", h,
+                                     lp["wqkv"].astype(cdt))
+                    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                else:
+                    q = jnp.einsum("btd,dhk->bthk", h,
+                                   lp["wq"].astype(cdt))
+                    kv = jnp.einsum("btd,dghk->btghk", h,
+                                    lp["wkv"].astype(cdt))
+                    k, v = kv[:, :, 0], kv[:, :, 1]
+                q = _rope(q, cos, sin)
+                k = _rope(k, cos, sin)
+                # GQA: k/v keep their true kv_heads width end-to-end — the
+                # attention ops broadcast per group internally (ring then
+                # rotates Hkv-wide tensors over ICI, not Hq-wide repeats)
+                q = constrain(q, ("batch", "seq", "heads", "head_dim"))
+                k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
+                v = constrain(v, ("batch", "seq", "kv_heads", "head_dim"))
+            with jax.named_scope("attention"):
+                o = attn_fn(q, k, v, scale)
+                # name the (pallas) attention output so the "dots" remat
+                # policy can save it — it isn't a dot, and recomputing the
+                # kernel in bwd costs a full extra attention pass
+                from jax.ad_checkpoint import checkpoint_name
+                o = checkpoint_name(o, "attn_out")
+            with jax.named_scope("attn_out"):
+                o = constrain(o, ("batch", "seq", "heads", "head_dim"))
+                o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cdt))
+                x = x + constrain(o, ("batch", "seq", "act_embed"))
 
-            h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+            with jax.named_scope("mlp_norm"):
+                h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
             if cfg.moe_experts:
                 from ray_tpu.ops.moe import moe_ffn
-                bsz, tsz, dsz = h.shape
-                y, aux = moe_ffn(
-                    {"w_router": lp["w_router"],
-                     "w_up": lp["w_moe_up"].astype(cdt),
-                     "w_down": lp["w_moe_down"].astype(cdt)},
-                    h.reshape(bsz * tsz, dsz),
-                    num_selected=cfg.moe_top_k,
-                    capacity_factor=cfg.moe_capacity_factor,
-                    rules=rules)
-                down = y.reshape(bsz, tsz, dsz).astype(cdt)
-                x = x + constrain(down, ("batch", "seq", "act_embed"))
+                with jax.named_scope("moe"):
+                    bsz, tsz, dsz = h.shape
+                    y, aux = moe_ffn(
+                        {"w_router": lp["w_router"],
+                         "w_up": lp["w_moe_up"].astype(cdt),
+                         "w_down": lp["w_moe_down"].astype(cdt)},
+                        h.reshape(bsz * tsz, dsz),
+                        num_selected=cfg.moe_top_k,
+                        capacity_factor=cfg.moe_capacity_factor,
+                        rules=rules)
+                    down = y.reshape(bsz, tsz, dsz).astype(cdt)
+                    x = x + constrain(down, ("batch", "seq", "act_embed"))
                 return x, aux
-            gu = jnp.einsum("btd,dgf->btgf", h, lp["w_gateup"].astype(cdt))
-            ff = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
-            ff = constrain(ff, ("batch", "seq", "act_mlp"))
-            down = jnp.einsum("btf,fd->btd", ff, lp["w_down"].astype(cdt))
-            x = x + constrain(down, ("batch", "seq", "act_embed"))
+            with jax.named_scope("mlp/gate_up"):
+                gu = jnp.einsum("btd,dgf->btgf", h,
+                                lp["w_gateup"].astype(cdt))
+                ff = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
+                ff = constrain(ff, ("batch", "seq", "act_mlp"))
+            with jax.named_scope("mlp/down"):
+                down = jnp.einsum("btf,fd->btd", ff,
+                                  lp["w_down"].astype(cdt))
+                x = x + constrain(down, ("batch", "seq", "act_embed"))
             return x, jnp.zeros((), jnp.float32)
 
         return layer
@@ -298,12 +328,16 @@ class Transformer:
         lm-head projection shared by apply() and loss()."""
         import jax.numpy as jnp
 
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = jnp.einsum("btd,dv->btv", x, head.astype(x.dtype),
-                            preferred_element_type=jnp.float32)
-        return with_logical_constraint(
-            logits, ("batch", "seq", "act_vocab"), mesh=mesh, rules=rules)
+        import jax
+
+        with jax.named_scope("head"):
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = jnp.einsum("btd,dv->btv", x, head.astype(x.dtype),
+                                preferred_element_type=jnp.float32)
+            return with_logical_constraint(
+                logits, ("batch", "seq", "act_vocab"), mesh=mesh,
+                rules=rules)
 
     @staticmethod
     def apply(params, tokens, cfg: TransformerConfig, *,
@@ -360,9 +394,11 @@ class Transformer:
         mb = b // n_micro
 
         # Embed outside the pipeline, then split into microbatches.
-        emb = with_logical_constraint(
-            params["embed"], ("vocab", "act_embed"), mesh=mesh, rules=rules)
-        x = jnp.take(emb, tokens, axis=0).astype(cdt)   # [B, T, d]
+        with jax.named_scope("embed"):
+            emb = with_logical_constraint(
+                params["embed"], ("vocab", "act_embed"), mesh=mesh,
+                rules=rules)
+            x = jnp.take(emb, tokens, axis=0).astype(cdt)   # [B, T, d]
         x_micro = x.reshape(n_micro, mb, t, x.shape[-1])
         y_micro = targets.reshape(n_micro, mb, t)
 
@@ -373,8 +409,9 @@ class Transformer:
             # shard-local constants, not closure-captured traced arrays
             # (shard_map rejects auto-sharded implicit captures)
             positions = jnp.arange(t, dtype=jnp.int32)[None, :]
-            cos, sin = _rope_tables(positions, cfg.head_dim,
-                                    cfg.rope_theta)
+            with jax.named_scope("qkv"):
+                cos, sin = _rope_tables(positions, cfg.head_dim,
+                                        cfg.rope_theta)
             # mesh=None inside the stage: the pipeline shard_map already
             # owns axis mapping; constraints no-op under manual meshes.
             layer = Transformer._make_layer_fn(cfg, None, rules, cos, sin)
@@ -392,18 +429,22 @@ class Transformer:
             def body(x, lp):
                 x, _aux = layer(x, lp)
                 return x, None
-            x, _ = lax.scan(body, x, stage_params)
+            with jax.named_scope("layers"):
+                x, _ = lax.scan(body, x, stage_params)
             return x
 
         def mb_loss(out, y, extras):
-            h = _rmsnorm(out, extras["final_norm"], cfg.norm_eps)
-            logits = jnp.einsum("btd,dv->btv", h,
-                                extras["head"].astype(h.dtype),
-                                preferred_element_type=jnp.float32)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(
-                logits, y[..., None], axis=-1)[..., 0]
-            return jnp.mean(logz - gold)
+            with jax.named_scope("final_norm"):
+                h = _rmsnorm(out, extras["final_norm"], cfg.norm_eps)
+            with jax.named_scope("head"):
+                logits = jnp.einsum("btd,dv->btv", h,
+                                    extras["head"].astype(h.dtype),
+                                    preferred_element_type=jnp.float32)
+            with jax.named_scope("loss"):
+                logz = jax.nn.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(
+                    logits, y[..., None], axis=-1)[..., 0]
+                return jnp.mean(logz - gold)
 
         run = make_pipeline_fn(stage_fn, n_stages, n_micro, mesh,
                                loss_fn=mb_loss)
@@ -527,14 +568,16 @@ class Transformer:
              mesh=None, rules: Optional[ShardingRules] = None):
         """Next-token cross-entropy. batch = {"tokens": [B,T+1] or
         ("tokens","targets") pair}; returns scalar mean loss (f32)."""
+        import jax
         import jax.numpy as jnp
+        from jax import lax
 
         if "targets" in batch:
             tokens, targets = batch["tokens"], batch["targets"]
         else:
-            tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-        import jax
-        from jax import lax
+            with jax.named_scope("loss"):
+                tokens = batch["tokens"][:, :-1]
+                targets = batch["tokens"][:, 1:]
 
         mask = batch.get("mask")
         b, t = tokens.shape
@@ -545,16 +588,18 @@ class Transformer:
                                         rules=rules, with_aux=True)
             logits = Transformer._head_logits(params, x, cfg, mesh=mesh,
                                               rules=rules)
-            logits = logits.astype(jnp.float32)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(
-                logits, targets[..., None], axis=-1)[..., 0]
-            nll = logz - gold
-            aux_term = cfg.moe_aux_coeff * aux if cfg.moe_experts else 0.0
-            if mask is not None:
-                return jnp.sum(nll * mask) / jnp.maximum(
-                    jnp.sum(mask), 1.0) + aux_term
-            return jnp.mean(nll) + aux_term
+            with jax.named_scope("loss"):
+                logits = logits.astype(jnp.float32)
+                logz = jax.nn.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(
+                    logits, targets[..., None], axis=-1)[..., 0]
+                nll = logz - gold
+                aux_term = cfg.moe_aux_coeff * aux if cfg.moe_experts \
+                    else 0.0
+                if mask is not None:
+                    return jnp.sum(nll * mask) / jnp.maximum(
+                        jnp.sum(mask), 1.0) + aux_term
+                return jnp.mean(nll) + aux_term
 
         # Chunked head + cross-entropy: scan T in loss_chunk slices so only
         # one [B, chunk, vocab] f32 logits block (and its grad, via
@@ -566,42 +611,52 @@ class Transformer:
         # contract against embed directly ("vd" orientation) rather than
         # materializing a [d, vocab] transpose each step
         tied = cfg.tie_embeddings
-        head = (params["embed"] if tied else params["lm_head"]).astype(cdt)
+        with jax.named_scope("head"):
+            head = (params["embed"] if tied
+                    else params["lm_head"]).astype(cdt)
         eq = "bcd,vd->bcv" if tied else "bcd,dv->bcv"
         n = t // chunk
 
         def chunk_nll(x_c, t_c):
-            logits = jnp.einsum(eq, x_c, head,
-                                preferred_element_type=jnp.float32)
-            logits = with_logical_constraint(
-                logits, ("batch", None, "act_vocab"), mesh=mesh,
-                rules=rules)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(
-                logits, t_c[..., None], axis=-1)[..., 0]
-            return logz - gold  # [b, chunk] f32
+            with jax.named_scope("head"):
+                logits = jnp.einsum(eq, x_c, head,
+                                    preferred_element_type=jnp.float32)
+                logits = with_logical_constraint(
+                    logits, ("batch", None, "act_vocab"), mesh=mesh,
+                    rules=rules)
+            with jax.named_scope("loss"):
+                logz = jax.nn.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(
+                    logits, t_c[..., None], axis=-1)[..., 0]
+                return logz - gold  # [b, chunk] f32
 
         chunk_nll = jax.checkpoint(chunk_nll)
-        xs = jnp.swapaxes(x.reshape(b, n, chunk, x.shape[-1]), 0, 1)
-        ts = jnp.swapaxes(targets.reshape(b, n, chunk), 0, 1)
-        if mask is None:
-            def body(tot, xt):
-                return tot + jnp.sum(chunk_nll(*xt)), None
-            total, _ = lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts),
-                                unroll=cfg.scan_unroll > 1)
-            loss_val = total / (b * t)
-            if cfg.moe_experts:
-                loss_val = loss_val + cfg.moe_aux_coeff * aux
-            return loss_val
-        ms = jnp.swapaxes(
-            mask.reshape(b, n, chunk), 0, 1).astype(jnp.float32)
 
-        def body_m(tot, xtm):
-            x_c, t_c, m_c = xtm
-            return tot + jnp.sum(chunk_nll(x_c, t_c) * m_c), None
-        total, _ = lax.scan(body_m, jnp.zeros((), jnp.float32),
-                            (xs, ts, ms), unroll=cfg.scan_unroll > 1)
-        loss_val = total / jnp.maximum(jnp.sum(mask), 1.0)
+        # the chunking itself (slicing the hidden states, stacking their
+        # gradients, the running sum) is "loss"; the projection inside
+        # chunk_nll names itself "head"
+        @jax.named_scope("loss")
+        def chunked_loss():
+            xs = jnp.swapaxes(x.reshape(b, n, chunk, x.shape[-1]), 0, 1)
+            ts = jnp.swapaxes(targets.reshape(b, n, chunk), 0, 1)
+            if mask is None:
+                def body(tot, xt):
+                    return tot + jnp.sum(chunk_nll(*xt)), None
+                total, _ = lax.scan(
+                    body, jnp.zeros((), jnp.float32), (xs, ts),
+                    unroll=cfg.scan_unroll > 1)
+                return total / (b * t)
+            ms = jnp.swapaxes(
+                mask.reshape(b, n, chunk), 0, 1).astype(jnp.float32)
+
+            def body_m(tot, xtm):
+                x_c, t_c, m_c = xtm
+                return tot + jnp.sum(chunk_nll(x_c, t_c) * m_c), None
+            total, _ = lax.scan(body_m, jnp.zeros((), jnp.float32),
+                                (xs, ts, ms), unroll=cfg.scan_unroll > 1)
+            return total / jnp.maximum(jnp.sum(mask), 1.0)
+
+        loss_val = chunked_loss()
         if cfg.moe_experts:
             loss_val = loss_val + cfg.moe_aux_coeff * aux
         return loss_val

@@ -99,10 +99,13 @@ def make_train_step(
 
         (loss, aux), grads = jax.value_and_grad(
             wrapped, has_aux=True)(state["params"])
-        updates, opt_state = optimizer.update(
-            grads, state["opt_state"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        # the last name of the models' scope vocabulary
+        # (models/transformer.py): everything after the gradient
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state["opt_state"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         new_state = {
             "params": params,
             "opt_state": opt_state,
@@ -151,9 +154,14 @@ def make_train_step(
         return fn
 
     def train_step(state, batch):
+        from ray_tpu._private import spans
         from ray_tpu.util import jax_sentinel
         with jax_sentinel.step_region("train.step"):
-            return _jitted(state)(state, batch)
+            # the dispatch, in the flight recorder and (spans.traced) on
+            # the host line of a device trace; the device's own time is
+            # under the scopes of models/transformer.py
+            with spans.traced("train.step"):
+                return _jitted(state)(state, batch)
 
     # like jit's own .lower: ahead-of-time lowering of the same program
     # (arrays or ShapeDtypeStructs), to read what the step compiles to

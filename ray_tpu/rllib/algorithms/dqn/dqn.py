@@ -448,7 +448,7 @@ class DQN(Algorithm):
                 continue
             try:
                 t0 = _time.perf_counter()
-                with _spans.span("learner.step",
+                with _spans.traced("learner.step",
                                  steps=cfg.train_batch_size), \
                         jax_sentinel.step_region("learner.step"):
                     st = self.learner_group.update(

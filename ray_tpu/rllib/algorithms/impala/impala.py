@@ -212,7 +212,7 @@ class Impala(Algorithm):
             try:
                 t0 = _time.perf_counter()
                 from ray_tpu.util import jax_sentinel
-                with _spans.span("learner.step", steps=steps), \
+                with _spans.traced("learner.step", steps=steps), \
                         jax_sentinel.step_region("learner.step"):
                     stats = self.learner_group.update(batch)
                 if self._feed is not None:

@@ -294,7 +294,7 @@ class Learner:
 
         from ray_tpu._private import spans as _spans
         from ray_tpu.util import jax_sentinel
-        with _spans.span("learner.update", num_iters=num_iters), \
+        with _spans.traced("learner.update", num_iters=num_iters), \
                 jax_sentinel.step_region("learner.update"):
             return self._update_impl(batch, minibatch_size, num_iters,
                                      seed, jax)

@@ -113,7 +113,7 @@ class HostStage:
                  axis_for) -> StagedBatch:
         """Stack same-structure fragments along axis_for(key) into a
         StagedBatch backed by a pooled slot."""
-        with _spans.span("feed.stage", nfrags=len(frags)) as _sp:
+        with _spans.traced("feed.stage", nfrags=len(frags)) as _sp:
             sb = self._assemble_impl(frags, axis_for)
             _sp["bytes"] = sb.nbytes
             return sb
@@ -232,7 +232,7 @@ class DeviceFeed:
         if isinstance(batch, StagedBatch):
             nbytes = batch.nbytes
             try:
-                with _spans.span("feed.ship", bytes=nbytes, fused=True):
+                with _spans.traced("feed.ship", bytes=nbytes, fused=True):
                     segs = {dt: jax.device_put(seg)
                             for dt, seg in sorted(batch.segments.items())}
                     # intentional barrier: the transfer must land before
@@ -241,7 +241,7 @@ class DeviceFeed:
                 sig = tuple((k, dt, off, n, shape)
                             for k, (dt, off, n, shape)
                             in sorted(batch.layout.items()))
-                with _spans.span("feed.unfuse"):
+                with _spans.traced("feed.unfuse"):
                     dev = self._unfuse_fn(sig)(segs)
             finally:
                 # a failed device_put/unfuse must still return the slot
@@ -249,7 +249,7 @@ class DeviceFeed:
                 batch.release()
             self.fused_batches += 1
             return dev, nbytes
-        with _spans.span("feed.ship", fused=False) as _sp:
+        with _spans.traced("feed.ship", fused=False) as _sp:
             dev = jax.device_put(batch)
             # intentional barrier: ship measures landed-transfer time,
             # and nbytes reads need materialized leaves
@@ -297,7 +297,7 @@ class DeviceFeed:
         # sampling is the bottleneck); feed.xfer isolates the tail spent
         # waiting for an already-dequeued transfer to land in HBM
         from ray_tpu._private import goodput
-        with _spans.span("feed.wait") as _sp:
+        with _spans.traced("feed.wait") as _sp:
             try:
                 dev, meta = self._out.get(timeout=timeout)
             except queue.Empty:
@@ -309,7 +309,7 @@ class DeviceFeed:
                 _sp["empty"] = True
                 raise
             t1 = time.perf_counter()
-            with _spans.span("feed.xfer"):
+            with _spans.traced("feed.xfer"):
                 # intentional barrier: xfer_s attributes residual
                 # transfer time to the consumer-visible wait
                 jax.block_until_ready(dev)  # graftlint: disable=RT021

@@ -32,7 +32,7 @@ import uuid
 from collections import defaultdict
 from typing import Any, Callable, Dict, List, Optional
 
-from ray_tpu._private import goodput
+from ray_tpu._private import goodput, spans
 from ray_tpu.train.backend import Backend, BackendConfig
 from ray_tpu.train.config import ScalingConfig
 from ray_tpu.train.session import TrainContext, TrainingResult
@@ -77,6 +77,8 @@ class BackendExecutor:
         # re-form path — but heartbeats flow (and the gang_rank_wedged
         # probe watches them) for fixed gangs too.
         self._gang_uid: Optional[str] = None
+        self._sessions_span = None  # train.gang.sessions, begun in
+        # _init_sessions and finished in _start_sessions
         self._step_deadline = None
         if self._elastic:
             from ray_tpu.train.elastic import (MembershipWatch,
@@ -134,11 +136,16 @@ class BackendExecutor:
                                           num_workers=target)
         if gang_env:
             kwargs["runtime_env"] = gang_env
+        # fresh id per FORMATION: it is the heartbeat channel (rows from
+        # a torn-down generation must never read as this gang's
+        # liveness) and what the formation's train.gang.* spans share
+        self._gang_uid = f"train:{uuid.uuid4().hex[:8]}"
         try:
             self.worker_group = WorkerGroup(
                 target,
                 self._scaling._resources_per_worker_not_none,
-                self._scaling.placement_strategy, **kwargs)
+                self._scaling.placement_strategy, gang=self._gang_uid,
+                **kwargs)
         except TimeoutError as e:
             raise TrainingWorkerError(
                 f"gang formation infeasible: {e}") from e
@@ -149,19 +156,26 @@ class BackendExecutor:
                 "probes", len(self.worker_group), target,
                 self._scaling.elastic_min_workers)
         self._contexts = self._build_contexts(self.worker_group)
-        # fresh heartbeat channel per FORMATION: rows from a torn-down
-        # generation must never read as this gang's liveness
-        self._gang_uid = f"train:{uuid.uuid4().hex[:8]}"
         for ctx in self._contexts:
             ctx.gang_id = self._gang_uid
-        if self._scaling.num_tpus_per_worker:
-            self._share_tpu_visibility(self.worker_group)
+        with spans.span("train.gang.visibility", **self._gang_attrs()):
+            if self._scaling.num_tpus_per_worker:
+                self._share_tpu_visibility(self.worker_group)
         if self._watch is not None:
             self._watch.watch_nodes(list(self.worker_group.node_ids))
 
+    def _gang_attrs(self) -> Dict[str, Any]:
+        """What the train.gang.* spans of one formation share."""
+        return {"gang": self._gang_uid,
+                "workers": len(self.worker_group),
+                "tpus": int(self._scaling.num_tpus_per_worker)}
+
     def _mesh_init(self) -> None:
-        """Backend process-group setup (jax.distributed over the gang)."""
-        self._backend.on_start(self.worker_group, self._backend_config)
+        """Backend process-group setup (jax.distributed over the gang;
+        _setup_worker, where a TPU worker first touches its chips)."""
+        with spans.span("train.gang.backend", **self._gang_attrs()):
+            self._backend.on_start(self.worker_group,
+                                   self._backend_config)
 
     def _build_contexts(self, wg: WorkerGroup) -> List[TrainContext]:
         """World/local/node ranks from the sorted gang (reference
@@ -241,6 +255,8 @@ class BackendExecutor:
         loop reloads/reshards model+optimizer state from the durable
         checkpoint it is handed)."""
         assert self._train_args is not None
+        self._sessions_span = spans.start_span(
+            "train.gang.sessions", **self._gang_attrs())
         self._backend.on_training_start(self.worker_group,
                                         self._backend_config)
         import ray_tpu
@@ -271,6 +287,8 @@ class BackendExecutor:
         import ray_tpu
         ray_tpu.get([w.start_training_session.remote()
                      for w in self.worker_group.workers], timeout=120)
+        spans.finish_span(self._sessions_span)
+        self._sessions_span = None
 
     def get_next_results(self, timeout: float = 600.0
                          ) -> Optional[List[TrainingResult]]:
@@ -406,7 +424,6 @@ class BackendExecutor:
     def _trip_wedge(self, reply: Dict[str, Any],
                     stale: List[Dict[str, Any]], deadline: float,
                     waited: float) -> None:
-        from ray_tpu._private import spans
         from ray_tpu.train import heartbeat as hb
         cls = hb.classify_wedge(reply, stale)
         spans.instant(

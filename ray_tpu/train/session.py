@@ -14,8 +14,10 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+from time import perf_counter
 from typing import Any, Callable, Dict, Optional
 
+from ray_tpu._private import spans
 from ray_tpu.train.checkpoint import Checkpoint
 
 
@@ -92,15 +94,23 @@ class _TrainSession:
     # -- worker-loop side --------------------------------------------
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
-        if self._heartbeat is not None:
-            # the report round IS the supervisor's step unit: its
-            # deadline is calibrated on report->report time
-            self._heartbeat.note_step()
-            self._heartbeat.set_phase("train")
-        self._results.put(TrainingResult(
-            metrics=dict(metrics),
-            checkpoint_dir=checkpoint.path if checkpoint else None,
-            rank=self.context.world_rank))
+        # also on the device trace's clock (spans.traced): the loop
+        # thread is inside `train.report` for as long as the driver's
+        # result round holds it in the size-1 queue's put
+        with spans.traced("train.report",
+                          rank=self.context.world_rank) as sp:
+            if self._heartbeat is not None:
+                # the report round IS the supervisor's step unit: its
+                # deadline is calibrated on report->report time
+                self._heartbeat.note_step()
+                self._heartbeat.set_phase("train")
+            result = TrainingResult(
+                metrics=dict(metrics),
+                checkpoint_dir=checkpoint.path if checkpoint else None,
+                rank=self.context.world_rank)
+            t0 = perf_counter()
+            self._results.put(result)
+            sp["blocked_s"] = perf_counter() - t0
 
     def get_checkpoint(self) -> Optional[Checkpoint]:
         return self.starting_checkpoint

@@ -30,6 +30,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import ray_tpu
+from ray_tpu._private import spans
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.session import (TrainContext, TrainingResult,
                                    _set_session, _TrainSession)
@@ -87,7 +88,10 @@ class WorkerGroup:
                  min_workers: Optional[int] = None,
                  reform_timeout_s: Optional[float] = None,
                  reform_settle_s: Optional[float] = None,
-                 runtime_env: Optional[Dict[str, Any]] = None):
+                 runtime_env: Optional[Dict[str, Any]] = None,
+                 gang: str = ""):
+        """`gang` is the formation's id (the backend executor's
+        `_gang_uid`): the attr its train.gang.* spans share."""
         from ray_tpu.util import (PlacementGroupSchedulingStrategy,
                                   placement_group)
 
@@ -97,7 +101,10 @@ class WorkerGroup:
         self._runtime_env = runtime_env
         self.pending_pgs: List[Any] = []
         self._pgs: List[Any] = []
+        span_attrs = {"gang": gang, "workers": num_workers,
+                      "tpus": int(resources_per_worker.get("TPU", 0))}
 
+        t0 = spans.begin()
         if min_workers is None:
             # fixed gang: one all-or-nothing placement group
             pg = placement_group(
@@ -172,8 +179,10 @@ class WorkerGroup:
             self._pgs = list(ready)
             self.pending_pgs = pending
             bundle_slots = [(pg, 0) for pg in ready]
+        spans.end("train.gang.placement", t0, **span_attrs)
 
-        self.num_workers = len(bundle_slots)
+        self.num_workers = span_attrs["workers"] = len(bundle_slots)
+        t0 = spans.begin()
         cls = ray_tpu.remote(RayTrainWorker)
         opts: Dict[str, Any] = {"num_cpus": 0}
         if runtime_env:
@@ -202,6 +211,8 @@ class WorkerGroup:
             # compounds it until the cluster reads infeasible
             self.shutdown()
             raise
+        # actor creation to the last worker process answering
+        spans.end("train.gang.actors", t0, **span_attrs)
         order = sorted(range(self.num_workers),
                        key=lambda i: (infos[i][0], infos[i][1]))
         self.workers = [self.workers[i] for i in order]

@@ -12,7 +12,10 @@ This module exposes jax.profiler with the framework's ergonomics:
 
     ray_tpu.util.tpu_profiler.start_server(9012)   # live tensorboard
 
-Traces are TensorBoard-compatible (xplane) directories.
+Traces are TensorBoard-compatible (xplane) directories. Ops carry the
+models' `jax.named_scope` vocabulary in their op_name; for a named host
+region inside a trace use `ray_tpu._private.spans.traced(name)`, which the
+train loop's own `train.step` / `train.report` spans go through.
 """
 
 from __future__ import annotations
@@ -43,12 +46,6 @@ def start_server(port: int = 9012):
     or `jax.profiler.trace_remote`)."""
     import jax
     return jax.profiler.start_server(port)
-
-
-def annotate(name: str):
-    """Named region inside a trace (jax.profiler.TraceAnnotation)."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
 
 
 def latest_trace_dir(log_dir: str) -> Optional[str]:

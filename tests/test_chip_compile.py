@@ -102,3 +102,74 @@ def test_explicit_flash_on_an_unsupported_shape_raises():
     auto = cfg.replace(attention_impl="auto")
     assert Transformer.resolve_attention_impl(auto) == "dense"
     assert jnp.isfinite(Transformer.apply(params, tokens, auto)).all()
+
+
+def test_scopes_survive_the_v5e_compiler(v5e):
+    """One Mistral-width layer and the head, the whole train step: after
+    the TPU compiler's fusion the ops still carry the program's scope
+    vocabulary (PERF.md section 3) in their op_name — at least 90% of the
+    fusions that run as ops of their own, and every Pallas kernel call."""
+    import re
+
+    import optax
+
+    from ray_tpu.models import Transformer
+    from ray_tpu.models.configs import TransformerConfig
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.parallel.train_step import make_train_step
+
+    seq = 512   # the widths are what fusion decides on; compiles in 12 s
+    cfg = TransformerConfig(
+        vocab_size=32000, d_model=4096, n_layers=1, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq_len=seq, norm_eps=1e-5,
+        tie_embeddings=False, attention_impl="flash", dtype="bfloat16",
+        param_dtype="float32", remat=True, loss_chunk=256)
+    mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
+    optimizer = optax.adamw(3e-4, weight_decay=0.01)
+    _, train_step = make_train_step(
+        lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh),
+        Transformer.param_specs(cfg), mesh, optimizer=optimizer)
+
+    def init(key):
+        params = Transformer.init(key, cfg)
+        return {"params": params, "opt_state": optimizer.init(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    state = jax.eval_shape(init, jax.random.key(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, seq + 1), jnp.int32)}
+    hlo = train_step.lower(state, batch).compile().as_text()
+
+    scopes = ("embed", "layers", "attn_norm", "qkv", "attention",
+              "attn_out", "mlp_norm", "mlp/gate_up", "mlp/down",
+              "final_norm", "head", "loss", "optimizer")
+
+    def scope_in(op_name):
+        return [s for s in scopes
+                if re.search(r"[/(]" + s + r"[/)]", op_name + "/")]
+
+    # an instruction inside a computation that a fusion calls is part of
+    # that fusion, not an op that runs (and shows in a trace) by itself
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo))
+    kernels, fusions, computation = [], [], None
+    for line in hlo.splitlines():
+        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if header:
+            computation = header.group(1)
+        if computation in fused:
+            continue
+        found = re.search(r'op_name="([^"]*)"', line)
+        op_name = found.group(1) if found else ""
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels.append(op_name)
+        elif " fusion(" in line:
+            fusions.append(op_name)
+    assert len(kernels) >= 4, kernels    # fwd, remat's fwd, dq, dkv
+    assert all("attention" in scope_in(k) for k in kernels), kernels
+    kept = [f for f in fusions if scope_in(f)]
+    share = len(kept) / len(fusions)
+    print(f"fusions with a vocabulary scope: {len(kept)} of {len(fusions)} "
+          f"({100 * share:.1f}%); the others: "
+          f"{sorted({f for f in fusions if not scope_in(f)})}")
+    assert share >= 0.9
+    seen = {s for op_name in kept + kernels for s in scope_in(op_name)}
+    assert seen == set(scopes), sorted(set(scopes) - seen)

@@ -1,0 +1,101 @@
+"""The scope vocabulary of models/transformer.py and parallel/train_step.py
+(PERF.md section 3): every name reaches the lowered module's `op_name`s,
+and the names are metadata only — the compiled program is the same
+without them."""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import TINY, Transformer
+from ray_tpu.parallel import MeshConfig, make_mesh
+from ray_tpu.parallel.train_step import make_train_step
+
+BLOCKS = {"embed", "layers", "attn_norm", "qkv", "attention", "attn_out",
+          "mlp_norm", "final_norm", "head", "loss"}
+FFN = {"dense": {"mlp/gate_up", "mlp/down"}, "moe": {"moe"}}
+VARIANTS = {
+    "dense": TINY.replace(remat=True),
+    "moe": TINY.replace(remat=True, moe_experts=4),
+}
+CHUNKS = {"chunked": 32, "whole": 0}
+TRANSFORMS = re.compile(r"\b(?:jvp|transpose|vmap)\(([^()]*)\)")
+
+
+def scopes_in(module_text):
+    """Every scope path in the op names of a lowered module's debug
+    locations, the transforms JAX wraps around a scope
+    (`transpose(jvp(layers))`) taken off."""
+    found = set()
+    for name in re.findall(r'loc\("([^"]+)"', module_text):
+        while TRANSFORMS.search(name):
+            name = TRANSFORMS.sub(r"\1", name)
+        parts = name.split("/")
+        found.update(parts)
+        found.update("/".join(p) for p in zip(parts, parts[1:]))
+    return found
+
+
+def batch_for(cfg, rows):
+    return {"tokens": jnp.zeros((rows, cfg.max_seq_len + 1), jnp.int32)}
+
+
+def lower_grad(cfg):
+    params = jax.eval_shape(lambda: Transformer.init(jax.random.key(0), cfg))
+    return jax.jit(jax.grad(lambda p, b: Transformer.loss(p, b, cfg))).lower(
+        params, batch_for(cfg, 2))
+
+
+def lower_step(cfg):
+    mesh = make_mesh(MeshConfig(data=-1))
+    init_state, train_step = make_train_step(
+        lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh),
+        Transformer.param_specs(cfg), mesh)
+    state = init_state(Transformer.init(jax.random.key(0), cfg))
+    return train_step.lower(state, batch_for(cfg, len(jax.devices())))
+
+
+LOWER = {"grad_of_loss": (lower_grad, set()),
+         "train_step": (lower_step, {"optimizer"})}
+
+
+@pytest.mark.parametrize("chunk", CHUNKS)
+@pytest.mark.parametrize("variant", VARIANTS)
+@pytest.mark.parametrize("program", LOWER)
+def test_every_scope_reaches_the_lowered_op_names(program, variant, chunk):
+    lower, extra = LOWER[program]
+    cfg = VARIANTS[variant].replace(loss_chunk=CHUNKS[chunk])
+    hlo = lower(cfg).as_text(debug_info=True)
+    found = scopes_in(hlo)
+    want = BLOCKS | FFN[variant] | extra
+    assert want <= found, sorted(want - found)
+    # forward, backward and recomputation read off JAX's own wrappers
+    assert "transpose(jvp(layers))" in hlo
+    assert "rematted_computation" in hlo
+
+
+def stripped(hlo_text):
+    """Optimized HLO less its debug metadata: each op's `metadata={...}`
+    and the header's tables of source locations."""
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", hlo_text)
+    return re.sub(r"(?ms)^FileNames$.*?^StackFrames$.*?\n\n", "", text)
+
+
+@pytest.mark.parametrize("variant,chunk", [("dense", "chunked"),
+                                           ("moe", "whole")])
+def test_scopes_change_metadata_only(monkeypatch, variant, chunk):
+    cfg = VARIANTS[variant].replace(loss_chunk=CHUNKS[chunk])
+    with_scopes = lower_step(cfg).compile().as_text()
+    assert "/attn_out/" in with_scopes
+
+    @contextlib.contextmanager
+    def no_scope(name):
+        yield
+
+    monkeypatch.setattr(jax, "named_scope", no_scope)
+    without = lower_step(cfg).compile().as_text()
+    assert "/attn_out/" not in without
+    assert stripped(with_scopes) == stripped(without)

@@ -175,46 +175,81 @@ def test_device_profile_reports_or_degrades(monkeypatch):
 # ---- overhead bound (acceptance) -------------------------------------------
 
 
-def test_profiler_overhead_under_two_percent(ray_start):
-    """In-situ: sample THIS process at 100hz while a real put+get
-    workload runs; overhead fraction = hz x the measured MEDIAN
-    per-sample walk cost (the spans-overhead methodology — end-to-end
-    differentials can't resolve sub-2% under this box's noise, and the
-    mean over-counts GIL preemption: a walk descheduled mid-flight
-    measures time the workload was actually running). While STOPPED
-    the contract is structural: no sampler thread, 0 records."""
-    import numpy as np
-    arr = np.zeros(1 << 20, dtype=np.uint8)
+_OVERHEAD_SCRIPT = r"""
+import json, threading
+import numpy as np
+import ray_tpu
+from ray_tpu._private import profiler as profiler_mod
 
-    stop = threading.Event()
+ray_tpu.init(num_cpus=2)
+arr = np.zeros(1 << 20, dtype=np.uint8)
+stop = threading.Event()
 
-    def workload():
-        while not stop.is_set():
-            ray_tpu.get(ray_tpu.put(arr))
 
-    w = threading.Thread(target=workload, daemon=True)
-    w.start()
-    try:
-        best = None
-        for _ in range(3):
-            prof = profiler_mod.collect_local(1.0, hz=100)
-            assert prof["samples"] > 20, "sampler starved"
-            pct = 100.0 * prof["hz"] * prof["sample_cost_p50_s"]
-            best = pct if best is None else min(best, pct)
-            if best < 2.0:
-                break
-        assert best < 2.0, \
-            f"profiler overhead {best:.2f}% >= 2% at 100hz"
-    finally:
-        stop.set()
-        w.join(timeout=10)
-    # stopped: zero records per op, structurally
-    s = profiler_mod.sampler()
-    assert not s.running
-    frozen = s.samples_total
-    for _ in range(3):
+def workload():
+    while not stop.is_set():
         ray_tpu.get(ray_tpu.put(arr))
-    assert s.samples_total == frozen, \
+
+
+w = threading.Thread(target=workload, daemon=True)
+w.start()
+out = {"threads": threading.active_count(), "rounds": []}
+try:
+    for _ in range(3):
+        prof = profiler_mod.collect_local(1.0, hz=100)
+        out["rounds"].append(
+            {"samples": prof["samples"],
+             "pct": 100.0 * prof["hz"] * prof["sample_cost_p50_s"]})
+        if out["rounds"][-1]["pct"] < 2.0:
+            break
+finally:
+    stop.set()
+    w.join(timeout=10)
+# stopped: zero records per op, structurally
+s = profiler_mod.sampler()
+out["running_after"] = s.running
+frozen = s.samples_total
+for _ in range(3):
+    ray_tpu.get(ray_tpu.put(arr))
+out["recorded_while_stopped"] = s.samples_total - frozen
+ray_tpu.shutdown()
+print("OVERHEAD " + json.dumps(out))
+"""
+
+
+def test_profiler_overhead_under_two_percent():
+    """In-situ: sample a driver process at 100hz while a real put+get
+    workload runs in it; overhead fraction = hz x the measured MEDIAN
+    per-sample walk cost (the spans-overhead methodology — end-to-end
+    differentials can't resolve sub-2% under this box's noise). Two
+    things are held still so that the bound reads the sampler and not
+    the test run around it. The driver is a process of its own: the
+    walk costs about a microsecond per live thread
+    (`sys._current_frames()` visits them all), and a test worker that
+    has run a hundred tests before this one carries a few hundred
+    (3.3% at 230 threads, 0.8% at the 20 a fresh driver has). And the
+    sampler takes the cost in its own thread's CPU time, so a walk that
+    waits for the GIL or for a core is not charged time in which the
+    workload was running. While STOPPED the contract is structural: no
+    sampler thread, 0 records."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", _OVERHEAD_SCRIPT], cwd=root,
+        capture_output=True, text=True, timeout=180)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads([ln for ln in proc.stdout.splitlines()
+                      if ln.startswith("OVERHEAD ")][-1][9:])
+    assert all(r["samples"] > 20 for r in out["rounds"]), \
+        f"sampler starved: {out}"
+    best = min(r["pct"] for r in out["rounds"])
+    assert best < 2.0, \
+        f"profiler overhead {best:.2f}% >= 2% at 100hz: {out}"
+    assert out["running_after"] is False
+    assert out["recorded_while_stopped"] == 0, \
         "stopped profiler recorded samples during ops"
 
 

@@ -9,6 +9,7 @@ import os
 
 import numpy as np
 
+from ray_tpu._private import spans
 from ray_tpu.util import tpu_profiler
 
 
@@ -22,7 +23,9 @@ def test_trace_produces_xplane_capture(tmp_path):
 
     x = jnp.asarray(np.random.randn(64, 64), jnp.float32)
     with tpu_profiler.trace(str(tmp_path)) as d:
-        with tpu_profiler.annotate("matmul-region"):
+        # named regions inside a trace: the span helper (its name is
+        # then on the host line, tests/test_train_spans.py)
+        with spans.traced("matmul-region"):
             jax.block_until_ready(f(x))
         assert d == str(tmp_path)
     run = tpu_profiler.latest_trace_dir(str(tmp_path))
