@@ -32,7 +32,8 @@ from typing import Any, Dict, Optional
 
 from ray_tpu.models.configs import TransformerConfig
 from ray_tpu.parallel.mesh import AXIS_SEQ
-from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
+from ray_tpu.parallel.sharding import (ShardingRules, logical_sharding,
+                                       with_logical_constraint)
 
 
 def _rope_tables(positions, head_dim, theta):
@@ -617,26 +618,43 @@ class Transformer:
         eq = "bcd,vd->bcv" if tied else "bcd,dv->bcv"
         n = t // chunk
 
-        def chunk_nll(x_c, t_c):
-            with jax.named_scope("head"):
-                logits = jnp.einsum(eq, x_c, head,
-                                    preferred_element_type=jnp.float32)
-                logits = with_logical_constraint(
-                    logits, ("batch", None, "act_vocab"), mesh=mesh,
-                    rules=rules)
-            with jax.named_scope("loss"):
-                logz = jax.nn.logsumexp(logits, axis=-1)
-                gold = jnp.take_along_axis(
-                    logits, t_c[..., None], axis=-1)[..., 0]
-                return logz - gold  # [b, chunk] f32
-
-        chunk_nll = jax.checkpoint(chunk_nll)
+        # GSPMD cannot carry an unreduced sum through a loop: it reduces the
+        # whole [vocab, d] dW, and gathers the head, once per chunk. So where
+        # the mesh splits only the batch, the chunks run per chip (shard_map).
+        per_chip = False
+        if mesh is not None:
+            batch_axes, = logical_sharding(("batch",), mesh, rules, (b,)).spec
+            head_spec = logical_sharding(
+                ("vocab", "embed") if tied else ("embed", "vocab"), mesh,
+                rules, head.shape).spec
+            per_chip = batch_axes is not None and all(
+                size == 1 for a, size in mesh.shape.items()
+                if a not in batch_axes)
+        # inside the map the chip owns its layout: no GSPMD constraint
+        c_mesh = None if per_chip else mesh
 
         # the chunking itself (slicing the hidden states, stacking their
         # gradients, the running sum) is "loss"; the projection inside
         # chunk_nll names itself "head"
         @jax.named_scope("loss")
-        def chunked_loss():
+        def chunked_nll_sum(head, x, targets, mask=None):
+            """sum over these sequences of the (masked) token nll"""
+            b = x.shape[0]
+
+            def chunk_nll(x_c, t_c):
+                with jax.named_scope("head"):
+                    logits = jnp.einsum(eq, x_c, head,
+                                        preferred_element_type=jnp.float32)
+                    logits = with_logical_constraint(
+                        logits, ("batch", None, "act_vocab"), mesh=c_mesh,
+                        rules=rules)
+                with jax.named_scope("loss"):
+                    logz = jax.nn.logsumexp(logits, axis=-1)
+                    gold = jnp.take_along_axis(
+                        logits, t_c[..., None], axis=-1)[..., 0]
+                    return logz - gold  # [b, chunk] f32
+
+            chunk_nll = jax.checkpoint(chunk_nll)
             xs = jnp.swapaxes(x.reshape(b, n, chunk, x.shape[-1]), 0, 1)
             ts = jnp.swapaxes(targets.reshape(b, n, chunk), 0, 1)
             if mask is None:
@@ -645,7 +663,7 @@ class Transformer:
                 total, _ = lax.scan(
                     body, jnp.zeros((), jnp.float32), (xs, ts),
                     unroll=cfg.scan_unroll > 1)
-                return total / (b * t)
+                return total
             ms = jnp.swapaxes(
                 mask.reshape(b, n, chunk), 0, 1).astype(jnp.float32)
 
@@ -654,9 +672,32 @@ class Transformer:
                 return tot + jnp.sum(chunk_nll(x_c, t_c) * m_c), None
             total, _ = lax.scan(body_m, jnp.zeros((), jnp.float32),
                                 (xs, ts, ms), unroll=cfg.scan_unroll > 1)
-            return total / jnp.maximum(jnp.sum(mask), 1.0)
+            return total
 
-        loss_val = chunked_loss()
+        def per_chip_nll_sum(head, *local):
+            with jax.named_scope("head"):
+                for dim, axes in enumerate(head_spec):
+                    if axes is not None:
+                        head = lax.all_gather(head, axes, axis=dim,
+                                              tiled=True)
+            total = chunked_nll_sum(head, *local)
+            with jax.named_scope("loss"):
+                return lax.psum(total, batch_axes)
+
+        args = (x, targets) if mask is None else (x, targets, mask)
+        if per_chip:
+            from jax.sharding import PartitionSpec as P
+            # check_vma=False as in _make_attention: the checker types the
+            # gathered head as varying and puts a psum of dW in every chunk
+            total = jax.shard_map(
+                per_chip_nll_sum, mesh=mesh,
+                in_specs=(head_spec,) + (P(batch_axes),) * len(args),
+                out_specs=P(), check_vma=False)(head, *args)
+        else:
+            total = chunked_nll_sum(head, *args)
+        with jax.named_scope("loss"):
+            loss_val = total / (b * t if mask is None
+                                else jnp.maximum(jnp.sum(mask), 1.0))
         if cfg.moe_experts:
             loss_val = loss_val + cfg.moe_aux_coeff * aux
         return loss_val

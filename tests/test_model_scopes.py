@@ -78,9 +78,13 @@ def test_every_scope_reaches_the_lowered_op_names(program, variant, chunk):
 
 
 def stripped(hlo_text):
-    """Optimized HLO less its debug metadata: each op's `metadata={...}`
-    and the header's tables of source locations."""
+    """Optimized HLO less its debug metadata: each op's `metadata={...}`,
+    the header's tables of source locations, and the numbers that make
+    instruction names unique (inside a shard_map an instruction is named
+    after its op_name, so `%reshape.12` is `%transpose_reshape.2` without
+    the scopes and every later `%reshape.N` counts from elsewhere)."""
     text = re.sub(r",? ?metadata=\{[^}]*\}", "", hlo_text)
+    text = re.sub(r"(%[\w\-]+?)(?:\.\d+)+\b", r"\1", text)
     return re.sub(r"(?ms)^FileNames$.*?^StackFrames$.*?\n\n", "", text)
 
 

@@ -110,3 +110,61 @@ class TestTrainStep:
             mtree = state["opt_state"][0].mu if name == "embed" \
                 else state["opt_state"][0].mu["layers"]
             assert mtree[name].sharding == tree[name].sharding, name
+
+
+def _rel_l2(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
+
+
+class TestChunkedHeadPerChip:
+    """Transformer.loss's chunked head under a mesh that splits only the
+    batch runs per chip under shard_map; every other mesh keeps the GSPMD
+    path. Both must give mesh=None's loss and gradients."""
+
+    CASES = {
+        # id: (mesh axes over 4 devices, cfg overrides, masked, mapped)
+        "fsdp4": (dict(data=1, fsdp=4), {}, False, True),
+        "data2_fsdp2": (dict(data=2, fsdp=2), {}, False, True),
+        "mask": (dict(data=1, fsdp=4), {}, True, True),
+        "tied": (dict(data=2, fsdp=2), {"tie_embeddings": True}, False,
+                 True),
+        "bf16": (dict(data=1, fsdp=4), {"dtype": "bfloat16"}, False, True),
+        "tensor2": (dict(data=1, fsdp=2, tensor=2), {}, False, False),
+        "seq2": (dict(data=2, seq=2), {}, True, False),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_matches_unsharded(self, case):
+        axes, overrides, masked, mapped = self.CASES[case]
+        cfg = TINY.replace(**{"dtype": "float32", "attention_impl": "dense",
+                              "remat": True, "loss_chunk": 16, **overrides})
+        mesh = make_mesh(MeshConfig(**axes), devices=jax.devices()[:4])
+        params = Transformer.init(jax.random.PRNGKey(0), cfg)
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(4), (8, 65), 0, cfg.vocab_size)
+        batch = {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+        if masked:
+            batch["mask"] = (jax.random.uniform(
+                jax.random.PRNGKey(5), (8, 64)) < 0.7).astype(jnp.float32)
+
+        def sharded(p):
+            return Transformer.loss(p, batch, cfg, mesh=mesh)
+
+        # which path ran is read from the traced program, as the code reads
+        # it from the mesh: dense attention leaves no other shard_map
+        assert ("shard_map" in str(jax.make_jaxpr(sharded)(params))) \
+            == mapped
+        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+            lambda p: Transformer.loss(p, batch, cfg)))(params)
+        loss, grads = jax.jit(jax.value_and_grad(sharded))(params)
+        if cfg.dtype == "bfloat16":
+            # same bf16 head, activations and dW carry as one chip: the
+            # difference is the order of bf16 roundings, not a precision
+            assert abs(float(loss) - float(ref_loss)) < 2e-2
+            assert _rel_l2(grads["lm_head"], ref_grads["lm_head"]) < 1e-2
+            return
+        assert abs(float(loss) - float(ref_loss)) < 1e-6 * abs(
+            float(ref_loss)) + 1e-6
+        errs = jax.tree.map(_rel_l2, grads, ref_grads)
+        assert all(e < 1e-5 for e in jax.tree.leaves(errs)), errs

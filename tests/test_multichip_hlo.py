@@ -14,12 +14,14 @@ fully replicating the activation). These tests pin the fix:
 """
 
 import os
+import re
 import subprocess
 import sys
 
 import jax
 import jax.numpy as jnp
 import optax
+import pytest
 
 from ray_tpu.models import TINY, Transformer
 from ray_tpu.parallel import MeshConfig, make_mesh
@@ -105,3 +107,51 @@ def test_dryrun_multichip_subprocess_clean():
     assert "ok" in proc.stdout
     assert "Involuntary full rematerialization" not in proc.stderr, \
         proc.stderr[-3000:]
+
+
+_COLLECTIVE = re.compile(
+    r"= \S+ (all-reduce|reduce-scatter|all-gather)(?:-start)?\(")
+_HEAD_OR_LOSS = re.compile(r'op_name="[^"]*/(?:head|loss)/')
+_COMPUTATION = re.compile(
+    r"\n(?=(?:ENTRY )?%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)")
+
+
+def _head_loss_collectives(text):
+    """(kind, line) of the vocab head's and the loss's collectives, split
+    into those of the entry computation and those of any other (a `while`
+    body: once per loss chunk)."""
+    entry, inner = [], []
+    for comp in _COMPUTATION.split(text):
+        into = entry if comp.lstrip().startswith("ENTRY") else inner
+        for line in comp.split("\n"):
+            m = _COLLECTIVE.search(line)
+            if m and _HEAD_OR_LOSS.search(line):
+                into.append((m.group(1), line.strip()))
+    return entry, inner
+
+
+@pytest.mark.parametrize("axes", [dict(data=1, fsdp=4),
+                                  dict(data=2, fsdp=2)],
+                         ids=["fsdp4", "data2_fsdp2"])
+def test_chunked_head_has_no_collective_per_chunk(axes):
+    """With only the batch split over chips the chunked head runs per chip:
+    the head is gathered and its gradient reduced once a step, in the entry
+    computation — not once per loss chunk inside the scan's `while` bodies
+    (where GSPMD put two all-gathers and a whole-[vocab, d] all-reduce)."""
+    mesh = make_mesh(MeshConfig(**axes), devices=jax.devices()[:4])
+    cfg = TINY.replace(attention_impl="dense", remat=True, loss_chunk=16)
+    params = Transformer.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (8, 64 + 1), 0, cfg.vocab_size)
+    init_state, train_step = make_train_step(
+        lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh),
+        Transformer.param_specs(cfg), mesh, optimizer=optax.adamw(1e-3))
+    text = train_step.lower(
+        init_state(params), {"tokens": tokens}).compile().as_text()
+    assert text.count("\nENTRY ") == 1
+    entry, inner = _head_loss_collectives(text)
+    assert not inner, inner
+    d_shard = cfg.d_model // axes["fsdp"]
+    assert any(kind in ("reduce-scatter", "all-reduce")
+               and f"[{d_shard},{cfg.vocab_size}]" in line
+               for kind, line in entry), entry
