@@ -1,7 +1,10 @@
 """Transformer configurations.
 
-Scales match the BASELINE.json north-star configs: GPT-2 125M for the
-data-parallel benchmark, Llama-2 7B for the FSDP benchmark.
+Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
+Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
+BENCHMARK.json's `train_olmoe_d1`), each at its published depth. The
+benchmark's own configurations are the published `config.json` files
+under benchmark/configs/, mapped onto TransformerConfig by its jobs.
 """
 
 from __future__ import annotations
@@ -23,6 +26,10 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # RMSNorm with a learned gain over the whole q and the whole k
+    # projection (all heads together), before the split into heads and
+    # before RoPE: OLMoE's / OLMo-2's QK-norm.
+    qk_norm: bool = False
     # "auto" | "dense" | "flash" | "ring" | "ulysses". auto = pallas
     # flash kernel on TPU when the seq axis is unsharded (ring when it
     # is), dense elsewhere; dense = materialized-scores attention with
@@ -47,12 +54,20 @@ class TransformerConfig:
     # time for shallow stacks. Keep 1 (rolled) for deep models and for
     # the pipeline axis.
     scan_unroll: int = 1
-    # Mixture-of-Experts FFN (ops/moe.py Switch-style router): 0 = dense
-    # FFN; >0 replaces every layer's FFN with moe_experts experts whose
-    # weights shard over the "expert" mesh axis. The router aux
-    # (load-balancing) loss is added to the LM loss with moe_aux_coeff.
+    # Mixture-of-Experts FFN (ops/moe.py): 0 = dense FFN; >0 replaces
+    # every layer's FFN with a softmax top-k router over moe_experts
+    # gated experts of width d_ff, dropless (sorted dispatch) unless the
+    # mesh has an "expert" axis above 1, over which the expert weights
+    # shard. The router aux (load-balancing) loss is added to the LM loss
+    # with moe_aux_coeff (`router_aux_loss_coef`).
     moe_experts: int = 0
     moe_top_k: int = 2
+    # renormalise a token's top-k router weights to sum to 1
+    # (`norm_topk_prob`; OLMoE publishes false)
+    moe_norm_topk: bool = True
+    # expert-parallel (capacity) branch only: slots per expert =
+    # factor x tokens x top_k / experts, tokens beyond it dropped. The
+    # sorted path drops nothing and never reads this.
     moe_capacity_factor: float = 1.25
     moe_aux_coeff: float = 0.01
 
@@ -77,7 +92,11 @@ class TransformerConfig:
         d, l, f, v = self.d_model, self.n_layers, self.ff_dim, self.vocab_size
         hd, nh, nkv = self.head_dim, self.n_heads, self.kv_heads
         attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
+        if self.qk_norm:
+            attn += nh * hd + nkv * hd
         mlp = 3 * d * f
+        if self.moe_experts:   # every expert, and the router
+            mlp = self.moe_experts * mlp + d * self.moe_experts
         norms = 2 * d
         head = 0 if self.tie_embeddings else d * v
         return v * d + l * (attn + mlp + norms) + d + head
@@ -103,3 +122,13 @@ LLAMA2_7B = TransformerConfig(
     vocab_size=32000, d_model=4096, n_layers=32, n_heads=32,
     n_kv_heads=32, d_ff=11008, max_seq_len=4096, norm_eps=1e-5,
     remat=True)
+
+# OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json; arXiv
+# 2409.02060): 16 layers of MHA with QK-norm and 64 SiLU-gated experts of
+# width 1024, top-8 routing with unnormalised weights, no shared expert.
+# 6.92B parameters, 1.3B of them active for a token.
+OLMOE_1B_7B = TransformerConfig(
+    vocab_size=50304, d_model=2048, n_layers=16, n_heads=16,
+    n_kv_heads=16, d_ff=1024, max_seq_len=4096, rope_theta=10000.0,
+    norm_eps=1e-5, qk_norm=True, moe_experts=64, moe_top_k=8,
+    moe_norm_topk=False, moe_aux_coeff=0.01, remat=True)

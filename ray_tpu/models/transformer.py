@@ -13,11 +13,11 @@ Every block names itself with `jax.named_scope`, and the names are an
 interface (PERF.md section 3; the benchmark's per-layer metrics and an
 operator's `ray_tpu profile --device` read them off each op's op_name):
 `embed`, `layers` (the scan's own stacking, slicing and carries),
-`attn_norm`, `qkv` (projections and RoPE), `attention` (kernels, GQA
-repeat, layout transposes), `attn_out`, `mlp_norm`, `mlp/gate_up`,
-`mlp/down` (`moe` on the MoE branch), `final_norm`, `head`, `loss`; the
-train step adds `optimizer` (parallel/train_step.py). Scopes are metadata
-only. Forward, backward and recomputation need none: JAX wraps the path
+`attn_norm`, `qkv` (projections, QK-norm and RoPE), `attention` (kernels,
+GQA repeat, layout transposes), `attn_out`, `mlp_norm`, `mlp/gate_up`,
+`mlp/down` (on the MoE branch `moe/router`, `moe/dispatch`, `moe/experts`,
+`moe/combine`: ops/moe.py), `final_norm`, `head`, `loss`; the train step
+adds `optimizer` (parallel/train_step.py). Scopes are metadata only. Forward, backward and recomputation need none: JAX wraps the path
 in `jvp(...)`, `transpose(jvp(...))` and remat's `rematted_computation`.
 
 Reference parity note: the reference has no in-tree LM (SURVEY.md §2.3,
@@ -99,12 +99,16 @@ class Transformer:
         }
         if cfg.moe_experts:
             # routed expert FFN (ops/moe.py): per-layer router + stacked
-            # expert weights, expert dim sharded over the "expert" axis
+            # expert weights, each expert gated like the dense MLP below;
+            # expert dim sharded over the "expert" axis
             e = cfg.moe_experts
             layers["w_router"] = norm_init(
-                0.02, keys[5], (l, d, e)).astype(jnp.float32)
-            layers["w_moe_up"] = norm_init(
-                d ** -0.5, keys[6], (l, e, d, f))
+                0.02, jax.random.fold_in(key, 98),
+                (l, d, e)).astype(jnp.float32)
+            layers["w_moe_gateup"] = jnp.stack(
+                [norm_init(d ** -0.5, keys[5], (l, e, d, f)),
+                 norm_init(d ** -0.5, keys[6], (l, e, d, f))],
+                axis=3)  # (l, e, d, 2, f)
             layers["w_moe_down"] = norm_init(
                 f ** -0.5, keys[7], (l, e, f, d))
         else:
@@ -125,6 +129,11 @@ class Transformer:
                 [norm_init(d ** -0.5, keys[2], (l, d, nkv, hd)),
                  norm_init(d ** -0.5, keys[3], (l, d, nkv, hd))],
                 axis=2)  # (l, d, 2, nkv, hd)
+        if cfg.qk_norm:
+            # RMSNorm gains over the whole q / k projection (all heads
+            # together), applied before the split into heads and RoPE
+            layers["q_norm"] = jnp.ones((l, nh * hd), pdt)
+            layers["k_norm"] = jnp.ones((l, nkv * hd), pdt)
         params = {
             "embed": norm_init(0.02, keys[0], (cfg.vocab_size, d)),
             "layers": layers,
@@ -145,7 +154,8 @@ class Transformer:
         }
         if cfg.moe_experts:
             layers["w_router"] = ("layers", "embed", None)
-            layers["w_moe_up"] = ("layers", "expert", "embed", "mlp")
+            layers["w_moe_gateup"] = ("layers", "expert", "embed", None,
+                                      "mlp")
             layers["w_moe_down"] = ("layers", "expert", "mlp", "embed")
         else:
             layers["w_gateup"] = ("layers", "embed", None, "mlp")
@@ -156,6 +166,9 @@ class Transformer:
             layers["wq"] = ("layers", "embed", "heads", "head_dim")
             layers["wkv"] = ("layers", "embed", None, "kv_heads",
                              "head_dim")
+        if cfg.qk_norm:
+            layers["q_norm"] = ("layers", "norm")
+            layers["k_norm"] = ("layers", "norm")
         specs = {
             "embed": ("vocab", "embed"),
             "layers": layers,
@@ -174,8 +187,11 @@ class Transformer:
         (compute dtype) — apply() stopping before the lm head, so the
         loss can chunk head+softmax over T (the f32 [B,T,vocab] logits
         and their grad are the biggest HBM tenant at GPT-2 scale).
-        with_aux=True returns (hidden, aux_loss) where aux_loss is the
-        summed MoE load-balancing loss (0 for dense FFN configs).
+        with_aux=True returns (hidden, aux_loss, routing): the MoE
+        load-balancing loss over all layers (0 for dense FFN configs) and
+        the layers' stacked routing records (ops/moe.py `moe_ffn`:
+        `tokens_per_expert [layers, E]`, `router_prob [layers, E]`,
+        `dropped [layers]`; None for dense FFN configs).
 
         When `mesh` is provided and cfg.attention_impl is ring/ulysses, the
         attention op runs inside shard_map over the "seq" axis; everything
@@ -222,30 +238,34 @@ class Transformer:
             else:
                 layer = jax.checkpoint(layer)
 
-        def scan_body(carry, lp):
-            x, aux_tot = carry
-            x, aux = layer(x, lp)
-            return (x, aux_tot + aux), None
-
         # the scan's own work (stacking and slicing saved activations,
         # carries) is "layers"; each block inside names itself
         with jax.named_scope("layers"):
-            (x, aux_total), _ = lax.scan(
-                scan_body, (x, jnp.zeros((), jnp.float32)),
-                params["layers"], unroll=cfg.scan_unroll)
+            x, routing = lax.scan(layer, x, params["layers"],
+                                  unroll=cfg.scan_unroll)
+        aux_total = jnp.zeros((), jnp.float32)
+        if cfg.moe_experts:
+            # not a sum of per-layer terms: the published loss takes its
+            # two means over the tokens of all layers together
+            from ray_tpu.ops.moe import load_balancing_loss
+            with jax.named_scope("moe/router"):
+                aux_total = load_balancing_loss(
+                    routing["tokens_per_expert"], routing["router_prob"],
+                    min(cfg.moe_top_k, cfg.moe_experts))
 
         with jax.named_scope("final_norm"):
             out = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
         if with_aux:
-            return out, aux_total
+            return out, aux_total, routing
         return out
 
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
                        rules: ShardingRules, cos, sin):
-        """Build layer(x, lp) -> (x, moe_aux) — the per-layer body shared
+        """Build layer(x, lp) -> (x, routing) — the per-layer body shared
         by hidden()'s scan and the pipeline stage executor
-        (parallel/pipeline.py make_pipeline_fn)."""
+        (parallel/pipeline.py make_pipeline_fn). `routing` is the MoE
+        layer's record (ops/moe.py `moe_ffn`), None on a dense layer."""
         import jax
         import jax.numpy as jnp
 
@@ -272,6 +292,11 @@ class Transformer:
                     kv = jnp.einsum("btd,dghk->btghk", h,
                                     lp["wkv"].astype(cdt))
                     k, v = kv[:, :, 0], kv[:, :, 1]
+                if cfg.qk_norm:
+                    q = _rmsnorm(q.reshape(q.shape[:2] + (-1,)),
+                                 lp["q_norm"], cfg.norm_eps).reshape(q.shape)
+                    k = _rmsnorm(k.reshape(k.shape[:2] + (-1,)),
+                                 lp["k_norm"], cfg.norm_eps).reshape(k.shape)
                 q = _rope(q, cos, sin)
                 k = _rope(k, cos, sin)
                 # GQA: k/v keep their true kv_heads width end-to-end — the
@@ -296,19 +321,23 @@ class Transformer:
                 h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
             if cfg.moe_experts:
                 from ray_tpu.ops.moe import moe_ffn
-                with jax.named_scope("moe"):
-                    bsz, tsz, dsz = h.shape
-                    y, aux = moe_ffn(
-                        {"w_router": lp["w_router"],
-                         "w_up": lp["w_moe_up"].astype(cdt),
-                         "w_down": lp["w_moe_down"].astype(cdt)},
-                        h.reshape(bsz * tsz, dsz),
-                        num_selected=cfg.moe_top_k,
-                        capacity_factor=cfg.moe_capacity_factor,
-                        rules=rules)
-                    down = y.reshape(bsz, tsz, dsz).astype(cdt)
+
+                # moe_ffn names its own four scopes under `moe/`
+                with jax.named_scope("moe/experts"):
+                    experts = {
+                        "w_router": lp["w_router"],
+                        "w_gateup": lp["w_moe_gateup"].astype(cdt),
+                        "w_down": lp["w_moe_down"].astype(cdt)}
+                y, routing = moe_ffn(
+                    experts, h.reshape(-1, h.shape[-1]),
+                    num_selected=cfg.moe_top_k,
+                    norm_topk=cfg.moe_norm_topk,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    mesh=mesh, rules=rules)
+                with jax.named_scope("moe/combine"):
+                    down = y.reshape(h.shape).astype(cdt)
                     x = x + constrain(down, ("batch", "seq", "act_embed"))
-                return x, aux
+                return x, routing
             with jax.named_scope("mlp/gate_up"):
                 gu = jnp.einsum("btd,dgf->btgf", h,
                                 lp["w_gateup"].astype(cdt))
@@ -318,7 +347,7 @@ class Transformer:
                 down = jnp.einsum("btf,fd->btd", ff,
                                   lp["w_down"].astype(cdt))
                 x = x + constrain(down, ("batch", "seq", "act_embed"))
-            return x, jnp.zeros((), jnp.float32)
+            return x, None
 
         return layer
 
@@ -428,7 +457,7 @@ class Transformer:
                     layer = jax.checkpoint(layer)
 
             def body(x, lp):
-                x, _aux = layer(x, lp)
+                x, _routing = layer(x, lp)
                 return x, None
             with jax.named_scope("layers"):
                 x, _ = lax.scan(body, x, stage_params)
@@ -566,9 +595,17 @@ class Transformer:
     # ---- loss -------------------------------------------------------
     @staticmethod
     def loss(params, batch, cfg: TransformerConfig, *,
-             mesh=None, rules: Optional[ShardingRules] = None):
+             mesh=None, rules: Optional[ShardingRules] = None,
+             with_metrics: bool = False):
         """Next-token cross-entropy. batch = {"tokens": [B,T+1] or
-        ("tokens","targets") pair}; returns scalar mean loss (f32)."""
+        ("tokens","targets") pair}; returns scalar mean loss (f32), for a
+        MoE config plus `moe_aux_coeff` x the load-balancing loss.
+
+        with_metrics=True returns (loss, metrics), the pair
+        `make_train_step` takes: its step's metrics then carry, from the
+        same forward pass, `moe_tokens_per_expert` (int32 [layers, E]),
+        `moe_dropped` (int32 scalar; 0 on the sorted path by construction)
+        and `moe_aux_loss`; an empty dict for a dense config."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -585,8 +622,8 @@ class Transformer:
         chunk = cfg.loss_chunk
         if not (chunk and t > chunk and t % chunk == 0):
             rules = rules or ShardingRules()
-            x, aux = Transformer.hidden(params, tokens, cfg, mesh=mesh,
-                                        rules=rules, with_aux=True)
+            x, aux, routing = Transformer.hidden(
+                params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
             logits = Transformer._head_logits(params, x, cfg, mesh=mesh,
                                               rules=rules)
             with jax.named_scope("loss"):
@@ -598,16 +635,19 @@ class Transformer:
                 aux_term = cfg.moe_aux_coeff * aux if cfg.moe_experts \
                     else 0.0
                 if mask is not None:
-                    return jnp.sum(nll * mask) / jnp.maximum(
+                    loss_val = jnp.sum(nll * mask) / jnp.maximum(
                         jnp.sum(mask), 1.0) + aux_term
-                return jnp.mean(nll) + aux_term
+                else:
+                    loss_val = jnp.mean(nll) + aux_term
+            return Transformer._loss_out(loss_val, aux, routing, cfg,
+                                         with_metrics)
 
         # Chunked head + cross-entropy: scan T in loss_chunk slices so only
         # one [B, chunk, vocab] f32 logits block (and its grad, via
         # jax.checkpoint recompute) lives in HBM at a time.
         rules = rules or ShardingRules()
-        x, aux = Transformer.hidden(params, tokens, cfg, mesh=mesh,
-                                    rules=rules, with_aux=True)
+        x, aux, routing = Transformer.hidden(
+            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
         cdt = x.dtype
         # contract against embed directly ("vd" orientation) rather than
         # materializing a [d, vocab] transpose each step
@@ -700,4 +740,17 @@ class Transformer:
                                 else jnp.maximum(jnp.sum(mask), 1.0))
         if cfg.moe_experts:
             loss_val = loss_val + cfg.moe_aux_coeff * aux
-        return loss_val
+        return Transformer._loss_out(loss_val, aux, routing, cfg,
+                                     with_metrics)
+
+    @staticmethod
+    def _loss_out(loss_val, aux, routing, cfg: TransformerConfig,
+                  with_metrics: bool):
+        if not with_metrics:
+            return loss_val
+        if not cfg.moe_experts:
+            return loss_val, {}
+        return loss_val, {
+            "moe_tokens_per_expert": routing["tokens_per_expert"],
+            "moe_dropped": routing["dropped"].sum(),
+            "moe_aux_loss": aux}

@@ -1,24 +1,48 @@
-"""Mixture-of-Experts FFN with expert-parallel dispatch.
+"""Mixture-of-Experts FFN: a top-k router over gated (SwiGLU) experts.
 
 The reference has no in-tree MoE/expert parallelism (SURVEY.md §2.4 "EP:
-Absent"); this is the TPU-native capability filling that row: a
-Switch/GShard-style top-k router with bounded expert capacity, dispatch
-and combine expressed as einsums over an [tokens, experts, capacity]
-one-hot — the formulation GSPMD partitions cleanly over the "expert" mesh
-axis (the einsums lower to all-to-alls on ICI), per the public MoE
-sharding pattern (PAPERS.md / scaling-book; patterns only).
+Absent"); this is the TPU-native capability filling that row. One expert
+definition, `silu(x W_gate) * (x W_up)` then `W_down`, the dense MLP's,
+and two lowerings of the dispatch, chosen by what the code sees at trace
+time (no option):
+
+- **sorted, dropless** (no mesh, or an `expert` mesh axis of 1): the N·k
+  token-slots are sorted by expert id (`argsort`), counted (`bincount`),
+  gathered into expert order, run through two grouped matmuls over the
+  ragged groups (`grouped_matmul_impl`: pallas `megablox.gmm` on a TPU
+  where its tiles divide the shapes, else `jax.lax.ragged_dot`), and
+  gathered back by the inverse permutation into a weighted sum over each
+  token's k slots. No token is ever dropped, and nothing is larger than
+  `[N·k, max(d, 2f)]`: the only `[N, E]` tensors are the router's logits
+  and probabilities. Both permutations are gathers in the backward pass
+  too (`_permutes`: a permutation's transpose is its inverse), so the
+  step has no scatter.
+- **capacity** (an `expert` mesh axis above 1; today the virtual-CPU
+  tests): Switch/GShard dispatch and combine as einsums over an
+  `[N, E, C]` one-hot, which GSPMD partitions over the expert axis (the
+  einsums lower to all-to-alls). Tokens over an expert's capacity are
+  dropped. `[N, E, C]` is 10.7 GB at 16,384 tokens, 64 experts, top-8:
+  this branch is for small expert-parallel meshes until the sorted path
+  runs under `shard_map` with an all-to-all (ROADMAP R2).
+
+Scopes inside the caller's `moe` (PERF.md section 3): `moe/router`
+(logits, softmax, top-k), `moe/dispatch` (sort, counts, gather),
+`moe/experts` (the grouped matmuls and silu-mul), `moe/combine` (the
+gather back and the weighted sum; the caller adds the residual there).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
-from ray_tpu.parallel.sharding import with_logical_constraint
+from ray_tpu.parallel.sharding import (ShardingRules, spec_entry_size,
+                                       with_logical_constraint)
 
 # Logical specs for shard_pytree / make_train_step param placement.
 MOE_PARAM_SPECS = {
     "w_router": ("embed", None),
-    "w_up": ("expert", "embed", "mlp"),
+    "w_gateup": ("expert", "embed", None, "mlp"),
     "w_down": ("expert", "mlp", "embed"),
 }
 
@@ -34,86 +58,272 @@ def init_moe_params(key, d_model: int, d_ff: int, n_experts: int
     return {
         "w_router": jax.random.normal(
             k1, (d_model, n_experts), jnp.float32) * 0.02,
-        "w_up": jax.random.normal(
-            k2, (n_experts, d_model, d_ff), jnp.float32) * scale_in,
+        # gate and up fused along an unsharded group axis, as the dense
+        # MLP's w_gateup
+        "w_gateup": jax.random.normal(
+            k2, (n_experts, d_model, 2, d_ff), jnp.float32) * scale_in,
         "w_down": jax.random.normal(
             k3, (n_experts, d_ff, d_model), jnp.float32) * scale_out,
     }
 
 
-def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
-            capacity_factor: float = 1.25,
-            rules: Optional[Any] = None) -> Tuple[Any, Any]:
-    """Top-k routed expert FFN.
-
-    x: [tokens, d_model] (flatten [B,T,D] before calling). Returns
-    (y [tokens, d_model], aux_loss scalar) where aux_loss is the standard
-    load-balancing loss (mean router prob × mean dispatch fraction × E).
-    Tokens over a full expert's capacity are dropped (contribute zero) —
-    the Switch capacity contract.
-    """
+def route(w_router, x, num_selected: int, norm_topk: bool):
+    """Router in float32 whatever the compute dtype (a rounded logit
+    changes WHICH experts a token gets, not only by how much):
+    probabilities `[N, E]`, the top-k weights and expert ids `[N, k]`."""
     import jax
     import jax.numpy as jnp
 
-    n_tokens, d_model = x.shape
-    n_experts = params["w_router"].shape[1]
-    k = min(num_selected, n_experts)
-    capacity = max(1, int(capacity_factor * n_tokens * k / n_experts))
-
-    logits = x @ params["w_router"]                     # [N, E]
+    logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
-    # top-k selection per token
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)       # [N, k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(axis=-1, keepdims=True), 1e-9)
+    top_w, top_e = jax.lax.top_k(probs, num_selected)
+    if norm_topk:
+        top_w = top_w / jnp.maximum(
+            top_w.sum(axis=-1, keepdims=True), 1e-9)
+    return probs, top_w, top_e
 
-    # Position of each token within its expert's capacity buffer, per
-    # selection slot (cumsum over tokens of the one-hot selection).
-    onehot = jax.nn.one_hot(gate_idx, n_experts, dtype=jnp.float32)
-    # [k, N, E] cumulative counts: slot 0 fills first, then slot 1, ...
-    sel = jnp.swapaxes(onehot, 0, 1)                    # [k, N, E]
-    flat = sel.reshape(k * n_tokens, n_experts)
-    pos_flat = jnp.cumsum(flat, axis=0) - flat          # [k*N, E]
-    pos = pos_flat.reshape(k, n_tokens, n_experts)
-    within = (pos < capacity)
-    keep = jnp.swapaxes((sel * within), 0, 1)           # [N, k, E]
-    pos_k = jnp.swapaxes((pos * sel).sum(-1), 0, 1)     # [N, k]
 
-    # dispatch [N, E, C] / combine [N, E, C]
-    cap_onehot = jax.nn.one_hot(pos_k.astype(jnp.int32), capacity,
-                                dtype=jnp.float32)
-    dispatch = jnp.einsum("nke,nkc->nec", keep, cap_onehot)
-    combine = jnp.einsum("nke,nkc,nk->nec", keep, cap_onehot, gate_vals)
+def load_balancing_loss(tokens_per_expert, router_prob, top_k: int):
+    """`E * sum_e f_e * P_e` as `transformers`' `load_balancing_loss_func`
+    computes it: over the tokens of ALL layers together (`[layers, E]`
+    inputs, or `[E]` for one layer), P_e the mean router probability of
+    expert e, f_e the share of tokens that chose e in each of their k
+    slots, summed over the slots. So f sums to k, and the loss is k (not
+    1) under uniform routing."""
+    import jax.numpy as jnp
 
-    expert_in = jnp.einsum("nec,nd->ecd", dispatch, x)  # [E, C, D]
-    expert_in = with_logical_constraint(
-        expert_in, ("expert", None, None), rules=rules)
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", expert_in, params["w_up"]))
-    h = with_logical_constraint(h, ("expert", None, "act_mlp"), rules=rules)
-    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"])
-    y = jnp.einsum("nec,ecd->nd", combine, expert_out)
+    counts = jnp.atleast_2d(tokens_per_expert).astype(jnp.float32)
+    n_experts = counts.shape[-1]
+    frac = counts.sum(0) * (top_k / jnp.maximum(counts.sum(), 1.0))
+    return n_experts * jnp.sum(frac * jnp.atleast_2d(router_prob).mean(0))
 
-    # load-balancing aux loss (Switch eq. 4): E * mean_frac · mean_prob
-    frac = dispatch.sum(axis=(0, 2)) / jnp.maximum(n_tokens * k, 1)
-    mean_prob = probs.mean(axis=0)
-    aux_loss = n_experts * jnp.sum(frac * mean_prob)
-    return y, aux_loss
+
+@functools.lru_cache(maxsize=None)
+def _permutes():
+    """(slots_of, combine): the two permutations of the sorted path as
+    custom_vjp functions, built on first use (jax is imported lazily in
+    this package). A permutation's transpose is the inverse permutation,
+    so both directions are row gathers and autodiff's scatter-adds never
+    appear; a gather from the `[N, d]` side costs half of one from the
+    `[N·k, d]` side on the chip (PERF.md section 6, PR 27), which is why
+    combine's backward gathers the token's cotangent, not the slots'."""
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def slots_of(x, order, inverse, k):
+        """x `[N, d]` -> `[N·k, d]`: row s is the token of sorted slot s
+        (slot i of the unsorted order belongs to token i // k)."""
+        return jnp.take(x, order // k, axis=0)
+
+    def slots_fwd(x, order, inverse, k):
+        return slots_of(x, order, inverse, k), (order, inverse)
+
+    def slots_bwd(k, res, g):
+        order, inverse = res
+        per_slot = jnp.take(g, inverse, axis=0).reshape(-1, k, g.shape[-1])
+        dx = per_slot.astype(jnp.float32).sum(1).astype(g.dtype)
+        return dx, None, None     # the permutations are integers
+
+    slots_of.defvjp(slots_fwd, slots_bwd)
+
+    @jax.custom_vjp
+    def combine(ys, top_w, order, inverse):
+        """ys `[N·k, d]` in expert order, top_w `[N, k]` -> `[N, d]`:
+        each token's k expert outputs, weighted and summed."""
+        return combine_fwd(ys, top_w, order, inverse)[0]
+
+    def combine_fwd(ys, top_w, order, inverse):
+        n, k = top_w.shape
+        per_slot = jnp.take(ys, inverse, axis=0).reshape(
+            n, k, ys.shape[-1])
+        y = jnp.einsum("nkd,nk->nd", per_slot.astype(jnp.float32), top_w)
+        return y.astype(ys.dtype), (per_slot, top_w, order, inverse)
+
+    def combine_bwd(res, g):
+        per_slot, top_w, order, inverse = res
+        k = top_w.shape[1]
+        w_sorted = jnp.take(top_w.reshape(-1), order)
+        dys = (jnp.take(g, order // k, axis=0).astype(jnp.float32)
+               * w_sorted[:, None]).astype(per_slot.dtype)
+        dw = jnp.einsum("nd,nkd->nk", g.astype(jnp.float32),
+                        per_slot.astype(jnp.float32))
+        return dys, dw, None, None
+
+    combine.defvjp(combine_fwd, combine_bwd)
+    return slots_of, combine
+
+
+# megablox tiles (rows, contraction, columns) of the grouped matmul: the
+# fastest of those tried on the v5e that fit its VMEM (PERF.md section 6,
+# PR 27)
+GMM_TILING = (512, 1024, 1024)
+
+
+def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int) -> str:
+    """`megablox` (the pallas grouped matmul that ships with JAX) where
+    the program runs on one TPU device and the kernel's tiles divide the
+    expert FFN's shapes, else `ragged_dot` (`jax.lax.ragged_dot`: any
+    platform, any shape, and GSPMD can partition it, which it cannot a
+    pallas call). Decided at trace time, like
+    `Transformer.resolve_attention_impl`."""
+    import jax
+
+    tm, tk, tn = GMM_TILING
+    tiles = rows % tm == 0 and all(
+        width % tile == 0 for width in (d_model, d_ff, 2 * d_ff)
+        for tile in (tk, tn))
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    one_device = mesh is None or mesh.size == 1
+    return "megablox" if device.platform == "tpu" and one_device and tiles \
+        else "ragged_dot"
+
+
+def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot"):
+    """The gated expert FFN over rows already in expert order: xs
+    `[M, d]`, group_sizes `[E]` summing to M; w_gateup `[E, d, 2, f]`,
+    w_down `[E, f, d]`."""
+    import jax
+
+    if impl == "megablox":
+        from jax.experimental.pallas.ops.tpu.megablox import ops
+
+        def grouped(lhs, rhs):
+            return ops.gmm(lhs, rhs, group_sizes, lhs.dtype, GMM_TILING)
+    else:
+        def grouped(lhs, rhs):
+            return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+    n_experts, d, _two, f = w_gateup.shape
+    gu = grouped(xs, w_gateup.reshape(n_experts, d, 2 * f))
+    h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+    return grouped(h, w_down)
+
+
+def _sorted_ffn(params, x, top_w, top_e, mesh):
+    import jax
+    import jax.numpy as jnp
+
+    slots_of, combine = _permutes()
+    k = top_e.shape[1]
+    n_experts, d_model, _two, d_ff = params["w_gateup"].shape
+    with jax.named_scope("moe/dispatch"):
+        slot_expert = top_e.reshape(-1)                  # [N·k]
+        sorted_expert, order = jax.lax.sort(
+            (slot_expert, jnp.arange(slot_expert.size, dtype=jnp.int32)),
+            num_keys=1, is_stable=True)
+        inverse = jnp.argsort(order)
+        # group sizes from the sorted ids' boundaries (a binary search
+        # per expert; no [N·k, E] one-hot, no scatter)
+        counts = jnp.diff(jnp.searchsorted(
+            sorted_expert, jnp.arange(n_experts + 1, dtype=jnp.int32))
+        ).astype(jnp.int32)
+        xs = slots_of(x, order, inverse, k)              # [N·k, d]
+    with jax.named_scope("moe/experts"):
+        ys = experts_ffn(
+            xs, params["w_gateup"], params["w_down"], counts,
+            grouped_matmul_impl(mesh, xs.shape[0], d_model, d_ff))
+    with jax.named_scope("moe/combine"):
+        y = combine(ys, top_w, order, inverse)
+    return y, counts, jnp.zeros((), jnp.int32)
+
+
+def _capacity_ffn(params, x, top_w, top_e, capacity_factor, constrain):
+    import jax
+    import jax.numpy as jnp
+
+    n_tokens = x.shape[0]
+    k = top_e.shape[1]
+    n_experts = params["w_router"].shape[1]
+    capacity = max(1, int(capacity_factor * n_tokens * k / n_experts))
+    with jax.named_scope("moe/dispatch"):
+        # Position of each token within its expert's capacity buffer, per
+        # selection slot (cumsum over tokens of the one-hot selection).
+        onehot = jax.nn.one_hot(top_e, n_experts, dtype=jnp.float32)
+        # [k, N, E] cumulative counts: slot 0 fills first, then slot 1, ...
+        sel = jnp.swapaxes(onehot, 0, 1)                    # [k, N, E]
+        flat = sel.reshape(k * n_tokens, n_experts)
+        pos_flat = jnp.cumsum(flat, axis=0) - flat          # [k*N, E]
+        pos = pos_flat.reshape(k, n_tokens, n_experts)
+        keep = jnp.swapaxes(sel * (pos < capacity), 0, 1)   # [N, k, E]
+        pos_k = jnp.swapaxes((pos * sel).sum(-1), 0, 1)     # [N, k]
+        cap_onehot = jax.nn.one_hot(pos_k.astype(jnp.int32), capacity,
+                                    dtype=jnp.float32)
+        dispatch = jnp.einsum("nke,nkc->nec", keep, cap_onehot)
+        counts = flat.sum(0).astype(jnp.int32)
+        dropped = n_tokens * k - dispatch.sum().astype(jnp.int32)
+        expert_in = jnp.einsum("nec,nd->ecd", dispatch.astype(x.dtype), x)
+        expert_in = constrain(expert_in, ("expert", None, None))
+    with jax.named_scope("moe/experts"):
+        gu = jnp.einsum("ecd,edgf->ecgf", expert_in, params["w_gateup"])
+        h = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
+        h = constrain(h, ("expert", None, "act_mlp"))
+        expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"])
+    with jax.named_scope("moe/combine"):
+        combine = jnp.einsum("nke,nkc,nk->nec", keep, cap_onehot, top_w)
+        y = jnp.einsum("nec,ecd->nd", combine,
+                       expert_out.astype(jnp.float32))
+    return y.astype(x.dtype), counts, dropped
+
+
+def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
+            norm_topk: bool = True, capacity_factor: float = 1.25,
+            mesh=None, rules: Optional[ShardingRules] = None
+            ) -> Tuple[Any, Dict[str, Any]]:
+    """Top-k routed gated-expert FFN.
+
+    x: `[tokens, d_model]` (flatten `[B, T, D]` before calling); params:
+    `w_router [d, E]` (used in float32), `w_gateup [E, d, 2, f]`,
+    `w_down [E, f, d]` in the compute dtype. Returns `(y, routing)`:
+    y `[tokens, d_model]` in x's dtype and the layer's routing record
+
+        tokens_per_expert  int32 [E]  slots routed to each expert (sums
+                                      to tokens x k, drops included)
+        router_prob        f32 [E]    mean router probability
+        dropped            int32 []   slots that ran through no expert
+
+    from which `load_balancing_loss` makes the aux loss. The top-k
+    weights are renormalised to sum to 1 only if `norm_topk`.
+
+    The sorted dropless path runs unless `mesh` has an `expert` axis
+    above 1 (module docstring); `capacity_factor` applies to that
+    expert-parallel branch only, where `dropped` can be above 0.
+    """
+    import jax
+
+    rules = rules or ShardingRules()
+    k = min(num_selected, params["w_router"].shape[1])
+    with jax.named_scope("moe/router"):
+        probs, top_w, top_e = route(params["w_router"], x, k, norm_topk)
+        router_prob = probs.mean(axis=0)
+    expert_parallel = mesh is not None and spec_entry_size(
+        rules.mesh_axes("expert"), mesh) > 1
+    if expert_parallel:
+        constrain = functools.partial(with_logical_constraint, mesh=mesh,
+                                      rules=rules)
+        y, counts, dropped = _capacity_ffn(params, x, top_w, top_e,
+                                           capacity_factor, constrain)
+    else:
+        y, counts, dropped = _sorted_ffn(params, x, top_w, top_e, mesh)
+    return y, {"tokens_per_expert": counts, "router_prob": router_prob,
+               "dropped": dropped}
 
 
 def moe_ffn_dense_reference(params: Dict[str, Any], x, *,
-                            num_selected: int = 2):
-    """Un-capacitated dense check: every token runs every selected expert
-    (no drops). Used by tests to validate the dispatch math."""
+                            num_selected: int = 2, norm_topk: bool = True):
+    """Un-capacitated dense check: every token runs every expert, and the
+    unselected ones get weight 0 (no drops). Used by tests to validate
+    the dispatch math."""
     import jax
     import jax.numpy as jnp
 
-    probs = jax.nn.softmax(x @ params["w_router"], axis=-1)
     k = min(num_selected, params["w_router"].shape[1])
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(axis=-1, keepdims=True), 1e-9)
-    h = jax.nn.gelu(jnp.einsum("nd,edf->nef", x, params["w_up"]))
+    probs, top_w, top_e = route(params["w_router"], x, k, norm_topk)
+    gu = jnp.einsum("nd,edgf->negf", x, params["w_gateup"])
+    h = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
     all_out = jnp.einsum("nef,efd->ned", h, params["w_down"])
     gates = jnp.zeros(probs.shape).at[
-        jnp.arange(x.shape[0])[:, None], gate_idx].set(gate_vals)
+        jnp.arange(x.shape[0])[:, None], top_e].set(top_w)
     return jnp.einsum("ne,ned->nd", gates, all_out)

@@ -34,7 +34,10 @@ def make_train_step(
 ) -> Tuple[Callable, Callable]:
     """Build (init_state, train_step), both jitted with explicit shardings.
 
-    loss_fn(params, batch) -> scalar loss (or (loss, aux dict)).
+    loss_fn(params, batch) -> scalar loss, or (loss, aux dict) whose
+    entries join the step's metrics beside `loss`, `grad_norm` and `step`
+    (`Transformer.loss(..., with_metrics=True)`: a MoE model's per-expert
+    token counts reach the loop from the step's own forward pass).
     init_state(params) -> state dict; train_step(state, batch) ->
     (state, metrics); train_step.lower(state, batch) -> jax Lowered.
     """

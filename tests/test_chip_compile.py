@@ -173,3 +173,66 @@ def test_scopes_survive_the_v5e_compiler(v5e):
     assert share >= 0.9
     seen = {s for op_name in kept + kernels for s in scope_in(op_name)}
     assert seen == set(scopes), sorted(set(scopes) - seen)
+
+
+def test_olmoe_layer_step_compiles_with_the_grouped_matmul_kernels(v5e):
+    """One OLMoE-width layer and the head, the whole train step
+    (`train_olmoe_d1`'s widths, a shorter sequence): on one TPU device the
+    expert FFN takes the pallas grouped matmul, forward (`gmm`) and both
+    transposes (`gmm`, `tgmm`); every kernel and the fusions of the expert
+    layer keep the `moe/*` sub-scopes after the v5e compiler; and no
+    buffer of the program is a `[tokens, experts, anything]` one-hot."""
+    import re
+
+    import optax
+
+    from ray_tpu.models import OLMOE_1B_7B, Transformer
+    from ray_tpu.ops.moe import grouped_matmul_impl
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.parallel.train_step import make_train_step
+
+    seq, rows = 512, 3     # 1,536 tokens: a size no width of the model has
+    cfg = OLMOE_1B_7B.replace(n_layers=1, max_seq_len=seq,
+                              attention_impl="flash", loss_chunk=256)
+    mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
+    slots = rows * seq * cfg.moe_top_k
+    assert grouped_matmul_impl(mesh, slots, cfg.d_model, cfg.ff_dim) == \
+        "megablox"
+    assert grouped_matmul_impl(None, slots, cfg.d_model, cfg.ff_dim) == \
+        "ragged_dot"          # this process's own devices are CPUs
+    assert grouped_matmul_impl(mesh, slots + 8, cfg.d_model,
+                               cfg.ff_dim) == "ragged_dot"
+    optimizer = optax.adamw(3e-4, weight_decay=0.01)
+    _, train_step = make_train_step(
+        lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh,
+                                      with_metrics=True),
+        Transformer.param_specs(cfg), mesh, optimizer=optimizer)
+
+    def init(key):
+        params = Transformer.init(key, cfg)
+        return {"params": params, "opt_state": optimizer.init(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    state = jax.eval_shape(init, jax.random.key(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, seq + 1), jnp.int32)}
+    hlo = train_step.lower(state, batch).compile().as_text()
+
+    kernels = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', hlo)
+    grouped = [(name, op) for name, op in kernels
+               if re.match(r"t?gmm(\.\d+)?$", name)]
+    # gate/up and down: forward, remat's forward, d lhs (gmm); d rhs (tgmm)
+    assert sum(n.startswith("gmm") for n, _ in grouped) == 6, kernels
+    assert sum(n.startswith("tgmm") for n, _ in grouped) == 2, kernels
+    assert all("moe/experts" in op for _, op in grouped), grouped
+    for sub in ("moe/router", "moe/dispatch", "moe/experts", "moe/combine"):
+        assert re.search(r'op_name="[^"]*[/(]' + sub + r'[/)]', hlo), sub
+    # nothing has a tokens-sized and an experts-sized dimension and a third
+    tokens = rows * seq
+    shapes = {tuple(map(int, dims.split(",")))
+              for dims in re.findall(r"\w+\[([\d,]+)\]", hlo)}
+    assert (tokens, cfg.moe_experts) in shapes           # the router's own
+    bad = [s for s in shapes if cfg.moe_experts in s and len(s) > 2
+           and (tokens in s or slots in s)]
+    assert not bad, bad

@@ -368,18 +368,22 @@ def test_kill_worker_postmortem_bundle(ray_start):
 
 
 def test_task_error_postmortem(ray_start):
+    # a name of its own: tests/test_core_api.py has a failing `boom` too,
+    # and under xdist both files can share one worker's cluster
     @ray_tpu.remote
-    def boom():
+    def boom_postmortem():
         print("pre-failure context BOOM-MARK")
         raise ValueError("intentional")
 
     with pytest.raises(ValueError):
-        ray_tpu.get(boom.options(max_retries=0).remote(), timeout=120)
+        ray_tpu.get(boom_postmortem.options(max_retries=0).remote(),
+                    timeout=120)
     found = None
     deadline = time.monotonic() + 15
     while time.monotonic() < deadline and found is None:
         for s in state_api.postmortems():
-            if s.get("kind") == "task_error" and s.get("task") == "boom":
+            if s.get("kind") == "task_error" \
+                    and s.get("task") == "boom_postmortem":
                 found = state_api.get_postmortem(s["postmortem_id"])
                 break
         time.sleep(0.2)

@@ -16,7 +16,9 @@ from ray_tpu.parallel.train_step import make_train_step
 
 BLOCKS = {"embed", "layers", "attn_norm", "qkv", "attention", "attn_out",
           "mlp_norm", "final_norm", "head", "loss"}
-FFN = {"dense": {"mlp/gate_up", "mlp/down"}, "moe": {"moe"}}
+FFN = {"dense": {"mlp/gate_up", "mlp/down"},
+       "moe": {"moe", "moe/router", "moe/dispatch", "moe/experts",
+               "moe/combine"}}
 VARIANTS = {
     "dense": TINY.replace(remat=True),
     "moe": TINY.replace(remat=True, moe_experts=4),

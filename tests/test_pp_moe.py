@@ -2,8 +2,9 @@
 
 Runs on the chip-free 8-device CPU mesh (conftest). Pipeline: 2-stage
 microbatched spmd pipeline must match the unpipelined model's loss and
-gradients. MoE: capacity dispatch must match the dense reference when
-capacity is ample, shard over the expert axis, and train.
+gradients. MoE: the sorted dropless dispatch and the expert-parallel
+capacity dispatch must match the dense reference (the latter when capacity
+is ample), shard over the expert axis, and train.
 """
 
 import numpy as np
@@ -12,8 +13,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.moe import (init_moe_params, moe_ffn,
-                             moe_ffn_dense_reference)
+from ray_tpu.ops.moe import (init_moe_params, load_balancing_loss,
+                             moe_ffn, moe_ffn_dense_reference)
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.parallel.pipeline import make_pipeline_fn, stack_stage_params
 
@@ -108,33 +109,59 @@ class TestPipeline:
         assert losses[-1] < losses[0] * 0.5, losses[::10]
 
 
+def _aux(routing, k):
+    return load_balancing_loss(routing["tokens_per_expert"],
+                               routing["router_prob"], k)
+
+
 class TestMoE:
-    def test_matches_dense_reference_with_ample_capacity(self):
+    """Both lowerings of `moe_ffn` on the same gated experts: the sorted
+    dropless path (no mesh, or no expert axis) and the capacity path an
+    `expert` mesh axis above 1 selects."""
+
+    @pytest.mark.parametrize("path", ["sorted", "capacity"])
+    def test_matches_dense_reference_with_ample_capacity(self, path):
+        mesh = None if path == "sorted" else make_mesh(
+            MeshConfig(data=2, expert=4))
         key = jax.random.PRNGKey(0)
         params = init_moe_params(key, d_model=16, d_ff=32, n_experts=4)
         x = jax.random.normal(jax.random.PRNGKey(1), (24, 16))
-        y, aux = moe_ffn(params, x, num_selected=2, capacity_factor=4.0)
+        y, routing = moe_ffn(params, x, num_selected=2,
+                             capacity_factor=4.0, mesh=mesh)
         y_ref = moe_ffn_dense_reference(params, x, num_selected=2)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-5)
-        assert float(aux) > 0.0
+        assert float(_aux(routing, 2)) > 0.0
+        assert int(routing["dropped"]) == 0
+        assert int(routing["tokens_per_expert"].sum()) == 24 * 2
 
     def test_capacity_drops_tokens(self):
+        """The expert-parallel branch keeps the Switch capacity contract;
+        the sorted path, given the same skew, drops nothing."""
+        mesh = make_mesh(MeshConfig(data=4, expert=2))
         key = jax.random.PRNGKey(2)
         params = init_moe_params(key, d_model=8, d_ff=16, n_experts=2)
         x = jax.random.normal(jax.random.PRNGKey(3), (16, 8))
-        y_tight, _ = moe_ffn(params, x, num_selected=1,
-                             capacity_factor=0.25)
-        y_ample, _ = moe_ffn(params, x, num_selected=1,
-                             capacity_factor=4.0)
+        y_tight, r_tight = moe_ffn(params, x, num_selected=1,
+                                   capacity_factor=0.25, mesh=mesh)
+        y_ample, r_ample = moe_ffn(params, x, num_selected=1,
+                                   capacity_factor=4.0, mesh=mesh)
         # tight capacity zeroes some tokens' outputs
         dropped = np.sum(np.all(np.asarray(y_tight) == 0.0, axis=-1))
         kept_all = np.sum(np.all(np.asarray(y_ample) == 0.0, axis=-1))
         assert dropped > kept_all
+        assert int(r_tight["dropped"]) == dropped > 0
+        assert int(r_ample["dropped"]) == 0
+        y_sorted, r_sorted = moe_ffn(params, x, num_selected=1,
+                                     capacity_factor=0.25)
+        assert int(r_sorted["dropped"]) == 0
+        np.testing.assert_allclose(np.asarray(y_sorted),
+                                   np.asarray(y_ample), rtol=1e-4,
+                                   atol=1e-5)
 
     def test_sharded_over_expert_axis(self):
-        """The same einsum formulation runs under jit with params sharded
-        on the expert mesh axis (GSPMD inserts the all-to-alls)."""
+        """The einsum formulation runs under jit with params sharded on
+        the expert mesh axis (GSPMD inserts the all-to-alls)."""
         from ray_tpu.parallel.sharding import shard_pytree
         from ray_tpu.ops.moe import MOE_PARAM_SPECS
 
@@ -147,10 +174,10 @@ class TestMoE:
 
         @jax.jit
         def f(p, x):
-            y, aux = moe_ffn(p, x, num_selected=2, capacity_factor=4.0)
-            return y, aux
+            return moe_ffn(p, x, num_selected=2, capacity_factor=4.0,
+                           mesh=mesh)
 
-        y, aux = f(params_sharded, x)
+        y, _routing = f(params_sharded, x)
         y_ref = moe_ffn_dense_reference(params, x, num_selected=2)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-5)
@@ -165,8 +192,8 @@ class TestMoE:
                                                 (8, 8)))
 
         def loss_fn(p):
-            y, aux = moe_ffn(p, x, num_selected=2, capacity_factor=2.0)
-            return jnp.mean((y - target) ** 2) + 0.01 * aux
+            y, routing = moe_ffn(p, x, num_selected=2)
+            return jnp.mean((y - target) ** 2) + 0.01 * _aux(routing, 2)
 
         opt = optax.adam(3e-3)
         opt_state = opt.init(params)
@@ -209,7 +236,7 @@ class TestFlagshipIntegration:
             losses.append(float(m["loss"]))
         assert losses[-1] < losses[0], losses
         # expert weights actually sharded over the expert axis
-        up = state["params"]["layers"]["w_moe_up"]
+        up = state["params"]["layers"]["w_moe_gateup"]
         spec = up.sharding.spec
         assert "expert" in str(spec), spec
 
