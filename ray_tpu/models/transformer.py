@@ -643,8 +643,10 @@ class Transformer:
                                          with_metrics)
 
         # Chunked head + cross-entropy: scan T in loss_chunk slices so only
-        # one [B, chunk, vocab] f32 logits block (and its grad, via
-        # jax.checkpoint recompute) lives in HBM at a time.
+        # one [B, chunk, vocab] f32 logits block lives in HBM at a time. Each
+        # chunk's gradient is taken in that same scan (`nll_sum_fwd`), while
+        # its logits exist: nothing is saved for, or computed again in, the
+        # backward pass.
         rules = rules or ShardingRules()
         x, aux, routing = Transformer.hidden(
             params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
@@ -673,46 +675,68 @@ class Transformer:
         # inside the map the chip owns its layout: no GSPMD constraint
         c_mesh = None if per_chip else mesh
 
+        def chunk_nll_sum(head, x_c, t_c, m_c):
+            """the (masked) token nll of one chunk, summed: f32 scalar"""
+            with jax.named_scope("head"):
+                logits = jnp.einsum(eq, x_c, head,
+                                    preferred_element_type=jnp.float32)
+                logits = with_logical_constraint(
+                    logits, ("batch", None, "act_vocab"), mesh=c_mesh,
+                    rules=rules)
+            with jax.named_scope("loss"):
+                logz = jax.nn.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(
+                    logits, t_c[..., None], axis=-1)[..., 0]
+                nll = logz - gold  # [b, chunk] f32
+                return jnp.sum(nll if m_c is None else nll * m_c)
+
+        def scan_chunks(step, init, x, targets, mask):
+            """`step(carry, (x_c, t_c, m_c))` over the n chunks of these
+            sequences; m_c is None for a batch without a mask"""
+            def split(a):  # [b, t, ...] -> [n, b, chunk, ...]
+                return jnp.swapaxes(
+                    a.reshape(a.shape[0], n, chunk, *a.shape[2:]), 0, 1)
+            ms = None if mask is None else split(mask).astype(jnp.float32)
+            return lax.scan(step, init, (split(x), split(targets), ms),
+                            unroll=cfg.scan_unroll > 1)
+
+        @jax.custom_vjp
+        def nll_sum(head, x, targets, mask):
+            def step(total, xtm):
+                return total + chunk_nll_sum(head, *xtm), None
+            return scan_chunks(step, jnp.zeros((), jnp.float32),
+                               x, targets, mask)[0]
+
+        def nll_sum_fwd(head, x, targets, mask):
+            # the sum's incoming cotangent is one scalar, so d head and dx
+            # are complete here but for that factor; d head is carried in
+            # the head's dtype, as autodiff's backward scan carried it
+            def step(carry, xtm):
+                total, d_head = carry
+                nll, (dh_c, dx_c) = jax.value_and_grad(
+                    chunk_nll_sum, argnums=(0, 1))(head, *xtm)
+                return (total + nll, d_head + dh_c), dx_c
+            (total, d_head), dxs = scan_chunks(
+                step, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
+                x, targets, mask)
+            return total, (d_head, jnp.swapaxes(dxs, 0, 1).reshape(x.shape))
+
+        def nll_sum_bwd(res, g):
+            with jax.named_scope("loss"):
+                d_head, dx = ((g * r).astype(r.dtype) for r in res)
+            return d_head, dx, None, None
+
+        nll_sum.defvjp(nll_sum_fwd, nll_sum_bwd)
+
         # the chunking itself (slicing the hidden states, stacking their
-        # gradients, the running sum) is "loss"; the projection inside
-        # chunk_nll names itself "head"
+        # gradients, the running sums) is "loss"; the projection inside
+        # chunk_nll_sum names itself "head"
         @jax.named_scope("loss")
         def chunked_nll_sum(head, x, targets, mask=None):
-            """sum over these sequences of the (masked) token nll"""
-            b = x.shape[0]
-
-            def chunk_nll(x_c, t_c):
-                with jax.named_scope("head"):
-                    logits = jnp.einsum(eq, x_c, head,
-                                        preferred_element_type=jnp.float32)
-                    logits = with_logical_constraint(
-                        logits, ("batch", None, "act_vocab"), mesh=c_mesh,
-                        rules=rules)
-                with jax.named_scope("loss"):
-                    logz = jax.nn.logsumexp(logits, axis=-1)
-                    gold = jnp.take_along_axis(
-                        logits, t_c[..., None], axis=-1)[..., 0]
-                    return logz - gold  # [b, chunk] f32
-
-            chunk_nll = jax.checkpoint(chunk_nll)
-            xs = jnp.swapaxes(x.reshape(b, n, chunk, x.shape[-1]), 0, 1)
-            ts = jnp.swapaxes(targets.reshape(b, n, chunk), 0, 1)
-            if mask is None:
-                def body(tot, xt):
-                    return tot + jnp.sum(chunk_nll(*xt)), None
-                total, _ = lax.scan(
-                    body, jnp.zeros((), jnp.float32), (xs, ts),
-                    unroll=cfg.scan_unroll > 1)
-                return total
-            ms = jnp.swapaxes(
-                mask.reshape(b, n, chunk), 0, 1).astype(jnp.float32)
-
-            def body_m(tot, xtm):
-                x_c, t_c, m_c = xtm
-                return tot + jnp.sum(chunk_nll(x_c, t_c) * m_c), None
-            total, _ = lax.scan(body_m, jnp.zeros((), jnp.float32),
-                                (xs, ts, ms), unroll=cfg.scan_unroll > 1)
-            return total
+            """sum over these sequences of the (masked) token nll. A
+            custom_vjp: reverse mode only (nothing in the tree takes a
+            forward-mode or a second derivative of the loss)."""
+            return nll_sum(head, x, targets, mask)
 
         def per_chip_nll_sum(head, *local):
             with jax.named_scope("head"):

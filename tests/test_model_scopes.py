@@ -27,18 +27,22 @@ CHUNKS = {"chunked": 32, "whole": 0}
 TRANSFORMS = re.compile(r"\b(?:jvp|transpose|vmap)\(([^()]*)\)")
 
 
+def scopes_of(name):
+    """Every scope path in one op name, the transforms JAX wraps around a
+    scope (`transpose(jvp(layers))`) taken off."""
+    while TRANSFORMS.search(name):
+        name = TRANSFORMS.sub(r"\1", name)
+    parts = name.split("/")
+    return set(parts) | {"/".join(p) for p in zip(parts, parts[1:])}
+
+
+def op_names(module_text):
+    """The op names in a lowered module's debug locations."""
+    return re.findall(r'loc\("([^"]+)"', module_text)
+
+
 def scopes_in(module_text):
-    """Every scope path in the op names of a lowered module's debug
-    locations, the transforms JAX wraps around a scope
-    (`transpose(jvp(layers))`) taken off."""
-    found = set()
-    for name in re.findall(r'loc\("([^"]+)"', module_text):
-        while TRANSFORMS.search(name):
-            name = TRANSFORMS.sub(r"\1", name)
-        parts = name.split("/")
-        found.update(parts)
-        found.update("/".join(p) for p in zip(parts, parts[1:]))
-    return found
+    return set().union(*map(scopes_of, op_names(module_text)))
 
 
 def batch_for(cfg, rows):
@@ -77,6 +81,42 @@ def test_every_scope_reaches_the_lowered_op_names(program, variant, chunk):
     # forward, backward and recomputation read off JAX's own wrappers
     assert "transpose(jvp(layers))" in hlo
     assert "rematted_computation" in hlo
+
+
+def lower_eval(cfg):
+    params = jax.eval_shape(lambda: Transformer.init(jax.random.key(0), cfg))
+    return jax.jit(lambda p, b: Transformer.loss(p, b, cfg)).lower(
+        params, batch_for(cfg, 2))
+
+
+# a vocabulary no other dimension of the model equals, so a matmul with it
+# in its type is one of the head's
+VOCAB = 272
+# per loss chunk: the logits, and with a gradient asked dx and dW, taken in
+# the forward scan while the logits exist -- never the logits a second time
+VOCAB_DOTS = {"grad_of_loss": (lower_grad, 3), "train_step": (lower_step, 3),
+              "evaluation": (lower_eval, 1)}
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+@pytest.mark.parametrize("program", VOCAB_DOTS)
+def test_chunked_head_recomputes_nothing(program, variant):
+    lower, dots = VOCAB_DOTS[program]
+    cfg = VARIANTS[variant].replace(loss_chunk=CHUNKS["chunked"],
+                                    vocab_size=VOCAB)
+    hlo = lower(cfg).as_text(debug_info=True)
+    # the scan's body is in the module once, whatever the number of chunks
+    vocab_dots = [line for line in hlo.splitlines()
+                  if "stablehlo.dot_general" in line
+                  and re.search(rf"(?<!\d){VOCAB}(?!\d)", line)]
+    assert len(vocab_dots) == dots, vocab_dots
+    rematted = [name for name in op_names(hlo)
+                if "rematted_computation" in name]
+    in_head = [name for name in rematted
+               if {"head", "loss"} & scopes_of(name)]
+    assert not in_head, in_head[:5]
+    # the layers' remat is not this test's business, and still there
+    assert bool(rematted) == (program != "evaluation")
 
 
 def stripped(hlo_text):

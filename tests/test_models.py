@@ -168,3 +168,53 @@ class TestChunkedHeadPerChip:
             float(ref_loss)) + 1e-6
         errs = jax.tree.map(_rel_l2, grads, ref_grads)
         assert all(e < 1e-5 for e in jax.tree.leaves(errs)), errs
+
+
+class TestChunkedHeadGradInForward:
+    """The chunked head takes each chunk's gradient in the forward scan (a
+    custom_vjp); loss_chunk=0 is plain autodiff through whole-sequence
+    logits. On mesh=None both must give the same loss and gradients."""
+
+    CASES = {
+        # id: (cfg overrides, masked)
+        "dense": ({}, False),
+        "tied": ({"tie_embeddings": True}, False),
+        "mask": ({}, True),
+        "tied_mask": ({"tie_embeddings": True}, True),
+        "moe_aux": ({"moe_experts": 4, "moe_aux_coeff": 0.01}, False),
+        "unrolled": ({"scan_unroll": 2}, False),
+        "bf16": ({"dtype": "bfloat16"}, False),
+        "bf16_mask": ({"dtype": "bfloat16"}, True),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_matches_unchunked_autodiff(self, case):
+        overrides, masked = self.CASES[case]
+        cfg = TINY.replace(**{"dtype": "float32", "attention_impl": "dense",
+                              "remat": True, "loss_chunk": 16, **overrides})
+        params = Transformer.init(jax.random.PRNGKey(0), cfg)
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(4), (4, 65), 0, cfg.vocab_size)
+        batch = {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+        if masked:
+            batch["mask"] = (jax.random.uniform(
+                jax.random.PRNGKey(5), (4, 64)) < 0.7).astype(jnp.float32)
+
+        def run(c):
+            return jax.jit(jax.value_and_grad(
+                lambda p: Transformer.loss(p, batch, c)))(params)
+
+        ref_loss, ref_grads = run(cfg.replace(loss_chunk=0))
+        loss, grads = run(cfg)
+        # an evaluation (the custom_vjp's primal) is the same number
+        assert float(jax.jit(lambda p: Transformer.loss(p, batch, cfg))(
+            params)) == pytest.approx(float(loss), rel=1e-6)
+        if cfg.dtype == "bfloat16":
+            assert abs(float(loss) - float(ref_loss)) < 2e-2
+            head = "embed" if cfg.tie_embeddings else "lm_head"
+            assert _rel_l2(grads[head], ref_grads[head]) < 1e-2
+            return
+        assert abs(float(loss) - float(ref_loss)) < 1e-5 * abs(
+            float(ref_loss))
+        errs = jax.tree.map(_rel_l2, grads, ref_grads)
+        assert all(e < 1e-5 for e in jax.tree.leaves(errs)), errs
