@@ -179,7 +179,7 @@ def _gpt2_loop(config: Dict[str, Any]) -> None:
     cache = _cache_counter()
     device = _device_dict()
     on_tpu = device["platform"] == "tpu"
-    # the settings bench.py trains this model with: the rolled layer
+    # unrolled, whole-sequence logits: the rolled layer
     # scan keeps 13.8 GB of residuals live at batch 16 (compile-time
     # memory analysis), the unrolled one 9.1 GB
     cfg = getattr(models, config["model"]).replace(

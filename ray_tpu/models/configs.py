@@ -42,8 +42,8 @@ class TransformerConfig:
     remat: bool = False                   # jax.checkpoint each layer
     # "full": recompute the whole layer in bwd (min memory, +1 fwd pass);
     # "dots": save matmul outputs, recompute only elementwise chains
-    # (near-zero recompute FLOPs — fastest when activations fit; pick it
-    # explicitly for small/mid models like the GPT-2 bench config).
+    # (near-zero recompute FLOPs — fastest when activations fit). Any
+    # other value raises.
     remat_policy: str = "full"
     # chunk the lm-head + cross-entropy over the sequence axis so the
     # [B,T,vocab] f32 logits (+grad) never materialize at once; 0 = off.
@@ -65,10 +65,6 @@ class TransformerConfig:
     # renormalise a token's top-k router weights to sum to 1
     # (`norm_topk_prob`; OLMoE publishes false)
     moe_norm_topk: bool = True
-    # expert-parallel (capacity) branch only: slots per expert =
-    # factor x tokens x top_k / experts, tokens beyond it dropped. The
-    # sorted path drops nothing and never reads this.
-    moe_capacity_factor: float = 1.25
     moe_aux_coeff: float = 0.01
 
     @property

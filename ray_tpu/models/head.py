@@ -1,0 +1,195 @@
+"""The vocab head: hidden states to logits, and to the token cross-entropy.
+
+The one module that knows the head's weight and its orientation (a tied
+`[vocab, d]` embedding contracted as `vd`, an untied `[d, vocab]`
+`lm_head`), the logits einsum with its f32 accumulation, the cross-entropy
+(`logsumexp` less the gold logit), and how a training step keeps the
+`[B, T, vocab]` f32 logits out of HBM: T is scanned in `cfg.loss_chunk`
+slices, each chunk's gradient is taken in that same scan while its logits
+exist (a `custom_vjp`), and where the mesh splits only the batch the chunks
+run per chip under `shard_map`.
+
+`logits` is for `Transformer.apply`; everything that trains takes `nll_sum`
+of `weight`. The projection is scope `head`, the cross-entropy and the
+chunking around it `loss` (models/transformer.py's vocabulary).
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+from ray_tpu.models.configs import TransformerConfig
+from ray_tpu.parallel.sharding import (ShardingRules, logical_sharding,
+                                       with_logical_constraint)
+
+
+def weight(params, cfg: TransformerConfig):
+    """The head's parameter as `logits` and `nll_sum` contract it: the
+    embedding itself when tied ("vd": no `[d, vocab]` transpose is
+    materialized each step), else `lm_head` ("dv")."""
+    return params["embed"] if cfg.tie_embeddings else params["lm_head"]
+
+
+def _cast(w, dtype):
+    import jax
+
+    with jax.named_scope("head"):
+        return w.astype(dtype)
+
+
+def _project(head, x, cfg: TransformerConfig, seq_axis, mesh, rules):
+    """x [b, c, d] x head -> f32 logits [b, c, vocab]; `seq_axis` is the
+    logical name of c: "seq" for whole sequences, None for a chunk."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("head"):
+        eq = "bcd,vd->bcv" if cfg.tie_embeddings else "bcd,dv->bcv"
+        logits = jnp.einsum(eq, x, head, preferred_element_type=jnp.float32)
+        return with_logical_constraint(
+            logits, ("batch", seq_axis, "act_vocab"), mesh=mesh, rules=rules)
+
+
+def logits(params, x, cfg: TransformerConfig, *, mesh=None,
+           rules: Optional[ShardingRules] = None):
+    """hidden states [B, T, d] -> f32 logits [B, T, vocab]."""
+    return _project(_cast(weight(params, cfg), x.dtype), x, cfg, "seq",
+                    mesh, rules)
+
+
+def _token_nll_sum(head, x, targets, mask, cfg, seq_axis, mesh, rules):
+    """The (masked) token cross-entropy of x [b, t, d] against targets
+    [b, t], summed: f32 scalar."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = _project(head, x, cfg, seq_axis, mesh, rules)
+    with jax.named_scope("loss"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0]
+        nll = logz - gold  # [b, t] f32
+        return jnp.sum(nll if mask is None else nll * mask)
+
+
+def _chunked_nll_sum(chunk_nll_sum, head, x, targets, mask, chunk, unroll):
+    """Sum of `chunk_nll_sum(head, x_c, t_c, m_c)` over the `chunk`-token
+    slices of these sequences, so only one [b, chunk, vocab] f32 logits
+    block lives in HBM at a time. Each chunk's gradient is taken in that
+    same scan (`nll_sum_fwd`), while its logits exist: nothing is saved
+    for, or computed again in, the backward pass. A custom_vjp: reverse
+    mode only (nothing in the tree takes a forward-mode or a second
+    derivative of the loss)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = x.shape[1] // chunk
+
+    def scan_chunks(step, init, x, targets, mask):
+        """`step(carry, (x_c, t_c, m_c))` over the n chunks; m_c is None
+        for a batch without a mask"""
+        def split(a):  # [b, t, ...] -> [n, b, chunk, ...]
+            return jnp.swapaxes(
+                a.reshape(a.shape[0], n, chunk, *a.shape[2:]), 0, 1)
+        ms = None if mask is None else split(mask)
+        return lax.scan(step, init, (split(x), split(targets), ms),
+                        unroll=unroll)
+
+    @jax.custom_vjp
+    def nll_sum(head, x, targets, mask):
+        def step(total, xtm):
+            return total + chunk_nll_sum(head, *xtm), None
+        return scan_chunks(step, jnp.zeros((), jnp.float32),
+                           x, targets, mask)[0]
+
+    def nll_sum_fwd(head, x, targets, mask):
+        # the sum's incoming cotangent is one scalar, so d head and dx
+        # are complete here but for that factor; d head is carried in
+        # the head's dtype, as autodiff's backward scan carried it
+        def step(carry, xtm):
+            total, d_head = carry
+            nll, (dh_c, dx_c) = jax.value_and_grad(
+                chunk_nll_sum, argnums=(0, 1))(head, *xtm)
+            return (total + nll, d_head + dh_c), dx_c
+        (total, d_head), dxs = scan_chunks(
+            step, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
+            x, targets, mask)
+        return total, (d_head, jnp.swapaxes(dxs, 0, 1).reshape(x.shape))
+
+    def nll_sum_bwd(res, g):
+        with jax.named_scope("loss"):
+            d_head, dx = ((g * r).astype(r.dtype) for r in res)
+        return d_head, dx, None, None
+
+    nll_sum.defvjp(nll_sum_fwd, nll_sum_bwd)
+
+    # the chunking itself (slicing the hidden states, stacking their
+    # gradients, the running sums) is "loss"; the projection inside
+    # chunk_nll_sum names itself "head"
+    with jax.named_scope("loss"):
+        return nll_sum(head, x, targets, mask)
+
+
+def nll_sum(w, x, targets, cfg: TransformerConfig, *, mask=None, mesh=None,
+            rules: Optional[ShardingRules] = None):
+    """Sum over the tokens of hidden states x [B, T, d] of the (masked)
+    negative log-likelihood of targets [B, T] under the head `w`
+    (`weight`): f32 scalar. Chunked over T where `cfg.loss_chunk` divides
+    a longer T, else plain autodiff through whole-sequence logits."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    rules = rules or ShardingRules()
+    head = _cast(w, x.dtype)
+    if mask is not None:
+        mask = mask.astype(jnp.float32)
+    b, t = targets.shape
+    chunk = cfg.loss_chunk
+    if not (chunk and t > chunk and t % chunk == 0):
+        return _token_nll_sum(head, x, targets, mask, cfg, "seq", mesh,
+                              rules)
+
+    # GSPMD cannot carry an unreduced sum through a loop: it reduces the
+    # whole [vocab, d] dW, and gathers the head, once per chunk. So where
+    # the mesh splits only the batch, the chunks run per chip (shard_map).
+    per_chip = False
+    if mesh is not None:
+        batch_axes, = logical_sharding(("batch",), mesh, rules, (b,)).spec
+        head_spec = logical_sharding(
+            ("vocab", "embed") if cfg.tie_embeddings else ("embed", "vocab"),
+            mesh, rules, head.shape).spec
+        per_chip = batch_axes is not None and all(
+            size == 1 for a, size in mesh.shape.items()
+            if a not in batch_axes)
+    # inside the map the chip owns its layout: no GSPMD constraint
+    c_mesh = None if per_chip else mesh
+
+    def chunk_nll_sum(head, x_c, t_c, m_c):
+        return _token_nll_sum(head, x_c, t_c, m_c, cfg, None, c_mesh, rules)
+
+    def local_nll_sum(head, x, targets, mask=None):
+        return _chunked_nll_sum(chunk_nll_sum, head, x, targets, mask,
+                                chunk, cfg.scan_unroll > 1)
+
+    args = (x, targets) if mask is None else (x, targets, mask)
+    if not per_chip:
+        return local_nll_sum(head, *args)
+
+    def per_chip_nll_sum(head, *local):
+        with jax.named_scope("head"):
+            for dim, axes in enumerate(head_spec):
+                if axes is not None:
+                    head = lax.all_gather(head, axes, axis=dim, tiled=True)
+        total = local_nll_sum(head, *local)
+        with jax.named_scope("loss"):
+            return lax.psum(total, batch_axes)
+
+    from jax.sharding import PartitionSpec as P
+    # check_vma=False as in Transformer._make_attention: the checker types
+    # the gathered head as varying and puts a psum of dW in every chunk
+    return jax.shard_map(
+        per_chip_nll_sum, mesh=mesh,
+        in_specs=(head_spec,) + (P(batch_axes),) * len(args),
+        out_specs=P(), check_vma=False)(head, *args)

@@ -16,9 +16,11 @@ operator's `ray_tpu profile --device` read them off each op's op_name):
 `attn_norm`, `qkv` (projections, QK-norm and RoPE), `attention` (kernels,
 GQA repeat, layout transposes), `attn_out`, `mlp_norm`, `mlp/gate_up`,
 `mlp/down` (on the MoE branch `moe/router`, `moe/dispatch`, `moe/experts`,
-`moe/combine`: ops/moe.py), `final_norm`, `head`, `loss`; the train step
-adds `optimizer` (parallel/train_step.py). Scopes are metadata only. Forward, backward and recomputation need none: JAX wraps the path
-in `jvp(...)`, `transpose(jvp(...))` and remat's `rematted_computation`.
+`moe/combine`: ops/moe.py), `final_norm`, `head`, `loss` (the vocab head
+and the cross-entropy: models/head.py); the train step adds `optimizer`
+(parallel/train_step.py). Scopes are metadata only. Forward, backward
+and recomputation need none: JAX wraps the path in `jvp(...)`,
+`transpose(jvp(...))` and remat's `rematted_computation`.
 
 Reference parity note: the reference has no in-tree LM (SURVEY.md §2.3,
 §5.7); its model math arrives via user torch code over NCCL groups. This
@@ -30,10 +32,10 @@ from __future__ import annotations
 import functools
 from typing import Any, Dict, Optional
 
+from ray_tpu.models import head
 from ray_tpu.models.configs import TransformerConfig
 from ray_tpu.parallel.mesh import AXIS_SEQ
-from ray_tpu.parallel.sharding import (ShardingRules, logical_sharding,
-                                       with_logical_constraint)
+from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
 
 
 def _rope_tables(positions, head_dim, theta):
@@ -180,6 +182,69 @@ class Transformer:
 
     # ---- forward ----------------------------------------------------
     @staticmethod
+    def embed(params, tokens, cfg: TransformerConfig, *,
+              mesh=None, rules: Optional[ShardingRules] = None):
+        """tokens [B, T] int32 -> embeddings [B, T, d] (compute dtype)."""
+        import jax
+        import jax.numpy as jnp
+
+        constrain = functools.partial(
+            with_logical_constraint, mesh=mesh, rules=rules)
+
+        # Constrain the lookup operand's embed dim to the ACTIVATION
+        # sharding (replicated / tensor) rather than the param's fsdp
+        # sharding: with the table's feature dim matching the output
+        # layout, the gather partitions on the (batch/seq-sharded) index
+        # dims directly. Leaving it fsdp-sharded makes SPMD emit a
+        # d-sharded gather then an "involuntary full rematerialization"
+        # to reshard d->batch/seq. This is the FSDP gather-at-use
+        # pattern: fwd all-gathers the table's d shards, bwd
+        # reduce-scatters the grad.
+        with jax.named_scope("embed"):
+            emb = constrain(params["embed"], ("vocab", "act_embed"))
+            x = jnp.take(emb, tokens, axis=0).astype(jnp.dtype(cfg.dtype))
+            return constrain(x, ("batch", "seq", "act_embed"))
+
+    @staticmethod
+    def _remat(layer, cfg: TransformerConfig):
+        """`layer` under cfg's rematerialization: the one place a layer is
+        wrapped in `jax.checkpoint`."""
+        import jax
+
+        if cfg.remat_policy not in ("full", "dots"):
+            raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
+        if not cfg.remat:
+            return layer
+        if cfg.remat_policy == "full":
+            return jax.checkpoint(layer)
+        policies = jax.checkpoint_policies
+        return jax.checkpoint(layer, policy=policies.save_from_both_policies(
+            policies.checkpoint_dots,
+            policies.save_only_these_names("attn_out")))
+
+    @staticmethod
+    def _stack(layers, x, cfg: TransformerConfig, *, mesh,
+               rules: ShardingRules, positions=None):
+        """x [B, T, d] through a run of stacked layers (leaves
+        [n, ...]: all of them in hidden(), one stage's in pipeline_loss())
+        -> (x, routing), `routing` the layers' stacked MoE records (None
+        for dense FFN configs)."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        if positions is None:
+            positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+        with jax.named_scope("qkv"):
+            cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+        layer = Transformer._remat(
+            Transformer._make_layer_fn(cfg, mesh, rules, cos, sin), cfg)
+        # the scan's own work (stacking and slicing saved activations,
+        # carries) is "layers"; each block inside names itself
+        with jax.named_scope("layers"):
+            return lax.scan(layer, x, layers, unroll=cfg.scan_unroll)
+
+    @staticmethod
     def hidden(params, tokens, cfg: TransformerConfig, *,
                mesh=None, rules: Optional[ShardingRules] = None,
                positions=None, with_aux: bool = False):
@@ -199,50 +264,12 @@ class Transformer:
         """
         import jax
         import jax.numpy as jnp
-        from jax import lax
 
         rules = rules or ShardingRules()
-        cdt = jnp.dtype(cfg.dtype)
-        b, t = tokens.shape
-        if positions is None:
-            positions = jnp.arange(t, dtype=jnp.int32)[None, :]
-
-        constrain = functools.partial(
-            with_logical_constraint, mesh=mesh, rules=rules)
-
-        # Constrain the lookup operand's embed dim to the ACTIVATION
-        # sharding (replicated / tensor) rather than the param's fsdp
-        # sharding: with the table's feature dim matching the output
-        # layout, the gather partitions on the (batch/seq-sharded) index
-        # dims directly. Leaving it fsdp-sharded makes SPMD emit a
-        # d-sharded gather then an "involuntary full rematerialization"
-        # to reshard d->batch/seq. This is the FSDP gather-at-use
-        # pattern: fwd all-gathers the table's d shards, bwd
-        # reduce-scatters the grad.
-        with jax.named_scope("embed"):
-            emb = constrain(params["embed"], ("vocab", "act_embed"))
-            x = jnp.take(emb, tokens, axis=0).astype(cdt)
-            x = constrain(x, ("batch", "seq", "act_embed"))
-
-        with jax.named_scope("qkv"):
-            cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-        layer = Transformer._make_layer_fn(cfg, mesh, rules, cos, sin)
-
-        if cfg.remat:
-            if cfg.remat_policy == "dots":
-                pol = jax.checkpoint_policies.save_from_both_policies(
-                    jax.checkpoint_policies.checkpoint_dots,
-                    jax.checkpoint_policies.save_only_these_names(
-                        "attn_out"))
-                layer = jax.checkpoint(layer, policy=pol)
-            else:
-                layer = jax.checkpoint(layer)
-
-        # the scan's own work (stacking and slicing saved activations,
-        # carries) is "layers"; each block inside names itself
-        with jax.named_scope("layers"):
-            x, routing = lax.scan(layer, x, params["layers"],
-                                  unroll=cfg.scan_unroll)
+        x = Transformer.embed(params, tokens, cfg, mesh=mesh, rules=rules)
+        x, routing = Transformer._stack(
+            params["layers"], x, cfg, mesh=mesh, rules=rules,
+            positions=positions)
         aux_total = jnp.zeros((), jnp.float32)
         if cfg.moe_experts:
             # not a sum of per-layer terms: the published loss takes its
@@ -262,10 +289,9 @@ class Transformer:
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
                        rules: ShardingRules, cos, sin):
-        """Build layer(x, lp) -> (x, routing) — the per-layer body shared
-        by hidden()'s scan and the pipeline stage executor
-        (parallel/pipeline.py make_pipeline_fn). `routing` is the MoE
-        layer's record (ops/moe.py `moe_ffn`), None on a dense layer."""
+        """Build layer(x, lp) -> (x, routing), the body `_stack` scans.
+        `routing` is the MoE layer's record (ops/moe.py `moe_ffn`), None
+        on a dense layer."""
         import jax
         import jax.numpy as jnp
 
@@ -331,9 +357,7 @@ class Transformer:
                 y, routing = moe_ffn(
                     experts, h.reshape(-1, h.shape[-1]),
                     num_selected=cfg.moe_top_k,
-                    norm_topk=cfg.moe_norm_topk,
-                    capacity_factor=cfg.moe_capacity_factor,
-                    mesh=mesh, rules=rules)
+                    norm_topk=cfg.moe_norm_topk, mesh=mesh, rules=rules)
                 with jax.named_scope("moe/combine"):
                     down = y.reshape(h.shape).astype(cdt)
                     x = x + constrain(down, ("batch", "seq", "act_embed"))
@@ -352,24 +376,6 @@ class Transformer:
         return layer
 
     @staticmethod
-    def _head_logits(params, x, cfg: TransformerConfig, *,
-                     mesh=None, rules: Optional[ShardingRules] = None):
-        """hidden states [B, T, d] -> f32 logits [B, T, vocab] — the one
-        lm-head projection shared by apply() and loss()."""
-        import jax.numpy as jnp
-
-        import jax
-
-        with jax.named_scope("head"):
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = jnp.einsum("btd,dv->btv", x, head.astype(x.dtype),
-                                preferred_element_type=jnp.float32)
-            return with_logical_constraint(
-                logits, ("batch", "seq", "act_vocab"), mesh=mesh,
-                rules=rules)
-
-    @staticmethod
     def apply(params, tokens, cfg: TransformerConfig, *,
               mesh=None, rules: Optional[ShardingRules] = None,
               positions=None):
@@ -377,8 +383,7 @@ class Transformer:
         rules = rules or ShardingRules()
         x = Transformer.hidden(params, tokens, cfg, mesh=mesh, rules=rules,
                                positions=positions)
-        return Transformer._head_logits(params, x, cfg, mesh=mesh,
-                                        rules=rules)
+        return head.logits(params, x, cfg, mesh=mesh, rules=rules)
 
     @staticmethod
     def pipeline_loss(params, batch, cfg: TransformerConfig, *,
@@ -390,23 +395,18 @@ class Transformer:
         execution of the same stacked layer params hidden() scans.
 
         Embedding runs outside the pipeline (vocab/fsdp-sharded GSPMD);
-        each stage applies n_layers/n_stages layers; the last stage's
-        loss_fn does final-norm + lm-head + CE per microbatch. Requires
+        each stage applies n_layers/n_stages layers (`_stack`, the scan
+        hidden() runs); the last stage's loss_fn does final-norm + the
+        head's `nll_sum` per microbatch. Requires
         batch divisible by n_micro, n_layers divisible by n_stages, and a
         stage-local attention impl (dense/flash — seq stays unsharded
         inside a stage)."""
         import jax
-        import jax.numpy as jnp
-        from jax import lax
 
         from ray_tpu.parallel.pipeline import make_pipeline_fn
 
         rules = rules or ShardingRules()
-        cdt = jnp.dtype(cfg.dtype)
-        if "targets" in batch:
-            tokens, targets = batch["tokens"], batch["targets"]
-        else:
-            tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+        tokens, targets = Transformer._tokens_and_targets(batch)
         b, t = tokens.shape
         if b % n_micro or cfg.n_layers % n_stages:
             raise ValueError(
@@ -424,57 +424,24 @@ class Transformer:
         mb = b // n_micro
 
         # Embed outside the pipeline, then split into microbatches.
-        with jax.named_scope("embed"):
-            emb = with_logical_constraint(
-                params["embed"], ("vocab", "act_embed"), mesh=mesh,
-                rules=rules)
-            x = jnp.take(emb, tokens, axis=0).astype(cdt)   # [B, T, d]
+        x = Transformer.embed(params, tokens, cfg, mesh=mesh, rules=rules)
         x_micro = x.reshape(n_micro, mb, t, x.shape[-1])
         y_micro = targets.reshape(n_micro, mb, t)
 
-        per_stage = cfg.n_layers // n_stages
-
         def stage_fn(stage_params, x):
-            # rope tables rebuilt from static positions inside the stage:
-            # shard-local constants, not closure-captured traced arrays
-            # (shard_map rejects auto-sharded implicit captures)
-            positions = jnp.arange(t, dtype=jnp.int32)[None, :]
-            with jax.named_scope("qkv"):
-                cos, sin = _rope_tables(positions, cfg.head_dim,
-                                        cfg.rope_theta)
+            # no positions passed: the rope tables are rebuilt from static
+            # positions inside the stage, shard-local constants and not
+            # closure-captured traced arrays (shard_map rejects
+            # auto-sharded implicit captures).
             # mesh=None inside the stage: the pipeline shard_map already
             # owns axis mapping; constraints no-op under manual meshes.
-            layer = Transformer._make_layer_fn(cfg, None, rules, cos, sin)
-            if cfg.remat:
-                # same per-layer rematerialization contract as hidden()
-                if cfg.remat_policy == "dots":
-                    pol = jax.checkpoint_policies.save_from_both_policies(
-                        jax.checkpoint_policies.checkpoint_dots,
-                        jax.checkpoint_policies.save_only_these_names(
-                            "attn_out"))
-                    layer = jax.checkpoint(layer, policy=pol)
-                else:
-                    layer = jax.checkpoint(layer)
-
-            def body(x, lp):
-                x, _routing = layer(x, lp)
-                return x, None
-            with jax.named_scope("layers"):
-                x, _ = lax.scan(body, x, stage_params)
-            return x
+            return Transformer._stack(stage_params, x, cfg, mesh=None,
+                                      rules=rules)[0]
 
         def mb_loss(out, y, extras):
             with jax.named_scope("final_norm"):
                 h = _rmsnorm(out, extras["final_norm"], cfg.norm_eps)
-            with jax.named_scope("head"):
-                logits = jnp.einsum("btd,dv->btv", h,
-                                    extras["head"].astype(h.dtype),
-                                    preferred_element_type=jnp.float32)
-            with jax.named_scope("loss"):
-                logz = jax.nn.logsumexp(logits, axis=-1)
-                gold = jnp.take_along_axis(
-                    logits, y[..., None], axis=-1)[..., 0]
-                return jnp.mean(logz - gold)
+            return head.nll_sum(extras["head"], h, y, cfg) / y.size
 
         run = make_pipeline_fn(stage_fn, n_stages, n_micro, mesh,
                                loss_fn=mb_loss)
@@ -482,13 +449,10 @@ class Transformer:
         # leading stage dim aligns with the "pipe" shards of the "layers"
         # axis, so this reshape is shard-local.
         staged = jax.tree.map(
-            lambda a: a.reshape((n_stages, per_stage) + a.shape[1:]),
+            lambda a: a.reshape((n_stages, -1) + a.shape[1:]),
             params["layers"])
-        extras = {
-            "final_norm": params["final_norm"],
-            "head": (params["embed"].T if cfg.tie_embeddings
-                     else params["lm_head"]),
-        }
+        extras = {"final_norm": params["final_norm"],
+                  "head": head.weight(params, cfg)}
         return run(staged, x_micro, y_micro, extras)
 
     @staticmethod
@@ -594,12 +558,24 @@ class Transformer:
 
     # ---- loss -------------------------------------------------------
     @staticmethod
+    def _tokens_and_targets(batch):
+        """batch = {"tokens": [B,T], "targets": [B,T]}, or {"tokens":
+        [B,T+1]} to be shifted by one -> (tokens, targets)."""
+        import jax
+
+        if "targets" in batch:
+            return batch["tokens"], batch["targets"]
+        with jax.named_scope("loss"):
+            return batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+
+    @staticmethod
     def loss(params, batch, cfg: TransformerConfig, *,
              mesh=None, rules: Optional[ShardingRules] = None,
              with_metrics: bool = False):
         """Next-token cross-entropy. batch = {"tokens": [B,T+1] or
-        ("tokens","targets") pair}; returns scalar mean loss (f32), for a
-        MoE config plus `moe_aux_coeff` x the load-balancing loss.
+        ("tokens","targets") pair}, optionally a "mask" [B,T]; returns
+        scalar mean loss (f32), for a MoE config plus `moe_aux_coeff` x the
+        load-balancing loss.
 
         with_metrics=True returns (loss, metrics), the pair
         `make_train_step` takes: its step's metrics then carry, from the
@@ -608,159 +584,16 @@ class Transformer:
         and `moe_aux_loss`; an empty dict for a dense config."""
         import jax
         import jax.numpy as jnp
-        from jax import lax
 
-        if "targets" in batch:
-            tokens, targets = batch["tokens"], batch["targets"]
-        else:
-            with jax.named_scope("loss"):
-                tokens = batch["tokens"][:, :-1]
-                targets = batch["tokens"][:, 1:]
-
-        mask = batch.get("mask")
-        b, t = tokens.shape
-        chunk = cfg.loss_chunk
-        if not (chunk and t > chunk and t % chunk == 0):
-            rules = rules or ShardingRules()
-            x, aux, routing = Transformer.hidden(
-                params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
-            logits = Transformer._head_logits(params, x, cfg, mesh=mesh,
-                                              rules=rules)
-            with jax.named_scope("loss"):
-                logits = logits.astype(jnp.float32)
-                logz = jax.nn.logsumexp(logits, axis=-1)
-                gold = jnp.take_along_axis(
-                    logits, targets[..., None], axis=-1)[..., 0]
-                nll = logz - gold
-                aux_term = cfg.moe_aux_coeff * aux if cfg.moe_experts \
-                    else 0.0
-                if mask is not None:
-                    loss_val = jnp.sum(nll * mask) / jnp.maximum(
-                        jnp.sum(mask), 1.0) + aux_term
-                else:
-                    loss_val = jnp.mean(nll) + aux_term
-            return Transformer._loss_out(loss_val, aux, routing, cfg,
-                                         with_metrics)
-
-        # Chunked head + cross-entropy: scan T in loss_chunk slices so only
-        # one [B, chunk, vocab] f32 logits block lives in HBM at a time. Each
-        # chunk's gradient is taken in that same scan (`nll_sum_fwd`), while
-        # its logits exist: nothing is saved for, or computed again in, the
-        # backward pass.
         rules = rules or ShardingRules()
+        tokens, targets = Transformer._tokens_and_targets(batch)
+        mask = batch.get("mask")
         x, aux, routing = Transformer.hidden(
             params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
-        cdt = x.dtype
-        # contract against embed directly ("vd" orientation) rather than
-        # materializing a [d, vocab] transpose each step
-        tied = cfg.tie_embeddings
-        with jax.named_scope("head"):
-            head = (params["embed"] if tied
-                    else params["lm_head"]).astype(cdt)
-        eq = "bcd,vd->bcv" if tied else "bcd,dv->bcv"
-        n = t // chunk
-
-        # GSPMD cannot carry an unreduced sum through a loop: it reduces the
-        # whole [vocab, d] dW, and gathers the head, once per chunk. So where
-        # the mesh splits only the batch, the chunks run per chip (shard_map).
-        per_chip = False
-        if mesh is not None:
-            batch_axes, = logical_sharding(("batch",), mesh, rules, (b,)).spec
-            head_spec = logical_sharding(
-                ("vocab", "embed") if tied else ("embed", "vocab"), mesh,
-                rules, head.shape).spec
-            per_chip = batch_axes is not None and all(
-                size == 1 for a, size in mesh.shape.items()
-                if a not in batch_axes)
-        # inside the map the chip owns its layout: no GSPMD constraint
-        c_mesh = None if per_chip else mesh
-
-        def chunk_nll_sum(head, x_c, t_c, m_c):
-            """the (masked) token nll of one chunk, summed: f32 scalar"""
-            with jax.named_scope("head"):
-                logits = jnp.einsum(eq, x_c, head,
-                                    preferred_element_type=jnp.float32)
-                logits = with_logical_constraint(
-                    logits, ("batch", None, "act_vocab"), mesh=c_mesh,
-                    rules=rules)
-            with jax.named_scope("loss"):
-                logz = jax.nn.logsumexp(logits, axis=-1)
-                gold = jnp.take_along_axis(
-                    logits, t_c[..., None], axis=-1)[..., 0]
-                nll = logz - gold  # [b, chunk] f32
-                return jnp.sum(nll if m_c is None else nll * m_c)
-
-        def scan_chunks(step, init, x, targets, mask):
-            """`step(carry, (x_c, t_c, m_c))` over the n chunks of these
-            sequences; m_c is None for a batch without a mask"""
-            def split(a):  # [b, t, ...] -> [n, b, chunk, ...]
-                return jnp.swapaxes(
-                    a.reshape(a.shape[0], n, chunk, *a.shape[2:]), 0, 1)
-            ms = None if mask is None else split(mask).astype(jnp.float32)
-            return lax.scan(step, init, (split(x), split(targets), ms),
-                            unroll=cfg.scan_unroll > 1)
-
-        @jax.custom_vjp
-        def nll_sum(head, x, targets, mask):
-            def step(total, xtm):
-                return total + chunk_nll_sum(head, *xtm), None
-            return scan_chunks(step, jnp.zeros((), jnp.float32),
-                               x, targets, mask)[0]
-
-        def nll_sum_fwd(head, x, targets, mask):
-            # the sum's incoming cotangent is one scalar, so d head and dx
-            # are complete here but for that factor; d head is carried in
-            # the head's dtype, as autodiff's backward scan carried it
-            def step(carry, xtm):
-                total, d_head = carry
-                nll, (dh_c, dx_c) = jax.value_and_grad(
-                    chunk_nll_sum, argnums=(0, 1))(head, *xtm)
-                return (total + nll, d_head + dh_c), dx_c
-            (total, d_head), dxs = scan_chunks(
-                step, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
-                x, targets, mask)
-            return total, (d_head, jnp.swapaxes(dxs, 0, 1).reshape(x.shape))
-
-        def nll_sum_bwd(res, g):
-            with jax.named_scope("loss"):
-                d_head, dx = ((g * r).astype(r.dtype) for r in res)
-            return d_head, dx, None, None
-
-        nll_sum.defvjp(nll_sum_fwd, nll_sum_bwd)
-
-        # the chunking itself (slicing the hidden states, stacking their
-        # gradients, the running sums) is "loss"; the projection inside
-        # chunk_nll_sum names itself "head"
-        @jax.named_scope("loss")
-        def chunked_nll_sum(head, x, targets, mask=None):
-            """sum over these sequences of the (masked) token nll. A
-            custom_vjp: reverse mode only (nothing in the tree takes a
-            forward-mode or a second derivative of the loss)."""
-            return nll_sum(head, x, targets, mask)
-
-        def per_chip_nll_sum(head, *local):
-            with jax.named_scope("head"):
-                for dim, axes in enumerate(head_spec):
-                    if axes is not None:
-                        head = lax.all_gather(head, axes, axis=dim,
-                                              tiled=True)
-            total = chunked_nll_sum(head, *local)
-            with jax.named_scope("loss"):
-                return lax.psum(total, batch_axes)
-
-        args = (x, targets) if mask is None else (x, targets, mask)
-        if per_chip:
-            from jax.sharding import PartitionSpec as P
-            # check_vma=False as in _make_attention: the checker types the
-            # gathered head as varying and puts a psum of dW in every chunk
-            total = jax.shard_map(
-                per_chip_nll_sum, mesh=mesh,
-                in_specs=(head_spec,) + (P(batch_axes),) * len(args),
-                out_specs=P(), check_vma=False)(head, *args)
-        else:
-            total = chunked_nll_sum(head, *args)
+        total = head.nll_sum(head.weight(params, cfg), x, targets, cfg,
+                             mask=mask, mesh=mesh, rules=rules)
         with jax.named_scope("loss"):
-            loss_val = total / (b * t if mask is None
+            loss_val = total / (targets.size if mask is None
                                 else jnp.maximum(jnp.sum(mask), 1.0))
         if cfg.moe_experts:
             loss_val = loss_val + cfg.moe_aux_coeff * aux

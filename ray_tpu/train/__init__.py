@@ -12,24 +12,13 @@ from ray_tpu.train.data_parallel_trainer import (DataParallelTrainer,  # noqa: F
                                                  Result)
 from ray_tpu.train.jax_backend import JaxConfig  # noqa: F401
 from ray_tpu.train.jax_trainer import JaxTrainer  # noqa: F401
-from ray_tpu.train.tensorflow_backend import TensorflowConfig  # noqa: F401
-from ray_tpu.train.tensorflow_trainer import TensorflowTrainer  # noqa: F401
-from ray_tpu.train.accelerate_trainer import AccelerateTrainer  # noqa: F401
-from ray_tpu.train.sklearn_trainer import SklearnTrainer  # noqa: F401
-from ray_tpu.train.torch_trainer import TorchTrainer  # noqa: F401
-from ray_tpu.train.transformers_trainer import (TransformersTrainer,  # noqa: F401,E501
-                                                prepare_trainer)
-from ray_tpu.train.torch_backend import TorchConfig  # noqa: F401
 from ray_tpu.train.session import (TrainContext, get_checkpoint,  # noqa: F401
                                    get_context, get_dataset_shard, report)
 
 __all__ = [
     "Checkpoint", "CheckpointConfig", "FailureConfig", "RunConfig",
     "ScalingConfig", "DataParallelTrainer", "Result", "JaxConfig",
-    "JaxTrainer", "TorchTrainer", "TorchConfig", "TensorflowTrainer",
-    "TransformersTrainer", "prepare_trainer", "SklearnTrainer",
-    "AccelerateTrainer",
-    "TensorflowConfig", "TrainContext", "report", "get_checkpoint",
+    "JaxTrainer", "TrainContext", "report", "get_checkpoint",
     "get_context", "get_dataset_shard",
 ]
 

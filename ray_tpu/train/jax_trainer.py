@@ -1,7 +1,7 @@
 """JaxTrainer: the flagship trainer (BASELINE.json north star).
 
-reference parity: slots into the trainer inventory exactly where
-TorchTrainer does (python/ray/train/torch/torch_trainer.py over
+reference parity: slots into the trainer inventory exactly where the
+reference's torch trainer does (python/ray/train/torch/torch_trainer.py over
 DataParallelTrainer, SURVEY.md §8.4) — a DataParallelTrainer subclass
 whose backend wires jax.distributed over the gang instead of NCCL.
 

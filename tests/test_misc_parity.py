@@ -254,43 +254,6 @@ print("REAP_OK")
     assert "REAP_OK" in out.stdout
 
 
-@pytest.mark.slow  # wall-time budget (ISSUE 8): torch.distributed gloo init costs ~19s; torch-parity only
-def test_torch_trainer_gloo_allreduce(ray_start):
-    """TorchTrainer parity row (§8.4): gloo process group over the gang,
-    DDP-style gradient averaging on CPU torch."""
-    from ray_tpu.train import ScalingConfig, TorchTrainer, report
-
-    def loop():
-        import torch
-        import torch.distributed as dist
-        rank = dist.get_rank()
-        world = dist.get_world_size()
-        t = torch.ones(2) * (rank + 1)
-        dist.all_reduce(t)  # 1+2 = 3 per element
-        model = torch.nn.Linear(4, 1)
-        # identical init across ranks (broadcast rank 0's params)
-        for p in model.parameters():
-            dist.broadcast(p.data, src=0)
-        x = torch.randn(8, 4, generator=torch.Generator().manual_seed(rank))
-        loss = model(x).pow(2).mean()
-        loss.backward()
-        for p in model.parameters():  # DDP-style grad averaging
-            dist.all_reduce(p.grad)
-            p.grad /= world
-        g0 = float(next(model.parameters()).grad.abs().sum())
-        report({"allreduce0": float(t[0]), "world": world, "gsum": g0})
-
-    trainer = TorchTrainer(
-        loop,
-        scaling_config=ScalingConfig(num_workers=2,
-                                     resources_per_worker={"CPU": 0.5}))
-    result = trainer.fit()
-    assert result.error is None, result.error
-    assert result.metrics["world"] == 2
-    assert result.metrics["allreduce0"] == 3.0
-    assert result.metrics["gsum"] > 0.0
-
-
 def test_arg_prefetch_across_nodes():
     """The dispatching node pulls a task's remote args into its local
     store before execution (reference DependencyManager/PullManager)."""

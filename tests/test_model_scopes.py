@@ -4,6 +4,7 @@ and the names are metadata only — the compiled program is the same
 without them."""
 
 import contextlib
+import inspect
 import re
 
 import jax
@@ -81,6 +82,27 @@ def test_every_scope_reaches_the_lowered_op_names(program, variant, chunk):
     # forward, backward and recomputation read off JAX's own wrappers
     assert "transpose(jvp(layers))" in hlo
     assert "rematted_computation" in hlo
+
+
+@pytest.mark.parametrize("chunk", CHUNKS)
+def test_pipeline_loss_carries_the_scan_vocabulary(chunk):
+    """`pipeline_loss` runs the stack, the head and the cross-entropy that
+    `loss` runs, so its lowered module names the same blocks; a private
+    forward in the pipeline would have to repeat every scope to pass, and
+    its source may not."""
+    cfg = VARIANTS["dense"].replace(loss_chunk=CHUNKS[chunk])
+    mesh = make_mesh(MeshConfig(data=-1, pipe=2))
+    params = jax.eval_shape(lambda: Transformer.init(jax.random.key(0), cfg))
+    hlo = jax.jit(jax.grad(lambda p, b: Transformer.pipeline_loss(
+        p, b, cfg, mesh=mesh, n_stages=2, n_micro=2))).lower(
+            params, batch_for(cfg, 4)).as_text(debug_info=True)
+    found = scopes_in(hlo)
+    want = BLOCKS | FFN["dense"]
+    assert want <= found, sorted(want - found)
+    assert "rematted_computation" in hlo
+    own = inspect.getsource(Transformer.pipeline_loss)
+    assert not re.search(
+        r'checkpoint|named_scope\("(embed|layers|qkv|head|loss)"', own)
 
 
 def lower_eval(cfg):

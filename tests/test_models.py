@@ -78,6 +78,34 @@ class TestTrainStep:
         assert losses[-1] < losses[0] * 0.7, losses
         assert int(jax.device_get(state["step"])) == 10
 
+    def test_remat_dots_matches_full(self, tiny_params):
+        """remat_policy="dots" saves what "full" recomputes: the same loss
+        and every gradient, here and on the 3D mesh."""
+        cfg = TINY.replace(dtype="float32", remat=True)
+        mesh = make_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
+        batch = {"tokens": jax.random.randint(
+            jax.random.PRNGKey(3), (4, 33), 0, cfg.vocab_size)}
+
+        def run(policy, mesh):
+            c = cfg.replace(remat_policy=policy)
+            return jax.jit(jax.value_and_grad(
+                lambda p: Transformer.loss(p, batch, c, mesh=mesh)))(
+                    tiny_params)
+
+        ref_loss, ref_grads = run("full", None)
+        for m in (None, mesh):
+            loss, grads = run("dots", m)
+            assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+            errs = jax.tree.map(_rel_l2, grads, ref_grads)
+            assert all(e < 1e-5 for e in jax.tree.leaves(errs)), errs
+
+    @pytest.mark.parametrize("remat", [True, False])
+    def test_unknown_remat_policy_raises(self, tiny_params, remat):
+        cfg = TINY.replace(remat=remat, remat_policy="dot")
+        batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+        with pytest.raises(ValueError, match="unknown remat_policy 'dot'"):
+            Transformer.loss(tiny_params, batch, cfg)
+
     def test_param_shardings_applied(self, tiny_params):
         import optax
         cfg = TINY.replace(dtype="float32")

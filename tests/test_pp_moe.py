@@ -240,20 +240,27 @@ class TestFlagshipIntegration:
         spec = up.sharding.spec
         assert "expert" in str(spec), spec
 
-    def test_transformer_pipeline_loss_matches_scan(self):
+    @pytest.mark.parametrize("loss_chunk", [0, 16])
+    def test_transformer_pipeline_loss_matches_scan(self, loss_chunk):
+        """The stages run the stack `hidden` scans and the last one the
+        head `loss` takes, chunked where the config says so."""
         from ray_tpu.models import TINY, Transformer
 
         cfg = TINY.replace(dtype="float32", attention_impl="dense",
-                           loss_chunk=0)
+                           loss_chunk=loss_chunk)
         mesh = make_mesh(MeshConfig(data=4, pipe=2))
         params = Transformer.init(jax.random.PRNGKey(0), cfg)
         tokens = jax.random.randint(
             jax.random.PRNGKey(1), (8, 33), 0, cfg.vocab_size)
-        ref = float(Transformer.loss(params, {"tokens": tokens}, cfg))
-        pl = float(Transformer.pipeline_loss(
-            params, {"tokens": tokens}, cfg, mesh=mesh,
-            n_stages=2, n_micro=4))
-        assert abs(ref - pl) < 1e-4, (ref, pl)
+        ref, ref_grads = jax.value_and_grad(
+            lambda p: Transformer.loss(p, {"tokens": tokens}, cfg))(params)
+        pl, grads = jax.jit(jax.value_and_grad(
+            lambda p: Transformer.pipeline_loss(
+                p, {"tokens": tokens}, cfg, mesh=mesh,
+                n_stages=2, n_micro=4)))(params)
+        assert abs(float(ref) - float(pl)) < 1e-4, (ref, pl)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+            np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-5)
 
     def test_transformer_pipeline_trains(self):
         import optax

@@ -1,8 +1,7 @@
-"""Ablation harness for the headline bench: times train-step variants
-to localize non-matmul overhead. Not part of the driver flow — dev tool.
+"""Ablation harness for the task path. Not part of the driver flow — dev
+tool; run it from the repo root.
 
-Usage: python tools/bench_ablate.py [name ...]
-       python tools/bench_ablate.py --suite lease [--n 1500]
+Usage: python tools/bench_ablate.py --suite lease [--n 1500]
            [--merge BENCH_CORE_r06.json]
 
 `--suite lease` ablates the task-path lease transport (ROADMAP item
@@ -17,106 +16,6 @@ from __future__ import annotations
 
 import os
 import sys
-import time
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-
-BATCH = 16
-WARMUP = 3
-STEPS = 10
-FLOPS_PER_TOKEN = 968e6
-
-
-def run_variant(name: str, *, n_heads=6, loss_chunk=0, batch=BATCH,
-                no_head=False, attention_impl="auto", scan_unroll=12,
-                remat=False, sgd=False, no_attn=False):
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from ray_tpu.models import GPT2_125M, Transformer
-    from ray_tpu.parallel import MeshConfig, make_mesh
-    from ray_tpu.parallel.train_step import make_train_step
-
-    devices = jax.devices()
-    mesh = make_mesh(MeshConfig(data=-1), devices=devices)
-    cfg = GPT2_125M.replace(
-        n_heads=n_heads, remat=remat, remat_policy="dots",
-        attention_impl=attention_impl, scan_unroll=scan_unroll,
-        loss_chunk=loss_chunk)
-    params = Transformer.init(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (batch * len(devices), cfg.max_seq_len + 1),
-        0, 50257)
-
-    restore_attn = None
-    if no_attn:
-        # identity attention: measures the whole attention block's cost
-        import ray_tpu.models.transformer as tr
-        restore_attn = tr.Transformer.__dict__["_make_attention"]
-
-        def fake_make(cfg2, mesh2, rules2):
-            return lambda q, k, v, scale: q
-        tr.Transformer._make_attention = staticmethod(fake_make)
-    if no_head:
-        def loss_fn(p, b):
-            h = Transformer.hidden(p, b["tokens"][:, :-1], cfg, mesh=mesh)
-            return jnp.mean(jnp.square(h.astype(jnp.float32)))
-    else:
-        def loss_fn(p, b):
-            return Transformer.loss(p, b, cfg, mesh=mesh)
-
-    opt = optax.sgd(1e-4) if sgd else \
-        optax.adamw(1e-4, weight_decay=0.01)
-    init_state, train_step = make_train_step(
-        loss_fn, Transformer.param_specs(cfg), mesh, optimizer=opt)
-    state = init_state(params)
-    batch_d = {"tokens": tokens}
-    for _ in range(WARMUP):
-        state, metrics = train_step(state, batch_d)
-    jax.device_get(metrics["loss"])
-    t0 = time.perf_counter()
-    for _ in range(STEPS):
-        state, metrics = train_step(state, batch_d)
-    loss = float(jax.device_get(metrics["loss"]))
-    dt = (time.perf_counter() - t0) / STEPS
-    toks = batch * len(devices) * cfg.max_seq_len
-    tps = toks / dt
-    print(f"{name:28s} step={dt*1e3:7.1f}ms tok/s={tps:9.0f} "
-          f"tflops={tps*FLOPS_PER_TOKEN/1e12:6.1f} loss={loss:.4f}",
-          flush=True)
-    del state
-    if restore_attn is not None:
-        import ray_tpu.models.transformer as tr
-        tr.Transformer._make_attention = restore_attn
-
-
-# NOTE: run_variant's defaults ARE the shipping bench config (heads6 +
-# unchunked CE). Legacy round-3/4a variants pin every divergent knob
-# explicitly so their meaning never drifts when defaults move.
-VARIANTS = {
-    "r3_baseline": {"n_heads": 12, "loss_chunk": 256},
-    "r3_heads6": {"n_heads": 6, "loss_chunk": 256},
-    "r3_chunk512": {"n_heads": 12, "loss_chunk": 512},
-    "heads6_chunk512": {"n_heads": 6, "loss_chunk": 512},
-    "nohead": {"no_head": True, "n_heads": 12, "loss_chunk": 256},
-    "nohead_heads6": {"no_head": True, "n_heads": 6, "loss_chunk": 256},
-    "r3_dense": {"n_heads": 12, "loss_chunk": 256,
-                 "attention_impl": "dense"},
-    "heads6_b32_c512": {"n_heads": 6, "batch": 32, "loss_chunk": 512},
-    "heads6_dense_c512": {"n_heads": 6, "attention_impl": "dense",
-                          "loss_chunk": 512},
-    # round-4b: decompose the ~40% non-matmul time around the shipping
-    # config ("best" = the defaults)
-    "best": {},
-    "best_sgd": {"sgd": True},
-    "best_noattn": {"no_attn": True},
-    "best_dense": {"attention_impl": "dense"},
-    "best_b24": {"batch": 24},
-    "best_unroll1": {"scan_unroll": 1},
-}
-
 
 # ----------------------------------------------------------------------
 # --suite lease: task-path lease-transport ablation
@@ -186,22 +85,13 @@ def run_lease_suite(n: int, merge_path: str) -> None:
 
 def main():
     argv = sys.argv[1:]
-    if "--suite" in argv:
-        i = argv.index("--suite")
-        suite = argv[i + 1]
-        if suite != "lease":
-            raise SystemExit(f"unknown suite: {suite}")
-        n = int(argv[argv.index("--n") + 1]) if "--n" in argv else 1500
-        merge = argv[argv.index("--merge") + 1] if "--merge" in argv \
-            else ""
-        run_lease_suite(n, merge)
-        return
-    names = argv or list(VARIANTS)
-    for n in names:
-        try:
-            run_variant(n, **VARIANTS[n])
-        except Exception as e:  # noqa: BLE001
-            print(f"{n:28s} FAILED: {type(e).__name__}: {e}", flush=True)
+    suite = argv[argv.index("--suite") + 1] if "--suite" in argv else None
+    if suite != "lease":
+        raise SystemExit(f"unknown suite: {suite} (usage: --suite lease "
+                         f"[--n N] [--merge FILE])")
+    n = int(argv[argv.index("--n") + 1]) if "--n" in argv else 1500
+    merge = argv[argv.index("--merge") + 1] if "--merge" in argv else ""
+    run_lease_suite(n, merge)
 
 
 if __name__ == "__main__":
