@@ -10,7 +10,7 @@ nothing; the untied output head is a matmul and counts.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 
 def head_dim(cfg: Dict[str, Any]) -> int:
@@ -67,33 +67,58 @@ def train_flops_per_token(cfg: Dict[str, Any], seq: int) -> float:
         cfg, seq)
 
 
-# ---- the flash attention kernels' calls --------------------------------
-# What each pallas call computes, in [T, hd] x [hd, T]-sized products:
-#   fwd      S = QK^T, O = PV                                   -> 2
-#   bwd dkv  S, dP = dO V^T, dV = P^T dO, dK = dS^T Q           -> 4
-#   bwd dq   S, dP, dQ = dS K                                   -> 3
-FLASH_KERNEL_MATMULS = {"fwd": 2, "bwd_dkv": 4, "bwd_dq": 3}
+# ---- the attention kernels' calls -----------------------------------
+# What one call of a kind computes, in [T, hd] x [hd, T]-sized products,
+# whichever library's kernel it is (pallas flash, splash):
+#   fwd        S = QK^T, O = PV                                 -> 2
+#   bwd_dkv    S, dP = dO V^T, dV = P^T dO, dK = dS^T Q         -> 4
+#   bwd_dq     S, dP, dQ = dS K                                 -> 3
+#   bwd_fused  S, dP, dV, dK, dQ: the whole backward in one     -> 5
+ATTENTION_KERNEL_MATMULS = {"fwd": 2, "bwd_dkv": 4, "bwd_dq": 3,
+                            "bwd_fused": 5}
+FUSED = "bwd_fused"
 
 
-def flash_call_flops(kind: str, batch: int, heads: int, seq: int,
-                     hd: int) -> float:
+def kinds_as_computed(kinds: Dict[str, Any]) -> Dict[str, Any]:
+    """`kind -> [seconds, events]` as a window's events were found
+    (`fwd`, `bwd_dkv`, `bwd_dq`), by what their calls computed: where
+    there are `bwd_dkv` events and no `bwd_dq` event, each `bwd_dkv` call
+    made dQ too and is `bwd_fused`."""
+    def events(kind: str) -> float:
+        return kinds.get(kind, (0.0, 0))[1]
+
+    if not events("bwd_dkv") or events("bwd_dq"):
+        return dict(kinds)
+    out = {k: v for k, v in kinds.items() if k not in ("bwd_dkv", "bwd_dq")}
+    out[FUSED] = kinds["bwd_dkv"]
+    return out
+
+
+def attention_call_flops(kind: str, batch: int, heads: int, seq: int,
+                         hd: int) -> float:
     return attention_matmul_flops(batch, heads, seq, hd,
-                                  FLASH_KERNEL_MATMULS[kind])
+                                  ATTENTION_KERNEL_MATMULS[kind])
 
 
-def flash_call_bytes(kind: str, batch: int, heads: int, seq: int,
-                     hd: int, itemsize: int = 2) -> float:
-    """Least HBM traffic of one call: each [B, H, T, hd] operand or result
-    once (the kernel is given K/V already repeated to H heads), f32
-    [B, H, T] softmax statistics once each."""
-    tensor = batch * heads * seq * hd * itemsize
+def attention_call_bytes(kind: str, batch: int, heads: int, seq: int,
+                         hd: int, kv_heads: Optional[int] = None,
+                         itemsize: int = 2) -> float:
+    """Least HBM traffic of one call: each operand or result once, q, o
+    and their cotangents at `[B, H, T, hd]`, k, v, dk and dv at their own
+    `kv_heads` (the call that does not repeat them is the one that moves
+    least; `heads` where not given), f32 `[B, H, T]` softmax statistics
+    once each."""
+    wide = batch * heads * seq * hd * itemsize
+    narrow = batch * (kv_heads or heads) * seq * hd * itemsize
     stat = batch * heads * seq * 4
-    if kind == "fwd":      # read q, k, v; write o, l, m
-        return 4 * tensor + 2 * stat
-    if kind == "bwd_dkv":  # read q, k, v, do, l, m, di; write dk, dv
-        return 6 * tensor + 3 * stat
-    if kind == "bwd_dq":   # read q, k, v, do, l, m, di; write dq
-        return 5 * tensor + 3 * stat
+    if kind == "fwd":        # read q, k, v; write o, l, m
+        return 2 * wide + 2 * narrow + 2 * stat
+    if kind == "bwd_dkv":    # read q, do, k, v, l, m, di; write dk, dv
+        return 2 * wide + 4 * narrow + 3 * stat
+    if kind == "bwd_dq":     # read q, do, k, v, l, m, di; write dq
+        return 3 * wide + 2 * narrow + 3 * stat
+    if kind == "bwd_fused":  # read q, do, k, v, l, m, di; write dq, dk, dv
+        return 3 * wide + 4 * narrow + 3 * stat
     raise KeyError(kind)
 
 

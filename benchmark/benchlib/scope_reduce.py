@@ -212,6 +212,15 @@ def phase_of(path: str) -> str:
     return "backward" if "transpose(" in path else "forward"
 
 
+def is_custom_call(name: str) -> bool:
+    """Whether a device event is a kernel the program called (a pallas
+    call) and no op of XLA's own, by the event's HLO text. (Not by the
+    path: a layout copy XLA puts before a kernel inherits the kernel's
+    `.../pallas_call` path; seen on a v5e, PR 31.) The two recorded
+    fixtures cut their names short of the opcode: nothing is marked there."""
+    return " custom-call(" in name
+
+
 def reduce_scopes(trace: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     """Every `XLA Ops` event's self time inside `bench_window` to exactly
     one bucket (collectives first, else the innermost scope, else
@@ -231,6 +240,7 @@ def reduce_scopes(trace: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     cells: Dict[Tuple[str, str], float] = defaultdict(float)
     ops: Dict[Tuple[str, str, str], List[float]] = defaultdict(
         lambda: [0.0, 0])
+    custom = set()
     for plane in planes:
         events = [e for line in plane["lines"] if line["name"] == tr.OPS_LINE
                   for e in line["events"] if e[1] + e[2] > lo and e[1] < hi]
@@ -247,6 +257,8 @@ def reduce_scopes(trace: Dict[str, Any]) -> Optional[Dict[str, Any]]:
             slot = ops[(short,) + key]
             slot[0] += seconds
             slot[1] += 1
+            if is_custom_call(name):
+                custom.add(short)
     bucket_s: Dict[str, float] = defaultdict(float)
     phase_s: Dict[str, float] = defaultdict(float)
     for (bucket, phase), seconds in cells.items():
@@ -259,7 +271,8 @@ def reduce_scopes(trace: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     for e in host:
         if e[0].startswith(SPAN_PREFIX) and lo <= e[1] and e[1] + e[2] <= hi:
             spans[e[0]].append(e[2] / 1e9)
-    top = sorted(ops.items(), key=lambda kv: -kv[1][0])[:40]
+    ranked = sorted(ops.items(), key=lambda kv: -kv[1][0])
+    top = ranked[:40]
     return {
         "devices": n,
         "self_s": self_s,
@@ -269,6 +282,11 @@ def reduce_scopes(trace: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         "bucket_phase_s": {f"{b}|{p}": s for (b, p), s in cells.items()},
         "top_ops": [[short, bucket, phase, s, int(c)]
                     for (short, bucket, phase), (s, c) in top],
+        # what `describe_attention` prints: the scope's largest ops, the
+        # program's kernels (custom calls) marked
+        "attention_ops": [[short, phase, s, int(c), short in custom]
+                          for (short, bucket, phase), (s, c) in ranked
+                          if bucket == "attention"][:16],
         "host_spans_s": dict(spans),
     }
 
@@ -304,6 +322,27 @@ def share(record: Dict[str, Any], buckets: Sequence[str] = (),
     seconds = sum(reduced["bucket_s"].get(b, 0.0) for b in buckets) + \
         sum(reduced["phase_s"].get(p, 0.0) for p in phases)
     return 100.0 * seconds / reduced["busy_s"]
+
+
+def describe_attention(record: Dict[str, Any]) -> str:
+    """For a kernel reader that found nothing: what ran under the scope
+    `attention` in this run's trace, the custom calls by their names, so
+    that whoever changed the kernels sees which names the configuration's
+    `kernels.attn` patterns had to match."""
+    kinds = ((record.get("trace") or {}).get("kernel_s") or {}).get("attn")
+    head = f"events per kind of `kernels.attn` [seconds, count]: {kinds}; "
+    reduced = for_record(record)
+    if not reduced:
+        return head + "no device trace of this run with the program's scopes"
+    found = reduced.get("attention_ops") or []
+    if not found:
+        return head + "no op ran under the scope `attention`"
+    calls = [f"{short} ({phase}, {s * 1e3:.2f} ms x{count})"
+             for short, phase, s, count, is_call in found if is_call]
+    rest = [short for short, _p, _s, _c, is_call in found if not is_call]
+    return (head + "custom calls under the scope `attention`: "
+            + ("; ".join(calls) or "none")
+            + "; the scope's other ops: " + (", ".join(rest[:8]) or "none"))
 
 
 def span_median_ms(record: Dict[str, Any], name: str) -> Optional[float]:

@@ -160,7 +160,7 @@ def reduce_device(plane: Dict[str, Any], window: Interval,
                   kernels: Dict[str, Dict[str, str]],
                   host: Sequence[Sequence[Any]]) -> Dict[str, Any]:
     lo, hi = window
-    ops = [e for e in _line(plane, OPS_LINE)
+    ops = [e[:3] for e in _line(plane, OPS_LINE)   # a 4th is the path
            if e[1] + e[2] > lo and e[1] < hi]
     timed = self_times(ops)
 

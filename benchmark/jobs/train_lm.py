@@ -107,7 +107,7 @@ def transformer_config(model: Dict[str, Any], train: Dict[str, Any],
         tie_embeddings=bool(model.get("tie_word_embeddings")),
         attention_impl=train["attention_impl"],
         dtype=train["compute_dtype"], param_dtype=train["param_dtype"],
-        remat=train["remat"], remat_policy=train["remat_policy"],
+        remat=train["remat"],   # what it saves is the program's to decide
         loss_chunk=train["loss_chunk"], scan_unroll=train["scan_unroll"])
 
 
@@ -156,7 +156,8 @@ def worker_loop(config: Dict[str, Any]) -> None:
     import ray_tpu.train as train
     from benchlib import device as bdev
     from benchlib import flops
-    from benchlib.checks import Checks
+    from benchlib.checks import (Checks, attention_as_expected,
+                                 kernel_calls)
     from benchlib.peaks import peaks_for
     from benchlib.spec import load_module
     from benchlib.traffic import TokenBatches
@@ -254,17 +255,18 @@ def worker_loop(config: Dict[str, Any]) -> None:
         "generated_code_size_in_bytes")} if ma is not None else {}
     hlo = compiled.as_text()
     del compiled
-    kernel_calls = hlo.count("tpu_custom_call")
+    n_kernel_calls = hlo.count("tpu_custom_call")
+    attn_calls = kernel_calls(hlo, model.get("kernels", {}).get("attn", {}))
     collectives = {k: hlo.count(f" {k}(") + hlo.count(f" {k}-start(")
                    for k in ("all-gather", "all-reduce", "reduce-scatter",
                              "all-to-all", "collective-permute")}
     del hlo
     impl = Transformer.resolve_attention_impl(cfg, mesh, seq)
     want = tr_cfg["expect_attention"]
-    checks.add("attention_impl",
-               impl == want and (want != "flash" or kernel_calls > 0),
-               {"resolved": impl, "expected": want,
-                "tpu_custom_call": kernel_calls})
+    checks.add("attention_impl", attention_as_expected(impl, want,
+                                                       attn_calls),
+               {"resolved": impl, "expected": want, "calls": attn_calls,
+                "tpu_custom_call": n_kernel_calls})
     leaf_path = model["layout"].get("sharded_leaf")
     if leaf_path:
         leaf = state["params"]
@@ -369,11 +371,12 @@ def worker_loop(config: Dict[str, Any]) -> None:
             "flops_per_token": flops.train_flops_per_token(model, seq),
             "params": n_params,
             "memory_analysis": memory_analysis,
-            "kernel_calls_in_step": kernel_calls,
+            "kernel_calls_in_step": n_kernel_calls,
             "collectives_in_step": collectives,
             "attention_call": {
                 "batch": batches.sequences // batch_devices,
-                "heads": model["num_attention_heads"], "seq": seq,
+                "heads": model["num_attention_heads"],
+                "kv_heads": model["num_key_value_heads"], "seq": seq,
                 "head_dim": flops.head_dim(model)},
         },
         "counters": {"losses_first_last": [losses[0], losses[-1]],

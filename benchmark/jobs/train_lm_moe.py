@@ -34,7 +34,6 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
-import re
 import statistics
 import time
 from typing import Any, Dict, List
@@ -99,7 +98,7 @@ def transformer_config(model: Dict[str, Any], train: Dict[str, Any],
         moe_aux_coeff=model["router_aux_loss_coef"],
         attention_impl=train["attention_impl"],
         dtype=train["compute_dtype"], param_dtype=train["param_dtype"],
-        remat=train["remat"], remat_policy=train["remat_policy"],
+        remat=train["remat"],   # what it saves is the program's to decide
         loss_chunk=train["loss_chunk"], scan_unroll=train["scan_unroll"])
 
 
@@ -168,14 +167,6 @@ def routing_load(counts, top_k: int) -> Dict[str, Any]:
             "spread": load < n_experts / top_k and empty <= n_experts // 2}
 
 
-def kernel_calls(hlo: str, patterns: Dict[str, str]) -> Dict[str, int]:
-    """How many pallas calls of the compiled step each pattern names."""
-    names = re.findall(
-        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', hlo)
-    return {key: sum(bool(re.match(pattern, name)) for name in names)
-            for key, pattern in patterns.items()}
-
-
 def worker_loop(config: Dict[str, Any]) -> None:
     entered_at = time.time()
     phases: Dict[str, float] = {}
@@ -194,7 +185,8 @@ def worker_loop(config: Dict[str, Any]) -> None:
     import ray_tpu.train as train
     from benchlib import device as bdev
     from benchlib import flops, flops_moe
-    from benchlib.checks import Checks
+    from benchlib.checks import (Checks, attention_as_expected,
+                                 grouped_matmul_as_expected, kernel_calls)
     from benchlib.peaks import peaks_for
     from benchlib.traffic import TokenBatches
     from ray_tpu.models import Transformer
@@ -323,21 +315,14 @@ def worker_loop(config: Dict[str, Any]) -> None:
     del hlo
     impl = Transformer.resolve_attention_impl(cfg, mesh, seq)
     want = tr_cfg["expect_attention"]
-    checks.add("attention_impl",
-               impl == want and (want != "flash" or all(
-                   attn_calls.get(k, 0) > 0
-                   for k in ("fwd", "bwd_dkv", "bwd_dq"))),
+    checks.add("attention_impl", attention_as_expected(impl, want,
+                                                       attn_calls),
                {"resolved": impl, "expected": want, "calls": attn_calls})
-    # the expert FFN's grouped matmul: gate/up and down, forward (once
-    # more under remat) and the transpose for the rows (`gmm`), and the
-    # transpose for the weights (`tgmm`)
     gmm_impl = grouped_matmul_impl(
         mesh, slots_per_step // batch_devices, cfg.d_model, cfg.ff_dim)
     want_gmm = tr_cfg["expect_grouped_matmul"]
-    checks.add("grouped_matmul_impl", gmm_impl == want_gmm and (
-        want_gmm != "megablox" or (
-            moe_calls.get("gmm", 0) >= 2 * (2 + bool(tr_cfg["remat"]))
-            and moe_calls.get("tgmm", 0) >= 2)),
+    checks.add("grouped_matmul_impl", grouped_matmul_as_expected(
+        gmm_impl, want_gmm, moe_calls),
         {"resolved": gmm_impl, "expected": want_gmm, "calls": moe_calls})
     # one [tokens, experts, capacity] float32 one-hot of the dispatch this
     # configuration cannot use (capacity 1.25 x tokens x k / experts)
@@ -472,7 +457,8 @@ def worker_loop(config: Dict[str, Any]) -> None:
             "collectives_in_step": collectives,
             "attention_call": {
                 "batch": batches.sequences // batch_devices,
-                "heads": model["num_attention_heads"], "seq": seq,
+                "heads": model["num_attention_heads"],
+                "kv_heads": model["num_key_value_heads"], "seq": seq,
                 "head_dim": flops.head_dim(model)},
             "experts_call": {
                 "model": {k: model[k] for k in (

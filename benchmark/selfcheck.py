@@ -7,8 +7,12 @@
 cell names is there, the FLOP functions against hand-worked numbers for
 both Mistral depths, the trace reduction against the recorded v5e trace in
 fixtures/ (each number recomputed here by rasterising the intervals, a
-method that shares nothing with the reduction's sweeps), the traffic
-generator's determinism, the span-bucket arithmetic on a hand-made ring.
+method that shares nothing with the reduction's sweeps), the attention
+kernels read by kind on hand-made traces with splash's names (a fused
+backward is five products; a trace whose kernels no pattern names makes
+the run say which metric found nothing and which calls it saw, and print
+no result), the traffic generator's determinism, the span-bucket
+arithmetic on a hand-made ring.
 
 `rehearsal`: every kind of cell at a tiny size on the CPU
 (`JAX_PLATFORMS=cpu`, rehearsal-only configuration files, four virtual
@@ -24,7 +28,9 @@ Each check is a function `check_<name>()` that raises AssertionError;
 
 from __future__ import annotations
 
+import contextlib
 import gzip
+import io
 import json
 import os
 import re
@@ -32,6 +38,7 @@ import shutil
 import subprocess
 import sys
 import tempfile
+import time
 from typing import Any, Dict, List, Tuple
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -39,8 +46,9 @@ ROOT = os.path.dirname(BENCH_DIR)
 if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
-from benchlib import flops, span_buckets, trace_reduce  # noqa: E402
-from benchlib.spec import load_json, metrics_of  # noqa: E402
+from benchlib import (flops, scope_reduce, span_buckets,  # noqa: E402
+                      trace_reduce)
+from benchlib.spec import load_json, load_module, metrics_of  # noqa: E402
 from benchlib.traffic import TokenBatches  # noqa: E402
 
 REHEARSAL_SPEC = os.path.join(BENCH_DIR, "rehearsal",
@@ -190,15 +198,20 @@ def check_flops_hand_worked() -> None:
     assert flops.total_params(deep) == 2_007_044_096
     assert flops.train_flops_per_token(deep, 4096) == \
         6 * 1_875_902_464 + 8 * attn_layer == 12_060_721_152
-    # one forward flash call at 4 x 32 heads x 4096 x 128: two products
-    assert flops.flash_call_flops("fwd", 4, 32, 4096, 128) == \
+    # one forward attention call at 4 x 32 heads x 4096 x 128: two
+    # products; dkv four, dq three, a backward in one call five
+    assert flops.attention_call_flops("fwd", 4, 32, 4096, 128) == \
         2 * 2 * 4 * 32 * 4096 * 4096 * 128 // 2 == 549_755_813_888
-    assert flops.flash_call_flops("bwd_dkv", 4, 32, 4096, 128) == \
-        2 * 549_755_813_888
+    for kind, halves in (("bwd_dkv", 4), ("bwd_dq", 3), ("bwd_fused", 5)):
+        assert flops.attention_call_flops(kind, 4, 32, 4096, 128) == \
+            halves * 549_755_813_888 // 2
+    # q and o at 32 heads, k and v at their own 8, two f32 statistics
+    assert flops.attention_call_bytes("fwd", 4, 32, 4096, 128, 8) == \
+        2 * 134_217_728 + 2 * 33_554_432 + 2 * 2_097_152 == 339_738_624
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    t, bound = flops.least_time_s(549_755_813_888, flops.flash_call_bytes(
-        "fwd", 4, 32, 4096, 128), peaks)
+    t, bound = flops.least_time_s(549_755_813_888, 339_738_624, peaks)
     assert bound == "compute" and abs(t - 549_755_813_888 / 197e12) < 1e-12
+    assert abs(339_738_624 / 819e9 - 0.000415) < 1e-6 < t    # 2.79 ms
     assert flops.least_time_s(1e9, 1e9, peaks)[1] == "memory"
 
 
@@ -219,8 +232,7 @@ def check_trace_reduction_on_fixture() -> None:
     reduction's numbers against a rasterised recomputation at 1 us, and
     against what the cell must show (kernel calls per step, no
     collectives on one chip)."""
-    with gzip.open(FIXTURE, "rt") as f:
-        trace = json.load(f)
+    trace = load_fixture(os.path.basename(FIXTURE))
     kernels = load_json(os.path.join(
         BENCH_DIR, "configs", "mistral-7b-v0.1-d2.json"))["kernels"]
     out = trace_reduce.reduce_trace(trace, kernels)
@@ -304,6 +316,91 @@ def check_trace_reduction_collectives() -> None:
     idle = dict(out["idle_by_host_s"])
     assert abs(idle["report"] - 200 * ns) < 1e-15
     assert abs(idle["unattributed"] - 100 * ns) < 1e-15
+
+
+def load_fixture(name: str) -> Dict[str, Any]:
+    path = os.path.join(BENCH_DIR, "fixtures", name)
+    with (gzip.open if name.endswith(".gz") else open)(path, "rt") as f:
+        return json.load(f)
+
+
+@contextlib.contextmanager
+def traced_record(fixture: str):
+    """The record a job would hand run.py after tracing what the fixture
+    holds: the worker's reduction by the d2 file's kernel names, d2's
+    attention call, and, for the readers that go by scope, the trace as
+    the file of `this run` (`scope_reduce` is pointed at it, and put back
+    on the way out)."""
+    trace = load_fixture(fixture)
+    model = load_json(os.path.join(
+        BENCH_DIR, "configs", "mistral-7b-v0.1-d2.json"))
+    saved = (scope_reduce.SCRATCH, scope_reduce._REDUCED,
+             scope_reduce.from_xplane)
+    with tempfile.TemporaryDirectory(prefix="bench_synth_") as scratch:
+        run = os.path.join(scratch, "cell", "trace", "plugins", "profile",
+                           "x")
+        os.makedirs(run)
+        with open(os.path.join(run, "host.xplane.pb"), "wb"):
+            pass
+        scope_reduce.SCRATCH, scope_reduce._REDUCED = scratch, {}
+        scope_reduce.from_xplane = lambda path: trace
+        try:
+            yield {
+                "trace": trace_reduce.reduce_trace(trace, model["kernels"]),
+                "window_started_at": time.time() - 60.0,
+                "static": {"peaks": {"bf16_flops_per_s": 197e12,
+                                     "hbm_bytes_per_s": 819e9},
+                           "attention_call": {
+                               "batch": 4, "heads": 32, "kv_heads": 8,
+                               "seq": 4096, "head_dim": 128}},
+                "device": {"platform": "tpu", "kind": "TPU v5 lite",
+                           "count": 1, "memory_peak_bytes": 1},
+                "correct": True, "attempted": 2, "failed": 0, "checks": {},
+                "end_to_end": {"train_tokens_per_s": 1.0}}
+        finally:
+            (scope_reduce.SCRATCH, scope_reduce._REDUCED,
+             scope_reduce.from_xplane) = saved
+
+
+def check_attention_kernels_by_kind() -> None:
+    """Splash's names on hand-made traces: separate dq and dkv calls are
+    `fwd`, `bwd_dkv`, `bwd_dq` with 2, 4, 3 products; a backward with no
+    dq event is `bwd_fused` with 5; neither reads over 100%."""
+    roofline = load_module("layer_metrics", "attn_kernel_roofline").roofline
+    unit = 549_755_813_888 / 2 / 197e12      # one product at d2's call
+    for name, ms in (
+            ("synthetic_splash_separate.json",
+             {"fwd": (2, 5.0), "bwd_dkv": (4, 9.0), "bwd_dq": (3, 7.0)}),
+            ("synthetic_splash_fused.json",
+             {"fwd": (2, 5.0), "bwd_fused": (5, 11.0)})):
+        with traced_record(name) as record:
+            out = roofline(record)
+        assert sorted(out["calls"]) == sorted(ms), (name, out["calls"])
+        for kind, (products, took_ms) in ms.items():
+            want = 100.0 * products * unit / (took_ms / 1e3)
+            assert abs(out["by_kind"][kind] - want) < 1e-9, (kind, out)
+            assert out["bound"][kind] == "compute"
+        assert 40.0 < out["share"] < 100.0, out
+
+
+def check_nothing_read_says_why() -> None:
+    """A traced run of a real cell whose attention kernels no pattern of
+    `kernels.attn` names: stderr names each metric without a reading and
+    the custom calls seen under the scope `attention`; no result line,
+    a non-zero exit."""
+    run = load_module(".", "run")   # benchmark/run.py
+    spec = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    out, err = io.StringIO(), io.StringIO()
+    with traced_record("synthetic_unknown_kernels.json") as record, \
+            contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
+        rc = run.emit(spec, "train_mistral7b_d2", 7, True, record, False)
+    assert rc == run.NOTHING_READ and not out.getvalue().strip(), out
+    for metric in ("attn_kernel_share", "attn_kernel_roofline",
+                   "attn_glue_share"):
+        said = [ln for ln in err.getvalue().splitlines()
+                if ln.startswith(f"[bench] NO READING of {metric} ")]
+        assert len(said) == 1 and "my_attn_fwd.17 (forward" in said[0] \
+            and "my_attn_bwd.11 (backward" in said[0], err.getvalue()
 
 
 def check_traffic_deterministic() -> None:
@@ -488,6 +585,7 @@ def _rehearsal_cells() -> List[Tuple[str, int]]:
 ARITHMETIC = [check_spec_contract, check_flops_hand_worked,
               check_trace_reduction_on_fixture,
               check_trace_reduction_collectives,
+              check_attention_kernels_by_kind, check_nothing_read_says_why,
               check_traffic_deterministic, check_span_buckets]
 PROCESSES = [check_no_result_without_accelerator,
              check_no_result_in_bare_directory, check_new_files_are_found]

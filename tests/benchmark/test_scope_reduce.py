@@ -372,4 +372,8 @@ def test_spec_contract_with_the_ten_new_entries():
     selfcheck.check_spec_contract()
     names = [m["name"] for m in load_json(
         os.path.join(ROOT, "BENCHMARK.json"))["per_layer"]]
-    assert names == BEFORE_PR_24 + TRACE_READERS + GANG_READERS
+    # PR 24's ten are there, in order, after the nine before them; later
+    # PRs add theirs behind
+    first = BEFORE_PR_24 + TRACE_READERS + GANG_READERS
+    assert names[:len(first)] == first
+    assert len(set(names)) == len(names)
