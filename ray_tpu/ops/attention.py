@@ -4,6 +4,12 @@ Single source of truth for the dense (fully local) attention used by the
 transformer, by ulysses_attention's inner computation, and by tests.
 Accumulates scores and the probs@V contraction in f32 regardless of the
 compute dtype (bf16 on TPU) via preferred_element_type.
+
+`flash_attention` is the fused path on the TPU: JAX's pallas splash
+attention (`jax.experimental.pallas.ops.tpu.splash_attention`) with a
+causal mask the kernel knows, K and V kept at their kv-head width, and a
+block geometry derived from the shape at trace time. One kernel library,
+no option: `dense_attention` is the reference the tests hold it to.
 """
 
 from __future__ import annotations
@@ -72,53 +78,83 @@ def dense_attention(q, k, v, *, causal: bool = True,
 
 
 def flash_shape_ok(t: int, head_dim: int) -> bool:
-    """Whether the pallas TPU flash kernel can tile this shape: seq in
-    blocks of >=128, head_dim on the lane dim."""
+    """Whether `flash_attention`'s kernel (JAX's pallas splash attention)
+    tiles this shape: the sequence in blocks of 128 or more, head_dim a
+    multiple of 64. `tests/test_chip_compile.py` holds this against what
+    the v5e compiler takes, T 128 to 8192 and head_dim 64, 128 and 256."""
     return t >= 128 and t % 128 == 0 and head_dim % 64 == 0
 
 
-def flash_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None):
-    """Fused flash attention on [batch, seq, heads, head_dim]: the
-    pallas TPU flash kernel (O(T) memory — never materializes the
-    [B,H,T,T] score matrix), f32 accumulation inside the kernel.
+def _splash_block_sizes(t: int, head_dim: int):
+    """The kernels' block geometry, a function of the shape seen at trace
+    time and of nothing else. Fetched blocks (q and kv, both kernels): the
+    largest of 1024 / 512 / 256 / 128 that divides T, from 512 down where
+    head_dim is above 128 (a 1024-row block of 256 columns overruns the
+    v5e's 16 MiB of scoped VMEM in the backward kernel). Compute
+    sub-blocks: up to 512 columns of scores at a time in the forward
+    kernel; the whole fetched block in the one backward kernel, which
+    makes dK, dV and dQ in one pass over the scores
+    (`use_fused_bwd_kernel`). The best of a sweep on a v5e at T = 4096,
+    D = 128, heads 32/8 and 16/16 (PERF.md section 6, PR 32)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
-    Raises ValueError for a shape the kernel cannot tile; off the TPU
-    the pallas lowering itself refuses. There is no dense fallback —
-    callers that want one say attention_impl="auto" or "dense".
-    """
+    largest = 1024 if head_dim <= 128 else 512
+    block = next(b for b in (1024, 512, 256, 128)
+                 if b <= largest and t % b == 0)
+    return BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=min(block, 512),
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        use_fused_bwd_kernel=True)
+
+
+def _splash_attention(q, k, v, *, causal: bool, scale: float,
+                      interpret: bool = False):
+    """`flash_attention`'s body; `interpret` runs the kernels in pallas
+    interpret mode, which is how the CPU tests read their numerics."""
+    import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        CausalMask, FullMask, MultiHeadMask, make_splash_mha)
 
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
-    b, t, h, d = q.shape
+    t, h, d = q.shape[1:]
     if not flash_shape_ok(t, d):
         raise ValueError(
             f"flash attention needs seq a multiple of 128 and head_dim a "
             f"multiple of 64, got seq={t}, head_dim={d}; use "
             f"attention_impl='auto' or 'dense' for this shape")
-    if k.shape[2] != h:
-        # the pallas kernel wants equal head counts; materialize the
-        # GQA repeat only on this (single-device-local) path
-        rep = h // k.shape[2]
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    if h % k.shape[2]:
+        raise ValueError(f"GQA needs kv heads ({k.shape[2]}) to divide "
+                         f"query heads ({h})")
+    # built once per trace: the mask's block table is numpy, and the layer
+    # stack that calls this is one lax.scan
+    head_mask = (CausalMask if causal else FullMask)((t, t))
+    kernel = make_splash_mha(
+        MultiHeadMask([head_mask] * h),
+        block_sizes=_splash_block_sizes(t, d), head_shards=1,
+        q_seq_shards=1, interpret=interpret)
+    # the kernel takes no scale (folded into q, rounded once) and one
+    # sequence [H, T, D] at a time; K and V keep their own head count,
+    # q head i reading kv head i // (H // Hkv) as gqa_scores does
+    scaled = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    o = jax.vmap(kernel)(jnp.swapaxes(scaled, 1, 2), jnp.swapaxes(k, 1, 2),
+                         jnp.swapaxes(v, 1, 2))
+    return jnp.swapaxes(o, 1, 2)
 
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes, flash_attention as _pallas_flash)
 
-    # largest block <=512 that divides t (the kernel requires exact
-    # divisibility; flash_shape_ok guarantees t % 128 == 0)
-    blk = next(b for b in (512, 256, 128) if t % b == 0)
-    sizes = BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk,
-        block_k_dkv=blk, block_q_dkv=blk,
-        block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
-    # kernel layout is [B, H, T, D]
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    o = _pallas_flash(qt, kt, vt, causal=causal, sm_scale=scale,
-                      block_sizes=sizes)
-    return jnp.swapaxes(o, 1, 2).astype(q.dtype)
+def flash_attention(q, k, v, *, causal: bool = True,
+                    scale: Optional[float] = None):
+    """Fused attention on [batch, seq, heads, head_dim] (k, v may carry
+    fewer heads): JAX's pallas splash attention kernels. O(T) memory (the
+    [B, H, T, T] scores never exist), bf16 operands with f32 accumulation
+    inside a kernel, blocks above the diagonal of a causal mask skipped
+    rather than computed and masked, K and V read at their own head count
+    (no GQA repeat in HBM), one backward kernel for dQ, dK and dV. Block
+    sizes follow from (T, head_dim): `_splash_block_sizes`.
+
+    Raises ValueError for a shape the kernel cannot tile; off the TPU
+    the pallas lowering itself refuses. There is no dense fallback:
+    callers that want one say attention_impl="auto" or "dense".
+    """
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    return _splash_attention(q, k, v, causal=causal, scale=scale)
