@@ -1,5 +1,14 @@
 """Transformer configurations.
 
+One `TransformerConfig` describes every decoder `models/transformer.py`
+runs: softmax attention as MHA / GQA (optionally with QK-norm) or latent
+(compressed key/value) attention with its own head widths; a dense SwiGLU
+FFN, or routed experts (a softmax router with the load-balancing loss, or
+a sigmoid router with a choice bias that is a buffer and a scaling
+factor), optionally behind leading dense layers of their own width, with
+shared experts every token passes, and with only a contiguous share of
+the experts held here (one chip of an expert-parallel layer).
+
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
 BENCHMARK.json's `train_olmoe_d1`), each at its published depth. The
@@ -66,6 +75,72 @@ class TransformerConfig:
     # (`norm_topk_prob`; OLMoE publishes false)
     moe_norm_topk: bool = True
     moe_aux_coeff: float = 0.01
+    # "softmax": probabilities over the experts, top-k of them, the aux
+    # loss above. "sigmoid": independent scores `sigmoid(W_r x)`; the
+    # choice is the top-k of score + `router_bias` (a per-expert buffer
+    # that no gradient and no optimizer update reaches:
+    # `Transformer.frozen`), the weights are the chosen scores themselves;
+    # no aux loss (`topk_method: noaux_tc`).
+    moe_scoring: str = "softmax"
+    # the weighted sum of a token's experts times this
+    # (`routed_scaling_factor`)
+    moe_routed_scale: float = 1.0
+    # shared experts: one gated FFN of width moe_shared_experts * d_ff
+    # that every token passes, added to the routed sum (`n_shared_experts`)
+    moe_shared_experts: int = 0
+    # the first moe_dense_layers layers keep a dense FFN of width
+    # moe_dense_ff (`first_k_dense_replace`, `intermediate_size`); the
+    # n_layers - moe_dense_layers after them are expert layers
+    moe_dense_layers: int = 0
+    moe_dense_ff: Optional[int] = None
+    # this program's share of an expert-parallel layer: experts
+    # moe_expert_offset .. + moe_experts_held are resident (0 held = all
+    # moe_experts). The router keeps its moe_experts outputs and its k a
+    # token; token-slots routed to an expert held elsewhere run through
+    # nothing here and are counted (ops/moe.py).
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
+    # Latent attention (kv_lora_rank > 0; DeepSeek-V2's MLA): queries and
+    # keys/values are up-projected from RMS-normed low-rank latents; a
+    # head is qk_nope_head_dim columns without position plus
+    # qk_rope_head_dim rotary columns, the rotary key head shared by all
+    # heads; values are v_head_dim wide. n_kv_heads is n_heads.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    def __post_init__(self):
+        if self.kv_lora_rank:
+            if not (self.q_lora_rank and self.qk_rope_head_dim
+                    and self.v_head_dim) or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, an even "
+                    "qk_rope_head_dim and v_head_dim")
+            if self.kv_heads != self.n_heads or self.qk_norm:
+                raise ValueError("latent attention has one key/value head "
+                                 "per query head and no QK-norm")
+        elif self.d_model % self.n_heads:
+            raise ValueError(f"d_model {self.d_model} % n_heads "
+                             f"{self.n_heads} != 0")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
+        if self.moe_experts:
+            first, held = self.moe_expert_offset, self.held_experts
+            if first < 0 or first + held > self.moe_experts:
+                raise ValueError(
+                    f"experts {first}..{first + held} of {self.moe_experts}")
+            if held < self.moe_experts and self.moe_scoring == "softmax":
+                raise ValueError("the softmax router's aux loss needs every "
+                                 "expert's count: a held share runs the "
+                                 "sigmoid router")
+            if not 0 <= self.moe_dense_layers < self.n_layers:
+                raise ValueError("moe_dense_layers must leave an expert "
+                                 "layer")
+        elif self.moe_dense_layers or self.moe_shared_experts \
+                or self.moe_experts_held:
+            raise ValueError("moe_* sizes without moe_experts")
 
     @property
     def kv_heads(self) -> int:
@@ -73,12 +148,28 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        assert self.d_model % self.n_heads == 0
+        """Width of a query / key head (what the attention kernel tiles);
+        a value head is `v_dim` wide."""
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """Columns of a head that carry position."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
 
     @property
     def ff_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
+
+    @property
+    def held_experts(self) -> int:
+        return self.moe_experts_held or self.moe_experts
 
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
@@ -87,15 +178,29 @@ class TransformerConfig:
     def num_params(self) -> int:
         d, l, f, v = self.d_model, self.n_layers, self.ff_dim, self.vocab_size
         hd, nh, nkv = self.head_dim, self.n_heads, self.kv_heads
-        attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
+        if self.kv_lora_rank:   # down, latent norm, up for q and for kv
+            qr, kvr = self.q_lora_rank, self.kv_lora_rank
+            attn = (d * qr + qr + qr * nh * hd
+                    + d * (kvr + self.rope_dim) + kvr
+                    + kvr * nh * (self.qk_nope_head_dim + self.v_dim)
+                    + nh * self.v_dim * d)
+        else:
+            attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
         if self.qk_norm:
             attn += nh * hd + nkv * hd
-        mlp = 3 * d * f
-        if self.moe_experts:   # every expert, and the router
-            mlp = self.moe_experts * mlp + d * self.moe_experts
         norms = 2 * d
+        mlp = 3 * d * f
+        dense = 0
+        if self.moe_experts:
+            # the experts held here, the shared ones and the router (its
+            # choice bias is a buffer, not a parameter)
+            mlp = (self.held_experts + self.moe_shared_experts) * mlp \
+                + d * self.moe_experts
+            dense = self.moe_dense_layers * (
+                attn + norms + 3 * d * (self.moe_dense_ff or f))
+            l -= self.moe_dense_layers
         head = 0 if self.tie_embeddings else d * v
-        return v * d + l * (attn + mlp + norms) + d + head
+        return v * d + dense + l * (attn + mlp + norms) + d + head
 
 
 TINY = TransformerConfig(

@@ -7,17 +7,22 @@ replicated, FSDP ("embed"->fsdp), tensor-parallel ("heads"/"mlp"->tensor),
 and sequence-parallel (ring/Ulysses attention over the "seq" axis) — XLA
 inserts the collectives. Layers are stacked and iterated with `lax.scan`
 (one compiled layer body regardless of depth — fast compiles, and the
-stacked leading dim is the natural pipeline-parallel axis).
+stacked leading dim is the natural pipeline-parallel axis). A model whose
+first layers keep a dense FFN before its expert layers
+(`moe_dense_layers`) is two such runs, `params["dense_layers"]` then
+`params["layers"]`; a layer is what its leaves say it is.
 
 Every block names itself with `jax.named_scope`, and the names are an
 interface (PERF.md section 3; the benchmark's per-layer metrics and an
 operator's `ray_tpu profile --device` read them off each op's op_name):
 `embed`, `layers` (the scan's own stacking, slicing and carries),
-`attn_norm`, `qkv` (projections, QK-norm and RoPE), `attention` (kernels,
-GQA repeat, layout transposes), `attn_out`, `mlp_norm`, `mlp/gate_up`,
-`mlp/down` (on the MoE branch `moe/router`, `moe/dispatch`, `moe/experts`,
-`moe/combine`: ops/moe.py), `final_norm`, `head`, `loss` (the vocab head
-and the cross-entropy: models/head.py); the train step adds `optimizer`
+`attn_norm`, `qkv` (projections, QK-norm and RoPE; with latent attention
+`qkv/q_down`, `qkv/kv_down`, `qkv/q_up`, `qkv/kv_up`, `qkv/assemble`
+inside it), `attention` (kernels, GQA repeat, layout transposes),
+`attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (in an expert layer
+`moe/router`, `moe/dispatch`, `moe/experts`, `moe/combine`, `moe/shared`:
+ops/moe.py), `final_norm`, `head`, `loss` (the vocab head and the
+cross-entropy: models/head.py); the train step adds `optimizer`
 (parallel/train_step.py). Scopes are metadata only. Forward, backward
 and recomputation need none: JAX wraps the path in `jvp(...)`,
 `transpose(jvp(...))` and remat's `rematted_computation`.
@@ -83,64 +88,102 @@ class Transformer:
 
         pdt = jnp.dtype(cfg.param_dtype)
         d, hd = cfg.d_model, cfg.head_dim
-        nh, nkv, f, l = cfg.n_heads, cfg.kv_heads, cfg.ff_dim, cfg.n_layers
-        keys = jax.random.split(key, 8)
+        nh, nkv, f = cfg.n_heads, cfg.kv_heads, cfg.ff_dim
 
         def norm_init(stddev, k, shape):
             return (jax.random.normal(k, shape, jnp.float32)
                     * stddev).astype(pdt)
 
-        # QKV and gate/up projections are FUSED along an unsharded group
-        # axis (one wide MXU matmul instead of 3/2 narrow ones; slicing the
-        # group axis never crosses a shard boundary). MHA fuses q,k,v into
-        # wqkv[..., 3, nh, hd]; GQA keeps wq separate and fuses k,v.
-        layers = {
-            "attn_norm": jnp.ones((l, d), pdt),
-            "wo": norm_init((nh * hd) ** -0.5, keys[4], (l, nh, hd, d)),
-            "mlp_norm": jnp.ones((l, d), pdt),
-        }
+        def attention(l, keys):
+            """One run of l layers' attention leaves and norm gains."""
+            layers = {
+                "attn_norm": jnp.ones((l, d), pdt),
+                "wo": norm_init((nh * cfg.v_dim) ** -0.5, keys[4],
+                                (l, nh, cfg.v_dim, d)),
+                "mlp_norm": jnp.ones((l, d), pdt),
+            }
+            if cfg.kv_lora_rank:
+                # latent attention: down-projections to the latents (the
+                # shared rotary key head rides on kv's), a norm gain per
+                # latent, up-projections to the heads ([q_nope ; q_rope]
+                # and [k_nope ; v] per head)
+                qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
+                layers["wq_a"] = norm_init(d ** -0.5, keys[1], (l, d, qr))
+                layers["q_a_norm"] = jnp.ones((l, qr), pdt)
+                layers["wq_b"] = norm_init(qr ** -0.5, keys[2],
+                                           (l, qr, nh, hd))
+                layers["wkv_a"] = norm_init(d ** -0.5, keys[3],
+                                            (l, d, kvr + cfg.rope_dim))
+                layers["kv_a_norm"] = jnp.ones((l, kvr), pdt)
+                layers["wkv_b"] = norm_init(
+                    kvr ** -0.5, jax.random.fold_in(keys[3], 1),
+                    (l, kvr, nh, cfg.qk_nope_head_dim + cfg.v_dim))
+            elif nkv == nh:
+                # QKV and gate/up projections are FUSED along an unsharded
+                # group axis (one wide MXU matmul instead of 3/2 narrow
+                # ones; slicing the group axis never crosses a shard
+                # boundary). MHA fuses q,k,v into wqkv[..., 3, nh, hd]; GQA
+                # keeps wq separate and fuses k,v.
+                layers["wqkv"] = jnp.stack(
+                    [norm_init(d ** -0.5, keys[1], (l, d, nh, hd)),
+                     norm_init(d ** -0.5, keys[2], (l, d, nh, hd)),
+                     norm_init(d ** -0.5, keys[3], (l, d, nh, hd))],
+                    axis=2)  # (l, d, 3, nh, hd)
+            else:
+                layers["wq"] = norm_init(d ** -0.5, keys[1], (l, d, nh, hd))
+                layers["wkv"] = jnp.stack(
+                    [norm_init(d ** -0.5, keys[2], (l, d, nkv, hd)),
+                     norm_init(d ** -0.5, keys[3], (l, d, nkv, hd))],
+                    axis=2)  # (l, d, 2, nkv, hd)
+            if cfg.qk_norm:
+                # RMSNorm gains over the whole q / k projection (all heads
+                # together), applied before the split into heads and RoPE
+                layers["q_norm"] = jnp.ones((l, nh * hd), pdt)
+                layers["k_norm"] = jnp.ones((l, nkv * hd), pdt)
+            return layers
+
+        def gated(keys, lead, width):
+            """A gated FFN's two leaves: gate and up fused, and down."""
+            return (jnp.stack(
+                [norm_init(d ** -0.5, keys[5], lead + (d, width)),
+                 norm_init(d ** -0.5, keys[6], lead + (d, width))],
+                axis=len(lead) + 1),  # lead + (d, 2, width)
+                norm_init(width ** -0.5, keys[7], lead + (width, d)))
+
+        keys = jax.random.split(key, 8)
+        l = cfg.n_layers - cfg.moe_dense_layers
+        layers = attention(l, keys)
         if cfg.moe_experts:
             # routed expert FFN (ops/moe.py): per-layer router + stacked
-            # expert weights, each expert gated like the dense MLP below;
-            # expert dim sharded over the "expert" axis
-            e = cfg.moe_experts
+            # expert weights (those held here), each expert gated like the
+            # dense MLP below; expert dim sharded over the "expert" axis
+            e, held = cfg.moe_experts, cfg.held_experts
             layers["w_router"] = norm_init(
                 0.02, jax.random.fold_in(key, 98),
                 (l, d, e)).astype(jnp.float32)
-            layers["w_moe_gateup"] = jnp.stack(
-                [norm_init(d ** -0.5, keys[5], (l, e, d, f)),
-                 norm_init(d ** -0.5, keys[6], (l, e, d, f))],
-                axis=3)  # (l, e, d, 2, f)
-            layers["w_moe_down"] = norm_init(
-                f ** -0.5, keys[7], (l, e, f, d))
+            layers["w_moe_gateup"], layers["w_moe_down"] = gated(
+                keys, (l, held), f)
+            if cfg.moe_scoring == "sigmoid":
+                # the choice bias: a buffer (Transformer.frozen), zero
+                # until whoever balances the load moves it
+                layers["router_bias"] = jnp.zeros((l, e), jnp.float32)
+            if cfg.moe_shared_experts:
+                shared = jax.random.split(jax.random.fold_in(key, 97), 8)
+                layers["w_shared_gateup"], layers["w_shared_down"] = gated(
+                    shared, (l,), cfg.moe_shared_experts * f)
         else:
-            layers["w_gateup"] = jnp.stack(
-                [norm_init(d ** -0.5, keys[5], (l, d, f)),
-                 norm_init(d ** -0.5, keys[6], (l, d, f))],
-                axis=2)  # (l, d, 2, f)
-            layers["w_down"] = norm_init(f ** -0.5, keys[7], (l, f, d))
-        if nkv == nh:
-            layers["wqkv"] = jnp.stack(
-                [norm_init(d ** -0.5, keys[1], (l, d, nh, hd)),
-                 norm_init(d ** -0.5, keys[2], (l, d, nh, hd)),
-                 norm_init(d ** -0.5, keys[3], (l, d, nh, hd))],
-                axis=2)  # (l, d, 3, nh, hd)
-        else:
-            layers["wq"] = norm_init(d ** -0.5, keys[1], (l, d, nh, hd))
-            layers["wkv"] = jnp.stack(
-                [norm_init(d ** -0.5, keys[2], (l, d, nkv, hd)),
-                 norm_init(d ** -0.5, keys[3], (l, d, nkv, hd))],
-                axis=2)  # (l, d, 2, nkv, hd)
-        if cfg.qk_norm:
-            # RMSNorm gains over the whole q / k projection (all heads
-            # together), applied before the split into heads and RoPE
-            layers["q_norm"] = jnp.ones((l, nh * hd), pdt)
-            layers["k_norm"] = jnp.ones((l, nkv * hd), pdt)
+            layers["w_gateup"], layers["w_down"] = gated(keys, (l,), f)
         params = {
             "embed": norm_init(0.02, keys[0], (cfg.vocab_size, d)),
             "layers": layers,
             "final_norm": jnp.ones((d,), pdt),
         }
+        if cfg.moe_dense_layers:
+            lead = jax.random.split(jax.random.fold_in(key, 96), 8)
+            dense = attention(cfg.moe_dense_layers, lead)
+            dense["w_gateup"], dense["w_down"] = gated(
+                lead, (cfg.moe_dense_layers,), cfg.moe_dense_ff or f)
+            params["dense_layers"] = dense
         if not cfg.tie_embeddings:
             params["lm_head"] = norm_init(
                 d ** -0.5, jax.random.fold_in(key, 99), (d, cfg.vocab_size))
@@ -149,36 +192,74 @@ class Transformer:
     @staticmethod
     def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         """Logical sharding spec tree, same structure as init()'s output."""
-        layers = {
-            "attn_norm": ("layers", "norm"),
-            "wo": ("layers", "heads", "head_dim", "embed"),
-            "mlp_norm": ("layers", "norm"),
-        }
+        def attention():
+            layers = {
+                "attn_norm": ("layers", "norm"),
+                "wo": ("layers", "heads", "head_dim", "embed"),
+                "mlp_norm": ("layers", "norm"),
+            }
+            if cfg.kv_lora_rank:
+                # the latents are narrow and every head reads all of them:
+                # down-projections shard with the model width, the
+                # up-projections by head
+                layers["wq_a"] = ("layers", "embed", None)
+                layers["q_a_norm"] = ("layers", "norm")
+                layers["wq_b"] = ("layers", None, "heads", "head_dim")
+                layers["wkv_a"] = ("layers", "embed", None)
+                layers["kv_a_norm"] = ("layers", "norm")
+                layers["wkv_b"] = ("layers", None, "heads", "head_dim")
+            elif cfg.kv_heads == cfg.n_heads:
+                layers["wqkv"] = ("layers", "embed", None, "heads",
+                                  "head_dim")
+            else:
+                layers["wq"] = ("layers", "embed", "heads", "head_dim")
+                layers["wkv"] = ("layers", "embed", None, "kv_heads",
+                                 "head_dim")
+            if cfg.qk_norm:
+                layers["q_norm"] = ("layers", "norm")
+                layers["k_norm"] = ("layers", "norm")
+            return layers
+
+        dense_ffn = {"w_gateup": ("layers", "embed", None, "mlp"),
+                     "w_down": ("layers", "mlp", "embed")}
+        layers = attention()
         if cfg.moe_experts:
             layers["w_router"] = ("layers", "embed", None)
             layers["w_moe_gateup"] = ("layers", "expert", "embed", None,
                                       "mlp")
             layers["w_moe_down"] = ("layers", "expert", "mlp", "embed")
+            if cfg.moe_scoring == "sigmoid":
+                layers["router_bias"] = ("layers", None)
+            if cfg.moe_shared_experts:
+                layers["w_shared_gateup"] = dense_ffn["w_gateup"]
+                layers["w_shared_down"] = dense_ffn["w_down"]
         else:
-            layers["w_gateup"] = ("layers", "embed", None, "mlp")
-            layers["w_down"] = ("layers", "mlp", "embed")
-        if cfg.kv_heads == cfg.n_heads:
-            layers["wqkv"] = ("layers", "embed", None, "heads", "head_dim")
-        else:
-            layers["wq"] = ("layers", "embed", "heads", "head_dim")
-            layers["wkv"] = ("layers", "embed", None, "kv_heads",
-                             "head_dim")
-        if cfg.qk_norm:
-            layers["q_norm"] = ("layers", "norm")
-            layers["k_norm"] = ("layers", "norm")
+            layers.update(dense_ffn)
         specs = {
             "embed": ("vocab", "embed"),
             "layers": layers,
             "final_norm": ("norm",),
         }
+        if cfg.moe_dense_layers:
+            specs["dense_layers"] = dict(attention(), **dense_ffn)
         if not cfg.tie_embeddings:
             specs["lm_head"] = ("embed", "vocab")
         return specs
+
+    @staticmethod
+    def frozen(cfg: TransformerConfig) -> Dict[str, Any]:
+        """Which leaves of init()'s tree are buffers and not parameters
+        (True): what `make_train_step(frozen=...)` keeps as it is, whatever
+        the gradient and the optimizer's weight decay. Today the sigmoid
+        router's choice bias (`e_score_correction_bias`)."""
+        import jax
+
+        specs = Transformer.param_specs(cfg)
+        mask = jax.tree.map(lambda _: False, specs,
+                            is_leaf=lambda x: isinstance(x, tuple))
+        if "router_bias" in mask["layers"]:
+            mask["layers"]["router_bias"] = True
+        return mask
 
     # ---- forward ----------------------------------------------------
     @staticmethod
@@ -236,7 +317,7 @@ class Transformer:
         if positions is None:
             positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
         with jax.named_scope("qkv"):
-            cos, sin = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+            cos, sin = _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
         layer = Transformer._remat(
             Transformer._make_layer_fn(cfg, mesh, rules, cos, sin), cfg)
         # the scan's own work (stacking and slicing saved activations,
@@ -254,9 +335,10 @@ class Transformer:
         and their grad are the biggest HBM tenant at GPT-2 scale).
         with_aux=True returns (hidden, aux_loss, routing): the MoE
         load-balancing loss over all layers (0 for dense FFN configs) and
-        the layers' stacked routing records (ops/moe.py `moe_ffn`:
-        `tokens_per_expert [layers, E]`, `router_prob [layers, E]`,
-        `dropped [layers]`; None for dense FFN configs).
+        the expert layers' stacked routing records (ops/moe.py `moe_ffn`:
+        `tokens_per_expert [layers, held]`, `slots_elsewhere [layers]`,
+        `router_prob [layers, E]`, `dropped [layers]`; None for dense FFN
+        configs). The sigmoid router has no aux loss: 0.
 
         When `mesh` is provided and cfg.attention_impl is ring/ulysses, the
         attention op runs inside shard_map over the "seq" axis; everything
@@ -267,11 +349,15 @@ class Transformer:
 
         rules = rules or ShardingRules()
         x = Transformer.embed(params, tokens, cfg, mesh=mesh, rules=rules)
+        if "dense_layers" in params:   # the leading run with a dense FFN
+            x, _ = Transformer._stack(
+                params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
+                positions=positions)
         x, routing = Transformer._stack(
             params["layers"], x, cfg, mesh=mesh, rules=rules,
             positions=positions)
         aux_total = jnp.zeros((), jnp.float32)
-        if cfg.moe_experts:
+        if cfg.moe_experts and cfg.moe_scoring == "softmax":
             # not a sum of per-layer terms: the published loss takes its
             # two means over the tokens of all layers together
             from ray_tpu.ops.moe import load_balancing_loss
@@ -302,13 +388,52 @@ class Transformer:
                                               seq_len=cos.shape[-2])
         scale = cfg.head_dim ** -0.5
 
+        def heads_constrained(q, k, v):
+            # GQA: k/v keep their true kv_heads width end-to-end — the
+            # attention ops broadcast per group internally (ring then
+            # rotates Hkv-wide tensors over ICI, not Hq-wide repeats)
+            return (constrain(q, ("batch", "seq", "heads", "head_dim")),
+                    constrain(k, ("batch", "seq", "kv_heads", "head_dim")),
+                    constrain(v, ("batch", "seq", "kv_heads", "head_dim")))
+
+        def latent_qkv(h, lp):
+            """Latent attention's q, k, v `[B, T, H, .]` from the normed
+            stream: each latent is down-projected and RMS-normed, the
+            heads are up-projected from it; the rotary key head comes
+            straight off the kv down-projection and is shared by all
+            heads; RoPE touches only the rotary columns."""
+            nope = cfg.qk_nope_head_dim
+            with jax.named_scope("q_down"):
+                c_q = _rmsnorm(jnp.einsum("btd,dr->btr", h,
+                                          lp["wq_a"].astype(cdt)),
+                               lp["q_a_norm"], cfg.norm_eps)
+            with jax.named_scope("kv_down"):
+                ckv = jnp.einsum("btd,dr->btr", h, lp["wkv_a"].astype(cdt))
+                c_kv = _rmsnorm(ckv[..., :cfg.kv_lora_rank],
+                                lp["kv_a_norm"], cfg.norm_eps)
+                k_rope = ckv[..., None, cfg.kv_lora_rank:]    # one head
+            with jax.named_scope("q_up"):
+                q = jnp.einsum("btr,rhk->bthk", c_q, lp["wq_b"].astype(cdt))
+            with jax.named_scope("kv_up"):
+                kv = jnp.einsum("btr,rhk->bthk", c_kv,
+                                lp["wkv_b"].astype(cdt))
+            with jax.named_scope("assemble"):
+                q = jnp.concatenate(
+                    [q[..., :nope], _rope(q[..., nope:], cos, sin)], axis=-1)
+                k_rope = jnp.broadcast_to(
+                    _rope(k_rope, cos, sin), q.shape[:3] + (cfg.rope_dim,))
+                k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+                return heads_constrained(q, k, kv[..., nope:])
+
         def layer(x, lp):
             # one jax.named_scope per block (module docstring): the names
             # reach every op's op_name, and so the device trace
             with jax.named_scope("attn_norm"):
                 h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
             with jax.named_scope("qkv"):
-                if cfg.kv_heads == cfg.n_heads:
+                if cfg.kv_lora_rank:
+                    q, k, v = latent_qkv(h, lp)
+                elif cfg.kv_heads == cfg.n_heads:
                     qkv = jnp.einsum("btd,dghk->btghk", h,
                                      lp["wqkv"].astype(cdt))
                     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
@@ -323,14 +448,9 @@ class Transformer:
                                  lp["q_norm"], cfg.norm_eps).reshape(q.shape)
                     k = _rmsnorm(k.reshape(k.shape[:2] + (-1,)),
                                  lp["k_norm"], cfg.norm_eps).reshape(k.shape)
-                q = _rope(q, cos, sin)
-                k = _rope(k, cos, sin)
-                # GQA: k/v keep their true kv_heads width end-to-end — the
-                # attention ops broadcast per group internally (ring then
-                # rotates Hkv-wide tensors over ICI, not Hq-wide repeats)
-                q = constrain(q, ("batch", "seq", "heads", "head_dim"))
-                k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
-                v = constrain(v, ("batch", "seq", "kv_heads", "head_dim"))
+                if not cfg.kv_lora_rank:
+                    q, k, v = heads_constrained(
+                        _rope(q, cos, sin), _rope(k, cos, sin), v)
             with jax.named_scope("attention"):
                 o = attn_fn(q, k, v, scale)
                 # name the (pallas) attention output so the "dots" remat
@@ -345,19 +465,30 @@ class Transformer:
 
             with jax.named_scope("mlp_norm"):
                 h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-            if cfg.moe_experts:
+            if "w_router" in lp:   # an expert layer, by its leaves
                 from ray_tpu.ops.moe import moe_ffn
 
-                # moe_ffn names its own four scopes under `moe/`
+                # moe_ffn names its own scopes under `moe/`
                 with jax.named_scope("moe/experts"):
                     experts = {
                         "w_router": lp["w_router"],
                         "w_gateup": lp["w_moe_gateup"].astype(cdt),
                         "w_down": lp["w_moe_down"].astype(cdt)}
+                if "router_bias" in lp:
+                    experts["router_bias"] = lp["router_bias"]
+                if "w_shared_gateup" in lp:
+                    with jax.named_scope("moe/shared"):
+                        experts["w_shared_gateup"] = \
+                            lp["w_shared_gateup"].astype(cdt)
+                        experts["w_shared_down"] = \
+                            lp["w_shared_down"].astype(cdt)
                 y, routing = moe_ffn(
                     experts, h.reshape(-1, h.shape[-1]),
                     num_selected=cfg.moe_top_k,
-                    norm_topk=cfg.moe_norm_topk, mesh=mesh, rules=rules)
+                    norm_topk=cfg.moe_norm_topk, scoring=cfg.moe_scoring,
+                    routed_scale=cfg.moe_routed_scale,
+                    expert_offset=cfg.moe_expert_offset, mesh=mesh,
+                    rules=rules)
                 with jax.named_scope("moe/combine"):
                     down = y.reshape(h.shape).astype(cdt)
                     x = x + constrain(down, ("batch", "seq", "act_embed"))
@@ -477,7 +608,9 @@ class Transformer:
         device = mesh.devices.flat[0] if mesh is not None \
             else jax.devices()[0]
         t = cfg.max_seq_len if seq_len is None else seq_len
+        # the kernel takes one head width for q, k and v
         return "flash" if device.platform == "tpu" and \
+            cfg.v_dim == cfg.head_dim and \
             flash_shape_ok(t, cfg.head_dim) else "dense"
 
     @staticmethod
@@ -579,9 +712,12 @@ class Transformer:
 
         with_metrics=True returns (loss, metrics), the pair
         `make_train_step` takes: its step's metrics then carry, from the
-        same forward pass, `moe_tokens_per_expert` (int32 [layers, E]),
-        `moe_dropped` (int32 scalar; 0 on the sorted path by construction)
-        and `moe_aux_loss`; an empty dict for a dense config."""
+        same forward pass, `moe_tokens_per_expert` (int32 [expert layers,
+        held experts]), `moe_dropped` (int32 scalar; 0 on the sorted path
+        by construction), `moe_aux_loss` and, where only a share of the
+        experts is held, `moe_slots_elsewhere` (int32 [expert layers]:
+        slots routed to experts held elsewhere); an empty dict for a dense
+        config."""
         import jax
         import jax.numpy as jnp
 
@@ -595,7 +731,7 @@ class Transformer:
         with jax.named_scope("loss"):
             loss_val = total / (targets.size if mask is None
                                 else jnp.maximum(jnp.sum(mask), 1.0))
-        if cfg.moe_experts:
+        if cfg.moe_experts and cfg.moe_scoring == "softmax":
             loss_val = loss_val + cfg.moe_aux_coeff * aux
         return Transformer._loss_out(loss_val, aux, routing, cfg,
                                      with_metrics)
@@ -607,7 +743,10 @@ class Transformer:
             return loss_val
         if not cfg.moe_experts:
             return loss_val, {}
-        return loss_val, {
+        metrics = {
             "moe_tokens_per_expert": routing["tokens_per_expert"],
             "moe_dropped": routing["dropped"].sum(),
             "moe_aux_loss": aux}
+        if cfg.held_experts < cfg.moe_experts:
+            metrics["moe_slots_elsewhere"] = routing["slots_elsewhere"]
+        return loss_val, metrics

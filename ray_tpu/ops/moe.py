@@ -2,33 +2,61 @@
 
 The reference has no in-tree MoE/expert parallelism (SURVEY.md §2.4 "EP:
 Absent"); this is the TPU-native capability filling that row. One expert
-definition, `silu(x W_gate) * (x W_up)` then `W_down`, the dense MLP's,
-and two lowerings of the dispatch, chosen by what the code sees at trace
+definition, `silu(x W_gate) * (x W_up)` then `W_down`, the dense MLP's.
+
+Two routers (`route`, float32 whatever the compute dtype): **softmax**
+probabilities, top-k of them, optionally renormalised, with the
+load-balancing loss (`load_balancing_loss`); **sigmoid** scores, the
+choice by score + a per-expert bias that is a buffer (no gradient reaches
+it: the weights are the chosen scores without it), renormalised,
+times a scaling factor, no aux loss.
+
+**A share of the experts.** `moe_ffn` is told which experts it holds by
+what it is given: `w_gateup` / `w_down` carry the held experts only (a
+contiguous run that starts at `expert_offset`), the router keeps all E
+outputs and its k a token. It computes the held experts' part of the
+result for the token-slots routed to them; slots routed to an expert held
+elsewhere run through nothing here, add nothing, and are counted
+(`slots_elsewhere`), not dropped: they are the work of the chips that
+hold those experts, and no code here stands in for them or their traffic.
+With every expert held this is the whole layer. A **shared expert**
+(`w_shared_gateup`, `w_shared_down`) is one more gated FFN that every
+token passes, added to the routed sum.
+
+Two lowerings of the dispatch, chosen by what the code sees at trace
 time (no option):
 
 - **sorted, dropless** (no mesh, or an `expert` mesh axis of 1): the N·k
-  token-slots are sorted by expert id (`argsort`), counted (`bincount`),
-  gathered into expert order, run through two grouped matmuls over the
-  ragged groups (`grouped_matmul_impl`: pallas `megablox.gmm` on a TPU
-  where its tiles divide the shapes, else `jax.lax.ragged_dot`), and
-  gathered back by the inverse permutation into a weighted sum over each
-  token's k slots. No token is ever dropped, and nothing is larger than
-  `[N·k, max(d, 2f)]`: the only `[N, E]` tensors are the router's logits
-  and probabilities. Both permutations are gathers in the backward pass
-  too (`_permutes`: a permutation's transpose is its inverse), so the
-  step has no scatter.
+  token-slots are sorted by expert id, the held experts first (`sort`),
+  counted (a binary search per expert), gathered into expert order, run
+  through two grouped matmuls over the ragged groups
+  (`grouped_matmul_impl`: pallas `megablox.gmm` on a TPU where its tiles
+  fit the shapes, else `jax.lax.ragged_dot`), and gathered back by the
+  inverse permutation into a weighted sum over each token's k slots. No
+  token is ever dropped, and nothing is larger than `[N·k, max(d, 2f)]`:
+  the only `[N, E]` tensors are the router's logits and scores. The
+  shapes are static, N·k rows whatever the share; the grouped matmuls'
+  work follows the slots received: `megablox.gmm` takes the group sizes
+  of all E experts with the held experts' weights and visits only the row
+  tiles of the groups it holds (rows of other groups come back zero);
+  `ragged_dot` gets the leading groups' rows and the rest are masked.
+  The kernel's tiles follow from each call's shapes (`gmm_tiles`). Both
+  permutations are gathers in the backward pass too (`_permutes`: a
+  permutation's transpose is its inverse), so the step has no scatter.
 - **capacity** (an `expert` mesh axis above 1; today the virtual-CPU
-  tests): Switch/GShard dispatch and combine as einsums over an
-  `[N, E, C]` one-hot, which GSPMD partitions over the expert axis (the
-  einsums lower to all-to-alls). Tokens over an expert's capacity are
-  dropped. `[N, E, C]` is 10.7 GB at 16,384 tokens, 64 experts, top-8:
-  this branch is for small expert-parallel meshes until the sorted path
-  runs under `shard_map` with an all-to-all (ROADMAP R2).
+  tests; every expert held, no shared expert): Switch/GShard dispatch and
+  combine as einsums over an `[N, E, C]` one-hot, which GSPMD partitions
+  over the expert axis (the einsums lower to all-to-alls). Tokens over an
+  expert's capacity are dropped. `[N, E, C]` is 10.7 GB at 16,384 tokens,
+  64 experts, top-8: this branch is for small expert-parallel meshes
+  until the sorted path runs under `shard_map` with an all-to-all
+  (ROADMAP R2).
 
 Scopes inside the caller's `moe` (PERF.md section 3): `moe/router`
-(logits, softmax, top-k), `moe/dispatch` (sort, counts, gather),
+(logits, scores, top-k), `moe/dispatch` (sort, counts, gather),
 `moe/experts` (the grouped matmuls and silu-mul), `moe/combine` (the
-gather back and the weighted sum; the caller adds the residual there).
+gather back and the weighted sum; the caller adds the residual there),
+`moe/shared` (the shared expert).
 """
 
 from __future__ import annotations
@@ -67,21 +95,36 @@ def init_moe_params(key, d_model: int, d_ff: int, n_experts: int
     }
 
 
-def route(w_router, x, num_selected: int, norm_topk: bool):
+def route(w_router, x, num_selected: int, norm_topk: bool, *,
+          scoring: str = "softmax", bias=None, routed_scale: float = 1.0):
     """Router in float32 whatever the compute dtype (a rounded logit
-    changes WHICH experts a token gets, not only by how much):
-    probabilities `[N, E]`, the top-k weights and expert ids `[N, k]`."""
+    changes WHICH experts a token gets, not only by how much): the scores
+    `[N, E]` (softmax probabilities, or independent sigmoids), the top-k
+    weights and expert ids `[N, k]`. With a `bias` `[E]` the choice is the
+    top-k of score + bias and the weights are the chosen scores without
+    it; the bias is a buffer, no gradient reaches it."""
     import jax
     import jax.numpy as jnp
 
     logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
                         w_router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, num_selected)
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    probs = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if bias is None:
+        top_w, top_e = jax.lax.top_k(probs, num_selected)
+    else:
+        _, top_e = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+            num_selected)
+        top_w = jnp.take_along_axis(probs, top_e, axis=-1)
     if norm_topk:
         top_w = top_w / jnp.maximum(
             top_w.sum(axis=-1, keepdims=True), 1e-9)
+    if routed_scale != 1.0:
+        top_w = top_w * routed_scale
     return probs, top_w, top_e
 
 
@@ -156,25 +199,37 @@ def _permutes():
     return slots_of, combine
 
 
-# megablox tiles (rows, contraction, columns) of the grouped matmul: the
-# fastest of those tried on the v5e that fit its VMEM (PERF.md section 6,
-# PR 27)
-GMM_TILING = (512, 1024, 1024)
+# megablox tiles (rows, contraction, columns) of the grouped matmul. Rows
+# and the widest tile are the fastest of those tried on the v5e that fit
+# its VMEM (PERF.md section 6, PR 27); a call whose contraction or columns
+# 1024 does not divide (an expert width of 1536) takes the largest
+# multiple of 128 below it that does.
+GMM_ROWS = 512
+GMM_TILES = (1024, 768, 512, 384, 256, 128)
+
+
+def gmm_tiles(m: int, k: int, n: int):
+    """The tiles of one `megablox` call from its own shapes (the kernel's
+    forward and its two transposes each look theirs up), None where no
+    tile divides."""
+    def tile(width: int):
+        return next((t for t in GMM_TILES if width % t == 0), None)
+
+    tk, tn = tile(k), tile(n)
+    return (GMM_ROWS, tk, tn) if m % GMM_ROWS == 0 and tk and tn else None
 
 
 def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int) -> str:
     """`megablox` (the pallas grouped matmul that ships with JAX) where
-    the program runs on one TPU device and the kernel's tiles divide the
-    expert FFN's shapes, else `ragged_dot` (`jax.lax.ragged_dot`: any
-    platform, any shape, and GSPMD can partition it, which it cannot a
-    pallas call). Decided at trace time, like
-    `Transformer.resolve_attention_impl`."""
+    the program runs on one TPU device and the kernel's tiles fit the
+    expert FFN's shapes (`gmm_tiles`), else `ragged_dot`
+    (`jax.lax.ragged_dot`: any platform, any shape, and GSPMD can
+    partition it, which it cannot a pallas call). Decided at trace time,
+    like `Transformer.resolve_attention_impl`."""
     import jax
 
-    tm, tk, tn = GMM_TILING
-    tiles = rows % tm == 0 and all(
-        width % tile == 0 for width in (d_model, d_ff, 2 * d_ff)
-        for tile in (tk, tn))
+    tiles = gmm_tiles(rows, d_model, 2 * d_ff) and gmm_tiles(
+        rows, d_ff, d_model)
     device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
     one_device = mesh is None or mesh.size == 1
     return "megablox" if device.platform == "tpu" and one_device and tiles \
@@ -183,34 +238,43 @@ def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int) -> str:
 
 def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot"):
     """The gated expert FFN over rows already in expert order: xs
-    `[M, d]`, group_sizes `[E]` summing to M; w_gateup `[E, d, 2, f]`,
-    w_down `[E, f, d]`."""
+    `[M, d]`, group_sizes `[E]` summing to M; w_gateup `[H, d, 2, f]`,
+    w_down `[H, f, d]` for the H <= E experts whose groups come first.
+    Rows of the other groups come back zero."""
     import jax
 
+    held, d, _two, f = w_gateup.shape
     if impl == "megablox":
         from jax.experimental.pallas.ops.tpu.megablox import ops
 
+        # fewer weights than groups: the kernel visits the row tiles of
+        # the groups it has weights for and zeroes the other rows
         def grouped(lhs, rhs):
-            return ops.gmm(lhs, rhs, group_sizes, lhs.dtype, GMM_TILING)
+            return ops.gmm(lhs, rhs, group_sizes, lhs.dtype, gmm_tiles)
     else:
+        if held < group_sizes.shape[0]:
+            group_sizes = group_sizes[:held]   # rows past them: zero
+
         def grouped(lhs, rhs):
             return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
-    n_experts, d, _two, f = w_gateup.shape
-    gu = grouped(xs, w_gateup.reshape(n_experts, d, 2 * f))
+    gu = grouped(xs, w_gateup.reshape(held, d, 2 * f))
     h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
     return grouped(h, w_down)
 
 
-def _sorted_ffn(params, x, top_w, top_e, mesh):
+def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0):
     import jax
     import jax.numpy as jnp
 
     slots_of, combine = _permutes()
     k = top_e.shape[1]
-    n_experts, d_model, _two, d_ff = params["w_gateup"].shape
+    n_experts = params["w_router"].shape[1]
+    held, d_model, _two, d_ff = params["w_gateup"].shape
     with jax.named_scope("moe/dispatch"):
         slot_expert = top_e.reshape(-1)                  # [N·k]
+        if expert_offset:   # the held experts' groups first
+            slot_expert = (slot_expert - expert_offset) % n_experts
         sorted_expert, order = jax.lax.sort(
             (slot_expert, jnp.arange(slot_expert.size, dtype=jnp.int32)),
             num_keys=1, is_stable=True)
@@ -227,6 +291,8 @@ def _sorted_ffn(params, x, top_w, top_e, mesh):
             grouped_matmul_impl(mesh, xs.shape[0], d_model, d_ff))
     with jax.named_scope("moe/combine"):
         y = combine(ys, top_w, order, inverse)
+    if held < n_experts:
+        counts = counts[:held]
     return y, counts, jnp.zeros((), jnp.int32)
 
 
@@ -268,47 +334,85 @@ def _capacity_ffn(params, x, top_w, top_e, capacity_factor, constrain):
     return y.astype(x.dtype), counts, dropped
 
 
+def shared_ffn(w_gateup, w_down, x):
+    """The shared expert: one gated FFN on every row of x `[N, d]`;
+    w_gateup `[d, 2, f]`, w_down `[f, d]` in the compute dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    gu = jnp.einsum("nd,dgf->ngf", x, w_gateup)
+    return jnp.einsum("nf,fd->nd", jax.nn.silu(gu[:, 0]) * gu[:, 1], w_down)
+
+
 def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
-            norm_topk: bool = True, capacity_factor: float = 1.25,
+            norm_topk: bool = True, scoring: str = "softmax",
+            routed_scale: float = 1.0, expert_offset: int = 0,
+            capacity_factor: float = 1.25,
             mesh=None, rules: Optional[ShardingRules] = None
             ) -> Tuple[Any, Dict[str, Any]]:
     """Top-k routed gated-expert FFN.
 
     x: `[tokens, d_model]` (flatten `[B, T, D]` before calling); params:
-    `w_router [d, E]` (used in float32), `w_gateup [E, d, 2, f]`,
-    `w_down [E, f, d]` in the compute dtype. Returns `(y, routing)`:
-    y `[tokens, d_model]` in x's dtype and the layer's routing record
+    `w_router [d, E]` (used in float32) and, where the router has one,
+    `router_bias [E]`; `w_gateup [H, d, 2, f]`, `w_down [H, f, d]` in the
+    compute dtype, the H <= E experts held here, experts `expert_offset`
+    to `expert_offset + H`; optionally the shared expert's
+    `w_shared_gateup [d, 2, fs]`, `w_shared_down [fs, d]`. Returns
+    `(y, routing)`: y `[tokens, d_model]` in x's dtype, the held experts'
+    weighted outputs for the slots routed to them plus the shared expert,
+    and the layer's routing record
 
-        tokens_per_expert  int32 [E]  slots routed to each expert (sums
-                                      to tokens x k, drops included)
-        router_prob        f32 [E]    mean router probability
-        dropped            int32 []   slots that ran through no expert
+        tokens_per_expert  int32 [H]  slots routed to each held expert
+        slots_elsewhere    int32 []   slots routed to experts not held
+                                      (with the above: tokens x k)
+        router_prob        f32 [E]    mean router score
+        dropped            int32 []   slots of held experts that ran
+                                      through none
 
-    from which `load_balancing_loss` makes the aux loss. The top-k
-    weights are renormalised to sum to 1 only if `norm_topk`.
+    from which `load_balancing_loss` makes the softmax router's aux loss.
+    The top-k weights are renormalised to sum to 1 only if `norm_topk`;
+    `scoring`, the bias and `routed_scale` are `route`'s.
 
     The sorted dropless path runs unless `mesh` has an `expert` axis
     above 1 (module docstring); `capacity_factor` applies to that
     expert-parallel branch only, where `dropped` can be above 0.
     """
     import jax
+    import jax.numpy as jnp
 
     rules = rules or ShardingRules()
-    k = min(num_selected, params["w_router"].shape[1])
+    n_experts = params["w_router"].shape[1]
+    held = params["w_gateup"].shape[0]
+    k = min(num_selected, n_experts)
     with jax.named_scope("moe/router"):
-        probs, top_w, top_e = route(params["w_router"], x, k, norm_topk)
+        probs, top_w, top_e = route(
+            params["w_router"], x, k, norm_topk, scoring=scoring,
+            bias=params.get("router_bias"), routed_scale=routed_scale)
         router_prob = probs.mean(axis=0)
     expert_parallel = mesh is not None and spec_entry_size(
         rules.mesh_axes("expert"), mesh) > 1
     if expert_parallel:
+        if held != n_experts:
+            raise ValueError("an expert mesh axis shards all the experts; "
+                             "a held share runs without it")
         constrain = functools.partial(with_logical_constraint, mesh=mesh,
                                       rules=rules)
         y, counts, dropped = _capacity_ffn(params, x, top_w, top_e,
                                            capacity_factor, constrain)
     else:
-        y, counts, dropped = _sorted_ffn(params, x, top_w, top_e, mesh)
-    return y, {"tokens_per_expert": counts, "router_prob": router_prob,
-               "dropped": dropped}
+        y, counts, dropped = _sorted_ffn(params, x, top_w, top_e, mesh,
+                                         expert_offset)
+    if "w_shared_gateup" in params:
+        with jax.named_scope("moe/shared"):
+            y = y + shared_ffn(params["w_shared_gateup"],
+                               params["w_shared_down"], x)
+    if held == n_experts:
+        elsewhere = jnp.zeros((), jnp.int32)
+    else:
+        with jax.named_scope("moe/router"):
+            elsewhere = x.shape[0] * k - counts.sum()
+    return y, {"tokens_per_expert": counts, "slots_elsewhere": elsewhere,
+               "router_prob": router_prob, "dropped": dropped}
 
 
 def moe_ffn_dense_reference(params: Dict[str, Any], x, *,

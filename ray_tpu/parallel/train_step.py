@@ -31,6 +31,7 @@ def make_train_step(
         # seq-divisible inputs can pass ("batch", "seq").
         batch_logical: Tuple[Optional[str], ...] = ("batch", None),
         donate: bool = True,
+        frozen: Any = None,
 ) -> Tuple[Callable, Callable]:
     """Build (init_state, train_step), both jitted with explicit shardings.
 
@@ -40,6 +41,11 @@ def make_train_step(
     token counts reach the loop from the step's own forward pass).
     init_state(params) -> state dict; train_step(state, batch) ->
     (state, metrics); train_step.lower(state, batch) -> jax Lowered.
+
+    `frozen`: a tree of bools shaped like the params, True for a leaf that
+    is a buffer and not a parameter (`Transformer.frozen(cfg)`: a sigmoid
+    router's choice bias). Such a leaf leaves a step bit for bit as it
+    entered it, whatever its gradient and the optimizer's weight decay.
     """
     import jax
     import jax.numpy as jnp
@@ -108,6 +114,10 @@ def make_train_step(
             updates, opt_state = optimizer.update(
                 grads, state["opt_state"], state["params"])
             params = optax.apply_updates(state["params"], updates)
+            if frozen is not None:
+                params = jax.tree.map(
+                    lambda new, old, keep: old if keep else new,
+                    params, state["params"], frozen)
             gnorm = optax.global_norm(grads)
         new_state = {
             "params": params,

@@ -299,3 +299,74 @@ def test_olmoe_layer_step_compiles_with_the_grouped_matmul_kernels(v5e):
     bad = [s for s in shapes if cfg.moe_experts in s and len(s) > 2
            and (tokens in s or slots in s)]
     assert not bad, bad
+
+
+def test_latent_attention_share_step_compiles_for_the_v5e(v5e):
+    """One dense and one expert layer of GLM-4.7-Flash's widths (latent
+    attention at 20 heads of 192 + 64 / 256, 8 of 64 experts of width 1536
+    held, a shared expert) + head, as one train step for the v5e: splash's
+    kernels at head_dim 256, `megablox` at the width 1024 does not divide
+    (tiles from each call's shapes), held weights only in the grouped
+    matmuls, and the new scopes on what the compiler leaves."""
+    import re
+
+    import optax
+
+    from ray_tpu.models import Transformer
+    from ray_tpu.models.configs import TransformerConfig
+    from ray_tpu.ops.moe import grouped_matmul_impl
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.parallel.train_step import make_train_step
+
+    seq, rows = 1024, 1
+    cfg = TransformerConfig(
+        vocab_size=19360, d_model=2048, n_layers=2, n_heads=20, d_ff=1536,
+        max_seq_len=seq, rope_theta=1e6, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        moe_experts=64, moe_top_k=4, moe_scoring="sigmoid",
+        moe_routed_scale=1.8, moe_shared_experts=1, moe_dense_layers=1,
+        moe_dense_ff=10240, moe_experts_held=8, moe_aux_coeff=0.0,
+        attention_impl="auto", dtype="bfloat16", param_dtype="float32",
+        remat=True, loss_chunk=256)
+    mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
+    assert Transformer.resolve_attention_impl(cfg, mesh, seq) == "flash"
+    slots = rows * seq * cfg.moe_top_k
+    assert grouped_matmul_impl(mesh, slots, cfg.d_model, cfg.ff_dim) == \
+        "megablox"
+    optimizer = optax.adamw(3e-4, weight_decay=0.01)
+    _, train_step = make_train_step(
+        lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh,
+                                      with_metrics=True),
+        Transformer.param_specs(cfg), mesh, optimizer=optimizer,
+        frozen=Transformer.frozen(cfg))
+
+    def init(key):
+        params = Transformer.init(key, cfg)
+        return {"params": params, "opt_state": optimizer.init(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    state = jax.eval_shape(init, jax.random.key(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, seq + 1), jnp.int32)}
+    hlo = train_step.lower(state, batch).compile().as_text()
+    hlo = re.sub(r"kernel_metadata=\{\n[^\n]*\n\}", "kernel_metadata={}", hlo)
+
+    kernels = re.findall(
+        r'%([\w.\-]+) = ([^\n]*)custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', hlo)
+    names = [name for name, _, _ in kernels]
+    grouped = [(n, text, op) for n, text, op in kernels
+               if re.match(r"t?gmm(\.\d+)?$", n)]   # text: the call's type
+    assert sum(n.startswith("gmm") for n, _, _ in grouped) == 6, names
+    assert sum(n.startswith("tgmm") for n, _, _ in grouped) == 2, names
+    assert all("moe/experts" in op for _, _, op in grouped), grouped
+    # the weights of the 8 held experts reach the kernels, never 64
+    assert re.search(r"bf16\[8,2048,3072\]", hlo)
+    assert re.search(r"bf16\[8,1536,2048\]", hlo)
+    assert not re.search(r"\[64,(2048|1536),", hlo)
+    # two layers: forward, remat's forward and the fused backward, each
+    assert sum(n.startswith("splash_mha_fwd") for n in names) == 4, names
+    assert sum(n.startswith("splash_mha_dkv") for n in names) == 2, names
+    for scope in ("qkv/q_down", "qkv/kv_down", "qkv/q_up", "qkv/kv_up",
+                  "qkv/assemble", "moe/shared", "moe/router", "moe/dispatch",
+                  "moe/experts", "moe/combine", "mlp/gate_up", "mlp/down"):
+        assert re.search(r'op_name="[^"]*[/(]' + scope + r'[/)]', hlo), scope
