@@ -49,11 +49,15 @@ class TransformerConfig:
     dtype: Any = "bfloat16"
     param_dtype: Any = "float32"
     remat: bool = False                   # jax.checkpoint each layer
-    # "full": recompute the whole layer in bwd (min memory, +1 fwd pass);
-    # "dots": save matmul outputs, recompute only elementwise chains
-    # (near-zero recompute FLOPs — fastest when activations fit). Any
+    # what a checkpointed layer keeps for its backward pass besides the
+    # scan's carry (Transformer._remat). "attention": the flash forward
+    # kernel's output and logsumexp, so the kernel is not run again (a
+    # layer without the flash kernel names nothing and is "full"'s
+    # program); "full": nothing, the whole layer is recomputed (min
+    # memory, +1 fwd pass); "dots": "attention" and every matmul's output
+    # (near-zero recompute FLOPs, fastest when activations fit). Any
     # other value raises.
-    remat_policy: str = "full"
+    remat_policy: str = "attention"
     # chunk the lm-head + cross-entropy over the sequence axis so the
     # [B,T,vocab] f32 logits (+grad) never materialize at once; 0 = off.
     loss_chunk: int = 256

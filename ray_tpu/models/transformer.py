@@ -289,19 +289,35 @@ class Transformer:
     @staticmethod
     def _remat(layer, cfg: TransformerConfig):
         """`layer` under cfg's rematerialization: the one place a layer is
-        wrapped in `jax.checkpoint`."""
+        wrapped in `jax.checkpoint`.
+
+        The default, "attention", keeps per layer what the flash forward
+        kernel hands its backward (`ops.attention.FLASH_RESIDUALS`: the
+        output [B, H, T, Dv] in the compute dtype and the logsumexp
+        [B, H, T] in f32) beside the scan's carry, and recomputes the rest
+        of the layer (norms, projections, RoPE, the MLP or the experts).
+        The backward pass then runs the forward kernel once a step, not
+        twice. The rule adapts by what the traced layer holds: only the
+        flash path names those values, so under `dense`, `ring` or
+        `ulysses` attention nothing is named, nothing is saved and the
+        program is "full"'s. At many layers it costs L x B*T*H*Dv x 2
+        bytes (+ L x B*H*T x 4) more than "full", which saves nothing but
+        the carry and is there for whoever needs those bytes. "dots" saves
+        every matmul's output besides."""
         import jax
 
-        if cfg.remat_policy not in ("full", "dots"):
+        from ray_tpu.ops.attention import FLASH_RESIDUALS
+
+        policies = jax.checkpoint_policies
+        residuals = policies.save_only_these_names(FLASH_RESIDUALS)
+        known = {"attention": residuals, "full": None,
+                 "dots": policies.save_from_both_policies(
+                     policies.checkpoint_dots, residuals)}
+        if cfg.remat_policy not in known:
             raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
         if not cfg.remat:
             return layer
-        if cfg.remat_policy == "full":
-            return jax.checkpoint(layer)
-        policies = jax.checkpoint_policies
-        return jax.checkpoint(layer, policy=policies.save_from_both_policies(
-            policies.checkpoint_dots,
-            policies.save_only_these_names("attn_out")))
+        return jax.checkpoint(layer, policy=known[cfg.remat_policy])
 
     @staticmethod
     def _stack(layers, x, cfg: TransformerConfig, *, mesh,
@@ -453,11 +469,6 @@ class Transformer:
                         _rope(q, cos, sin), _rope(k, cos, sin), v)
             with jax.named_scope("attention"):
                 o = attn_fn(q, k, v, scale)
-                # name the (pallas) attention output so the "dots" remat
-                # policy can save it — it isn't a dot, and recomputing the
-                # kernel in bwd costs a full extra attention pass
-                from jax.ad_checkpoint import checkpoint_name
-                o = checkpoint_name(o, "attn_out")
             with jax.named_scope("attn_out"):
                 o = constrain(o, ("batch", "seq", "heads", "head_dim"))
                 o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cdt))

@@ -107,6 +107,14 @@ def _splash_block_sizes(t: int, head_dim: int):
         use_fused_bwd_kernel=True)
 
 
+# What the forward kernel hands its backward, by `checkpoint_name`: the
+# output [H, T, Dv] in the compute dtype and the logsumexp [H, T] in f32,
+# one sequence each. A `jax.checkpoint` whose policy saves this name keeps
+# both, and its backward pass does not run the forward kernel again
+# (`Transformer._remat`).
+FLASH_RESIDUALS = "flash_residuals"
+
+
 def _splash_attention(q, k, v, *, causal: bool, scale: float,
                       interpret: bool = False):
     """`flash_attention`'s body; `interpret` runs the kernels in pallas
@@ -131,7 +139,8 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
     kernel = make_splash_mha(
         MultiHeadMask([head_mask] * h),
         block_sizes=_splash_block_sizes(t, d), head_shards=1,
-        q_seq_shards=1, interpret=interpret)
+        q_seq_shards=1, interpret=interpret,
+        residual_checkpoint_name=FLASH_RESIDUALS)
     # the kernel takes no scale (folded into q, rounded once) and one
     # sequence [H, T, D] at a time; K and V keep their own head count,
     # q head i reading kv head i // (H // Hkv) as gqa_scores does

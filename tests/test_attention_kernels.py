@@ -109,3 +109,66 @@ def test_kv_heads_must_divide_query_heads():
     kv = jnp.zeros((1, 128, 4, 128), jnp.bfloat16)
     with pytest.raises(ValueError, match="divide"):
         flash_attention(q, kv, kv)
+
+
+def _pallas_calls(jaxpr):
+    """The `pallas_call` equations of a jaxpr, those of its sub-jaxprs
+    (scan bodies, remat's recomputation) included."""
+    def subjaxprs(value):
+        if isinstance(value, (list, tuple)):
+            for item in value:
+                yield from subjaxprs(item)
+        elif hasattr(value, "eqns"):
+            yield value
+        elif hasattr(value, "jaxpr"):
+            yield value.jaxpr
+
+    return sum(
+        (eqn.primitive.name == "pallas_call") + sum(
+            _pallas_calls(sub) for value in eqn.params.values()
+            for sub in subjaxprs(value))
+        for eqn in jaxpr.eqns)
+
+
+def test_the_layers_remat_keeps_the_forward_kernels_output():
+    """Two layers of q/k/v projections, the kernel and `wo` as one scan
+    under `Transformer._remat`: the default policy saves what the forward
+    kernel names (`FLASH_RESIDUALS`), so the gradient holds the forward
+    and the backward kernel and not the forward a second time, and the
+    saved output is the recomputed one, bit for bit."""
+    from ray_tpu.models import Transformer
+    from ray_tpu.models.configs import TransformerConfig
+
+    layers, d_model, heads, kv_heads, seq = 2, 64, 2, 1, 256
+    keys = jax.random.split(jax.random.key(34), 5)
+    shapes = {"wq": (layers, d_model, heads, HEAD_DIM),
+              "wk": (layers, d_model, kv_heads, HEAD_DIM),
+              "wv": (layers, d_model, kv_heads, HEAD_DIM),
+              "wo": (layers, heads, HEAD_DIM, d_model)}
+    params = {name: jax.random.normal(k, s, jnp.float32) * s[1] ** -0.5
+              for k, (name, s) in zip(keys, shapes.items())}
+    x = jax.random.normal(keys[4], (1, seq, d_model), jnp.float32)
+
+    def layer(x, lp):
+        q, k, v = (jnp.einsum("btd,dhk->bthk", x, lp[w])
+                   for w in ("wq", "wk", "wv"))
+        o = _splash_attention(q, k, v, causal=True, scale=HEAD_DIM ** -0.5,
+                              interpret=True)
+        return x + jnp.einsum("bthk,hkd->btd", o, lp["wo"]), None
+
+    def run(**policy):
+        """((loss, gradients), pallas calls in their jaxpr)."""
+        wrapped = Transformer._remat(layer, TransformerConfig(
+            vocab_size=8, d_model=d_model, n_layers=layers, n_heads=heads,
+            d_ff=8, remat=True, **policy))
+        fn = jax.value_and_grad(
+            lambda p, x: jnp.sum(jax.lax.scan(wrapped, x, p)[0] ** 2),
+            argnums=(0, 1))
+        return (jax.jit(fn)(params, x),
+                _pallas_calls(jax.make_jaxpr(fn)(params, x).jaxpr))
+
+    got, got_calls = run()
+    want, want_calls = run(remat_policy="full")
+    assert (got_calls, want_calls) == (2, 3)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b))

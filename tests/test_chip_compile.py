@@ -163,11 +163,38 @@ def test_explicit_flash_on_an_unsupported_shape_raises():
     assert jnp.isfinite(Transformer.apply(params, tokens, auto)).all()
 
 
-def test_scopes_survive_the_v5e_compiler(v5e):
-    """One Mistral-width layer and the head, the whole train step: after
-    the TPU compiler's fusion the ops still carry the program's scope
-    vocabulary (PERF.md section 3) in their op_name — at least 90% of the
-    fusions that run as ops of their own, and every Pallas kernel call."""
+# what `remat=True` saves (Transformer._remat): the default policy and the
+# save-nothing baseline, with the forward kernel's calls per layer scan
+REMAT_POLICIES = [pytest.param({}, 1, id="default"),
+                  pytest.param({"remat_policy": "full"}, 2, id="full")]
+
+
+def assert_saved_residuals(hlo, policy, stacked):
+    """Under the default policy the scan stacks the forward kernel's output
+    `[L, B, H, T, Dv]` in the compute dtype and its logsumexp `[L, B, H, T]`
+    in f32, the narrow form (a JAX that named the logsumexp before its
+    slice would stack `[..., T, 128]`); under "full" neither exists."""
+    import re
+
+    layers, b, h, t, dv = stacked
+    out = re.escape(f"bf16[{layers},{b},{h},{t},{dv}]")
+    lse = re.escape(f"f32[{layers},{b},{h},{t}]")
+    wide = re.escape(f"f32[{layers},{b},{h},{t},128]")
+    saved = not policy
+    assert bool(re.search(out + r"[{ ]", hlo)) == saved, out
+    assert bool(re.search(lse + r"[{ ]", hlo)) == saved, lse
+    assert not re.search(wide + r"[{ ]", hlo), wide
+
+
+@pytest.mark.parametrize("policy,fwd_calls", REMAT_POLICIES)
+def test_scopes_survive_the_v5e_compiler(v5e, policy, fwd_calls):
+    """Two Mistral-width layers (one scan) and the head, the whole train
+    step: after the TPU compiler's fusion the ops still carry the
+    program's scope vocabulary (PERF.md section 3) in their op_name — at
+    least 90% of the fusions that run as ops of their own, and every
+    Pallas kernel call.
+    The layer's remat keeps the forward kernel's output: one forward call
+    and the one backward call, where "full" runs the forward twice."""
     import re
 
     import optax
@@ -179,10 +206,10 @@ def test_scopes_survive_the_v5e_compiler(v5e):
 
     seq = 512   # the widths are what fusion decides on; compiles in 12 s
     cfg = TransformerConfig(
-        vocab_size=32000, d_model=4096, n_layers=1, n_heads=32,
+        vocab_size=32000, d_model=4096, n_layers=2, n_heads=32,
         n_kv_heads=8, d_ff=14336, max_seq_len=seq, norm_eps=1e-5,
         tie_embeddings=False, attention_impl="flash", dtype="bfloat16",
-        param_dtype="float32", remat=True, loss_chunk=256)
+        param_dtype="float32", remat=True, loss_chunk=256, **policy)
     mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
     optimizer = optax.adamw(3e-4, weight_decay=0.01)
     _, train_step = make_train_step(
@@ -212,7 +239,7 @@ def test_scopes_survive_the_v5e_compiler(v5e):
     # an instruction inside a computation that a fusion calls is part of
     # that fusion, not an op that runs (and shows in a trace) by itself
     fused = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo))
-    kernels, fusions, computation = [], [], None
+    kernels, fusions, computation = {}, [], None   # kernels: name -> op_name
     for line in hlo.splitlines():
         header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
         if header:
@@ -222,19 +249,26 @@ def test_scopes_survive_the_v5e_compiler(v5e):
         found = re.search(r'op_name="([^"]*)"', line)
         op_name = found.group(1) if found else ""
         if 'custom_call_target="tpu_custom_call"' in line:
-            kernels.append(op_name)
+            kernels[re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)[1]] = \
+                op_name
         elif " fusion(" in line:
             fusions.append(op_name)
-    # forward, remat's forward, and the one backward call (dK, dV, dQ)
-    assert len(kernels) >= 3, kernels
-    assert all("attention" in scope_in(k) for k in kernels), kernels
+    # the forward (under "full" remat's too) and the one backward call
+    # (dK, dV, dQ), and no other kernel
+    assert sum(n.startswith("splash_mha_fwd") for n in kernels) == \
+        fwd_calls, kernels
+    assert sum(n.startswith("splash_mha_dkv") for n in kernels) == 1, kernels
+    assert len(kernels) == fwd_calls + 1, kernels
+    assert_saved_residuals(hlo, policy, (2, 1, 32, seq, 128))
+    assert all("attention" in scope_in(k) for k in kernels.values()), kernels
     kept = [f for f in fusions if scope_in(f)]
     share = len(kept) / len(fusions)
     print(f"fusions with a vocabulary scope: {len(kept)} of {len(fusions)} "
           f"({100 * share:.1f}%); the others: "
           f"{sorted({f for f in fusions if not scope_in(f)})}")
     assert share >= 0.9
-    seen = {s for op_name in kept + kernels for s in scope_in(op_name)}
+    seen = {s for op_name in kept + list(kernels.values())
+            for s in scope_in(op_name)}
     assert seen == set(scopes), sorted(set(scopes) - seen)
 
 
@@ -301,8 +335,10 @@ def test_olmoe_layer_step_compiles_with_the_grouped_matmul_kernels(v5e):
     assert not bad, bad
 
 
-def test_latent_attention_share_step_compiles_for_the_v5e(v5e):
-    """One dense and one expert layer of GLM-4.7-Flash's widths (latent
+@pytest.mark.parametrize("policy,fwd_calls", REMAT_POLICIES)
+def test_latent_attention_share_step_compiles_for_the_v5e(v5e, policy,
+                                                          fwd_calls):
+    """One dense and two expert layers of GLM-4.7-Flash's widths (latent
     attention at 20 heads of 192 + 64 / 256, 8 of 64 experts of width 1536
     held, a shared expert) + head, as one train step for the v5e: splash's
     kernels at head_dim 256, `megablox` at the width 1024 does not divide
@@ -320,14 +356,14 @@ def test_latent_attention_share_step_compiles_for_the_v5e(v5e):
 
     seq, rows = 1024, 1
     cfg = TransformerConfig(
-        vocab_size=19360, d_model=2048, n_layers=2, n_heads=20, d_ff=1536,
+        vocab_size=19360, d_model=2048, n_layers=3, n_heads=20, d_ff=1536,
         max_seq_len=seq, rope_theta=1e6, q_lora_rank=768, kv_lora_rank=512,
         qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
         moe_experts=64, moe_top_k=4, moe_scoring="sigmoid",
         moe_routed_scale=1.8, moe_shared_experts=1, moe_dense_layers=1,
         moe_dense_ff=10240, moe_experts_held=8, moe_aux_coeff=0.0,
         attention_impl="auto", dtype="bfloat16", param_dtype="float32",
-        remat=True, loss_chunk=256)
+        remat=True, loss_chunk=256, **policy)
     mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
     assert Transformer.resolve_attention_impl(cfg, mesh, seq) == "flash"
     slots = rows * seq * cfg.moe_top_k
@@ -363,9 +399,12 @@ def test_latent_attention_share_step_compiles_for_the_v5e(v5e):
     assert re.search(r"bf16\[8,2048,3072\]", hlo)
     assert re.search(r"bf16\[8,1536,2048\]", hlo)
     assert not re.search(r"\[64,(2048|1536),", hlo)
-    # two layers: forward, remat's forward and the fused backward, each
-    assert sum(n.startswith("splash_mha_fwd") for n in names) == 4, names
+    # two scans (the dense layer, the two expert layers): the forward
+    # (under "full" remat's too) and the fused backward, each
+    assert sum(n.startswith("splash_mha_fwd") for n in names) == \
+        2 * fwd_calls, names
     assert sum(n.startswith("splash_mha_dkv") for n in names) == 2, names
+    assert_saved_residuals(hlo, policy, (2, rows, 20, seq, 256))
     for scope in ("qkv/q_down", "qkv/kv_down", "qkv/q_up", "qkv/kv_up",
                   "qkv/assemble", "moe/shared", "moe/router", "moe/dispatch",
                   "moe/experts", "moe/combine", "mlp/gate_up", "mlp/down"):

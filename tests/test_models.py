@@ -78,10 +78,14 @@ class TestTrainStep:
         assert losses[-1] < losses[0] * 0.7, losses
         assert int(jax.device_get(state["step"])) == 10
 
-    def test_remat_dots_matches_full(self, tiny_params):
-        """remat_policy="dots" saves what "full" recomputes: the same loss
-        and every gradient, here and on the 3D mesh."""
+    @pytest.mark.parametrize("on_mesh", [False, True],
+                             ids=["no_mesh", "mesh_3d"])
+    @pytest.mark.parametrize("policy", ["dots", "attention"])
+    def test_remat_policy_matches_full(self, tiny_params, policy, on_mesh):
+        """A policy saves what "full" recomputes: the same loss and every
+        gradient, here and on the 3D mesh."""
         cfg = TINY.replace(dtype="float32", remat=True)
+        assert TINY.remat_policy == "attention"   # what remat=True means
         mesh = make_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
         batch = {"tokens": jax.random.randint(
             jax.random.PRNGKey(3), (4, 33), 0, cfg.vocab_size)}
@@ -93,11 +97,30 @@ class TestTrainStep:
                     tiny_params)
 
         ref_loss, ref_grads = run("full", None)
-        for m in (None, mesh):
-            loss, grads = run("dots", m)
-            assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
-            errs = jax.tree.map(_rel_l2, grads, ref_grads)
-            assert all(e < 1e-5 for e in jax.tree.leaves(errs)), errs
+        loss, grads = run(policy, mesh if on_mesh else None)
+        assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+        errs = jax.tree.map(_rel_l2, grads, ref_grads)
+        assert all(e < 1e-5 for e in jax.tree.leaves(errs)), errs
+
+    def test_default_remat_without_the_kernel_is_fulls_program(
+            self, tiny_params):
+        """Only the flash kernel names what the default policy saves: with
+        dense attention nothing is named, nothing is saved, and the
+        compiled program is "full"'s."""
+        from tests.test_model_scopes import stripped
+
+        cfg = TINY.replace(remat=True, attention_impl="dense")
+        batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+
+        def compiled_text(policy):
+            c = cfg.replace(remat_policy=policy)
+            # less the source locations, which are no part of the program
+            return stripped(jax.jit(jax.value_and_grad(
+                lambda p: Transformer.loss(p, batch, c))).lower(
+                    tiny_params).compile().as_text())
+
+        assert compiled_text("attention") == compiled_text("full")
+        assert compiled_text("dots") != compiled_text("full")
 
     @pytest.mark.parametrize("remat", [True, False])
     def test_unknown_remat_policy_raises(self, tiny_params, remat):

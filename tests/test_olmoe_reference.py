@@ -208,11 +208,12 @@ def test_norm_topk_follows_the_config():
         100 * RTOL * np.abs(np.asarray(other)).max()
 
 
-@pytest.mark.parametrize("variant", ["remat", "remat_dots", "remat_unrolled",
-                                     "chunked"])
+@pytest.mark.parametrize("variant", ["remat", "remat_full", "remat_dots",
+                                     "remat_unrolled", "chunked"])
 def test_remat_scan_and_chunked_head_change_nothing(variant):
     base = config(8, 2)
     cfg = {"remat": base.replace(remat=True),
+           "remat_full": base.replace(remat=True, remat_policy="full"),
            "remat_dots": base.replace(remat=True, remat_policy="dots"),
            "remat_unrolled": base.replace(remat=True, scan_unroll=2),
            "chunked": base.replace(remat=True, loss_chunk=16)}[variant]
