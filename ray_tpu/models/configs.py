@@ -7,7 +7,11 @@ FFN, or routed experts (a softmax router with the load-balancing loss, or
 a sigmoid router with a choice bias that is a buffer and a scaling
 factor), optionally behind leading dense layers of their own width, with
 shared experts every token passes, and with only a contiguous share of
-the experts held here (one chip of an expert-parallel layer).
+the experts held here (one chip of an expert-parallel layer). A hybrid
+(`layer_pattern`) is a published sequence of single sublayers, each a
+Mamba-2 mixer (`M`), an attention block (`*`) or an expert layer (`E`)
+alone; its experts may live in a latent (`moe_latent`) and be plain
+`relu(x)^2` MLPs with no gate.
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -114,8 +118,56 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # width of an attention head where it is not d_model / n_heads (a
+    # chip's share of the heads, or a model that publishes `head_dim`)
+    attn_head_dim: int = 0
+    # rotary embedding on q and k; off where other layers carry position
+    rope: bool = True
+    # A hybrid's layers, one character a layer (`hybrid_override_pattern`):
+    # `M` a Mamba-2 mixer, `*` attention, `E` an expert layer, each alone
+    # as `x + f(norm(x))`. Empty: every layer is attention then an FFN.
+    # len(layer_pattern) == n_layers; `pattern_runs` factors it into the
+    # runs `Transformer._stack` scans.
+    layer_pattern: str = ""
+    # the Mamba-2 mixer (ops/ssm.py): ssm_heads heads of ssm_head_dim,
+    # ssm_groups groups of B/C of state ssm_state, a depthwise causal
+    # convolution of ssm_conv_kernel taps, the scan in chunks of ssm_chunk
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    # experts that live in a latent of this width: one down-projection of
+    # the stream before the dispatch, one up-projection after the combine;
+    # the router and the shared expert read the stream itself (0: none)
+    moe_latent: int = 0
+    # the experts' and the shared expert's activation, and whether a gate
+    # multiplies it: "silu" gated is `silu(x Wg) * (x Wu)`, "relu2"
+    # ungated is `relu(x W1)^2`
+    moe_act: str = "silu"
+    moe_gated: bool = True
+    # the shared expert's own width (0: moe_shared_experts * d_ff)
+    moe_shared_ff: int = 0
 
     def __post_init__(self):
+        if self.layer_pattern:
+            unknown = set(self.layer_pattern) - set("ME*")
+            if unknown or len(self.layer_pattern) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r}: {self.n_layers} "
+                    f"characters of M, E, * (got {sorted(unknown)})")
+            if "M" in self.layer_pattern and (
+                    not self.ssm_heads or self.ssm_heads % self.ssm_groups):
+                raise ValueError("a mixer needs ssm_heads, a multiple of "
+                                 "ssm_groups")
+            if ("E" in self.layer_pattern) != bool(self.moe_experts) \
+                    or self.moe_dense_layers or self.kv_lora_rank:
+                raise ValueError("a layer_pattern has expert layers where "
+                                 "it says E, no leading dense run and no "
+                                 "latent attention")
+        if self.moe_act not in ("silu", "relu2"):
+            raise ValueError(f"unknown moe_act {self.moe_act!r}")
         if self.kv_lora_rank:
             if not (self.q_lora_rank and self.qk_rope_head_dim
                     and self.v_head_dim) or self.qk_rope_head_dim % 2:
@@ -125,7 +177,7 @@ class TransformerConfig:
             if self.kv_heads != self.n_heads or self.qk_norm:
                 raise ValueError("latent attention has one key/value head "
                                  "per query head and no QK-norm")
-        elif self.d_model % self.n_heads:
+        elif not self.attn_head_dim and self.d_model % self.n_heads:
             raise ValueError(f"d_model {self.d_model} % n_heads "
                              f"{self.n_heads} != 0")
         if self.moe_scoring not in ("softmax", "sigmoid"):
@@ -156,7 +208,7 @@ class TransformerConfig:
         a value head is `v_dim` wide."""
         if self.kv_lora_rank:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
 
     @property
     def v_dim(self) -> int:
@@ -175,6 +227,24 @@ class TransformerConfig:
     def held_experts(self) -> int:
         return self.moe_experts_held or self.moe_experts
 
+    @property
+    def ssm_inner(self) -> int:
+        """Width of the mixer's x, z and output: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the convolution runs over: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def shared_ff(self) -> int:
+        return self.moe_shared_ff or self.moe_shared_experts * self.ff_dim
+
+    @property
+    def pattern_runs(self):
+        return pattern_runs(self.layer_pattern)
+
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
 
@@ -192,6 +262,8 @@ class TransformerConfig:
             attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
         if self.qk_norm:
             attn += nh * hd + nkv * hd
+        if self.layer_pattern:
+            return self._pattern_params(attn)
         norms = 2 * d
         mlp = 3 * d * f
         dense = 0
@@ -205,6 +277,52 @@ class TransformerConfig:
             l -= self.moe_dense_layers
         head = 0 if self.tie_embeddings else d * v
         return v * d + dense + l * (attn + mlp + norms) + d + head
+
+    def _pattern_params(self, attn: int) -> int:
+        """num_params of a hybrid: each sublayer with its one norm."""
+        d, v = self.d_model, self.vocab_size
+        inner, conv = self.ssm_inner, self.ssm_conv_dim
+        mixer = (d * (inner + conv + self.ssm_heads)       # W_in
+                 + conv * self.ssm_conv_kernel + conv      # conv, its bias
+                 + 3 * self.ssm_heads                      # dt_bias, A_log, D
+                 + inner + inner * d)                      # gated norm, W_out
+        mats = 3 if self.moe_gated else 2
+        width = self.moe_latent or d
+        expert = (d * self.moe_experts                     # router
+                  + (2 * d * self.moe_latent if self.moe_latent else 0)
+                  + self.held_experts * mats * width * self.ff_dim
+                  + (mats * d * self.shared_ff
+                     if self.moe_shared_experts else 0))
+        each = {"M": mixer, "*": attn, "E": expert}
+        layers = sum(each[c] + d for c in self.layer_pattern)
+        head = 0 if self.tie_embeddings else d * v
+        return v * d + layers + d + head
+
+
+def pattern_runs(pattern: str):
+    """A layer pattern as the runs a scan takes: [(block, repeats), ...]
+    with the blocks' repeats laid end to end giving the pattern back. At
+    each position the block whose repeats cover the most layers (the
+    shorter block on a tie); layers that repeat nothing join the block
+    before them if that one runs once. `MEMEMEMEM*E` is 4 x `ME` and one
+    `M*E`; the published 88 layers are eight runs."""
+    runs = []
+    i = 0
+    while i < len(pattern):
+        best = (1, 1)
+        for p in range(1, (len(pattern) - i) // 2 + 1):
+            block, r = pattern[i:i + p], 1
+            while pattern[i + r * p:i + (r + 1) * p] == block:
+                r += 1
+            if r > 1 and p * r > best[0] * best[1]:
+                best = (p, r)
+        p, r = best
+        if r == 1 and runs and runs[-1][1] == 1:
+            runs[-1] = (runs[-1][0] + pattern[i], 1)
+        else:
+            runs.append((pattern[i:i + p], r))
+        i += p * r
+    return runs
 
 
 TINY = TransformerConfig(

@@ -10,7 +10,14 @@ inserts the collectives. Layers are stacked and iterated with `lax.scan`
 stacked leading dim is the natural pipeline-parallel axis). A model whose
 first layers keep a dense FFN before its expert layers
 (`moe_dense_layers`) is two such runs, `params["dense_layers"]` then
-`params["layers"]`; a layer is what its leaves say it is.
+`params["layers"]`; a layer is what its leaves say it is. A hybrid
+(`layer_pattern`: each layer a Mamba-2 mixer, an attention block or an
+expert layer ALONE, `x + f(norm(x))` with one norm) is the runs of
+`cfg.pattern_runs`, `params["runs"]`: each run a block, a list of unlike
+sublayers, its leaves stacked over the block's repeats and scanned as one
+body. A sublayer too is what its leaves say: `attn_norm` brings
+attention, `ssm_norm` a mixer, `mlp_norm` an FFN or experts, and the
+homogeneous layer is the one with both the first and the last.
 
 Every block names itself with `jax.named_scope`, and the names are an
 interface (PERF.md section 3; the benchmark's per-layer metrics and an
@@ -20,8 +27,10 @@ operator's `ray_tpu profile --device` read them off each op's op_name):
 `qkv/q_down`, `qkv/kv_down`, `qkv/q_up`, `qkv/kv_up`, `qkv/assemble`
 inside it), `attention` (kernels, GQA repeat, layout transposes),
 `attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (in an expert layer
-`moe/router`, `moe/dispatch`, `moe/experts`, `moe/combine`, `moe/shared`:
-ops/moe.py), `final_norm`, `head`, `loss` (the vocab head and the
+`moe/router`, `moe/dispatch`, `moe/experts`, `moe/combine`, `moe/shared`,
+`moe/latent`: ops/moe.py), in a mixer `ssm_norm` and `ssm/in_proj`,
+`ssm/conv`, `ssm/scan`, `ssm/gate_norm`, `ssm/out_proj` (ops/ssm.py),
+`final_norm`, `head`, `loss` (the vocab head and the
 cross-entropy: models/head.py); the train step adds `optimizer`
 (parallel/train_step.py). Scopes are metadata only. Forward, backward
 and recomputation need none: JAX wraps the path in `jvp(...)`,
@@ -142,42 +151,108 @@ class Transformer:
                 layers["k_norm"] = jnp.ones((l, nkv * hd), pdt)
             return layers
 
-        def gated(keys, lead, width):
+        def gated(keys, lead, width, d_in=d):
             """A gated FFN's two leaves: gate and up fused, and down."""
             return (jnp.stack(
-                [norm_init(d ** -0.5, keys[5], lead + (d, width)),
-                 norm_init(d ** -0.5, keys[6], lead + (d, width))],
-                axis=len(lead) + 1),  # lead + (d, 2, width)
-                norm_init(width ** -0.5, keys[7], lead + (width, d)))
+                [norm_init(d_in ** -0.5, keys[5], lead + (d_in, width)),
+                 norm_init(d_in ** -0.5, keys[6], lead + (d_in, width))],
+                axis=len(lead) + 1),  # lead + (d_in, 2, width)
+                norm_init(width ** -0.5, keys[7], lead + (width, d_in)))
 
-        keys = jax.random.split(key, 8)
-        l = cfg.n_layers - cfg.moe_dense_layers
-        layers = attention(l, keys)
-        if cfg.moe_experts:
-            # routed expert FFN (ops/moe.py): per-layer router + stacked
-            # expert weights (those held here), each expert gated like the
-            # dense MLP below; expert dim sharded over the "expert" axis
+        def ffn(keys, lead, width, d_in=d):
+            """A gated FFN's leaves, or a plain one's `(up, down)`."""
+            if cfg.moe_gated:
+                return gated(keys, lead, width, d_in)
+            return (norm_init(d_in ** -0.5, keys[5], lead + (d_in, width)),
+                    norm_init(width ** -0.5, keys[7], lead + (width, d_in)))
+
+        def experts(l, key, keys):
+            """One run of l expert layers' leaves (ops/moe.py): per-layer
+            router + stacked expert weights (those held here), each expert
+            an MLP like the dense one below, in a latent where the model
+            has one; expert dim sharded over the "expert" axis."""
             e, held = cfg.moe_experts, cfg.held_experts
-            layers["w_router"] = norm_init(
+            first = "gateup" if cfg.moe_gated else "up"
+            layers = {"w_router": norm_init(
                 0.02, jax.random.fold_in(key, 98),
-                (l, d, e)).astype(jnp.float32)
-            layers["w_moe_gateup"], layers["w_moe_down"] = gated(
-                keys, (l, held), f)
+                (l, d, e)).astype(jnp.float32)}
+            layers["w_moe_" + first], layers["w_moe_down"] = ffn(
+                keys, (l, held), f, cfg.moe_latent or d)
             if cfg.moe_scoring == "sigmoid":
                 # the choice bias: a buffer (Transformer.frozen), zero
                 # until whoever balances the load moves it
                 layers["router_bias"] = jnp.zeros((l, e), jnp.float32)
             if cfg.moe_shared_experts:
                 shared = jax.random.split(jax.random.fold_in(key, 97), 8)
-                layers["w_shared_gateup"], layers["w_shared_down"] = gated(
-                    shared, (l,), cfg.moe_shared_experts * f)
-        else:
-            layers["w_gateup"], layers["w_down"] = gated(keys, (l,), f)
+                layers["w_shared_" + first], layers["w_shared_down"] = ffn(
+                    shared, (l,), cfg.shared_ff)
+            if cfg.moe_latent:
+                latent = jax.random.split(jax.random.fold_in(key, 95))
+                layers["w_latent_down"] = norm_init(
+                    d ** -0.5, latent[0], (l, d, cfg.moe_latent))
+                layers["w_latent_up"] = norm_init(
+                    cfg.moe_latent ** -0.5, latent[1],
+                    (l, cfg.moe_latent, d))
+            return layers
+
+        def mixer(l, key):
+            """One run of l Mamba-2 mixers' leaves (ops/ssm.py), A and dt
+            from the published initialiser: A uniform in [1, 16], dt
+            log-uniform in [0.001, 0.1] through softplus's inverse, D 1, the
+            convolution uniform within 1/sqrt(taps)."""
+            ks = jax.random.split(key, 5)
+            inner, conv, nh_ = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
+            dt = jnp.exp(jax.random.uniform(
+                ks[3], (l, nh_), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+            bound = cfg.ssm_conv_kernel ** -0.5   # a depthwise tap's fan-in
+            return {
+                "ssm_norm": jnp.ones((l, d), pdt),
+                "w_in": norm_init(d ** -0.5, ks[0],
+                                  (l, d, inner + conv + nh_)),
+                "conv_w": jax.random.uniform(
+                    ks[1], (l, conv, cfg.ssm_conv_kernel), jnp.float32,
+                    -bound, bound).astype(pdt),
+                "conv_b": jax.random.uniform(
+                    jax.random.fold_in(ks[1], 1), (l, conv), jnp.float32,
+                    -bound, bound).astype(pdt),
+                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+                "A_log": jnp.log(jax.random.uniform(
+                    ks[4], (l, nh_), jnp.float32, 1.0, 16.0)).astype(pdt),
+                "D": jnp.ones((l, nh_), pdt),
+                "gate_norm": jnp.ones((l, inner), pdt),
+                "w_out": norm_init(inner ** -0.5, ks[2], (l, inner, d)),
+            }
+
+        def sublayer(kind, l, key):
+            keys = jax.random.split(key, 8)
+            if kind == "M":
+                return mixer(l, key)
+            if kind == "*":
+                sub = attention(l, keys)
+                del sub["mlp_norm"]
+                return sub
+            return dict(experts(l, key, keys),
+                        mlp_norm=jnp.ones((l, d), pdt))
+
+        keys = jax.random.split(key, 8)
         params = {
             "embed": norm_init(0.02, keys[0], (cfg.vocab_size, d)),
-            "layers": layers,
             "final_norm": jnp.ones((d,), pdt),
         }
+        if cfg.layer_pattern:
+            params["runs"] = [
+                [sublayer(kind, repeats,
+                          jax.random.fold_in(key, 1000 + 100 * n + i))
+                 for i, kind in enumerate(block)]
+                for n, (block, repeats) in enumerate(cfg.pattern_runs)]
+        else:
+            l = cfg.n_layers - cfg.moe_dense_layers
+            layers = attention(l, keys)
+            if cfg.moe_experts:
+                layers.update(experts(l, key, keys))
+            else:
+                layers["w_gateup"], layers["w_down"] = gated(keys, (l,), f)
+            params["layers"] = layers
         if cfg.moe_dense_layers:
             lead = jax.random.split(jax.random.fold_in(key, 96), 8)
             dense = attention(cfg.moe_dense_layers, lead)
@@ -222,24 +297,55 @@ class Transformer:
 
         dense_ffn = {"w_gateup": ("layers", "embed", None, "mlp"),
                      "w_down": ("layers", "mlp", "embed")}
-        layers = attention()
-        if cfg.moe_experts:
-            layers["w_router"] = ("layers", "embed", None)
-            layers["w_moe_gateup"] = ("layers", "expert", "embed", None,
-                                      "mlp")
-            layers["w_moe_down"] = ("layers", "expert", "mlp", "embed")
+
+        def experts():
+            layers = {"w_router": ("layers", "embed", None),
+                      "w_moe_down": ("layers", "expert", "mlp", "embed")}
+            if cfg.moe_gated:
+                layers["w_moe_gateup"] = ("layers", "expert", "embed", None,
+                                          "mlp")
+            else:
+                layers["w_moe_up"] = ("layers", "expert", "embed", "mlp")
             if cfg.moe_scoring == "sigmoid":
                 layers["router_bias"] = ("layers", None)
-            if cfg.moe_shared_experts:
+            if cfg.moe_shared_experts and cfg.moe_gated:
                 layers["w_shared_gateup"] = dense_ffn["w_gateup"]
+            elif cfg.moe_shared_experts:
+                layers["w_shared_up"] = ("layers", "embed", "mlp")
+            if cfg.moe_shared_experts:
                 layers["w_shared_down"] = dense_ffn["w_down"]
-        else:
-            layers.update(dense_ffn)
+            if cfg.moe_latent:
+                layers["w_latent_down"] = ("layers", "embed", None)
+                layers["w_latent_up"] = ("layers", None, "embed")
+            return layers
+
+        def sublayer(kind):
+            if kind == "M":   # a mixer's heads are not sharded here
+                return {"ssm_norm": ("layers", "norm"),
+                        "w_in": ("layers", "embed", None),
+                        "conv_w": ("layers", None, None),
+                        "conv_b": ("layers", None),
+                        "dt_bias": ("layers", None),
+                        "A_log": ("layers", None), "D": ("layers", None),
+                        "gate_norm": ("layers", None),
+                        "w_out": ("layers", None, "embed")}
+            if kind == "*":
+                sub = attention()
+                del sub["mlp_norm"]
+                return sub
+            return dict(experts(), mlp_norm=("layers", "norm"))
+
         specs = {
             "embed": ("vocab", "embed"),
-            "layers": layers,
             "final_norm": ("norm",),
         }
+        if cfg.layer_pattern:
+            specs["runs"] = [[sublayer(kind) for kind in block]
+                             for block, _ in cfg.pattern_runs]
+        else:
+            layers = attention()
+            layers.update(experts() if cfg.moe_experts else dense_ffn)
+            specs["layers"] = layers
         if cfg.moe_dense_layers:
             specs["dense_layers"] = dict(attention(), **dense_ffn)
         if not cfg.tie_embeddings:
@@ -257,8 +363,11 @@ class Transformer:
         specs = Transformer.param_specs(cfg)
         mask = jax.tree.map(lambda _: False, specs,
                             is_leaf=lambda x: isinstance(x, tuple))
-        if "router_bias" in mask["layers"]:
-            mask["layers"]["router_bias"] = True
+        blocks = mask["runs"] if "runs" in mask else [[mask["layers"]]]
+        for block in blocks:
+            for sub in block:
+                if "router_bias" in sub:
+                    sub["router_bias"] = True
         return mask
 
     # ---- forward ----------------------------------------------------
@@ -325,21 +434,38 @@ class Transformer:
         """x [B, T, d] through a run of stacked layers (leaves
         [n, ...]: all of them in hidden(), one stage's in pipeline_loss())
         -> (x, routing), `routing` the layers' stacked MoE records (None
-        for dense FFN configs)."""
+        for dense FFN configs). A run that is a list is a block of unlike
+        sublayers (module docstring): the scan's body runs them in turn,
+        each under `_remat` by itself, and `routing` is the list of the
+        expert sublayers' stacked records."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
-        if positions is None:
-            positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
-        with jax.named_scope("qkv"):
-            cos, sin = _rope_tables(positions, cfg.rope_dim, cfg.rope_theta)
+        cos = sin = None
+        if cfg.rope:
+            if positions is None:
+                positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+            with jax.named_scope("qkv"):
+                cos, sin = _rope_tables(positions, cfg.rope_dim,
+                                        cfg.rope_theta)
         layer = Transformer._remat(
-            Transformer._make_layer_fn(cfg, mesh, rules, cos, sin), cfg)
+            Transformer._make_layer_fn(cfg, mesh, rules, cos, sin,
+                                       seq_len=x.shape[1]), cfg)
+
+        def block(x, subs):
+            records = []
+            for sub in subs:
+                x, routing = layer(x, sub)
+                if routing is not None:
+                    records.append(routing)
+            return x, records
+
         # the scan's own work (stacking and slicing saved activations,
         # carries) is "layers"; each block inside names itself
         with jax.named_scope("layers"):
-            return lax.scan(layer, x, layers, unroll=cfg.scan_unroll)
+            return lax.scan(block if isinstance(layers, list) else layer,
+                            x, layers, unroll=cfg.scan_unroll)
 
     @staticmethod
     def hidden(params, tokens, cfg: TransformerConfig, *,
@@ -369,9 +495,21 @@ class Transformer:
             x, _ = Transformer._stack(
                 params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
                 positions=positions)
-        x, routing = Transformer._stack(
-            params["layers"], x, cfg, mesh=mesh, rules=rules,
-            positions=positions)
+        if "runs" in params:
+            records = []   # per run and expert sublayer: [repeats, ...]
+            for run in params["runs"]:
+                x, found = Transformer._stack(
+                    run, x, cfg, mesh=mesh, rules=rules, positions=positions)
+                if found:   # into the layers' order: [repeats * found, ...]
+                    records.append(jax.tree.map(
+                        lambda *r: jnp.stack(r, 1).reshape(
+                            (-1,) + r[0].shape[1:]), *found))
+            routing = jax.tree.map(lambda *r: jnp.concatenate(r),
+                                   *records) if records else None
+        else:
+            x, routing = Transformer._stack(
+                params["layers"], x, cfg, mesh=mesh, rules=rules,
+                positions=positions)
         aux_total = jnp.zeros((), jnp.float32)
         if cfg.moe_experts and cfg.moe_scoring == "softmax":
             # not a sum of per-layer terms: the published loss takes its
@@ -390,10 +528,13 @@ class Transformer:
 
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
-                       rules: ShardingRules, cos, sin):
-        """Build layer(x, lp) -> (x, routing), the body `_stack` scans.
-        `routing` is the MoE layer's record (ops/moe.py `moe_ffn`), None
-        on a dense layer."""
+                       rules: ShardingRules, cos, sin, seq_len: int):
+        """Build layer(x, lp) -> (x, routing), the body `_stack` scans
+        (or, in a block, one of its sublayers): what lp's leaves say,
+        attention under `attn_norm`, a mixer under `ssm_norm`, an FFN or
+        experts under `mlp_norm`, each `x + f(norm(x))`. `routing` is the
+        MoE layer's record (ops/moe.py `moe_ffn`), None without one. cos
+        and sin are None where the model has no rotary embedding."""
         import jax
         import jax.numpy as jnp
 
@@ -401,7 +542,7 @@ class Transformer:
         constrain = functools.partial(
             with_logical_constraint, mesh=mesh, rules=rules)
         attn_fn = Transformer._make_attention(cfg, mesh, rules,
-                                              seq_len=cos.shape[-2])
+                                              seq_len=seq_len)
         scale = cfg.head_dim ** -0.5
 
         def heads_constrained(q, k, v):
@@ -441,7 +582,7 @@ class Transformer:
                 k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
                 return heads_constrained(q, k, kv[..., nope:])
 
-        def layer(x, lp):
+        def attention(x, lp):
             # one jax.named_scope per block (module docstring): the names
             # reach every op's op_name, and so the device trace
             with jax.named_scope("attn_norm"):
@@ -465,41 +606,64 @@ class Transformer:
                     k = _rmsnorm(k.reshape(k.shape[:2] + (-1,)),
                                  lp["k_norm"], cfg.norm_eps).reshape(k.shape)
                 if not cfg.kv_lora_rank:
-                    q, k, v = heads_constrained(
-                        _rope(q, cos, sin), _rope(k, cos, sin), v)
+                    if cfg.rope:
+                        q, k = _rope(q, cos, sin), _rope(k, cos, sin)
+                    q, k, v = heads_constrained(q, k, v)
             with jax.named_scope("attention"):
                 o = attn_fn(q, k, v, scale)
             with jax.named_scope("attn_out"):
                 o = constrain(o, ("batch", "seq", "heads", "head_dim"))
                 o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cdt))
-                x = x + constrain(o, ("batch", "seq", "act_embed"))
+                return x + constrain(o, ("batch", "seq", "act_embed"))
 
+        def mixer(x, lp):
+            from ray_tpu.ops.ssm import mamba2_mixer
+
+            with jax.named_scope("ssm_norm"):
+                h = _rmsnorm(x, lp["ssm_norm"], cfg.norm_eps)
+            with jax.named_scope("ssm/in_proj"):
+                w_in = lp["w_in"].astype(cdt)
+            with jax.named_scope("ssm/out_proj"):
+                w_out = lp["w_out"].astype(cdt)
+            # mamba2_mixer names its own scopes under `ssm/`
+            out = mamba2_mixer(
+                h, dict(lp, w_in=w_in, w_out=w_out),
+                head_dim=cfg.ssm_head_dim, state=cfg.ssm_state,
+                chunk=cfg.ssm_chunk, eps=cfg.norm_eps)
+            with jax.named_scope("ssm/out_proj"):
+                return x + constrain(out, ("batch", "seq", "act_embed"))
+
+        # an expert layer's leaves as `moe_ffn` names them, by the scope
+        # their casts belong to
+        expert_leaves = (
+            ("moe/experts", ("w_moe_gateup", "w_moe_up", "w_moe_down")),
+            ("moe/shared", ("w_shared_gateup", "w_shared_up",
+                            "w_shared_down")),
+            ("moe/latent", ("w_latent_down", "w_latent_up")))
+
+        def ffn(x, lp):
             with jax.named_scope("mlp_norm"):
                 h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
             if "w_router" in lp:   # an expert layer, by its leaves
                 from ray_tpu.ops.moe import moe_ffn
 
                 # moe_ffn names its own scopes under `moe/`
-                with jax.named_scope("moe/experts"):
-                    experts = {
-                        "w_router": lp["w_router"],
-                        "w_gateup": lp["w_moe_gateup"].astype(cdt),
-                        "w_down": lp["w_moe_down"].astype(cdt)}
+                experts = {"w_router": lp["w_router"]}
                 if "router_bias" in lp:
                     experts["router_bias"] = lp["router_bias"]
-                if "w_shared_gateup" in lp:
-                    with jax.named_scope("moe/shared"):
-                        experts["w_shared_gateup"] = \
-                            lp["w_shared_gateup"].astype(cdt)
-                        experts["w_shared_down"] = \
-                            lp["w_shared_down"].astype(cdt)
+                for scope, names in expert_leaves:
+                    with jax.named_scope(scope):
+                        experts.update(
+                            (name.replace("w_moe_", "w_"),
+                             lp[name].astype(cdt))
+                            for name in names if name in lp)
                 y, routing = moe_ffn(
                     experts, h.reshape(-1, h.shape[-1]),
                     num_selected=cfg.moe_top_k,
                     norm_topk=cfg.moe_norm_topk, scoring=cfg.moe_scoring,
                     routed_scale=cfg.moe_routed_scale,
-                    expert_offset=cfg.moe_expert_offset, mesh=mesh,
-                    rules=rules)
+                    expert_offset=cfg.moe_expert_offset, act=cfg.moe_act,
+                    mesh=mesh, rules=rules)
                 with jax.named_scope("moe/combine"):
                     down = y.reshape(h.shape).astype(cdt)
                     x = x + constrain(down, ("batch", "seq", "act_embed"))
@@ -514,6 +678,16 @@ class Transformer:
                                   lp["w_down"].astype(cdt))
                 x = x + constrain(down, ("batch", "seq", "act_embed"))
             return x, None
+
+        def layer(x, lp):
+            routing = None
+            if "attn_norm" in lp:
+                x = attention(x, lp)
+            if "ssm_norm" in lp:
+                x = mixer(x, lp)
+            if "mlp_norm" in lp:
+                x, routing = ffn(x, lp)
+            return x, routing
 
         return layer
 
@@ -557,9 +731,10 @@ class Transformer:
         if cfg.attention_impl in ("ring", "ulysses"):
             raise ValueError("pipeline stages need stage-local attention "
                              "(dense/flash), not ring/ulysses")
-        if cfg.moe_experts:
+        if cfg.moe_experts or cfg.layer_pattern:
             raise ValueError(
-                "pipeline_loss does not thread the MoE aux "
+                "pipeline_loss takes one homogeneous run of layers and "
+                "does not thread the MoE aux "
                 "(load-balancing) loss out of the pipeline yet; train "
                 "MoE configs via Transformer.loss (expert axis), or set "
                 "moe_experts=0 for the pipe axis")

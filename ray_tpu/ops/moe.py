@@ -1,8 +1,10 @@
-"""Mixture-of-Experts FFN: a top-k router over gated (SwiGLU) experts.
+"""Mixture-of-Experts FFN: a top-k router over expert MLPs.
 
 The reference has no in-tree MoE/expert parallelism (SURVEY.md §2.4 "EP:
-Absent"); this is the TPU-native capability filling that row. One expert
-definition, `silu(x W_gate) * (x W_up)` then `W_down`, the dense MLP's.
+Absent"); this is the TPU-native capability filling that row. An expert
+is what its weights say: gated, `act(x W_gate) * (x W_up)` then `W_down`
+(`w_gateup [.., d, 2, f]`, the dense MLP's), or plain, `act(x W_1)` then
+`W_2` (`w_up [.., d, f]`); `act` is `silu` or `relu2` (`relu(x)^2`).
 
 Two routers (`route`, float32 whatever the compute dtype): **softmax**
 probabilities, top-k of them, optionally renormalised, with the
@@ -21,7 +23,11 @@ elsewhere run through nothing here, add nothing, and are counted
 hold those experts, and no code here stands in for them or their traffic.
 With every expert held this is the whole layer. A **shared expert**
 (`w_shared_gateup`, `w_shared_down`) is one more gated FFN that every
-token passes, added to the routed sum.
+token passes, added to the routed sum. **Experts in a latent**
+(`w_latent_down [d, r]`, `w_latent_up [r, d]`): the stream is projected
+down once a token before the dispatch and the combined sum up once after
+it, so the rows that are sorted, gathered and multiplied are r wide; the
+router and the shared expert read the stream itself.
 
 Two lowerings of the dispatch, chosen by what the code sees at trace
 time (no option):
@@ -40,6 +46,10 @@ time (no option):
   of all E experts with the held experts' weights and visits only the row
   tiles of the groups it holds (rows of other groups come back zero);
   `ragged_dot` gets the leading groups' rows and the rest are masked.
+  A token picks an expert at most once, so at most min(k, H) of its k
+  slots can be held: where H < k the path past the router keeps, per
+  token, the min(k, H) slots that sort first (the held ones among them:
+  none is ever dropped) and moves N·min(k, H) rows, not N·k.
   The kernel's tiles follow from each call's shapes (`gmm_tiles`). Both
   permutations are gathers in the backward pass too (`_permutes`: a
   permutation's transpose is its inverse), so the step has no scatter.
@@ -56,7 +66,8 @@ Scopes inside the caller's `moe` (PERF.md section 3): `moe/router`
 (logits, scores, top-k), `moe/dispatch` (sort, counts, gather),
 `moe/experts` (the grouped matmuls and silu-mul), `moe/combine` (the
 gather back and the weighted sum; the caller adds the residual there),
-`moe/shared` (the shared expert).
+`moe/shared` (the shared expert), `moe/latent` (the two projections
+around experts that live in a latent).
 """
 
 from __future__ import annotations
@@ -75,24 +86,41 @@ MOE_PARAM_SPECS = {
 }
 
 
-def init_moe_params(key, d_model: int, d_ff: int, n_experts: int
-                    ) -> Dict[str, Any]:
+def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
+                    gated: bool = True) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
     k1, k2, k3 = jax.random.split(key, 3)
     scale_in = (2.0 / d_model) ** 0.5
     scale_out = (2.0 / d_ff) ** 0.5
+    # gate and up fused along an unsharded group axis, as the dense MLP's
+    # w_gateup; a plain expert has the one matrix
+    first = {"w_gateup": (n_experts, d_model, 2, d_ff)} if gated \
+        else {"w_up": (n_experts, d_model, d_ff)}
     return {
         "w_router": jax.random.normal(
             k1, (d_model, n_experts), jnp.float32) * 0.02,
-        # gate and up fused along an unsharded group axis, as the dense
-        # MLP's w_gateup
-        "w_gateup": jax.random.normal(
-            k2, (n_experts, d_model, 2, d_ff), jnp.float32) * scale_in,
+        **{name: jax.random.normal(k2, shape, jnp.float32) * scale_in
+           for name, shape in first.items()},
         "w_down": jax.random.normal(
             k3, (n_experts, d_ff, d_model), jnp.float32) * scale_out,
     }
+
+
+def activation(act: str):
+    import jax
+
+    if act == "silu":
+        return jax.nn.silu
+    if act == "relu2":
+        return lambda x: jax.numpy.square(jax.nn.relu(x))
+    raise ValueError(f"unknown expert activation {act!r}")
+
+
+def _first_matmul(params):
+    """An expert's first weight: gate and up fused, or the plain one."""
+    return params["w_gateup"] if "w_gateup" in params else params["w_up"]
 
 
 def route(w_router, x, num_selected: int, norm_topk: bool, *,
@@ -219,7 +247,8 @@ def gmm_tiles(m: int, k: int, n: int):
     return (GMM_ROWS, tk, tn) if m % GMM_ROWS == 0 and tk and tn else None
 
 
-def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int) -> str:
+def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int,
+                        gated: bool = True) -> str:
     """`megablox` (the pallas grouped matmul that ships with JAX) where
     the program runs on one TPU device and the kernel's tiles fit the
     expert FFN's shapes (`gmm_tiles`), else `ragged_dot`
@@ -228,7 +257,7 @@ def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int) -> str:
     like `Transformer.resolve_attention_impl`."""
     import jax
 
-    tiles = gmm_tiles(rows, d_model, 2 * d_ff) and gmm_tiles(
+    tiles = gmm_tiles(rows, d_model, (1 + gated) * d_ff) and gmm_tiles(
         rows, d_ff, d_model)
     device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
     one_device = mesh is None or mesh.size == 1
@@ -236,14 +265,15 @@ def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int) -> str:
         else "ragged_dot"
 
 
-def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot"):
-    """The gated expert FFN over rows already in expert order: xs
-    `[M, d]`, group_sizes `[E]` summing to M; w_gateup `[H, d, 2, f]`,
-    w_down `[H, f, d]` for the H <= E experts whose groups come first.
-    Rows of the other groups come back zero."""
+def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot",
+                act: str = "silu"):
+    """The expert FFN over rows already in expert order: xs `[M, d]`,
+    group_sizes `[E]` summing to M; w_gateup `[H, d, 2, f]` (gated) or
+    `[H, d, f]` (plain), w_down `[H, f, d]` for the H <= E experts whose
+    groups come first. Rows of the other groups come back zero."""
     import jax
 
-    held, d, _two, f = w_gateup.shape
+    held, d, f = w_gateup.shape[0], w_gateup.shape[1], w_gateup.shape[-1]
     if impl == "megablox":
         from jax.experimental.pallas.ops.tpu.megablox import ops
 
@@ -258,19 +288,34 @@ def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot"):
         def grouped(lhs, rhs):
             return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
-    gu = grouped(xs, w_gateup.reshape(held, d, 2 * f))
-    h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+    gu = grouped(xs, w_gateup.reshape(held, d, -1))
+    h = activation(act)(gu[:, :f])
+    if w_gateup.ndim == 4:
+        h = h * gu[:, f:]
     return grouped(h, w_down)
 
 
-def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0):
+def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
+                act: str = "silu"):
     import jax
     import jax.numpy as jnp
 
     slots_of, combine = _permutes()
-    k = top_e.shape[1]
     n_experts = params["w_router"].shape[1]
-    held, d_model, _two, d_ff = params["w_gateup"].shape
+    w_first = _first_matmul(params)
+    held, d_model, d_ff = w_first.shape[0], w_first.shape[1], \
+        w_first.shape[-1]
+    if held < top_e.shape[1]:
+        # fewer experts held than a token picks, each at most once: of a
+        # token's k slots the `held` that sort first hold every held one
+        with jax.named_scope("moe/dispatch"):
+            _, keep = jax.lax.top_k(
+                -((top_e - expert_offset) % n_experts), held)
+            top_e = jnp.take_along_axis(top_e, keep, axis=1)
+            # the weights through a one-hot: its transpose is no scatter
+            top_w = jnp.einsum("nk,nck->nc", top_w, jax.nn.one_hot(
+                keep, top_w.shape[1], dtype=top_w.dtype))
+    k = top_e.shape[1]
     with jax.named_scope("moe/dispatch"):
         slot_expert = top_e.reshape(-1)                  # [N·k]
         if expert_offset:   # the held experts' groups first
@@ -287,8 +332,9 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0):
         xs = slots_of(x, order, inverse, k)              # [N·k, d]
     with jax.named_scope("moe/experts"):
         ys = experts_ffn(
-            xs, params["w_gateup"], params["w_down"], counts,
-            grouped_matmul_impl(mesh, xs.shape[0], d_model, d_ff))
+            xs, w_first, params["w_down"], counts,
+            grouped_matmul_impl(mesh, xs.shape[0], d_model, d_ff,
+                                gated=w_first.ndim == 4), act)
     with jax.named_scope("moe/combine"):
         y = combine(ys, top_w, order, inverse)
     if held < n_experts:
@@ -334,20 +380,24 @@ def _capacity_ffn(params, x, top_w, top_e, capacity_factor, constrain):
     return y.astype(x.dtype), counts, dropped
 
 
-def shared_ffn(w_gateup, w_down, x):
-    """The shared expert: one gated FFN on every row of x `[N, d]`;
-    w_gateup `[d, 2, f]`, w_down `[f, d]` in the compute dtype."""
-    import jax
+def shared_ffn(w_gateup, w_down, x, act: str = "silu"):
+    """The shared expert: one FFN on every row of x `[N, d]`; w_gateup
+    `[d, 2, f]` (gated) or `[d, f]` (plain), w_down `[f, d]` in the
+    compute dtype."""
     import jax.numpy as jnp
 
-    gu = jnp.einsum("nd,dgf->ngf", x, w_gateup)
-    return jnp.einsum("nf,fd->nd", jax.nn.silu(gu[:, 0]) * gu[:, 1], w_down)
+    if w_gateup.ndim == 2:
+        h = activation(act)(jnp.einsum("nd,df->nf", x, w_gateup))
+    else:
+        gu = jnp.einsum("nd,dgf->ngf", x, w_gateup)
+        h = activation(act)(gu[:, 0]) * gu[:, 1]
+    return jnp.einsum("nf,fd->nd", h, w_down)
 
 
 def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
             norm_topk: bool = True, scoring: str = "softmax",
             routed_scale: float = 1.0, expert_offset: int = 0,
-            capacity_factor: float = 1.25,
+            act: str = "silu", capacity_factor: float = 1.25,
             mesh=None, rules: Optional[ShardingRules] = None
             ) -> Tuple[Any, Dict[str, Any]]:
     """Top-k routed gated-expert FFN.
@@ -356,8 +406,11 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
     `w_router [d, E]` (used in float32) and, where the router has one,
     `router_bias [E]`; `w_gateup [H, d, 2, f]`, `w_down [H, f, d]` in the
     compute dtype, the H <= E experts held here, experts `expert_offset`
-    to `expert_offset + H`; optionally the shared expert's
-    `w_shared_gateup [d, 2, fs]`, `w_shared_down [fs, d]`. Returns
+    to `expert_offset + H` (plain experts: `w_up [H, d, f]` in place of
+    `w_gateup`); optionally the shared expert's `w_shared_gateup
+    [d, 2, fs]` (plain: `w_shared_up [d, fs]`), `w_shared_down [fs, d]`,
+    and `w_latent_down [d, r]`, `w_latent_up [r, d]` around experts that
+    take r-wide rows; `act` is every expert's activation. Returns
     `(y, routing)`: y `[tokens, d_model]` in x's dtype, the held experts'
     weighted outputs for the slots routed to them plus the shared expert,
     and the layer's routing record
@@ -382,7 +435,7 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
 
     rules = rules or ShardingRules()
     n_experts = params["w_router"].shape[1]
-    held = params["w_gateup"].shape[0]
+    held = _first_matmul(params).shape[0]
     k = min(num_selected, n_experts)
     with jax.named_scope("moe/router"):
         probs, top_w, top_e = route(
@@ -392,20 +445,29 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
     expert_parallel = mesh is not None and spec_entry_size(
         rules.mesh_axes("expert"), mesh) > 1
     if expert_parallel:
-        if held != n_experts:
-            raise ValueError("an expert mesh axis shards all the experts; "
-                             "a held share runs without it")
+        if held != n_experts or "w_gateup" not in params \
+                or "w_latent_down" in params or act != "silu":
+            raise ValueError("an expert mesh axis shards all of a layer's "
+                             "gated silu experts; a held share, plain "
+                             "experts and a latent run without it")
         constrain = functools.partial(with_logical_constraint, mesh=mesh,
                                       rules=rules)
         y, counts, dropped = _capacity_ffn(params, x, top_w, top_e,
                                            capacity_factor, constrain)
     else:
-        y, counts, dropped = _sorted_ffn(params, x, top_w, top_e, mesh,
-                                         expert_offset)
-    if "w_shared_gateup" in params:
+        rows = x
+        if "w_latent_down" in params:
+            with jax.named_scope("moe/latent"):
+                rows = jnp.einsum("nd,dr->nr", x, params["w_latent_down"])
+        y, counts, dropped = _sorted_ffn(params, rows, top_w, top_e, mesh,
+                                         expert_offset, act)
+        if "w_latent_up" in params:
+            with jax.named_scope("moe/latent"):
+                y = jnp.einsum("nr,rd->nd", y, params["w_latent_up"])
+    shared = params.get("w_shared_gateup", params.get("w_shared_up"))
+    if shared is not None:
         with jax.named_scope("moe/shared"):
-            y = y + shared_ffn(params["w_shared_gateup"],
-                               params["w_shared_down"], x)
+            y = y + shared_ffn(shared, params["w_shared_down"], x, act)
     if held == n_experts:
         elsewhere = jnp.zeros((), jnp.int32)
     else:
