@@ -479,8 +479,9 @@ class Transformer:
         load-balancing loss over all layers (0 for dense FFN configs) and
         the expert layers' stacked routing records (ops/moe.py `moe_ffn`:
         `tokens_per_expert [layers, held]`, `slots_elsewhere [layers]`,
-        `router_prob [layers, E]`, `dropped [layers]`; None for dense FFN
-        configs). The sigmoid router has no aux loss: 0.
+        `router_prob [layers, E]`, `dropped [layers]`, `rows_bounded
+        [layers]`; None for dense FFN configs). The sigmoid router has no
+        aux loss: 0.
 
         When `mesh` is provided and cfg.attention_impl is ring/ulysses, the
         attention op runs inside shard_map over the "seq" axis; everything
@@ -902,7 +903,10 @@ class Transformer:
         held experts]), `moe_dropped` (int32 scalar; 0 on the sorted path
         by construction), `moe_aux_loss` and, where only a share of the
         experts is held, `moe_slots_elsewhere` (int32 [expert layers]:
-        slots routed to experts held elsewhere); an empty dict for a dense
+        slots routed to experts held elsewhere) and `moe_rows_bounded`
+        (int32 [expert layers]: 1 where this step's held rows fitted
+        `ops/moe.row_bound`'s run and the path past the sort ran over it,
+        0 where it ran over every row); an empty dict for a dense
         config."""
         import jax
         import jax.numpy as jnp
@@ -935,4 +939,5 @@ class Transformer:
             "moe_aux_loss": aux}
         if cfg.held_experts < cfg.moe_experts:
             metrics["moe_slots_elsewhere"] = routing["slots_elsewhere"]
+            metrics["moe_rows_bounded"] = routing["rows_bounded"]
         return loss_val, metrics

@@ -41,15 +41,28 @@ time (no option):
   inverse permutation into a weighted sum over each token's k slots. No
   token is ever dropped, and nothing is larger than `[N·k, max(d, 2f)]`:
   the only `[N, E]` tensors are the router's logits and scores. The
-  shapes are static, N·k rows whatever the share; the grouped matmuls'
-  work follows the slots received: `megablox.gmm` takes the group sizes
-  of all E experts with the held experts' weights and visits only the row
+  grouped matmuls' work follows the slots received: `megablox.gmm` takes
+  the group sizes with the held experts' weights and visits only the row
   tiles of the groups it holds (rows of other groups come back zero);
   `ragged_dot` gets the leading groups' rows and the rest are masked.
   A token picks an expert at most once, so at most min(k, H) of its k
   slots can be held: where H < k the path past the router keeps, per
   token, the min(k, H) slots that sort first (the held ones among them:
-  none is ever dropped) and moves N·min(k, H) rows, not N·k.
+  none is ever dropped) and sorts N·min(k, H) slots, not N·k.
+  **Past the sort a held share runs over the rows it received.** The held
+  experts' groups sort first, so their rows are the leading
+  `R = counts[:H].sum()` of the sorted order and the device knows R
+  before the gather. Shapes are static, so the run is a bound from the
+  shapes (`row_bound`: B, twice what H of E experts receive under uniform
+  routing, in whole row tiles) and one `lax.cond` on `R <= B` a layer and
+  step: the gather, the grouped matmuls (group sizes `[counts[:H]…,
+  B − R]`), the activation and `combine`'s backward run over B rows;
+  `combine`'s forward and `slots_of`'s backward read the rows past B as
+  zeros, which they are. A step whose held rows exceed B runs over all
+  N·min(k, H) rows, the path of a layer with every expert held with
+  `ragged_dot` for its grouped matmuls: nothing is ever dropped, and
+  `rows_bounded` in the routing record says which ran. Where every
+  expert is held or B would pass half the rows no `cond` is traced.
   The kernel's tiles follow from each call's shapes (`gmm_tiles`). Both
   permutations are gathers in the backward pass too (`_permutes`: a
   permutation's transpose is its inverse), so the step has no scatter.
@@ -63,7 +76,8 @@ time (no option):
   (ROADMAP R2).
 
 Scopes inside the caller's `moe` (PERF.md section 3): `moe/router`
-(logits, scores, top-k), `moe/dispatch` (sort, counts, gather),
+(logits, scores, top-k), `moe/dispatch` (sort, counts, the `cond` on
+the rows received, gather),
 `moe/experts` (the grouped matmuls and silu-mul), `moe/combine` (the
 gather back and the weighted sum; the caller adds the residual there),
 `moe/shared` (the shared expert), `moe/latent` (the two projections
@@ -183,10 +197,20 @@ def _permutes():
     import jax
     import jax.numpy as jnp
 
+    def rows_of_slots(ys, inverse):
+        """Row `inverse[i]` of ys for every slot i. ys may stop after a
+        leading run of the sorted order (`row_bound`): the rows past it
+        belong to no held expert and read zero."""
+        if ys.shape[0] == inverse.size:   # every row: no index is past it
+            return jnp.take(ys, inverse, axis=0)
+        return jnp.take(ys, inverse, axis=0, fill_value=0)
+
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
     def slots_of(x, order, inverse, k):
-        """x `[N, d]` -> `[N·k, d]`: row s is the token of sorted slot s
-        (slot i of the unsorted order belongs to token i // k)."""
+        """x `[N, d]` -> `[M, d]`: row s is the token of sorted slot s
+        (slot i of the unsorted order belongs to token i // k). `order` is
+        the sorted order `[N·k]` or a leading run of it, `inverse` always
+        the whole inverse permutation."""
         return jnp.take(x, order // k, axis=0)
 
     def slots_fwd(x, order, inverse, k):
@@ -194,7 +218,7 @@ def _permutes():
 
     def slots_bwd(k, res, g):
         order, inverse = res
-        per_slot = jnp.take(g, inverse, axis=0).reshape(-1, k, g.shape[-1])
+        per_slot = rows_of_slots(g, inverse).reshape(-1, k, g.shape[-1])
         dx = per_slot.astype(jnp.float32).sum(1).astype(g.dtype)
         return dx, None, None     # the permutations are integers
 
@@ -202,14 +226,14 @@ def _permutes():
 
     @jax.custom_vjp
     def combine(ys, top_w, order, inverse):
-        """ys `[N·k, d]` in expert order, top_w `[N, k]` -> `[N, d]`:
-        each token's k expert outputs, weighted and summed."""
+        """ys `[M, d]` in expert order (all N·k slots, or the leading run
+        `order` names), top_w `[N, k]` -> `[N, d]`: each token's k expert
+        outputs, weighted and summed."""
         return combine_fwd(ys, top_w, order, inverse)[0]
 
     def combine_fwd(ys, top_w, order, inverse):
         n, k = top_w.shape
-        per_slot = jnp.take(ys, inverse, axis=0).reshape(
-            n, k, ys.shape[-1])
+        per_slot = rows_of_slots(ys, inverse).reshape(n, k, ys.shape[-1])
         y = jnp.einsum("nkd,nk->nd", per_slot.astype(jnp.float32), top_w)
         return y.astype(ys.dtype), (per_slot, top_w, order, inverse)
 
@@ -268,8 +292,8 @@ def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int,
 def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot",
                 act: str = "silu"):
     """The expert FFN over rows already in expert order: xs `[M, d]`,
-    group_sizes `[E]` summing to M; w_gateup `[H, d, 2, f]` (gated) or
-    `[H, d, f]` (plain), w_down `[H, f, d]` for the H <= E experts whose
+    group_sizes `[G]` summing to M; w_gateup `[H, d, 2, f]` (gated) or
+    `[H, d, f]` (plain), w_down `[H, f, d]` for the H <= G experts whose
     groups come first. Rows of the other groups come back zero."""
     import jax
 
@@ -295,17 +319,55 @@ def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot",
     return grouped(h, w_down)
 
 
+def row_bound(n_tokens: int, k: int, held: int, n_experts: int,
+              rows: int) -> Optional[int]:
+    """The rows the path past the sort runs over where a share of the
+    experts is held: twice what `held` of `n_experts` receive of the
+    `n_tokens * k` slots under uniform routing, up to a multiple of
+    `GMM_ROWS` (the kernel's row tile). None where every expert is held or
+    the run would be over half of the `rows` the sort hands on: the path
+    then runs over all of them and no `cond` is traced."""
+    if held >= n_experts:
+        return None
+    tile = n_experts * GMM_ROWS
+    bound = -(-2 * n_tokens * k * held // tile) * GMM_ROWS
+    return bound if bound <= rows // 2 else None
+
+
+def _rows_ffn(m: int, impl: str, x, top_w, w_first, w_down, order, inverse,
+              counts, *, k: int, act: str):
+    """The path past the sort over the m leading rows of the sorted order
+    (all of them, or `row_bound`'s run, which then holds every held
+    expert's rows): gather, the expert FFN, the weighted sum per token."""
+    import jax
+    import jax.numpy as jnp
+
+    slots_of, combine = _permutes()
+    held = w_first.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        if m < order.size:
+            order = order[:m]
+            # one more group, of no expert: the run's rows past the held
+            received = counts[:held]
+            counts = jnp.concatenate([received, m - received.sum(
+                keepdims=True)])
+        xs = slots_of(x, order, inverse, k)              # [m, d]
+    with jax.named_scope("moe/experts"):
+        ys = experts_ffn(xs, w_first, w_down, counts, impl, act)
+    with jax.named_scope("moe/combine"):
+        return combine(ys, top_w, order, inverse)
+
+
 def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
                 act: str = "silu"):
     import jax
     import jax.numpy as jnp
 
-    slots_of, combine = _permutes()
     n_experts = params["w_router"].shape[1]
     w_first = _first_matmul(params)
-    held, d_model, d_ff = w_first.shape[0], w_first.shape[1], \
-        w_first.shape[-1]
-    if held < top_e.shape[1]:
+    held = w_first.shape[0]
+    picked = top_e.shape[1]
+    if held < picked:
         # fewer experts held than a token picks, each at most once: of a
         # token's k slots the `held` that sort first hold every held one
         with jax.named_scope("moe/dispatch"):
@@ -329,17 +391,36 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
         counts = jnp.diff(jnp.searchsorted(
             sorted_expert, jnp.arange(n_experts + 1, dtype=jnp.int32))
         ).astype(jnp.int32)
-        xs = slots_of(x, order, inverse, k)              # [N·k, d]
-    with jax.named_scope("moe/experts"):
-        ys = experts_ffn(
-            xs, w_first, params["w_down"], counts,
-            grouped_matmul_impl(mesh, xs.shape[0], d_model, d_ff,
-                                gated=w_first.ndim == 4), act)
-    with jax.named_scope("moe/combine"):
-        y = combine(ys, top_w, order, inverse)
+    over = functools.partial(_rows_ffn, k=k, act=act)
+    past_the_sort = (x, top_w, w_first, params["w_down"], order, inverse,
+                     counts)
+    bound = row_bound(x.shape[0], picked, held, n_experts, order.size)
+    impl = grouped_matmul_impl(mesh, bound or order.size, w_first.shape[1],
+                               w_first.shape[-1], gated=w_first.ndim == 4)
+    if bound is None:
+        y = over(order.size, impl, *past_the_sort)
+        bounded = jnp.zeros((), jnp.int32)
+    else:
+        # the held experts' groups sort first, so their rows are the
+        # leading counts[:held].sum() and the device knows the number
+        # before the gather. The branch over every row is the dropless
+        # fallback. It keeps nothing for the backward pass but what it is
+        # given (a `cond`'s branches each return the residuals of both,
+        # the one not taken as zeros, and its own are N·k rows long), and
+        # its grouped matmuls are `ragged_dot`: every program with a
+        # second set of pallas kernels traces and lowers each of them
+        # anew, 8-11% of a job's warm set-up (PERF.md section 6, PR 36)
+        with jax.named_scope("moe/dispatch"):
+            fits = counts[:held].sum() <= bound
+            y = jax.lax.cond(
+                fits, functools.partial(over, bound, impl),
+                jax.checkpoint(functools.partial(over, order.size,
+                                                 "ragged_dot")),
+                *past_the_sort)
+            bounded = fits.astype(jnp.int32)
     if held < n_experts:
         counts = counts[:held]
-    return y, counts, jnp.zeros((), jnp.int32)
+    return y, counts, jnp.zeros((), jnp.int32), bounded
 
 
 def _capacity_ffn(params, x, top_w, top_e, capacity_factor, constrain):
@@ -421,6 +502,9 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
         router_prob        f32 [E]    mean router score
         dropped            int32 []   slots of held experts that ran
                                       through none
+        rows_bounded       int32 []   1 where the path past the sort ran
+                                      over `row_bound`'s run of rows, 0
+                                      where over every row
 
     from which `load_balancing_loss` makes the softmax router's aux loss.
     The top-k weights are renormalised to sum to 1 only if `norm_topk`;
@@ -454,13 +538,14 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
                                       rules=rules)
         y, counts, dropped = _capacity_ffn(params, x, top_w, top_e,
                                            capacity_factor, constrain)
+        bounded = jnp.zeros((), jnp.int32)
     else:
         rows = x
         if "w_latent_down" in params:
             with jax.named_scope("moe/latent"):
                 rows = jnp.einsum("nd,dr->nr", x, params["w_latent_down"])
-        y, counts, dropped = _sorted_ffn(params, rows, top_w, top_e, mesh,
-                                         expert_offset, act)
+        y, counts, dropped, bounded = _sorted_ffn(
+            params, rows, top_w, top_e, mesh, expert_offset, act)
         if "w_latent_up" in params:
             with jax.named_scope("moe/latent"):
                 y = jnp.einsum("nr,rd->nd", y, params["w_latent_up"])
@@ -474,7 +559,8 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
         with jax.named_scope("moe/router"):
             elsewhere = x.shape[0] * k - counts.sum()
     return y, {"tokens_per_expert": counts, "slots_elsewhere": elsewhere,
-               "router_prob": router_prob, "dropped": dropped}
+               "router_prob": router_prob, "dropped": dropped,
+               "rows_bounded": bounded}
 
 
 def moe_ffn_dense_reference(params: Dict[str, Any], x, *,

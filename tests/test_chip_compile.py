@@ -343,14 +343,15 @@ def test_latent_attention_share_step_compiles_for_the_v5e(v5e, policy,
     held, a shared expert) + head, as one train step for the v5e: splash's
     kernels at head_dim 256, `megablox` at the width 1024 does not divide
     (tiles from each call's shapes), held weights only in the grouped
-    matmuls, and the new scopes on what the compiler leaves."""
+    matmuls, the run of rows a share's path is bounded to behind a `cond`,
+    and the new scopes on what the compiler leaves."""
     import re
 
     import optax
 
     from ray_tpu.models import Transformer
     from ray_tpu.models.configs import TransformerConfig
-    from ray_tpu.ops.moe import grouped_matmul_impl
+    from ray_tpu.ops.moe import grouped_matmul_impl, row_bound
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.train_step import make_train_step
 
@@ -392,9 +393,16 @@ def test_latent_attention_share_step_compiles_for_the_v5e(v5e, policy,
     names = [name for name, _, _ in kernels]
     grouped = [(n, text, op) for n, text, op in kernels
                if re.match(r"t?gmm(\.\d+)?$", n)]   # text: the call's type
+    # the kernels run over `row_bound`'s run of 1,024 rows, not the 4,096
+    # slots; the path over every row is the other branch of one `cond` a
+    # pass (forward, remat's forward, backward), on `ragged_dot`
     assert sum(n.startswith("gmm") for n, _, _ in grouped) == 6, names
     assert sum(n.startswith("tgmm") for n, _, _ in grouped) == 2, names
     assert all("moe/experts" in op for _, _, op in grouped), grouped
+    assert row_bound(rows * seq, cfg.moe_top_k, 8, 64, slots) == 1024
+    assert {int(re.match(r"bf16\[(\d+),", text).group(1))
+            for n, text, _ in grouped if n.startswith("gmm")} == {1024}
+    assert len(re.findall(r" conditional\(", hlo)) == 3
     # the weights of the 8 held experts reach the kernels, never 64
     assert re.search(r"bf16\[8,2048,3072\]", hlo)
     assert re.search(r"bf16\[8,1536,2048\]", hlo)
@@ -427,7 +435,7 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
 
     from ray_tpu.models import Transformer
     from ray_tpu.models.configs import TransformerConfig
-    from ray_tpu.ops.moe import gmm_tiles, grouped_matmul_impl
+    from ray_tpu.ops.moe import gmm_tiles, grouped_matmul_impl, row_bound
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.train_step import make_train_step
 
@@ -474,9 +482,13 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     grouped = [(n, op) for n, _, op in kernels
                if re.match(r"t?gmm(\.\d+)?$", n)]
     # two scans with an expert sublayer each: per matmul the forward,
-    # remat's forward and the transpose for the rows; one for the weights
+    # remat's forward and the transpose for the rows; one for the weights:
+    # over `row_bound`'s run of 1,024 rows. The path over all 8,192 is the
+    # other branch of a `cond` a pass and scan, on `ragged_dot`
     assert sum(n.startswith("gmm") for n, _ in grouped) == 12, names
     assert sum(n.startswith("tgmm") for n, _ in grouped) == 4, names
+    assert row_bound(rows * seq, 22, 8, 512, held_rows) == 1024
+    assert len(re.findall(r" conditional\(", hlo)) == 6
     assert all("moe/experts" in op for _, op in grouped), grouped
     assert sum(n.startswith("splash_mha_fwd") for n in names) == 1, names
     assert sum(n.startswith("splash_mha_dkv") for n in names) == 1, names
