@@ -2661,6 +2661,15 @@ class CoreWorker:
             st = self.actors.get(actor_id.hex())
             return bool(st is not None and st.dead)
 
+    def actor_address(self, actor_id: ActorID
+                      ) -> Optional[Tuple[str, int]]:
+        """Where the actor's live incarnation serves RPCs, as far as
+        this caller has resolved it (a dict lookup, no RPC); None for an
+        actor never called from here, or dead."""
+        with self._lock:
+            st = self.actors.get(actor_id.hex())
+            return None if st is None or st.dead else st.address
+
     def actor_pending_calls(self, actor_id: ActorID) -> int:
         """Caller-side count of this actor's submitted-but-unfinished
         calls (reference max_pending_calls backpressure)."""

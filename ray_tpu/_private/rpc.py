@@ -32,8 +32,12 @@ _LEN = struct.Struct(">Q")
 # Server-handle spans are edge-sampled (Dapper-style): most handlers are
 # tens of µs and a per-dispatch record would tax every RPC by ~1%; one
 # in K still shows where server time goes, scaled by the rate. Blocking
-# ops keep their own always-on spans (store.wait / store.pull).
+# ops keep their own always-on spans (store.wait / store.pull). The tail
+# is not sampled: a dispatch that took _SERVER_SPAN_SLOW_S or more is
+# always recorded, with `sampled=1` (scale the `sampled=K` records by K,
+# count the others as they are).
 _SERVER_SPAN_SAMPLE_K = 16
+_SERVER_SPAN_SLOW_S = 5e-3
 _server_span_tick = itertools.count()
 
 
@@ -111,11 +115,10 @@ class _Handler(socketserver.BaseRequestHandler):
                     method, kwargs, oneway = item
                 else:
                     (method, kwargs), oneway = item, False
-                with _spans.span("rpc.server", method=method,
-                                 bytes=len(req),
-                                 sampled=_SERVER_SPAN_SAMPLE_K) \
-                        if next(_server_span_tick) \
-                        % _SERVER_SPAN_SAMPLE_K == 0 else _spans.NOOP:
+                t0 = _spans.begin()
+                sampled = next(_server_span_tick) \
+                    % _SERVER_SPAN_SAMPLE_K == 0
+                try:
                     # chaos plane server hook: delay / kill_worker rules
                     # (subsumes the old _chaos_delay env-var injection)
                     chaos_lib.on_server_dispatch(method)
@@ -148,6 +151,15 @@ class _Handler(socketserver.BaseRequestHandler):
                                 reply[1])
                         continue
                     _send_frame(sock, pickle.dumps(reply, protocol=5))
+                finally:
+                    # the tail rule: a handler that held a server thread
+                    # (and the interpreter) this long is always on record
+                    if sampled or time.perf_counter() - t0 \
+                            >= _SERVER_SPAN_SLOW_S:
+                        _spans.end(
+                            "rpc.server", t0, method=method,
+                            bytes=len(req),
+                            sampled=_SERVER_SPAN_SAMPLE_K if sampled else 1)
         except (ConnectionLost, ConnectionResetError, BrokenPipeError, OSError):
             return
         finally:

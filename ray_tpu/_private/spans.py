@@ -25,14 +25,22 @@ ph "X" = complete span, "i" = instant event (Chrome trace phases).
 
 from __future__ import annotations
 
+import gc
 import os
 import sys
 import threading
 import uuid
 from _thread import get_ident as _get_ident
-from time import perf_counter
+from collections import OrderedDict
+from time import perf_counter, thread_time
 from time import time as _wall_time
 from typing import Any, Dict, Iterable, List, Optional
+
+try:  # RUSAGE_THREAD is Linux's; elsewhere thread_usage() has no ivcsw
+    import resource as _resource
+    _RUSAGE_THREAD = getattr(_resource, "RUSAGE_THREAD", None)
+except ImportError:  # pragma: no cover - no resource module
+    _resource, _RUSAGE_THREAD = None, None
 
 # One id per interpreter: snapshots are deduped on it when a process is
 # reachable through two fan-out paths (e.g. the head process hosts the
@@ -131,6 +139,11 @@ def configure(enabled: Optional[bool] = None,
     global _enabled, _RING
     if enabled is not None:
         _enabled = bool(enabled)
+        hooked = _on_gc in gc.callbacks
+        if _enabled and not hooked:
+            gc.callbacks.append(_on_gc)
+        elif hooked and not _enabled:
+            gc.callbacks.remove(_on_gc)
     if capacity is not None:
         _RING = SpanRing(capacity)
 
@@ -331,6 +344,108 @@ def instant(name: str, /, **attrs: Any) -> None:
     _RING.record(("i", name, perf_counter(), 0.0,
                   _get_ident(), getattr(_tls, "trace_id", None),
                   attrs or None))
+
+
+def thread_usage() -> Dict[str, Any]:
+    """What the calling thread cost its host since its previous call
+    here (the first: since the thread began): `cpu_s`, its CPU time, and
+    `ivcsw`, the times the kernel took the CPU from it. The attrs of a
+    loop's per-step span (train.step): a step that ran long with the
+    thread off the CPU and switched out is the host's, one with neither
+    is the device's or the runtime's. Two cheap syscalls; {} when the
+    recorder is off."""
+    if not _enabled:
+        return {}
+    cpu = thread_time()
+    ivcsw = _resource.getrusage(_RUSAGE_THREAD).ru_nivcsw \
+        if _RUSAGE_THREAD is not None else 0
+    last_cpu, last_ivcsw = getattr(_tls, "usage", (0.0, 0))
+    _tls.usage = (cpu, ivcsw)
+    return {"cpu_s": cpu - last_cpu, "ivcsw": ivcsw - last_ivcsw}
+
+
+# ---------------------------------------------------------------------
+# What can hold the interpreter: the cyclic collector
+# ---------------------------------------------------------------------
+
+# a collection stops every thread of the process (it runs under the
+# GIL), so a full one over a heap that JAX tracing filled is a stall of
+# whatever loop was waiting, on whichever thread triggered it
+GC_SPAN = "gc.collect"
+GC_MIN_S = 1e-3
+_gc_t0 = 0.0
+_gc_annotation: Any = None
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """gc.callbacks hook: a collection of generation 2, or any that took
+    GC_MIN_S or more, is a `gc.collect` span on the thread it ran on;
+    younger, shorter ones cost two clock reads and record nothing.
+    Collections never nest (one at a time, under the GIL), so one module
+    slot holds the start. A full collection also enters a
+    `jax.profiler.TraceAnnotation` where JAX is imported, like traced():
+    known at the start, and rare. Takes no lock."""
+    global _gc_t0, _gc_annotation
+    if phase == "start":
+        _gc_t0 = perf_counter()
+        if info.get("generation") == 2 and _enabled:
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            if profiler is not None:
+                try:
+                    _gc_annotation = profiler.TraceAnnotation(GC_SPAN)
+                    _gc_annotation.__enter__()
+                except Exception:  # noqa: BLE001 - interpreter exit
+                    _gc_annotation = None
+        return
+    t1 = perf_counter()
+    annotation, _gc_annotation = _gc_annotation, None
+    if annotation is not None:
+        try:
+            annotation.__exit__(None, None, None)
+        except Exception:  # noqa: BLE001 - interpreter exit
+            pass
+    generation = info.get("generation")
+    if not _enabled or (generation != 2 and t1 - _gc_t0 < GC_MIN_S):
+        return
+    # a plain dict read: current_thread() would take threading's lock
+    # to register a foreign thread, and a collection can start anywhere
+    thread = getattr(threading, "_active", {}).get(_get_ident())
+    _RING.record(("X", GC_SPAN, _gc_t0, t1 - _gc_t0, _get_ident(), None,
+                  {"generation": generation,
+                   "collected": info.get("collected"),
+                   "thread": thread.name if thread is not None else ""}))
+
+
+if _enabled:   # installed with the ring; RAY_TPU_SPANS=0 hooks nothing
+    gc.callbacks.append(_on_gc)
+
+
+# ---------------------------------------------------------------------
+# Rings that outlive their process: a torn-down train gang's workers
+# ---------------------------------------------------------------------
+
+RETAINED_GANGS = 4
+# gang id -> {process label: snapshot}, oldest gang first
+_retained: "OrderedDict[str, Dict[str, Dict[str, Any]]]" = OrderedDict()
+
+
+def retain(gang: str, snaps: Iterable[Dict[str, Any]]) -> None:
+    """Keep worker snapshots (already stamped with `clock_offset_s`
+    against THIS process's wall clock) past their processes' death: the
+    train driver calls this as it tears a gang down, the one place a
+    retained ring comes from. Bounded: the newest snapshot per process
+    label of the newest RETAINED_GANGS gangs."""
+    kept = _retained.setdefault(gang, {})
+    for snap in snaps:
+        kept[snap.get("label") or f"proc-{snap.get('pid')}"] = snap
+    _retained.move_to_end(gang)
+    while len(_retained) > RETAINED_GANGS:
+        _retained.popitem(last=False)
+
+
+def retained_snapshots() -> List[Dict[str, Any]]:
+    """What retain() holds, oldest gang first."""
+    return [snap for kept in _retained.values() for snap in kept.values()]
 
 
 # ---------------------------------------------------------------------
@@ -548,7 +663,9 @@ def merge_snapshots(snaps: Iterable[Dict[str, Any]],
         pid = snap.get("label") or f"proc-{snap.get('pid')}"
         events.append({
             "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
-            "args": {"name": pid,
+            # `dropped`: how many records this ring had overwritten when
+            # it was read (a timeline that lost its head says so)
+            "args": {"name": pid, "dropped": int(snap.get("dropped") or 0),
                      **({"node_id": snap["node_id"][:12]}
                         if snap.get("node_id") else {})},
         })

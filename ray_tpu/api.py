@@ -169,16 +169,21 @@ def timeline(filename: Optional[str] = None, *, spans: bool = False,
     interleaves them with the task events plus CHAOS_FAULT_INJECTED
     cluster events. trace_id filters the dump to one `start_trace`
     block's task records and span records. Without a cluster (after
-    `shutdown()`), spans=True serves this process's own ring alone."""
+    `shutdown()`), spans=True serves this process's own ring, merged
+    with the rings a train driver kept of its torn-down gangs' workers
+    (`BackendExecutor._retain_rings`), on this process's timebase."""
     import json
 
     from ray_tpu._private import spans as spans_mod
     if spans and not is_initialized():
         # after shutdown() (or before init()) this process's own ring is
-        # what is left: the driver's spans of a finished fit()
-        # (train.gang.*), in the same event form
-        events = spans_mod.merge_snapshots([spans_mod.snapshot()],
-                                           trace_id=trace_id)
+        # what is left, and what it kept of its train workers': the
+        # driver's spans of a finished fit() (train.gang.*) and the
+        # loops' (train.step, host_sync.*, train.report, gc.collect),
+        # in the same event form
+        events = spans_mod.merge_snapshots(
+            [spans_mod.snapshot()] + spans_mod.retained_snapshots(),
+            trace_id=trace_id)
     else:
         events = _cluster_timeline(spans, trace_id)
     if filename:

@@ -171,9 +171,9 @@ def make_train_step(
         from ray_tpu.util import jax_sentinel
         with jax_sentinel.step_region("train.step"):
             # the dispatch, in the flight recorder and (spans.traced) on
-            # the host line of a device trace; the device's own time is
-            # under the scopes of models/transformer.py
-            with spans.traced("train.step"):
+            # the host line of a device trace, with the loop thread's CPU
+            # time and preemptions since its last step (cpu_s, ivcsw)
+            with spans.traced("train.step", **spans.thread_usage()):
                 return _jitted(state)(state, batch)
 
     # like jit's own .lower: ahead-of-time lowering of the same program

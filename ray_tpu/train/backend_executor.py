@@ -574,8 +574,46 @@ class BackendExecutor:
         lives so restarts resume from it."""
         self._latest_checkpoint_dir = checkpoint_dir
 
+    # the workers' flight recorders are pulled as a gang goes: one RPC
+    # a worker, best effort, short (a dead worker refuses at once; a
+    # frozen one costs this long)
+    RINGS_PULL_TIMEOUT_S = 1.5
+    # of a large gang the lowest ranks only: a ring is a few MB in the
+    # driver, and spans.retain keeps RETAINED_GANGS gangs of them
+    RINGS_MAX_WORKERS = 8
+
+    def _retain_rings(self) -> None:
+        """Keep each worker's span ring past the gang (the end of
+        fit(), every elastic re-form): the driver's `spans` module
+        holds the newest few, stamped with the worker's clock offset
+        against the driver's as the cluster collect estimates it (RPC
+        midpoint), and `ray_tpu.timeline(spans=True)` merges them with
+        the driver's own ring once the cluster is gone."""
+        from ray_tpu.train.elastic import _core_worker_or_none
+        cw = _core_worker_or_none()
+        if cw is None or not spans.enabled():
+            return
+        with spans.span("train.rings", **self._gang_attrs()) as sp:
+            ranks = self.worker_group.workers[:self.RINGS_MAX_WORKERS]
+            addrs = [a for a in (cw.actor_address(w._actor_id)
+                                 for w in ranks) if a]
+            snaps = []
+            for _addr, snap, t0, t1 in spans.pull_snapshots(
+                    addrs, "cw_spans_snapshot",
+                    timeout=self.RINGS_PULL_TIMEOUT_S, grace_s=0.5):
+                snap["clock_offset_s"] = \
+                    snap["wall_time"] - (t0 + t1) / 2.0
+                snaps.append(snap)
+            spans.retain(self._gang_uid or "", snaps)
+            sp["pulled"] = len(snaps)
+            sp["records"] = sum(len(s["spans"]) for s in snaps)
+
     def _teardown_group(self) -> None:
         if self.worker_group is not None:
+            try:
+                self._retain_rings()
+            except Exception:  # noqa: BLE001 - the rings are best-effort
+                pass
             try:
                 self._backend.on_shutdown(self.worker_group,
                                           self._backend_config)

@@ -21,10 +21,15 @@ Two signals:
     `__bool__`) and `jax.device_get` are patched to account the bytes
     they pull across, tagged by step region:
         ray_tpu_host_transfer_bytes_total{region=<region>}
-    Inside a region each forcing point also records a flight-recorder
-    span (`host_sync.<via>`) whose duration is the actual blocked wall
-    time, so `tools/perf_report.py` can attribute step time stalled on
-    syncs. As an escalation, RAY_TPU_JAX_SENTINEL_GUARD=log|disallow
+    On a thread that has run a step region each forcing point also
+    records a flight-recorder span (`host_sync.<via>`) whose duration
+    is the actual blocked wall time, so `tools/perf_report.py` can
+    attribute step time stalled on syncs: `region=<region>` inside one,
+    `region="after:<last region>"` past it — a train loop's
+    `float(metrics["loss"])` after `train_step` is
+    `host_sync.float{region="after:train.step"}`, the loop's wait for
+    the device. The span is `spans.traced`, so a device trace shows it
+    on the thread's host line too. As an escalation, RAY_TPU_JAX_SENTINEL_GUARD=log|disallow
     additionally applies jax's device→host transfer guard for the
     region scope — "log" names every transfer source C++-side,
     "disallow" turns hidden syncs into hard errors at the offending
@@ -49,8 +54,9 @@ from __future__ import annotations
 
 import os
 import threading
-from time import perf_counter
 from typing import Any, Dict, Optional
+
+from ray_tpu._private import spans as _spans
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -90,20 +96,31 @@ def current_region() -> Optional[str]:
 # ---------------------------------------------------------------------
 
 
-def _account(nbytes: int, via: str, t0: float) -> None:
+def _sync_span(via: str):
+    """The span around one forcing point: its blocked wall time under
+    the open step region's label, or `after:<the last one>` on a thread
+    that has left its region (the loop's own read of the step's
+    result). A thread that never ran a region gets the shared no-op."""
+    region = current_region()
+    if region is None:
+        last = getattr(_tls, "last_region", None)
+        if last is None:
+            return _spans.NOOP
+        region = "after:" + last
+    return _spans.traced(f"host_sync.{via}", region=region)
+
+
+def _account(nbytes: int, sp: Dict[str, Any]) -> None:
     """One observed device→host transfer: count the bytes against the
-    current step region, and inside a region also record the blocked
-    wall time as a host_sync span for perf_report's stall buckets."""
+    current step region (outside one: "untracked", which the watchdog
+    never judges) and put them on the forcing point's span."""
     if not _installed:
         return
     try:
-        region = current_region()
-        _xfer_counter.inc(float(max(0, nbytes)),
-                          tags={"region": region or "untracked"})
-        if region is not None:
-            from ray_tpu._private import spans as _spans
-            _spans.end(f"host_sync.{via}", t0,
-                       bytes=int(nbytes), region=region)
+        sp["bytes"] = int(nbytes)
+        _xfer_counter.inc(
+            float(max(0, nbytes)),
+            tags={"region": current_region() or "untracked"})
     except Exception:  # noqa: BLE001 - accounting must never break the
         pass           # transfer it observes
 
@@ -203,57 +220,42 @@ def install() -> bool:
         _orig["__bool__"] = ArrayImpl.__bool__
         _orig["device_get"] = jax.device_get
 
-        def item(self, *a):
-            t0 = perf_counter()
-            out = _orig["item"](self, *a)
-            if not _in_xfer():
-                _account(getattr(self, "nbytes", 0), "item", t0)
-            return out
-
-        def __array__(self, *a, **kw):
-            t0 = perf_counter()
-            out = _orig["__array__"](self, *a, **kw)
-            if not _in_xfer():
-                _account(getattr(self, "nbytes", 0), "asarray", t0)
-            return out
-
-        def _scalar(name: str):
+        def _forcing(name: str, via: str):
             orig = _orig[name]
 
-            def coerce(self):
-                t0 = perf_counter()
-                out = orig(self)
-                if not _in_xfer():
-                    _account(getattr(self, "nbytes", 0),
-                             name.strip("_"), t0)
+            def forcing(self, *a, **kw):
+                if _in_xfer():   # a leaf of an accounted device_get
+                    return orig(self, *a, **kw)
+                with _sync_span(via) as sp:
+                    out = orig(self, *a, **kw)
+                    _account(getattr(self, "nbytes", 0), sp)
                 return out
-            coerce.__name__ = name
-            return coerce
+            forcing.__name__ = name
+            return forcing
 
         def device_get(x):
             # reentrancy guard: device_get coerces each leaf through
             # __array__ — one accounted transfer, not two
             if _in_xfer():
                 return _orig["device_get"](x)
-            _tls.in_xfer = True
-            t0 = perf_counter()
-            try:
-                out = _orig["device_get"](x)
-            finally:
-                _tls.in_xfer = False
-            try:
-                total = sum(getattr(leaf, "nbytes", 0)
-                            for leaf in jax.tree_util.tree_leaves(x))
-            except Exception:  # noqa: BLE001 - odd pytree
-                total = 0
-            _account(total, "device_get", t0)
+            with _sync_span("device_get") as sp:
+                _tls.in_xfer = True
+                try:
+                    out = _orig["device_get"](x)
+                finally:
+                    _tls.in_xfer = False
+                try:
+                    total = sum(getattr(leaf, "nbytes", 0)
+                                for leaf in jax.tree_util.tree_leaves(x))
+                except Exception:  # noqa: BLE001 - odd pytree
+                    total = 0
+                _account(total, sp)
             return out
 
-        ArrayImpl.item = item
-        ArrayImpl.__array__ = __array__
-        ArrayImpl.__float__ = _scalar("__float__")
-        ArrayImpl.__int__ = _scalar("__int__")
-        ArrayImpl.__bool__ = _scalar("__bool__")
+        ArrayImpl.item = _forcing("item", "item")
+        ArrayImpl.__array__ = _forcing("__array__", "asarray")
+        for name in ("__float__", "__int__", "__bool__"):
+            setattr(ArrayImpl, name, _forcing(name, name.strip("_")))
         jax.device_get = device_get
         _installed = True
         return True
@@ -342,6 +344,8 @@ class _StepRegion:
         stack = getattr(_tls, "regions", None)
         if stack:
             stack.pop()
+        # what a forcing point past the region is labelled after
+        _tls.last_region = self.name
         return None
 
 

@@ -19,14 +19,29 @@ every wall-clock microsecond lands in exactly one bucket and the bucket
 percentages sum to 100. This replaces the hand-derived
 feed_xfer_stall_pct numbers in the RL bench with trace-derived ones.
 
+`--steps` is the loop view, for a train loop (`train.step` spans, the
+thread with the most of them): the period of each step (the start of one
+`train.step` to the next), the median, and for each step longer than 1.1
+medians its excess over the median — where on the loop thread it lay
+(`train.step` the dispatch, `host_sync.*` the wait for the device,
+`train.report`, none of them: the loop's own code), which spans of 1 ms
+or more on any other thread or process overlapped it (`gc.collect`,
+`rpc.server` by method, `cw.*`, the driver's), and the loop thread's
+`cpu_s` / `ivcsw` over the step. A stall with a span over it is named; one
+with the thread off the CPU (`cpu_s` short of the period it was not
+waiting, `ivcsw` up) and nothing over it is the host's; one with neither
+is the device's or the runtime's.
+
 Usage:
     python tools/perf_report.py TRACE.json [--format=json] [--out FILE]
+    python tools/perf_report.py TRACE.json --steps
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import statistics
 import sys
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -210,6 +225,211 @@ def goodput_view(report: Dict[str, Any]) -> Dict[str, Any]:
     }
 
 
+# ---------------------------------------------------------------------
+# The loop view (--steps)
+# ---------------------------------------------------------------------
+
+LOOP_SPAN = "train.step"
+# what a step's time on the loop thread is split into; the rest is
+# "other": the loop's own code between the program's spans
+LOOP_PARTS: Tuple[Tuple[str, str], ...] = (
+    ("train.step", "train.step"), ("host_sync", "host_sync."),
+    ("train.report", "train.report"))
+STALL_FACTOR = 1.1     # a stall step: longer than this many medians
+OVERLAP_MIN_S = 1e-3   # spans elsewhere shorter than this name nothing
+
+
+def _span_events(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    return [e for e in events
+            if e.get("ph") == "X" and e.get("cat") == "span"]
+
+
+def _label(e: Dict[str, Any]) -> str:
+    """`rpc.server:cw_push_task`, `task.run:next_result`, `gc.collect`."""
+    args = e.get("args") or {}
+    detail = args.get("method") or args.get("name")
+    return f"{e['name']}:{detail}" if detail else str(e["name"])
+
+
+def pick_loop_thread(events: List[Dict[str, Any]],
+                     process: Optional[str] = None,
+                     thread: Optional[str] = None
+                     ) -> Optional[Tuple[Any, Any]]:
+    """The (pid, tid) with the most `train.step` spans."""
+    count: Dict[Tuple[Any, Any], int] = {}
+    for e in _span_events(events):
+        if e.get("name") != LOOP_SPAN:
+            continue
+        if process is not None and str(e.get("pid")) != process:
+            continue
+        if thread is not None and str(e.get("tid")) != thread:
+            continue
+        key = (e.get("pid"), e.get("tid"))
+        count[key] = count.get(key, 0) + 1
+    return max(count, key=count.get) if count else None
+
+
+def loop_steps(events: List[Dict[str, Any]], key: Tuple[Any, Any],
+               lo_s: Optional[float] = None, hi_s: Optional[float] = None
+               ) -> List[Dict[str, Any]]:
+    """The loop thread's steps, oldest first: each from the start of one
+    `train.step` to the start of the next (the last `train.step` bounds
+    the step before it and makes none itself), with its seconds in each
+    of LOOP_PARTS and in none, the loop thread's `cpu_s` / `ivcsw` over
+    it (carried by the NEXT `train.step`: since the previous one) and
+    the largest `blocked_s` of its reports. `lo_s`/`hi_s` keep the steps
+    whose `train.step` starts in that range of the timeline's clock, and
+    a step needs its successor in range too."""
+    mine = sorted((e for e in _span_events(events)
+                   if (e.get("pid"), e.get("tid")) == key),
+                  key=lambda e: e["ts"])
+    marks = [e for e in mine if e["name"] == LOOP_SPAN
+             and (lo_s is None or e["ts"] / 1e6 >= lo_s)
+             and (hi_s is None or e["ts"] / 1e6 <= hi_s)]
+    steps: List[Dict[str, Any]] = []
+    for cur, nxt in zip(marks, marks[1:]):
+        start, end = cur["ts"] / 1e6, nxt["ts"] / 1e6
+        parts = {part: 0.0 for part, _prefix in LOOP_PARTS}
+        blocked = 0.0
+        for e in mine:
+            t0 = e["ts"] / 1e6
+            if not start <= t0 < end:
+                continue
+            for part, prefix in LOOP_PARTS:
+                if str(e["name"]).startswith(prefix):
+                    parts[part] += min(end, t0 + e.get("dur", 0.0) / 1e6) - t0
+                    break
+            if e["name"] == "train.report":
+                blocked = max(blocked, float(
+                    (e.get("args") or {}).get("blocked_s") or 0.0))
+        period = end - start
+        parts["other"] = max(0.0, period - sum(parts.values()))
+        args = nxt.get("args") or {}
+        steps.append({"start_s": start, "period_s": period, "parts": parts,
+                      "cpu_s": args.get("cpu_s"), "ivcsw": args.get("ivcsw"),
+                      "blocked_s": blocked})
+    return steps
+
+
+def _overlapping(elsewhere: List[Tuple[float, float, Dict[str, Any]]],
+                 start: float, end: float) -> Dict[Tuple[str, str], float]:
+    """(label, process) -> seconds inside [start, end) of the spans
+    elsewhere (t0, t1, event)."""
+    out: Dict[Tuple[str, str], float] = {}
+    for t0, t1, e in elsewhere:
+        if t1 > start and t0 < end:
+            k = (_label(e), str(e.get("pid")))
+            out[k] = out.get(k, 0.0) + min(t1, end) - max(t0, start)
+    return out
+
+
+def steps_report(events: List[Dict[str, Any]],
+                 process: Optional[str] = None,
+                 thread: Optional[str] = None,
+                 lo_s: Optional[float] = None,
+                 hi_s: Optional[float] = None) -> Optional[Dict[str, Any]]:
+    """The loop view: the steps' median period and every stall step with
+    where its excess lay and what overlapped it. None without a thread
+    that ran `train.step` at least three times in range.
+
+    What NAMES a stall: a span elsewhere names the part of its seconds in
+    the step that is over its usual share of a step (the median, over the
+    other steps, of its seconds a second of step). A wait for the loop
+    itself — the actor's `task.run:next_result`, the driver's `cw.get`
+    on it — covers nine tenths of every step, stretches with a stall and
+    so names none of it; a `gc.collect` that the other steps do not have
+    names all it overlaps. `train.report`'s `blocked_s` over its usual
+    names too (the driver's round held the loop). The sum is capped at
+    the step's excess; the rest is unnamed."""
+    key = pick_loop_thread(events, process, thread)
+    if key is None:
+        return None
+    steps = loop_steps(events, key, lo_s, hi_s)
+    if len(steps) < 2:
+        return None
+    median = statistics.median([s["period_s"] for s in steps])
+    stalled = [s["period_s"] > STALL_FACTOR * median for s in steps]
+    calm = [i for i, bad in enumerate(stalled) if not bad] \
+        or list(range(len(steps)))
+    part_names = [part for part, _ in LOOP_PARTS] + ["other"]
+    usual = {part: statistics.median([steps[i]["parts"][part] for i in calm])
+             for part in part_names}
+    # spans of OVERLAP_MIN_S or more on any other thread or process
+    elsewhere = [(e["ts"] / 1e6, (e["ts"] + e.get("dur", 0.0)) / 1e6, e)
+                 for e in _span_events(events)
+                 if (e.get("pid"), e.get("tid")) != key
+                 and e.get("dur", 0.0) / 1e6 >= OVERLAP_MIN_S]
+    over = [_overlapping(elsewhere, s["start_s"],
+                         s["start_s"] + s["period_s"]) for s in steps]
+    usual_blocked = statistics.median([steps[i]["blocked_s"] for i in calm])
+    stalls = []
+    for i, (step, bad) in enumerate(zip(steps, stalled)):
+        if not bad:
+            continue
+        excess = step["period_s"] - median
+        rows = []
+        for k, seconds in over[i].items():
+            share = statistics.median([over[c].get(k, 0.0) / steps[c]["period_s"]
+                             for c in calm])
+            rows.append({"name": k[0], "process": k[1], "seconds": seconds,
+                         "over_usual_s": max(
+                             0.0, seconds - share * step["period_s"])})
+        rows.sort(key=lambda r: (-r["over_usual_s"], -r["seconds"]))
+        named = min(excess, sum(r["over_usual_s"] for r in rows)
+                    + max(0.0, step["blocked_s"] - usual_blocked))
+        stalls.append({
+            "step": i,
+            "at_s": step["start_s"] - steps[0]["start_s"],
+            "period_s": step["period_s"], "excess_s": excess,
+            "lay": {part: step["parts"][part] - usual[part]
+                    for part in part_names},
+            "overlapped": rows,
+            "named_s": named, "unnamed_s": excess - named,
+            "cpu_s": step["cpu_s"], "ivcsw": step["ivcsw"],
+            "blocked_s": step["blocked_s"]})
+    total = sum(s["period_s"] for s in steps)
+    return {"process": str(key[0]), "thread": str(key[1]),
+            "steps": len(steps), "median_period_s": median,
+            "total_s": total, "usual_parts_s": usual,
+            "usual_cpu_s": statistics.median(
+                [steps[i]["cpu_s"] or 0.0 for i in calm]),
+            "stall_s": sum(s["excess_s"] for s in stalls),
+            "unnamed_s": sum(s["unnamed_s"] for s in stalls),
+            "stalls": stalls}
+
+
+def format_steps(report: Dict[str, Any]) -> str:
+    usual = report["usual_parts_s"]
+    lines = [
+        f"loop report — process {report['process']} thread "
+        f"{report['thread']}",
+        f"{report['steps']} steps over {report['total_s']:.3f} s, median "
+        f"period {report['median_period_s'] * 1e3:.2f} ms ("
+        + ", ".join(f"{part} {s * 1e3:.2f}" for part, s in usual.items())
+        + f"; cpu {report['usual_cpu_s'] * 1e3:.2f} ms)",
+        f"stall steps (> {STALL_FACTOR} medians): {len(report['stalls'])}, "
+        f"excess {report['stall_s'] * 1e3:.1f} ms = "
+        f"{100 * report['stall_s'] / report['total_s']:.3f}% of the steps' "
+        f"time, unnamed {report['unnamed_s'] * 1e3:.1f} ms"]
+    for s in report["stalls"]:
+        lay = ", ".join(f"{part} {v * 1e3:+.1f}"
+                        for part, v in s["lay"].items() if abs(v) >= 5e-4)
+        cpu = "n/a" if s["cpu_s"] is None else f"{s['cpu_s'] * 1e3:.1f} ms"
+        lines.append(
+            f"  step {s['step']} at {s['at_s']:.3f} s: period "
+            f"{s['period_s'] * 1e3:.1f} ms, excess "
+            f"{s['excess_s'] * 1e3:.1f} ms; lay in: {lay or 'nothing'}; "
+            f"cpu {cpu}, ivcsw {s['ivcsw']}; named "
+            f"{s['named_s'] * 1e3:.1f} ms")
+        for o in s["overlapped"][:8]:
+            lines.append(f"      {o['seconds'] * 1e3:9.1f} ms "
+                         f"({o['over_usual_s'] * 1e3:+.1f} over its usual)"
+                         f"  {o['name']}  [{o['process']}]")
+        if not s["overlapped"]:
+            lines.append("      nothing of 1 ms or more overlapped it")
+    return "\n".join(lines)
+
+
 def format_text(report: Dict[str, Any]) -> str:
     lines = [f"perf report — process {report['process']} "
              f"thread {report['thread']}",
@@ -238,15 +458,27 @@ def main(argv=None) -> int:
                     help="restrict to one thread id")
     ap.add_argument("--format", choices=["text", "json"], default="text")
     ap.add_argument("--out", default=None, help="write JSON report here")
+    ap.add_argument("--steps", action="store_true",
+                    help="the loop view: a train loop's steps and stalls")
     args = ap.parse_args(argv)
 
     with open(args.trace) as f:
         events = json.load(f)
-    report = attribute(events, process=args.process, thread=args.thread)
+    if args.steps:
+        report = steps_report(events, process=args.process,
+                              thread=args.thread)
+        if report is None:
+            raise SystemExit("no thread with three `train.step` spans in "
+                             "trace (a train loop, exported with --spans?)")
+        text = format_steps(report)
+    else:
+        report = attribute(events, process=args.process,
+                           thread=args.thread)
+        text = format_text(report)
     if args.format == "json":
         print(json.dumps(report, indent=1))
     else:
-        print(format_text(report))
+        print(text)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(report, f, indent=1)
