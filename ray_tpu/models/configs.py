@@ -55,8 +55,11 @@ class TransformerConfig:
     remat: bool = False                   # jax.checkpoint each layer
     # what a checkpointed layer keeps for its backward pass besides the
     # scan's carry (Transformer._remat). "attention": the flash forward
-    # kernel's output and logsumexp, so the kernel is not run again (a
-    # layer without the flash kernel names nothing and is "full"'s
+    # kernel's output and logsumexp, so the kernel is not run again, and
+    # an expert layer's routing (the router's f32 logits, the chosen
+    # experts, the sort's permutations and counts), so neither are the
+    # router's product, the top-k and the sorts (a layer without the
+    # flash kernel and without a router names nothing and is "full"'s
     # program); "full": nothing, the whole layer is recomputed (min
     # memory, +1 fwd pass); "dots": "attention" and every matmul's output
     # (near-zero recompute FLOPs, fastest when activations fit). Any

@@ -400,25 +400,33 @@ class Transformer:
         """`layer` under cfg's rematerialization: the one place a layer is
         wrapped in `jax.checkpoint`.
 
-        The default, "attention", keeps per layer what the flash forward
-        kernel hands its backward (`ops.attention.FLASH_RESIDUALS`: the
-        output [B, H, T, Dv] in the compute dtype and the logsumexp
-        [B, H, T] in f32) beside the scan's carry, and recomputes the rest
-        of the layer (norms, projections, RoPE, the MLP or the experts).
-        The backward pass then runs the forward kernel once a step, not
-        twice. The rule adapts by what the traced layer holds: only the
-        flash path names those values, so under `dense`, `ring` or
-        `ulysses` attention nothing is named, nothing is saved and the
-        program is "full"'s. At many layers it costs L x B*T*H*Dv x 2
-        bytes (+ L x B*H*T x 4) more than "full", which saves nothing but
-        the carry and is there for whoever needs those bytes. "dots" saves
-        every matmul's output besides."""
+        The default, "attention", keeps per layer, beside the scan's
+        carry, what the layer names: what the flash forward kernel hands
+        its backward (`ops.attention.FLASH_RESIDUALS`: the output
+        [B, H, T, Dv] in the compute dtype and the logsumexp [B, H, T] in
+        f32) and an expert layer's routing (`ops.moe.ROUTING_RESIDUALS`:
+        the router's logits [N, E] f32, the chosen experts [N, k], the
+        sort's two permutations [N·min(k, held)] and counts [E], int32).
+        It recomputes the rest of the layer (norms, projections, RoPE,
+        the MLP or the experts past the sort). The backward pass then
+        runs the forward kernel, the f32 router product, the top-k and
+        the sorts once a step, not twice. The rule adapts by what the
+        traced layer holds: only the flash path and a router name those
+        values, so under `dense`, `ring` or `ulysses` attention with a
+        dense FFN nothing is named, nothing is saved and the program is
+        "full"'s. At many layers it costs L x B*T*H*Dv x 2 bytes
+        (+ L x B*H*T x 4) and, an expert layer, N*E x 4 bytes (+ the
+        int32s) more than "full", which saves nothing but the carry and
+        is there for whoever needs those bytes. "dots" saves every
+        matmul's output besides."""
         import jax
 
         from ray_tpu.ops.attention import FLASH_RESIDUALS
+        from ray_tpu.ops.moe import ROUTING_RESIDUALS
 
         policies = jax.checkpoint_policies
-        residuals = policies.save_only_these_names(FLASH_RESIDUALS)
+        residuals = policies.save_only_these_names(FLASH_RESIDUALS,
+                                                   ROUTING_RESIDUALS)
         known = {"attention": residuals, "full": None,
                  "dots": policies.save_from_both_policies(
                      policies.checkpoint_dots, residuals)}

@@ -92,6 +92,22 @@ from typing import Any, Dict, Optional, Tuple
 from ray_tpu.parallel.sharding import (ShardingRules, spec_entry_size,
                                        with_logical_constraint)
 
+# The name (`jax.ad_checkpoint.checkpoint_name`) of what a checkpointed
+# expert layer keeps of its routing, as `ops.attention.FLASH_RESIDUALS` is
+# of the attention kernel's: the router's logits `[N, E]` f32, the chosen
+# experts `[N, k]` int32 and their scores `[N, k]` f32 as gathered
+# (`route`); `keep` `[N, held]` where fewer experts are held than a token
+# picks, and the sort's `order`, `inverse` `[N·min(k, held)]` and `counts`
+# `[E]` (`_sorted_ffn`), all int32. `Transformer._remat`'s policy saves
+# them, so the backward pass runs no router product, no top-k, no gather
+# of the chosen scores and no sort again: the scores, the weights'
+# normalisation and the one-hot through `keep` are remade from these by
+# elementwise work. The LOGITS and not the scores: a sigmoid's and a
+# softmax's derivative is written in its own output, so the backward asks
+# for the un-named value that went into the naming, and remat would make
+# it from the product. Outside a `jax.checkpoint` a name is the identity.
+ROUTING_RESIDUALS = "routing_residuals"
+
 # Logical specs for shard_pytree / make_train_step param placement.
 MOE_PARAM_SPECS = {
     "w_router": ("embed", None),
@@ -147,27 +163,63 @@ def route(w_router, x, num_selected: int, norm_topk: bool, *,
     it; the bias is a buffer, no gradient reaches it."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
 
-    logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32),
-                        w_router.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
+    logits = checkpoint_name(
+        jnp.einsum("nd,de->ne", x.astype(jnp.float32),
+                   w_router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST), ROUTING_RESIDUALS)
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown router scoring {scoring!r}")
     probs = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
         else jax.nn.sigmoid(logits)
-    if bias is None:
-        top_w, top_e = jax.lax.top_k(probs, num_selected)
-    else:
-        _, top_e = jax.lax.top_k(
-            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
-            num_selected)
-        top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+    choice = probs if bias is None else probs + jax.lax.stop_gradient(
+        bias.astype(jnp.float32))
+    values, top_e = jax.lax.top_k(choice, num_selected)
+    top_e = checkpoint_name(top_e, ROUTING_RESIDUALS)
+    # the chosen scores: `top_k`'s own values where nothing was added to
+    # them, else gathered. Kept too: the gather of N·k scalars from [N, E]
+    # is 1.8 ms a layer on the v5e at 8,192 x 22 of 512
+    top_w = checkpoint_name(
+        _scores_at()(probs, jax.lax.stop_gradient(values), top_e)
+        if bias is None else jnp.take_along_axis(probs, top_e, axis=-1),
+        ROUTING_RESIDUALS)
     if norm_topk:
         top_w = top_w / jnp.maximum(
             top_w.sum(axis=-1, keepdims=True), 1e-9)
     if routed_scale != 1.0:
         top_w = top_w * routed_scale
     return probs, top_w, top_e
+
+
+@functools.lru_cache(maxsize=None)
+def _scores_at():
+    """`scores_at(probs, values, top_e)`: `top_k`'s values as what they
+    are, `take_along_axis(probs, top_e)`. Forward the values `top_k`
+    made, no gather (N·k scalars from `[N, E]` are 1.4 ms a layer on the
+    v5e at 16,384 x 8 of 64); backward that gather's transpose at the ids
+    it is handed, the NAMED ones: `top_k`'s own derivative reads the ids
+    it made itself, and remat would run it again for them. Built on first
+    use, as `_permutes`."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def scores_at(probs, values, top_e):
+        return values
+
+    def fwd(probs, values, top_e):
+        return values, (top_e, jax.ShapeDtypeStruct(probs.shape, probs.dtype))
+
+    def bwd(res, g):
+        top_e, like = res
+        dprobs, = jax.linear_transpose(
+            lambda probs: jnp.take_along_axis(probs, top_e, axis=-1),
+            like)(g)
+        return dprobs, jnp.zeros_like(g), None   # the ids are integers
+
+    scores_at.defvjp(fwd, bwd)
+    return scores_at
 
 
 def load_balancing_loss(tokens_per_expert, router_prob, top_k: int):
@@ -362,6 +414,7 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
                 act: str = "silu"):
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
 
     n_experts = params["w_router"].shape[1]
     w_first = _first_matmul(params)
@@ -373,6 +426,7 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
         with jax.named_scope("moe/dispatch"):
             _, keep = jax.lax.top_k(
                 -((top_e - expert_offset) % n_experts), held)
+            keep = checkpoint_name(keep, ROUTING_RESIDUALS)
             top_e = jnp.take_along_axis(top_e, keep, axis=1)
             # the weights through a one-hot: its transpose is no scatter
             top_w = jnp.einsum("nk,nck->nc", top_w, jax.nn.one_hot(
@@ -391,6 +445,8 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
         counts = jnp.diff(jnp.searchsorted(
             sorted_expert, jnp.arange(n_experts + 1, dtype=jnp.int32))
         ).astype(jnp.int32)
+        order, inverse, counts = checkpoint_name(
+            (order, inverse, counts), ROUTING_RESIDUALS)
     over = functools.partial(_rows_ffn, k=k, act=act)
     past_the_sort = (x, top_w, w_first, params["w_down"], order, inverse,
                      counts)

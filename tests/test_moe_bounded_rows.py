@@ -223,3 +223,41 @@ def test_a_config_with_every_expert_held_has_no_such_counter():
     _, metrics = Transformer.loss(params, batch, cfg, with_metrics=True)
     assert "moe_rows_bounded" not in metrics
     assert "moe_slots_elsewhere" not in metrics
+
+
+@pytest.mark.parametrize("flood,bounded", [(0.0, 1), (1.0, 0)],
+                         ids=["bounded_run", "fallback"])
+def test_the_kept_routing_serves_both_branches(flood, bounded):
+    """Under the default remat policy a layer keeps its routing
+    (`moe.ROUTING_RESIDUALS`: the sort's `order`, `inverse` and `counts`
+    are made before the `cond` and are both branches' operands). A step
+    whose held rows fit the run, and one whose choice bias sends every
+    slot to the held experts and overruns it: the loss and the routing
+    record of `remat_policy="full"` to the bit, its gradients to rounding
+    (XLA:CPU fuses the forward it runs again in its own way)."""
+    params = Transformer.init(jax.random.key(3), CFG)
+    params["embed"] = jax.random.normal(jax.random.key(5),
+                                        params["embed"].shape)
+    params["layers"]["router_bias"] = jnp.broadcast_to(
+        jnp.where(jnp.arange(E) < 8, flood, 0.0), (CFG.n_layers, E))
+    batch = {"tokens": jax.random.randint(
+        jax.random.key(4), (2, 257), 0, CFG.vocab_size)}
+
+    def step(policy):
+        cfg = CFG.replace(remat_policy=policy)
+        return jax.jit(jax.value_and_grad(
+            lambda p: Transformer.loss(p, batch, cfg, with_metrics=True),
+            has_aux=True))(params)
+
+    (loss, metrics), grads = step("attention")
+    np.testing.assert_array_equal(metrics["moe_rows_bounded"], bounded)
+    assert int(metrics["moe_dropped"]) == 0
+    if not bounded:      # every slot on the held experts: four runs' worth
+        np.testing.assert_array_equal(metrics["moe_slots_elsewhere"], 0)
+    (want_loss, want_metrics), want_grads = step("full")
+    assert float(loss) == float(want_loss)
+    jax.tree.map(np.testing.assert_array_equal, metrics, want_metrics)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(got, want, atol=2e-6 * scale, rtol=0)
+    assert float(jnp.abs(grads["layers"]["w_router"]).max()) > 0
