@@ -638,7 +638,7 @@ class Transformer:
             out = mamba2_mixer(
                 h, dict(lp, w_in=w_in, w_out=w_out),
                 head_dim=cfg.ssm_head_dim, state=cfg.ssm_state,
-                chunk=cfg.ssm_chunk, eps=cfg.norm_eps)
+                chunk=cfg.ssm_chunk, eps=cfg.norm_eps, mesh=mesh)
             with jax.named_scope("ssm/out_proj"):
                 return x + constrain(out, ("batch", "seq", "act_embed"))
 

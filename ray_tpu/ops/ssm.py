@@ -13,18 +13,50 @@ over G groups of state N (Dao & Gu, "Transformers are SSMs", 2024, as
     y = grouped_rmsnorm(y * silu(z)) * gain   groups of H·P / G channels
     out = y W_out
 
-**The scan in chunks** (`ssd_scan`): within a chunk of Q steps the
-recurrence unrolls into the quadratic form `y_i = sum_{j<=i} L_ij (C_i.B_j)
-dt_j x_j` with `L_ij = exp(sum_{j<m<=i} dt_m A)`, three einsums over
-`[chunks, Q, Q]` blocks; each chunk's contribution to the state is one
-more einsum, and the T/Q chunk states are chained by a `lax.scan`. The
-decays are float32; the products take their operands in the compute dtype
-and accumulate in float32. Nothing is `[T, T]`: at T = 8,192 and Q = 128
-the largest temporaries are the `[T/Q, H, Q, Q]` decay blocks and the
-`[T/Q, H, P, N]` chunk states, and autodiff's residuals are those, so the
-backward pass holds in the same memory. T must be a multiple of the
-chunk: any other T is refused, not padded. Plain XLA; a pallas kernel is
-later work (ROADMAP R4).
+**The scan in chunks**: within a chunk of Q steps the recurrence unrolls
+into the quadratic form `y_i = sum_{j<=i} L_ij (C_i.B_j) dt_j x_j` with
+`L_ij = exp(sum_{j<m<=i} dt_m A)`; what a chunk adds to the state is one
+more product, and the T/Q chunk states are chained. The decays, their
+running sums `cum` and the state are float32; the products take their
+operands in the compute dtype and accumulate in float32; `y` leaves in
+float32. Nothing is `[T, T]`. T must be a multiple of the chunk: any
+other T is refused, not padded. Two implementations of the one
+algorithm, chosen at trace time by `ssd_scan_impl` (no option, in the
+manner of `ops/moe.grouped_matmul_impl`):
+
+- `"xla"` (`ssd_scan`): three einsums over `[T/Q, H, Q, Q]` blocks, the
+  chunk states, a `lax.scan` over them, autodiff's backward. The blocks
+  and the `[T/Q, H, P, N]` states go through HBM. It runs on the CPU, on
+  a mesh above one device (GSPMD cannot partition a pallas call) and for
+  every shape the kernel does not tile, and it is the oracle of the
+  kernel's tests.
+- `"pallas"` (`ssd_scan_pallas`): on one TPU device where the shapes tile
+  (`scan_shape_ok`). Grid `(batch, head blocks, chunks)`, the chunk axis
+  sequential; one grid step takes one chunk and the heads of one block
+  (`scan_head_block`: at most `SCAN_WIDTH` lanes of `x`, inside one B/C
+  group). It reads `x [Q, heads·P]`, `B [Q, N]`, `C [Q, N]` as lane-dense
+  blocks of the `[B, T, H·P]` and `[B, T, G·N]` layouts the convolution
+  leaves (no transpose or reshape of `x` or `y` around the call), `dt`
+  with the steps on the lanes `[heads, Q]` (1 MB, turned by XLA) and `a`.
+  In VMEM and never in HBM: `cum` (a float32 product of `dt a` with a
+  0/1 matrix at the highest precision: the running sum and both of its
+  layouts in one), `C Bᵀ` once a group, per head the masked decay
+  `exp(cum_i - cum_j)` (masked before the exponential) and the weights
+  `[Q, Q]`, and the carried state `[N, heads·P]` float32 in a scratch
+  that persists over the chunk axis. Two heads of 64 share a lane tile:
+  each head's weights multiply the tile's 128 lanes (the MXU is that
+  wide anyway) and a lane mask keeps its half. The forward
+  (`ssd_scan_fwd`) also writes the state entering each chunk,
+  `[T/Q, N, H·P]` float32: its one residual, alive between remat's
+  forward and the backward of the same layer. The backward
+  (`ssd_scan_bwd`, under `jax.custom_vjp`) walks the chunks in reverse
+  with the state's cotangent carried in VMEM, makes a chunk's `cum`,
+  decays and weights again, reads `x`, `B`, `C`, `dt`, `a`, the entering
+  states and `dy`, and returns `dx`, `dB`, `dC`, `ddt` and the cotangent
+  of `dt a`, from which `da` is one sum. Roundings as above; `x *
+  to_end` is formed in float32 before its one rounding where `ssd_scan`
+  multiplies two rounded factors, and `cum`'s sums are added in the
+  MXU's order, not `cumsum`'s.
 
 **A share of the heads.** The mixer is told its heads and groups by the
 weights it is given (`A_log` has H entries, the convolution H·P + 2·G·N
@@ -40,6 +72,7 @@ Scopes (PERF.md section 3): `ssm/in_proj`, `ssm/conv`, `ssm/scan`,
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 
@@ -57,6 +90,14 @@ def causal_conv(x, w, b):
     return y
 
 
+def _whole_chunks(t: int, chunk: int) -> int:
+    """T / chunk; a T that is no whole chunks is refused, not padded."""
+    if t % chunk:
+        raise ValueError(f"the scan takes whole chunks: {t} steps are no "
+                         f"multiple of {chunk}")
+    return t // chunk
+
+
 def ssd_scan(x, dt, a, b, c, chunk: int):
     """The selective scan `S_t = exp(dt_t a) S_{t-1} + dt_t x_t b_t^T`,
     `y_t = S_t c_t` in chunks: x `[B, T, H, P]`, dt `[B, T, H]` (after
@@ -68,10 +109,7 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
 
     bsz, t, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
-    if t % chunk:
-        raise ValueError(f"the scan takes whole chunks: {t} steps are no "
-                         f"multiple of {chunk}")
-    nc, q, r = t // chunk, chunk, h // g
+    nc, q, r = _whole_chunks(t, chunk), chunk, h // g
     f32 = jnp.float32
     xc = x.reshape(bsz, nc, q, g, r, p)
     bc = b.reshape(bsz, nc, q, g, n)
@@ -114,27 +152,442 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
     return y.reshape(bsz, t, h, p)
 
 
+# ---- the scan as a pallas TPU kernel ------------------------------------
+# One grid step takes at most SCAN_WIDTH lanes of x: 16 heads of 64, the
+# whole of a Nemotron group, 0.5 MB of carried state in VMEM (the fastest
+# of 16, 8, 4 and 2 heads a step on the v5e: PERF.md section 6, PR 42).
+SCAN_LANES = 128
+SCAN_WIDTH = 1024
+SCAN_CHUNKS = (128, 256)
+
+
+def scan_head_block(heads_per_group: int, head_dim: int):
+    """Heads one grid step of the kernel takes: the most that divide a
+    B/C group, are whole sublane tiles of 8 (dt reaches the kernel with
+    the heads on the sublanes) and stay inside `SCAN_WIDTH` lanes of x;
+    None where no count does."""
+    return next((hb for hb in range(heads_per_group, 0, -1)
+                 if heads_per_group % hb == 0 and hb % 8 == 0
+                 and hb * head_dim <= SCAN_WIDTH), None)
+
+
+def scan_shape_ok(seq_len: int, heads: int, head_dim: int, groups: int,
+                  state: int, chunk: int) -> bool:
+    """Whether the kernel tiles the scan: whole chunks of 128 or 256
+    steps, a head width that divides a lane tile, a state of whole lane
+    tiles, a head block (`scan_head_block`)."""
+    return (chunk in SCAN_CHUNKS and seq_len % chunk == 0
+            and head_dim in (64, 128) and state % SCAN_LANES == 0
+            and heads % groups == 0
+            and scan_head_block(heads // groups, head_dim) is not None)
+
+
+def ssd_scan_impl(mesh, seq_len: int, heads: int, head_dim: int,
+                  groups: int, state: int, chunk: int) -> str:
+    """`"pallas"` (`ssd_scan_pallas`) where the program runs on one TPU
+    device and the kernel tiles the shapes (`scan_shape_ok`), else
+    `"xla"` (`ssd_scan`: any platform, any whole number of chunks, and
+    GSPMD can partition it, which it cannot a pallas call). Decided at
+    trace time, like `ops/moe.grouped_matmul_impl`; a job may print it to
+    say what a step compiled with."""
+    import jax
+
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    one_device = mesh is None or mesh.size == 1
+    return "pallas" if device.platform == "tpu" and one_device and \
+        scan_shape_ok(seq_len, heads, head_dim, groups, state, chunk) \
+        else "xla"
+
+
+def _head_lanes(hb: int, p: int):
+    """The kernel's walk over a block's heads: per lane tile of x, the
+    heads that lie in it as (index in the block, lane mask or None where
+    a head is the whole tile)."""
+    import jax
+    import jax.numpy as jnp
+
+    per_tile = SCAN_LANES // p
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, SCAN_LANES), 1)
+    tiles = []
+    for t in range(hb * p // SCAN_LANES):
+        tiles.append((slice(t * SCAN_LANES, (t + 1) * SCAN_LANES), [
+            (t * per_tile + k,
+             None if per_tile == 1 else
+             (lane >= k * p) & (lane < (k + 1) * p))
+            for k in range(per_tile)]))
+    return tiles
+
+
+def _last_row(v):
+    """`v[-1:]` of a `[Q, lanes]` value as a masked sum: Mosaic folds a
+    slice of a lane broadcast into a broadcast both ways, which it does
+    not lower."""
+    import jax
+    import jax.numpy as jnp
+
+    last = jax.lax.broadcasted_iota(
+        jnp.int32, (v.shape[0], 1), 0) == v.shape[0] - 1
+    return jnp.sum(jnp.where(last, v, 0.0), axis=0, keepdims=True)
+
+
+def _by_head(heads, column):
+    """`[rows, 1]` columns of some heads laid over a tile's lanes:
+    `column(h)` on the lanes of head h."""
+    import jax.numpy as jnp
+
+    out = None
+    for h, mask in heads:
+        out = column(h) if out is None or mask is None else jnp.where(
+            mask, column(h), out)
+    return out
+
+
+def _decays(dt_ref, a_ref):
+    """dt `[hb, Q]` (the steps on the lanes) and a `[hb, 1]` of a block's
+    heads -> dt and `cum`, the log-decay up to and including each step of
+    the chunk, both ways round: `[Q, 128]` with head h in lane h, and
+    `[hb, Q]`; and the `[Q, Q]` 0/1 matrix of the steps j <= i. The
+    running sum and the turn are float32 products with a 0/1 matrix at
+    the highest precision: the MXU adds what a `cumsum` adds, and turns
+    exactly."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    hb, q = dt_ref.shape[2:]
+    exact = dict(precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=f32)
+    nt = (((1,), (1,)), ((), ()))
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    upto = (row >= col).astype(f32)                  # [i, j]: j <= i
+    dt_r = dt_ref[0, 0]
+    dta_r = dt_r * a_ref[0]
+    pad = jnp.zeros((SCAN_LANES - hb, q), f32)
+    dt_c = jax.lax.dot_general(
+        (row == col).astype(f32), jnp.concatenate([dt_r, pad]), nt, **exact)
+    cum_c = jax.lax.dot_general(upto, jnp.concatenate([dta_r, pad]), nt,
+                                **exact)
+    cum_r = jax.lax.dot_general(dta_r, upto, nt, **exact)
+    return dt_c, cum_c, dt_r, cum_r, upto
+
+
+def _scan_fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, st_ref,
+                     state, *, hb: int, p: int):
+    """One chunk of one head block: x `[Q, hb·P]`, B and C `[Q, N]`, dt
+    `[hb, Q]`, a `[hb, 1]` -> y `[Q, hb·P]` float32 and the state that
+    entered the chunk, `[N, hb·P]`; `state` carries it over the chunks."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    cdt = x_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    bmat, cmat = b_ref[0], c_ref[0]
+    scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=f32)     # [i, j]
+    b_t = bmat.T                                                 # [N, Q]
+    dt_c, cum_c, dt_r, cum_r, upto = _decays(dt_ref, a_ref)
+    lower = upto > 0
+    entered = jnp.exp(cum_c)              # the entering state's decay to i
+    to_end = jnp.exp(_last_row(cum_c) - cum_c) * dt_c
+    for lanes, heads in _head_lanes(hb, p):
+        xt = x_ref[0, :, lanes]
+        s_in = state[:, lanes]
+        st_ref[0, 0, :, lanes] = s_in
+        entered_t = _by_head(heads, lambda h: entered[:, h:h + 1])
+        y = jnp.dot(cmat, s_in.astype(cdt),
+                    preferred_element_type=f32) * entered_t
+        intra = None
+        for h, mask in heads:
+            # masked before the exponential: above the diagonal the sum
+            # is positive and would overflow
+            decay = jnp.exp(jnp.where(
+                lower, cum_c[:, h:h + 1] - cum_r[h:h + 1, :], -jnp.inf))
+            weights = (scores * decay * dt_r[h:h + 1, :]).astype(cdt)
+            part = jnp.dot(weights, xt, preferred_element_type=f32)
+            intra = part if intra is None else jnp.where(mask, part, intra)
+        y_ref[0, :, lanes] = y + intra
+        xs = (xt.astype(f32)
+              * _by_head(heads, lambda h: to_end[:, h:h + 1])).astype(cdt)
+        # the chunk's whole decay is the entering state's to the last step
+        state[:, lanes] = s_in * _last_row(entered_t) + jnp.dot(
+            b_t, xs, preferred_element_type=f32)
+
+
+def _scan_bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, st_ref, g_ref,
+                     dx_ref, db_ref, dc_ref, ddt_ref, ddta_ref, dstate,
+                     later, *, hb: int, p: int):
+    """The same chunk going backward: besides the forward's operands the
+    state that entered `[N, hb·P]` and dy `[Q, hb·P]` float32 -> dx, this
+    head block's part of dB and dC `[Q, N]`, and `[hb, Q]` each the
+    cotangent of dt where it stands alone and that of `dt a`, the summand
+    of `cum`. `dstate` carries the cotangent of the state a chunk hands
+    on; `later` gathers what reaches `cum_i` as the later step of a pair.
+    Everything `[Q, Q]` is held transposed (`[j, i]`), so that no product
+    takes a transposed operand but dC's."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    q = x_ref.shape[1]
+    cdt = x_ref.dtype
+    nt = (((1,), (1,)), ((), ()))
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    bmat, cmat = b_ref[0], c_ref[0]
+    scores_t = jax.lax.dot_general(bmat, cmat, nt,
+                                   preferred_element_type=f32)   # [j, i]
+    c_t = cmat.T                                                 # [N, Q]
+    dt_c, cum_c, _, cum_r, upto = _decays(dt_ref, a_ref)
+    upper = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)            # [j, i]
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, SCAN_LANES), 1)
+    entered = jnp.exp(cum_c)
+    decay_to_end = jnp.exp(_last_row(cum_c) - cum_c)
+    to_end = decay_to_end * dt_c
+    dscores_t = jnp.zeros((q, q), f32)
+    db = jnp.zeros(db_ref.shape[1:], f32)
+    dc = jnp.zeros(dc_ref.shape[1:], f32)
+    # per head, head h in lane h: [Q, 128] and [1, 128]
+    d_intra = jnp.zeros((q, SCAN_LANES), f32)     # sum_i dW_ij s_ij L_ij
+    d_to_end = jnp.zeros((q, SCAN_LANES), f32)    # sum_p x_jp (B dS)_jp
+    d_entered = jnp.zeros((q, SCAN_LANES), f32)   # sum_p dy_ip (C S)_ip
+    d_chunk_decay = jnp.zeros((1, SCAN_LANES), f32)
+    for lanes, heads in _head_lanes(hb, p):
+        xt = x_ref[0, :, lanes]
+        x32 = xt.astype(f32)
+        gt = g_ref[0, :, lanes]
+        s_in = st_ref[0, 0, :, lanes]
+        ds_out = dstate[:, lanes]
+        s_in_c, ds_out_c, gt_c = (s_in.astype(cdt), ds_out.astype(cdt),
+                                  gt.astype(cdt))
+        from_state = jnp.dot(cmat, s_in_c, preferred_element_type=f32)
+        dxs = jnp.dot(bmat, ds_out_c, preferred_element_type=f32)
+        g_state = gt * from_state
+        x_dxs = x32 * dxs
+        ds_s = jnp.sum(ds_out * s_in, axis=0, keepdims=True)     # [1, 128]
+        dx = None
+        for h, mask in heads:
+            here = head_lane == h
+
+            def only(v):
+                return v if mask is None else jnp.where(mask, v, 0.0)
+
+            dw_t = jax.lax.dot_general(
+                only(x32).astype(cdt), gt_c, nt,
+                preferred_element_type=f32)                      # [j, i]
+            decay_t = jnp.exp(jnp.where(
+                upper, cum_r[h:h + 1, :] - cum_c[:, h:h + 1], -jnp.inf))
+            sl_t = scores_t * decay_t
+            w_t = sl_t * dt_c[:, h:h + 1]
+            a_t = dw_t * sl_t
+            d_intra = jnp.where(
+                here, jnp.sum(a_t, axis=1, keepdims=True), d_intra)
+            later[h:h + 1, :] = jnp.sum(
+                a_t * dt_c[:, h:h + 1], axis=0, keepdims=True)
+            dscores_t = dscores_t + dw_t * (decay_t * dt_c[:, h:h + 1])
+            part = jnp.dot(w_t.astype(cdt), gt_c,
+                           preferred_element_type=f32)
+            dx = part if dx is None else jnp.where(mask, part, dx)
+            d_to_end = jnp.where(
+                here, jnp.sum(only(x_dxs), axis=1, keepdims=True), d_to_end)
+            d_entered = jnp.where(
+                here, jnp.sum(only(g_state), axis=1, keepdims=True),
+                d_entered)
+            d_chunk_decay = jnp.where(
+                here, jnp.sum(only(ds_s), axis=1, keepdims=True),
+                d_chunk_decay)
+        to_end_t = _by_head(heads, lambda h: to_end[:, h:h + 1])
+        dx_ref[0, :, lanes] = (dx + dxs * to_end_t).astype(dx_ref.dtype)
+        xs = (x32 * to_end_t).astype(cdt)
+        entered_t = _by_head(heads, lambda h: entered[:, h:h + 1])
+        ge = (gt * entered_t).astype(cdt)
+        db = db + jax.lax.dot_general(xs, ds_out_c, nt,
+                                      preferred_element_type=f32)
+        dc = dc + jax.lax.dot_general(ge, s_in_c, nt,
+                                      preferred_element_type=f32)
+        dstate[:, lanes] = ds_out * _last_row(entered_t) + jnp.dot(
+            c_t, ge, preferred_element_type=f32)
+    db = db + jnp.dot(dscores_t.astype(cdt), cmat,
+                      preferred_element_type=f32)
+    dc = dc + jnp.dot(dscores_t.T.astype(cdt), bmat,
+                      preferred_element_type=f32)
+    db_ref[0] = db.astype(db_ref.dtype)
+    dc_ref[0] = dc.astype(dc_ref.dtype)
+    # cum_j reaches y through the weights of column j (-), through to_end
+    # (-), as cum_i of the entering state's term (+) and of the weights
+    # of row i (+, `later`); cum of the chunk's last step through to_end
+    # of every step and through the chunk's decay
+    through_end = d_to_end * to_end
+    last = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    dcum_c = d_entered * entered - d_intra * dt_c - through_end + jnp.where(
+        last, jnp.sum(through_end, axis=0, keepdims=True)
+        + d_chunk_decay * _last_row(entered), 0.0)
+    dcum_r = dcum_c.T[:hb] + later[:hb]                          # [hb, i]
+    # the transpose of the running sum: dt_j a reaches every cum_i, i >= j
+    ddta = jnp.dot(dcum_r, upto,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=f32)
+    ddta_ref[0, 0] = ddta
+    ddt_ref[0, 0] = (d_intra + d_to_end * decay_to_end).T[:hb] \
+        + ddta * a_ref[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_calls(bsz: int, t: int, h: int, p: int, groups: int, n: int,
+                chunk: int, hb: int, interpret: bool):
+    """The scan of one set of shapes under `jax.custom_vjp`, built once a
+    process (`functools.lru_cache`) with each `pallas_call` behind a
+    `jax.jit` of its own: a pallas kernel's body is traced anew by every
+    call, in every program of a job (two scans of layers, the forward,
+    remat's forward and the backward of each), and jit's cache hands the
+    first trace to all of them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nc, q, blocks, width = t // chunk, chunk, h // hb, hb * p
+    per_group = blocks // groups
+    f32 = jnp.float32
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    def specs(reverse):
+        """The block specs of a walk over the chunks, first to last or
+        last to first: x-wide, a group's B or C, a head block's part of
+        dB or dC, the entering states, dt-like rows, a."""
+        def at(ci):
+            return nc - 1 - ci if reverse else ci
+        return (
+            pl.BlockSpec((1, q, width), lambda bi, hi, ci: (bi, at(ci), hi)),
+            pl.BlockSpec((1, q, n),
+                         lambda bi, hi, ci: (bi, at(ci), hi // per_group)),
+            pl.BlockSpec((1, q, n), lambda bi, hi, ci: (bi, at(ci), hi)),
+            pl.BlockSpec((1, 1, n, width),
+                         lambda bi, hi, ci: (bi, at(ci), 0, hi)),
+            pl.BlockSpec((1, 1, hb, q),
+                         lambda bi, hi, ci: (bi, hi, 0, at(ci))),
+            pl.BlockSpec((1, hb, 1), lambda bi, hi, ci: (hi, 0, 0)))
+
+    @functools.partial(jax.jit, inline=True)
+    def forward(x, dt_rows, a_col, b, c):
+        wide, grouped, _, states, rows, heads = specs(False)
+        return pl.pallas_call(
+            functools.partial(_scan_fwd_kernel, hb=hb, p=p),
+            grid=(bsz, blocks, nc),
+            in_specs=[wide, grouped, grouped, rows, heads],
+            out_specs=[wide, states],
+            out_shape=[jax.ShapeDtypeStruct((bsz, t, h * p), f32),
+                       jax.ShapeDtypeStruct((bsz, nc, n, h * p), f32)],
+            scratch_shapes=[pltpu.VMEM((n, width), f32)],
+            compiler_params=params, interpret=interpret,
+            name="ssd_scan_fwd")(x, b, c, dt_rows, a_col)
+
+    @functools.partial(jax.jit, inline=True)
+    def backward(x, dt_rows, a_col, b, c, entering, dy):
+        wide, grouped, per_block, states, rows, heads = specs(True)
+        # a group's head blocks each see its B and C: their parts of dB
+        # and dC are summed in float32
+        part = b.dtype if per_group == 1 else f32
+        return pl.pallas_call(
+            functools.partial(_scan_bwd_kernel, hb=hb, p=p),
+            grid=(bsz, blocks, nc),
+            in_specs=[wide, grouped, grouped, rows, heads, states, wide],
+            out_specs=[wide, per_block, per_block, rows, rows],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct((bsz, t, blocks * n), part),
+                       jax.ShapeDtypeStruct((bsz, t, blocks * n), part),
+                       jax.ShapeDtypeStruct(dt_rows.shape, f32),
+                       jax.ShapeDtypeStruct(dt_rows.shape, f32)],
+            scratch_shapes=[pltpu.VMEM((n, width), f32),
+                            pltpu.VMEM((SCAN_LANES, q), f32)],
+            compiler_params=params, interpret=interpret,
+            name="ssd_scan_bwd")(x, b, c, dt_rows, a_col, entering, dy)
+
+    @jax.custom_vjp
+    def scan(x, dt_rows, a_col, b, c):
+        return forward(x, dt_rows, a_col, b, c)[0]
+
+    def scan_fwd(x, dt_rows, a_col, b, c):
+        y, entering = forward(x, dt_rows, a_col, b, c)
+        return y, (x, dt_rows, a_col, b, c, entering)
+
+    def scan_bwd(res, dy):
+        _, dt_rows, a_col, b, _, _ = res
+        dx, db, dc, ddt, ddta = backward(*res, dy)
+
+        def over_blocks(d):
+            return d if per_group == 1 else d.reshape(
+                bsz, t, groups, per_group, n).sum(axis=3).reshape(
+                    bsz, t, groups * n).astype(b.dtype)
+        da = jnp.sum(ddta * dt_rows, axis=(0, 3)).reshape(a_col.shape)
+        return dx, ddt, da, over_blocks(db), over_blocks(dc)
+
+    scan.defvjp(scan_fwd, scan_bwd)
+    return scan
+
+
+def ssd_scan_pallas(x, dt, a, b, c, chunk: int, groups: int, *,
+                    head_block=None, interpret: bool = False):
+    """`ssd_scan` as a pallas TPU kernel with a backward kernel of its
+    own (module docstring), on the layouts the convolution leaves: x
+    `[B, T, H·P]`, dt `[B, T, H]` (after softplus, float32), a `[H]`, b
+    and c `[B, T, G·N]` -> y `[B, T, H·P]` float32. The shapes are
+    `scan_shape_ok`'s to vouch for; `head_block` and `interpret` are the
+    tests' (a block smaller than `scan_head_block`'s, the kernel on the
+    CPU)."""
+    import jax.numpy as jnp
+
+    bsz, t, h = dt.shape
+    p, n = x.shape[2] // h, b.shape[2] // groups
+    _whole_chunks(t, chunk)
+    hb = head_block or scan_head_block(h // groups, p)
+    scan = _scan_calls(bsz, t, h, p, groups, n, chunk, hb, interpret)
+    # the steps on the lanes: [B, blocks, hb, T]
+    dt_rows = jnp.swapaxes(dt.astype(jnp.float32), 1, 2).reshape(
+        bsz, h // hb, hb, t)
+    return scan(x, dt_rows, a.astype(jnp.float32).reshape(h // hb, hb, 1),
+                b, c)
+
+
 def gated_norm(y, z, gain, groups: int, eps: float):
     """`grouped_rmsnorm(y * silu(z)) * gain`: y, z `[..., C]`, the RMS
-    over each of `groups` runs of C / groups channels, in float32."""
+    over each of `groups` runs of C / groups channels, in float32. A
+    group is a slice of the last axis, not a `[..., groups, C / groups]`
+    reshape: on the TPU that shape has another tiling, and the compiler
+    copied every `[B, T, C]` operand of the norm and of its backward into
+    it (PERF.md section 6, PR 42)."""
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
     v = y.astype(f32) * jax.nn.silu(z.astype(f32))
-    grouped = v.reshape(v.shape[:-1] + (groups, -1))
-    scale = jax.lax.rsqrt(
-        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
-    return (grouped * scale).reshape(v.shape) * gain.astype(f32)
+    width = v.shape[-1] // groups
+    runs = [v[..., g * width:(g + 1) * width] for g in range(groups)]
+    normed = [run * jax.lax.rsqrt(
+        jnp.mean(run * run, axis=-1, keepdims=True) + eps) for run in runs]
+    return jnp.concatenate(normed, axis=-1) * gain.astype(f32)
 
 
 def mamba2_mixer(h, lp: Dict[str, Any], *, head_dim: int, state: int,
-                 chunk: int, eps: float):
+                 chunk: int, eps: float, mesh=None):
     """h `[B, T, d]` (normed, compute dtype) -> the mixer's output before
     the residual, `[B, T, d]`. lp: `w_in [d, 2·H·P + 2·G·N + H]` and
     `w_out [H·P, d]` in the compute dtype; `conv_w [H·P + 2·G·N, K]`,
     `conv_b`, `dt_bias [H]`, `A_log [H]`, `D [H]`, `gate_norm [H·P]`.
-    Heads and groups are read off the leaves (module docstring)."""
+    Heads and groups are read off the leaves (module docstring); `mesh`
+    is what the program runs on, for `ssd_scan_impl`'s choice."""
     import jax
     import jax.numpy as jnp
 
@@ -151,17 +604,22 @@ def mamba2_mixer(h, lp: Dict[str, Any], *, head_dim: int, state: int,
         dt = zxbcdt[..., inner + conv_dim:]
     with jax.named_scope("ssm/conv"):
         xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp["conv_b"]))
-        x = xbc[..., :inner].reshape(bsz, t, heads, head_dim)
-        b = xbc[..., inner:inner + groups * state].reshape(
-            bsz, t, groups, state)
-        c = xbc[..., inner + groups * state:].reshape(
-            bsz, t, groups, state)
+        x, b, c = (xbc[..., :inner], xbc[..., inner:inner + groups * state],
+                   xbc[..., inner + groups * state:])
     with jax.named_scope("ssm/scan"):
         dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
-        y = ssd_scan(x, dt, -jnp.exp(lp["A_log"].astype(f32)), b, c, chunk)
-        y = y + x.astype(f32) * lp["D"].astype(f32)[:, None]
+        a, skip = -jnp.exp(lp["A_log"].astype(f32)), lp["D"].astype(f32)
+        if ssd_scan_impl(mesh, t, heads, head_dim, groups, state,
+                         chunk) == "pallas":
+            # the kernel reads the convolution's own layouts
+            y = ssd_scan_pallas(x, dt, a, b, c, chunk, groups)
+        else:
+            y = ssd_scan(x.reshape(bsz, t, heads, head_dim), dt, a,
+                         b.reshape(bsz, t, groups, state),
+                         c.reshape(bsz, t, groups, state),
+                         chunk).reshape(bsz, t, inner)
+        y = y + x.astype(f32) * jnp.repeat(skip, head_dim)
     with jax.named_scope("ssm/gate_norm"):
-        y = gated_norm(y.reshape(bsz, t, inner), z, lp["gate_norm"], groups,
-                       eps).astype(h.dtype)
+        y = gated_norm(y, z, lp["gate_norm"], groups, eps).astype(h.dtype)
     with jax.named_scope("ssm/out_proj"):
         return jnp.einsum("bte,ed->btd", y, lp["w_out"])

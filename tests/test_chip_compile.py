@@ -419,16 +419,70 @@ def test_latent_attention_share_step_compiles_for_the_v5e(v5e, policy,
         assert re.search(r'op_name="[^"]*[/(]' + scope + r'[/)]', hlo), scope
 
 
+# [B, T, heads, head width, groups, state, chunk]: the Nemotron cell's
+# mixer as one chip holds it, the uncut model's (128 heads in 8 groups,
+# two batch rows), a head that is a whole lane tile with a chunk of 256,
+# and the smallest shape `scan_shape_ok` says yes to
+SCAN_CALLS = [(1, 8192, 32, 64, 2, 128, 128), (2, 1024, 128, 64, 8, 128, 128),
+              (1, 512, 8, 128, 1, 128, 256), (1, 128, 8, 64, 1, 128, 128)]
+
+
+@pytest.mark.parametrize(
+    "b,t,h,p,g,n,q", SCAN_CALLS,
+    ids=["x".join(map(str, call)) for call in SCAN_CALLS])
+def test_scan_kernels_fwd_bwd(v5e, b, t, h, p, g, n, q):
+    """Forward and backward of `ops/ssm.ssd_scan_pallas` alone for the v5e
+    compiler: one `ssd_scan_fwd` and one `ssd_scan_bwd` call, x and dy
+    reaching them in the convolution's `[B, T, H·P]` layout (no four-way
+    copy of either around the calls), the entering states `[T/Q, N, H·P]`
+    float32 the only residual the forward writes, and `scan_shape_ok` said
+    yes to what compiled."""
+    import re
+
+    from ray_tpu.ops.ssm import scan_shape_ok, ssd_scan_pallas
+
+    assert scan_shape_ok(t, h, p, g, n, q)
+    chip = SingleDeviceSharding(v5e)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(x, dt, a, bm, cm):
+        return ssd_scan_pallas(x, dt, a, bm, cm, q, g).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((b, t, h * p), jnp.bfloat16), arg((b, t, h), jnp.float32),
+        arg((h,), jnp.float32), arg((b, t, g * n), jnp.bfloat16),
+        arg((b, t, g * n), jnp.bfloat16)).compile().as_text()
+    calls = {re.search(r"ssd_scan_(fwd|bwd)", name).group(0): (out, operands)
+             for name, out, operands in re.findall(
+                 r'%([\w.\-]+) = (\([^\n]*?\)) custom-call\(([^\n]*?)\), '
+                 r'custom_call_target="tpu_custom_call"', hlo)}
+    assert sorted(calls) == ["ssd_scan_bwd", "ssd_scan_fwd"], sorted(calls)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    fwd_out, fwd_in = calls["ssd_scan_fwd"]
+    bwd_out, _ = calls["ssd_scan_bwd"]
+    assert f"f32[{b},{t},{h * p}]" in fwd_out
+    assert f"f32[{b},{t // q},{n},{h * p}]" in fwd_out
+    assert f"bf16[{b},{t},{h * p}]" in bwd_out
+    # x, B and C as the program's arguments hold them: no copy before
+    assert fwd_in.startswith("%x.1, %bm.1, %cm.1, "), fwd_in
+    assert not re.search(rf"\[{b},{t},{h},{p}\]", hlo)
+
+
 def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     """Two `ME` blocks and one `M*E` block of Nemotron-3-Super's widths as
     one chip holds them (32 mixer heads of 64 in 2 groups of state 128, 8
     query heads over 1 key/value head of 128 with no rotary embedding, 8
     of 512 `relu^2` experts of width 2688 in a latent of 1024, top-22, a
     shared expert of 5376) + head, as one train step for the v5e: the
-    chunked scan as plain XLA, splash's kernels at GQA 8 / 1, `megablox`
-    with tiles from each call's shapes (2688 = 7 x 384), past the sort
-    tokens x min(22, 8) rows and never tokens x 22, and the new scopes on
-    what the compiler leaves."""
+    chunked scan as its pallas kernels (`ops/ssm.ssd_scan_impl` says
+    `"pallas"` for the described device: under `ssm/scan` the forward
+    kernel in the forward and in remat's forward, the backward kernel in
+    the backward, and no `[T/Q, H, Q, Q]` block left there), splash's
+    kernels at GQA 8 / 1, `megablox` with tiles from each call's shapes
+    (2688 = 7 x 384), past the sort tokens x min(22, 8) rows and never
+    tokens x 22, and the new scopes on what the compiler leaves."""
     import re
 
     import optax
@@ -436,6 +490,7 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     from ray_tpu.models import Transformer
     from ray_tpu.models.configs import TransformerConfig
     from ray_tpu.ops.moe import gmm_tiles, grouped_matmul_impl, row_bound
+    from ray_tpu.ops.ssm import ssd_scan_impl
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.train_step import make_train_step
 
@@ -458,6 +513,10 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     assert gmm_tiles(held_rows, 2688, 1024) == (512, 384, 1024)
     assert grouped_matmul_impl(mesh, held_rows, cfg.moe_latent, cfg.ff_dim,
                                gated=False) == "megablox"
+    scan_shape = (seq, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state, cfg.ssm_chunk)
+    assert ssd_scan_impl(mesh, *scan_shape) == "pallas"
+    assert ssd_scan_impl(None, *scan_shape) == "xla"            # the CPU
     optimizer = optax.adamw(3e-7, weight_decay=0.01)
     _, train_step = make_train_step(
         lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh,
@@ -492,6 +551,27 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     assert all("moe/experts" in op for _, op in grouped), grouped
     assert sum(n.startswith("splash_mha_fwd") for n in names) == 1, names
     assert sum(n.startswith("splash_mha_dkv") for n in names) == 1, names
+    # two scans with a mixer each: the scan's forward kernel in the
+    # forward and in remat's forward, its backward kernel in the backward
+    scan_fwd = [op for n, _, op in kernels if n.startswith("ssd_scan_fwd")]
+    scan_bwd = [op for n, _, op in kernels if n.startswith("ssd_scan_bwd")]
+    assert len(scan_fwd) == 4 and len(scan_bwd) == 2, names
+    assert all("ssm/scan" in op for op in scan_fwd + scan_bwd), kernels
+    assert sorted(("rematted_computation" in op, "transpose(jvp" in op)
+                  for op in scan_fwd) == [(False, False)] * 2 + \
+        [(True, True)] * 2, scan_fwd
+    assert all("transpose(jvp" in op and "rematted_computation" not in op
+               for op in scan_bwd), scan_bwd
+    # what the kernel keeps in VMEM: nothing under the scope is as large
+    # as one [T/Q, H, Q, Q] block of decays or weights (the XLA path's
+    # temporaries), whatever its layout
+    block = seq * cfg.ssm_chunk * cfg.ssm_heads
+    for shape, op in re.findall(
+            r'= \w+\[([\d,]+)\][^\n]*op_name="([^"]*ssm/scan[^"]*)"', hlo):
+        size = 1
+        for dim in shape.split(","):
+            size *= int(dim)
+        assert size < block, (shape, op)
     # the weights of the 8 held experts reach the kernels, never 512
     assert re.search(r"bf16\[8,1024,2688\]", hlo)
     assert re.search(r"bf16\[8,2688,1024\]", hlo)
