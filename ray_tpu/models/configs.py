@@ -11,7 +11,11 @@ the experts held here (one chip of an expert-parallel layer). A hybrid
 (`layer_pattern`) is a published sequence of single sublayers, each a
 Mamba-2 mixer (`M`), an attention block (`*`) or an expert layer (`E`)
 alone; its experts may live in a latent (`moe_latent`) and be plain
-`relu(x)^2` MLPs with no gate.
+`relu(x)^2` MLPs with no gate. A decoder-hybrid-decoder (SambaY; the
+lower-case kinds of `layer_pattern`) is a sequence of layers each a
+Mamba-1 mixer, differential attention (windowed, full or cross) or a gated
+memory unit FOLLOWED by a dense MLP, whose later layers read one earlier
+mixer's scan output and one earlier attention layer's keys and values.
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -23,6 +27,7 @@ under benchmark/configs/, mapped onto TransformerConfig by its jobs.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 
@@ -152,14 +157,48 @@ class TransformerConfig:
     moe_gated: bool = True
     # the shared expert's own width (0: moe_shared_experts * d_ff)
     moe_shared_ff: int = 0
+    # The lower-case kinds of `layer_pattern`, each sublayer FOLLOWED by a
+    # dense gated MLP of width d_ff in the same layer (SambaY, arXiv
+    # 2507.06607): `m` a Mamba-1 mixer, `s` the Mamba-1 mixer whose scan
+    # output (before its gate) is the memory of every `g` after it, `w`
+    # attention under a causal window of attn_window keys, `f` full causal
+    # attention whose keys and values are those of every `c` after it, `g`
+    # a gated memory unit `(m * silu(h W1)) W2`, `c` cross-attention with a
+    # query projection only. The Mamba-1 mixer (ops/ssm.py): ssm_d_inner
+    # channels each with its own state of ssm_state and its own decay,
+    # the step from a low-rank projection of ssm_dt_rank, ssm_conv_kernel
+    # taps, the scan in chunks of ssm_chunk with the state carried.
+    ssm_d_inner: int = 0
+    ssm_dt_rank: int = 0
+    attn_window: int = 0
+    # differential attention (arXiv 2410.05258): heads in pairs, two
+    # softmax maps a pair and `(A1 - lambda A2) V` with V 2 x head_dim
+    # wide and shared by two key pairs, lambda learned (four vectors of
+    # head_dim a layer around lambda_init = 0.8 - 0.6 exp(-0.3 l), l the
+    # PUBLISHED index of the layer: layer_index_offset + its place here),
+    # an RMS norm over the pair's output, times (1 - lambda_init)
+    diff_attention: bool = False
+    layer_index_offset: int = 0
+    # a bias on attention's projections (q, k, v and the output)
+    attn_bias: bool = False
+    # "rms": RMSNorm with a gain; "layernorm": LayerNorm with gain and
+    # bias (`<name>_bias` beside every norm's gain, the final one too)
+    norm: str = "rms"
 
     def __post_init__(self):
+        if self.norm not in ("rms", "layernorm"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.norm == "layernorm" and not self.layer_pattern:
+            raise ValueError("LayerNorm's biases are a layer_pattern's "
+                             "sublayers': the homogeneous layer has none")
         if self.layer_pattern:
-            unknown = set(self.layer_pattern) - set("ME*")
+            unknown = set(self.layer_pattern) - set("ME*" + FFN_KINDS)
             if unknown or len(self.layer_pattern) != self.n_layers:
                 raise ValueError(
                     f"layer_pattern {self.layer_pattern!r}: {self.n_layers} "
-                    f"characters of M, E, * (got {sorted(unknown)})")
+                    f"characters of M, E, *, {', '.join(FFN_KINDS)} "
+                    f"(got {sorted(unknown)})")
+            self._check_shared_tensors()
             if "M" in self.layer_pattern and (
                     not self.ssm_heads or self.ssm_heads % self.ssm_groups):
                 raise ValueError("a mixer needs ssm_heads, a multiple of "
@@ -201,6 +240,39 @@ class TransformerConfig:
                 or self.moe_experts_held:
             raise ValueError("moe_* sizes without moe_experts")
 
+    def _check_shared_tensors(self):
+        """The lower-case kinds' sizes, and every reader of a tensor that
+        crosses layers after the one layer that makes it."""
+        pattern = self.layer_pattern
+        if set("ms") & set(pattern) and not (
+                self.ssm_d_inner and self.ssm_dt_rank):
+            raise ValueError("a Mamba-1 mixer (m, s) needs ssm_d_inner and "
+                             "ssm_dt_rank")
+        if "w" in pattern and not self.attn_window:
+            raise ValueError("window attention (w) needs attn_window")
+        if self.diff_attention and (
+                self.n_heads % 4 or self.kv_heads * 2 != self.n_heads
+                or self.kv_lora_rank or self.qk_norm):
+            raise ValueError(
+                "differential attention pairs the heads: n_heads a "
+                "multiple of 4, half as many key heads, no latent and no "
+                "QK-norm")
+        for reader, maker, what in (
+                ("g", "s", "a gated memory unit (g) reads the scan output "
+                           "of the mixer s"),
+                ("c", "f", "cross-attention (c) reads the keys and values "
+                           "of the full attention layer f")):
+            if pattern.count(maker) > 1:
+                raise ValueError(f"layer_pattern {pattern!r}: {what}, and "
+                                 f"one layer makes it")
+            if reader in pattern and not 0 <= pattern.find(maker) \
+                    < pattern.find(reader):
+                raise ValueError(
+                    f"layer_pattern {pattern!r}: {what}, and there is no "
+                    f"{maker} before the first {reader}")
+            if reader in pattern and not self.ssm_d_inner:
+                raise ValueError(f"{what}: ssm_d_inner is its width")
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
@@ -234,6 +306,12 @@ class TransformerConfig:
     def ssm_inner(self) -> int:
         """Width of the mixer's x, z and output: heads x head width."""
         return self.ssm_heads * self.ssm_head_dim
+
+    def lambda_init(self, layer: int) -> float:
+        """Differential attention's lambda_init of this program's layer
+        `layer`, by its published index."""
+        return 0.8 - 0.6 * math.exp(
+            -0.3 * (self.layer_index_offset + layer))
 
     @property
     def ssm_conv_dim(self) -> int:
@@ -297,9 +375,46 @@ class TransformerConfig:
                   + (mats * d * self.shared_ff
                      if self.moe_shared_experts else 0))
         each = {"M": mixer, "*": attn, "E": expert}
-        layers = sum(each[c] + d for c in self.layer_pattern)
+        if set(FFN_KINDS) & set(self.layer_pattern):
+            each.update(self._ffn_kind_params(attn))
+        norm = d * self._norm_leaves
+        layers = sum(each[c] + norm for c in self.layer_pattern)
         head = 0 if self.tie_embeddings else d * v
-        return v * d + layers + d + head
+        return v * d + layers + norm + head
+
+    @property
+    def _norm_leaves(self) -> int:
+        """Leaves of width d_model a norm has: a gain, and a bias."""
+        return 2 if self.norm == "layernorm" else 1
+
+    def _ffn_kind_params(self, attn: int):
+        """Parameters of each lower-case kind without its first norm
+        (`_pattern_params` adds one a layer): the sublayer, the second
+        norm and the MLP."""
+        d, inner, n = self.d_model, self.ssm_d_inner, self.ssm_state
+        hd = self.head_dim
+        mixer = (d * 2 * inner                               # W_in
+                 + inner * self.ssm_conv_kernel + inner      # conv, bias
+                 + inner * (self.ssm_dt_rank + 2 * n)        # W_x
+                 + self.ssm_dt_rank * inner + inner          # W_dt, b_dt
+                 + inner * n + inner + inner * d)            # A_log, D, W_out
+        bias = (self.n_heads + 2 * self.kv_heads) * hd + d \
+            if self.attn_bias else 0
+        # four lambda vectors and the pair norm's gain (lambda_init is a
+        # buffer)
+        diff = 4 * hd + 2 * hd if self.diff_attention else 0
+        kv = 2 * d * self.kv_heads * hd + (
+            2 * self.kv_heads * hd if self.attn_bias else 0)
+        rest = self._norm_leaves * d + 3 * d * self.ff_dim
+        each = {"m": mixer, "s": mixer, "w": attn + bias + diff,
+                "f": attn + bias + diff, "g": 2 * d * inner,
+                "c": attn + bias + diff - kv}
+        return {kind: count + rest for kind, count in each.items()}
+
+
+# the kinds of `layer_pattern` that are followed by a dense MLP in the same
+# layer (TransformerConfig: `m`, `s`, `w`, `f`, `g`, `c`)
+FFN_KINDS = "msfwgc"
 
 
 def pattern_runs(pattern: str):

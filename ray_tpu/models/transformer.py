@@ -16,8 +16,14 @@ expert layer ALONE, `x + f(norm(x))` with one norm) is the runs of
 `cfg.pattern_runs`, `params["runs"]`: each run a block, a list of unlike
 sublayers, its leaves stacked over the block's repeats and scanned as one
 body. A sublayer too is what its leaves say: `attn_norm` brings
-attention, `ssm_norm` a mixer, `mlp_norm` an FFN or experts, and the
-homogeneous layer is the one with both the first and the last.
+attention, `ssm_norm` a mixer, `gmu_norm` a gated memory unit, `mlp_norm`
+an FFN or experts, and the homogeneous layer is the one with both the
+first and the last; `w_x` makes the mixer Mamba-1, `lambda_q1` makes
+attention differential, no `wkv` makes it cross-attention, a
+`<norm>_bias` makes the norm a LayerNorm. What the leaves cannot say the
+layer's kind in `layer_pattern` does: a window, and which layer's
+tensors cross layers (`_stack`'s `shared`: the scan output `memory` of
+the mixer `s`, the `k` and `v` of the attention layer `f`).
 
 Every block names itself with `jax.named_scope`, and the names are an
 interface (PERF.md section 3; the benchmark's per-layer metrics and an
@@ -26,11 +32,16 @@ operator's `ray_tpu profile --device` read them off each op's op_name):
 `attn_norm`, `qkv` (projections, QK-norm and RoPE; with latent attention
 `qkv/q_down`, `qkv/kv_down`, `qkv/q_up`, `qkv/kv_up`, `qkv/assemble`
 inside it), `attention` (kernels, GQA repeat, layout transposes),
-`attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (in an expert layer
+`attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (differential
+attention: `attention/window`, `attention/full` or `attention/cross`
+around the kernel calls, `attention/diff` around lambda, the subtraction,
+the pair norm and the scale; in an expert layer
 `moe/router`, `moe/dispatch`, `moe/experts`, `moe/combine`, `moe/shared`,
 `moe/latent`: ops/moe.py), in a mixer `ssm_norm` and `ssm/in_proj`,
-`ssm/conv`, `ssm/scan`, `ssm/gate_norm`, `ssm/out_proj` (ops/ssm.py),
-`final_norm`, `head`, `loss` (the vocab head and the
+`ssm/conv`, `ssm/scan`, `ssm/gate_norm`, `ssm/out_proj` (ops/ssm.py; a
+Mamba-1 mixer `ssm/x_proj` and `ssm/gate` and no `ssm/gate_norm`), in a
+gated memory unit `gmu_norm` and `gmu/in_proj`, `gmu/gate`,
+`gmu/out_proj`, `final_norm`, `head`, `loss` (the vocab head and the
 cross-entropy: models/head.py); the train step adds `optimizer`
 (parallel/train_step.py). Scopes are metadata only. Forward, backward
 and recomputation need none: JAX wraps the path in `jvp(...)`,
@@ -47,7 +58,7 @@ import functools
 from typing import Any, Dict, Optional
 
 from ray_tpu.models import head
-from ray_tpu.models.configs import TransformerConfig
+from ray_tpu.models.configs import FFN_KINDS, TransformerConfig
 from ray_tpu.parallel.mesh import AXIS_SEQ
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
 
@@ -84,6 +95,30 @@ def _rmsnorm(x, w, eps):
     scale = jnp.reciprocal(
         jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps))
     return (x32 * scale).astype(x.dtype) * w.astype(x.dtype)
+
+
+def _layernorm(x, w, b, eps):
+    import jax.numpy as jnp
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jnp.reciprocal(
+        jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps))
+    return (x32 * scale).astype(x.dtype) * w.astype(x.dtype) \
+        + b.astype(x.dtype)
+
+
+def _norm(x, leaves, name, eps):
+    """The norm whose leaves are `name` (and `name_bias`): a LayerNorm
+    where there is a bias, else RMSNorm."""
+    if name + "_bias" in leaves:
+        return _layernorm(x, leaves[name], leaves[name + "_bias"], eps)
+    return _rmsnorm(x, leaves[name], eps)
+
+
+# the norms that open a sublayer, by their gain's leaf
+NORMS = ("attn_norm", "ssm_norm", "gmu_norm", "mlp_norm")
+# the tensors of `_stack`'s `shared` each kind of layer makes
+MAKES = {"s": ("memory",), "f": ("k", "v")}
 
 
 class Transformer:
@@ -149,6 +184,19 @@ class Transformer:
                 # together), applied before the split into heads and RoPE
                 layers["q_norm"] = jnp.ones((l, nh * hd), pdt)
                 layers["k_norm"] = jnp.ones((l, nkv * hd), pdt)
+            if cfg.attn_bias:
+                layers["bq"] = jnp.zeros((l, nh, hd), pdt)
+                layers["bkv"] = jnp.zeros((l, 2, nkv, hd), pdt)
+                layers["bo"] = jnp.zeros((l, d), pdt)
+            if cfg.diff_attention:
+                # lambda's four vectors at the published 0.1, the pair
+                # norm's gain; lambda_init is set by `sublayer`, which
+                # knows the layers' places
+                for i, name in enumerate(("lambda_q1", "lambda_k1",
+                                          "lambda_q2", "lambda_k2")):
+                    layers[name] = norm_init(
+                        0.1, jax.random.fold_in(keys[4], 1 + i), (l, hd))
+                layers["subln"] = jnp.ones((l, 2 * hd), pdt)
             return layers
 
         def gated(keys, lead, width, d_in=d):
@@ -223,28 +271,90 @@ class Transformer:
                 "w_out": norm_init(inner ** -0.5, ks[2], (l, inner, d)),
             }
 
-        def sublayer(kind, l, key):
+        def mixer1(l, key):
+            """One run of l Mamba-1 mixers' leaves (ops/ssm.py), from the
+            published initialiser: A_{c,n} = n + 1, dt log-uniform in
+            [0.001, 0.1] through softplus's inverse, D 1."""
+            ks = jax.random.split(key, 6)
+            inner, n, rank = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_dt_rank
+            dt = jnp.exp(jax.random.uniform(
+                ks[3], (l, inner), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+            bound = cfg.ssm_conv_kernel ** -0.5
+            return {
+                "ssm_norm": jnp.ones((l, d), pdt),
+                "w_in": norm_init(d ** -0.5, ks[0], (l, d, 2 * inner)),
+                "conv_w": jax.random.uniform(
+                    ks[1], (l, inner, cfg.ssm_conv_kernel), jnp.float32,
+                    -bound, bound).astype(pdt),
+                "conv_b": jax.random.uniform(
+                    jax.random.fold_in(ks[1], 1), (l, inner), jnp.float32,
+                    -bound, bound).astype(pdt),
+                "w_x": norm_init(inner ** -0.5, ks[4],
+                                 (l, inner, rank + 2 * n)),
+                "w_dt": norm_init(rank ** -0.5, ks[5], (l, rank, inner)),
+                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+                "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                    1, n + 1, dtype=jnp.float32)), (l, inner, n)).astype(pdt),
+                "D": jnp.ones((l, inner), pdt),
+                "w_out": norm_init(inner ** -0.5, ks[2], (l, inner, d)),
+            }
+
+        def with_mlp(sub, l, keys):
+            """A lower-case kind: the sublayer, then a dense MLP."""
+            sub["mlp_norm"] = jnp.ones((l, d), pdt)
+            sub["w_gateup"], sub["w_down"] = gated(keys, (l,), f)
+            return sub
+
+        def sublayer(kind, l, key, places):
+            """`places`: the l layers' indices in the pattern."""
             keys = jax.random.split(key, 8)
             if kind == "M":
-                return mixer(l, key)
-            if kind == "*":
+                sub = mixer(l, key)
+            elif kind == "*":
                 sub = attention(l, keys)
                 del sub["mlp_norm"]
-                return sub
-            return dict(experts(l, key, keys),
-                        mlp_norm=jnp.ones((l, d), pdt))
+            elif kind in "ms":
+                sub = with_mlp(mixer1(l, key), l, keys)
+            elif kind in "wfc":
+                sub = with_mlp(attention(l, keys), l, keys)
+                if kind == "c":   # the keys and values are the layer f's
+                    sub = {name: leaf for name, leaf in sub.items()
+                           if name not in ("wkv", "bkv")}
+            elif kind == "g":
+                inner = cfg.ssm_d_inner
+                sub = with_mlp({
+                    "gmu_norm": jnp.ones((l, d), pdt),
+                    "w_gmu_in": norm_init(d ** -0.5, keys[1], (l, d, inner)),
+                    "w_gmu_out": norm_init(inner ** -0.5, keys[2],
+                                           (l, inner, d))}, l, keys)
+            else:
+                sub = dict(experts(l, key, keys),
+                           mlp_norm=jnp.ones((l, d), pdt))
+            if "lambda_q1" in sub:   # a buffer (Transformer.frozen)
+                sub["lambda_init"] = jnp.asarray(
+                    [cfg.lambda_init(i) for i in places], jnp.float32)
+            if cfg.norm == "layernorm":
+                sub.update({name + "_bias": jnp.zeros((l, d), pdt)
+                            for name in NORMS if name in sub})
+            return sub
 
         keys = jax.random.split(key, 8)
         params = {
             "embed": norm_init(0.02, keys[0], (cfg.vocab_size, d)),
             "final_norm": jnp.ones((d,), pdt),
         }
+        if cfg.norm == "layernorm":
+            params["final_norm_bias"] = jnp.zeros((d,), pdt)
         if cfg.layer_pattern:
-            params["runs"] = [
-                [sublayer(kind, repeats,
-                          jax.random.fold_in(key, 1000 + 100 * n + i))
-                 for i, kind in enumerate(block)]
-                for n, (block, repeats) in enumerate(cfg.pattern_runs)]
+            params["runs"], at = [], 0
+            for n, (block, repeats) in enumerate(cfg.pattern_runs):
+                params["runs"].append([
+                    sublayer(kind, repeats,
+                             jax.random.fold_in(key, 1000 + 100 * n + i),
+                             range(at + i, at + len(block) * repeats,
+                                   len(block)))
+                    for i, kind in enumerate(block)])
+                at += len(block) * repeats
         else:
             l = cfg.n_layers - cfg.moe_dense_layers
             layers = attention(l, keys)
@@ -293,6 +403,15 @@ class Transformer:
             if cfg.qk_norm:
                 layers["q_norm"] = ("layers", "norm")
                 layers["k_norm"] = ("layers", "norm")
+            if cfg.attn_bias:
+                layers["bq"] = ("layers", "heads", "head_dim")
+                layers["bkv"] = ("layers", None, "kv_heads", "head_dim")
+                layers["bo"] = ("layers", "norm")
+            if cfg.diff_attention:
+                for name in ("lambda_q1", "lambda_k1", "lambda_q2",
+                             "lambda_k2", "subln"):
+                    layers[name] = ("layers", None)
+                layers["lambda_init"] = ("layers",)
             return layers
 
         dense_ffn = {"w_gateup": ("layers", "embed", None, "mlp"),
@@ -320,25 +439,47 @@ class Transformer:
             return layers
 
         def sublayer(kind):
-            if kind == "M":   # a mixer's heads are not sharded here
-                return {"ssm_norm": ("layers", "norm"),
-                        "w_in": ("layers", "embed", None),
-                        "conv_w": ("layers", None, None),
-                        "conv_b": ("layers", None),
-                        "dt_bias": ("layers", None),
-                        "A_log": ("layers", None), "D": ("layers", None),
-                        "gate_norm": ("layers", None),
-                        "w_out": ("layers", None, "embed")}
-            if kind == "*":
+            if kind in "Mms":   # a mixer's channels are not sharded here
+                sub = {"ssm_norm": ("layers", "norm"),
+                       "w_in": ("layers", "embed", None),
+                       "conv_w": ("layers", None, None),
+                       "conv_b": ("layers", None),
+                       "dt_bias": ("layers", None),
+                       "D": ("layers", None),
+                       "w_out": ("layers", None, "embed")}
+                if kind == "M":
+                    sub.update(A_log=("layers", None),
+                               gate_norm=("layers", None))
+                else:
+                    sub.update(A_log=("layers", None, None),
+                               w_x=("layers", None, None),
+                               w_dt=("layers", None, None))
+            elif kind in "*wfc":
                 sub = attention()
-                del sub["mlp_norm"]
-                return sub
-            return dict(experts(), mlp_norm=("layers", "norm"))
+                if kind == "*":
+                    del sub["mlp_norm"]
+                if kind == "c":
+                    sub = {name: spec for name, spec in sub.items()
+                           if name not in ("wkv", "bkv")}
+            elif kind == "g":
+                sub = {"gmu_norm": ("layers", "norm"),
+                       "w_gmu_in": ("layers", "embed", None),
+                       "w_gmu_out": ("layers", None, "embed")}
+            else:
+                sub = dict(experts(), mlp_norm=("layers", "norm"))
+            if kind in FFN_KINDS:
+                sub.update(dense_ffn, mlp_norm=("layers", "norm"))
+            if cfg.norm == "layernorm":
+                sub.update({name + "_bias": ("layers", "norm")
+                            for name in NORMS if name in sub})
+            return sub
 
         specs = {
             "embed": ("vocab", "embed"),
             "final_norm": ("norm",),
         }
+        if cfg.norm == "layernorm":
+            specs["final_norm_bias"] = ("norm",)
         if cfg.layer_pattern:
             specs["runs"] = [[sublayer(kind) for kind in block]
                              for block, _ in cfg.pattern_runs]
@@ -357,7 +498,8 @@ class Transformer:
         """Which leaves of init()'s tree are buffers and not parameters
         (True): what `make_train_step(frozen=...)` keeps as it is, whatever
         the gradient and the optimizer's weight decay. Today the sigmoid
-        router's choice bias (`e_score_correction_bias`)."""
+        router's choice bias (`e_score_correction_bias`) and differential
+        attention's `lambda_init`, a constant of the layer's place."""
         import jax
 
         specs = Transformer.param_specs(cfg)
@@ -366,8 +508,9 @@ class Transformer:
         blocks = mask["runs"] if "runs" in mask else [[mask["layers"]]]
         for block in blocks:
             for sub in block:
-                if "router_bias" in sub:
-                    sub["router_bias"] = True
+                for name in ("router_bias", "lambda_init"):
+                    if name in sub:
+                        sub[name] = True
         return mask
 
     # ---- forward ----------------------------------------------------
@@ -438,14 +581,23 @@ class Transformer:
 
     @staticmethod
     def _stack(layers, x, cfg: TransformerConfig, *, mesh,
-               rules: ShardingRules, positions=None):
+               rules: ShardingRules, positions=None, kinds=None,
+               shared=None):
         """x [B, T, d] through a run of stacked layers (leaves
         [n, ...]: all of them in hidden(), one stage's in pipeline_loss())
-        -> (x, routing), `routing` the layers' stacked MoE records (None
-        for dense FFN configs). A run that is a list is a block of unlike
-        sublayers (module docstring): the scan's body runs them in turn,
-        each under `_remat` by itself, and `routing` is the list of the
-        expert sublayers' stacked records."""
+        -> (x, routing, shared), `routing` the layers' stacked MoE records
+        (None for dense FFN configs). A run that is a list is a block of
+        unlike sublayers (module docstring) of the kinds `kinds`: the
+        scan's body runs them in turn, each under `_remat` by itself, and
+        `routing` is the list of the expert sublayers' stacked records.
+
+        `shared` holds the tensors that cross layers, by name (`MAKES`):
+        the run's layers read them as constants of the scan, so that the
+        backward pass keeps one copy and their gradient is the sum over
+        the layers that read them, and as inputs of a layer under
+        `_remat`, which does not compute them again. A run with a layer
+        that makes one runs once (`TransformerConfig` sees to it) and
+        without a scan; the returned `shared` has what it made."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -457,23 +609,39 @@ class Transformer:
             with jax.named_scope("qkv"):
                 cos, sin = _rope_tables(positions, cfg.rope_dim,
                                         cfg.rope_theta)
-        layer = Transformer._remat(
-            Transformer._make_layer_fn(cfg, mesh, rules, cos, sin,
-                                       seq_len=x.shape[1]), cfg)
+        layer_fn = Transformer._make_layer_fn(cfg, mesh, rules, cos, sin,
+                                              seq_len=x.shape[1])
+        shared = dict(shared or {})
 
-        def block(x, subs):
+        @functools.cache
+        def layer(kind):
+            return Transformer._remat(
+                functools.partial(layer_fn, kind=kind), cfg)
+
+        def block(x, subs, shared):
             records = []
-            for sub in subs:
-                x, routing = layer(x, sub)
+            for kind, sub in zip(kinds or [None] * len(subs), subs):
+                x, routing, made = layer(kind)(x, sub, shared)
+                shared = {**shared, **made}
                 if routing is not None:
                     records.append(routing)
-            return x, records
+            return x, records, shared
 
         # the scan's own work (stacking and slicing saved activations,
         # carries) is "layers"; each block inside names itself
         with jax.named_scope("layers"):
-            return lax.scan(block if isinstance(layers, list) else layer,
-                            x, layers, unroll=cfg.scan_unroll)
+            if not isinstance(layers, list):
+                x, routing = lax.scan(
+                    lambda x, lp: layer(None)(x, lp, shared)[:2],
+                    x, layers, unroll=cfg.scan_unroll)
+            elif set(kinds or ()) & set(MAKES):
+                x, routing, shared = block(
+                    x, jax.tree.map(lambda leaf: leaf[0], layers), shared)
+            else:
+                x, routing = lax.scan(
+                    lambda x, subs: block(x, subs, shared)[:2],
+                    x, layers, unroll=cfg.scan_unroll)
+        return x, routing, shared
 
     @staticmethod
     def hidden(params, tokens, cfg: TransformerConfig, *,
@@ -501,14 +669,16 @@ class Transformer:
         rules = rules or ShardingRules()
         x = Transformer.embed(params, tokens, cfg, mesh=mesh, rules=rules)
         if "dense_layers" in params:   # the leading run with a dense FFN
-            x, _ = Transformer._stack(
+            x = Transformer._stack(
                 params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
-                positions=positions)
+                positions=positions)[0]
         if "runs" in params:
             records = []   # per run and expert sublayer: [repeats, ...]
-            for run in params["runs"]:
-                x, found = Transformer._stack(
-                    run, x, cfg, mesh=mesh, rules=rules, positions=positions)
+            shared = {}    # the tensors that cross layers (`_stack`)
+            for (kinds, _), run in zip(cfg.pattern_runs, params["runs"]):
+                x, found, shared = Transformer._stack(
+                    run, x, cfg, mesh=mesh, rules=rules, positions=positions,
+                    kinds=kinds, shared=shared)
                 if found:   # into the layers' order: [repeats * found, ...]
                     records.append(jax.tree.map(
                         lambda *r: jnp.stack(r, 1).reshape(
@@ -516,7 +686,7 @@ class Transformer:
             routing = jax.tree.map(lambda *r: jnp.concatenate(r),
                                    *records) if records else None
         else:
-            x, routing = Transformer._stack(
+            x, routing, _ = Transformer._stack(
                 params["layers"], x, cfg, mesh=mesh, rules=rules,
                 positions=positions)
         aux_total = jnp.zeros((), jnp.float32)
@@ -530,7 +700,7 @@ class Transformer:
                     min(cfg.moe_top_k, cfg.moe_experts))
 
         with jax.named_scope("final_norm"):
-            out = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            out = _norm(x, params, "final_norm", cfg.norm_eps)
         if with_aux:
             return out, aux_total, routing
         return out
@@ -538,12 +708,16 @@ class Transformer:
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
                        rules: ShardingRules, cos, sin, seq_len: int):
-        """Build layer(x, lp) -> (x, routing), the body `_stack` scans
-        (or, in a block, one of its sublayers): what lp's leaves say,
-        attention under `attn_norm`, a mixer under `ssm_norm`, an FFN or
-        experts under `mlp_norm`, each `x + f(norm(x))`. `routing` is the
-        MoE layer's record (ops/moe.py `moe_ffn`), None without one. cos
-        and sin are None where the model has no rotary embedding."""
+        """Build layer(x, lp, shared, kind) -> (x, routing, made), the body
+        `_stack` scans (or, in a block, one of its sublayers): what lp's
+        leaves say, attention under `attn_norm`, a mixer under `ssm_norm`,
+        a gated memory unit under `gmu_norm`, an FFN or experts under
+        `mlp_norm`, each `x + f(norm(x))`. `routing` is the MoE layer's
+        record (ops/moe.py `moe_ffn`), None without one; `shared` the
+        tensors earlier layers made for this one, `made` what this layer
+        makes for later ones (`MAKES[kind]`), `kind` the layer's character
+        in `layer_pattern` (None outside one). cos and sin are None where
+        the model has no rotary embedding."""
         import jax
         import jax.numpy as jnp
 
@@ -552,6 +726,9 @@ class Transformer:
             with_logical_constraint, mesh=mesh, rules=rules)
         attn_fn = Transformer._make_attention(cfg, mesh, rules,
                                               seq_len=seq_len)
+        window_fn = Transformer._make_attention(
+            cfg, mesh, rules, seq_len=seq_len, window=cfg.attn_window) \
+            if cfg.attn_window else None
         scale = cfg.head_dim ** -0.5
 
         def heads_constrained(q, k, v):
@@ -591,11 +768,36 @@ class Transformer:
                 k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
                 return heads_constrained(q, k, kv[..., nope:])
 
-        def attention(x, lp):
+        def differential(q, k, v, lp, fn, kind):
+            """Differential attention's `(A1 - lambda A2) V`: q
+            `[B, T, H, D]` with the heads in the order (key pair g, map j,
+            query pair r of the two that share g), k `[B, T, H/2, D]` as
+            (g, j), v `[B, T, H/4, 2D]` -> `[B, T, H, D]`, the pairs
+            (g, r) laid over two heads each. One kernel call: head
+            (g, j, r) reads key head (g, j), GQA's own grouping, and value
+            head g repeated for its two maps."""
+            b, t, h, hd = q.shape
+            with jax.named_scope("attention/" + kind):
+                maps = fn(q, k, jnp.repeat(v, 2, axis=2), scale)
+            with jax.named_scope("attention/diff"):
+                f32 = jnp.float32
+                lam = jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
+                                      * lp["lambda_k1"].astype(f32))) \
+                    - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
+                                      * lp["lambda_k2"].astype(f32))) \
+                    + lp["lambda_init"]
+                maps = maps.reshape(b, t, h // 4, 2, 2, 2 * hd).astype(f32)
+                o = maps[:, :, :, 0] - lam * maps[:, :, :, 1]
+                o = _rmsnorm(o, lp["subln"].astype(f32), cfg.norm_eps) \
+                    * (1.0 - lp["lambda_init"])
+                return o.astype(cdt).reshape(b, t, h, hd)
+
+        def attention(x, lp, shared, kind):
             # one jax.named_scope per block (module docstring): the names
             # reach every op's op_name, and so the device trace
             with jax.named_scope("attn_norm"):
-                h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+                h = _norm(x, lp, "attn_norm", cfg.norm_eps)
+            made = {}
             with jax.named_scope("qkv"):
                 if cfg.kv_lora_rank:
                     q, k, v = latent_qkv(h, lp)
@@ -606,9 +808,16 @@ class Transformer:
                 else:
                     q = jnp.einsum("btd,dhk->bthk", h,
                                    lp["wq"].astype(cdt))
-                    kv = jnp.einsum("btd,dghk->btghk", h,
-                                    lp["wkv"].astype(cdt))
-                    k, v = kv[:, :, 0], kv[:, :, 1]
+                    if "bq" in lp:
+                        q = q + lp["bq"].astype(cdt)
+                    if "wkv" in lp:
+                        kv = jnp.einsum("btd,dghk->btghk", h,
+                                        lp["wkv"].astype(cdt))
+                        if "bkv" in lp:
+                            kv = kv + lp["bkv"].astype(cdt)
+                        k, v = kv[:, :, 0], kv[:, :, 1]
+                    else:   # cross-attention: the layer f's, as they are
+                        k, v = shared["k"], shared["v"]
                 if cfg.qk_norm:
                     q = _rmsnorm(q.reshape(q.shape[:2] + (-1,)),
                                  lp["q_norm"], cfg.norm_eps).reshape(q.shape)
@@ -618,18 +827,61 @@ class Transformer:
                     if cfg.rope:
                         q, k = _rope(q, cos, sin), _rope(k, cos, sin)
                     q, k, v = heads_constrained(q, k, v)
-            with jax.named_scope("attention"):
-                o = attn_fn(q, k, v, scale)
+                if "lambda_q1" in lp and "wkv" in lp:
+                    # a pair's value head: two key heads' columns
+                    v = v.reshape(v.shape[:2] + (-1, 2 * cfg.head_dim))
+                if kind == "f":
+                    made = {"k": k, "v": v}
+            if "lambda_q1" in lp:
+                fn = window_fn if kind == "w" else attn_fn
+                o = differential(q, k, v, lp, fn, {
+                    "w": "window", "f": "full", "c": "cross"}[kind])
+            else:
+                with jax.named_scope("attention"):
+                    o = attn_fn(q, k, v, scale)
             with jax.named_scope("attn_out"):
                 o = constrain(o, ("batch", "seq", "heads", "head_dim"))
                 o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cdt))
-                return x + constrain(o, ("batch", "seq", "act_embed"))
+                if "bo" in lp:
+                    o = o + lp["bo"].astype(cdt)
+                return x + constrain(o, ("batch", "seq", "act_embed")), made
+
+        def mixer1(x, lp):
+            """A Mamba-1 mixer -> (x, its scan output)."""
+            from ray_tpu.ops.ssm import mamba1_mixer
+
+            with jax.named_scope("ssm_norm"):
+                h = _norm(x, lp, "ssm_norm", cfg.norm_eps)
+            cast = dict(lp)
+            for scope, name in (("in_proj", "w_in"), ("x_proj", "w_x"),
+                                ("x_proj", "w_dt"), ("out_proj", "w_out")):
+                with jax.named_scope("ssm/" + scope):
+                    cast[name] = lp[name].astype(cdt)
+            # mamba1_mixer names its own scopes under `ssm/`
+            out, y = mamba1_mixer(h, cast, chunk=min(cfg.ssm_chunk,
+                                                     x.shape[1]))
+            with jax.named_scope("ssm/out_proj"):
+                return x + constrain(out, ("batch", "seq", "act_embed")), y
+
+        def gmu(x, lp, memory):
+            """A gated memory unit: the stream gates the memory."""
+            with jax.named_scope("gmu_norm"):
+                h = _norm(x, lp, "gmu_norm", cfg.norm_eps)
+            with jax.named_scope("gmu/in_proj"):
+                gate = jnp.einsum("btd,de->bte", h,
+                                  lp["w_gmu_in"].astype(cdt))
+            with jax.named_scope("gmu/gate"):
+                gated = memory * jax.nn.silu(gate)
+            with jax.named_scope("gmu/out_proj"):
+                out = jnp.einsum("bte,ed->btd", gated,
+                                 lp["w_gmu_out"].astype(cdt))
+                return x + constrain(out, ("batch", "seq", "act_embed"))
 
         def mixer(x, lp):
             from ray_tpu.ops.ssm import mamba2_mixer
 
             with jax.named_scope("ssm_norm"):
-                h = _rmsnorm(x, lp["ssm_norm"], cfg.norm_eps)
+                h = _norm(x, lp, "ssm_norm", cfg.norm_eps)
             with jax.named_scope("ssm/in_proj"):
                 w_in = lp["w_in"].astype(cdt)
             with jax.named_scope("ssm/out_proj"):
@@ -652,7 +904,7 @@ class Transformer:
 
         def ffn(x, lp):
             with jax.named_scope("mlp_norm"):
-                h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+                h = _norm(x, lp, "mlp_norm", cfg.norm_eps)
             if "w_router" in lp:   # an expert layer, by its leaves
                 from ray_tpu.ops.moe import moe_ffn
 
@@ -688,15 +940,21 @@ class Transformer:
                 x = x + constrain(down, ("batch", "seq", "act_embed"))
             return x, None
 
-        def layer(x, lp):
-            routing = None
+        def layer(x, lp, shared=None, kind=None):
+            routing, made = None, {}
             if "attn_norm" in lp:
-                x = attention(x, lp)
-            if "ssm_norm" in lp:
+                x, made = attention(x, lp, shared, kind)
+            if "w_x" in lp:       # a Mamba-1 mixer, by its leaves
+                x, y = mixer1(x, lp)
+                if kind == "s":
+                    made = {"memory": y}
+            elif "ssm_norm" in lp:
                 x = mixer(x, lp)
+            if "gmu_norm" in lp:
+                x = gmu(x, lp, shared["memory"])
             if "mlp_norm" in lp:
                 x, routing = ffn(x, lp)
-            return x, routing
+            return x, routing, made
 
         return layer
 
@@ -810,7 +1068,10 @@ class Transformer:
 
     @staticmethod
     def _make_attention(cfg: TransformerConfig, mesh, rules: ShardingRules,
-                        seq_len: Optional[int] = None):
+                        seq_len: Optional[int] = None, window: int = 0):
+        """attention(q, k, v, scale) under a causal mask, with `window` > 0
+        one that also ends `window` keys back; dense and flash take it,
+        ring and ulysses do not."""
         import jax
         from jax.sharding import PartitionSpec as P
 
@@ -865,7 +1126,7 @@ class Transformer:
 
         if impl in ("dense", "flash") or seq_unsharded:
             local = flash_attention if impl == "flash" else dense_attention
-            body = functools.partial(local, causal=True)
+            body = functools.partial(local, causal=True, window=window)
             if impl == "flash" and mesh is not None:
                 # pallas kernels don't GSPMD-partition; run per-shard under
                 # shard_map with batch/heads sharded as the constraints say.
@@ -876,6 +1137,9 @@ class Transformer:
 
         from ray_tpu.parallel.ring import ring_attention
         from ray_tpu.parallel.ulysses import ulysses_attention
+
+        if window:
+            raise ValueError("ring and ulysses attention take no window")
 
         # Heads stay sharded over the tensor axis inside the shard_map —
         # SP composes with TP instead of all-gathering Q/K/V heads.

@@ -60,18 +60,24 @@ def gqa_pv(p, v):
 
 
 def dense_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, window: int = 0):
     """Multi-head / grouped-query attention on [batch, seq, heads,
-    head_dim] arrays; k/v may carry fewer (kv) heads than q."""
+    head_dim] arrays; k/v may carry fewer (kv) heads than q, and v
+    another width than k. `window` > 0 (with `causal`): query i sees the
+    keys j with i - j < window."""
     import jax
     import jax.numpy as jnp
 
+    if window and not causal:
+        raise ValueError("a window is a causal window")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s = gqa_scores(q, k, scale)
     if causal:
         tq, tk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((tq, tk), bool))
+        if window:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return gqa_pv(p, v).astype(q.dtype)
@@ -95,7 +101,11 @@ def _splash_block_sizes(t: int, head_dim: int):
     kernel; the whole fetched block in the one backward kernel, which
     makes dK, dV and dQ in one pass over the scores
     (`use_fused_bwd_kernel`). The best of a sweep on a v5e at T = 4096,
-    D = 128, heads 32/8 and 16/16 (PERF.md section 6, PR 32)."""
+    D = 128, heads 32/8 and 16/16 (PERF.md section 6, PR 32). A window
+    keeps these blocks: smaller ones would skip more of a 512 window's
+    keys, and the fused backward's dQ partials, one `[H, T, D]` f32 a kv
+    block, would double (5.4 GB at T = 16,384, 40 heads of 64: PERF.md
+    section 6, PR 43)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
     largest = 1024 if head_dim <= 128 else 512
@@ -116,13 +126,13 @@ FLASH_RESIDUALS = "flash_residuals"
 
 
 def _splash_attention(q, k, v, *, causal: bool, scale: float,
-                      interpret: bool = False):
+                      window: int = 0, interpret: bool = False):
     """`flash_attention`'s body; `interpret` runs the kernels in pallas
     interpret mode, which is how the CPU tests read their numerics."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.pallas.ops.tpu.splash_attention import (
-        CausalMask, FullMask, MultiHeadMask, make_splash_mha)
+        CausalMask, FullMask, LocalMask, MultiHeadMask, make_splash_mha)
 
     t, h, d = q.shape[1:]
     if not flash_shape_ok(t, d):
@@ -135,7 +145,12 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
                          f"query heads ({h})")
     # built once per trace: the mask's block table is numpy, and the layer
     # stack that calls this is one lax.scan
-    head_mask = (CausalMask if causal else FullMask)((t, t))
+    if window and not causal:
+        raise ValueError("a window is a causal window")
+    if window:   # i - j < window and j <= i; blocks outside are skipped
+        head_mask = LocalMask((t, t), (window - 1, 0), 0)
+    else:
+        head_mask = (CausalMask if causal else FullMask)((t, t))
     kernel = make_splash_mha(
         MultiHeadMask([head_mask] * h),
         block_sizes=_splash_block_sizes(t, d), head_shards=1,
@@ -151,12 +166,15 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, window: int = 0):
     """Fused attention on [batch, seq, heads, head_dim] (k, v may carry
-    fewer heads): JAX's pallas splash attention kernels. O(T) memory (the
+    fewer heads, v another width than k): JAX's pallas splash attention
+    kernels. O(T) memory (the
     [B, H, T, T] scores never exist), bf16 operands with f32 accumulation
-    inside a kernel, blocks above the diagonal of a causal mask skipped
-    rather than computed and masked, K and V read at their own head count
+    inside a kernel, blocks above the diagonal of a causal mask (and,
+    with `window` > 0, blocks wholly before the window: `LocalMask`)
+    skipped rather than computed and masked, K and V read at their own
+    head count
     (no GQA repeat in HBM), one backward kernel for dQ, dK and dV. Block
     sizes follow from (T, head_dim): `_splash_block_sizes`.
 
@@ -166,4 +184,5 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    return _splash_attention(q, k, v, causal=causal, scale=scale)
+    return _splash_attention(q, k, v, causal=causal, scale=scale,
+                             window=window)

@@ -1,4 +1,6 @@
-"""Mamba-2 mixer: a selective state-space layer in its chunked (SSD) form.
+"""Mamba-2 mixer: a selective state-space layer in its chunked (SSD) form;
+at the end of the module the Mamba-1 mixer (`selective_scan`,
+`mamba1_mixer`), whose decay differs for every channel and state index.
 
 The reference has no state-space layer of any kind; this fills that row
 beside `ops/attention.py` and `ops/moe.py`. One mixer, H heads of width P
@@ -67,7 +69,8 @@ projection's sum. The gated norm's groups are the B/C groups, so it stays
 local to a share. No code stands in for absent heads.
 
 Scopes (PERF.md section 3): `ssm/in_proj`, `ssm/conv`, `ssm/scan`,
-`ssm/gate_norm`, `ssm/out_proj`.
+`ssm/gate_norm`, `ssm/out_proj`; a Mamba-1 mixer `ssm/in_proj`,
+`ssm/conv`, `ssm/x_proj`, `ssm/scan`, `ssm/gate`, `ssm/out_proj`.
 """
 
 from __future__ import annotations
@@ -623,3 +626,133 @@ def mamba2_mixer(h, lp: Dict[str, Any], *, head_dim: int, state: int,
         y = gated_norm(y, z, lp["gate_norm"], groups, eps).astype(h.dtype)
     with jax.named_scope("ssm/out_proj"):
         return jnp.einsum("bte,ed->btd", y, lp["w_out"])
+
+
+# ---- Mamba-1 ---------------------------------------------------------------
+# The selective scan of Gu & Dao, "Mamba" (2023), as `phi4flash` publishes
+# it: C channels, each with its OWN state of N numbers and its own decay
+#
+#     s_t = exp(dt_t (x) A) . s_{t-1} + (dt_t . x_t) (x) B_t     s is C x N
+#     y_t = s_t C_t
+#
+# `exp(dt_{t,c} A_{c,n})` differs for every channel c and state index n,
+# where Mamba-2's decay is one scalar a head: there is no `[Q, Q]` block
+# product to unroll a chunk into, and the states of all steps `[T, C, N]`
+# in float32 are 5.4 GB at 16,384 tokens and 5,120 channels. So the scan is
+# chunked with the state carried between chunks (`selective_scan`), plain
+# XLA: a pallas kernel is the next step, as `ssd_scan_pallas` was.
+
+
+def _scan_steps(chunk: int) -> int:
+    """Steps a sub-chunk takes one after another: the chunk's other
+    factor, its sub-chunks, run side by side. 64 where it divides."""
+    return next(q for q in (64, 32, 16, 8, 4, 2, 1) if chunk % q == 0)
+
+
+def selective_scan(x, dt, a, b, c, chunk: int):
+    """Mamba-1's recurrence (above): x `[B, T, C]`, dt `[B, T, C]` (after
+    softplus, float32), a `[C, N]` (negative, float32), b and c
+    `[B, T, N]` -> y `[B, T, C]` float32. T % chunk != 0 is refused.
+
+    T/chunk chunks run one after another (`lax.scan`, the state `[B, N,
+    C]` float32 its carry: channels on the lanes) and each is a
+    `jax.checkpoint`: what the backward pass keeps is a chunk's inputs and
+    entering state, and one chunk's states at a time are alive. Inside a
+    chunk, its chunk/Q sub-chunks of Q steps run side by side from a zero
+    state, Q steps of one `[B, chunk/Q, N, C]` update each (few, wide
+    steps where the plain recurrence is T narrow ones); the sub-chunks'
+    entering states are then chained (chunk/Q steps) and what each
+    entering state adds to its steps' outputs, `sum_n C_t[n]
+    exp(cum_t a)[c, n] s_in[c, n]` with `cum` the running sum of dt
+    inside the sub-chunk, is one elementwise pass with no recurrence.
+    Every decay is the exponential of a non-positive number: nothing
+    overflows, whatever dt."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    bsz, t, ch = x.shape
+    n = b.shape[-1]
+    groups, q = _whole_chunks(t, chunk), _scan_steps(chunk)
+    sub = chunk // q
+    f32 = jnp.float32
+    a_t = a.astype(f32).T                                   # [N, C]
+
+    @jax.checkpoint
+    def one_chunk(s0, inp):
+        xg, dtg, bg, cg = inp          # [B, sub, Q, C] x2, [B, sub, Q, N] x2
+        dtx = dtg * xg.astype(f32)
+
+        def step(s, at):
+            dt_q, dtx_q, b_q, c_q = at          # [B, sub, C] x2, [B, sub, N] x2
+            s = jnp.exp(dt_q[:, :, None] * a_t) * s \
+                + b_q[..., None] * dtx_q[:, :, None]
+            return s, jnp.sum(s * c_q[..., None], axis=2)
+
+        by_step = [jnp.moveaxis(v, 2, 0) for v in (
+            dtg, dtx, bg.astype(f32), cg.astype(f32))]
+        local, y = lax.scan(step, jnp.zeros((bsz, sub, n, ch), f32),
+                            by_step)
+        # the sub-chunks' entering states, chained
+        cum = jnp.cumsum(dtg, axis=2)                       # [B, sub, Q, C]
+        through = jnp.exp(cum[:, :, -1, None] * a_t)        # [B, sub, N, C]
+
+        def chain(s, at):
+            decay, added = at
+            return decay * s + added, s
+
+        s_end, entering = lax.scan(
+            chain, s0, (jnp.moveaxis(through, 1, 0),
+                        jnp.moveaxis(local, 1, 0)))
+        entering = jnp.moveaxis(entering, 0, 1)             # [B, sub, N, C]
+        carried = jnp.sum(
+            cg.astype(f32)[..., None] * jnp.exp(cum[:, :, :, None] * a_t)
+            * entering[:, :, None], axis=3)                 # [B, sub, Q, C]
+        return s_end, jnp.moveaxis(y, 0, 2) + carried
+
+    def chunks(v):   # [B, T, W] -> [groups, B, sub, Q, W]
+        return jnp.moveaxis(
+            v.reshape(bsz, groups, sub, q, v.shape[-1]), 1, 0)
+
+    _, y = lax.scan(one_chunk, jnp.zeros((bsz, n, ch), f32),
+                    (chunks(x), chunks(dt.astype(f32)), chunks(b),
+                     chunks(c)))
+    return jnp.moveaxis(y, 0, 1).reshape(bsz, t, ch)
+
+
+def mamba1_mixer(h, lp: Dict[str, Any], *, chunk: int):
+    """h `[B, T, d]` (normed, compute dtype) -> (the mixer's output before
+    the residual `[B, T, d]`, the scan's output y `[B, T, C]` in the
+    compute dtype: with the `D x` skip, before the gate). lp: `w_in
+    [d, 2·C]` ([x | z]), `w_x [C, R + 2·N]` ([delta | B | C]), `w_dt
+    [R, C]` and `w_out [C, d]` in the compute dtype; `conv_w [C, K]`,
+    `conv_b`, `dt_bias [C]`, `A_log [C, N]`, `D [C]`. Channels, state and
+    the step's rank are read off the leaves. B, C and delta come off the
+    convolved x, not off the stream; dt, the decays and the state are
+    float32."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    inner, n = lp["A_log"].shape
+    rank = lp["w_dt"].shape[0]
+    with jax.named_scope("ssm/in_proj"):
+        xz = jnp.einsum("btd,de->bte", h, lp["w_in"])
+        x, z = xz[..., :inner], xz[..., inner:]
+    with jax.named_scope("ssm/conv"):
+        x = jax.nn.silu(causal_conv(x, lp["conv_w"], lp["conv_b"]))
+    with jax.named_scope("ssm/x_proj"):
+        dbc = jnp.einsum("btc,ce->bte", x, lp["w_x"],
+                         preferred_element_type=f32)
+        b, c = dbc[..., rank:rank + n], dbc[..., rank + n:]
+        dt = jnp.einsum("btr,rc->btc", dbc[..., :rank].astype(h.dtype),
+                        lp["w_dt"], preferred_element_type=f32)
+        dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
+    with jax.named_scope("ssm/scan"):
+        y = selective_scan(x, dt, -jnp.exp(lp["A_log"].astype(f32)), b, c,
+                           chunk)
+        y = (y + x.astype(f32) * lp["D"].astype(f32)).astype(h.dtype)
+    with jax.named_scope("ssm/gate"):
+        gated = y * jax.nn.silu(z)
+    with jax.named_scope("ssm/out_proj"):
+        return jnp.einsum("bte,ed->btd", gated, lp["w_out"]), y

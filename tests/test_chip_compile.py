@@ -586,3 +586,104 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
                   "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
                   "attention", "qkv", "attn_out"):
         assert re.search(r'op_name="[^"]*[/(]' + scope + r'[/)]', hlo), scope
+
+
+@pytest.mark.parametrize("window", [0, 512], ids=["causal", "window512"])
+def test_masked_differential_kernels_fwd_bwd(v5e, window):
+    """The call differential attention makes at Phi-4-mini-flash's widths
+    (40 query heads of 64 in the order key pair, map, query pair over 20
+    key heads, the 10 value heads of 128 repeated for their two maps)
+    under the causal mask and under a 512 window, forward and backward for
+    the v5e compiler: one forward call and one that makes dK, dV and dQ,
+    V reaching both 128 wide."""
+    import re
+
+    from ray_tpu.ops.attention import flash_attention
+
+    t = 4096
+    chip = SingleDeviceSharding(v5e)
+    q = jax.ShapeDtypeStruct((1, t, 40, 64), jnp.bfloat16, sharding=chip)
+    k = jax.ShapeDtypeStruct((1, t, 20, 64), jnp.bfloat16, sharding=chip)
+    v = jax.ShapeDtypeStruct((1, t, 20, 128), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, window=window).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile()
+    hlo = compiled.as_text()
+    names = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    assert sorted(re.sub(r"\.\d+$", "", n) for n in names) == [
+        "splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"], names
+    # V and dV at their width, 20 heads; the maps' outputs at 40
+    assert re.search(rf"bf16\[(1,)?20,{t},128\]", hlo)
+    assert re.search(rf"bf16\[(1,)?40,{t},128\]", hlo)
+
+
+def test_sambay_step_compiles_and_fits_the_v5e(v5e):
+    """Published layers 14-19 of Phi-4-mini-flash-reasoning at their
+    widths and an eighth of the vocabulary (the benchmark's
+    `train_phi4miniflash_d6`) as one train step of 16,384 tokens for the
+    v5e: splash's kernels once forward and once backward for each of the
+    three attention layers, under `attention/window`, `attention/full` and
+    `attention/cross`; the Mamba-1 scans under `ssm/scan` as plain XLA
+    loops; and the compiler's memory report under the 15.75 GB the
+    runtime gives a program."""
+    import re
+
+    import optax
+
+    from ray_tpu.models import Transformer
+    from ray_tpu.models.configs import TransformerConfig
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.parallel.train_step import make_train_step
+
+    seq = 16384
+    cfg = TransformerConfig(
+        vocab_size=25008, d_model=2560, n_layers=6, layer_pattern="mwsfgc",
+        layer_index_offset=14, n_heads=40, n_kv_heads=20, rope=False,
+        diff_attention=True, attn_bias=True, attn_window=512, d_ff=10240,
+        max_seq_len=seq, norm="layernorm", tie_embeddings=True,
+        ssm_d_inner=5120, ssm_state=16, ssm_dt_rank=160, ssm_chunk=1024,
+        attention_impl="auto", dtype="bfloat16", param_dtype="float32",
+        remat=True, loss_chunk=256)
+    assert cfg.num_params == 697_094_272
+    mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
+    assert Transformer.resolve_attention_impl(cfg, mesh, seq) == "flash"
+    optimizer = optax.adamw(3e-4, weight_decay=0.01)
+    _, train_step = make_train_step(
+        lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh),
+        Transformer.param_specs(cfg), mesh, optimizer=optimizer,
+        frozen=Transformer.frozen(cfg))
+
+    def init(key):
+        params = Transformer.init(key, cfg)
+        return {"params": params, "opt_state": optimizer.init(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    state = jax.eval_shape(init, jax.random.key(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, seq + 1), jnp.int32)}
+    compiled = train_step.lower(state, batch).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes
+             + ma.generated_code_size_in_bytes)
+    assert 12e9 < total < 15.75e9, total
+    hlo = re.sub(r"kernel_metadata=\{\n[^\n]*\n\}", "kernel_metadata={}",
+                 compiled.as_text())
+    kernels = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', hlo)
+    for kind in ("window", "full", "cross"):
+        mine = [n for n, op in kernels if f"attention/{kind}" in op]
+        assert sorted(re.sub(r"\.\d+$", "", n) for n in mine) == [
+            "splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"], \
+            (kind, kernels)
+    assert len(kernels) == 6
+    # remat keeps the forward kernels' outputs: none runs again
+    assert not [op for _, op in kernels if "rematted_computation" in op]
+    for scope in ("ssm/scan", "ssm/x_proj", "ssm/gate", "gmu/gate",
+                  "attention/diff", "mlp/gate_up", "head"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)"]', hlo), scope
