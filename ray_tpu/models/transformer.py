@@ -859,7 +859,7 @@ class Transformer:
                     cast[name] = lp[name].astype(cdt)
             # mamba1_mixer names its own scopes under `ssm/`
             out, y = mamba1_mixer(h, cast, chunk=min(cfg.ssm_chunk,
-                                                     x.shape[1]))
+                                                     x.shape[1]), mesh=mesh)
             with jax.named_scope("ssm/out_proj"):
                 return x + constrain(out, ("batch", "seq", "act_embed")), y
 

@@ -1,6 +1,8 @@
 """Mamba-2 mixer: a selective state-space layer in its chunked (SSD) form;
 at the end of the module the Mamba-1 mixer (`selective_scan`,
-`mamba1_mixer`), whose decay differs for every channel and state index.
+`selective_scan_pallas`, `mamba1_mixer`), whose decay differs for every
+channel and state index: its scan too is two implementations of one
+algorithm, chosen at trace time by `selective_scan_impl`.
 
 The reference has no state-space layer of any kind; this fills that row
 beside `ops/attention.py` and `ops/moe.py`. One mixer, H heads of width P
@@ -185,6 +187,16 @@ def scan_shape_ok(seq_len: int, heads: int, head_dim: int, groups: int,
             and scan_head_block(heads // groups, head_dim) is not None)
 
 
+def _one_tpu_device(mesh) -> bool:
+    """Whether the program runs on one TPU device: the mesh's, or the
+    default device where there is no mesh. Where a pallas call can run:
+    GSPMD cannot partition one."""
+    import jax
+
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    return device.platform == "tpu" and (mesh is None or mesh.size == 1)
+
+
 def ssd_scan_impl(mesh, seq_len: int, heads: int, head_dim: int,
                   groups: int, state: int, chunk: int) -> str:
     """`"pallas"` (`ssd_scan_pallas`) where the program runs on one TPU
@@ -193,13 +205,8 @@ def ssd_scan_impl(mesh, seq_len: int, heads: int, head_dim: int,
     GSPMD can partition it, which it cannot a pallas call). Decided at
     trace time, like `ops/moe.grouped_matmul_impl`; a job may print it to
     say what a step compiled with."""
-    import jax
-
-    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
-    one_device = mesh is None or mesh.size == 1
-    return "pallas" if device.platform == "tpu" and one_device and \
-        scan_shape_ok(seq_len, heads, head_dim, groups, state, chunk) \
-        else "xla"
+    return "pallas" if _one_tpu_device(mesh) and scan_shape_ok(
+        seq_len, heads, head_dim, groups, state, chunk) else "xla"
 
 
 def _head_lanes(hb: int, p: int):
@@ -638,9 +645,34 @@ def mamba2_mixer(h, lp: Dict[str, Any], *, head_dim: int, state: int,
 # `exp(dt_{t,c} A_{c,n})` differs for every channel c and state index n,
 # where Mamba-2's decay is one scalar a head: there is no `[Q, Q]` block
 # product to unroll a chunk into, and the states of all steps `[T, C, N]`
-# in float32 are 5.4 GB at 16,384 tokens and 5,120 channels. So the scan is
-# chunked with the state carried between chunks (`selective_scan`), plain
-# XLA: a pallas kernel is the next step, as `ssd_scan_pallas` was.
+# in float32 are 5.4 GB at 16,384 tokens and 5,120 channels. Two
+# implementations, chosen at trace time by `selective_scan_impl` (no
+# option, as `ssd_scan_impl` chooses for Mamba-2):
+#
+# - `"xla"` (`selective_scan`): chunks with the state carried between
+#   them, each a checkpoint; a chunk's `[chunk, N, C]` float32 states go
+#   through HBM in every pass. It runs on the CPU, on a mesh above one
+#   device and for every shape the kernels do not tile, and it is the
+#   oracle of the kernels' tests.
+# - `"pallas"` (`selective_scan_pallas`): on one TPU device where the
+#   shapes tile (`scan1_shape_ok`). Grid `(batch, channel blocks, time
+#   blocks)`, the time axis sequential. x `[Q, Cb]` and dt `[Q, Cb]` are
+#   read as lane-dense blocks of the `[B, T, C]` layout they arrive in and
+#   `y + D x` is written the same way, in the compute dtype; b and c
+#   arrive with (step, n) on the lanes (1 MB each, turned by XLA) and are
+#   laid down the sublanes once a block (`_columns`). The state `[N,
+#   lanes]` float32 (n on the sublanes, the channels on the lanes) is in
+#   registers over a block's steps and in a VMEM scratch between blocks:
+#   a step is `s = exp(dt_t a) s + b_t (dt_t x_t)`, `y_t = sum_n c_t s`,
+#   in float32, every decay the exponential of a non-positive number. The
+#   forward (`selective_scan_fwd`) also writes the state entering each
+#   time block, `[T/Q, N, C]` float32: its one residual besides its
+#   inputs. The backward (`selective_scan_bwd`, under `jax.custom_vjp`)
+#   walks the time blocks in reverse with the state's cotangent carried in
+#   VMEM, makes a block's states again from its entering state into VMEM,
+#   and returns dx, ddt, this channel block's part of db and dc (their
+#   sums over the lanes turned back by one transpose a block), and da and
+#   dD summed over time in their output blocks.
 
 
 def _scan_steps(chunk: int) -> int:
@@ -720,7 +752,395 @@ def selective_scan(x, dt, a, b, c, chunk: int):
     return jnp.moveaxis(y, 0, 1).reshape(bsz, t, ch)
 
 
-def mamba1_mixer(h, lp: Dict[str, Any], *, chunk: int):
+# ---- the Mamba-1 scan as a pallas TPU kernel --------------------------------
+# One grid step takes SCAN1_STEPS steps of at most SCAN1_CHANNELS channels
+# and walks them `SCAN1_GROUP` lanes at a time, the steps one after
+# another with the group's state `[N, lanes]` float32 in registers (found
+# on the v5e: PERF.md section 6, PR 45). The tiling is the kernel's own:
+# `chunk` is only what T must divide by.
+SCAN1_STEPS = 128
+SCAN1_CHANNELS = 2560
+SCAN1_GROUP = 640
+
+
+def scan1_channel_block(channels: int):
+    """Channels one grid step of the Mamba-1 kernels takes: the most
+    whole lane tiles that divide the channels and stay inside
+    `SCAN1_CHANNELS`; None where the channels are no whole lane tiles."""
+    return next((cb for cb in range(SCAN1_CHANNELS, 0, -SCAN_LANES)
+                 if channels % cb == 0), None)
+
+
+def _scan1_group(block: int) -> int:
+    """Lanes of a channel block whose state is carried in registers at
+    once: the most whole lane tiles that divide it inside `SCAN1_GROUP`."""
+    return next(g for g in range(min(block, SCAN1_GROUP), 0, -SCAN_LANES)
+                if block % g == 0)
+
+
+def scan1_shape_ok(seq_len: int, channels: int, state: int,
+                   chunk: int) -> bool:
+    """Whether the Mamba-1 kernels tile the scan: T whole chunks and whole
+    time blocks, channels a whole number of channel blocks, a state of
+    whole sublane tiles."""
+    return (seq_len % chunk == 0 and seq_len % SCAN1_STEPS == 0
+            and state % 8 == 0 and scan1_channel_block(channels) is not None)
+
+
+def selective_scan_impl(mesh, seq_len: int, channels: int, state: int,
+                        chunk: int) -> str:
+    """`"pallas"` (`selective_scan_pallas`) where the program runs on one
+    TPU device and the kernels tile the shapes (`scan1_shape_ok`), else
+    `"xla"` (`selective_scan`: any platform, any whole number of chunks,
+    and GSPMD can partition it). Decided at trace time, as
+    `ssd_scan_impl` decides for Mamba-2."""
+    return "pallas" if _one_tpu_device(mesh) and scan1_shape_ok(
+        seq_len, channels, state, chunk) else "xla"
+
+
+def _columns(row):
+    """A block's b or c as it arrives, `[1, Q·N]` with (step, n) on the
+    lanes -> `[Q·N, 128]`: rows t·N .. t·N + N are step t's N numbers down
+    the sublanes, each across all lanes (the state has n on the sublanes
+    and the channels on the lanes). A sublane broadcast and one
+    transpose."""
+    import jax.numpy as jnp
+
+    return jnp.broadcast_to(row, (SCAN_LANES, row.shape[1])).T
+
+
+def _lane_sums(acc):
+    """`[Q·N, 128]` -> `[1, Q·N]`, each row's sum over the lanes: the
+    turn of `_columns` back, for db and dc."""
+    import jax.numpy as jnp
+
+    return jnp.sum(acc.T, axis=0, keepdims=True)
+
+
+def _steps(q: int, body, init):
+    """`body(i, j, carry)` over the steps t = 8 i + j, 0 .. q - 1, a
+    sublane tile of steps to a loop body: Mosaic loads a row of a tile at
+    a dynamic tile index i and a static row j, not at a dynamic row, and
+    what a step does beside the chain of its state overlaps its
+    neighbours'."""
+    import jax
+
+    def tile(i, carry):
+        for j in range(8):
+            carry = body(i, j, carry)
+        return carry
+
+    return jax.lax.fori_loop(0, q // 8, tile, init)
+
+
+def _each_group(groups: int, body):
+    """`body(g)` for the lane groups 0 .. groups - 1 as one loop, not
+    `groups` copies of its steps: a kernel's body is traced and lowered
+    in every program that holds a call (warm `setup_s`: PERF.md section
+    6, PR 45). The bodies write to refs, the kernel's memory."""
+    import jax
+
+    jax.lax.fori_loop(0, groups, lambda g, carry: (body(g), carry)[1], 0)
+
+
+def _to_groups(scr, v):
+    """`[rows, Cb]` laid into a scratch `[G, rows/8, 8, W]`: lane group g
+    apart (the kernels walk the groups in a loop, and a loop's index can
+    choose a leading axis, not a lane), a step's row at `[g, t // 8,
+    t % 8]`. Rows of a's kind, `[N, Cb]`, go to `[G, N/8, 8, W]` alike."""
+    width = scr.shape[-1]
+    for g in range(scr.shape[0]):
+        scr[g] = v[:, g * width:(g + 1) * width].reshape(scr.shape[1:])
+
+
+def _from_groups(scr):
+    """`_to_groups` back: `[G, rows/8, 8, W]` -> `[rows, Cb]`."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate(
+        [scr[g].reshape(-1, scr.shape[-1]) for g in range(scr.shape[0])],
+        axis=1)
+
+
+def _lay_down(x_ref, dt_ref, b_ref, c_ref, a_ref, dt_scr, u_scr, a_scr,
+              bcol, ccol):
+    """What both kernels read a step at a time, laid down once a block:
+    dt, `dt x` and a by lane groups, b and c down the sublanes."""
+    import jax.numpy as jnp
+
+    _to_groups(dt_scr, dt_ref[0])
+    _to_groups(u_scr, dt_ref[0] * x_ref[0].astype(jnp.float32))
+    _to_groups(a_scr, a_ref[...])
+    bcol[...] = _columns(b_ref[0])
+    ccol[...] = _columns(c_ref[0])
+
+
+def _scan1_fwd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref,
+                      st_ref, state, dt_scr, u_scr, y_scr, a_scr, bcol,
+                      ccol, *, n: int):
+    """One time block of one channel block: x `[Q, Cb]`, dt `[Q, Cb]`
+    float32, b and c `[1, Q·N]`, a `[N, Cb]`, D `[1, Cb]` -> `y + D x`
+    `[Q, Cb]` in x's dtype and the state that entered the block,
+    `[N, Cb]`; `state` carries it over the time blocks, by lane groups."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    q = x_ref.shape[1]
+    groups, tiles = state.shape[0], state.shape[-1] // SCAN_LANES
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    st_ref[0, 0] = _from_groups(state)
+    _lay_down(x_ref, dt_ref, b_ref, c_ref, a_ref, dt_scr, u_scr, a_scr,
+              bcol, ccol)
+
+    def group(g):
+        a_g = a_scr[g].reshape(n, -1)
+
+        def step(i, j, s):
+            rows = pl.ds(pl.multiple_of((i * 8 + j) * n, n), n)
+            # a tile's columns over the group's tiles: the same registers
+            b_t = pltpu.repeat(bcol[rows, :], tiles, axis=1)
+            c_t = pltpu.repeat(ccol[rows, :], tiles, axis=1)
+            # the exponential of a non-positive number
+            s = jnp.exp(dt_scr[g, i, j:j + 1, :] * a_g) * s \
+                + b_t * u_scr[g, i, j:j + 1, :]
+            y_scr[g, i, j:j + 1, :] = jnp.sum(c_t * s, axis=0, keepdims=True)
+            return s
+
+        state[g] = _steps(q, step, state[g].reshape(n, -1)).reshape(
+            state.shape[1:])
+
+    _each_group(groups, group)
+    # f32, rounded once
+    y_ref[0] = (_from_groups(y_scr)
+                + x_ref[0].astype(f32) * d_ref[...]).astype(y_ref.dtype)
+
+
+def _scan1_bwd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, st_ref,
+                      g_ref, dx_ref, ddt_ref, db_ref, dc_ref, da_ref,
+                      dd_ref, dstate, da_acc, dt_scr, u_scr, g_scr, du_scr,
+                      ddta_scr, a_scr, st_scr, bcol, ccol, dbacc, dcacc,
+                      states, *, n: int):
+    """The same block going backward (the time blocks last to first):
+    besides the forward's operands the state that entered `[N, Cb]` and
+    dy `[Q, Cb]` -> dx, ddt `[Q, Cb]`, this channel block's part of db
+    and dc `[1, Q·N]`, and, summed over the time blocks (`da_acc`, the
+    output block itself), da `[N, Cb]` and dD `[1, Cb]`. A lane group's
+    states are made again from the entering state into `states` (row
+    block t + 1 is s_t, row block 0 the entering state) and never leave
+    VMEM; `dstate` carries `g_{t+1} * dL/ds_{t+1}`, the cotangent a block
+    hands the one before it."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    q = x_ref.shape[1]
+    groups, width = dstate.shape[0], dstate.shape[-1]
+    tiles = width // SCAN_LANES
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+        da_acc[...] = jnp.zeros_like(da_acc)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    _lay_down(x_ref, dt_ref, b_ref, c_ref, a_ref, dt_scr, u_scr, a_scr,
+              bcol, ccol)
+    _to_groups(g_scr, g_ref[0].astype(f32))
+    _to_groups(st_scr, st_ref[0, 0])
+    dbacc[...] = jnp.zeros_like(dbacc)
+    dcacc[...] = jnp.zeros_like(dcacc)
+
+    def block(t):
+        return pl.ds(pl.multiple_of(t * n, n), n)
+
+    def over_tiles(v):
+        """`[N, W]` -> `[N, 128]`: the sum of the group's lane tiles, what
+        is left of a sum over the channels before `_lane_sums`."""
+        return functools.reduce(lambda u, k: u + v[
+            :, k * SCAN_LANES:(k + 1) * SCAN_LANES], range(1, tiles),
+            v[:, :SCAN_LANES])
+
+    def group(g):
+        a_g = a_scr[g].reshape(n, width)
+        states[0:n, :] = st_scr[g].reshape(n, width)
+
+        def forward(i, j, s):
+            t = i * 8 + j
+            s = jnp.exp(dt_scr[g, i, j:j + 1, :] * a_g) * s \
+                + pltpu.repeat(bcol[block(t), :], tiles, axis=1) \
+                * u_scr[g, i, j:j + 1, :]
+            states[block(t + 1), :] = s
+            return s
+
+        _steps(q, forward, states[0:n, :])
+
+        def backward(i, j, carry):
+            i, j = q // 8 - 1 - i, 7 - j
+            t = i * 8 + j
+            lam, da = carry
+            b_t = pltpu.repeat(bcol[block(t), :], tiles, axis=1)
+            c_t = pltpu.repeat(ccol[block(t), :], tiles, axis=1)
+            dt_t = dt_scr[g, i, j:j + 1, :]
+            dy_t = g_scr[g, i, j:j + 1, :]
+            lam = lam + c_t * dy_t                            # dL/ds_t
+            dcacc[block(t), :] += over_tiles(dy_t * states[block(t + 1), :])
+            dbacc[block(t), :] += over_tiles(lam * u_scr[g, i, j:j + 1, :])
+            du_scr[g, i, j:j + 1, :] = jnp.sum(b_t * lam, axis=0,
+                                               keepdims=True)
+            lam = jnp.exp(dt_t * a_g) * lam       # what s_{t-1} receives
+            w = lam * states[block(t), :]                 # dL/d(dt_t a)
+            ddta_scr[g, i, j:j + 1, :] = jnp.sum(w * a_g, axis=0,
+                                                 keepdims=True)
+            return lam, da + w * dt_t
+
+        lam, da = _steps(q, backward, (dstate[g].reshape(n, width),
+                                       da_acc[g].reshape(n, width)))
+        dstate[g] = lam.reshape(dstate.shape[1:])
+        da_acc[g] = da.reshape(da_acc.shape[1:])
+
+    _each_group(groups, group)
+    x32, dy = x_ref[0].astype(f32), g_ref[0].astype(f32)
+    du = _from_groups(du_scr)
+    dx_ref[0] = (du * dt_ref[0] + dy * d_ref[...]).astype(dx_ref.dtype)
+    ddt_ref[0] = _from_groups(ddta_scr) + du * x32
+    da_ref[0] = _from_groups(da_acc)
+    dd_ref[0] += jnp.sum(dy * x32, axis=0, keepdims=True)
+    db_ref[0, 0] = _lane_sums(dbacc[...])
+    dc_ref[0, 0] = _lane_sums(dcacc[...])
+
+
+@functools.lru_cache(maxsize=None)
+def _scan1_calls(bsz: int, t: int, ch: int, n: int, q: int, cb: int,
+                 group: int, interpret: bool):
+    """The Mamba-1 scan of one set of shapes under `jax.custom_vjp`, built
+    once a process with each `pallas_call` behind a `jax.jit` of its own,
+    for `_scan_calls`' reason."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nt, blocks = t // q, ch // cb
+    f32 = jnp.float32
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+    def specs(reverse):
+        """The block specs of a walk over the time blocks, first to last
+        or last to first: x-wide, b-like rows, a, D, the entering states,
+        a channel block's part of db or dc, da's and dD's sums."""
+        def at(ti):
+            return nt - 1 - ti if reverse else ti
+        return (
+            pl.BlockSpec((1, q, cb), lambda bi, ci, ti: (bi, at(ti), ci)),
+            pl.BlockSpec((1, 1, q * n), lambda bi, ci, ti: (bi, 0, at(ti))),
+            pl.BlockSpec((n, cb), lambda bi, ci, ti: (0, ci)),
+            pl.BlockSpec((1, cb), lambda bi, ci, ti: (0, ci)),
+            pl.BlockSpec((1, 1, n, cb),
+                         lambda bi, ci, ti: (bi, at(ti), 0, ci)),
+            pl.BlockSpec((1, 1, 1, q * n),
+                         lambda bi, ci, ti: (bi, ci, 0, at(ti))),
+            pl.BlockSpec((1, n, cb), lambda bi, ci, ti: (bi, 0, ci)),
+            pl.BlockSpec((1, 1, cb), lambda bi, ci, ti: (bi, 0, ci)))
+
+    def columns():
+        return pltpu.VMEM((q * n, SCAN_LANES), f32)
+
+    def by_groups(rows):
+        return pltpu.VMEM((cb // group, rows // 8, 8, group), f32)
+
+    @functools.partial(jax.jit, inline=True)
+    def forward(x, dt, a_t, b_row, c_row, d_row):
+        wide, rows, a_spec, d_spec, states, _, _, _ = specs(False)
+        return pl.pallas_call(
+            functools.partial(_scan1_fwd_kernel, n=n),
+            grid=(bsz, blocks, nt),
+            in_specs=[wide, wide, rows, rows, a_spec, d_spec],
+            out_specs=[wide, states],
+            out_shape=[jax.ShapeDtypeStruct((bsz, t, ch), x.dtype),
+                       jax.ShapeDtypeStruct((bsz, nt, n, ch), f32)],
+            scratch_shapes=[by_groups(n)] + [by_groups(q)] * 3
+            + [by_groups(n)] + [columns()] * 2,
+            compiler_params=params, interpret=interpret,
+            name="selective_scan_fwd")(x, dt, b_row, c_row, a_t, d_row)
+
+    @functools.partial(jax.jit, inline=True)
+    def backward(x, dt, a_t, b_row, c_row, d_row, entering, dy):
+        wide, rows, a_spec, d_spec, states, parts, da_spec, dd_spec = \
+            specs(True)
+        part = jax.ShapeDtypeStruct((bsz, blocks, 1, t * n), f32)
+        return pl.pallas_call(
+            functools.partial(_scan1_bwd_kernel, n=n),
+            grid=(bsz, blocks, nt),
+            in_specs=[wide, wide, rows, rows, a_spec, d_spec, states, wide],
+            out_specs=[wide, wide, parts, parts, da_spec, dd_spec],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct(x.shape, f32), part, part,
+                       jax.ShapeDtypeStruct((bsz, n, ch), f32),
+                       jax.ShapeDtypeStruct((bsz, 1, ch), f32)],
+            scratch_shapes=[by_groups(n)] * 2 + [by_groups(q)] * 5
+            + [by_groups(n)] * 2 + [columns()] * 4
+            + [pltpu.VMEM(((q + 1) * n, group), f32)],
+            compiler_params=params, interpret=interpret,
+            name="selective_scan_bwd")(x, dt, b_row, c_row, a_t, d_row,
+                                       entering, dy)
+
+    @jax.custom_vjp
+    def scan(x, dt, a_t, b_row, c_row, d_row):
+        return forward(x, dt, a_t, b_row, c_row, d_row)[0]
+
+    def scan_fwd(*operands):
+        y, entering = forward(*operands)
+        return y, (operands, entering)
+
+    def scan_bwd(res, dy):
+        operands, entering = res
+        dx, ddt, db, dc, da, dd = backward(*operands, entering, dy)
+        # the channel blocks' parts of db and dc, the rows' of da and dD
+        return (dx, ddt, da.sum(axis=0), db.sum(axis=1), dc.sum(axis=1),
+                dd.sum(axis=0))
+
+    scan.defvjp(scan_fwd, scan_bwd)
+    return scan
+
+
+def selective_scan_pallas(x, dt, a, b, c, d, chunk: int, *,
+                          interpret: bool = False):
+    """`selective_scan` with the `D x` skip as a pallas TPU kernel and a
+    backward kernel of its own: x `[B, T, C]`, dt `[B, T, C]` (after
+    softplus, float32), a `[C, N]` (negative), b and c `[B, T, N]`, d
+    `[C]` -> `y + d x` `[B, T, C]` in x's dtype (computed in float32,
+    rounded once). `chunk` is what T must divide by, as it must in
+    `selective_scan`; the kernels tile time by `SCAN1_STEPS`. The shapes
+    are `scan1_shape_ok`'s to vouch for; `interpret` is the tests' (the
+    kernel on the CPU)."""
+    import jax.numpy as jnp
+
+    bsz, t, ch = x.shape
+    n = b.shape[-1]
+    _whole_chunks(t, chunk)
+    _whole_chunks(t, SCAN1_STEPS)
+    cb = scan1_channel_block(ch)
+    scan = _scan1_calls(bsz, t, ch, n, SCAN1_STEPS, cb, _scan1_group(cb),
+                        interpret)
+    f32 = jnp.float32
+    # a with the channels on the lanes, b and c with (step, n) on the
+    # lanes: 1 MB each, turned by XLA
+    return scan(x, dt.astype(f32), a.astype(f32).T,
+                b.astype(f32).reshape(bsz, 1, t * n),
+                c.astype(f32).reshape(bsz, 1, t * n),
+                d.astype(f32).reshape(1, ch))
+
+
+def mamba1_mixer(h, lp: Dict[str, Any], *, chunk: int, mesh=None):
     """h `[B, T, d]` (normed, compute dtype) -> (the mixer's output before
     the residual `[B, T, d]`, the scan's output y `[B, T, C]` in the
     compute dtype: with the `D x` skip, before the gate). lp: `w_in
@@ -729,7 +1149,8 @@ def mamba1_mixer(h, lp: Dict[str, Any], *, chunk: int):
     `conv_b`, `dt_bias [C]`, `A_log [C, N]`, `D [C]`. Channels, state and
     the step's rank are read off the leaves. B, C and delta come off the
     convolved x, not off the stream; dt, the decays and the state are
-    float32."""
+    float32. `mesh` is what the program runs on, for
+    `selective_scan_impl`'s choice."""
     import jax
     import jax.numpy as jnp
 
@@ -749,9 +1170,13 @@ def mamba1_mixer(h, lp: Dict[str, Any], *, chunk: int):
                         lp["w_dt"], preferred_element_type=f32)
         dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
     with jax.named_scope("ssm/scan"):
-        y = selective_scan(x, dt, -jnp.exp(lp["A_log"].astype(f32)), b, c,
-                           chunk)
-        y = (y + x.astype(f32) * lp["D"].astype(f32)).astype(h.dtype)
+        a = -jnp.exp(lp["A_log"].astype(f32))
+        if selective_scan_impl(mesh, x.shape[1], inner, n, chunk) == "pallas":
+            # the kernel reads the convolution's own layout and adds D x
+            y = selective_scan_pallas(x, dt, a, b, c, lp["D"], chunk)
+        else:
+            y = selective_scan(x, dt, a, b, c, chunk)
+            y = (y + x.astype(f32) * lp["D"].astype(f32)).astype(h.dtype)
     with jax.named_scope("ssm/gate"):
         gated = y * jax.nn.silu(z)
     with jax.named_scope("ssm/out_proj"):

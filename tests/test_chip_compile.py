@@ -8,6 +8,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -470,6 +471,62 @@ def test_scan_kernels_fwd_bwd(v5e, b, t, h, p, g, n, q):
     assert not re.search(rf"\[{b},{t},{h},{p}\]", hlo)
 
 
+# [B, T, channels, state]: the Phi-4-mini-flash cell's mixer, and a
+# smaller one in two batch rows whose channels are one block
+SCAN1_CALLS = [(1, 16384, 5120, 16), (2, 512, 384, 16)]
+
+
+@pytest.mark.parametrize(
+    "b,t,ch,n", SCAN1_CALLS,
+    ids=["x".join(map(str, call)) for call in SCAN1_CALLS])
+def test_mamba1_scan_kernels_fwd_bwd(v5e, b, t, ch, n):
+    """Forward and backward of `ops/ssm.selective_scan_pallas` alone for
+    the v5e compiler: one `selective_scan_fwd` and one `selective_scan_bwd`
+    call, x, dt and dy reaching them in the `[B, T, C]` layout they arrive
+    in, `y + D x` and dx leaving in the compute dtype, the entering states
+    `[T/Q, N, C]` float32 the only residual the forward writes, and
+    `scan1_shape_ok` said yes to what compiled."""
+    import re
+
+    from ray_tpu.ops.ssm import (SCAN1_STEPS, scan1_shape_ok,
+                                 selective_scan_pallas)
+
+    assert scan1_shape_ok(t, ch, n, 128)
+    chip = SingleDeviceSharding(v5e)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(x, dt, a, bm, cm, d):
+        return selective_scan_pallas(x, dt, a, bm, cm, d, 128).astype(
+            jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        arg((b, t, ch), jnp.bfloat16), arg((b, t, ch), jnp.float32),
+        arg((ch, n), jnp.float32), arg((b, t, n), jnp.float32),
+        arg((b, t, n), jnp.float32), arg((ch,), jnp.float32)
+    ).compile().as_text()
+    calls = {re.search(r"selective_scan_(fwd|bwd)", name).group(0):
+             (out, operands) for name, out, operands in re.findall(
+                 r'%([\w.\-]+) = (\([^\n]*?\)) custom-call\(([^\n]*?)\), '
+                 r'custom_call_target="tpu_custom_call"', hlo)}
+    assert sorted(calls) == ["selective_scan_bwd", "selective_scan_fwd"], \
+        sorted(calls)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    fwd_out, fwd_in = calls["selective_scan_fwd"]
+    bwd_out, bwd_in = calls["selective_scan_bwd"]
+    assert f"bf16[{b},{t},{ch}]" in fwd_out
+    assert f"f32[{b},{t // SCAN1_STEPS},{n},{ch}]" in fwd_out
+    assert f"bf16[{b},{t},{ch}]" in bwd_out and f"f32[{b},{t},{ch}]" in bwd_out
+    # x and dt as the program's arguments hold them (small ones the
+    # compiler prefetches, a `copy-start` / `copy-done`): no other layout
+    ours = r"%(x\.1|copy-done[.\d]*), %(dt\.1|copy-done[.\d]*), "
+    assert re.match(ours, fwd_in) and re.match(ours, bwd_in), (fwd_in, bwd_in)
+    assert not re.search(rf"\[{b},{t},{ch}\]\S* (copy|transpose)\(", hlo)
+    # no state of every step anywhere: nothing has T, N and C at once
+    assert not re.search(rf"\[({b},)?{t},({n},{ch}|{ch},{n})\]", hlo)
+
+
 def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     """Two `ME` blocks and one `M*E` block of Nemotron-3-Super's widths as
     one chip holds them (32 mixer heads of 64 in 2 groups of state 128, 8
@@ -628,15 +685,20 @@ def test_sambay_step_compiles_and_fits_the_v5e(v5e):
     `train_phi4miniflash_d6`) as one train step of 16,384 tokens for the
     v5e: splash's kernels once forward and once backward for each of the
     three attention layers, under `attention/window`, `attention/full` and
-    `attention/cross`; the Mamba-1 scans under `ssm/scan` as plain XLA
-    loops; and the compiler's memory report under the 15.75 GB the
-    runtime gives a program."""
+    `attention/cross`; the Mamba-1 scans under `ssm/scan` as their pallas
+    kernels (`ops/ssm.selective_scan_impl` says "pallas" for this mesh:
+    each mixer's forward, remat's forward and the backward, the state
+    never in HBM but for the `[T/Q, N, C]` entering states); and the
+    compiler's memory report no higher than it was with the scans as
+    plain XLA loops, 13.98 GB, under the 15.75 GB the runtime gives a
+    program."""
     import re
 
     import optax
 
     from ray_tpu.models import Transformer
     from ray_tpu.models.configs import TransformerConfig
+    from ray_tpu.ops.ssm import SCAN1_STEPS, selective_scan_impl
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.train_step import make_train_step
 
@@ -652,6 +714,8 @@ def test_sambay_step_compiles_and_fits_the_v5e(v5e):
     assert cfg.num_params == 697_094_272
     mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
     assert Transformer.resolve_attention_impl(cfg, mesh, seq) == "flash"
+    assert selective_scan_impl(mesh, seq, 5120, 16, 1024) == "pallas"
+    assert selective_scan_impl(None, seq, 5120, 16, 1024) == "xla"  # the CPU
     optimizer = optax.adamw(3e-4, weight_decay=0.01)
     _, train_step = make_train_step(
         lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh),
@@ -670,7 +734,7 @@ def test_sambay_step_compiles_and_fits_the_v5e(v5e):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes
              + ma.generated_code_size_in_bytes)
-    assert 12e9 < total < 15.75e9, total
+    assert 12e9 < total <= 13.98e9, total
     hlo = re.sub(r"kernel_metadata=\{\n[^\n]*\n\}", "kernel_metadata={}",
                  compiled.as_text())
     kernels = re.findall(
@@ -681,9 +745,26 @@ def test_sambay_step_compiles_and_fits_the_v5e(v5e):
         assert sorted(re.sub(r"\.\d+$", "", n) for n in mine) == [
             "splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"], \
             (kind, kernels)
-    assert len(kernels) == 6
-    # remat keeps the forward kernels' outputs: none runs again
-    assert not [op for _, op in kernels if "rematted_computation" in op]
+    # each mixer's scan forward, in remat's forward and backward
+    scans = [(re.sub(r"\.\d+$", "", n), op) for n, op in kernels
+             if "ssm/scan" in op]
+    assert sorted(n for n, _ in scans) == \
+        ["selective_scan_bwd"] * 2 + ["selective_scan_fwd"] * 4, kernels
+    assert sum("rematted_computation" in op for _, op in scans) == 2
+    assert len(kernels) == 12
+    # remat keeps the attention kernels' outputs: none runs again
+    assert not [op for n, op in kernels
+                if "rematted_computation" in op and "splash" in n]
+    # under `ssm/scan` the state is in HBM only as it enters a time block:
+    # nothing state-shaped beyond `[T/Q, N, C]`, nothing above `[T, C]`
+    for line in hlo.splitlines():
+        if not re.search(r'op_name="[^"]*[/(]ssm/scan[/)"]', line):
+            continue
+        for dims in re.findall(r"\b(?:f32|bf16|s32)\[([\d,]+)\]", line):
+            dims = [int(v) for v in dims.split(",")]
+            assert np.prod(dims) <= seq * 5120, line[:300]
+            if dims[-2:] == [16, 5120]:
+                assert np.prod(dims[:-2]) <= seq // SCAN1_STEPS, line[:300]
     for scope in ("ssm/scan", "ssm/x_proj", "ssm/gate", "gmu/gate",
                   "attention/diff", "mlp/gate_up", "head"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)"]', hlo), scope
