@@ -1,4 +1,7 @@
-"""TPU accelerator manager: chip discovery, visibility, pod-slice resources.
+"""TPU accelerator manager: chip discovery, visibility, release, pod-slice
+resources. The one module that spells a host's chip device nodes, whether
+they can be opened right now, and the env contract that makes a subset of
+them visible to a process.
 
 reference parity: python/ray/_private/accelerators/tpu.py:75-398
 (TPUAcceleratorManager) — chip detection via /dev/accel* or /dev/vfio
@@ -11,10 +14,12 @@ used for multi-host SPMD gang targeting (tpu.py:335-398).
 
 from __future__ import annotations
 
+import errno
 import glob
 import logging
 import os
-from typing import Dict, List, Optional, Tuple
+import time
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 from ray_tpu._private.accelerators.accelerator import AcceleratorManager
 
@@ -31,7 +36,37 @@ TPU_FAKE_CHIPS_ENV = "RAY_TPU_FAKE_NUM_CHIPS"
 TPU_FAKE_POD_TYPE_ENV = "RAY_TPU_FAKE_POD_TYPE"
 TPU_FAKE_WORKER_ID_ENV = "RAY_TPU_FAKE_WORKER_ID"
 
-_CHIPS_PER_HOST_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+# Sub-host slices need their bounds spelled; four chips are the whole host,
+# which libtpu finds by itself.
+_CHIPS_PER_HOST_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+
+_ACCEL_GLOB = "/dev/accel*"
+_VFIO_DIR = "/dev/vfio"
+
+# How long either end waits for chips a dead holder has not let go yet
+# (the four chips of a v5e host took some 25 s), and how often it asks.
+# `NodeManager.shutdown()` waits so before it returns the chips it granted,
+# a train worker before it starts its TPU backend: the same question, the
+# same patience.
+_CHIP_WAIT_BOUND_S = 60.0
+_CHIP_WAIT_POLL_S = 0.25
+
+
+def _chip_device_nodes() -> List[str]:
+    """The host's chip device nodes in chip order: /dev/accel* (PCIe),
+    else one VFIO group /dev/vfio/<n> a chip (reference tpu.py:110-117).
+    A listing that fails reads as no chips, and a faked count has no node
+    behind it."""
+    if TPU_FAKE_CHIPS_ENV in os.environ:
+        return []
+    nodes = glob.glob(_ACCEL_GLOB)
+    if not nodes:
+        try:
+            nodes = [os.path.join(_VFIO_DIR, e)
+                     for e in os.listdir(_VFIO_DIR) if e != "vfio"]
+        except OSError:
+            return []
+    return sorted(nodes, key=lambda n: (len(n), n))     # 2 before 10
 
 
 class TPUAcceleratorManager(AcceleratorManager):
@@ -48,15 +83,49 @@ class TPUAcceleratorManager(AcceleratorManager):
         fake = os.environ.get(TPU_FAKE_CHIPS_ENV)
         if fake is not None:
             return int(fake)
-        # reference tpu.py:110-117: count /dev/accel* (PCIe) or vfio devices.
-        accel = glob.glob("/dev/accel*")
-        if accel:
-            return len(accel)
-        try:
-            vfio = [e for e in os.listdir("/dev/vfio") if e != "vfio"]
-            return len(vfio)
-        except FileNotFoundError:
-            return 0
+        return len(_chip_device_nodes())
+
+    @staticmethod
+    def get_busy_chip_nodes(
+            ids: Optional[Sequence[Union[int, str]]] = None) -> List[str]:
+        """The VFIO groups, of all the host's chips or of the chips `ids`,
+        that cannot be opened right now. A group opens for one process at
+        a time, and the kernel goes on unpinning a dead holder's memory
+        for a while after it died, so whoever hands chips on and whoever
+        is about to open them asks this first. `/dev/vfio/<n>` is the
+        layout the v5e hosts have and the only one the busy state was seen
+        on: nothing is asked of `/dev/accel*` hosts. EBUSY alone means
+        busy; any other error is libtpu's to report and reads as free."""
+        nodes = [n for n in _chip_device_nodes()
+                 if os.path.dirname(n) == _VFIO_DIR]
+        if ids is not None:
+            # an id with no node behind it is libtpu's to refuse
+            nodes = [nodes[int(i)] for i in ids
+                     if str(i).isdigit() and int(i) < len(nodes)]
+        busy = []
+        for node in nodes:
+            try:
+                os.close(os.open(node, os.O_RDWR))
+            except OSError as e:
+                if e.errno == errno.EBUSY:
+                    busy.append(node)
+        return busy
+
+    @staticmethod
+    def wait_for_chips(ids: Optional[Sequence[Union[int, str]]] = None
+                       ) -> Tuple[float, List[str]]:
+        """Poll until none of those chips is busy or the bound has passed.
+        Returns the seconds waited (0.0 where the first answer was "none")
+        and the nodes still busy at the bound; what to say about either is
+        the caller's."""
+        busy = TPUAcceleratorManager.get_busy_chip_nodes(ids)
+        if not busy:
+            return 0.0, busy
+        started = time.monotonic()
+        while busy and time.monotonic() - started < _CHIP_WAIT_BOUND_S:
+            time.sleep(_CHIP_WAIT_POLL_S)
+            busy = TPUAcceleratorManager.get_busy_chip_nodes(ids)
+        return time.monotonic() - started, busy
 
     @staticmethod
     def get_current_node_accelerator_type() -> Optional[str]:
@@ -129,12 +198,17 @@ class TPUAcceleratorManager(AcceleratorManager):
         return [s for s in v.split(",") if s]
 
     @staticmethod
+    def get_visibility_env(ids: Sequence[Union[int, str]]) -> Dict[str, str]:
+        """The env that shows a process these chips of its host and no
+        others: the chip ids, and for a sub-host slice the topology bounds
+        libtpu needs to carve it (reference tpu.py:157-196)."""
+        env = {TPU_VISIBLE_CHIPS_ENV: ",".join(str(i) for i in ids)}
+        bounds = _CHIPS_PER_HOST_BOUNDS.get(len(ids))
+        if bounds is not None:
+            env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = bounds
+            env[TPU_HOST_BOUNDS_ENV] = TPU_SINGLE_HOST_BOUNDS
+        return env
+
+    @staticmethod
     def set_current_process_visible_accelerator_ids(ids: List[str]) -> None:
-        """Set chip visibility + topology bounds env for subprocesses
-        (reference tpu.py:157-196: libtpu needs the host/chip bounds to
-        carve a sub-host topology)."""
-        os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
-        n = len(ids)
-        if n in _CHIPS_PER_HOST_BOUNDS and n != 4:
-            os.environ[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = _CHIPS_PER_HOST_BOUNDS[n]
-            os.environ[TPU_HOST_BOUNDS_ENV] = TPU_SINGLE_HOST_BOUNDS
+        os.environ.update(TPUAcceleratorManager.get_visibility_env(ids))

@@ -107,6 +107,11 @@ class NodeManager:
         self._gcs = rpc_lib.RpcClient(self.gcs_address, timeout=60)
         self._lock = TracedLock("node_manager")
         self._dead = False
+        # set once `TPU` left `available` for a lease, an actor or a
+        # placement group's bundle (a train worker asks for nothing
+        # itself and sits in the bundle that holds its chips): only then
+        # are the host's chips this node's to wait for
+        self._granted_chips = False
 
         if resources is None:
             resources = {}
@@ -137,6 +142,8 @@ class NodeManager:
 
             def subtract(rs, other):  # noqa: N805
                 ResourceSet.subtract(rs, other)
+                if other.get("TPU"):
+                    rs._nm._granted_chips = True
                 rs._nm._resync_event.set()
 
         self.available = _SyncedResources(resources)
@@ -1702,6 +1709,29 @@ class NodeManager:
     def drain(self) -> None:
         self.shutdown()
 
+    def _await_chip_release(self) -> None:
+        """Return once the chips this node's workers held can be opened
+        again, or the bound has passed: the kernel goes on unpinning a
+        dead worker's chips after the process is gone, and a job that
+        starts meanwhile waits for them in its TPU start. The process
+        that held the chips owns their release, so the wait lies here,
+        after the job. Another tenant's chips on the same host are not
+        waited for: a node that never granted `TPU` does not ask."""
+        if not self._granted_chips:
+            return
+        from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
+        waited, busy = TPUAcceleratorManager.wait_for_chips()
+        if busy:
+            logger.warning(
+                "shutdown: %s still busy %.1f s after the workers died; "
+                "a job that starts on this host now waits for them in its "
+                "TPU start", ", ".join(busy), waited)
+        elif waited:
+            logger.info(
+                "shutdown: waited %.1f s for the host's %d chips to be "
+                "released", waited,
+                TPUAcceleratorManager.get_current_node_num_accelerators())
+
     def shutdown(self) -> None:
         if self._dead:
             return
@@ -1733,6 +1763,14 @@ class NodeManager:
                     handle.proc.wait(timeout=5)
                 except subprocess.TimeoutExpired:
                     handle.proc.kill()
+                    # the monitor thread reaps a worker only if this
+                    # process lives that long, and an unreaped worker
+                    # still holds what it had open
+                    try:
+                        handle.proc.wait(timeout=5)
+                    except subprocess.TimeoutExpired:
+                        pass    # exiting in the kernel; its chips: below
+        self._await_chip_release()
         try:
             self._gcs.call("unregister_node", node_id_hex=self.node_id.hex())
         except Exception:  # noqa: BLE001 - GCS gone; health check expires us

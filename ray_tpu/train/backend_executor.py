@@ -200,28 +200,17 @@ class BackendExecutor:
     def _share_tpu_visibility(self, wg: WorkerGroup) -> None:
         """Split the node's TPU chips among co-located workers
         (reference backend_executor.py:258 shares CUDA_VISIBLE_DEVICES;
-        TPU env contract per _private/accelerators/tpu.py:157-196)."""
-        from ray_tpu._private.accelerators.tpu import (
-            TPU_CHIPS_PER_HOST_BOUNDS_ENV, TPU_HOST_BOUNDS_ENV,
-            TPU_SINGLE_HOST_BOUNDS, TPU_VISIBLE_CHIPS_ENV)
+        the env contract is the accelerator module's)."""
+        from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
 
         per_worker = int(self._scaling.num_tpus_per_worker)
         env_per_worker: List[Dict[str, str]] = []
         next_chip: Dict[str, int] = defaultdict(int)
-        for ctx, node_id in zip(self._contexts, wg.node_ids):
+        for node_id in wg.node_ids:
             start = next_chip[node_id]
-            chips = list(range(start, start + per_worker))
             next_chip[node_id] += per_worker
-            env = {TPU_VISIBLE_CHIPS_ENV:
-                   ",".join(str(c) for c in chips)}
-            # sub-host slicing bounds (1/2/4-chip topologies)
-            if per_worker == 1:
-                env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = "1,1,1"
-                env[TPU_HOST_BOUNDS_ENV] = TPU_SINGLE_HOST_BOUNDS
-            elif per_worker == 2:
-                env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = "1,2,1"
-                env[TPU_HOST_BOUNDS_ENV] = TPU_SINGLE_HOST_BOUNDS
-            env_per_worker.append(env)
+            env_per_worker.append(TPUAcceleratorManager.get_visibility_env(
+                range(start, start + per_worker)))
         wg.setup_env(env_per_worker)
 
     # ---- training ---------------------------------------------------

@@ -72,8 +72,9 @@ def _init_jax_distributed(coordinator_address: str, num_processes: int,
 
 def _setup_worker(num_tpus: int) -> None:
     """Last step of gang set-up on every worker, before the train loop
-    jits anything: place the compile cache, and hold the worker to the
-    chips its ScalingConfig asked for. TPU visibility env is applied to
+    jits anything: place the compile cache, wait (bounded) for chips a
+    predecessor still holds, and hold the worker to the chips its
+    ScalingConfig asked for. TPU visibility env is applied to
     a live process, so a worker whose JAX was already pinned elsewhere
     (a reused pool worker, a missing libtpu) would otherwise train on
     the CPU and nobody would notice."""
@@ -82,6 +83,19 @@ def _setup_worker(num_tpus: int) -> None:
     if not num_tpus:
         return
     import jax
+    from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
+    # A predecessor that could not give its chips back (SIGKILL, a gang
+    # killed inside a live cluster) leaves them busy for a while, and a
+    # TPU backend that failed once stays failed in this process. Asked as
+    # late as the first touch of the backend allows: the time spent
+    # getting here counts towards the release. At the bound libtpu speaks.
+    waited, busy = TPUAcceleratorManager.wait_for_chips(
+        TPUAcceleratorManager.get_current_process_visible_accelerator_ids())
+    if waited:
+        logger.warning(
+            "waited %.1f s for this worker's %d chips, which a "
+            "predecessor had not let go%s", waited, num_tpus,
+            f"; still busy: {', '.join(busy)}" if busy else "")
     local = jax.local_devices()
     if len(local) != num_tpus or \
             any(d.platform != "tpu" for d in local):

@@ -117,7 +117,8 @@ class WorkerGroup:
                     f"placement group for {num_workers} x "
                     f"{resources_per_worker} not schedulable within 120s; "
                     f"the cluster offers {ray_tpu.cluster_resources()} "
-                    f"(TPU counts come from /dev/accel* or /dev/vfio/*)")
+                    f"(a node's TPU count is the number of its chip "
+                    f"device nodes: _private/accelerators/tpu.py)")
             self._pg = pg
             self._pgs = [pg]
             bundle_slots = [(pg, i) for i in range(num_workers)]
