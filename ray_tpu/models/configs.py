@@ -15,7 +15,13 @@ alone; its experts may live in a latent (`moe_latent`) and be plain
 lower-case kinds of `layer_pattern`) is a sequence of layers each a
 Mamba-1 mixer, differential attention (windowed, full or cross) or a gated
 memory unit FOLLOWED by a dense MLP, whose later layers read one earlier
-mixer's scan output and one earlier attention layer's keys and values.
+mixer's scan output and one earlier attention layer's keys and values. A
+linear-attention hybrid (the kinds `k`, `K`, `l`, `L`) is a sequence of
+layers each Kimi Delta Attention (ops/kda.py) or softmax attention
+(latent where `kv_lora_rank` says so, its queries projected directly
+where `q_lora_rank` is 0), FOLLOWED by a dense MLP (lower case) or by
+experts (upper case), whose router may limit a token's choice to some
+groups of experts (`moe_groups`, `moe_topk_groups`).
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -121,6 +127,10 @@ class TransformerConfig:
     # head is qk_nope_head_dim columns without position plus
     # qk_rope_head_dim rotary columns, the rotary key head shared by all
     # heads; values are v_head_dim wide. n_kv_heads is n_heads.
+    # q_lora_rank 0: no query latent, the queries are one projection of
+    # the stream. With qk_norm each query head and each key head
+    # ([k_nope | k_rope]) is RMS-normed over its own head_dim with one
+    # gain a side, before RoPE.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -184,6 +194,27 @@ class TransformerConfig:
     # "rms": RMSNorm with a gain; "layernorm": LayerNorm with gain and
     # bias (`<name>_bias` beside every norm's gain, the final one too)
     norm: str = "rms"
+    # Four more kinds of `layer_pattern`, each a sublayer FOLLOWED by a
+    # dense gated MLP of width moe_dense_ff or d_ff (lower case) or by the
+    # experts (upper case: the `moe_*` sizes, experts of width d_ff): `k`
+    # and `K` Kimi Delta Attention (ops/kda.py: kda_heads heads of
+    # kda_head_dim, keys and values alike, three depthwise causal
+    # convolutions of kda_conv_kernel taps, a decay per channel bounded
+    # below by kda_gate_lower a step, the delta rule in chunks of
+    # kda_chunk); `l` and `L` softmax attention as the other sizes say
+    # (latent with kv_lora_rank). A leading dense layer is a lower-case
+    # character, not `moe_dense_layers`.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_kernel: int = 4
+    kda_chunk: int = 64
+    kda_gate_lower: float = -5.0
+    # group-limited routing (`n_group`, `topk_group`; the sigmoid router):
+    # the experts in moe_groups equal groups, a group ranked by the sum
+    # of its two largest scores (with the choice bias), a token's top-k
+    # taken among the experts of its moe_topk_groups best groups. 1: none
+    moe_groups: int = 1
+    moe_topk_groups: int = 1
 
     def __post_init__(self):
         if self.norm not in ("rms", "layernorm"):
@@ -192,38 +223,56 @@ class TransformerConfig:
             raise ValueError("LayerNorm's biases are a layer_pattern's "
                              "sublayers': the homogeneous layer has none")
         if self.layer_pattern:
-            unknown = set(self.layer_pattern) - set("ME*" + FFN_KINDS)
+            unknown = set(self.layer_pattern) - set("M*" + EXPERT_KINDS
+                                                    + FFN_KINDS)
             if unknown or len(self.layer_pattern) != self.n_layers:
                 raise ValueError(
                     f"layer_pattern {self.layer_pattern!r}: {self.n_layers} "
-                    f"characters of M, E, *, {', '.join(FFN_KINDS)} "
-                    f"(got {sorted(unknown)})")
+                    f"characters of M, *, {', '.join(EXPERT_KINDS)}, "
+                    f"{', '.join(FFN_KINDS)} (got {sorted(unknown)})")
             self._check_shared_tensors()
             if "M" in self.layer_pattern and (
                     not self.ssm_heads or self.ssm_heads % self.ssm_groups):
                 raise ValueError("a mixer needs ssm_heads, a multiple of "
                                  "ssm_groups")
-            if ("E" in self.layer_pattern) != bool(self.moe_experts) \
-                    or self.moe_dense_layers or self.kv_lora_rank:
+            if bool(set(EXPERT_KINDS) & set(self.layer_pattern)) \
+                    != bool(self.moe_experts) or self.moe_dense_layers \
+                    or (self.kv_lora_rank
+                        and set("*wfc") & set(self.layer_pattern)):
                 raise ValueError("a layer_pattern has expert layers where "
-                                 "it says E, no leading dense run and no "
-                                 "latent attention")
+                                 "it says E, K or L, no leading dense run "
+                                 "(moe_dense_layers) and latent attention "
+                                 "in the kinds l and L only")
+            if set("kK") & set(self.layer_pattern) and not self.kda_heads:
+                raise ValueError("Kimi Delta Attention (k, K) needs "
+                                 "kda_heads")
         if self.moe_act not in ("silu", "relu2"):
             raise ValueError(f"unknown moe_act {self.moe_act!r}")
         if self.kv_lora_rank:
-            if not (self.q_lora_rank and self.qk_rope_head_dim
-                    and self.v_head_dim) or self.qk_rope_head_dim % 2:
+            if not (self.qk_rope_head_dim and self.v_head_dim) \
+                    or self.qk_rope_head_dim % 2:
                 raise ValueError(
-                    "latent attention needs q_lora_rank, an even "
-                    "qk_rope_head_dim and v_head_dim")
-            if self.kv_heads != self.n_heads or self.qk_norm:
+                    "latent attention needs an even qk_rope_head_dim and "
+                    "v_head_dim")
+            if self.kv_heads != self.n_heads:
                 raise ValueError("latent attention has one key/value head "
-                                 "per query head and no QK-norm")
+                                 "per query head")
         elif not self.attn_head_dim and self.d_model % self.n_heads:
             raise ValueError(f"d_model {self.d_model} % n_heads "
                              f"{self.n_heads} != 0")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
+        if self.moe_groups > 1 and (
+                self.moe_scoring != "sigmoid"
+                or self.moe_experts % self.moe_groups
+                or not 1 <= self.moe_topk_groups <= self.moe_groups
+                or self.moe_experts // self.moe_groups < 2
+                or self.moe_topk_groups * self.moe_experts
+                // self.moe_groups < self.moe_top_k):
+            raise ValueError(
+                "group-limited routing is the sigmoid router's: moe_experts "
+                "in moe_groups equal groups of two experts or more, "
+                "moe_topk_groups of them holding moe_top_k experts")
         if self.moe_experts:
             first, held = self.moe_expert_offset, self.held_experts
             if first < 0 or first + held > self.moe_experts:
@@ -335,14 +384,14 @@ class TransformerConfig:
         hd, nh, nkv = self.head_dim, self.n_heads, self.kv_heads
         if self.kv_lora_rank:   # down, latent norm, up for q and for kv
             qr, kvr = self.q_lora_rank, self.kv_lora_rank
-            attn = (d * qr + qr + qr * nh * hd
+            attn = ((d * qr + qr + qr * nh * hd if qr else d * nh * hd)
                     + d * (kvr + self.rope_dim) + kvr
                     + kvr * nh * (self.qk_nope_head_dim + self.v_dim)
                     + nh * self.v_dim * d)
         else:
             attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
-        if self.qk_norm:
-            attn += nh * hd + nkv * hd
+        if self.qk_norm:   # latent: one gain a side over a head
+            attn += 2 * hd if self.kv_lora_rank else nh * hd + nkv * hd
         if self.layer_pattern:
             return self._pattern_params(attn)
         norms = 2 * d
@@ -378,9 +427,21 @@ class TransformerConfig:
         if set(FFN_KINDS) & set(self.layer_pattern):
             each.update(self._ffn_kind_params(attn))
         norm = d * self._norm_leaves
+        # a sublayer, its second norm and the experts
+        each.update(K=self._kda_params + norm + expert,
+                    L=attn + norm + expert)
         layers = sum(each[c] + norm for c in self.layer_pattern)
         head = 0 if self.tie_embeddings else d * v
         return v * d + layers + norm + head
+
+    @property
+    def _kda_params(self) -> int:
+        """A Kimi Delta Attention sublayer without its norm: q, k, v and
+        their convolutions, the decay's projection with A and its bias,
+        beta's and the output gate's, the head norm's gain, W_o."""
+        d, h, hd = self.d_model, self.kda_heads, self.kda_head_dim
+        return (3 * d * h * hd + 3 * h * hd * self.kda_conv_kernel
+                + d * h * hd + h + h * hd + 2 * d * h + hd + h * hd * d)
 
     @property
     def _norm_leaves(self) -> int:
@@ -405,16 +466,20 @@ class TransformerConfig:
         diff = 4 * hd + 2 * hd if self.diff_attention else 0
         kv = 2 * d * self.kv_heads * hd + (
             2 * self.kv_heads * hd if self.attn_bias else 0)
-        rest = self._norm_leaves * d + 3 * d * self.ff_dim
+        rest = self._norm_leaves * d \
+            + 3 * d * (self.moe_dense_ff or self.ff_dim)
         each = {"m": mixer, "s": mixer, "w": attn + bias + diff,
                 "f": attn + bias + diff, "g": 2 * d * inner,
-                "c": attn + bias + diff - kv}
+                "c": attn + bias + diff - kv, "k": self._kda_params,
+                "l": attn}
         return {kind: count + rest for kind, count in each.items()}
 
 
 # the kinds of `layer_pattern` that are followed by a dense MLP in the same
-# layer (TransformerConfig: `m`, `s`, `w`, `f`, `g`, `c`)
-FFN_KINDS = "msfwgc"
+# layer (TransformerConfig: `m`, `s`, `w`, `f`, `g`, `c`, `k`, `l`), and
+# those that are or end in an expert layer (`E` alone, `K`, `L`)
+FFN_KINDS = "msfwgckl"
+EXPERT_KINDS = "EKL"
 
 
 def pattern_runs(pattern: str):

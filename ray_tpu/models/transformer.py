@@ -16,9 +16,10 @@ expert layer ALONE, `x + f(norm(x))` with one norm) is the runs of
 `cfg.pattern_runs`, `params["runs"]`: each run a block, a list of unlike
 sublayers, its leaves stacked over the block's repeats and scanned as one
 body. A sublayer too is what its leaves say: `attn_norm` brings
-attention, `ssm_norm` a mixer, `gmu_norm` a gated memory unit, `mlp_norm`
-an FFN or experts, and the homogeneous layer is the one with both the
-first and the last; `w_x` makes the mixer Mamba-1, `lambda_q1` makes
+attention, `ssm_norm` a mixer, `kda_norm` Kimi Delta Attention, `gmu_norm`
+a gated memory unit, `mlp_norm` an FFN or experts (`w_router` makes it
+experts), and the homogeneous layer is the one with both the first and
+the last; `w_x` makes the mixer Mamba-1, `lambda_q1` makes
 attention differential, no `wkv` makes it cross-attention, a
 `<norm>_bias` makes the norm a LayerNorm. What the leaves cannot say the
 layer's kind in `layer_pattern` does: a window, and which layer's
@@ -31,7 +32,8 @@ operator's `ray_tpu profile --device` read them off each op's op_name):
 `embed`, `layers` (the scan's own stacking, slicing and carries),
 `attn_norm`, `qkv` (projections, QK-norm and RoPE; with latent attention
 `qkv/q_down`, `qkv/kv_down`, `qkv/q_up`, `qkv/kv_up`, `qkv/assemble`
-inside it), `attention` (kernels, GQA repeat, layout transposes),
+inside it, `qkv/q_proj` in place of the first and third where the queries
+have no latent), `attention` (kernels, GQA repeat, layout transposes),
 `attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (differential
 attention: `attention/window`, `attention/full` or `attention/cross`
 around the kernel calls, `attention/diff` around lambda, the subtraction,
@@ -41,7 +43,9 @@ the pair norm and the scale; in an expert layer
 `ssm/conv`, `ssm/scan`, `ssm/gate_norm`, `ssm/out_proj` (ops/ssm.py; a
 Mamba-1 mixer `ssm/x_proj` and `ssm/gate` and no `ssm/gate_norm`), in a
 gated memory unit `gmu_norm` and `gmu/in_proj`, `gmu/gate`,
-`gmu/out_proj`, `final_norm`, `head`, `loss` (the vocab head and the
+`gmu/out_proj`, in Kimi Delta Attention `kda_norm` and `kda/qkv_proj`,
+`kda/conv`, `kda/gates`, `kda/delta`, `kda/out_norm`, `kda/out_proj`
+(ops/kda.py), `final_norm`, `head`, `loss` (the vocab head and the
 cross-entropy: models/head.py); the train step adds `optimizer`
 (parallel/train_step.py). Scopes are metadata only. Forward, backward
 and recomputation need none: JAX wraps the path in `jvp(...)`,
@@ -58,7 +62,8 @@ import functools
 from typing import Any, Dict, Optional
 
 from ray_tpu.models import head
-from ray_tpu.models.configs import FFN_KINDS, TransformerConfig
+from ray_tpu.models.configs import (EXPERT_KINDS, FFN_KINDS,
+                                       TransformerConfig)
 from ray_tpu.parallel.mesh import AXIS_SEQ
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
 
@@ -116,7 +121,7 @@ def _norm(x, leaves, name, eps):
 
 
 # the norms that open a sublayer, by their gain's leaf
-NORMS = ("attn_norm", "ssm_norm", "gmu_norm", "mlp_norm")
+NORMS = ("attn_norm", "ssm_norm", "kda_norm", "gmu_norm", "mlp_norm")
 # the tensors of `_stack`'s `shared` each kind of layer makes
 MAKES = {"s": ("memory",), "f": ("k", "v")}
 
@@ -152,10 +157,15 @@ class Transformer:
                 # latent, up-projections to the heads ([q_nope ; q_rope]
                 # and [k_nope ; v] per head)
                 qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
-                layers["wq_a"] = norm_init(d ** -0.5, keys[1], (l, d, qr))
-                layers["q_a_norm"] = jnp.ones((l, qr), pdt)
-                layers["wq_b"] = norm_init(qr ** -0.5, keys[2],
-                                           (l, qr, nh, hd))
+                if qr:
+                    layers["wq_a"] = norm_init(d ** -0.5, keys[1],
+                                               (l, d, qr))
+                    layers["q_a_norm"] = jnp.ones((l, qr), pdt)
+                    layers["wq_b"] = norm_init(qr ** -0.5, keys[2],
+                                               (l, qr, nh, hd))
+                else:   # no query latent: one projection of the stream
+                    layers["wq"] = norm_init(d ** -0.5, keys[1],
+                                             (l, d, nh, hd))
                 layers["wkv_a"] = norm_init(d ** -0.5, keys[3],
                                             (l, d, kvr + cfg.rope_dim))
                 layers["kv_a_norm"] = jnp.ones((l, kvr), pdt)
@@ -179,7 +189,11 @@ class Transformer:
                     [norm_init(d ** -0.5, keys[2], (l, d, nkv, hd)),
                      norm_init(d ** -0.5, keys[3], (l, d, nkv, hd))],
                     axis=2)  # (l, d, 2, nkv, hd)
-            if cfg.qk_norm:
+            if cfg.qk_norm and cfg.kv_lora_rank:
+                # latent attention's: one gain a side over a head
+                layers["q_norm"] = jnp.ones((l, hd), pdt)
+                layers["k_norm"] = jnp.ones((l, hd), pdt)
+            elif cfg.qk_norm:
                 # RMSNorm gains over the whole q / k projection (all heads
                 # together), applied before the split into heads and RoPE
                 layers["q_norm"] = jnp.ones((l, nh * hd), pdt)
@@ -299,10 +313,36 @@ class Transformer:
                 "w_out": norm_init(inner ** -0.5, ks[2], (l, inner, d)),
             }
 
+        def kda(l, key):
+            """One run of l Kimi Delta Attention sublayers' leaves
+            (ops/kda.py): A_log uniform in log [1, 16] as a Mamba-2
+            mixer's, the decay's bias zero, the convolutions uniform within
+            1/sqrt(taps)."""
+            ks = jax.random.split(key, 7)
+            h_, hd_, taps = cfg.kda_heads, cfg.kda_head_dim, \
+                cfg.kda_conv_kernel
+            bound = taps ** -0.5
+            return {
+                "kda_norm": jnp.ones((l, d), pdt),
+                "w_kda_qkv": norm_init(d ** -0.5, ks[0], (l, d, 3, h_, hd_)),
+                "kda_conv": jax.random.uniform(
+                    ks[1], (l, 3, h_ * hd_, taps), jnp.float32, -bound,
+                    bound).astype(pdt),
+                "w_kda_a": norm_init(d ** -0.5, ks[2], (l, d, h_, hd_)),
+                "kda_A_log": jnp.log(jax.random.uniform(
+                    ks[3], (l, h_), jnp.float32, 1.0, 16.0)).astype(pdt),
+                "kda_a_bias": jnp.zeros((l, h_, hd_), pdt),
+                "w_kda_bg": norm_init(d ** -0.5, ks[4], (l, d, 2, h_)),
+                "kda_out_norm": jnp.ones((l, hd_), pdt),
+                "w_kda_out": norm_init((h_ * hd_) ** -0.5, ks[5],
+                                       (l, h_, hd_, d)),
+            }
+
         def with_mlp(sub, l, keys):
             """A lower-case kind: the sublayer, then a dense MLP."""
             sub["mlp_norm"] = jnp.ones((l, d), pdt)
-            sub["w_gateup"], sub["w_down"] = gated(keys, (l,), f)
+            sub["w_gateup"], sub["w_down"] = gated(
+                keys, (l,), cfg.moe_dense_ff or f)
             return sub
 
         def sublayer(kind, l, key, places):
@@ -320,6 +360,15 @@ class Transformer:
                 if kind == "c":   # the keys and values are the layer f's
                     sub = {name: leaf for name, leaf in sub.items()
                            if name not in ("wkv", "bkv")}
+            elif kind == "k":
+                sub = with_mlp(kda(l, key), l, keys)
+            elif kind == "l":
+                sub = with_mlp(attention(l, keys), l, keys)
+            elif kind == "K":
+                sub = dict(kda(l, key), **experts(l, key, keys),
+                           mlp_norm=jnp.ones((l, d), pdt))
+            elif kind == "L":
+                sub = dict(attention(l, keys), **experts(l, key, keys))
             elif kind == "g":
                 inner = cfg.ssm_d_inner
                 sub = with_mlp({
@@ -387,9 +436,12 @@ class Transformer:
                 # the latents are narrow and every head reads all of them:
                 # down-projections shard with the model width, the
                 # up-projections by head
-                layers["wq_a"] = ("layers", "embed", None)
-                layers["q_a_norm"] = ("layers", "norm")
-                layers["wq_b"] = ("layers", None, "heads", "head_dim")
+                if cfg.q_lora_rank:
+                    layers["wq_a"] = ("layers", "embed", None)
+                    layers["q_a_norm"] = ("layers", "norm")
+                    layers["wq_b"] = ("layers", None, "heads", "head_dim")
+                else:
+                    layers["wq"] = ("layers", "embed", "heads", "head_dim")
                 layers["wkv_a"] = ("layers", "embed", None)
                 layers["kv_a_norm"] = ("layers", "norm")
                 layers["wkv_b"] = ("layers", None, "heads", "head_dim")
@@ -416,6 +468,16 @@ class Transformer:
 
         dense_ffn = {"w_gateup": ("layers", "embed", None, "mlp"),
                      "w_down": ("layers", "mlp", "embed")}
+        # Kimi Delta Attention's heads are not sharded here
+        kda = {"kda_norm": ("layers", "norm"),
+               "w_kda_qkv": ("layers", "embed", None, None, None),
+               "kda_conv": ("layers", None, None, None),
+               "w_kda_a": ("layers", "embed", None, None),
+               "kda_A_log": ("layers", None),
+               "kda_a_bias": ("layers", None, None),
+               "w_kda_bg": ("layers", "embed", None, None),
+               "kda_out_norm": ("layers", None),
+               "w_kda_out": ("layers", None, None, "embed")}
 
         def experts():
             layers = {"w_router": ("layers", "embed", None),
@@ -454,7 +516,9 @@ class Transformer:
                     sub.update(A_log=("layers", None, None),
                                w_x=("layers", None, None),
                                w_dt=("layers", None, None))
-            elif kind in "*wfc":
+            elif kind in "kK":
+                sub = dict(kda)
+            elif kind in "*wfclL":
                 sub = attention()
                 if kind == "*":
                     del sub["mlp_norm"]
@@ -466,7 +530,9 @@ class Transformer:
                        "w_gmu_in": ("layers", "embed", None),
                        "w_gmu_out": ("layers", None, "embed")}
             else:
-                sub = dict(experts(), mlp_norm=("layers", "norm"))
+                sub = {}
+            if kind in EXPERT_KINDS:
+                sub.update(experts(), mlp_norm=("layers", "norm"))
             if kind in FFN_KINDS:
                 sub.update(dense_ffn, mlp_norm=("layers", "norm"))
             if cfg.norm == "layernorm":
@@ -746,26 +812,43 @@ class Transformer:
             straight off the kv down-projection and is shared by all
             heads; RoPE touches only the rotary columns."""
             nope = cfg.qk_nope_head_dim
-            with jax.named_scope("q_down"):
-                c_q = _rmsnorm(jnp.einsum("btd,dr->btr", h,
-                                          lp["wq_a"].astype(cdt)),
-                               lp["q_a_norm"], cfg.norm_eps)
+            if "wq" in lp:   # no query latent
+                with jax.named_scope("q_proj"):
+                    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(cdt))
+            else:
+                with jax.named_scope("q_down"):
+                    c_q = _rmsnorm(jnp.einsum("btd,dr->btr", h,
+                                              lp["wq_a"].astype(cdt)),
+                                   lp["q_a_norm"], cfg.norm_eps)
             with jax.named_scope("kv_down"):
                 ckv = jnp.einsum("btd,dr->btr", h, lp["wkv_a"].astype(cdt))
                 c_kv = _rmsnorm(ckv[..., :cfg.kv_lora_rank],
                                 lp["kv_a_norm"], cfg.norm_eps)
                 k_rope = ckv[..., None, cfg.kv_lora_rank:]    # one head
-            with jax.named_scope("q_up"):
-                q = jnp.einsum("btr,rhk->bthk", c_q, lp["wq_b"].astype(cdt))
+            if "wq" not in lp:
+                with jax.named_scope("q_up"):
+                    q = jnp.einsum("btr,rhk->bthk", c_q,
+                                   lp["wq_b"].astype(cdt))
             with jax.named_scope("kv_up"):
                 kv = jnp.einsum("btr,rhk->bthk", c_kv,
                                 lp["wkv_b"].astype(cdt))
+            def roped(x):   # RoPE on a head's rotary columns
+                return jnp.concatenate(
+                    [x[..., :nope], _rope(x[..., nope:], cos, sin)], axis=-1)
+
             with jax.named_scope("assemble"):
-                q = jnp.concatenate(
-                    [q[..., :nope], _rope(q[..., nope:], cos, sin)], axis=-1)
-                k_rope = jnp.broadcast_to(
-                    _rope(k_rope, cos, sin), q.shape[:3] + (cfg.rope_dim,))
-                k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+                if "q_norm" in lp:
+                    # each head normed over its own columns, the key head
+                    # with the shared rotary columns it is given; then RoPE
+                    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                        k_rope, q.shape[:3] + (cfg.rope_dim,))], axis=-1)
+                    q = roped(_rmsnorm(q, lp["q_norm"], cfg.norm_eps))
+                    k = roped(_rmsnorm(k, lp["k_norm"], cfg.norm_eps))
+                else:
+                    q = roped(q)
+                    k_rope = jnp.broadcast_to(_rope(k_rope, cos, sin),
+                                              q.shape[:3] + (cfg.rope_dim,))
+                    k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
                 return heads_constrained(q, k, kv[..., nope:])
 
         def differential(q, k, v, lp, fn, kind):
@@ -818,7 +901,7 @@ class Transformer:
                         k, v = kv[:, :, 0], kv[:, :, 1]
                     else:   # cross-attention: the layer f's, as they are
                         k, v = shared["k"], shared["v"]
-                if cfg.qk_norm:
+                if cfg.qk_norm and not cfg.kv_lora_rank:
                     q = _rmsnorm(q.reshape(q.shape[:2] + (-1,)),
                                  lp["q_norm"], cfg.norm_eps).reshape(q.shape)
                     k = _rmsnorm(k.reshape(k.shape[:2] + (-1,)),
@@ -877,6 +960,24 @@ class Transformer:
                                  lp["w_gmu_out"].astype(cdt))
                 return x + constrain(out, ("batch", "seq", "act_embed"))
 
+        def kda(x, lp):
+            """Kimi Delta Attention (ops/kda.py)."""
+            from ray_tpu.ops.kda import kda_mixer
+
+            with jax.named_scope("kda_norm"):
+                h = _norm(x, lp, "kda_norm", cfg.norm_eps)
+            cast = dict(lp)
+            for scope, name in (("qkv_proj", "w_kda_qkv"),
+                                ("gates", "w_kda_a"), ("gates", "w_kda_bg"),
+                                ("out_proj", "w_kda_out")):
+                with jax.named_scope("kda/" + scope):
+                    cast[name] = lp[name].astype(cdt)
+            # kda_mixer names its own scopes under `kda/`
+            out = kda_mixer(h, cast, chunk=cfg.kda_chunk,
+                            lower=cfg.kda_gate_lower, eps=cfg.norm_eps)
+            with jax.named_scope("kda/out_proj"):
+                return x + constrain(out, ("batch", "seq", "act_embed"))
+
         def mixer(x, lp):
             from ray_tpu.ops.ssm import mamba2_mixer
 
@@ -924,6 +1025,7 @@ class Transformer:
                     norm_topk=cfg.moe_norm_topk, scoring=cfg.moe_scoring,
                     routed_scale=cfg.moe_routed_scale,
                     expert_offset=cfg.moe_expert_offset, act=cfg.moe_act,
+                    n_group=cfg.moe_groups, topk_group=cfg.moe_topk_groups,
                     mesh=mesh, rules=rules)
                 with jax.named_scope("moe/combine"):
                     down = y.reshape(h.shape).astype(cdt)
@@ -950,6 +1052,8 @@ class Transformer:
                     made = {"memory": y}
             elif "ssm_norm" in lp:
                 x = mixer(x, lp)
+            if "kda_norm" in lp:
+                x = kda(x, lp)
             if "gmu_norm" in lp:
                 x = gmu(x, lp, shared["memory"])
             if "mlp_norm" in lp:
@@ -1061,10 +1165,10 @@ class Transformer:
         device = mesh.devices.flat[0] if mesh is not None \
             else jax.devices()[0]
         t = cfg.max_seq_len if seq_len is None else seq_len
-        # the kernel takes one head width for q, k and v
+        # the kernel takes one width for q and k and another for v
         return "flash" if device.platform == "tpu" and \
-            cfg.v_dim == cfg.head_dim and \
-            flash_shape_ok(t, cfg.head_dim) else "dense"
+            flash_shape_ok(t, cfg.head_dim) and \
+            flash_shape_ok(t, cfg.v_dim) else "dense"
 
     @staticmethod
     def _make_attention(cfg: TransformerConfig, mesh, rules: ShardingRules,
@@ -1178,8 +1282,10 @@ class Transformer:
         slots routed to experts held elsewhere) and `moe_rows_bounded`
         (int32 [expert layers]: 1 where this step's held rows fitted
         `ops/moe.row_bound`'s run and the path past the sort ran over it,
-        0 where it ran over every row); an empty dict for a dense
-        config."""
+        0 where it ran over every row) and, under group-limited routing,
+        `moe_groups_chosen` (int32 [expert layers, groups]: the tokens
+        that kept each group; `moe_topk_groups` x tokens a layer); an
+        empty dict for a dense config."""
         import jax
         import jax.numpy as jnp
 
@@ -1212,4 +1318,6 @@ class Transformer:
         if cfg.held_experts < cfg.moe_experts:
             metrics["moe_slots_elsewhere"] = routing["slots_elsewhere"]
             metrics["moe_rows_bounded"] = routing["rows_bounded"]
+        if cfg.moe_groups > 1:
+            metrics["moe_groups_chosen"] = routing["groups_chosen"]
         return loss_val, metrics

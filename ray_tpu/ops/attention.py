@@ -95,11 +95,11 @@ def _splash_block_sizes(t: int, head_dim: int):
     """The kernels' block geometry, a function of the shape seen at trace
     time and of nothing else. Fetched blocks (q and kv, both kernels): the
     largest of 1024 / 512 / 256 / 128 that divides T, from 512 down where
-    head_dim is above 128 (a 1024-row block of 256 columns overruns the
-    v5e's 16 MiB of scoped VMEM in the backward kernel). Compute
-    sub-blocks: up to 512 columns of scores at a time in the forward
-    kernel; the whole fetched block in the one backward kernel, which
-    makes dK, dV and dQ in one pass over the scores
+    head_dim is above 192 (a 1024-row block of 256 columns overruns the
+    v5e's 16 MiB of scoped VMEM in the backward kernel, one of 192 with
+    values of 128 fits). Compute sub-blocks: up to 512 columns of scores
+    at a time in the forward kernel; the whole fetched block in the one
+    backward kernel, which makes dK, dV and dQ in one pass over the scores
     (`use_fused_bwd_kernel`). The best of a sweep on a v5e at T = 4096,
     D = 128, heads 32/8 and 16/16 (PERF.md section 6, PR 32). A window
     keeps these blocks: smaller ones would skip more of a 512 window's
@@ -108,7 +108,7 @@ def _splash_block_sizes(t: int, head_dim: int):
     section 6, PR 43)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
 
-    largest = 1024 if head_dim <= 128 else 512
+    largest = 1024 if head_dim <= 192 else 512
     block = next(b for b in (1024, 512, 256, 128)
                  if b <= largest and t % b == 0)
     return BlockSizes(

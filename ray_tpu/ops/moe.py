@@ -11,7 +11,12 @@ probabilities, top-k of them, optionally renormalised, with the
 load-balancing loss (`load_balancing_loss`); **sigmoid** scores, the
 choice by score + a per-expert bias that is a buffer (no gradient reaches
 it: the weights are the chosen scores without it), renormalised,
-times a scaling factor, no aux loss.
+times a scaling factor, no aux loss. The sigmoid router's choice may be
+**group-limited** (`n_group`, `topk_group`): the E experts in `n_group`
+equal groups, a group ranked by the sum of its two largest scores (with
+the bias), a token's top-k taken among the experts of its `topk_group`
+best groups only; `groups_chosen` in the routing record counts the
+tokens that kept each group.
 
 **A share of the experts.** `moe_ffn` is told which experts it holds by
 what it is given: `w_gateup` / `w_down` carry the held experts only (a
@@ -153,14 +158,34 @@ def _first_matmul(params):
     return params["w_gateup"] if "w_gateup" in params else params["w_up"]
 
 
+def limit_to_groups(choice, n_group: int, topk_group: int):
+    """Group-limited choice: `choice` `[N, E]` with the experts outside
+    each token's `topk_group` best of `n_group` groups at -inf, and the
+    groups kept `[N, n_group]` bool. A group's rank is the sum of its two
+    largest entries; ties go to the lower index, as `top_k`'s do. No
+    gradient passes: the choice is read for its ids only."""
+    import jax
+    import jax.numpy as jnp
+
+    n, e = choice.shape
+    grouped = jax.lax.stop_gradient(choice).reshape(n, n_group, e // n_group)
+    rank = jax.lax.top_k(grouped, 2)[0].sum(-1)          # [N, n_group]
+    _, best = jax.lax.top_k(rank, topk_group)
+    kept = jax.nn.one_hot(best, n_group, dtype=jnp.bool_).any(axis=1)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(n, e), kept
+
+
 def route(w_router, x, num_selected: int, norm_topk: bool, *,
-          scoring: str = "softmax", bias=None, routed_scale: float = 1.0):
+          scoring: str = "softmax", bias=None, routed_scale: float = 1.0,
+          n_group: int = 1, topk_group: int = 1):
     """Router in float32 whatever the compute dtype (a rounded logit
     changes WHICH experts a token gets, not only by how much): the scores
     `[N, E]` (softmax probabilities, or independent sigmoids), the top-k
     weights and expert ids `[N, k]`. With a `bias` `[E]` the choice is the
     top-k of score + bias and the weights are the chosen scores without
-    it; the bias is a buffer, no gradient reaches it."""
+    it; the bias is a buffer, no gradient reaches it. With `n_group` > 1
+    the choice is group-limited (`limit_to_groups`) and a fourth value
+    comes back: the tokens that kept each group, int32 `[n_group]`."""
     import jax
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
@@ -175,6 +200,8 @@ def route(w_router, x, num_selected: int, norm_topk: bool, *,
         else jax.nn.sigmoid(logits)
     choice = probs if bias is None else probs + jax.lax.stop_gradient(
         bias.astype(jnp.float32))
+    if n_group > 1:
+        choice, kept = limit_to_groups(choice, n_group, topk_group)
     values, top_e = jax.lax.top_k(choice, num_selected)
     top_e = checkpoint_name(top_e, ROUTING_RESIDUALS)
     # the chosen scores: `top_k`'s own values where nothing was added to
@@ -182,13 +209,16 @@ def route(w_router, x, num_selected: int, norm_topk: bool, *,
     # is 1.8 ms a layer on the v5e at 8,192 x 22 of 512
     top_w = checkpoint_name(
         _scores_at()(probs, jax.lax.stop_gradient(values), top_e)
-        if bias is None else jnp.take_along_axis(probs, top_e, axis=-1),
+        if bias is None and n_group == 1
+        else jnp.take_along_axis(probs, top_e, axis=-1),
         ROUTING_RESIDUALS)
     if norm_topk:
         top_w = top_w / jnp.maximum(
             top_w.sum(axis=-1, keepdims=True), 1e-9)
     if routed_scale != 1.0:
         top_w = top_w * routed_scale
+    if n_group > 1:
+        return probs, top_w, top_e, kept.sum(0, dtype=jnp.int32)
     return probs, top_w, top_e
 
 
@@ -535,6 +565,7 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
             norm_topk: bool = True, scoring: str = "softmax",
             routed_scale: float = 1.0, expert_offset: int = 0,
             act: str = "silu", capacity_factor: float = 1.25,
+            n_group: int = 1, topk_group: int = 1,
             mesh=None, rules: Optional[ShardingRules] = None
             ) -> Tuple[Any, Dict[str, Any]]:
     """Top-k routed gated-expert FFN.
@@ -561,10 +592,13 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
         rows_bounded       int32 []   1 where the path past the sort ran
                                       over `row_bound`'s run of rows, 0
                                       where over every row
+        groups_chosen      int32 [n_group]  tokens that kept each group
+                                      (group-limited routing only)
 
     from which `load_balancing_loss` makes the softmax router's aux loss.
     The top-k weights are renormalised to sum to 1 only if `norm_topk`;
-    `scoring`, the bias and `routed_scale` are `route`'s.
+    `scoring`, the bias, `routed_scale`, `n_group` and `topk_group` are
+    `route`'s.
 
     The sorted dropless path runs unless `mesh` has an `expert` axis
     above 1 (module docstring); `capacity_factor` applies to that
@@ -578,9 +612,10 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
     held = _first_matmul(params).shape[0]
     k = min(num_selected, n_experts)
     with jax.named_scope("moe/router"):
-        probs, top_w, top_e = route(
+        probs, top_w, top_e, *groups = route(
             params["w_router"], x, k, norm_topk, scoring=scoring,
-            bias=params.get("router_bias"), routed_scale=routed_scale)
+            bias=params.get("router_bias"), routed_scale=routed_scale,
+            n_group=n_group, topk_group=topk_group)
         router_prob = probs.mean(axis=0)
     expert_parallel = mesh is not None and spec_entry_size(
         rules.mesh_axes("expert"), mesh) > 1
@@ -614,9 +649,12 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
     else:
         with jax.named_scope("moe/router"):
             elsewhere = x.shape[0] * k - counts.sum()
-    return y, {"tokens_per_expert": counts, "slots_elsewhere": elsewhere,
+    routing = {"tokens_per_expert": counts, "slots_elsewhere": elsewhere,
                "router_prob": router_prob, "dropped": dropped,
                "rows_bounded": bounded}
+    if groups:
+        routing["groups_chosen"], = groups
+    return y, routing
 
 
 def moe_ffn_dense_reference(params: Dict[str, Any], x, *,

@@ -529,7 +529,8 @@ def test_num_params_at_the_published_widths():
     (dict(moe_dense_layers=3), "moe_dense_layers"),
     (dict(moe_scoring="tanh"), "moe_scoring"),
     (dict(moe_experts_held=4, moe_scoring="softmax"), "aux loss"),
-    (dict(q_lora_rank=0), "latent attention"),
+    # a query latent is no longer needed (PR 50): the value width is
+    (dict(v_head_dim=0), "latent attention"),
     (dict(n_kv_heads=2), "latent attention"),
     (dict(moe_experts=0, moe_dense_layers=0, moe_shared_experts=0,
           kv_lora_rank=0, n_heads=3), "n_heads"),

@@ -45,6 +45,16 @@ KINDS = {
         moe_routed_scale=5.0, moe_shared_experts=1, moe_shared_ff=24,
         moe_latent=24, moe_act="relu2", moe_gated=False, moe_experts_held=2,
         moe_aux_coeff=0.0),
+    # train_ling3flash_ep64_d7: a group-limited choice (two more `top_k`
+    # a layer: a group's two largest, the groups) behind Kimi Delta
+    # Attention and latent attention; the run `LK` holds two expert layers
+    "group_limited_kda": TransformerConfig(
+        **BASE, n_layers=5, layer_pattern="kKKLK", kv_lora_rank=8,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, qk_norm=True,
+        moe_dense_ff=48, kda_heads=2, kda_head_dim=8, kda_chunk=32,
+        moe_experts=64, moe_top_k=4, moe_scoring="sigmoid",
+        moe_routed_scale=2.5, moe_groups=8, moe_topk_groups=4,
+        moe_shared_experts=1, moe_experts_held=8, moe_aux_coeff=0.0),
 }
 BATCH = {"tokens": jax.random.randint(jax.random.key(1), (2, SEQ + 1), 0,
                                       BASE["vocab_size"])}
@@ -153,6 +163,10 @@ PIECES = {
                            "score_gather": 1, "named": 6, "scans": 1},
     "held_below_picked_latent": {"sort": 2, "top_k": 2, "router_product": 1,
                                  "score_gather": 1, "named": 7, "scans": 2},
+    # the group limit's two `top_k` are the forward's alone: the kept
+    # `top_e` is all the backward reads of the choice
+    "group_limited_kda": {"sort": 2, "top_k": 3, "router_product": 1,
+                          "score_gather": 1, "named": 6, "scans": 3},
 }
 
 
