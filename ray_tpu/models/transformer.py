@@ -973,7 +973,7 @@ class Transformer:
                 with jax.named_scope("kda/" + scope):
                     cast[name] = lp[name].astype(cdt)
             # kda_mixer names its own scopes under `kda/`
-            out = kda_mixer(h, cast, chunk=cfg.kda_chunk,
+            out = kda_mixer(h, cast, chunk=cfg.kda_chunk, mesh=mesh,
                             lower=cfg.kda_gate_lower, eps=cfg.norm_eps)
             with jax.named_scope("kda/out_proj"):
                 return x + constrain(out, ("batch", "seq", "act_embed"))

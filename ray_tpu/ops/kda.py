@@ -64,6 +64,53 @@ take their operands in the compute dtype and accumulate in float32. A T
 that is no whole chunks is padded at its end with steps that change
 nothing (k = v = 0, beta = 0, no decay).
 
+**Two implementations of the one algorithm**, chosen at trace time by
+`kda_delta_impl` (no option, as `ops/ssm.ssd_scan_impl` chooses a scan's):
+
+- `"xla"` (`gated_delta_rule`): the factors, blocks, inverses and the six
+  per-chunk tensors go through HBM between every two passes (the column
+  factors alone `[H, T/C, C/16, C, D]` float32), a `lax.scan` chains the
+  chunks, autodiff makes the backward. It runs on the CPU, on a mesh above
+  one device (GSPMD cannot partition a pallas call) and for every shape
+  the kernels do not tile, and it is the oracle of the kernels' tests.
+- `"pallas"` (`gated_delta_rule_pallas`): on one TPU device where the
+  shapes tile (`delta_shape_ok`: chunks of 64, keys and values one lane
+  tile wide, the heads in pairs). Grid `(batch, head blocks, chunks)`, the
+  chunk axis sequential; a grid step takes one chunk of `DELTA_HEADS`
+  heads. It reads q, k, v (compute dtype) and g (float32) as lane-dense
+  `[64, heads·128]` blocks of the `[B, T, H·D]` layout the convolution
+  and the gates leave (no `[B, H, T/C, C, D]` copy of any of them; beta
+  `[B, T, H]`, 0.5 MB, is turned by XLA to two columns a pair of heads)
+  and writes o `[B, T, H·D]` float32. Two heads share every tile: a
+  pair's steps lie one above the other as `[128, 128]`, a head's
+  `[64, 64]` blocks are the two diagonal blocks of a `[128, 128]` matrix
+  (the MXU is that wide anyway, so a pair's key-key block, inverse and
+  products cost what one head's would), and what falls between the heads
+  is masked. In VMEM and never in HBM: the L2 norms, G (a float32 product
+  with a 0/1 matrix at the highest precision), the row and column factors
+  relative to the middles of the rows' sub-blocks (a column of a later
+  sub-block masked before the exponential), A and B (four products of a
+  sub-block's rows of q and k with k under its column factors, at the
+  highest precision), the inverse by doubling (ten float32 products at
+  the highest precision, a `fori_loop`), `T beta V`, `T beta (K e^G)`, U,
+  and the carried state, transposed `[Dv, D]` float32 a head (a decay lies
+  along its lanes) in a scratch that persists over the chunk axis. The
+  forward (`kda_delta_fwd`) also writes the state entering each chunk,
+  `[T/C, H·Dv, D]` float32: its one residual, alive between remat's
+  forward and the backward of the same layer. The backward
+  (`kda_delta_bwd`, under `jax.custom_vjp`) walks the chunks in reverse
+  with the state's cotangent carried in VMEM, makes a chunk's factors,
+  blocks, inverse and U again, and returns dq, dk, dv (compute dtype,
+  through the L2 norms), dg and dbeta; the inverse's cotangent is
+  `T^T dT T^T` on the strictly lower part (two products, no second
+  solve), what reaches G through the factors' reference rows is added
+  to those rows, and dg is the transpose of the running sum (one more
+  product with the 0/1 matrix). Roundings as above, the same operands of
+  the same products in the compute dtype; G's sums are added in the
+  MXU's order and not `cumsum`'s, and the cotangents of the products with
+  `S`, `U` and `T` are rounded to the compute dtype where autodiff leaves
+  them float32 beside a rounded operand (the MXU rounds both either way).
+
 **A share of the heads.** The sublayer is told its heads by the weights it
 is given: the columns of the projections, the channels of the
 convolutions and the rows of `W_o` that belong to some heads give that
@@ -77,7 +124,10 @@ inverses, the state), `kda/out_norm`, `kda/out_proj`.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
+
+from ray_tpu.ops.ssm import _one_tpu_device
 
 # steps of a sub-block: SUB x |lower| stays under float32's e^88
 SUB = 16
@@ -107,15 +157,38 @@ def causal_conv(x, w):
 
 
 def log_decay(a, a_log, bias, lower: float):
-    """The bounded gate: a `[B, T, H, D]`, a_log `[H]`, bias `[H, D]` ->
-    `lower * sigmoid(exp(a_log) * (a + bias))`, float32, in (lower, 0)."""
+    """The bounded gate: a `[B, T, H, D]` or `[B, T, H·D]`, a_log `[H]`,
+    bias `[H, D]` -> `lower * sigmoid(exp(a_log) * (a + bias))`, float32,
+    in (lower, 0), in a's layout."""
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
+    rate = jnp.broadcast_to(jnp.exp(a_log.astype(f32))[:, None], bias.shape)
     return lower * jax.nn.sigmoid(
-        jnp.exp(a_log.astype(f32))[:, None]
-        * (a.astype(f32) + bias.astype(f32)))
+        rate.reshape(a.shape[2:])
+        * (a.astype(f32) + bias.astype(f32).reshape(a.shape[2:])))
+
+
+def head_norm(o, gate, gain, eps: float):
+    """`rmsnorm(o_h) * gain * gate_h` a head: o `[B, T, H·D]`, gate
+    `[B, T, H]`, gain `[D]`, in float32. A head is a slice of the last
+    axis, not a `[.., H, D]` reshape: on the TPU that shape has another
+    tiling, and the compiler copied `[B, T, H·D]` into it and back in
+    every pass (`ops/ssm.gated_norm` met the same; PERF.md section 6,
+    PR 51)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    heads = gate.shape[-1]
+    d = o.shape[-1] // heads
+    gain = gain.astype(f32)
+    runs = [o[..., h * d:(h + 1) * d].astype(f32) for h in range(heads)]
+    return jnp.concatenate(
+        [run * jax.lax.rsqrt(jnp.mean(run * run, axis=-1, keepdims=True)
+                             + eps) * gain * gate[..., h:h + 1]
+         for h, run in enumerate(runs)], axis=-1)
 
 
 def _unit_lower_inverse(n):
@@ -220,14 +293,460 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
     return o[:, :t] if pad else o
 
 
+# ---- the delta rule as pallas TPU kernels -----------------------------------
+# One grid step takes one chunk of DELTA_CHUNK steps and a block of heads,
+# two heads to a `[128, 128]` tile: a pair's steps one above the other
+# (head A's 64 rows, then head B's), every `[64, 64]` block of a head one
+# of the two diagonal blocks of a `[128, 128]` matrix. The MXU is that wide
+# anyway: a pair's products cost what one head's would.
+DELTA_CHUNK = 64
+DELTA_LANES = 128
+DELTA_HEADS = 4          # heads a grid step takes (PERF.md section 6, PR 51)
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def delta_shape_ok(seq_len: int, heads: int, d_k: int, d_v: int,
+                   chunk: int) -> bool:
+    """Whether the kernels tile the delta rule: whole chunks of 64 steps,
+    keys and values one lane tile wide, the heads in pairs."""
+    return (chunk == DELTA_CHUNK and seq_len % chunk == 0
+            and d_k == DELTA_LANES and d_v == DELTA_LANES
+            and heads % 2 == 0)
+
+
+def kda_delta_impl(mesh, seq_len: int, heads: int, d_k: int, d_v: int,
+                   chunk: int) -> str:
+    """`"pallas"` (`gated_delta_rule_pallas`) where the program runs on one
+    TPU device and the kernels tile the shapes (`delta_shape_ok`), else
+    `"xla"` (`gated_delta_rule`: any platform, any length, and GSPMD can
+    partition it). Decided at trace time, as `ops/ssm.ssd_scan_impl`
+    decides for a Mamba-2 scan."""
+    return "pallas" if _one_tpu_device(mesh) and delta_shape_ok(
+        seq_len, heads, d_k, d_v, chunk) else "xla"
+
+
+def _dot(a, b, dims, exact: bool = False):
+    """A product accumulated in float32; `exact`: float32 operands at the
+    highest precision (A, B, the inverse, the running sums)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())),
+        precision=jax.lax.Precision.HIGHEST if exact else None,
+        preferred_element_type=jnp.float32)
+
+
+def _pair(ref, p: int):
+    """A pair's `[64, 2·128]` block of a `[B, T, H·D]` operand -> `[128,
+    128]`, head A's steps above head B's."""
+    import jax.numpy as jnp
+
+    lo = 2 * p * DELTA_LANES
+    return jnp.concatenate([ref[0, :, lo:lo + DELTA_LANES],
+                            ref[0, :, lo + DELTA_LANES:lo + 2 * DELTA_LANES]],
+                           axis=0)
+
+
+def _unpair(ref, p: int, value):
+    """`_pair` back: `[128, 128]` into a pair's block of an output."""
+    lo, c = 2 * p * DELTA_LANES, DELTA_CHUNK
+    ref[0, :, lo:lo + DELTA_LANES] = value[:c].astype(ref.dtype)
+    ref[0, :, lo + DELTA_LANES:lo + 2 * DELTA_LANES] = \
+        value[c:].astype(ref.dtype)
+
+
+def _per_head(fn):
+    """`fn(h, rows)` of a pair's two heads, one above the other: `rows`
+    head h's steps of a `[128, ..]` value."""
+    import jax.numpy as jnp
+
+    c = DELTA_CHUNK
+    return jnp.concatenate(
+        [fn(h, slice(h * c, (h + 1) * c)) for h in range(2)], axis=0)
+
+
+def _states_at(p: int):
+    """Where the states `[Dv, D]` of pair p's two heads lie in a grid
+    step's `[hb·Dv, D]`."""
+    w = DELTA_LANES
+    return [slice((2 * p + h) * w, (2 * p + h + 1) * w) for h in range(2)]
+
+
+def _by_sub(of_head):
+    """A pair's `[128, 128]` from its sub-blocks' rows: `of_head(h)` lists
+    head h's `[SUB, 128]` pieces, first sub-block first."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate(of_head(0) + of_head(1), axis=0)
+
+
+def _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p: int):
+    """The part of a chunk that is independent of the state, for the pair
+    p of a grid step's heads: the normed q and k, the running sums G (and
+    the rows of them the factors refer to, read off `g_scr`), A and B
+    relative to the middles of the rows' sub-blocks, the unit lower
+    triangular inverse by doubling, `T beta V` and `T beta (K e^G)`: a
+    namespace of `[128, 128]` values of the pair."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    c, n, ns = DELTA_CHUNK, 2 * DELTA_CHUNK, DELTA_CHUNK // SUB
+    cdt = v_ref.dtype
+    ch = types.SimpleNamespace()
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    same = (row >= c) == (col >= c)          # both of one head
+    ch.lower, ch.strict = same & (col <= row), same & (col < row)
+    ch.beta = jnp.concatenate(
+        [beta_ref[0, p, :, 0:1], beta_ref[0, p, :, 1:2]], axis=0)   # [128, 1]
+
+    def unit(ref):
+        x = _pair(ref, p).astype(f32)
+        r = jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + L2_EPS)
+        return x * r, r
+
+    ch.q_unit, ch.q_r = unit(q_ref)
+    ch.k, ch.k_r = unit(k_ref)
+    ch.scale = DELTA_LANES ** -0.5
+    ch.q = ch.q_unit * ch.scale
+    ch.v = _pair(v_ref, p)
+    # G: the running sum as a float32 product with a 0/1 matrix
+    ch.cum = _dot(ch.lower.astype(f32), _pair(g_ref, p), _NN, exact=True)
+    g_scr[...] = ch.cum
+    ch.middle = [[g_scr[h * c + s * SUB + SUB // 2 - 1:
+                        h * c + s * SUB + SUB // 2, :]
+                  for s in range(ns)] for h in range(2)]             # [1, 128]
+    last = [g_scr[h * c + c - 1:h * c + c, :] for h in range(2)]
+    ch.decay = [jnp.exp(v) for v in last]        # a chunk's whole decay
+
+    def over_heads(rows):    # a `[1, 128]` row a head, over its steps
+        return _per_head(lambda h, _: jnp.broadcast_to(
+            rows[h], (c, DELTA_LANES)))
+
+    middles = _by_sub(lambda h: [
+        jnp.broadcast_to(ch.middle[h][s], (SUB, DELTA_LANES))
+        for s in range(ns)])
+    ch.row = jnp.exp(ch.cum - middles)           # exponents in +-40
+    ch.decayed = jnp.exp(ch.cum)
+    ch.to_end = jnp.exp(over_heads(last) - ch.cum)                   # <= 1
+    rq, rk = ch.q * ch.row, ch.k * ch.row
+    step = row & (c - 1)                         # the step inside its head
+
+    def columns(s):
+        """The column factors of the rows' sub-block s and k under them:
+        a column of a later sub-block is masked before the exponential."""
+        factor = jnp.exp(jnp.where(
+            step < (s + 1) * SUB,
+            over_heads([ch.middle[h][s] for h in range(2)]) - ch.cum,
+            -jnp.inf))
+        return factor, ch.k * factor
+
+    def rows_of(s):
+        """Sub-block s's rows of q and k, both heads: `[4 SUB, 128]`."""
+        return jnp.concatenate(
+            [v[h * c + s * SUB:h * c + (s + 1) * SUB]
+             for h in range(2) for v in (rq, rk)], axis=0)
+
+    ch.columns, ch.rows_of = columns, rows_of
+    scores = [_dot(rows_of(s), columns(s)[1], _NT, exact=True)
+              for s in range(ns)]                # [4 SUB, 128] each
+    ch.b_mat = jnp.where(ch.lower, _by_sub(lambda h: [
+        sc[2 * h * SUB:(2 * h + 1) * SUB] for sc in scores]), 0.0)
+    ch.a_mat = jnp.where(ch.strict, _by_sub(lambda h: [
+        sc[(2 * h + 1) * SUB:(2 * h + 2) * SUB] for sc in scores]), 0.0)
+    # (I - N)^-1 by doubling, N = -beta A: N^64 = 0
+    nil = -ch.beta * ch.a_mat
+
+    def double(_, carry):
+        inv, power = carry       # the powers below `reach`, N^reach
+        return (inv + _dot(inv, power, _NN, exact=True),
+                _dot(power, power, _NN, exact=True))
+
+    inv, power = jax.lax.fori_loop(
+        0, 4, double, ((row == col).astype(f32) + nil,
+                       _dot(nil, nil, _NN, exact=True)))
+    ch.inv = inv + _dot(inv, power, _NN, exact=True)
+    ch.inv_c = ch.inv.astype(cdt)
+    ch.rhs = jnp.concatenate(
+        [ch.v.astype(f32) * ch.beta, ch.k * ch.decayed * ch.beta],
+        axis=1).astype(cdt)                                      # [128, 256]
+    solved = _dot(ch.inv_c, ch.rhs, _NN)
+    ch.t_v, ch.t_k = solved[:, :DELTA_LANES], \
+        solved[:, DELTA_LANES:].astype(cdt)
+    ch.q_in = (ch.q * ch.decayed).astype(cdt)
+    ch.b_in = ch.b_mat.astype(cdt)
+    ch.k_out = (ch.k * ch.to_end).astype(cdt)
+    return ch
+
+
+def _corrected(ch, states):
+    """U = T beta V - (T beta (K e^G)) S_0 of a pair, in the compute
+    dtype: `states` the two heads' entering states `[Dv, D]`, rounded."""
+    return _per_head(lambda h, rows: ch.t_v[rows] - _dot(
+        ch.t_k[rows], states[h], _NT)).astype(states[0].dtype)
+
+
+def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
+                      state, g_scr, *, pairs: int):
+    """One chunk of one block of heads: q, k, v, g `[64, hb·128]`, beta
+    `[hb/2, 64, 2]` -> o `[64, hb·128]` float32 and the states that
+    entered the chunk, transposed `[hb·Dv, D]` (a decay lies along the
+    lanes); `state` carries them over the chunks."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    c, cdt = DELTA_CHUNK, v_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    for p in range(pairs):
+        ch = _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p)
+        at = _states_at(p)
+        entering = [state[at[h], :] for h in range(2)]
+        for h in range(2):
+            st_ref[0, 0, at[h], :] = entering[h]
+        rounded = [s.astype(cdt) for s in entering]
+        u = _corrected(ch, rounded)
+        intra = _dot(ch.b_in, u, _NN)
+        _unpair(o_ref, p, intra + _per_head(
+            lambda h, rows: _dot(ch.q_in[rows], rounded[h], _NT)))
+        for h in range(2):
+            rows = slice(h * c, (h + 1) * c)
+            state[at[h], :] = entering[h] * ch.decay[h] + _dot(
+                u[rows], ch.k_out[rows], _TN)
+
+
+def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
+                      dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate,
+                      g_scr, *, pairs: int):
+    """The same chunk going backward (the chunks last to first): besides
+    the forward's operands the entering states and do `[64, hb·128]`
+    float32 -> dq, dk, dv, dg and dbeta `[hb/2, 64, 2]`. A chunk's
+    factors, blocks and inverse are made again; `dstate` carries the
+    cotangent of the state a chunk hands on. The inverse's cotangent is
+    `T^T dT T^T` on the strictly lower part."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    c, w, ns = DELTA_CHUNK, DELTA_LANES, DELTA_CHUNK // SUB
+    cdt = v_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    for p in range(pairs):
+        ch = _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p)
+        at = _states_at(p)
+        entering = [st_ref[0, 0, at[h], :] for h in range(2)]
+        rounded = [s.astype(cdt) for s in entering]
+        u = _corrected(ch, rounded)
+        d_o = _pair(do_ref, p).astype(cdt)
+        d_out = [dstate[at[h], :] for h in range(2)]
+        d_out_c = [d.astype(cdt) for d in d_out]
+        # ---- the four products of the chain -------------------------
+        d_u = _dot(ch.b_in, d_o, _TN) + _per_head(
+            lambda h, rows: _dot(ch.k_out[rows], d_out_c[h], _NT))
+        d_u_c = d_u.astype(cdt)
+        d_b = jnp.where(ch.lower, _dot(d_o, u, _NT), 0.0)
+        d_q_in = _per_head(lambda h, rows: _dot(d_o[rows], rounded[h], _NN))
+        d_t_k = -_per_head(lambda h, rows: _dot(d_u_c[rows], rounded[h], _NN))
+        d_k_out = _per_head(lambda h, rows: _dot(u[rows], d_out_c[h], _NN))
+        d_last = []
+        for h in range(2):
+            rows = slice(h * c, (h + 1) * c)
+            d_decay = jnp.sum(d_out[h] * entering[h], axis=0, keepdims=True)
+            d_last.append(d_decay * ch.decay[h])
+            dstate[at[h], :] = d_out[h] * ch.decay[h] \
+                + _dot(d_o[rows], ch.q_in[rows], _TN) \
+                - _dot(d_u_c[rows], ch.t_k[rows], _TN)
+        # ---- T beta [V | K e^G], and the inverse --------------------
+        d_solved = jnp.concatenate([d_u_c, d_t_k.astype(cdt)], axis=1)
+        d_rhs = _dot(ch.inv_c, d_solved, _TN)                    # [128, 256]
+        d_inv = _dot(d_solved, ch.rhs, _NT)
+        d_nil = jnp.where(ch.strict, _dot(
+            _dot(ch.inv, d_inv, _TN, exact=True), ch.inv, _NT, exact=True),
+            0.0)
+        d_a = -ch.beta * d_nil
+        d_rhs_v, d_rhs_k = d_rhs[:, :w], d_rhs[:, w:]
+        d_beta = jnp.sum(
+            d_rhs_v * ch.v.astype(f32) + d_rhs_k * ch.k * ch.decayed
+            - d_nil * ch.a_mat, axis=1, keepdims=True)
+        # ---- A and B: the rows' and the columns' factors ------------
+        d_k = d_rhs_k * ch.decayed * ch.beta + d_k_out * ch.to_end
+        through_end = d_k_out * ch.k * ch.to_end
+        d_cum = (d_q_in * ch.q + d_rhs_k * ch.k * ch.beta) * ch.decayed \
+            - through_end
+        d_middle = [[None] * ns for _ in range(2)]
+        d_rows = []
+        for s in range(ns):
+            d_scores = jnp.concatenate(
+                [m[h * c + s * SUB:h * c + (s + 1) * SUB]
+                 for h in range(2) for m in (d_b, d_a)], axis=0)
+            factor, under = ch.columns(s)
+            d_rows.append(_dot(d_scores, under, _NN, exact=True))
+            d_under = _dot(d_scores, ch.rows_of(s), _TN, exact=True)
+            d_k = d_k + d_under * factor
+            through = d_under * under
+            d_cum = d_cum - through
+            for h in range(2):
+                d_middle[h][s] = jnp.sum(through[h * c:(h + 1) * c], axis=0,
+                                         keepdims=True)
+        d_rq = _by_sub(lambda h: [
+            d[2 * h * SUB:(2 * h + 1) * SUB] for d in d_rows])
+        d_rk = _by_sub(lambda h: [
+            d[(2 * h + 1) * SUB:(2 * h + 2) * SUB] for d in d_rows])
+        through_row = (d_rq * ch.q + d_rk * ch.k) * ch.row
+        d_cum = d_cum + through_row
+        d_q = d_rq * ch.row + d_q_in * ch.decayed
+        d_k = d_k + d_rk * ch.row
+        # what reaches G through the rows the factors refer to
+        g_scr[...] = d_cum
+        for h in range(2):
+            for s in range(ns):
+                lo = h * c + s * SUB
+                at_mid = slice(lo + SUB // 2 - 1, lo + SUB // 2)
+                g_scr[at_mid, :] += d_middle[h][s] - jnp.sum(
+                    through_row[lo:lo + SUB], axis=0, keepdims=True)
+            g_scr[h * c + c - 1:h * c + c, :] += d_last[h] + jnp.sum(
+                through_end[h * c:(h + 1) * c], axis=0, keepdims=True)
+        # the transpose of the running sum
+        _unpair(dg_ref, p, _dot(ch.lower.astype(f32), g_scr[...], _TN,
+                                exact=True))
+
+        def through_norm(d_unit, unit, r):
+            return r * (d_unit - unit * jnp.sum(d_unit * unit, axis=1,
+                                                keepdims=True))
+
+        _unpair(dq_ref, p, through_norm(d_q * ch.scale, ch.q_unit, ch.q_r))
+        _unpair(dk_ref, p, through_norm(d_k, ch.k, ch.k_r))
+        _unpair(dv_ref, p, d_rhs_v * ch.beta)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (c, 2), 1)
+        dbeta_ref[0, p] = jnp.where(lane == 0, d_beta[:c], d_beta[c:])
+
+
+@functools.lru_cache(maxsize=None)
+def _delta_calls(bsz: int, t: int, heads: int, hb: int, interpret: bool):
+    """The delta rule of one set of shapes under `jax.custom_vjp`, built
+    once a process with each `pallas_call` behind a `jax.jit` of its own,
+    for the reason of `ops/ssm._scan_calls`: a pallas kernel's body is
+    traced anew by every call, in every program of a job."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c, w = DELTA_CHUNK, DELTA_LANES
+    nc, blocks, pairs = t // c, heads // hb, hb // 2
+    f32 = jnp.float32
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+    def specs(reverse):
+        """The block specs of a walk over the chunks, first to last or
+        last to first: q-wide, beta-like pairs of columns, the entering
+        states."""
+        def at(ci):
+            return nc - 1 - ci if reverse else ci
+        return (
+            pl.BlockSpec((1, c, hb * w), lambda bi, hi, ci: (bi, at(ci), hi)),
+            pl.BlockSpec((1, pairs, c, 2),
+                         lambda bi, hi, ci: (bi, hi, at(ci), 0)),
+            pl.BlockSpec((1, 1, hb * w, w),
+                         lambda bi, hi, ci: (bi, at(ci), hi, 0)))
+
+    scratch = [pltpu.VMEM((hb * w, w), f32), pltpu.VMEM((2 * c, w), f32)]
+
+    @functools.partial(jax.jit, inline=True)
+    def forward(q, k, v, g, beta):
+        wide, cols, states = specs(False)
+        return pl.pallas_call(
+            functools.partial(_delta_fwd_kernel, pairs=pairs),
+            grid=(bsz, blocks, nc),
+            in_specs=[wide, wide, wide, wide, cols],
+            out_specs=[wide, states],
+            out_shape=[jax.ShapeDtypeStruct((bsz, t, heads * w), f32),
+                       jax.ShapeDtypeStruct((bsz, nc, heads * w, w), f32)],
+            scratch_shapes=scratch, compiler_params=params,
+            interpret=interpret, name="kda_delta_fwd")(q, k, v, g, beta)
+
+    @functools.partial(jax.jit, inline=True)
+    def backward(q, k, v, g, beta, entering, d_o):
+        wide, cols, states = specs(True)
+        return pl.pallas_call(
+            functools.partial(_delta_bwd_kernel, pairs=pairs),
+            grid=(bsz, blocks, nc),
+            in_specs=[wide, wide, wide, wide, cols, states, wide],
+            out_specs=[wide, wide, wide, wide, cols],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype),
+                       jax.ShapeDtypeStruct(g.shape, f32),
+                       jax.ShapeDtypeStruct(beta.shape, f32)],
+            scratch_shapes=scratch, compiler_params=params,
+            interpret=interpret, name="kda_delta_bwd")(
+                q, k, v, g, beta, entering, d_o)
+
+    @jax.custom_vjp
+    def rule(q, k, v, g, beta):
+        return forward(q, k, v, g, beta)[0]
+
+    def rule_fwd(*operands):
+        o, entering = forward(*operands)
+        return o, (operands, entering)
+
+    def rule_bwd(res, d_o):
+        operands, entering = res
+        return tuple(backward(*operands, entering, d_o))
+
+    rule.defvjp(rule_fwd, rule_bwd)
+    return rule
+
+
+def gated_delta_rule_pallas(q, k, v, g, beta, *, chunk: int = DELTA_CHUNK,
+                            head_block=None, interpret: bool = False):
+    """`gated_delta_rule` as pallas TPU kernels with a backward kernel of
+    their own (module docstring), on the layouts the convolution and the
+    gates leave: q, k, v `[B, T, H·D]` (L2-normed in the kernels; v's
+    dtype is the compute dtype), g `[B, T, H·D]` float32, beta
+    `[B, T, H]` -> o `[B, T, H·D]` float32. The shapes are
+    `delta_shape_ok`'s to vouch for; `head_block` and `interpret` are the
+    tests' (fewer heads a grid step, the kernels on the CPU)."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    bsz, t, heads = beta.shape
+    if not delta_shape_ok(t, heads, q.shape[2] // heads,
+                          v.shape[2] // heads, chunk):
+        raise ValueError(f"the kernels do not tile {q.shape} in chunks of "
+                         f"{chunk} with {heads} heads")
+    hb = head_block or next(b for b in (DELTA_HEADS, 2) if heads % b == 0)
+    rule = _delta_calls(bsz, t, heads, hb, interpret)
+
+    def pairs(a):    # [B, T, H] <-> [B, H/2, T, 2]: 0.5 MB, turned by XLA
+        return jnp.swapaxes(a.reshape(bsz, t, heads // 2, 2), 1, 2)
+
+    return rule(q, k, v, g.astype(f32), pairs(beta.astype(f32)))
+
+
 def kda_mixer(h, lp: Dict[str, Any], *, chunk: int, lower: float,
-              eps: float):
+              eps: float, mesh=None):
     """h `[B, T, d]` (normed, compute dtype) -> the sublayer's output
     before the residual, `[B, T, d]`. lp: `w_kda_qkv [d, 3, H, D]`,
     `w_kda_a [d, H, D]`, `w_kda_bg [d, 2, H]` (beta, the output gate) and
     `w_kda_out [H, D, d]` in the compute dtype; `kda_conv [3, H*D, K]`,
     `kda_A_log [H]`, `kda_a_bias [H, D]`, `kda_out_norm [D]`. The heads
-    are read off the leaves (module docstring)."""
+    are read off the leaves (module docstring); `mesh` is what the program
+    runs on, for `kda_delta_impl`'s choice."""
     import jax
     import jax.numpy as jnp
 
@@ -241,20 +760,29 @@ def kda_mixer(h, lp: Dict[str, Any], *, chunk: int, lower: float,
         # channels side by side
         qkv = jax.nn.silu(causal_conv(
             qkv.reshape(bsz, t, 3 * heads * d),
-            lp["kda_conv"].reshape(3 * heads * d, -1))
-            ).reshape(bsz, t, 3, heads, d)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            lp["kda_conv"].reshape(3 * heads * d, -1)))
+        # `[B, T, H·D]` each: a run of the convolution's lanes
+        q, k, v = (qkv[..., i * heads * d:(i + 1) * heads * d]
+                   for i in range(3))
     with jax.named_scope("kda/gates"):
-        g = log_decay(jnp.einsum("btd,dhk->bthk", h, lp["w_kda_a"]),
+        # in the convolution's `[B, T, H·D]` layout, as the kernels and
+        # `head_norm` read it
+        g = log_decay(jnp.einsum("btd,de->bte", h,
+                                 lp["w_kda_a"].reshape(-1, heads * d)),
                       lp["kda_A_log"], lp["kda_a_bias"], lower)
         bg = jax.nn.sigmoid(jnp.einsum(
             "btd,dgh->btgh", h, lp["w_kda_bg"]).astype(f32))
         beta, gate = bg[:, :, 0], bg[:, :, 1]
     with jax.named_scope("kda/delta"):
-        o = gated_delta_rule(q, k, v, g, beta, chunk=chunk)
+        if kda_delta_impl(mesh, t, heads, d, d, chunk) == "pallas":
+            # the kernels read the convolution's own layout
+            o = gated_delta_rule_pallas(q, k, v, g, beta, chunk=chunk)
+        else:
+            o = gated_delta_rule(
+                *(a.reshape(bsz, t, heads, d) for a in (q, k, v, g)), beta,
+                chunk=chunk).reshape(bsz, t, heads * d)
     with jax.named_scope("kda/out_norm"):
-        y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
-        y = (y * lp["kda_out_norm"].astype(f32)
-             * gate[..., None]).astype(h.dtype)
+        y = head_norm(o, gate, lp["kda_out_norm"], eps).astype(h.dtype)
     with jax.named_scope("kda/out_proj"):
-        return jnp.einsum("bthk,hkd->btd", y, lp["w_kda_out"])
+        return jnp.einsum("bte,ed->btd", y,
+                          lp["w_kda_out"].reshape(heads * d, -1))

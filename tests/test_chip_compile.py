@@ -527,6 +527,65 @@ def test_mamba1_scan_kernels_fwd_bwd(v5e, b, t, ch, n):
     assert not re.search(rf"\[({b},)?{t},({n},{ch}|{ch},{n})\]", hlo)
 
 
+# [B, T, heads]: the Ling cell's call (8 heads of 128, chunk 64), and a
+# small one in two batch rows and one block of heads
+KDA_CALLS = [(1, 16384, 8), (2, 256, 4)]
+
+
+@pytest.mark.parametrize(
+    "b,t,h", KDA_CALLS, ids=["x".join(map(str, call)) for call in KDA_CALLS])
+def test_kda_kernels_fwd_bwd(v5e, b, t, h):
+    """Forward and backward of `ops/kda.gated_delta_rule_pallas` alone for
+    the v5e compiler: one `kda_delta_fwd` and one `kda_delta_bwd` call, q,
+    k, v and g reaching them as the program's arguments hold them, in the
+    `[B, T, H·D]` layout of the convolution and the gates (no head-major
+    copy before the call), dq, dk, dv leaving in the compute dtype, the
+    entering states `[T/64, H·Dv, D]` float32 the only residual the
+    forward writes, nothing of a sub-block's factors (`[.., 4, 64, 128]`)
+    in the compiled text, and `delta_shape_ok` said yes to what compiled."""
+    import re
+
+    from ray_tpu.ops.kda import (DELTA_CHUNK, delta_shape_ok,
+                                 gated_delta_rule_pallas)
+
+    d, c = 128, DELTA_CHUNK
+    assert delta_shape_ok(t, h, d, d, c)
+    chip = SingleDeviceSharding(v5e)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_rule_pallas(q, k, v, g, beta, chunk=c).sum()
+
+    wide = (b, t, h * d)
+    hlo = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+        arg(wide, jnp.bfloat16), arg(wide, jnp.bfloat16),
+        arg(wide, jnp.bfloat16), arg(wide, jnp.float32),
+        arg((b, t, h), jnp.float32)).compile().as_text()
+    calls = {re.search(r"kda_delta_(fwd|bwd)", name).group(0):
+             (out, operands) for name, out, operands in re.findall(
+                 r'%([\w.\-]+) = (\([^\n]*?\)) custom-call\(([^\n]*?)\), '
+                 r'custom_call_target="tpu_custom_call"', hlo)}
+    assert sorted(calls) == ["kda_delta_bwd", "kda_delta_fwd"], sorted(calls)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    fwd_out, fwd_in = calls["kda_delta_fwd"]
+    bwd_out, bwd_in = calls["kda_delta_bwd"]
+    states = f"f32[{b},{t // c},{h * d},{d}]"
+    assert f"f32[{b},{t},{h * d}]" in fwd_out and states in fwd_out
+    assert fwd_out.count("[") == 2, fwd_out             # o and the states
+    assert bwd_out.count(f"bf16[{b},{t},{h * d}]") == 3, bwd_out
+    assert bwd_out.count(f"f32[{b},{t},{h * d}]") == 1, bwd_out
+    # q, k, v, g as the program's arguments hold them (small ones the
+    # compiler prefetches, a `copy-start` / `copy-done`): no other layout
+    ours = "".join(rf"%({name}\.1|copy-done[.\d]*), " for name in "qkvg")
+    assert re.match(ours, fwd_in) and re.match(ours, bwd_in), (fwd_in, bwd_in)
+    assert not re.search(rf"\[{b},{t},{h * d}\]\S* (copy|transpose)\(", hlo)
+    assert not re.search(rf"\[{b},{h},{t // c},{c},{d}\]", hlo)
+    # a sub-block's factors never reach HBM
+    assert not re.search(r"f32\[[\d,]*4,64,128\]", hlo)
+
+
 def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     """Two `ME` blocks and one `M*E` block of Nemotron-3-Super's widths as
     one chip holds them (32 mixer heads of 64 in 2 groups of state 128, 8
@@ -780,8 +839,11 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     benchmark's `train_ling3flash_ep64_d7` has four more `K` layers):
     splash takes keys 192 wide beside values 128 wide, unpadded, in blocks
     of 1,024; `megablox` over `row_bound`'s run of 4,096 rows; the delta
-    rule is plain XLA under `kda/delta` (no pallas call there) and holds
-    nothing `[T, T]`; the new scopes are on what the compiler leaves."""
+    rule is the pallas kernels under `kda/delta` (`kda_delta_impl` says
+    "pallas" for this mesh and these shapes: per KDA layer a
+    `kda_delta_fwd` in the forward, one in remat's forward and a
+    `kda_delta_bwd`) and nothing there is as large as a sub-block's
+    factors; the new scopes are on what the compiler leaves."""
     import re
 
     import optax
@@ -789,6 +851,7 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     from ray_tpu.models import Transformer
     from ray_tpu.models.configs import TransformerConfig
     from ray_tpu.ops.attention import _splash_block_sizes
+    from ray_tpu.ops.kda import kda_delta_impl
     from ray_tpu.ops.moe import gmm_tiles, grouped_matmul_impl, row_bound
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.train_step import make_train_step
@@ -816,6 +879,7 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     assert gmm_tiles(bound, 2560, 2 * 768) == (512, 512, 768)
     assert gmm_tiles(bound, 768, 2560) == (512, 768, 512)
     assert grouped_matmul_impl(mesh, bound, 2560, 768) == "megablox"
+    assert kda_delta_impl(mesh, seq, 8, 128, 128, cfg.kda_chunk) == "pallas"
     optimizer = optax.adamw(3e-7, weight_decay=0.01)
     _, train_step = make_train_step(
         lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh,
@@ -845,7 +909,16 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     assert names == ["gmm"] * 12 + ["splash_mha_dkv_no_residuals",
                                     "splash_mha_fwd_residuals"] \
         + ["tgmm"] * 4, names
-    assert not [op for _, op in kernels if "kda/" in op]
+    # two KDA layers: each a forward, remat's forward and a backward of
+    # the delta rule, all under `kda/delta` and nowhere else
+    under_kda = sorted(
+        (re.search(r"kda_delta_(fwd|bwd)", n).group(0),
+         "rematted_computation" in op, "transpose(jvp" in op)
+        for n, op in kernels if "kda" in n or "kda/" in op)
+    assert under_kda == [("kda_delta_bwd", False, True)] * 2 \
+        + [("kda_delta_fwd", False, False)] * 2 \
+        + [("kda_delta_fwd", True, True)] * 2, under_kda
+    assert all("/kda/delta/" in op for n, op in kernels if "kda" in n)
     assert not [op for n, op in kernels
                 if "rematted_computation" in op and "splash" in n]
     for scope in ("kda_norm", "kda/qkv_proj", "kda/conv", "kda/gates",
@@ -853,14 +926,15 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
                   "qkv/kv_down", "qkv/kv_up", "qkv/assemble", "moe/router",
                   "moe/shared", "mlp/gate_up", "head"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)"]', hlo), scope
-    # under `kda/delta` nothing is `[T, T]`-sized: the largest tensor is
-    # the sub-blocks' column factors, `[H, T/C, C/16, C, D]` float32
+    # under `kda/delta` the largest tensor is the entering states,
+    # `[T/C, H·Dv, D]` float32: the sub-blocks' column factors
+    # `[H, T/C, C/16, C, D]`, four times that, stay in VMEM
     for line in hlo.splitlines():
         if not re.search(r'op_name="[^"]*[/(]kda/delta[/)"]', line):
             continue
         for dims in re.findall(r"\b(?:f32|bf16|s32)\[([\d,]+)\]", line):
             assert np.prod([int(v) for v in dims.split(",")]) \
-                <= 8 * seq * 4 * 128, line[:300]
+                <= 8 * seq * 2 * 128, line[:300]
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes
