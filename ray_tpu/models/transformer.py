@@ -614,20 +614,20 @@ class Transformer:
         its backward (`ops.attention.FLASH_RESIDUALS`: the output
         [B, H, T, Dv] in the compute dtype and the logsumexp [B, H, T] in
         f32) and an expert layer's routing (`ops.moe.ROUTING_RESIDUALS`:
-        the router's logits [N, E] f32, the chosen experts [N, k], the
-        sort's two permutations [N·min(k, held)] and counts [E], int32).
-        It recomputes the rest of the layer (norms, projections, RoPE,
-        the MLP or the experts past the sort). The backward pass then
-        runs the forward kernel, the f32 router product, the top-k and
-        the sorts once a step, not twice. The rule adapts by what the
-        traced layer holds: only the flash path and a router name those
-        values, so under `dense`, `ring` or `ulysses` attention with a
-        dense FFN nothing is named, nothing is saved and the program is
-        "full"'s. At many layers it costs L x B*T*H*Dv x 2 bytes
-        (+ L x B*H*T x 4) and, an expert layer, N*E x 4 bytes (+ the
-        int32s) more than "full", which saves nothing but the carry and
-        is there for whoever needs those bytes. "dots" saves every
-        matmul's output besides."""
+        the router's logits [N, E] f32, the chosen experts [N, k] int32
+        and their scores f32, `keep` [N, held] where held < k, the sort's
+        two permutations [N·min(k, held)] and counts [E], int32). It
+        recomputes the rest (norms, projections, RoPE, the MLP or the
+        experts past the sort). The backward pass then runs the forward
+        kernel, the f32 router product, the top-k and the sorts once a
+        step, not twice. The rule adapts by what the traced layer holds:
+        only the flash path and a router name those values, so under
+        `dense`, `ring` or `ulysses` attention with a dense FFN nothing is
+        named, nothing is saved and the program is "full"'s. At many
+        layers it costs L x B*T*H*Dv x 2 bytes (+ L x B*H*T x 4) and, an
+        expert layer, N*E x 4 bytes (+ the `[N, k]`s) more than "full",
+        which saves nothing but the carry and is there for whoever needs
+        those bytes. "dots" saves every matmul's output besides."""
         import jax
 
         from ray_tpu.ops.attention import FLASH_RESIDUALS

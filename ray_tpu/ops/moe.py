@@ -100,11 +100,11 @@ from ray_tpu.parallel.sharding import (ShardingRules, spec_entry_size,
 # The name (`jax.ad_checkpoint.checkpoint_name`) of what a checkpointed
 # expert layer keeps of its routing, as `ops.attention.FLASH_RESIDUALS` is
 # of the attention kernel's: the router's logits `[N, E]` f32, the chosen
-# experts `[N, k]` int32 and their scores `[N, k]` f32 as gathered
+# experts `[N, k]` int32 and their scores `[N, k]` f32 as read off them
 # (`route`); `keep` `[N, held]` where fewer experts are held than a token
 # picks, and the sort's `order`, `inverse` `[N·min(k, held)]` and `counts`
 # `[E]` (`_sorted_ffn`), all int32. `Transformer._remat`'s policy saves
-# them, so the backward pass runs no router product, no top-k, no gather
+# them, so the backward pass runs no router product, no top-k, no read
 # of the chosen scores and no sort again: the scores, the weights'
 # normalisation and the one-hot through `keep` are remade from these by
 # elementwise work. The LOGITS and not the scores: a sigmoid's and a
@@ -183,9 +183,10 @@ def route(w_router, x, num_selected: int, norm_topk: bool, *,
     `[N, E]` (softmax probabilities, or independent sigmoids), the top-k
     weights and expert ids `[N, k]`. With a `bias` `[E]` the choice is the
     top-k of score + bias and the weights are the chosen scores without
-    it; the bias is a buffer, no gradient reaches it. With `n_group` > 1
-    the choice is group-limited (`limit_to_groups`) and a fourth value
-    comes back: the tokens that kept each group, int32 `[n_group]`."""
+    it, read off the selection (`_chosen_scores`); the bias is a buffer,
+    no gradient reaches it. With `n_group` > 1 the choice is group-limited
+    (`limit_to_groups`) and a fourth value comes back: the tokens that
+    kept each group, int32 `[n_group]`."""
     import jax
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
@@ -205,12 +206,13 @@ def route(w_router, x, num_selected: int, norm_topk: bool, *,
     values, top_e = jax.lax.top_k(choice, num_selected)
     top_e = checkpoint_name(top_e, ROUTING_RESIDUALS)
     # the chosen scores: `top_k`'s own values where nothing was added to
-    # them, else gathered. Kept too: the gather of N·k scalars from [N, E]
-    # is 1.8 ms a layer on the v5e at 8,192 x 22 of 512
+    # them, else read off the selection by compares (`_chosen_scores`: a
+    # gather of N·k scalars from [N, E] is 1.8 ms a layer on the v5e at
+    # 8,192 x 22 of 512). Kept either way
     top_w = checkpoint_name(
         _scores_at()(probs, jax.lax.stop_gradient(values), top_e)
         if bias is None and n_group == 1
-        else jnp.take_along_axis(probs, top_e, axis=-1),
+        else _chosen_scores(probs, top_e),
         ROUTING_RESIDUALS)
     if norm_topk:
         top_w = top_w / jnp.maximum(
@@ -222,6 +224,27 @@ def route(w_router, x, num_selected: int, norm_topk: bool, *,
     return probs, top_w, top_e
 
 
+def _chosen_scores(probs, top_e):
+    """`take_along_axis(probs, top_e)` `[N, k]` without the gather: column
+    e of a row goes to the slot whose id is e, every other term of the sum
+    over E is zero, so the sum is exact and the bits are the gather's. Its
+    derivative is the same compares (a slot's cotangent goes to column e;
+    `top_k` picks no column twice, so that sum is exact too), no
+    scatter-add, and reads the ids it is handed, the NAMED ones. XLA makes
+    compare, select and sum one pass over `probs`, so nothing `[N, k, E]`
+    reaches memory (`tests/test_chip_compile.py` reads the v5e's steps
+    for it). Behind a barrier, as the gather was a pass of its own: a
+    compiler that fuses the sum into what reads it rounds the weights'
+    normalisation one way where remat keeps the scores and another where
+    it makes them again (XLA:CPU, a last bit of the loss)."""
+    import jax
+    import jax.numpy as jnp
+
+    columns = jax.lax.broadcasted_iota(jnp.int32, (1, 1, probs.shape[-1]), 2)
+    return jax.lax.optimization_barrier(
+        jnp.where(top_e[..., None] == columns, probs[:, None, :], 0).sum(-1))
+
+
 @functools.lru_cache(maxsize=None)
 def _scores_at():
     """`scores_at(probs, values, top_e)`: `top_k`'s values as what they
@@ -229,8 +252,10 @@ def _scores_at():
     made, no gather (N·k scalars from `[N, E]` are 1.4 ms a layer on the
     v5e at 16,384 x 8 of 64); backward that gather's transpose at the ids
     it is handed, the NAMED ones: `top_k`'s own derivative reads the ids
-    it made itself, and remat would run it again for them. Built on first
-    use, as `_permutes`."""
+    it made itself, and remat would run it again for them. For a router
+    without a choice bias and group limit only: where something was added
+    to the scores `top_k`'s values are not the weights, and
+    `_chosen_scores` reads them. Built on first use, as `_permutes`."""
     import jax
     import jax.numpy as jnp
 

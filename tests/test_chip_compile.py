@@ -336,6 +336,44 @@ def test_olmoe_layer_step_compiles_with_the_grouped_matmul_kernels(v5e):
     assert not bad, bad
 
 
+def assert_chosen_scores_read_off_the_selection(hlo, tokens, picked,
+                                                experts):
+    """A biased router's chosen scores as the v5e's compiler leaves them
+    (`ops/moe._chosen_scores`): compare, select and sum are one fusion, so
+    nothing `[N, k, E]` is a buffer in memory (a value of a computation
+    that no fusion calls); no gather makes an `[N, k]` and none runs under
+    `moe/router`; no scatter fills the `[N, E]` scores (XLA flattens that
+    one to `[N·E]` and gives it no `op_name`). Returns the fusions under
+    the router that hold an `[N, k, E]` value: one forward and one
+    backward a scan at least, or the check read nothing."""
+    import re
+
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    wide = re.compile(rf"\[{tokens},({picked},{experts}|{experts},{picked})\]")
+    chosen = re.compile(rf"\[({tokens},{picked}|{picked},{tokens})\]")
+    scores = re.compile(rf"f32\[({tokens},{experts}|{tokens * experts})\]")
+    inside, holding = None, set()
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+        if head or " = " not in line:
+            continue
+        shape, op = line.split(" = ", 1)[1].split(" ", 1)
+        if wide.search(shape):
+            assert inside in fused, line[:300]
+            if "moe/router" in line:
+                holding.add(inside)
+        if op.startswith("gather("):
+            assert not chosen.search(shape) and "moe/router" not in line, \
+                line[:300]
+        if op.startswith("scatter("):
+            assert not scores.match(shape) and "moe/router" not in line, \
+                line[:300]
+    assert len(holding) >= 2, holding
+    return holding
+
+
 @pytest.mark.parametrize("policy,fwd_calls", REMAT_POLICIES)
 def test_latent_attention_share_step_compiles_for_the_v5e(v5e, policy,
                                                           fwd_calls):
@@ -414,6 +452,7 @@ def test_latent_attention_share_step_compiles_for_the_v5e(v5e, policy,
         2 * fwd_calls, names
     assert sum(n.startswith("splash_mha_dkv") for n in names) == 2, names
     assert_saved_residuals(hlo, policy, (2, rows, 20, seq, 256))
+    assert_chosen_scores_read_off_the_selection(hlo, rows * seq, 4, 64)
     for scope in ("qkv/q_down", "qkv/kv_down", "qkv/q_up", "qkv/kv_up",
                   "qkv/assemble", "moe/shared", "moe/router", "moe/dispatch",
                   "moe/experts", "moe/combine", "mlp/gate_up", "mlp/down"):
@@ -697,6 +736,7 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     assert re.search(rf"bf16\[{held_rows},1024\]", hlo)
     assert re.search(rf"bf16\[{held_rows},2688\]", hlo)
     assert not re.search(rf"\[{rows * seq * 22},(1024|2688|4096)\]", hlo)
+    assert_chosen_scores_read_off_the_selection(hlo, rows * seq, 22, 512)
     for scope in ("ssm/in_proj", "ssm/conv", "ssm/scan", "ssm/gate_norm",
                   "ssm/out_proj", "ssm_norm", "moe/latent", "moe/shared",
                   "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
@@ -921,6 +961,7 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     assert all("/kda/delta/" in op for n, op in kernels if "kda" in n)
     assert not [op for n, op in kernels
                 if "rematted_computation" in op and "splash" in n]
+    assert_chosen_scores_read_off_the_selection(hlo, seq, 8, 512)
     for scope in ("kda_norm", "kda/qkv_proj", "kda/conv", "kda/gates",
                   "kda/delta", "kda/out_norm", "kda/out_proj", "qkv/q_proj",
                   "qkv/kv_down", "qkv/kv_up", "qkv/assemble", "moe/router",

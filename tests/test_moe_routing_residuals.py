@@ -2,8 +2,11 @@
 (`ops/moe.ROUTING_RESIDUALS`, saved by `Transformer._remat`'s one policy):
 the router's logits, the chosen experts and their scores, `keep` and the
 sort's permutations and counts, so the gradient's program holds the f32
-router product, each `top_k`, the gather of the chosen scores and each
-`sort` once an expert layer where `remat_policy="full"` holds each twice.
+router product, each `top_k` and each `sort` once an expert layer where
+`remat_policy="full"` holds each twice. The chosen scores are read with no
+gather from the `[N, E]` scores (PR 52: `top_k`'s own values, or compares
+against the chosen ids where a bias or a group limit made the choice), and
+only the router without either still scatters their cotangents.
 Read off the jaxpr of `jax.grad(Transformer.loss)` on the CPU, one tiny
 configuration of each kind of the benchmark's expert cells; a dense
 configuration names nothing and lowers to the same text with and without
@@ -111,7 +114,7 @@ def readings(jaxpr, cfg):
     """How often the program holds each piece of the routing."""
     n_tokens = BATCH["tokens"].shape[0] * SEQ
     counts = {"sort": 0, "top_k": 0, "router_product": 0, "score_gather": 0,
-              "named": 0}
+              "score_scatter": 0, "named": 0}
     scores = (n_tokens, cfg.moe_experts)
     for eqn in equations(jaxpr):
         name = eqn.primitive.name
@@ -119,6 +122,9 @@ def readings(jaxpr, cfg):
             counts[name] += 1
         elif name == "gather" and eqn.invars[0].aval.shape == scores:
             counts["score_gather"] += 1     # the chosen scores, [N, k]
+        elif name.startswith("scatter") and \
+                eqn.outvars[0].aval.shape == scores:
+            counts["score_scatter"] += 1    # their cotangents, into [N, E]
         elif is_router_product(eqn, n_tokens, cfg):
             counts["router_product"] += 1
         elif name == "name" and eqn.params["name"] == moe.ROUTING_RESIDUALS:
@@ -156,17 +162,23 @@ def programs():
 # a scan's body is one expert layer of its run: pieces a layer in the
 # forward's program, and how many scans hold an expert layer
 PIECES = {
-    # without a choice bias the chosen scores are `top_k`'s own values
+    # without a choice bias the chosen scores are `top_k`'s own values,
+    # and `_scores_at`'s backward the scatter-add of their cotangents
     "every_expert_softmax": {"sort": 2, "top_k": 1, "router_product": 1,
-                             "score_gather": 0, "named": 6, "scans": 1},
+                             "score_gather": 0, "score_scatter": 1,
+                             "named": 6, "scans": 1},
+    # with one, compares against the chosen ids both ways
     "held_share_sigmoid": {"sort": 2, "top_k": 1, "router_product": 1,
-                           "score_gather": 1, "named": 6, "scans": 1},
+                           "score_gather": 0, "score_scatter": 0,
+                           "named": 6, "scans": 1},
     "held_below_picked_latent": {"sort": 2, "top_k": 2, "router_product": 1,
-                                 "score_gather": 1, "named": 7, "scans": 2},
+                                 "score_gather": 0, "score_scatter": 0,
+                                 "named": 7, "scans": 2},
     # the group limit's two `top_k` are the forward's alone: the kept
     # `top_e` is all the backward reads of the choice
     "group_limited_kda": {"sort": 2, "top_k": 3, "router_product": 1,
-                          "score_gather": 1, "named": 6, "scans": 3},
+                          "score_gather": 0, "score_scatter": 0,
+                          "named": 6, "scans": 3},
 }
 
 
@@ -175,9 +187,9 @@ PIECES = {
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_the_gradient_runs_the_routing_once_a_layer(programs, kind, reading):
     """The sort and the `argsort`, `route`'s `top_k` and `keep`'s, the f32
-    product, the gather of the chosen scores: once an expert layer under
-    the default policy, as in the forward alone; twice under "full",
-    whose backward makes them again."""
+    product: once an expert layer under the default policy, as in the
+    forward alone; twice under "full", whose backward makes them again.
+    A gather of the chosen scores from `[N, E]`: in none."""
     assert KINDS[kind].remat_policy == "attention"     # the default
     seen = programs(kind)
     want = PIECES[kind][reading] * PIECES[kind]["scans"]
@@ -187,8 +199,20 @@ def test_the_gradient_runs_the_routing_once_a_layer(programs, kind, reading):
 
 
 @pytest.mark.parametrize("kind", list(KINDS))
+def test_the_chosen_cotangents_scatter_only_without_a_bias(programs, kind):
+    """A scatter-add into `[N, E]` is the backward's alone, once a layer
+    whatever the policy, and only where `_scores_at` handed on `top_k`'s
+    values: a biased or group-limited router's derivative is compares."""
+    seen = programs(kind)
+    want = PIECES[kind]["score_scatter"] * PIECES[kind]["scans"]
+    assert seen["forward"]["score_scatter"] == 0
+    assert seen["default"]["score_scatter"] == want
+    assert seen["full"]["score_scatter"] == want
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
 def test_what_is_named_is_what_the_layer_has(programs, kind):
-    """The logits, `top_e` and the scores gathered there, the two
+    """The logits, `top_e` and the scores read at them, the two
     permutations and the counts; `keep` only where fewer experts are held
     than a token picks."""
     want = PIECES[kind]["named"] * PIECES[kind]["scans"]
