@@ -1,13 +1,17 @@
 """Transformer configurations.
 
 One `TransformerConfig` describes every decoder `models/transformer.py`
-runs: softmax attention as MHA / GQA (optionally with QK-norm) or latent
+runs: softmax attention as MHA / GQA (optionally with QK-norm, over the
+whole projection or a head at a time) or latent
 (compressed key/value) attention with its own head widths; a dense SwiGLU
 FFN, or routed experts (a softmax router with the load-balancing loss, or
 a sigmoid router with a choice bias that is a buffer and a scaling
 factor), optionally behind leading dense layers of their own width, with
 shared experts every token passes, and with only a contiguous share of
-the experts held here (one chip of an expert-parallel layer). A hybrid
+the experts held here (one chip of an expert-parallel layer). Trained on
+next-token cross-entropy over one causal stream, or (`block_length`) as a
+block-diffusion model: a masked-token loss over a doubled stream under
+the block-diffusion mask (models/diffusion.py). A hybrid
 (`layer_pattern`) is a published sequence of single sublayers, each a
 Mamba-2 mixer (`M`), an attention block (`*`) or an expert layer (`E`)
 alone; its experts may live in a latent (`moe_latent`) and be plain
@@ -52,8 +56,12 @@ class TransformerConfig:
     tie_embeddings: bool = False
     # RMSNorm with a learned gain over the whole q and the whole k
     # projection (all heads together), before the split into heads and
-    # before RoPE: OLMoE's / OLMo-2's QK-norm.
+    # before RoPE: OLMoE's / OLMo-2's QK-norm. With qk_norm_per_head each
+    # query and each key head is normed over its own head_dim with one
+    # gain a side that its heads share (Qwen3's `q_norm` / `k_norm`);
+    # latent attention's QK-norm is always that one.
     qk_norm: bool = False
+    qk_norm_per_head: bool = False
     # "auto" | "dense" | "flash" | "ring" | "ulysses". auto = pallas
     # flash kernel on TPU when the seq axis is unsharded (ring when it
     # is), dense elsewhere; dense = materialized-scores attention with
@@ -215,6 +223,19 @@ class TransformerConfig:
     # taken among the experts of its moe_topk_groups best groups. 1: none
     moe_groups: int = 1
     moe_topk_groups: int = 1
+    # Block diffusion (arXiv 2503.09573, 2510.06303; models/diffusion.py).
+    # 0: next-token training under a causal mask. Above 0 the sequence is
+    # blocks of block_length tokens, attention is causal by block (a block
+    # sees itself in both directions, `ops/attention.block_visible`), and
+    # `Transformer.loss` is the masked-token loss: each block noised at
+    # its own time t ~ U(diffusion_t_min, 1) (a token replaced by
+    # mask_token_id with probability t), the stream the noised copy and
+    # the clean one behind it, the cross-entropy over the masked
+    # positions weighted by 1/t, no shift by one. mask_token_id -1: the
+    # vocabulary's last row.
+    block_length: int = 0
+    mask_token_id: int = -1
+    diffusion_t_min: float = 1e-3
 
     def __post_init__(self):
         if self.norm not in ("rms", "layernorm"):
@@ -278,16 +299,32 @@ class TransformerConfig:
             if first < 0 or first + held > self.moe_experts:
                 raise ValueError(
                     f"experts {first}..{first + held} of {self.moe_experts}")
-            if held < self.moe_experts and self.moe_scoring == "softmax":
+            if held < self.moe_experts and self.moe_scoring == "softmax" \
+                    and self.moe_aux_coeff:
                 raise ValueError("the softmax router's aux loss needs every "
-                                 "expert's count: a held share runs the "
-                                 "sigmoid router")
+                                 "expert's count, the other chips' too: a "
+                                 "held share runs it with moe_aux_coeff 0, "
+                                 "or the sigmoid router")
             if not 0 <= self.moe_dense_layers < self.n_layers:
                 raise ValueError("moe_dense_layers must leave an expert "
                                  "layer")
         elif self.moe_dense_layers or self.moe_shared_experts \
                 or self.moe_experts_held:
             raise ValueError("moe_* sizes without moe_experts")
+        if self.qk_norm_per_head and not self.qk_norm:
+            raise ValueError("qk_norm_per_head says how qk_norm norms")
+        if self.block_length < 0 or not 0 < self.diffusion_t_min < 1 \
+                or not -1 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(
+                "block diffusion: block_length 0 or above, diffusion_t_min "
+                "inside (0, 1), mask_token_id a row of the vocabulary")
+        if self.block_length and (
+                self.attention_impl in ("ring", "ulysses")
+                or self.layer_pattern or self.attn_window):
+            raise ValueError(
+                "the block-diffusion mask runs on dense and flash "
+                "attention in the homogeneous layer: not under ring or "
+                "ulysses, a window or a layer_pattern")
 
     def _check_shared_tensors(self):
         """The lower-case kinds' sizes, and every reader of a tensor that
@@ -348,6 +385,11 @@ class TransformerConfig:
         return self.d_ff or 4 * self.d_model
 
     @property
+    def mask_token(self) -> int:
+        """The id a noised token is replaced by."""
+        return self.mask_token_id % self.vocab_size
+
+    @property
     def held_experts(self) -> int:
         return self.moe_experts_held or self.moe_experts
 
@@ -390,8 +432,9 @@ class TransformerConfig:
                     + nh * self.v_dim * d)
         else:
             attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
-        if self.qk_norm:   # latent: one gain a side over a head
-            attn += 2 * hd if self.kv_lora_rank else nh * hd + nkv * hd
+        if self.qk_norm:   # a head at a time: one gain a side
+            attn += 2 * hd if self.kv_lora_rank or self.qk_norm_per_head \
+                else nh * hd + nkv * hd
         if self.layer_pattern:
             return self._pattern_params(attn)
         norms = 2 * d

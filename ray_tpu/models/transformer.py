@@ -33,7 +33,9 @@ operator's `ray_tpu profile --device` read them off each op's op_name):
 `attn_norm`, `qkv` (projections, QK-norm and RoPE; with latent attention
 `qkv/q_down`, `qkv/kv_down`, `qkv/q_up`, `qkv/kv_up`, `qkv/assemble`
 inside it, `qkv/q_proj` in place of the first and third where the queries
-have no latent), `attention` (kernels, GQA repeat, layout transposes),
+have no latent; `qkv/qk_norm` around GQA's QK-norm), `attention` (kernels,
+GQA repeat, layout transposes; `attention/block_diffusion` around the
+call under the block-diffusion mask),
 `attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (differential
 attention: `attention/window`, `attention/full` or `attention/cross`
 around the kernel calls, `attention/diff` around lambda, the subtraction,
@@ -46,7 +48,11 @@ gated memory unit `gmu_norm` and `gmu/in_proj`, `gmu/gate`,
 `gmu/out_proj`, in Kimi Delta Attention `kda_norm` and `kda/qkv_proj`,
 `kda/conv`, `kda/gates`, `kda/delta`, `kda/out_norm`, `kda/out_proj`
 (ops/kda.py), `final_norm`, `head`, `loss` (the vocab head and the
-cross-entropy: models/head.py); the train step adds `optimizer`
+cross-entropy: models/head.py); a block-diffusion model's loss adds
+`diffusion/noise` (the draws, the replacement, the weights and their
+counts: models/diffusion.py) and `diffusion/stream` (the doubled
+stream's concatenation and positions, the split before the final norm);
+the train step adds `optimizer`
 (parallel/train_step.py). Scopes are metadata only. Forward, backward
 and recomputation need none: JAX wraps the path in `jvp(...)`,
 `transpose(jvp(...))` and remat's `rematted_computation`.
@@ -100,6 +106,17 @@ def _rmsnorm(x, w, eps):
     scale = jnp.reciprocal(
         jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps))
     return (x32 * scale).astype(x.dtype) * w.astype(x.dtype)
+
+
+def _qk_norm(x, gain, eps):
+    """QK-norm of q or k `[B, T, H, D]`, as the gain's width says: one
+    head's (`[D]`: each head normed over its own columns, the heads
+    sharing the gain) or the whole projection's (`[H * D]`: all heads
+    normed together)."""
+    if gain.shape[-1] == x.shape[-1]:
+        return _rmsnorm(x, gain, eps)
+    return _rmsnorm(x.reshape(x.shape[:2] + (-1,)), gain,
+                    eps).reshape(x.shape)
 
 
 def _layernorm(x, w, b, eps):
@@ -189,8 +206,8 @@ class Transformer:
                     [norm_init(d ** -0.5, keys[2], (l, d, nkv, hd)),
                      norm_init(d ** -0.5, keys[3], (l, d, nkv, hd))],
                     axis=2)  # (l, d, 2, nkv, hd)
-            if cfg.qk_norm and cfg.kv_lora_rank:
-                # latent attention's: one gain a side over a head
+            if cfg.qk_norm and (cfg.kv_lora_rank or cfg.qk_norm_per_head):
+                # one gain a side over a head (latent attention's always)
                 layers["q_norm"] = jnp.ones((l, hd), pdt)
                 layers["k_norm"] = jnp.ones((l, hd), pdt)
             elif cfg.qk_norm:
@@ -648,7 +665,7 @@ class Transformer:
     @staticmethod
     def _stack(layers, x, cfg: TransformerConfig, *, mesh,
                rules: ShardingRules, positions=None, kinds=None,
-               shared=None):
+               shared=None, noised: int = 0):
         """x [B, T, d] through a run of stacked layers (leaves
         [n, ...]: all of them in hidden(), one stage's in pipeline_loss())
         -> (x, routing, shared), `routing` the layers' stacked MoE records
@@ -663,7 +680,10 @@ class Transformer:
         the layers that read them, and as inputs of a layer under
         `_remat`, which does not compute them again. A run with a layer
         that makes one runs once (`TransformerConfig` sees to it) and
-        without a scan; the returned `shared` has what it made."""
+        without a scan; the returned `shared` has what it made. `noised`:
+        the leading positions of x that are the noised copy of the ones
+        behind them (a block-diffusion model's doubled stream; 0: a plain
+        stream)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -676,7 +696,8 @@ class Transformer:
                 cos, sin = _rope_tables(positions, cfg.rope_dim,
                                         cfg.rope_theta)
         layer_fn = Transformer._make_layer_fn(cfg, mesh, rules, cos, sin,
-                                              seq_len=x.shape[1])
+                                              seq_len=x.shape[1],
+                                              noised=noised)
         shared = dict(shared or {})
 
         @functools.cache
@@ -712,7 +733,7 @@ class Transformer:
     @staticmethod
     def hidden(params, tokens, cfg: TransformerConfig, *,
                mesh=None, rules: Optional[ShardingRules] = None,
-               positions=None, with_aux: bool = False):
+               positions=None, with_aux: bool = False, noised: int = 0):
         """tokens [B, T] int32 -> final-norm hidden states [B, T, d]
         (compute dtype) — apply() stopping before the lm head, so the
         loss can chunk head+softmax over T (the f32 [B,T,vocab] logits
@@ -723,7 +744,14 @@ class Transformer:
         `tokens_per_expert [layers, held]`, `slots_elsewhere [layers]`,
         `router_prob [layers, E]`, `dropped [layers]`, `rows_bounded
         [layers]`; None for dense FFN configs). The sigmoid router has no
-        aux loss: 0.
+        aux loss: 0, as a softmax router over a held share has (the loss
+        needs the other chips' counts).
+
+        A block-diffusion model (`cfg.block_length`) attends causally by
+        block. `noised` = L > 0: tokens is its doubled stream, L noised
+        positions then their L clean copies (`positions` 0..L-1 twice),
+        under the block-diffusion mask; only the noised half is read, so
+        the final norm runs over it alone: -> [B, L, d].
 
         When `mesh` is provided and cfg.attention_impl is ring/ulysses, the
         attention op runs inside shard_map over the "seq" axis; everything
@@ -737,7 +765,7 @@ class Transformer:
         if "dense_layers" in params:   # the leading run with a dense FFN
             x = Transformer._stack(
                 params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
-                positions=positions)[0]
+                positions=positions, noised=noised)[0]
         if "runs" in params:
             records = []   # per run and expert sublayer: [repeats, ...]
             shared = {}    # the tensors that cross layers (`_stack`)
@@ -754,9 +782,10 @@ class Transformer:
         else:
             x, routing, _ = Transformer._stack(
                 params["layers"], x, cfg, mesh=mesh, rules=rules,
-                positions=positions)
+                positions=positions, noised=noised)
         aux_total = jnp.zeros((), jnp.float32)
-        if cfg.moe_experts and cfg.moe_scoring == "softmax":
+        if cfg.moe_experts and cfg.moe_scoring == "softmax" \
+                and cfg.held_experts == cfg.moe_experts:
             # not a sum of per-layer terms: the published loss takes its
             # two means over the tokens of all layers together
             from ray_tpu.ops.moe import load_balancing_loss
@@ -765,6 +794,9 @@ class Transformer:
                     routing["tokens_per_expert"], routing["router_prob"],
                     min(cfg.moe_top_k, cfg.moe_experts))
 
+        if noised:   # nothing reads the clean copy's last layer
+            with jax.named_scope("diffusion/stream"):
+                x = x[:, :noised]
         with jax.named_scope("final_norm"):
             out = _norm(x, params, "final_norm", cfg.norm_eps)
         if with_aux:
@@ -773,7 +805,8 @@ class Transformer:
 
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
-                       rules: ShardingRules, cos, sin, seq_len: int):
+                       rules: ShardingRules, cos, sin, seq_len: int,
+                       noised: int = 0):
         """Build layer(x, lp, shared, kind) -> (x, routing, made), the body
         `_stack` scans (or, in a block, one of its sublayers): what lp's
         leaves say, attention under `attn_norm`, a mixer under `ssm_norm`,
@@ -791,7 +824,7 @@ class Transformer:
         constrain = functools.partial(
             with_logical_constraint, mesh=mesh, rules=rules)
         attn_fn = Transformer._make_attention(cfg, mesh, rules,
-                                              seq_len=seq_len)
+                                              seq_len=seq_len, noised=noised)
         window_fn = Transformer._make_attention(
             cfg, mesh, rules, seq_len=seq_len, window=cfg.attn_window) \
             if cfg.attn_window else None
@@ -842,8 +875,8 @@ class Transformer:
                     # with the shared rotary columns it is given; then RoPE
                     k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
                         k_rope, q.shape[:3] + (cfg.rope_dim,))], axis=-1)
-                    q = roped(_rmsnorm(q, lp["q_norm"], cfg.norm_eps))
-                    k = roped(_rmsnorm(k, lp["k_norm"], cfg.norm_eps))
+                    q = roped(_qk_norm(q, lp["q_norm"], cfg.norm_eps))
+                    k = roped(_qk_norm(k, lp["k_norm"], cfg.norm_eps))
                 else:
                     q = roped(q)
                     k_rope = jnp.broadcast_to(_rope(k_rope, cos, sin),
@@ -902,10 +935,9 @@ class Transformer:
                     else:   # cross-attention: the layer f's, as they are
                         k, v = shared["k"], shared["v"]
                 if cfg.qk_norm and not cfg.kv_lora_rank:
-                    q = _rmsnorm(q.reshape(q.shape[:2] + (-1,)),
-                                 lp["q_norm"], cfg.norm_eps).reshape(q.shape)
-                    k = _rmsnorm(k.reshape(k.shape[:2] + (-1,)),
-                                 lp["k_norm"], cfg.norm_eps).reshape(k.shape)
+                    with jax.named_scope("qk_norm"):
+                        q = _qk_norm(q, lp["q_norm"], cfg.norm_eps)
+                        k = _qk_norm(k, lp["k_norm"], cfg.norm_eps)
                 if not cfg.kv_lora_rank:
                     if cfg.rope:
                         q, k = _rope(q, cos, sin), _rope(k, cos, sin)
@@ -920,7 +952,8 @@ class Transformer:
                 o = differential(q, k, v, lp, fn, {
                     "w": "window", "f": "full", "c": "cross"}[kind])
             else:
-                with jax.named_scope("attention"):
+                with jax.named_scope("attention/block_diffusion"
+                                     if cfg.block_length else "attention"):
                     o = attn_fn(q, k, v, scale)
             with jax.named_scope("attn_out"):
                 o = constrain(o, ("batch", "seq", "heads", "head_dim"))
@@ -1102,6 +1135,10 @@ class Transformer:
         if cfg.attention_impl in ("ring", "ulysses"):
             raise ValueError("pipeline stages need stage-local attention "
                              "(dense/flash), not ring/ulysses")
+        if cfg.block_length:
+            raise ValueError("pipeline_loss is next-token training: a "
+                             "block-diffusion model (block_length) trains "
+                             "through Transformer.loss")
         if cfg.moe_experts or cfg.layer_pattern:
             raise ValueError(
                 "pipeline_loss takes one homogeneous run of layers and "
@@ -1172,10 +1209,14 @@ class Transformer:
 
     @staticmethod
     def _make_attention(cfg: TransformerConfig, mesh, rules: ShardingRules,
-                        seq_len: Optional[int] = None, window: int = 0):
+                        seq_len: Optional[int] = None, window: int = 0,
+                        noised: int = 0):
         """attention(q, k, v, scale) under a causal mask, with `window` > 0
-        one that also ends `window` keys back; dense and flash take it,
-        ring and ulysses do not."""
+        one that also ends `window` keys back, with `cfg.block_length` the
+        block-diffusion mask (over a doubled stream of `noised` noised
+        positions and their clean copies, or causal by block over a plain
+        one); dense and flash take all three, ring and ulysses the first
+        alone."""
         import jax
         from jax.sharding import PartitionSpec as P
 
@@ -1231,6 +1272,9 @@ class Transformer:
         if impl in ("dense", "flash") or seq_unsharded:
             local = flash_attention if impl == "flash" else dense_attention
             body = functools.partial(local, causal=True, window=window)
+            if cfg.block_length:
+                body = functools.partial(body, noised=noised,
+                                         block_length=cfg.block_length)
             if impl == "flash" and mesh is not None:
                 # pallas kernels don't GSPMD-partition; run per-shard under
                 # shard_map with batch/heads sharded as the constraints say.
@@ -1244,6 +1288,9 @@ class Transformer:
 
         if window:
             raise ValueError("ring and ulysses attention take no window")
+        if cfg.block_length:
+            raise ValueError("ring and ulysses attention take no "
+                             "block-diffusion mask (block_length)")
 
         # Heads stay sharded over the tensor axis inside the shard_map —
         # SP composes with TP instead of all-gathering Q/K/V heads.
@@ -1271,7 +1318,9 @@ class Transformer:
         """Next-token cross-entropy. batch = {"tokens": [B,T+1] or
         ("tokens","targets") pair}, optionally a "mask" [B,T]; returns
         scalar mean loss (f32), for a MoE config plus `moe_aux_coeff` x the
-        load-balancing loss.
+        load-balancing loss. A block-diffusion model (`cfg.block_length`)
+        has another objective, the masked-token loss of
+        `_block_diffusion_loss`, over the batch `diffusion.noised` makes.
 
         with_metrics=True returns (loss, metrics), the pair
         `make_train_step` takes: its step's metrics then carry, from the
@@ -1290,6 +1339,9 @@ class Transformer:
         import jax.numpy as jnp
 
         rules = rules or ShardingRules()
+        if cfg.block_length:
+            return Transformer._block_diffusion_loss(
+                params, batch, cfg, mesh, rules, with_metrics)
         tokens, targets = Transformer._tokens_and_targets(batch)
         mask = batch.get("mask")
         x, aux, routing = Transformer.hidden(
@@ -1303,6 +1355,82 @@ class Transformer:
             loss_val = loss_val + cfg.moe_aux_coeff * aux
         return Transformer._loss_out(loss_val, aux, routing, cfg,
                                      with_metrics)
+
+    @staticmethod
+    def block_diffusion_hidden(params, batch, cfg: TransformerConfig, *,
+                               mesh=None,
+                               rules: Optional[ShardingRules] = None):
+        """A block-diffusion model's final-norm hidden states at the L
+        noised positions, [B, L, d], with `hidden`'s aux loss and routing
+        and the noised batch. batch = what `diffusion.noised` makes
+        ({"tokens": the noised copy [B, L], "targets": the clean tokens,
+        "mask": the weights), or what it takes ({"tokens", "noise_key"}:
+        noised here). The stream is the noised copy and the clean one
+        behind it, 2L positions with the position ids 0..L-1 twice, under
+        the block-diffusion mask (`ops/attention.block_visible`)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import diffusion
+
+        if "noise_key" in batch:
+            batch = diffusion.noised(batch, cfg)
+        if not cfg.block_length or not {"tokens", "targets", "mask"} <= set(
+                batch):
+            raise ValueError(
+                "a block-diffusion model (block_length) reads the batch "
+                "diffusion.noised makes (tokens, targets, mask) or takes "
+                f"(tokens, noise_key), got {sorted(batch)}")
+        clean = batch["targets"]
+        length = clean.shape[1]
+        if length % cfg.block_length:
+            raise ValueError(
+                f"block_length {cfg.block_length} does not divide "
+                f"sequences of {length} tokens")
+        with jax.named_scope("diffusion/stream"):
+            stream = jnp.concatenate([batch["tokens"], clean], axis=1)
+            positions = jnp.tile(jnp.arange(length, dtype=jnp.int32),
+                                 2)[None, :]
+        return Transformer.hidden(
+            params, stream, cfg, mesh=mesh, rules=rules or ShardingRules(),
+            positions=positions, with_aux=True, noised=length) + (batch,)
+
+    @staticmethod
+    def _block_diffusion_loss(params, batch, cfg: TransformerConfig, mesh,
+                              rules: ShardingRules, with_metrics: bool):
+        """The masked-token loss of a block-diffusion model
+        (models/diffusion.py) over `block_diffusion_hidden`'s read
+        positions: a masked position's own logits predict its token (no
+        shift by one), and the loss is the cross-entropy over the masked
+        positions, each weighted by 1/t of its block, divided by B * L:
+        the mean over the sequences of each one's bound. Metrics besides
+        the MoE's: `diffusion_masked_tokens` (int32: masked positions of
+        the batch) and `diffusion_weight_sum` (f32: the sum of their
+        weights)."""
+        import jax
+        import jax.numpy as jnp
+
+        x, aux, routing, batch = Transformer.block_diffusion_hidden(
+            params, batch, cfg, mesh=mesh, rules=rules)
+        clean, weights = batch["targets"], batch["mask"]
+        total = head.nll_sum(head.weight(params, cfg), x, clean, cfg,
+                             mask=weights, mesh=mesh, rules=rules)
+        with jax.named_scope("loss"):
+            loss_val = total / clean.size
+        if cfg.moe_aux_coeff and cfg.moe_experts \
+                and cfg.moe_scoring == "softmax":
+            loss_val = loss_val + cfg.moe_aux_coeff * aux
+        if not with_metrics:
+            return loss_val
+        with jax.named_scope("diffusion/noise"):
+            counts = {
+                "diffusion_masked_tokens": jnp.sum(weights > 0,
+                                                   dtype=jnp.int32),
+                "diffusion_weight_sum": jnp.sum(weights,
+                                                dtype=jnp.float32)}
+        loss_val, metrics = Transformer._loss_out(loss_val, aux, routing,
+                                                  cfg, True)
+        return loss_val, dict(metrics, **counts)
 
     @staticmethod
     def _loss_out(loss_val, aux, routing, cfg: TransformerConfig,

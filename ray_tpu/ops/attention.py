@@ -10,10 +10,21 @@ attention (`jax.experimental.pallas.ops.tpu.splash_attention`) with a
 causal mask the kernel knows, K and V kept at their kv-head width, and a
 block geometry derived from the shape at trace time. One kernel library,
 no option: `dense_attention` is the reference the tests hold it to.
+
+Three masks, the same on both paths: causal, a causal window, and the
+block-diffusion mask (`block_length` > 0; `block_visible` is its
+predicate), the one that is not causal in the stream's order. Over a plain
+stream it is causal by block: a position sees every block up to its own,
+its own in both directions. Over a doubled stream (`noised` = L > 0: L
+noised positions, then their L clean copies; arXiv 2503.09573) a noised
+block sees itself in both directions and the clean blocks strictly before
+it, a clean block the clean blocks up to itself, and nothing clean sees
+anything noised: L^2 + L*block_length pairs of the 4 L^2.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 
@@ -59,21 +70,63 @@ def gqa_pv(p, v):
     return o.reshape(b, tq, hq, d)
 
 
+def block_visible(q_ids, kv_ids, block_length: int, noised: int = 0):
+    """The block-diffusion mask as a predicate of position ids (numpy or
+    jax integer arrays that broadcast): whether query `q_ids` sees key
+    `kv_ids`. The first `noised` positions of the stream are the noised
+    copy of the ones behind them (0: a plain stream, every position
+    clean); a position's block is its place in its own copy over
+    `block_length`.
+
+        noised query, noised key   the same block
+        noised query, clean key    a block strictly before the query's
+        clean query, clean key     a block up to the query's own
+        clean query, noised key    never
+    """
+    q_clean, kv_clean = q_ids >= noised, kv_ids >= noised
+    q_block = (q_ids - noised * q_clean) // block_length
+    kv_block = (kv_ids - noised * kv_clean) // block_length
+    return (q_clean & kv_clean & (kv_block <= q_block)) \
+        | (~q_clean & kv_clean & (kv_block < q_block)) \
+        | (~q_clean & ~kv_clean & (kv_block == q_block))
+
+
+def _check_block_mask(t: int, block_length: int, noised: int, window: int):
+    if block_length < 0 or noised and not block_length:
+        raise ValueError("a noised copy needs a block_length above 0")
+    if block_length and window:
+        raise ValueError("the block-diffusion mask takes no window")
+    if noised and (t != 2 * noised or noised % block_length):
+        raise ValueError(
+            f"a doubled stream is {noised} noised positions and their "
+            f"{noised} clean copies in whole blocks of {block_length}, got "
+            f"{t} positions")
+
+
 def dense_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None, window: int = 0):
+                    scale: Optional[float] = None, window: int = 0,
+                    block_length: int = 0, noised: int = 0):
     """Multi-head / grouped-query attention on [batch, seq, heads,
     head_dim] arrays; k/v may carry fewer (kv) heads than q, and v
     another width than k. `window` > 0 (with `causal`): query i sees the
-    keys j with i - j < window."""
+    keys j with i - j < window. `block_length` > 0: the block-diffusion
+    mask (`block_visible`) in place of the causal one, over a doubled
+    stream where `noised` > 0."""
     import jax
     import jax.numpy as jnp
 
     if window and not causal:
         raise ValueError("a window is a causal window")
+    _check_block_mask(q.shape[1], block_length, noised, window)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s = gqa_scores(q, k, scale)
-    if causal:
+    if block_length:
+        mask = block_visible(jnp.arange(q.shape[1])[:, None],
+                             jnp.arange(k.shape[1])[None, :], block_length,
+                             noised)
+        s = jnp.where(mask[None, None], s, -jnp.inf)
+    elif causal:
         tq, tk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((tq, tk), bool))
         if window:
@@ -125,8 +178,61 @@ def _splash_block_sizes(t: int, head_dim: int):
 FLASH_RESIDUALS = "flash_residuals"
 
 
+@functools.lru_cache(maxsize=None)
+def _block_diffusion_mask(t: int, block_length: int, noised: int):
+    """`block_visible` over `t` positions as a splash mask the kernel
+    computes from the positions' indices: no `[t, t]` array on the host
+    or the device (268 MB of booleans at 16,384), a block's
+    emptiness read off the predicate over that block alone."""
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as mask_lib)
+
+    class BlockDiffusionMask(mask_lib._ComputableMask):
+        def __init__(self):
+            self.key = (t, block_length, noised)
+            super().__init__(
+                shape=(t, t), shard_count=1,
+                mask_function=lambda q_ids, kv_ids: block_visible(
+                    q_ids, kv_ids, block_length, noised))
+
+        def __eq__(self, other):
+            return isinstance(other, type(self)) and self.key == other.key \
+                and np.array_equal(self.q_sequence, other.q_sequence)
+
+        def __hash__(self):
+            return hash((type(self).__name__, self.key))
+
+    return BlockDiffusionMask()
+
+
+@functools.lru_cache(maxsize=None)
+def block_table(t: int, head_dim: int, block_length: int, noised: int = 0):
+    """What the block-diffusion mask leaves of `flash_attention`'s grid at
+    this shape: the kernel blocks (`_splash_block_sizes`' q x kv) that
+    hold a visible pair, how many of those hold a hidden one too (the
+    partial blocks, computed whole and masked), a block's area in pairs,
+    and the pairs the mask needs. The same reading of the mask the
+    kernel's own table takes, block by block."""
+    sizes = _splash_block_sizes(t, head_dim)
+    mask = _block_diffusion_mask(t, block_length, noised)
+    non_empty = partial = pairs = 0
+    for qs in range(0, t, sizes.block_q):
+        for ks in range(0, t, sizes.block_kv):
+            seen = int(mask[slice(qs, qs + sizes.block_q),
+                            slice(ks, ks + sizes.block_kv)].sum())
+            pairs += seen
+            non_empty += seen > 0
+            partial += 0 < seen < sizes.block_q * sizes.block_kv
+    return {"blocks": (t // sizes.block_q) * (t // sizes.block_kv),
+            "non_empty": non_empty, "partial": partial,
+            "block_pairs": sizes.block_q * sizes.block_kv,
+            "pairs_needed": pairs}
+
+
 def _splash_attention(q, k, v, *, causal: bool, scale: float,
-                      window: int = 0, interpret: bool = False):
+                      window: int = 0, block_length: int = 0,
+                      noised: int = 0, interpret: bool = False):
     """`flash_attention`'s body; `interpret` runs the kernels in pallas
     interpret mode, which is how the CPU tests read their numerics."""
     import jax
@@ -147,7 +253,10 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
     # stack that calls this is one lax.scan
     if window and not causal:
         raise ValueError("a window is a causal window")
-    if window:   # i - j < window and j <= i; blocks outside are skipped
+    _check_block_mask(t, block_length, noised, window)
+    if block_length:   # computed from indices; empty blocks are skipped
+        head_mask = _block_diffusion_mask(t, block_length, noised)
+    elif window:   # i - j < window and j <= i; blocks outside are skipped
         head_mask = LocalMask((t, t), (window - 1, 0), 0)
     else:
         head_mask = (CausalMask if causal else FullMask)((t, t))
@@ -166,7 +275,8 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None, window: int = 0):
+                    scale: Optional[float] = None, window: int = 0,
+                    block_length: int = 0, noised: int = 0):
     """Fused attention on [batch, seq, heads, head_dim] (k, v may carry
     fewer heads, v another width than k): JAX's pallas splash attention
     kernels. O(T) memory (the
@@ -176,7 +286,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     skipped rather than computed and masked, K and V read at their own
     head count
     (no GQA repeat in HBM), one backward kernel for dQ, dK and dV. Block
-    sizes follow from (T, head_dim): `_splash_block_sizes`.
+    sizes follow from (T, head_dim): `_splash_block_sizes`. With
+    `block_length` > 0 the block-diffusion mask (`block_visible`; a
+    doubled stream where `noised` > 0), computed in the kernel from the
+    positions' indices: the blocks it leaves empty are skipped, those it
+    cuts are computed whole (`block_table` counts both).
 
     Raises ValueError for a shape the kernel cannot tile; off the TPU
     the pallas lowering itself refuses. There is no dense fallback:
@@ -185,4 +299,5 @@ def flash_attention(q, k, v, *, causal: bool = True,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return _splash_attention(q, k, v, causal=causal, scale=scale,
-                             window=window)
+                             window=window, block_length=block_length,
+                             noised=noised)
