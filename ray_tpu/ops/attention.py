@@ -20,6 +20,18 @@ noised positions, then their L clean copies; arXiv 2503.09573) a noised
 block sees itself in both directions and the clean blocks strictly before
 it, a clean block the clean blocks up to itself, and nothing clean sees
 anything noised: L^2 + L*block_length pairs of the 4 L^2.
+
+`block_visible` is that mask's definition: `dense_attention`'s predicate
+and the tests' oracle. The splash kernels get another form of it. The
+library calls a computed mask's function on every `[block_q,
+block_kv_compute]` tile of every block the kernels run, full blocks too
+(only a mask that comes as an array is exempt there), so each operation of
+the predicate is VPU time an element beside the exponential.
+`block_visible` is 44 of them with two divisions; the kernels' form
+(`_visible_from_bounds`) is a few compares on what `_query_bounds` worked
+out a query on the host and handed over as `q_sequence`, and
+`tests/test_block_mask_predicate.py` holds it to the definition pair for
+pair.
 """
 
 from __future__ import annotations
@@ -178,12 +190,53 @@ def _splash_block_sizes(t: int, head_dim: int):
 FLASH_RESIDUALS = "flash_residuals"
 
 
+_CLEAN = -2 ** 31   # int32's sign bit
+
+
+def _query_bounds(t: int, block_length: int, noised: int):
+    """What each of `t` queries sees under `block_visible`, one int32 a
+    query (numpy, made once a shape on the host): everything in the
+    predicate that is the query's alone. A query sees at most two runs of
+    keys: the clean keys `[noised, noised + c)`, c = (b + 1) block_length
+    for a clean query of block b and b block_length for a noised one, and,
+    a noised query alone, its own noised block `[c, c + block_length)`:
+    for it c is that block's start too. So c is all a query carries, with
+    the sign bit set on a clean one (any `t` below 2^31 - block_length;
+    no field of bits to overrun)."""
+    import numpy as np
+
+    ids = np.arange(t)
+    clean = ids >= noised
+    c = ((ids - noised * clean) // block_length + clean) * block_length
+    return np.where(clean, c + _CLEAN, c).astype(np.int32)
+
+
+def _visible_from_bounds(bounds, kv_ids, block_length: int, noised: int):
+    """`block_visible` of the queries whose `_query_bounds` are `bounds`
+    and the keys `kv_ids` (numpy or jax integer arrays that broadcast):
+    the form the kernels evaluate, a tile at a time. An interval test is
+    one unsigned compare, `kv - lo` as uint32 below the width. The clean
+    run reads c under the sign bit; the noised block's test takes `bounds`
+    as it is: with the sign bit set `kv - bounds` is 2^31 off every key,
+    so a clean query fails it without a flag being looked at. No division,
+    remainder or multiplication, six operations an element."""
+    def u32(x):   # the same bits: no operation in the kernel
+        return x.astype("uint32")
+
+    return (u32(kv_ids - noised) < u32(bounds & ~_CLEAN)) \
+        | (u32(kv_ids - bounds) < block_length)
+
+
 @functools.lru_cache(maxsize=None)
 def _block_diffusion_mask(t: int, block_length: int, noised: int):
     """`block_visible` over `t` positions as a splash mask the kernel
-    computes from the positions' indices: no `[t, t]` array on the host
-    or the device (268 MB of booleans at 16,384), a block's
-    emptiness read off the predicate over that block alone."""
+    computes: no `[t, t]` array on the host or the device (268 MB of
+    booleans at 16,384), a block's emptiness read off the predicate over
+    that block alone. The library evaluates a computed mask on every block
+    it runs and not on the partial ones alone, so what it is handed is
+    `_visible_from_bounds` with `_query_bounds` as the mask's
+    `q_sequence`, which the library's block tables, `block_table` and the
+    kernel all read through the one `mask_function`."""
     import numpy as np
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask as mask_lib)
@@ -193,8 +246,9 @@ def _block_diffusion_mask(t: int, block_length: int, noised: int):
             self.key = (t, block_length, noised)
             super().__init__(
                 shape=(t, t), shard_count=1,
-                mask_function=lambda q_ids, kv_ids: block_visible(
-                    q_ids, kv_ids, block_length, noised))
+                mask_function=lambda bounds, kv_ids: _visible_from_bounds(
+                    bounds, kv_ids, block_length, noised))
+            self.q_sequence = _query_bounds(t, block_length, noised)
 
         def __eq__(self, other):
             return isinstance(other, type(self)) and self.key == other.key \
@@ -288,9 +342,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     (no GQA repeat in HBM), one backward kernel for dQ, dK and dV. Block
     sizes follow from (T, head_dim): `_splash_block_sizes`. With
     `block_length` > 0 the block-diffusion mask (`block_visible`; a
-    doubled stream where `noised` > 0), computed in the kernel from the
-    positions' indices: the blocks it leaves empty are skipped, those it
-    cuts are computed whole (`block_table` counts both).
+    doubled stream where `noised` > 0), computed in the kernel: the
+    blocks it leaves empty are skipped, those it cuts are computed whole
+    (`block_table` counts both), and the kernel evaluates the predicate
+    on every block it runs, a full one too (the library exempts none from
+    a computed mask). So it is handed each query's bounds as `q_sequence`
+    and a predicate of a few compares (`_block_diffusion_mask`), held to
+    `block_visible`, the definition.
 
     Raises ValueError for a shape the kernel cannot tile; off the TPU
     the pallas lowering itself refuses. There is no dense fallback:
