@@ -34,7 +34,7 @@ from _thread import get_ident as _get_ident
 from collections import OrderedDict
 from time import perf_counter, thread_time
 from time import time as _wall_time
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 try:  # RUSAGE_THREAD is Linux's; elsewhere thread_usage() has no ivcsw
     import resource as _resource
@@ -598,11 +598,25 @@ def dedupe_by_uid(snaps) -> List[Dict[str, Any]]:
     return unique
 
 
+# what holds sums that are not in the ring yet (the jax sentinel's
+# folded compile events) writes them as every snapshot begins
+_before_snapshot: List[Callable[[], None]] = []
+
+
+def before_snapshot(flush: Callable[[], None]) -> None:
+    _before_snapshot.append(flush)
+
+
 def snapshot() -> Dict[str, Any]:
     """This process's ring, with the clock pair the merger needs to map
     monotonic span times onto this process's wall clock (and from there,
     via the collector's RPC-midpoint offset estimate, onto one cluster
     timebase)."""
+    for flush in _before_snapshot:
+        try:
+            flush()
+        except Exception:  # noqa: BLE001 - the snapshot is best-effort
+            pass
     dropped = _RING.sync_dropped_metric()
     return {
         "proc_uid": PROC_UID,

@@ -19,6 +19,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
+from ray_tpu._private import spans
 from ray_tpu._private.ids import JobID
 
 logger = logging.getLogger(__name__)
@@ -195,10 +196,14 @@ def init(address: Optional[str] = None, *,
         raise RuntimeError("ray_tpu.init() called twice "
                            "(use ignore_reinit_error=True)")
 
+    # the driver's share of a job's set-up, down to the node's
+    # registration answered: one span in this process's ring
+    t_init = spans.begin()
     from ray_tpu._private.core_worker import CoreWorker
     from ray_tpu._private.rpc import RpcClient
 
     node = None
+    n_nodes = 1
     if address is None:
         node = HeadNode(resources=resources, num_cpus=num_cpus,
                         object_store_memory=object_store_memory,
@@ -215,6 +220,7 @@ def init(address: Optional[str] = None, *,
         nodes = [n for n in gcs.call("get_all_nodes") if n.alive]
         if not nodes:
             raise RuntimeError(f"no alive nodes at {address}")
+        n_nodes = len(nodes)
         head = next((n for n in nodes if n.is_head), nodes[0])
         nm_address = head.address
         store_address = head.store_address
@@ -242,6 +248,8 @@ def init(address: Optional[str] = None, *,
                             node_manager_address=nm_address, node=node,
                             namespace=namespace)
     atexit.register(shutdown)
+    spans.end("cluster.init", t_init, address=address or "local",
+              nodes=n_nodes)
     return _global_worker
 
 

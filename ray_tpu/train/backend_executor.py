@@ -77,8 +77,6 @@ class BackendExecutor:
         # re-form path — but heartbeats flow (and the gang_rank_wedged
         # probe watches them) for fixed gangs too.
         self._gang_uid: Optional[str] = None
-        self._sessions_span = None  # train.gang.sessions, begun in
-        # _init_sessions and finished in _start_sessions
         self._step_deadline = None
         if self._elastic:
             from ray_tpu.train.elastic import (MembershipWatch,
@@ -158,9 +156,8 @@ class BackendExecutor:
         self._contexts = self._build_contexts(self.worker_group)
         for ctx in self._contexts:
             ctx.gang_id = self._gang_uid
-        with spans.span("train.gang.visibility", **self._gang_attrs()):
-            if self._scaling.num_tpus_per_worker:
-                self._share_tpu_visibility(self.worker_group)
+        if self._scaling.num_tpus_per_worker:
+            self._share_tpu_visibility(self.worker_group)
         if self._watch is not None:
             self._watch.watch_nodes(list(self.worker_group.node_ids))
 
@@ -172,7 +169,10 @@ class BackendExecutor:
 
     def _mesh_init(self) -> None:
         """Backend process-group setup (jax.distributed over the gang;
-        _setup_worker, where a TPU worker first touches its chips)."""
+        _setup_worker, where a TPU worker first touches its chips). What
+        each worker does under this span is its own `train.worker.*`
+        spans (train/jax_backend.py); the remainder over the slowest
+        worker's is the RPC round and the actors' queues."""
         with spans.span("train.gang.backend", **self._gang_attrs()):
             self._backend.on_start(self.worker_group,
                                    self._backend_config)
@@ -244,8 +244,6 @@ class BackendExecutor:
         loop reloads/reshards model+optimizer state from the durable
         checkpoint it is handed)."""
         assert self._train_args is not None
-        self._sessions_span = spans.start_span(
-            "train.gang.sessions", **self._gang_attrs())
         self._backend.on_training_start(self.worker_group,
                                         self._backend_config)
         import ray_tpu
@@ -276,8 +274,6 @@ class BackendExecutor:
         import ray_tpu
         ray_tpu.get([w.start_training_session.remote()
                      for w in self.worker_group.workers], timeout=120)
-        spans.finish_span(self._sessions_span)
-        self._sessions_span = None
 
     def get_next_results(self, timeout: float = 600.0
                          ) -> Optional[List[TrainingResult]]:

@@ -14,8 +14,10 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import sys
 from typing import Optional, Type
 
+from ray_tpu._private import spans
 from ray_tpu.train.backend import Backend, BackendConfig
 from ray_tpu.train.worker_group import WorkerGroup
 
@@ -54,34 +56,65 @@ def _get_node_ip() -> str:
         s.close()
 
 
+def _import_jax(need_jax: bool, **attrs) -> None:
+    """The compile cache's placement and the process's first `import
+    jax` (the placement makes it where the environment names no
+    directory) as `train.worker.jax_import`; `cached` where a reused
+    pool worker had it."""
+    from ray_tpu._private.compile_cache import enable_compile_cache
+    with spans.span("train.worker.jax_import",
+                    cached="jax" in sys.modules, **attrs):
+        enable_compile_cache()
+        if need_jax:
+            import jax  # noqa: F401
+
+
 def _init_jax_distributed(coordinator_address: str, num_processes: int,
-                          process_id: int) -> None:
+                          process_id: int, gang: str = "") -> None:
     import os
 
+    _import_jax(True, rank=process_id, gang=gang)
     import jax
     if os.environ.get("JAX_PLATFORMS") == "cpu":
         # XLA's CPU backend refuses cross-process computations unless
         # collectives go through gloo — needed for the chip-free ladder
         # to run real multi-process gang collectives.
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    jax.distributed.initialize(
-        coordinator_address=coordinator_address,
-        num_processes=num_processes,
-        process_id=process_id)
+    with spans.span("train.worker.distributed_init", rank=process_id,
+                    gang=gang, processes=num_processes):
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id)
 
 
-def _setup_worker(num_tpus: int) -> None:
+def _setup_worker(num_tpus: int, rank: int = 0, gang: str = "") -> None:
     """Last step of gang set-up on every worker, before the train loop
     jits anything: place the compile cache, wait (bounded) for chips a
     predecessor still holds, and hold the worker to the chips its
     ScalingConfig asked for. TPU visibility env is applied to
     a live process, so a worker whose JAX was already pinned elsewhere
     (a reused pool worker, a missing libtpu) would otherwise train on
-    the CPU and nobody would notice."""
-    from ray_tpu._private.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    if not num_tpus:
-        return
+    the CPU and nobody would notice.
+
+    What the driver's one `train.gang.backend` span waits for is told
+    apart here, in the worker's own ring (which outlives the gang):
+    `train.worker.jax_import`, `.chip_wait`, `.tpu_start`, each with the
+    worker's `rank` and the formation's `gang`."""
+    _import_jax(bool(num_tpus), rank=rank, gang=gang)
+    if num_tpus:
+        _start_tpu_runtime(num_tpus, rank, gang)
+    if "jax" in sys.modules:
+        # from here on, before the loop's first jit, the set-up's traces,
+        # lowerings, cache loads and compiles are `jax.*` spans, not only
+        # those after the first step region. Installed after the
+        # runtime's start: libtpu starts in a process that nothing has
+        # patched, as it always did
+        from ray_tpu.util import jax_sentinel
+        jax_sentinel.install()
+
+
+def _start_tpu_runtime(num_tpus: int, rank: int, gang: str) -> None:
     import jax
     from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
     # A predecessor that could not give its chips back (SIGKILL, a gang
@@ -89,14 +122,21 @@ def _setup_worker(num_tpus: int) -> None:
     # TPU backend that failed once stays failed in this process. Asked as
     # late as the first touch of the backend allows: the time spent
     # getting here counts towards the release. At the bound libtpu speaks.
-    waited, busy = TPUAcceleratorManager.wait_for_chips(
-        TPUAcceleratorManager.get_current_process_visible_accelerator_ids())
+    # recorded when it waited 0 s too: 0 says the predecessor released
+    with spans.span("train.worker.chip_wait", rank=rank, gang=gang) as sp:
+        waited, busy = TPUAcceleratorManager.wait_for_chips(
+            TPUAcceleratorManager
+            .get_current_process_visible_accelerator_ids())
+        sp["waited_s"], sp["busy"] = waited, list(busy)
     if waited:
         logger.warning(
             "waited %.1f s for this worker's %d chips, which a "
             "predecessor had not let go%s", waited, num_tpus,
             f"; still busy: {', '.join(busy)}" if busy else "")
-    local = jax.local_devices()
+    with spans.span("train.worker.tpu_start", rank=rank, gang=gang) as sp:
+        local = jax.local_devices()   # libtpu's start
+        sp["devices"] = len(local)
+        sp["platform"] = ",".join(sorted({d.platform for d in local}))
     if len(local) != num_tpus or \
             any(d.platform != "tpu" for d in local):
         raise RuntimeError(
@@ -143,9 +183,13 @@ class _JaxBackend(Backend):
             self._init_distributed(worker_group, backend_config)
         else:
             logger.debug("JaxBackend: single-process mode, no coordinator")
-        worker_group.execute(
-            _setup_worker,
-            int(worker_group.resources_per_worker.get("TPU", 0)))
+        import ray_tpu
+        num_tpus = int(worker_group.resources_per_worker.get("TPU", 0))
+        gang = getattr(worker_group, "gang", "")
+        ray_tpu.get([
+            w.apply.remote(_setup_worker, num_tpus, rank, gang)
+            for rank, w in enumerate(worker_group.workers)
+        ], timeout=300)
 
     @staticmethod
     def _init_distributed(worker_group: WorkerGroup,
@@ -168,10 +212,11 @@ class _JaxBackend(Backend):
             port = 0
         port = port or worker_group.execute_single(0, _free_port)
         coordinator = f"{ip}:{port}"
+        gang = getattr(worker_group, "gang", "")
         import ray_tpu
         ray_tpu.get([
             w.apply.remote(_init_jax_distributed, coordinator,
-                              len(worker_group), rank)
+                           len(worker_group), rank, gang)
             for rank, w in enumerate(worker_group.workers)
         ], timeout=300)
 

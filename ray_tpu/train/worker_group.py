@@ -96,6 +96,7 @@ class WorkerGroup:
                                   placement_group)
 
         self.target_workers = num_workers
+        self.gang = gang
         self.elastic = min_workers is not None
         self._resources = dict(resources_per_worker)
         self._runtime_env = runtime_env

@@ -11,11 +11,46 @@ watchdog's `jit_recompile_storm` / `unexpected_host_transfer` probes.
 Two signals:
 
   - **compiles** — `jax.monitoring`'s backend-compile duration event
-    fires exactly once per real XLA compilation (silent on cache-warm
-    dispatches), so counting it per step-region label splits clean
+    fires once for every program the process's own jit cache did not
+    hold (silent on a cached dispatch). In jax 0.9.0 the event wraps
+    `compiler.compile_or_get_cached` (`pxla._cached_compilation`), so a
+    program LOADED from the persistent compilation cache fires it too,
+    with the retrieval as its duration: the event cannot tell a cache
+    load from a compile. Counting it per step-region label still splits
     warmup (`kind="first"`) from the steady-state recompiles that mean
-    a shape/static-arg hazard slipped through (`kind="recompile"`):
+    a shape/static-arg hazard slipped through (`kind="recompile"`: a
+    shape that changes is a hazard whether or not the disk had the
+    program):
         ray_tpu_jit_compiles_total{fn=<region>, kind=first|recompile}
+    Where the outcomes ARE told apart is the flight recorder: each of
+    JAX's three phases of such a program is a span (`jax.trace`,
+    `jax.lower`, `jax.compile`) carrying `fun` (the jitted function's
+    name), `region` (as `host_sync.*`: the open step region,
+    `after:<the last one>` or `untracked`) and, on `jax.compile`,
+    `cache`, from the persistent cache's own events on the same thread
+    since its previous compile event:
+        hit    found and loaded (`retrieval_s`: the read alone)
+        miss   not found, compiled, and WRITTEN (jax fires
+               `cache_misses` where it writes the entry)
+        small  asked, not found, compiled, and not kept: under
+               `jax_persistent_cache_min_compile_time_secs` or the
+               entry-size floor (or a host callback, or not process 0),
+               so compiled again in every run
+        off    the cache was not asked at all
+    An event of under FOLD_BELOW_S records no span of its own (a set-up
+    that compiles op by op fires three events an eager op): it is summed
+    per thread, span name and `cache` into a count and seconds, and the
+    sum is written as ONE record (`folded_n`, `folded_s`; its interval
+    is the stretch the folded events lay in, at most FOLD_SPAN_S long)
+    when the next such event comes that late, and at every snapshot of
+    the ring. So seconds and counts stay exact (a reader adds `dur` of
+    the records without `folded_n` to `folded_s` of those with) while
+    the ring holds hundreds of records a set-up, not tens of thousands.
+    (ISSUE 55 proposed carrying the sums on the next recorded span of the
+    name; a record of their own keeps WHEN they ran, which a reader
+    that splits set-up from window needs, and leaves a span's `dur` its
+    own.) `jax.trace` events nest (`matmul` inside `<lambda>`): readers
+    take the union of a thread's intervals, not the sum.
   - **host transfers** — the Python-level forcing points on jax arrays
     (`.item()`, `__array__`/np coercion, `__float__`/`__int__`/
     `__bool__`) and `jax.device_get` are patched to account the bytes
@@ -54,11 +89,26 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Any, Dict, Optional
+from _thread import get_ident as _get_ident
+from time import perf_counter
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu._private import spans as _spans
 
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+PHASE_SPANS = {TRACE_EVENT: "jax.trace", LOWER_EVENT: "jax.lower",
+               COMPILE_EVENT: "jax.compile"}
+# the persistent cache's own events, on the compiling thread, in order:
+# asked -> (found | written after the compile | neither)
+CACHE_OUTCOMES = {
+    "/jax/compilation_cache/compile_requests_use_cache": "small",
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss"}
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+FOLD_BELOW_S = 1e-3   # gc.collect's rule: shorter records no span
+FOLD_SPAN_S = 1.0     # the longest stretch one folded record stands for
 
 SNAPSHOT_KEY = "jax_sentinel"
 
@@ -70,6 +120,12 @@ _listener_registered = False
 
 # region label -> lifetime compile count (splits first vs recompile)
 _compiles: Dict[str, int] = {}
+# (thread, span name, cache outcome) -> [count, seconds, first start,
+# last end] of the events under FOLD_BELOW_S not yet written to the ring
+_folded: Dict[Tuple[int, str, Optional[str]], List[float]] = {}
+# "jax.compile|hit" -> [events, seconds] of the process's life, whatever
+# became of their spans: what the ring's records must add up to
+_phase_totals: Dict[str, List[float]] = {}
 
 _compile_counter: Any = None
 _xfer_counter: Any = None
@@ -96,17 +152,24 @@ def current_region() -> Optional[str]:
 # ---------------------------------------------------------------------
 
 
+def _region_label() -> Optional[str]:
+    """The open step region's label, `after:<the last one>` on a thread
+    that has left its region, None on one that never ran any."""
+    region = current_region()
+    if region is None:
+        last = getattr(_tls, "last_region", None)
+        return None if last is None else "after:" + last
+    return region
+
+
 def _sync_span(via: str):
     """The span around one forcing point: its blocked wall time under
     the open step region's label, or `after:<the last one>` on a thread
     that has left its region (the loop's own read of the step's
     result). A thread that never ran a region gets the shared no-op."""
-    region = current_region()
+    region = _region_label()
     if region is None:
-        last = getattr(_tls, "last_region", None)
-        if last is None:
-            return _spans.NOOP
-        region = "after:" + last
+        return _spans.NOOP
     return _spans.traced(f"host_sync.{via}", region=region)
 
 
@@ -129,15 +192,101 @@ def _in_xfer() -> bool:
     return getattr(_tls, "in_xfer", False)
 
 
+def _on_event(event: str, **_kw: Any) -> None:
+    """jax.monitoring's plain-event listener: what the persistent cache
+    says of the program this thread is compiling, kept for the
+    `jax.compile` span that the compile event closes."""
+    outcome = CACHE_OUTCOMES.get(event)
+    if outcome is not None and _installed:
+        _tls.cache = outcome
+
+
+def _write_folded(key: Tuple[int, str, Optional[str]],
+                  acc: List[float]) -> None:
+    tid, name, cache = key
+    attrs: Dict[str, Any] = {"folded_n": acc[0], "folded_s": acc[1]}
+    if cache is not None:
+        attrs["cache"] = cache
+    _spans.ring().record(("X", name, acc[2], acc[3] - acc[2], tid, None,
+                          attrs))
+
+
+def _flush_folded() -> None:
+    """Every sum to the ring; the ring's snapshot calls this first."""
+    with _lock:
+        pending = list(_folded.items())
+        _folded.clear()
+    for key, acc in pending:
+        _write_folded(key, acc)
+
+
+def _phase_span(name: str, duration: float, fun: Any,
+                cache: Optional[str], retrieval_s: Optional[float]) -> None:
+    """One of JAX's three phases, ended now: a span of its own from
+    FOLD_BELOW_S up, else into its (thread, name, cache) sum."""
+    label = name if cache is None else f"{name}|{cache}"
+    recording = _spans.enabled()
+    now = perf_counter()
+    folds = recording and duration < FOLD_BELOW_S
+    key = (_get_ident(), name, cache)
+    stale = None
+    with _lock:
+        total = _phase_totals.setdefault(label, [0, 0.0])
+        total[0] += 1
+        total[1] += duration
+        if folds:
+            acc = _folded.get(key)
+            if acc is not None and now - acc[2] > FOLD_SPAN_S:
+                stale, acc = acc, None
+            if acc is None:
+                _folded[key] = [1, duration, now - duration, now]
+            else:
+                acc[0] += 1
+                acc[1] += duration
+                acc[3] = now
+    if stale is not None:
+        _write_folded(key, stale)
+    if folds or not recording:
+        return
+    attrs: Dict[str, Any] = {"region": _region_label() or "untracked"}
+    if fun is not None:   # some of jax's traces name no function
+        attrs["fun"] = str(fun)
+    if cache is not None:
+        attrs["cache"] = cache
+    if retrieval_s is not None:
+        attrs["retrieval_s"] = retrieval_s
+    _spans.complete(name, duration, **attrs)
+
+
 def _on_event_duration(event: str, duration: float,
-                       **_kw: Any) -> None:
-    """jax.monitoring listener: fires once per real backend compile
-    (warm cache hits are silent), on the dispatching thread — so the
-    thread-local region label attributes it. The listener stays
-    registered for the process lifetime; _installed gates its body."""
-    if event != COMPILE_EVENT or not _installed:
+                       **kw: Any) -> None:
+    """jax.monitoring's duration listener, on the dispatching thread, so
+    the thread-local region label attributes it: each of the three
+    phases of a program the process's jit cache did not hold becomes a
+    span, and the last of them, the backend-compile event (a load from
+    the persistent cache fires it too), is counted and charged to the
+    goodput ledger's `compile` whatever its outcome. Never runs on a
+    cached dispatch. The listener stays registered for the process
+    lifetime; _installed gates its body."""
+    if not _installed:
+        return
+    if event == CACHE_RETRIEVAL_EVENT:
+        _tls.retrieval_s = float(duration)
+        return
+    name = PHASE_SPANS.get(event)
+    if name is None:
         return
     try:
+        cache = retrieval_s = None
+        if event == COMPILE_EVENT:
+            cache = getattr(_tls, "cache", None) or "off"
+            if cache == "hit":
+                retrieval_s = getattr(_tls, "retrieval_s", None)
+            _tls.cache = _tls.retrieval_s = None
+        _phase_span(name, float(duration), kw.get("fun_name"), cache,
+                    retrieval_s)
+        if cache is None:
+            return
         fn = current_region() or "untracked"
         with _lock:
             n = _compiles.get(fn, 0)
@@ -158,9 +307,12 @@ def _on_event_duration(event: str, duration: float,
 def _snapshot_extra() -> Dict[str, Any]:
     """Rides every metrics harvest: which regions this process has
     compiled under (the watchdog's storm probe names them; operators
-    grep it from `ray_tpu metrics dump`)."""
+    grep it from `ray_tpu metrics dump`), and its traces, lowerings and
+    compiles by cache outcome as [events, seconds] of the process's
+    life: whether the persistent cache hits, without a timeline."""
     with _lock:
-        return {"installed": _installed, "compiles": dict(_compiles)}
+        return {"installed": _installed, "compiles": dict(_compiles),
+                "phases": {k: list(v) for k, v in _phase_totals.items()}}
 
 
 # ---------------------------------------------------------------------
@@ -191,9 +343,11 @@ def install() -> bool:
         from ray_tpu.util.metrics import Counter, get_or_create
         _compile_counter = get_or_create(
             Counter, "ray_tpu_jit_compiles_total",
-            description="XLA backend compiles by step-region label; "
-                        "kind=first is warmup, kind=recompile means a "
-                        "recompile hazard (see graftlint RT020)",
+            description="programs the jit cache did not hold (compiled "
+                        "or loaded from the persistent cache) by "
+                        "step-region label; kind=first is warmup, "
+                        "kind=recompile means a recompile hazard (see "
+                        "graftlint RT020)",
             tag_keys=("fn", "kind"))
         _xfer_counter = get_or_create(
             Counter, "ray_tpu_host_transfer_bytes_total",
@@ -205,6 +359,8 @@ def install() -> bool:
         if not _listener_registered:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_event_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            _spans.before_snapshot(_flush_folded)
             _listener_registered = True
         metrics_plane.register_snapshot_extra(
             SNAPSHOT_KEY, _snapshot_extra)

@@ -134,3 +134,95 @@ def test_no_train_loop_in_the_trace(tmp_path):
     path.write_text(json.dumps(events))
     with pytest.raises(SystemExit):
         perf_report.main([str(path), "--steps"])
+
+
+# ---- a compile inside a step is named with the step (PR 55) -------------
+
+
+def test_a_shape_that_changes_in_a_step_is_named_by_its_compile():
+    """A loop whose batch changes its shape in the fourth step: that
+    step's excess lies in `train.step` (the dispatch compiled), and what
+    names it is the loop thread's own `jax.compile` of the step's
+    function, with the cache's outcome; the benchmark's reader counts one
+    compile in the window."""
+    import os
+    import sys
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import spans
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.parallel.train_step import make_train_step
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from benchlib import setup_spans
+
+    def loss(params, batch):
+        x = batch["x"]
+        for i in range(12):   # enough for the compiler to take a while
+            x = jnp.tanh(x @ params["w"]) + jnp.cos(x + i)
+        return jnp.mean(x * x), {}
+
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    init_state, train_step = make_train_step(
+        loss, {"w": (None, None)}, mesh)
+    state = init_state({"w": jnp.eye(32) * 0.5})
+    batches = {n: {"x": jnp.ones((n, 32))} for n in (8, 16)}
+    state, metrics = train_step(state, batches[8])   # warm-up
+    float(metrics["loss"])
+    t_setup = time.time()
+    time.sleep(0.02)
+    window_started_at = time.time()
+    for step in range(10):
+        state, metrics = train_step(state, batches[16 if step == 3 else 8])
+        float(metrics["loss"])
+        time.sleep(0.02)
+    window_s = time.time() - window_started_at
+    events = spans.merge_snapshots([spans.snapshot()])
+    me = spans.process_label()
+    events = [e for e in events if e.get("ph") == "M"
+              or e["ts"] / 1e6 >= t_setup]
+
+    report = perf_report.steps_report(events, process=me)
+    assert report is not None
+    stall = max(report["stalls"], key=lambda s: s["excess_s"])
+    assert stall["step"] == 3
+    assert stall["lay"]["train.step"] > 0.5 * stall["excess_s"]
+    named = [o for o in stall["overlapped"]
+             if o["name"].startswith("jax.compile:")]
+    assert [o["name"] for o in named] == ["jax.compile:jit(_step)[off]"]
+    assert named[0]["over_usual_s"] > 0
+    assert stall["named_s"] >= named[0]["over_usual_s"] - 1e-9
+    assert "jax.compile:jit(_step)[off]" in perf_report.format_steps(report)
+
+    got = setup_spans.setup_metrics(events, window_started_at,
+                                    window_started_at - t_setup, window_s)
+    assert got["window_compiles"] == 1
+    assert got["setup_backend_compile_s"] == 0   # the warm-up was before
+
+
+def test_compile_is_a_bucket_above_learner_compute():
+    events = [
+        {"ph": "X", "cat": "span", "name": "learner.update", "pid": "p",
+         "tid": 1, "ts": 0.0, "dur": 10e6, "args": {}},
+        {"ph": "X", "cat": "span", "name": "jax.trace", "pid": "p",
+         "tid": 1, "ts": 1e6, "dur": 1e6, "args": {"fun": "f"}},
+        {"ph": "X", "cat": "span", "name": "jax.lower", "pid": "p",
+         "tid": 1, "ts": 2e6, "dur": 1e6, "args": {"fun": "jit(f)"}},
+        {"ph": "X", "cat": "span", "name": "jax.compile", "pid": "p",
+         "tid": 1, "ts": 3e6, "dur": 2e6,
+         "args": {"fun": "jit(f)", "cache": "miss"}},
+        {"ph": "X", "cat": "span", "name": "host_sync.float", "pid": "p",
+         "tid": 1, "ts": 4.5e6, "dur": 1e6, "args": {}},
+    ]
+    report = perf_report.attribute(events)
+    seconds = {b: r["seconds"] for b, r in report["buckets"].items()}
+    assert seconds["compile"] == pytest.approx(3.5)   # the sync outranks
+    assert seconds["host_sync"] == pytest.approx(1.0)
+    assert seconds["learner_compute"] == pytest.approx(5.5)
+    assert report["goodput"]["buckets"]["compile"] == pytest.approx(3.5)

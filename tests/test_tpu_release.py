@@ -385,6 +385,50 @@ def test_worker_goes_on_at_the_bound(worker, vfio, monkeypatch, caplog):
         in said[0]
 
 
+def _worker_spans(t0):
+    from ray_tpu._private import spans
+    return {r[1]: r for r in spans.ring().snapshot_records()
+            if r[2] >= t0 and r[1].startswith("train.worker.")}
+
+
+def test_worker_records_what_it_did_under_the_backend_span(worker, vfio):
+    """PR 55: the wait and the runtime's start are spans of the worker's
+    own ring (the driver's `train.gang.backend` is one span around the
+    RPC), the wait with what it waited, recorded at 0 s too."""
+    from ray_tpu._private import spans
+    t0 = spans.begin()
+    worker(4, Opener(vfio, errno.EBUSY, fails=3), visible="0,1,2,3")
+    got = _worker_spans(t0)
+    assert set(got) == {"train.worker.jax_import", "train.worker.chip_wait",
+                        "train.worker.tpu_start"}
+    wait, start = got["train.worker.chip_wait"], got["train.worker.tpu_start"]
+    assert wait[6]["waited_s"] >= 0.15 and wait[6]["busy"] == []
+    assert wait[3] >= wait[6]["waited_s"] and wait[6]["rank"] == 0
+    assert start[6] == {"rank": 0, "gang": "", "devices": 4,
+                        "platform": "tpu"}
+    assert got["train.worker.jax_import"][6]["cached"] is True
+    assert wait[2] + wait[3] <= start[2] + 1e-6
+
+    t0 = spans.begin()
+    worker(1, Opener(vfio, errno.EBUSY, fails=0), visible="1")
+    free = _worker_spans(t0)["train.worker.chip_wait"]
+    assert free[6]["waited_s"] == 0.0 and free[6]["busy"] == []
+
+
+def test_worker_still_busy_at_the_bound_says_which(worker, vfio,
+                                                   monkeypatch):
+    from ray_tpu._private import spans
+    monkeypatch.setattr(tpu, "_CHIP_WAIT_BOUND_S", 0.3)
+    t0 = spans.begin()
+    with pytest.raises(RuntimeError, match="sees 0 local device"):
+        worker(2, Opener(vfio, errno.EBUSY), visible="2,3", devices=0)
+    got = _worker_spans(t0)
+    assert got["train.worker.chip_wait"][6]["busy"] == [
+        str(vfio / "2"), str(vfio / "3")]
+    assert got["train.worker.chip_wait"][6]["waited_s"] >= 0.3
+    assert got["train.worker.tpu_start"][6]["devices"] == 0
+
+
 def test_worker_without_tpus_asks_nothing(worker, vfio):
     opener = Opener(vfio, errno.EBUSY)
     assert worker(0, opener, visible="0") is None   # jax never touched

@@ -20,8 +20,9 @@ import pytest
 
 from ray_tpu._private import spans
 
-GANG = ["train.gang.placement", "train.gang.actors", "train.gang.visibility",
-        "train.gang.backend", "train.gang.sessions"]
+# PR 55 took `train.gang.visibility` and `.sessions` away (no reader; under
+# 0.04 s together): the formation is these three and a remainder
+GANG = ["train.gang.placement", "train.gang.actors", "train.gang.backend"]
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -348,15 +349,34 @@ events = [e for e in ray_tpu.timeline(spans=True) if e.get('ph') == 'X']
 def of(name, worker):
     return [e for e in events if e['name'] == name
             and str(e['pid']).startswith('worker-') == worker]
-sessions = of('train.gang.sessions', False)[0]
+backend = of('train.gang.backend', False)[0]
 rings = of('train.rings', False)[0]
 assert rings['args']['pulled'] == 1 and rings['args']['records'] > 0
-lo, hi = sessions['ts'], rings['ts'] + rings['dur']
+lo, hi = backend['ts'] + backend['dur'], rings['ts'] + rings['dur']
+# what the worker did under the driver's one backend span is in ITS ring,
+# inside that span's interval on the driver's timebase (a CPU gang is
+# given no chip: it waits for none and starts none)
+imports = of('train.worker.jax_import', True)
+assert len(imports) == 1 and imports[0]['args']['rank'] == 0
+assert imports[0]['args']['gang'] == backend['args']['gang']
+assert backend['ts'] - 5e3 <= imports[0]['ts'] and \
+    imports[0]['ts'] + imports[0]['dur'] <= lo + 5e3, (backend, imports)
+assert not of('train.worker.chip_wait', True)
+assert not of('train.worker.tpu_start', True)
+# the set-up's compiles are spans from the worker's first jit on: the
+# sentinel is installed with the import, not with the first step region
+compiles = of('jax.compile', True)
+assert compiles and all(e['ts'] >= lo - 5e3 for e in compiles)
+assert {e['args'].get('region') for e in compiles
+        if 'folded_n' not in e['args']} >= {'untracked', 'train.step'}
+init = of('cluster.init', False)
+assert len(init) == 1 and init[0]['args'] == {'address': 'local', 'nodes': 1}
+assert init[0]['ts'] + init[0]['dur'] <= backend['ts']
 for name in ('train.step', 'host_sync.float', 'train.report', 'gc.collect'):
     mine = of(name, True)
     assert len(mine) >= (1 if name == 'gc.collect' else 3), (name, len(mine))
-    # one timebase: the loop ran between the driver starting the
-    # sessions and pulling the rings (same host: a millisecond of slack)
+    # one timebase: the loop ran between the backend's set-up ending
+    # and the driver pulling the rings (same host: a millisecond of slack)
     if name == 'gc.collect':   # the worker collected while starting too
         mine = [e for e in mine if e['ts'] >= lo][-1:]
         assert mine and mine[0]['args']['generation'] == 2

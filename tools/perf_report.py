@@ -5,6 +5,10 @@ Consumes the JSON that `ray_tpu timeline --spans` (or
 loop's wall time into named buckets:
 
     learner_compute   learner.step / learner.update spans
+    compile           jax.trace / jax.lower / jax.compile (the jax
+                      sentinel's: a program the jit cache did not hold,
+                      traced, lowered, then compiled or loaded from the
+                      persistent cache), inside whatever step ran it
     device_feed       feed.stage / feed.ship / feed.xfer / feed.unfuse
     rollout_wait      feed.wait (consumer starved: upstream sampling or
                       the learner queue is the bottleneck)
@@ -26,8 +30,10 @@ medians its excess over the median — where on the loop thread it lay
 (`train.step` the dispatch, `host_sync.*` the wait for the device,
 `train.report`, none of them: the loop's own code), which spans of 1 ms
 or more on any other thread or process overlapped it (`gc.collect`,
-`rpc.server` by method, `cw.*`, the driver's), and the loop thread's
-`cpu_s` / `ivcsw` over the step. A stall with a span over it is named; one
+`rpc.server` by method, `cw.*`, the driver's) or, on the loop thread
+itself, which compile lay in it (`jax.compile:<function>[<cache
+outcome>]`: a shape that changed), and the loop thread's `cpu_s` /
+`ivcsw` over the step. A stall with a span over it is named; one
 with the thread off the CPU (`cpu_s` short of the period it was not
 waiting, `ivcsw` up) and nothing over it is the host's; one with neither
 is the device's or the runtime's.
@@ -45,6 +51,8 @@ import statistics
 import sys
 from typing import Any, Dict, List, Optional, Tuple
 
+COMPILE_SPANS = ("jax.trace", "jax.lower", "jax.compile")
+
 # bucket -> (priority, span-name prefixes); higher priority wins overlap.
 # task.run is deliberately NOT bucketed: it is an umbrella covering a
 # whole task body (including any nested learner.update), and ranking it
@@ -54,16 +62,20 @@ BUCKETS: Dict[str, Tuple[int, Tuple[str, ...]]] = {
     # checkpoint/reform/reshard/resume) outrank everything: wall time
     # inside a re-form is recovery cost, not compute/transport, even
     # when store/rpc spans nest inside it
-    "elastic_reconfig": (5, ("elastic.",)),
+    "elastic_reconfig": (6, ("elastic.",)),
     # device→host syncs recorded by the jax sentinel inside step
     # regions (util/jax_sentinel.py): wall time blocked on a forced
     # transfer is stall, not compute, even though the spans nest
     # inside learner.* — so host_sync outranks every work bucket
-    "host_sync": (4, ("host_sync.",)),
-    "store_rpc": (3, ("rpc.", "store.", "cw.", "envelope.")),
-    "device_feed": (2, ("feed.stage", "feed.ship", "feed.xfer",
+    "host_sync": (5, ("host_sync.",)),
+    "store_rpc": (4, ("rpc.", "store.", "cw.", "envelope.")),
+    "device_feed": (3, ("feed.stage", "feed.ship", "feed.xfer",
                         "feed.unfuse")),
-    "rollout_wait": (1, ("feed.wait", "runner.sample")),
+    "rollout_wait": (2, ("feed.wait", "runner.sample")),
+    # a program the jit cache did not hold (util/jax_sentinel.py): its
+    # trace, lowering and compile-or-load nest inside the learner.* or
+    # train.step that dispatched it, and are not compute
+    "compile": (1, COMPILE_SPANS),
     "learner_compute": (0, ("learner.",)),
 }
 
@@ -198,6 +210,7 @@ def attribute(events: List[Dict[str, Any]],
 # XLA, the feed pipeline, or a store RPC.
 GOODPUT_MAP: Dict[str, str] = {
     "learner_compute": "productive_step",
+    "compile": "compile",
     "device_feed": "productive_step",
     "store_rpc": "productive_step",
     "host_sync": "productive_step",
@@ -245,10 +258,12 @@ def _span_events(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
 
 
 def _label(e: Dict[str, Any]) -> str:
-    """`rpc.server:cw_push_task`, `task.run:next_result`, `gc.collect`."""
+    """`rpc.server:cw_push_task`, `task.run:next_result`, `gc.collect`,
+    `jax.compile:jit(_step)[miss]`."""
     args = e.get("args") or {}
-    detail = args.get("method") or args.get("name")
-    return f"{e['name']}:{detail}" if detail else str(e["name"])
+    detail = args.get("method") or args.get("name") or args.get("fun")
+    cache = f"[{args['cache']}]" if args.get("cache") else ""
+    return f"{e['name']}:{detail}{cache}" if detail else str(e["name"])
 
 
 def pick_loop_thread(events: List[Dict[str, Any]],
@@ -332,7 +347,10 @@ def steps_report(events: List[Dict[str, Any]],
     where its excess lay and what overlapped it. None without a thread
     that ran `train.step` at least three times in range.
 
-    What NAMES a stall: a span elsewhere names the part of its seconds in
+    What NAMES a stall: a span elsewhere (or a `jax.trace`, `jax.lower`
+    or `jax.compile` of the loop thread itself: a program compiled
+    inside the step, by its function and its cache outcome) names the
+    part of its seconds in
     the step that is over its usual share of a step (the median, over the
     other steps, of its seconds a second of step). A wait for the loop
     itself — the actor's `task.run:next_result`, the driver's `cw.get`
@@ -354,10 +372,14 @@ def steps_report(events: List[Dict[str, Any]],
     part_names = [part for part, _ in LOOP_PARTS] + ["other"]
     usual = {part: statistics.median([steps[i]["parts"][part] for i in calm])
              for part in part_names}
-    # spans of OVERLAP_MIN_S or more on any other thread or process
+    # spans of OVERLAP_MIN_S or more on any other thread or process,
+    # and on the loop thread itself what compiled inside a step (it
+    # lies in `train.step`'s own seconds and names them)
     elsewhere = [(e["ts"] / 1e6, (e["ts"] + e.get("dur", 0.0)) / 1e6, e)
                  for e in _span_events(events)
-                 if (e.get("pid"), e.get("tid")) != key
+                 if ((e.get("pid"), e.get("tid")) != key
+                     or (e["name"] in COMPILE_SPANS
+                         and "folded_n" not in (e.get("args") or {})))
                  and e.get("dur", 0.0) / 1e6 >= OVERLAP_MIN_S]
     over = [_overlapping(elsewhere, s["start_s"],
                          s["start_s"] + s["period_s"]) for s in steps]
