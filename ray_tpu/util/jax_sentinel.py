@@ -32,10 +32,12 @@ Two signals:
         hit    found and loaded (`retrieval_s`: the read alone)
         miss   not found, compiled, and WRITTEN (jax fires
                `cache_misses` where it writes the entry)
-        small  asked, not found, compiled, and not kept: under
-               `jax_persistent_cache_min_compile_time_secs` or the
-               entry-size floor (or a host callback, or not process 0),
-               so compiled again in every run
+        small  asked, not found, compiled, and not kept, so compiled
+               again in every run. Since `enable_compile_cache()` puts
+               jax's compile-time floor at 0 (PR 56) that is no longer
+               the common case but what is left: a program with a host
+               callback, a process that is not process 0, an entry
+               under a size floor, or a floor the user's environment set
         off    the cache was not asked at all
     An event of under FOLD_BELOW_S records no span of its own (a set-up
     that compiles op by op fires three events an eager op): it is summed

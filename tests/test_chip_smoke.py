@@ -59,9 +59,10 @@ def test_compile_cache_dir_is_placed_from_outside_or_fixed(
         tmp_path, monkeypatch):
     import jax
 
-    from ray_tpu._private.compile_cache import (compile_cache_dir,
+    from ray_tpu._private.compile_cache import (_FLOORS, compile_cache_dir,
                                                 enable_compile_cache)
     was = jax.config.jax_compilation_cache_dir
+    floors = {k: getattr(jax.config, k) for k in _FLOORS}
     try:
         outside = str(tmp_path / "placed")
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
@@ -77,3 +78,5 @@ def test_compile_cache_dir_is_placed_from_outside_or_fixed(
             assert jax.config.jax_compilation_cache_dir == fixed
     finally:
         jax.config.update("jax_compilation_cache_dir", was)
+        for k, v in floors.items():   # enable_compile_cache() puts them at 0
+            jax.config.update(k, v)
