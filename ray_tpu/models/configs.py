@@ -25,7 +25,11 @@ layers each Kimi Delta Attention (ops/kda.py) or softmax attention
 (latent where `kv_lora_rank` says so, its queries projected directly
 where `q_lora_rank` is 0), FOLLOWED by a dense MLP (lower case) or by
 experts (upper case), whose router may limit a token's choice to some
-groups of experts (`moe_groups`, `moe_topk_groups`).
+groups of experts (`moe_groups`, `moe_topk_groups`). `W` is attention
+under a causal window of `attn_window` keys FOLLOWED by experts, beside
+`L`, full attention then experts: a model that mixes the two
+(`layer_types`), with its RoPE scaled by YaRN (`rope_yarn_factor`) on the
+full layers only.
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -236,6 +240,23 @@ class TransformerConfig:
     block_length: int = 0
     mask_token_id: int = -1
     diffusion_t_min: float = 1e-3
+    # One more kind of `layer_pattern`: `W`, attention under a causal
+    # window of attn_window keys (the query's own among them) FOLLOWED by
+    # the experts, as `L` is full attention followed by them.
+    # YaRN (arXiv 2309.00071; `rope_parameters.rope_type: yarn`), 0: none.
+    # The per-pair frequencies theta^(-p/half) are kept where a pair turns
+    # more than rope_yarn_beta_fast times over rope_yarn_original_len
+    # positions, divided by rope_yarn_factor where it turns fewer than
+    # rope_yarn_beta_slow times, blended linearly between (the bounds
+    # floored and ceiled: `truncate`), and cos and sin are multiplied by
+    # rope_yarn_attention_factor (0: 0.1 ln(factor) + 1), on q and on k.
+    # The window kinds (`w`, `W`) keep the plain table: a model scales the
+    # layers that see the whole context and not those that see a window.
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_len: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_attention_factor: float = 0.0
 
     def __post_init__(self):
         if self.norm not in ("rms", "layernorm"):
@@ -259,11 +280,11 @@ class TransformerConfig:
             if bool(set(EXPERT_KINDS) & set(self.layer_pattern)) \
                     != bool(self.moe_experts) or self.moe_dense_layers \
                     or (self.kv_lora_rank
-                        and set("*wfc") & set(self.layer_pattern)):
+                        and set("*wfcW") & set(self.layer_pattern)):
                 raise ValueError("a layer_pattern has expert layers where "
-                                 "it says E, K or L, no leading dense run "
-                                 "(moe_dense_layers) and latent attention "
-                                 "in the kinds l and L only")
+                                 "it says E, K, L or W, no leading dense "
+                                 "run (moe_dense_layers) and latent "
+                                 "attention in the kinds l and L only")
             if set("kK") & set(self.layer_pattern) and not self.kda_heads:
                 raise ValueError("Kimi Delta Attention (k, K) needs "
                                  "kda_heads")
@@ -318,6 +339,12 @@ class TransformerConfig:
             raise ValueError(
                 "block diffusion: block_length 0 or above, diffusion_t_min "
                 "inside (0, 1), mask_token_id a row of the vocabulary")
+        if self.rope_yarn_factor and (
+                self.rope_yarn_factor < 1 or self.rope_yarn_original_len < 1
+                or not self.rope):
+            raise ValueError(
+                "YaRN: rope_yarn_factor 1 or above (0: none), with "
+                "rope_yarn_original_len, on a model with RoPE")
         if self.block_length and (
                 self.attention_impl in ("ring", "ulysses")
                 or self.layer_pattern or self.attn_window):
@@ -334,8 +361,8 @@ class TransformerConfig:
                 self.ssm_d_inner and self.ssm_dt_rank):
             raise ValueError("a Mamba-1 mixer (m, s) needs ssm_d_inner and "
                              "ssm_dt_rank")
-        if "w" in pattern and not self.attn_window:
-            raise ValueError("window attention (w) needs attn_window")
+        if set("wW") & set(pattern) and not self.attn_window:
+            raise ValueError("window attention (w, W) needs attn_window")
         if self.diff_attention and (
                 self.n_heads % 4 or self.kv_heads * 2 != self.n_heads
                 or self.kv_lora_rank or self.qk_norm):
@@ -383,6 +410,12 @@ class TransformerConfig:
     @property
     def ff_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
+
+    @property
+    def yarn_attention_factor(self) -> float:
+        """What YaRN's cos and sin are multiplied by."""
+        return self.rope_yarn_attention_factor or (
+            0.1 * math.log(self.rope_yarn_factor) + 1.0)
 
     @property
     def mask_token(self) -> int:
@@ -472,7 +505,7 @@ class TransformerConfig:
         norm = d * self._norm_leaves
         # a sublayer, its second norm and the experts
         each.update(K=self._kda_params + norm + expert,
-                    L=attn + norm + expert)
+                    L=attn + norm + expert, W=attn + norm + expert)
         layers = sum(each[c] + norm for c in self.layer_pattern)
         head = 0 if self.tie_embeddings else d * v
         return v * d + layers + norm + head
@@ -520,9 +553,9 @@ class TransformerConfig:
 
 # the kinds of `layer_pattern` that are followed by a dense MLP in the same
 # layer (TransformerConfig: `m`, `s`, `w`, `f`, `g`, `c`, `k`, `l`), and
-# those that are or end in an expert layer (`E` alone, `K`, `L`)
+# those that are or end in an expert layer (`E` alone, `K`, `L`, `W`)
 FFN_KINDS = "msfwgckl"
-EXPERT_KINDS = "EKL"
+EXPERT_KINDS = "EKLW"
 
 
 def pattern_runs(pattern: str):

@@ -36,12 +36,15 @@ inside it, `qkv/q_proj` in place of the first and third where the queries
 have no latent; `qkv/qk_norm` around GQA's QK-norm), `attention` (kernels,
 GQA repeat, layout transposes; `attention/block_diffusion` around the
 call under the block-diffusion mask),
-`attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (differential
+`attn_out`, `mlp_norm`, `mlp/gate_up`, `mlp/down` (a model that
+mixes window and full layers: `attention/window` or `attention/full`
+around the kernel call, and its two RoPE tables under `rope/plain` and
+`rope/yarn`; differential
 attention: `attention/window`, `attention/full` or `attention/cross`
 around the kernel calls, `attention/diff` around lambda, the subtraction,
 the pair norm and the scale; in an expert layer
 `moe/router`, `moe/dispatch`, `moe/experts`, `moe/combine`, `moe/shared`,
-`moe/latent`: ops/moe.py), in a mixer `ssm_norm` and `ssm/in_proj`,
+`moe/latent`, on an expert mesh `moe/exchange`: ops/moe.py), in a mixer `ssm_norm` and `ssm/in_proj`,
 `ssm/conv`, `ssm/scan`, `ssm/gate_norm`, `ssm/out_proj` (ops/ssm.py; a
 Mamba-1 mixer `ssm/x_proj` and `ssm/gate` and no `ssm/gate_norm`), in a
 gated memory unit `gmu_norm` and `gmu/in_proj`, `gmu/gate`,
@@ -74,17 +77,40 @@ from ray_tpu.parallel.mesh import AXIS_SEQ
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
 
 
-def _rope_tables(positions, head_dim, theta):
+def _rope_tables(positions, head_dim, theta, yarn=None):
     """cos/sin tables [..., T, half] (f32) for explicit positions — global
     positions keep RoPE exact when the sequence axis is sharded. Computed
     once per forward and closed over by the layer scan (not recomputed
-    per layer)."""
+    per layer). `yarn` = (factor, original_len, beta_fast, beta_slow,
+    attention_factor): YaRN's table (`TransformerConfig.rope_yarn_factor`),
+    the frequencies of the pairs that turn fewer than beta_slow times over
+    original_len positions divided by factor, those that turn more than
+    beta_fast times kept, a linear blend between, and cos and sin times
+    attention_factor."""
+    import math
+
     import jax.numpy as jnp
 
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    scale = None
+    if yarn is not None:
+        factor, original_len, beta_fast, beta_slow, scale = yarn
+
+        def pair_that_turns(times):   # the (fractional) pair that does
+            return head_dim * math.log(
+                original_len / (times * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(pair_that_turns(beta_fast)), 0)
+        high = min(math.ceil(pair_that_turns(beta_slow)), head_dim - 1)
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low)
+            / max(high - low, 1e-3), 0.0, 1.0)
+        freqs = freqs * (1.0 - ramp) + freqs / factor * ramp
     angles = positions[..., None].astype(jnp.float32) * freqs
-    return jnp.cos(angles), jnp.sin(angles)
+    if scale is None:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
 
 
 def _rope(x, cos, sin):
@@ -384,7 +410,7 @@ class Transformer:
             elif kind == "K":
                 sub = dict(kda(l, key), **experts(l, key, keys),
                            mlp_norm=jnp.ones((l, d), pdt))
-            elif kind == "L":
+            elif kind in "LW":
                 sub = dict(attention(l, keys), **experts(l, key, keys))
             elif kind == "g":
                 inner = cfg.ssm_d_inner
@@ -497,13 +523,17 @@ class Transformer:
                "w_kda_out": ("layers", None, None, "embed")}
 
         def experts():
+            # an expert's own d_model dimension has a name of its own
+            # (parallel/sharding.py: `expert_embed`)
             layers = {"w_router": ("layers", "embed", None),
-                      "w_moe_down": ("layers", "expert", "mlp", "embed")}
+                      "w_moe_down": ("layers", "expert", "mlp",
+                                     "expert_embed")}
             if cfg.moe_gated:
-                layers["w_moe_gateup"] = ("layers", "expert", "embed", None,
-                                          "mlp")
+                layers["w_moe_gateup"] = ("layers", "expert", "expert_embed",
+                                          None, "mlp")
             else:
-                layers["w_moe_up"] = ("layers", "expert", "embed", "mlp")
+                layers["w_moe_up"] = ("layers", "expert", "expert_embed",
+                                      "mlp")
             if cfg.moe_scoring == "sigmoid":
                 layers["router_bias"] = ("layers", None)
             if cfg.moe_shared_experts and cfg.moe_gated:
@@ -535,7 +565,7 @@ class Transformer:
                                w_dt=("layers", None, None))
             elif kind in "kK":
                 sub = dict(kda)
-            elif kind in "*wfclL":
+            elif kind in "*wfclLW":
                 sub = attention()
                 if kind == "*":
                     del sub["mlp_norm"]
@@ -688,16 +718,30 @@ class Transformer:
         import jax.numpy as jnp
         from jax import lax
 
-        cos = sin = None
+        cos = sin = window_rope = None
         if cfg.rope:
             if positions is None:
                 positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
-            with jax.named_scope("qkv"):
-                cos, sin = _rope_tables(positions, cfg.rope_dim,
-                                        cfg.rope_theta)
+            if cfg.rope_yarn_factor:
+                # two tables in one model: YaRN's for the layers that see
+                # the whole context, the plain one for the window kinds
+                with jax.named_scope("rope/plain"):
+                    window_rope = _rope_tables(positions, cfg.rope_dim,
+                                               cfg.rope_theta)
+                with jax.named_scope("rope/yarn"):
+                    cos, sin = _rope_tables(
+                        positions, cfg.rope_dim, cfg.rope_theta,
+                        (cfg.rope_yarn_factor, cfg.rope_yarn_original_len,
+                         cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow,
+                         cfg.yarn_attention_factor))
+            else:
+                with jax.named_scope("qkv"):
+                    cos, sin = _rope_tables(positions, cfg.rope_dim,
+                                            cfg.rope_theta)
         layer_fn = Transformer._make_layer_fn(cfg, mesh, rules, cos, sin,
                                               seq_len=x.shape[1],
-                                              noised=noised)
+                                              noised=noised,
+                                              window_rope=window_rope)
         shared = dict(shared or {})
 
         @functools.cache
@@ -806,7 +850,7 @@ class Transformer:
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
                        rules: ShardingRules, cos, sin, seq_len: int,
-                       noised: int = 0):
+                       noised: int = 0, window_rope=None):
         """Build layer(x, lp, shared, kind) -> (x, routing, made), the body
         `_stack` scans (or, in a block, one of its sublayers): what lp's
         leaves say, attention under `attn_norm`, a mixer under `ssm_norm`,
@@ -816,7 +860,9 @@ class Transformer:
         tensors earlier layers made for this one, `made` what this layer
         makes for later ones (`MAKES[kind]`), `kind` the layer's character
         in `layer_pattern` (None outside one). cos and sin are None where
-        the model has no rotary embedding."""
+        the model has no rotary embedding; `window_rope`: the (cos, sin)
+        the window kinds (`w`, `W`) take where the model has two tables
+        (YaRN on the other layers)."""
         import jax
         import jax.numpy as jnp
 
@@ -914,6 +960,7 @@ class Transformer:
             with jax.named_scope("attn_norm"):
                 h = _norm(x, lp, "attn_norm", cfg.norm_eps)
             made = {}
+            windowed = kind in ("w", "W")
             with jax.named_scope("qkv"):
                 if cfg.kv_lora_rank:
                     q, k, v = latent_qkv(h, lp)
@@ -940,7 +987,9 @@ class Transformer:
                         k = _qk_norm(k, lp["k_norm"], cfg.norm_eps)
                 if not cfg.kv_lora_rank:
                     if cfg.rope:
-                        q, k = _rope(q, cos, sin), _rope(k, cos, sin)
+                        c, s = window_rope if windowed \
+                            and window_rope is not None else (cos, sin)
+                        q, k = _rope(q, c, s), _rope(k, c, s)
                     q, k, v = heads_constrained(q, k, v)
                 if "lambda_q1" in lp and "wkv" in lp:
                     # a pair's value head: two key heads' columns
@@ -951,9 +1000,15 @@ class Transformer:
                 fn = window_fn if kind == "w" else attn_fn
                 o = differential(q, k, v, lp, fn, {
                     "w": "window", "f": "full", "c": "cross"}[kind])
+            elif windowed:
+                with jax.named_scope("attention/window"):
+                    o = window_fn(q, k, v, scale)
             else:
-                with jax.named_scope("attention/block_diffusion"
-                                     if cfg.block_length else "attention"):
+                # a model that mixes windows and full layers names both
+                with jax.named_scope(
+                        "attention/block_diffusion" if cfg.block_length
+                        else "attention/full" if cfg.attn_window and kind
+                        else "attention"):
                     o = attn_fn(q, k, v, scale)
             with jax.named_scope("attn_out"):
                 o = constrain(o, ("batch", "seq", "heads", "head_dim"))
@@ -1333,8 +1388,12 @@ class Transformer:
         `ops/moe.row_bound`'s run and the path past the sort ran over it,
         0 where it ran over every row) and, under group-limited routing,
         `moe_groups_chosen` (int32 [expert layers, groups]: the tokens
-        that kept each group; `moe_topk_groups` x tokens a layer); an
-        empty dict for a dense config."""
+        that kept each group; `moe_topk_groups` x tokens a layer) and, on
+        a mesh whose experts' axis is above 1, a layer and shard each
+        (int32 [expert layers, shards]; `ops/moe._exchange_ffn`),
+        `moe_rows_received`, `moe_exchange_rows_sent`,
+        `moe_exchange_rows_needed`, `moe_exchange_pairs` and
+        `moe_exchange_bounded`; an empty dict for a dense config."""
         import jax
         import jax.numpy as jnp
 
@@ -1448,4 +1507,9 @@ class Transformer:
             metrics["moe_rows_bounded"] = routing["rows_bounded"]
         if cfg.moe_groups > 1:
             metrics["moe_groups_chosen"] = routing["groups_chosen"]
+        if "exchange_rows_sent" in routing:   # an expert mesh's exchange
+            metrics.update(("moe_" + name, routing[name]) for name in (
+                "rows_received", "exchange_rows_sent",
+                "exchange_rows_needed", "exchange_pairs",
+                "exchange_bounded"))
         return loss_val, metrics

@@ -71,14 +71,33 @@ time (no option):
   The kernel's tiles follow from each call's shapes (`gmm_tiles`). Both
   permutations are gathers in the backward pass too (`_permutes`: a
   permutation's transpose is its inverse), so the step has no scatter.
-- **capacity** (an `expert` mesh axis above 1; today the virtual-CPU
-  tests; every expert held, no shared expert): Switch/GShard dispatch and
-  combine as einsums over an `[N, E, C]` one-hot, which GSPMD partitions
-  over the expert axis (the einsums lower to all-to-alls). Tokens over an
-  expert's capacity are dropped. `[N, E, C]` is 10.7 GB at 16,384 tokens,
-  64 experts, top-8: this branch is for small expert-parallel meshes
-  until the sorted path runs under `shard_map` with an all-to-all
-  (ROADMAP R2).
+- **sorted, dropless, exchanged** (an `expert` mesh axis above 1): the
+  same path under `shard_map`, one shard a device (`_exchange_ffn`). The
+  router runs outside it, row by row under GSPMD, as it does on one
+  device. Where the tokens are divided over the experts' axis (the
+  `batch` or `seq` rule names it) each shard sorts ITS n·k slots by
+  expert id, which is by destination too (a shard's E/P experts are a
+  contiguous run of ids), tells every shard how many rows it sends for
+  each of its experts (an all-to-all of `[P, E/P]` int32), sends each
+  shard the rows of its experts in one bucket of `exchange_bound` rows
+  (twice the uniform share n·k/P, in whole tiles: the send and the
+  receive buffers are `[P · bound, d]`, so at the uniform load half of
+  what travels is padding), regroups what it received by expert (the
+  sources' runs are already in expert order: a gather from 64-entry
+  tables), runs the grouped matmuls over it with its own experts
+  (`megablox` per device, as on one device), and sends the results back
+  the way they came; `combine` reads them by the inverse permutation. No
+  `[N, E, C]` tensor and no token dropped: a step in which some bucket of
+  some shard would overflow takes, on EVERY shard (a `pmax` of the
+  overflow decides the one `lax.cond` a layer: a collective in a branch
+  that some shards skip hangs the gang), the same exchange in
+  `ceil(n·k / bound)` rounds of that bucket, which takes any load in the
+  memory of one round. `exchange_bounded` says which ran. Gradients pass
+  through both exchanges (an all-to-all's transpose is the all-to-all
+  back) and every permutation stays a gather in the backward pass. Where
+  the tokens are NOT divided over the experts' axis (its shards hold the
+  same tokens) nothing is exchanged: each shard is the held share above at
+  its own offset and the shares are summed (`psum`).
 
 Scopes inside the caller's `moe` (PERF.md section 3): `moe/router`
 (logits, scores, top-k), `moe/dispatch` (sort, counts, the `cond` on
@@ -86,7 +105,9 @@ the rows received, gather),
 `moe/experts` (the grouped matmuls and silu-mul), `moe/combine` (the
 gather back and the weighted sum; the caller adds the residual there),
 `moe/shared` (the shared expert), `moe/latent` (the two projections
-around experts that live in a latent).
+around experts that live in a latent), `moe/exchange` (on an expert mesh:
+the counts' all-to-all, the overflow's `pmax` and both row exchanges,
+forward and backward).
 """
 
 from __future__ import annotations
@@ -116,8 +137,8 @@ ROUTING_RESIDUALS = "routing_residuals"
 # Logical specs for shard_pytree / make_train_step param placement.
 MOE_PARAM_SPECS = {
     "w_router": ("embed", None),
-    "w_gateup": ("expert", "embed", None, "mlp"),
-    "w_down": ("expert", "mlp", "embed"),
+    "w_gateup": ("expert", "expert_embed", None, "mlp"),
+    "w_down": ("expert", "mlp", "expert_embed"),
 }
 
 
@@ -292,24 +313,42 @@ def load_balancing_loss(tokens_per_expert, router_prob, top_k: int):
     return n_experts * jnp.sum(frac * jnp.atleast_2d(router_prob).mean(0))
 
 
+def _past_the_end(padded: str):
+    """`jnp.take`'s arguments for what an index past the end reads: the
+    last row ("clip"), zero ("fill"), or `take`'s own rule ("")."""
+    return {"clip": {"mode": "clip"}, "fill": {"fill_value": 0}, "": {}}[
+        padded]
+
+
 @functools.lru_cache(maxsize=None)
-def _permutes():
+def _permutes(padded: str = ""):
     """(slots_of, combine): the two permutations of the sorted path as
     custom_vjp functions, built on first use (jax is imported lazily in
     this package). A permutation's transpose is the inverse permutation,
     so both directions are row gathers and autodiff's scatter-adds never
     appear; a gather from the `[N, d]` side costs half of one from the
     `[N·k, d]` side on the chip (PERF.md section 6, PR 27), which is why
-    combine's backward gathers the token's cotangent, not the slots'."""
+    combine's backward gathers the token's cotangent, not the slots'.
+    `padded`: the exchange's buckets (`_exchange_ffn`), where `order` has
+    rows of no slot, written as an index past the end. "clip": one round
+    carries every slot, and what a row of no slot holds is never read (an
+    index past the end reads the last row: no pass to mask it; on the v5e
+    the mask was a pass of its own over the 604 MB buffer, 1.85 ms, twelve
+    times a layer). "fill": one of several rounds, where `inverse` too has
+    slots of no row (another round's); both read zero."""
     import jax
     import jax.numpy as jnp
+
+    past_the_end = _past_the_end(padded)
+    take = functools.partial(jnp.take, axis=0, **past_the_end)
 
     def rows_of_slots(ys, inverse):
         """Row `inverse[i]` of ys for every slot i. ys may stop after a
         leading run of the sorted order (`row_bound`): the rows past it
         belong to no held expert and read zero."""
-        if ys.shape[0] == inverse.size:   # every row: no index is past it
-            return jnp.take(ys, inverse, axis=0)
+        # every row is there: no index is past it; or the padding's rule
+        if ys.shape[0] == inverse.size or padded:
+            return take(ys, inverse)
         return jnp.take(ys, inverse, axis=0, fill_value=0)
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -318,7 +357,7 @@ def _permutes():
         (slot i of the unsorted order belongs to token i // k). `order` is
         the sorted order `[N·k]` or a leading run of it, `inverse` always
         the whole inverse permutation."""
-        return jnp.take(x, order // k, axis=0)
+        return take(x, order // k)
 
     def slots_fwd(x, order, inverse, k):
         return slots_of(x, order, inverse, k), (order, inverse)
@@ -347,8 +386,8 @@ def _permutes():
     def combine_bwd(res, g):
         per_slot, top_w, order, inverse = res
         k = top_w.shape[1]
-        w_sorted = jnp.take(top_w.reshape(-1), order)
-        dys = (jnp.take(g, order // k, axis=0).astype(jnp.float32)
+        w_sorted = jnp.take(top_w.reshape(-1), order, **past_the_end)
+        dys = (take(g, order // k).astype(jnp.float32)
                * w_sorted[:, None]).astype(per_slot.dtype)
         dw = jnp.einsum("nd,nkd->nk", g.astype(jnp.float32),
                         per_slot.astype(jnp.float32))
@@ -379,9 +418,10 @@ def gmm_tiles(m: int, k: int, n: int):
 
 
 def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int,
-                        gated: bool = True) -> str:
+                        gated: bool = True, per_shard: bool = False) -> str:
     """`megablox` (the pallas grouped matmul that ships with JAX) where
-    the program runs on one TPU device and the kernel's tiles fit the
+    the call runs on one TPU device (the whole program, or `per_shard`:
+    one shard of a `shard_map`) and the kernel's tiles fit the
     expert FFN's shapes (`gmm_tiles`), else `ragged_dot`
     (`jax.lax.ragged_dot`: any platform, any shape, and GSPMD can
     partition it, which it cannot a pallas call). Decided at trace time,
@@ -391,7 +431,7 @@ def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int,
     tiles = gmm_tiles(rows, d_model, (1 + gated) * d_ff) and gmm_tiles(
         rows, d_ff, d_model)
     device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
-    one_device = mesh is None or mesh.size == 1
+    one_device = mesh is None or mesh.size == 1 or per_shard
     return "megablox" if device.platform == "tpu" and one_device and tiles \
         else "ragged_dot"
 
@@ -465,8 +505,8 @@ def _rows_ffn(m: int, impl: str, x, top_w, w_first, w_down, order, inverse,
         return combine(ys, top_w, order, inverse)
 
 
-def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
-                act: str = "silu"):
+def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset=0,
+                act: str = "silu", per_shard: bool = False):
     import jax
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
@@ -489,7 +529,8 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
     k = top_e.shape[1]
     with jax.named_scope("moe/dispatch"):
         slot_expert = top_e.reshape(-1)                  # [N·k]
-        if expert_offset:   # the held experts' groups first
+        # the held experts' groups first (a shard's offset is traced)
+        if not isinstance(expert_offset, int) or expert_offset:
             slot_expert = (slot_expert - expert_offset) % n_experts
         sorted_expert, order = jax.lax.sort(
             (slot_expert, jnp.arange(slot_expert.size, dtype=jnp.int32)),
@@ -507,7 +548,8 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
                      counts)
     bound = row_bound(x.shape[0], picked, held, n_experts, order.size)
     impl = grouped_matmul_impl(mesh, bound or order.size, w_first.shape[1],
-                               w_first.shape[-1], gated=w_first.ndim == 4)
+                               w_first.shape[-1], gated=w_first.ndim == 4,
+                               per_shard=per_shard)
     if bound is None:
         y = over(order.size, impl, *past_the_sort)
         bounded = jnp.zeros((), jnp.int32)
@@ -534,42 +576,322 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset: int = 0,
     return y, counts, jnp.zeros((), jnp.int32), bounded
 
 
-def _capacity_ffn(params, x, top_w, top_e, capacity_factor, constrain):
+# The bucket one shard sends another, in multiples of the uniform share
+# n·k / P of a shard's slots (`exchange_bound`).
+EXCHANGE_BOUND = 2
+
+
+def exchange_bound(slots: int, shards: int) -> Optional[int]:
+    """The rows of one bucket of the exchange (`_exchange_ffn`): of a
+    shard's `slots` (its n·k) what goes to ONE of the `shards`,
+    `EXCHANGE_BOUND` times the uniform share, in whole tiles: a `shards`-th
+    of `GMM_ROWS`, so that the `shards` buckets a shard receives are whole
+    row tiles of the kernel, or sublane tiles of 8 where the share is
+    smaller than that. None where the bucket would hold every slot: it
+    cannot overflow then, and no `cond` is traced. What the bound costs:
+    send and receive buffers of `shards * bound` rows where `slots` would
+    do under a ragged exchange, and at the uniform load
+    `(shards - 1) * bound` rows leave a shard where
+    `slots * (shards - 1) / shards` are needed: `EXCHANGE_BOUND` times as
+    many."""
+    share = -(-EXCHANGE_BOUND * slots // shards)
+    tile = max(GMM_ROWS // shards, 8)
+    if share < tile:
+        tile = 8
+    bound = -(-share // tile) * tile
+    return bound if bound < slots else None
+
+
+def _lookup(table, index):
+    """`table[index]` for a small 1-D table as compares and a sum, no
+    gather (`_chosen_scores`' reason); an index past the table reads 0."""
     import jax
     import jax.numpy as jnp
 
-    n_tokens = x.shape[0]
-    k = top_e.shape[1]
+    columns = jax.lax.broadcasted_iota(jnp.int32, (1, table.size), 1)
+    return jnp.where(index[:, None] == columns, table[None, :], 0).sum(-1)
+
+
+def _round_tables(r, bucket: int, k: int, slot_expert, order, inverse,
+                  counts, recv_counts):
+    """The index tables of round `r` of the exchange on one shard, all
+    int32. Sending: bucket p of the round holds the rows
+    `[r * bucket, (r + 1) * bucket)` of the run of the sorted order that
+    goes to shard p. `order_send [P * bucket]`: the slot each row of the
+    send buffer carries (n·k: none); `pos_send [n·k]`: the row of the
+    buffer (sent, and returned) that carries each slot (`P * bucket`: not
+    this round). Receiving: source s's bucket holds its rows for this
+    shard's experts in expert order (`recv_counts [P, held]` over all
+    rounds); `regroup [P * bucket]`: the row of the receive buffer that
+    comes to each place of the expert-major order the grouped matmul
+    takes, `ungroup [P * bucket]` the inverse (`P * bucket`: no row), and
+    `groups [held + 1]`: each held expert's rows this round, then the
+    rows of no expert."""
+    import jax.numpy as jnp
+
+    shards, held = recv_counts.shape
+    size = shards * bucket
+    first = r * bucket
+    send_rows = counts.reshape(shards, held).sum(1)          # [P]
+    starts = jnp.cumsum(send_rows) - send_rows
+    j = jnp.arange(bucket, dtype=jnp.int32)
+    run = first + j[None, :]                                 # [1, bucket]
+    sorted_at = jnp.minimum(starts[:, None] + run, order.size - 1)
+    order_send = jnp.where(run < send_rows[:, None],
+                           jnp.take(order, sorted_at), order.size)
+    shard_of = slot_expert // held                           # [n·k]
+    within = inverse - _lookup(starts, shard_of) - first
+    pos_send = jnp.where((within >= 0) & (within < bucket),
+                         shard_of * bucket + within, size)
+    # a source's run for expert e lies at [before, before + count) of its
+    # rows for this shard; this round's bucket holds [lo, hi) of it
+    before = jnp.cumsum(recv_counts, axis=1) - recv_counts   # [P, held]
+    lo = jnp.clip(before - first, 0, bucket)
+    hi = jnp.clip(before + recv_counts - first, 0, bucket)
+    rows = hi - lo                                           # [P, held]
+    groups = rows.sum(0)                                     # [held]
+    # expert-major: expert e's rows from source 0, 1, ...
+    target = (jnp.cumsum(groups) - groups)[None, :] \
+        + jnp.cumsum(rows, axis=0) - rows                    # [P, held]
+    source = jnp.arange(shards, dtype=jnp.int32)[:, None] * bucket + lo
+    t = jnp.arange(size, dtype=jnp.int32)
+    # the 64 blocks in the target's order (e-major): the block of place t
+    # is the number of blocks that end at or before it
+    ends = (target + rows).T.reshape(-1)
+    block = (t[:, None] >= ends[None, :]).sum(-1, dtype=jnp.int32)
+    regroup = jnp.where(t < groups.sum(),
+                        t + _lookup((source - target).T.reshape(-1), block),
+                        size)
+    expert = (j[None, :, None] >= hi[:, None, :]).sum(-1, dtype=jnp.int32)
+    shift = jnp.where(
+        expert[..., None] == jnp.arange(held, dtype=jnp.int32),
+        (target - lo)[:, None, :], 0).sum(-1)                # [P, bucket]
+    ungroup = jnp.where(expert < held, j[None, :] + shift, size)
+    groups = jnp.concatenate([groups, size - groups.sum(keepdims=True)])
+    return (order_send.reshape(-1), pos_send, regroup, ungroup.reshape(-1),
+            groups.astype(jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _regrouped(padded: str = "fill"):
+    """`regrouped(rows, index, back)`: `rows[index]`, an index past the end
+    reading zero ("fill") or the last row ("clip": what a row of no slot
+    holds is never read, `_permutes`); its transpose is the gather by
+    `back`, the inverse permutation (`_permutes`' reason)."""
+    import jax
+    import jax.numpy as jnp
+
+    take = functools.partial(jnp.take, axis=0, **_past_the_end(padded))
+
+    @jax.custom_vjp
+    def regrouped(rows, index, back):
+        return take(rows, index)
+
+    def fwd(rows, index, back):
+        return regrouped(rows, index, back), (index, back)
+
+    def bwd(res, g):
+        index, back = res
+        return take(g, back), None, None
+
+    regrouped.defvjp(fwd, bwd)
+    return regrouped
+
+
+def _axes_above_one(rules: ShardingRules, logical: str, mesh):
+    entry = rules.mesh_axes(logical)
+    axes = entry if isinstance(entry, tuple) else (entry,)
+    return tuple(a for a in axes if a and mesh.shape.get(a, 1) > 1)
+
+
+def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
+                  act: str = "silu"):
+    """The sorted path on a mesh whose experts' axis is above 1 (module
+    docstring): x `[N, d]`, top_w and top_e `[N, k]` as `route` made them,
+    the experts' leaves `[E, ...]` sharded by expert. -> (y `[N, d]`,
+    record): `tokens_per_expert [E]` over all shards, and a shard each
+    (`[shards]`, the mesh's token and expert shards in order)
+    `rows_received` (rows its experts ran, its own among them),
+    `exchange_rows_sent` (rows that left it, padding included),
+    `exchange_rows_needed` (its slots routed to other shards),
+    `exchange_pairs` (its distinct (token, other shard) pairs: the least
+    any exchange must move) and `exchange_bounded` (1: one round of
+    bounded buckets; 0: the rounds that take any load)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+    from jax.sharding import PartitionSpec as P
+
+    w_first, w_down = _first_matmul(params), params["w_down"]
     n_experts = params["w_router"].shape[1]
-    capacity = max(1, int(capacity_factor * n_tokens * k / n_experts))
-    with jax.named_scope("moe/dispatch"):
-        # Position of each token within its expert's capacity buffer, per
-        # selection slot (cumsum over tokens of the one-hot selection).
-        onehot = jax.nn.one_hot(top_e, n_experts, dtype=jnp.float32)
-        # [k, N, E] cumulative counts: slot 0 fills first, then slot 1, ...
-        sel = jnp.swapaxes(onehot, 0, 1)                    # [k, N, E]
-        flat = sel.reshape(k * n_tokens, n_experts)
-        pos_flat = jnp.cumsum(flat, axis=0) - flat          # [k*N, E]
-        pos = pos_flat.reshape(k, n_tokens, n_experts)
-        keep = jnp.swapaxes(sel * (pos < capacity), 0, 1)   # [N, k, E]
-        pos_k = jnp.swapaxes((pos * sel).sum(-1), 0, 1)     # [N, k]
-        cap_onehot = jax.nn.one_hot(pos_k.astype(jnp.int32), capacity,
-                                    dtype=jnp.float32)
-        dispatch = jnp.einsum("nke,nkc->nec", keep, cap_onehot)
-        counts = flat.sum(0).astype(jnp.int32)
-        dropped = n_tokens * k - dispatch.sum().astype(jnp.int32)
-        expert_in = jnp.einsum("nec,nd->ecd", dispatch.astype(x.dtype), x)
-        expert_in = constrain(expert_in, ("expert", None, None))
-    with jax.named_scope("moe/experts"):
-        gu = jnp.einsum("ecd,edgf->ecgf", expert_in, params["w_gateup"])
-        h = jax.nn.silu(gu[:, :, 0]) * gu[:, :, 1]
-        h = constrain(h, ("expert", None, "act_mlp"))
-        expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"])
-    with jax.named_scope("moe/combine"):
-        combine = jnp.einsum("nke,nkc,nk->nec", keep, cap_onehot, top_w)
-        y = jnp.einsum("nec,ecd->nd", combine,
-                       expert_out.astype(jnp.float32))
-    return y.astype(x.dtype), counts, dropped
+    ep = _axes_above_one(rules, "expert", mesh)
+    over = tuple(dict.fromkeys(_axes_above_one(rules, "batch", mesh)
+                               + _axes_above_one(rules, "seq", mesh)))
+    shards = spec_entry_size(ep, mesh)
+    divided = set(ep) <= set(over)
+    if not divided and set(ep) & set(over):
+        raise ValueError(
+            f"the experts' mesh axes {ep} lie partly over the tokens' "
+            f"{over}: all of them (the tokens travel) or none (the shares "
+            f"are summed)")
+    if n_experts % shards or x.shape[0] % spec_entry_size(over, mesh):
+        raise ValueError(
+            f"{n_experts} experts over {shards} shards, {x.shape[0]} tokens "
+            f"over {spec_entry_size(over, mesh)}: neither divides")
+    held = n_experts // shards
+    k = top_e.shape[1]
+    gated = w_first.ndim == 4
+    every = tuple(dict.fromkeys(over + ep))    # the axes a shard is one of
+
+    def summed(counts):     # over the shards that hold other tokens
+        return jax.lax.psum(counts, over) if over else counts
+
+    def held_share(x, top_w, top_e, w_router, w_first, w_down):
+        """The shards of the experts' axis hold the same tokens: each is
+        the held share at its own offset, and the shares are summed."""
+        offset = jax.lax.axis_index(ep) * held
+        y, mine, _, _ = _sorted_ffn(
+            {"w_router": w_router, "w_down": w_down,
+             "w_gateup" if gated else "w_up": w_first},
+            x, top_w, top_e, mesh, offset, act, per_shard=True)
+        with jax.named_scope("moe/combine"):
+            y = jax.lax.psum(y, ep)
+        with jax.named_scope("moe/dispatch"):
+            counts = summed(jax.lax.all_gather(mine, ep, tiled=True))
+        zero = jnp.zeros((1,), jnp.int32)
+        return y, counts, mine.sum(keepdims=True), zero, zero, zero, zero
+
+    def exchanged(x, top_w, top_e, w_first, w_down):
+        n, d = x.shape
+        m = n * k
+        with jax.named_scope("moe/dispatch"):
+            slot_expert = top_e.reshape(-1)
+            sorted_expert, order = jax.lax.sort(
+                (slot_expert, jnp.arange(m, dtype=jnp.int32)),
+                num_keys=1, is_stable=True)
+            inverse = jnp.argsort(order)
+            counts = jnp.diff(jnp.searchsorted(
+                sorted_expert, jnp.arange(n_experts + 1, dtype=jnp.int32))
+            ).astype(jnp.int32)
+        with jax.named_scope("moe/exchange"):
+            # what each shard sends this one, before any row moves
+            recv_counts = jax.lax.all_to_all(
+                counts.reshape(shards, held), ep, 0, 0)
+        order, inverse, counts, recv_counts = checkpoint_name(
+            (order, inverse, counts, recv_counts), ROUTING_RESIDUALS)
+        bound = exchange_bound(m, shards)
+        bucket = bound or m
+        impl = grouped_matmul_impl(mesh, shards * bucket, d,
+                                   w_first.shape[-1], gated=gated,
+                                   per_shard=True)
+
+        def one_round(r, x, top_w, w_first, w_down, tables=None,
+                      impl="ragged_dot"):
+            """Round r of the exchange -> this round's part of y; with
+            `tables` the one round that carries every slot."""
+            padded = "fill" if tables is None else "clip"
+            slots_of, combine = _permutes(padded)
+            regrouped = _regrouped(padded)
+            with jax.named_scope("moe/dispatch"):
+                if tables is None:
+                    tables = _round_tables(r, bucket, k, slot_expert, order,
+                                           inverse, counts, recv_counts)
+                order_send, pos_send, regroup, ungroup, groups = tables
+                sent = slots_of(x, order_send, pos_send, k)
+            with jax.named_scope("moe/exchange"):
+                got = jax.lax.all_to_all(
+                    sent.reshape(shards, bucket, d), ep, 0, 0)
+            with jax.named_scope("moe/dispatch"):
+                xs = regrouped(got.reshape(-1, d), regroup, ungroup)
+            with jax.named_scope("moe/experts"):
+                ys = experts_ffn(xs, w_first, w_down, groups, impl, act)
+            with jax.named_scope("moe/combine"):
+                back = regrouped(ys, ungroup, regroup)
+            with jax.named_scope("moe/exchange"):
+                back = jax.lax.all_to_all(
+                    back.reshape(shards, bucket, d), ep, 0, 0)
+            with jax.named_scope("moe/combine"):
+                return combine(back.reshape(-1, d), top_w, order_send,
+                               pos_send)
+
+        operands = (x, top_w, w_first, w_down)
+        if bound is None:
+            with jax.named_scope("moe/dispatch"):
+                tables = _round_tables(0, bucket, k, slot_expert, order,
+                                       inverse, counts, recv_counts)
+            y = one_round(0, *operands, tables, impl)
+            fits = jnp.ones((), jnp.bool_)
+            rounds = 1
+        else:
+            rounds = -(-m // bucket)
+            with jax.named_scope("moe/dispatch"):
+                # the fast round's tables are kept with the routing
+                tables = checkpoint_name(
+                    _round_tables(0, bucket, k, slot_expert, order, inverse,
+                                  counts, recv_counts), ROUTING_RESIDUALS)
+                fullest = counts.reshape(shards, held).sum(1).max()
+            with jax.named_scope("moe/exchange"):
+                # on a value every shard agrees on: a collective in a
+                # branch that some shards skip hangs the gang
+                fits = checkpoint_name(
+                    jax.lax.pmax(fullest, ep) <= bucket, ROUTING_RESIDUALS)
+
+            def any_load(x, top_w, w_first, w_down, *tables):
+                # its grouped matmuls are `ragged_dot`, as the held share's
+                # fallback's and for its reason (`_sorted_ffn`: a second
+                # set of pallas kernels in every program); each round is
+                # checkpointed, so the backward pass holds one round's
+                # rows at a time, as the forward does
+                @jax.checkpoint
+                def step(y, r):
+                    part = one_round(r, x, top_w, w_first, w_down)
+                    return y + part.astype(jnp.float32), None
+                y, _ = jax.lax.scan(
+                    step, jnp.zeros((n, d), jnp.float32),
+                    jnp.arange(rounds, dtype=jnp.int32))
+                return y.astype(x.dtype)
+
+            with jax.named_scope("moe/dispatch"):
+                y = jax.lax.cond(
+                    fits,
+                    lambda x, top_w, w_first, w_down, *tables: one_round(
+                        0, x, top_w, w_first, w_down, tables, impl),
+                    jax.checkpoint(any_load), *operands, *tables)
+        with jax.named_scope("moe/dispatch"):
+            mine = jax.lax.axis_index(ep)
+            own = jax.lax.dynamic_slice_in_dim(counts, mine * held,
+                                               held).sum()
+            received = recv_counts.sum(keepdims=True).reshape(1)
+            needed = (m - own).reshape(1)
+            sent_rows = jnp.where(fits, 1, rounds).astype(jnp.int32) \
+                * ((shards - 1) * bucket)
+            # the distinct (token, other shard) pairs
+            to = jax.lax.broadcasted_iota(jnp.int32, (1, 1, shards), 2)
+            reached = ((top_e // held)[..., None] == to).any(1)    # [n, P]
+            pairs = (reached & (to[0] != mine)).sum(dtype=jnp.int32)
+            counts = summed(counts)
+        return (y, counts, received, sent_rows.reshape(1), needed,
+                pairs.reshape(1), fits.astype(jnp.int32).reshape(1))
+
+    tokens = P(over or None, None)
+    experts_in = (P(ep, *[None] * (w_first.ndim - 1)), P(ep, None, None))
+    a_shard = P(every)
+    outs = (tokens, P(), a_shard, a_shard, a_shard, a_shard, a_shard)
+    if divided:
+        fn = jax.shard_map(exchanged, mesh=mesh,
+                           in_specs=(tokens, tokens, tokens) + experts_in,
+                           out_specs=outs, check_vma=False)
+        y, *record = fn(x, top_w, top_e, w_first, w_down)
+    else:
+        fn = jax.shard_map(held_share, mesh=mesh,
+                           in_specs=(tokens, tokens, tokens, P())
+                           + experts_in,
+                           out_specs=outs, check_vma=False)
+        y, *record = fn(x, top_w, top_e, params["w_router"], w_first,
+                        w_down)
+    names = ("tokens_per_expert", "rows_received", "exchange_rows_sent",
+             "exchange_rows_needed", "exchange_pairs", "exchange_bounded")
+    return y, dict(zip(names, record))
 
 
 def shared_ffn(w_gateup, w_down, x, act: str = "silu"):
@@ -589,8 +911,7 @@ def shared_ffn(w_gateup, w_down, x, act: str = "silu"):
 def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
             norm_topk: bool = True, scoring: str = "softmax",
             routed_scale: float = 1.0, expert_offset: int = 0,
-            act: str = "silu", capacity_factor: float = 1.25,
-            n_group: int = 1, topk_group: int = 1,
+            act: str = "silu", n_group: int = 1, topk_group: int = 1,
             mesh=None, rules: Optional[ShardingRules] = None
             ) -> Tuple[Any, Dict[str, Any]]:
     """Top-k routed gated-expert FFN.
@@ -625,9 +946,11 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
     `scoring`, the bias, `routed_scale`, `n_group` and `topk_group` are
     `route`'s.
 
-    The sorted dropless path runs unless `mesh` has an `expert` axis
-    above 1 (module docstring); `capacity_factor` applies to that
-    expert-parallel branch only, where `dropped` can be above 0.
+    Where `mesh` has an `expert` axis above 1 (module docstring) the
+    experts' leaves are all E, sharded by expert, `dropped` is 0 as
+    everywhere, and the record has besides, a shard each (`[shards]`),
+    `rows_received`, `exchange_rows_sent`, `exchange_rows_needed`,
+    `exchange_pairs` and `exchange_bounded` (`_exchange_ffn`).
     """
     import jax
     import jax.numpy as jnp
@@ -644,27 +967,26 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
         router_prob = probs.mean(axis=0)
     expert_parallel = mesh is not None and spec_entry_size(
         rules.mesh_axes("expert"), mesh) > 1
+    if expert_parallel and held != n_experts:
+        raise ValueError("an expert mesh axis shards all of a layer's "
+                         "experts: a held share (moe_experts_held) runs "
+                         "without it")
+    rows = x
+    if "w_latent_down" in params:
+        with jax.named_scope("moe/latent"):
+            rows = jnp.einsum("nd,dr->nr", x, params["w_latent_down"])
+    exchange = {}
     if expert_parallel:
-        if held != n_experts or "w_gateup" not in params \
-                or "w_latent_down" in params or act != "silu":
-            raise ValueError("an expert mesh axis shards all of a layer's "
-                             "gated silu experts; a held share, plain "
-                             "experts and a latent run without it")
-        constrain = functools.partial(with_logical_constraint, mesh=mesh,
-                                      rules=rules)
-        y, counts, dropped = _capacity_ffn(params, x, top_w, top_e,
-                                           capacity_factor, constrain)
-        bounded = jnp.zeros((), jnp.int32)
+        y, exchange = _exchange_ffn(params, rows, top_w, top_e, mesh, rules,
+                                    act)
+        counts = exchange.pop("tokens_per_expert")
+        dropped = bounded = jnp.zeros((), jnp.int32)
     else:
-        rows = x
-        if "w_latent_down" in params:
-            with jax.named_scope("moe/latent"):
-                rows = jnp.einsum("nd,dr->nr", x, params["w_latent_down"])
         y, counts, dropped, bounded = _sorted_ffn(
             params, rows, top_w, top_e, mesh, expert_offset, act)
-        if "w_latent_up" in params:
-            with jax.named_scope("moe/latent"):
-                y = jnp.einsum("nr,rd->nd", y, params["w_latent_up"])
+    if "w_latent_up" in params:
+        with jax.named_scope("moe/latent"):
+            y = jnp.einsum("nr,rd->nd", y, params["w_latent_up"])
     shared = params.get("w_shared_gateup", params.get("w_shared_up"))
     if shared is not None:
         with jax.named_scope("moe/shared"):
@@ -676,7 +998,7 @@ def moe_ffn(params: Dict[str, Any], x, *, num_selected: int = 2,
             elsewhere = x.shape[0] * k - counts.sum()
     routing = {"tokens_per_expert": counts, "slots_elsewhere": elsewhere,
                "router_prob": router_prob, "dropped": dropped,
-               "rows_bounded": bounded}
+               "rows_bounded": bounded, **exchange}
     if groups:
         routing["groups_chosen"], = groups
     return y, routing

@@ -69,6 +69,11 @@ DEFAULT_RULES: Dict[str, Union[str, Tuple[str, ...], None]] = {
     "vocab": AXIS_TENSOR,
     "layers": AXIS_PIPE,
     "expert": AXIS_EXPERT,
+    # an expert's own d_model dimension: `embed`'s axis by default, and a
+    # name of its own because a layout that lays the experts over that
+    # axis (`expert` -> "fsdp": the experts by expert on the axis that
+    # holds everything else in shards) must keep it whole
+    "expert_embed": AXIS_FSDP,
     "norm": None,
     # Activation axes (distinct from param axes: activations keep their
     # feature dims replicated/tensor-sharded even when params are

@@ -115,19 +115,25 @@ def _aux(routing, k):
 
 
 class TestMoE:
-    """Both lowerings of `moe_ffn` on the same gated experts: the sorted
-    dropless path (no mesh, or no expert axis) and the capacity path an
-    `expert` mesh axis above 1 selects."""
+    """`moe_ffn` on the same gated experts on one device and on meshes
+    whose `expert` axis is above 1: the sorted dropless path everywhere
+    (under `shard_map` there: the shares summed where the expert shards
+    hold the same tokens, the tokens exchanged where they are divided
+    over that axis)."""
 
-    @pytest.mark.parametrize("path", ["sorted", "capacity"])
+    @pytest.mark.parametrize("path", ["sorted", "mesh", "exchange"])
     def test_matches_dense_reference_with_ample_capacity(self, path):
+        from ray_tpu.parallel.sharding import ShardingRules
+
         mesh = None if path == "sorted" else make_mesh(
             MeshConfig(data=2, expert=4))
+        rules = ShardingRules().replace(batch=("data", "expert")) \
+            if path == "exchange" else None
         key = jax.random.PRNGKey(0)
         params = init_moe_params(key, d_model=16, d_ff=32, n_experts=4)
         x = jax.random.normal(jax.random.PRNGKey(1), (24, 16))
-        y, routing = moe_ffn(params, x, num_selected=2,
-                             capacity_factor=4.0, mesh=mesh)
+        y, routing = moe_ffn(params, x, num_selected=2, mesh=mesh,
+                             rules=rules)
         y_ref = moe_ffn_dense_reference(params, x, num_selected=2)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-5)
@@ -135,33 +141,36 @@ class TestMoE:
         assert int(routing["dropped"]) == 0
         assert int(routing["tokens_per_expert"].sum()) == 24 * 2
 
-    def test_capacity_drops_tokens(self):
-        """The expert-parallel branch keeps the Switch capacity contract;
-        the sorted path, given the same skew, drops nothing."""
+    @pytest.mark.parametrize("batch", [("data",), ("data", "expert")],
+                             ids=["shares_summed", "tokens_exchanged"])
+    def test_nothing_dropped_at_the_load_that_used_to_drop(self, batch):
+        """The skew under which the capacity path (gone: ROADMAP D9)
+        dropped tokens at a tight factor: an expert mesh drops nothing,
+        zeroes no token's output and equals the one-device path."""
+        from ray_tpu.parallel.sharding import ShardingRules
+
         mesh = make_mesh(MeshConfig(data=4, expert=2))
+        rules = ShardingRules().replace(batch=batch)
         key = jax.random.PRNGKey(2)
         params = init_moe_params(key, d_model=8, d_ff=16, n_experts=2)
         x = jax.random.normal(jax.random.PRNGKey(3), (16, 8))
-        y_tight, r_tight = moe_ffn(params, x, num_selected=1,
-                                   capacity_factor=0.25, mesh=mesh)
-        y_ample, r_ample = moe_ffn(params, x, num_selected=1,
-                                   capacity_factor=4.0, mesh=mesh)
-        # tight capacity zeroes some tokens' outputs
-        dropped = np.sum(np.all(np.asarray(y_tight) == 0.0, axis=-1))
-        kept_all = np.sum(np.all(np.asarray(y_ample) == 0.0, axis=-1))
-        assert dropped > kept_all
-        assert int(r_tight["dropped"]) == dropped > 0
-        assert int(r_ample["dropped"]) == 0
-        y_sorted, r_sorted = moe_ffn(params, x, num_selected=1,
-                                     capacity_factor=0.25)
-        assert int(r_sorted["dropped"]) == 0
-        np.testing.assert_allclose(np.asarray(y_sorted),
-                                   np.asarray(y_ample), rtol=1e-4,
-                                   atol=1e-5)
+        y_mesh, r_mesh = moe_ffn(params, x, num_selected=1, mesh=mesh,
+                                 rules=rules)
+        y_sorted, r_sorted = moe_ffn(params, x, num_selected=1)
+        counts = np.asarray(r_sorted["tokens_per_expert"])
+        # the capacity path's buffer at its tight factor 0.25 held
+        # int(0.25 * 16 * 1 / 2) = 2 slots an expert, and dropped the rest
+        assert counts.min() > 2
+        assert int(r_mesh["dropped"]) == int(r_sorted["dropped"]) == 0
+        np.testing.assert_array_equal(r_mesh["tokens_per_expert"], counts)
+        assert not np.all(np.asarray(y_mesh) == 0.0, axis=-1).any()
+        np.testing.assert_allclose(np.asarray(y_mesh), np.asarray(y_sorted),
+                                   rtol=1e-4, atol=1e-5)
 
     def test_sharded_over_expert_axis(self):
-        """The einsum formulation runs under jit with params sharded on
-        the expert mesh axis (GSPMD inserts the all-to-alls)."""
+        """The sorted path runs under jit with params sharded on the
+        expert mesh axis (`shard_map` over it; the tokens are not divided
+        over it here, so the shards' shares are summed)."""
         from ray_tpu.parallel.sharding import shard_pytree
         from ray_tpu.ops.moe import MOE_PARAM_SPECS
 
@@ -174,8 +183,7 @@ class TestMoE:
 
         @jax.jit
         def f(p, x):
-            return moe_ffn(p, x, num_selected=2, capacity_factor=4.0,
-                           mesh=mesh)
+            return moe_ffn(p, x, num_selected=2, mesh=mesh)
 
         y, _routing = f(params_sharded, x)
         y_ref = moe_ffn_dense_reference(params, x, num_selected=2)
