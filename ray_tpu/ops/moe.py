@@ -79,22 +79,37 @@ time (no option):
   expert id, which is by destination too (a shard's E/P experts are a
   contiguous run of ids), tells every shard how many rows it sends for
   each of its experts (an all-to-all of `[P, E/P]` int32), sends each
-  shard the rows of its experts in one bucket of `exchange_bound` rows
-  (twice the uniform share n·k/P, in whole tiles: the send and the
-  receive buffers are `[P · bound, d]`, so at the uniform load half of
-  what travels is padding), regroups what it received by expert (the
-  sources' runs are already in expert order: a gather from 64-entry
-  tables), runs the grouped matmuls over it with its own experts
-  (`megablox` per device, as on one device), and sends the results back
-  the way they came; `combine` reads them by the inverse permutation. No
-  `[N, E, C]` tensor and no token dropped: a step in which some bucket of
-  some shard would overflow takes, on EVERY shard (a `pmax` of the
-  overflow decides the one `lax.cond` a layer: a collective in a branch
-  that some shards skip hangs the gang), the same exchange in
-  `ceil(n·k / bound)` rounds of that bucket, which takes any load in the
-  memory of one round. `exchange_bounded` says which ran. Gradients pass
-  through both exchanges (an all-to-all's transpose is the all-to-all
-  back) and every permutation stays a gather in the backward pass. Where
+  shard the rows of its experts, which then lie there by expert (an
+  expert's rows from source 0, 1, ...), runs the grouped matmuls over
+  them with its own experts (`megablox` per device, as on one device),
+  and sends the results back the way they came; `combine` reads them by
+  the inverse permutation. How the rows travel is what the platform
+  offers (`exchange_impl`, at trace time). **Ragged**, on a TPU: the
+  shard's n·k sorted rows are the send buffer as they are, E runs one
+  behind the other, and `jax.lax.ragged_all_to_all` puts the run of
+  expert e straight where that expert's shard keeps this source's rows
+  for e, in a receive buffer of `P · exchange_bound` rows (twice a
+  shard's mean total) that is in the grouped matmul's order as it
+  arrives; each shard has told the others where (one more int32
+  all-to-all, `[P, 2, E/P]`: the places on its two sides). The rows that
+  leave a shard are the rows routed elsewhere, nothing is gathered on the
+  receiving side, and what the bound bounds is a shard's RECEIVED total.
+  **Buckets**, elsewhere (XLA:CPU has no ragged all-to-all): one bucket
+  of `exchange_bound` rows a (from, to) pair (twice the uniform share
+  n·k/P, in whole tiles) through a fixed-shape `all_to_all`, regrouped by
+  expert by a gather from 64-entry tables (the sources' runs are already
+  in expert order) and ungrouped by another on the way back: the send
+  and the receive buffers are `[P · bound, d]`, so at the uniform load
+  half of what travels and of what is gathered is padding, and the bound
+  bounds every bucket. No `[N, E, C]` tensor and no token dropped: a step
+  in which a receive buffer (a bucket) of some shard would overflow
+  takes, on EVERY shard (a `pmax` of the overflow decides the one
+  `lax.cond` a layer: a collective in a branch that some shards skip
+  hangs the gang), the exchange in `ceil(n·k / bound)` rounds of dense
+  buckets, which takes any load in the memory of one round.
+  `exchange_bounded` says which ran. Gradients pass through both
+  exchanges (an all-to-all's transpose is the all-to-all back, ragged or
+  dense) and every permutation stays a gather in the backward pass. Where
   the tokens are NOT divided over the experts' axis (its shards hold the
   same tokens) nothing is exchanged: each shard is the held share above at
   its own offset and the shares are summed (`psum`).
@@ -106,8 +121,8 @@ the rows received, gather),
 gather back and the weighted sum; the caller adds the residual there),
 `moe/shared` (the shared expert), `moe/latent` (the two projections
 around experts that live in a latent), `moe/exchange` (on an expert mesh:
-the counts' all-to-all, the overflow's `pmax` and both row exchanges,
-forward and backward).
+the counts' and the offsets' all-to-alls, the overflow's `pmax` and both
+row exchanges, forward and backward).
 """
 
 from __future__ import annotations
@@ -436,6 +451,16 @@ def grouped_matmul_impl(mesh, rows: int, d_model: int, d_ff: int,
         else "ragged_dot"
 
 
+def exchange_impl(mesh) -> str:
+    """How the rows of the one bounded round of the exchange travel
+    (`_exchange_ffn`): `ragged`, each shard's sorted rows as they are
+    through `jax.lax.ragged_all_to_all`, where the mesh's devices are TPUs;
+    `buckets`, a fixed-shape `all_to_all` of buckets of `exchange_bound`
+    rows, everywhere else (XLA:CPU has no ragged all-to-all). Decided at
+    trace time from what the mesh says, as `grouped_matmul_impl`."""
+    return "ragged" if mesh.devices.flat[0].platform == "tpu" else "buckets"
+
+
 def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot",
                 act: str = "silu"):
     """The expert FFN over rows already in expert order: xs `[M, d]`,
@@ -588,12 +613,18 @@ def exchange_bound(slots: int, shards: int) -> Optional[int]:
     of `GMM_ROWS`, so that the `shards` buckets a shard receives are whole
     row tiles of the kernel, or sublane tiles of 8 where the share is
     smaller than that. None where the bucket would hold every slot: it
-    cannot overflow then, and no `cond` is traced. What the bound costs:
-    send and receive buffers of `shards * bound` rows where `slots` would
-    do under a ragged exchange, and at the uniform load
+    cannot overflow then, and no `cond` is traced. `shards` of them are
+    the receive buffer and the rows of the grouped matmuls on both paths
+    (`exchange_impl`). What it bounds in the one round that carries every
+    slot, and what it costs: under **buckets** every (from, to) bucket;
+    the send buffer too is `shards * bound` rows, and at the uniform load
     `(shards - 1) * bound` rows leave a shard where
-    `slots * (shards - 1) / shards` are needed: `EXCHANGE_BOUND` times as
-    many."""
+    `slots * (shards - 1) / shards` are needed, `EXCHANGE_BOUND` times as
+    many. Under **ragged** a shard's RECEIVED total, `shards * bound` rows
+    (`EXCHANGE_BOUND` times the mean: no (from, to) run has a bound of its
+    own); the rows sent are the rows needed, and the bound's rows are the
+    static shape of the receive buffer and of the grouped matmuls, whose
+    kernels visit the rows received."""
     share = -(-EXCHANGE_BOUND * slots // shards)
     tile = max(GMM_ROWS // shards, 8)
     if share < tile:
@@ -673,6 +704,47 @@ def _round_tables(r, bucket: int, k: int, slot_expert, order, inverse,
 
 
 @functools.lru_cache(maxsize=None)
+def _ragged_exchange(axis, rows_in: int, rows_out: int, filled: bool):
+    """`moved(rows, here, there)`: runs of rows between the shards of
+    `axis` through `jax.lax.ragged_all_to_all`, `rows [rows_in, d]` into a
+    buffer `[rows_out, d]`. `here` and `there` describe the two sides of
+    the one exchange, each three int32 `[P · r]`, r runs a pair of
+    shards, entry `p · r + j` for run j to (from) shard p: where the run
+    starts in this shard's buffer, how many rows it has, and where it
+    starts in p's buffer on the other side. Where nothing lands the
+    buffer holds zeros if `filled`, else whatever the memory held: for
+    readers that never use those rows. The transpose is the same exchange with
+    the sides swapped (every row that travelled has one place on each
+    side), so the backward pass is that call: no cotangent for the receive
+    buffer, and none of the masks and offset all-to-alls jax's own
+    transpose rule puts around it."""
+    import jax
+    import jax.numpy as jnp
+
+    # on a TPU `lax.empty` allocates and writes nothing (a fill of the
+    # 604 MB buffer read 1.85 ms on the v5e, PERF.md section 6, PR 58)
+    buffer = jnp.zeros if filled else jax.lax.empty
+
+    @jax.custom_vjp
+    def moved(rows, here, there):
+        start, size, lands = here
+        return jax.lax.ragged_all_to_all(
+            rows, buffer((rows_out,) + rows.shape[1:], rows.dtype),
+            start, size, lands, there[1], axis_name=axis)
+
+    def fwd(rows, here, there):
+        return moved(rows, here, there), (here, there)
+
+    def bwd(res, g):
+        here, there = res
+        back = _ragged_exchange(axis, rows_out, rows_in, filled)
+        return back(g, there, here), None, None
+
+    moved.defvjp(fwd, bwd)
+    return moved
+
+
+@functools.lru_cache(maxsize=None)
 def _regrouped(padded: str = "fill"):
     """`regrouped(rows, index, back)`: `rows[index]`, an index past the end
     reading zero ("fill") or the last row ("clip": what a row of no slot
@@ -712,11 +784,12 @@ def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
     record): `tokens_per_expert [E]` over all shards, and a shard each
     (`[shards]`, the mesh's token and expert shards in order)
     `rows_received` (rows its experts ran, its own among them),
-    `exchange_rows_sent` (rows that left it, padding included),
+    `exchange_rows_sent` (rows that left it, the buckets' padding
+    included: under the ragged exchange the rows needed),
     `exchange_rows_needed` (its slots routed to other shards),
     `exchange_pairs` (its distinct (token, other shard) pairs: the least
-    any exchange must move) and `exchange_bounded` (1: one round of
-    bounded buckets; 0: the rounds that take any load)."""
+    any exchange must move) and `exchange_bounded` (1: the one bounded
+    round; 0: the rounds that take any load)."""
     import jax
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
@@ -781,9 +854,60 @@ def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
             (order, inverse, counts, recv_counts), ROUTING_RESIDUALS)
         bound = exchange_bound(m, shards)
         bucket = bound or m
-        impl = grouped_matmul_impl(mesh, shards * bucket, d,
-                                   w_first.shape[-1], gated=gated,
-                                   per_shard=True)
+        size = shards * bucket
+        impl = grouped_matmul_impl(mesh, size, d, w_first.shape[-1],
+                                   gated=gated, per_shard=True)
+        ragged = exchange_impl(mesh) == "ragged"
+
+        def fast_tables():
+            """What the one round that carries every slot reads, kept
+            with the routing."""
+            if not ragged:
+                return _round_tables(0, bucket, k, slot_expert, order,
+                                     inverse, counts, recv_counts)
+            # the two sides of the ragged exchange (`_ragged_exchange`), a
+            # run a (shard, expert): this shard's sorted slots, E runs one
+            # behind the other, and its receive buffer in the order the
+            # grouped matmul takes, expert-major, an expert's rows from
+            # source 0, 1, ...: the rows land regrouped. Each shard tells
+            # the others where their runs lie on its two sides
+            groups = recv_counts.sum(0)                          # [held]
+            target = (jnp.cumsum(groups) - groups)[None, :] \
+                + jnp.cumsum(recv_counts, axis=0) - recv_counts  # [P, held]
+            starts = (jnp.cumsum(counts) - counts).reshape(shards, held)
+            with jax.named_scope("moe/exchange"):
+                lands = jax.lax.all_to_all(
+                    jnp.stack([target, starts], 1), ep, 0, 0)    # [P,2,held]
+            groups = jnp.concatenate(
+                [groups, size - groups.sum(keepdims=True)]).astype(jnp.int32)
+            return (groups,
+                    (starts.reshape(-1), counts, lands[:, 0].reshape(-1)),
+                    (target.reshape(-1), recv_counts.reshape(-1),
+                     lands[:, 1].reshape(-1)))
+
+        def ragged_round(x, top_w, w_first, w_down, groups, *sides):
+            """The one round that carries every slot, each shard's sorted
+            rows sent as they are: the one-device path's two permutations
+            around the exchange, no row of no slot on the sending side and
+            no regrouping on the receiving one."""
+            slots_of, combine = _permutes("clip")
+            sorted_side, received_side = sides[:3], sides[3:]
+            # the receive buffer's rows past the received: `megablox`
+            # masks the rows of no held group by selects, forward and
+            # transposes, so what they hold is never used
+            filled = impl != "megablox"
+            with jax.named_scope("moe/dispatch"):
+                sent = slots_of(x, order, inverse, k)            # [m, d]
+            with jax.named_scope("moe/exchange"):
+                xs = _ragged_exchange(ep, m, size, filled)(
+                    sent, sorted_side, received_side)
+            with jax.named_scope("moe/experts"):
+                ys = experts_ffn(xs, w_first, w_down, groups, impl, act)
+            with jax.named_scope("moe/exchange"):
+                back = _ragged_exchange(ep, size, m, filled)(
+                    ys, received_side, sorted_side)
+            with jax.named_scope("moe/combine"):
+                return combine(back, top_w, order, inverse)
 
         def one_round(r, x, top_w, w_first, w_down, tables=None,
                       impl="ragged_dot"):
@@ -814,27 +938,33 @@ def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
                 return combine(back.reshape(-1, d), top_w, order_send,
                                pos_send)
 
+        def fast_round(x, top_w, w_first, w_down, *tables):
+            if ragged:
+                return ragged_round(x, top_w, w_first, w_down, *tables)
+            return one_round(0, x, top_w, w_first, w_down, tables, impl)
+
         operands = (x, top_w, w_first, w_down)
         if bound is None:
             with jax.named_scope("moe/dispatch"):
-                tables = _round_tables(0, bucket, k, slot_expert, order,
-                                       inverse, counts, recv_counts)
-            y = one_round(0, *operands, tables, impl)
+                tables = jax.tree.leaves(fast_tables())
+            y = fast_round(*operands, *tables)
             fits = jnp.ones((), jnp.bool_)
             rounds = 1
         else:
             rounds = -(-m // bucket)
             with jax.named_scope("moe/dispatch"):
-                # the fast round's tables are kept with the routing
-                tables = checkpoint_name(
-                    _round_tables(0, bucket, k, slot_expert, order, inverse,
-                                  counts, recv_counts), ROUTING_RESIDUALS)
-                fullest = counts.reshape(shards, held).sum(1).max()
+                tables = jax.tree.leaves(checkpoint_name(
+                    fast_tables(), ROUTING_RESIDUALS))
+                # one round carries every slot iff nothing overflows: a
+                # shard's receive buffer where its rows lie packed, else
+                # some (from, to) bucket
+                fullest, room = (recv_counts.sum(), size) if ragged else (
+                    counts.reshape(shards, held).sum(1).max(), bucket)
             with jax.named_scope("moe/exchange"):
                 # on a value every shard agrees on: a collective in a
                 # branch that some shards skip hangs the gang
                 fits = checkpoint_name(
-                    jax.lax.pmax(fullest, ep) <= bucket, ROUTING_RESIDUALS)
+                    jax.lax.pmax(fullest, ep) <= room, ROUTING_RESIDUALS)
 
             def any_load(x, top_w, w_first, w_down, *tables):
                 # its grouped matmuls are `ragged_dot`, as the held share's
@@ -852,11 +982,8 @@ def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
                 return y.astype(x.dtype)
 
             with jax.named_scope("moe/dispatch"):
-                y = jax.lax.cond(
-                    fits,
-                    lambda x, top_w, w_first, w_down, *tables: one_round(
-                        0, x, top_w, w_first, w_down, tables, impl),
-                    jax.checkpoint(any_load), *operands, *tables)
+                y = jax.lax.cond(fits, fast_round, jax.checkpoint(any_load),
+                                 *operands, *tables)
         with jax.named_scope("moe/dispatch"):
             mine = jax.lax.axis_index(ep)
             own = jax.lax.dynamic_slice_in_dim(counts, mine * held,
@@ -865,6 +992,8 @@ def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
             needed = (m - own).reshape(1)
             sent_rows = jnp.where(fits, 1, rounds).astype(jnp.int32) \
                 * ((shards - 1) * bucket)
+            if ragged:      # the one round sent the rows needed, no more
+                sent_rows = jnp.where(fits, needed[0], sent_rows)
             # the distinct (token, other shard) pairs
             to = jax.lax.broadcasted_iota(jnp.int32, (1, 1, shards), 2)
             reached = ((top_e // held)[..., None] == to).any(1)    # [n, P]

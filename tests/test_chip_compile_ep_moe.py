@@ -39,8 +39,9 @@ def test_expert_parallel_step_compiles_and_fits_the_v5e_host(v5e_host):
     with the whole vocabulary, as one train step of 4 x 8,192 tokens for
     the FOUR described devices of a v5e host (the benchmark's
     `train_mellum2_ep4_d4`): the experts 16 a device behind the exchange
-    (`ops/moe._exchange_ffn`: all-to-alls in the compiled step, `megablox`
-    inside the `shard_map` over the 131,072 rows of the receive buffer),
+    (`ops/moe._exchange_ffn`: the rows through `ragged-all-to-all`, the
+    counts through all-to-alls in the compiled step, `megablox` inside the
+    `shard_map` over the 131,072 rows of the receive buffer),
     everything else sharded four ways and gathered for use, splash under
     a window and under the causal mask by their scopes, and the compile's
     memory report: whether one sequence a chip fits, at no chip time."""
@@ -50,7 +51,7 @@ def test_expert_parallel_step_compiles_and_fits_the_v5e_host(v5e_host):
 
     from ray_tpu.models import Transformer
     from ray_tpu.models.configs import TransformerConfig
-    from ray_tpu.ops.moe import exchange_bound, gmm_tiles
+    from ray_tpu.ops.moe import exchange_bound, exchange_impl, gmm_tiles
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.sharding import ShardingRules
     from ray_tpu.parallel.train_step import make_train_step
@@ -102,16 +103,26 @@ def test_expert_parallel_step_compiles_and_fits_the_v5e_host(v5e_host):
     splash = sorted({re.search(r"attention/(\w+)", op).group(1)
                      for n, op in kernels if "splash" in n})
     assert splash == ["full", "window"], splash
+    # the rows travel ragged (`ops/moe.exchange_impl`: these are TPUs), out
+    # and back in the forward, in remat's forward and in the backward of
+    # each scan; the counts, the offsets and the rounds that take any load
+    # through dense all-to-alls
+    assert exchange_impl(mesh) == "ragged"
+    ragged = [line for line in hlo.splitlines()
+              if re.search(r" ragged-all-to-all(-start)?\(", line)]
+    assert len(ragged) == 12, len(ragged)
     exchanges = [line for line in hlo.splitlines()
                  if re.search(r" all-to-all(-start)?\(", line)]
-    assert exchanges and all("moe/exchange" in line for line in exchanges)
+    assert exchanges and all("moe/exchange" in line
+                             for line in exchanges + ragged)
     for scope in ("rope/plain", "rope/yarn", "qkv/qk_norm", "moe/router",
                   "moe/dispatch", "moe/exchange", "moe/experts",
                   "moe/combine", "head"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)"]', hlo), scope
     # a device's share of the state (params and adamw's moments: 12 B a
     # parameter over four devices) and the step's peak within the chip's
-    # 16.9 GB: one sequence a chip fits (15.45 GB when this was written)
+    # 16.9 GB: one sequence a chip fits (15.45 GB when this was written;
+    # 15.44 with the rows sent ragged, PR 58)
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes == pytest.approx(
         12 * cfg.num_params / 4, rel=1e-3)

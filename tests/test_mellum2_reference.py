@@ -468,6 +468,105 @@ def test_dropless_at_any_load_and_the_branch_is_agreed(load):
             [0, 64, 64, 64]
 
 
+def ragged_all_to_all_from_gathers(operand, output, input_offsets,
+                                   send_sizes, output_offsets, recv_sizes,
+                                   *, axis_name, axis_index_groups=None):
+    """`jax.lax.ragged_all_to_all` as its documentation defines it, for
+    XLA:CPU, which has none: every shard gathers every shard's operand and
+    offsets, and row q of its output is the row of the source whose run
+    covers q (`recv_sizes`: the receiver's own word for how long each run
+    is), or `output`'s where nothing lands."""
+    me = jax.lax.axis_index(axis_name)
+    runs = recv_sizes.size // jax.lax.axis_size(axis_name)   # a pair
+
+    def for_me(told):       # [P, P * runs] -> the senders' runs for me
+        return jax.lax.dynamic_slice_in_dim(
+            jax.lax.all_gather(told, axis_name), me * runs, runs,
+            axis=1).reshape(-1)
+
+    operands = jax.lax.all_gather(operand, axis_name)        # [P, rows, d]
+    starts, lands = for_me(input_offsets), for_me(output_offsets)
+    q = jnp.arange(output.shape[0])
+    covers = (q >= lands[:, None]) & (q < (lands + recv_sizes)[:, None])
+    run = covers.argmax(0)
+    row = jnp.clip(starts[run] + q - lands[run], 0, operand.shape[0] - 1)
+    return jnp.where(covers.any(0)[:, None], operands[run // runs, row],
+                     output)
+
+
+RAGGED_BOUNDED = {"all_to_one_chip": 0, "one_chip_overflows": 1, "spread": 1,
+                  "at_the_bound": 1}
+
+
+@pytest.mark.parametrize("load", sorted(LOADS))
+def test_the_ragged_exchange_sends_the_rows_it_has(load, monkeypatch):
+    """The TPU's lowering of the one bounded round (`moe.exchange_impl`:
+    each shard's sorted rows through `ragged_all_to_all`) forced onto the
+    CPU's mesh with the collective emulated: output and gradients against
+    the one-device sorted path and the reference's loop, the rows sent
+    are the rows needed, and the branch by what a chip RECEIVES (chip 3's
+    1.75 of the mean fits the receive buffer of twice the mean; every row
+    to one chip does not)."""
+    monkeypatch.setattr(moe, "exchange_impl", lambda mesh: "ragged")
+    monkeypatch.setattr(jax.lax, "ragged_all_to_all",
+                        ragged_all_to_all_from_gathers)
+    with jax.default_matmul_precision("highest"):
+        params, x, top_w = layer_and_rows()
+        top_e = LOADS[load]()
+        mesh = mesh_of(4)
+
+        def weigh(y):
+            return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
+
+        def one_device(p, x, w):
+            y = moe._sorted_ffn(p, x, w, top_e, None)[0]
+            return weigh(y), y
+
+        def exchanged(p, x, w):
+            y, record = moe._exchange_ffn(p, x, w, top_e, mesh, RULES)
+            return weigh(y), (y, record)
+
+        (_, want), want_grads = jax.jit(jax.value_and_grad(
+            one_device, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
+        jaxpr = str(jax.make_jaxpr(jax.grad(
+            lambda *a: exchanged(*a)[0], argnums=(0, 1, 2)))(
+                params, x, top_w))
+        (_, (got, record)), grads = jax.jit(jax.value_and_grad(
+            exchanged, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
+        assert_close(got, want, "y")
+        assert_close(got, loop_reference(params, x, top_w, top_e),
+                     "y against the loop")
+        for a, b in zip(jax.tree.leaves(grads),
+                        jax.tree.leaves(want_grads)):
+            if np.abs(np.asarray(b)).max() > 0:
+                assert_close(a, b, "gradient")
+    # every permutation a gather in the backward pass too
+    assert "scatter" not in jaxpr
+    bounded = RAGGED_BOUNDED[load]
+    assert np.asarray(record["exchange_bounded"]).tolist() == [bounded] * 4
+    counts = np.asarray(record["tokens_per_expert"])
+    assert counts.sum() == N * K
+    np.testing.assert_array_equal(
+        record["rows_received"], counts.reshape(4, -1).sum(-1))
+    needed = np.asarray(record["exchange_rows_needed"])
+    # chip c's slots for its own experts stay: its tokens are rows c*64..
+    own = [int(((np.asarray(top_e)[c * 64:(c + 1) * 64] // 2) == c).sum())
+           for c in range(4)]
+    assert needed.tolist() == [N // 4 * K - o for o in own]
+    sent = np.asarray(record["exchange_rows_sent"])
+    if bounded:
+        np.testing.assert_array_equal(sent, needed)
+    else:       # the dense rounds that take any load, as on the CPU
+        assert sent.tolist() == [2 * 3 * 64] * 4
+    if load == "one_chip_overflows":
+        # chip 0's 128 slots on top of its near-uniform share of the rest
+        assert 192 < np.asarray(record["rows_received"])[3] <= 256
+
+
+def test_exchange_impl_by_what_the_mesh_says():
+    assert moe.exchange_impl(mesh_of(4)) == "buckets"
+
+
 def test_the_held_shares_add_up_to_the_exchange_and_the_uncut_layer():
     """`moe_ffn` as a held share at each of the four offsets (the one-chip
     path of the share cells) sums to what the exchange gives and to the
