@@ -29,7 +29,10 @@ groups of experts (`moe_groups`, `moe_topk_groups`). `W` is attention
 under a causal window of `attn_window` keys FOLLOWED by experts, beside
 `L`, full attention then experts: a model that mixes the two
 (`layer_types`), with its RoPE scaled by YaRN (`rope_yarn_factor`) on the
-full layers only.
+full layers only. `d` is a Gated DeltaNet mixer (ops/kda.py: the delta
+rule behind one decay a head) and `a` softmax attention, each FOLLOWED by
+a dense MLP and each sublayer under OLMo-2/3's reordered norm,
+`x + norm(f(x))` with no norm before the sublayer.
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -257,6 +260,24 @@ class TransformerConfig:
     rope_yarn_beta_fast: float = 32.0
     rope_yarn_beta_slow: float = 1.0
     rope_yarn_attention_factor: float = 0.0
+    # Two more kinds of `layer_pattern`, each a sublayer FOLLOWED by a
+    # dense gated MLP (width moe_dense_ff or d_ff) and each sublayer under
+    # the reordered norm of OLMo-2/3, `x + norm(f(x))`: no norm before a
+    # sublayer, one on its output (the leaves `<name>_post_norm` where the
+    # other kinds have `<name>_norm`; the kind says where the norm sits,
+    # no other field does). `d` a Gated DeltaNet mixer (arXiv 2412.06464;
+    # ops/kda.py: gdn_heads heads with keys of gdn_key_dim and values of
+    # gdn_value_dim, one depthwise causal convolution of gdn_conv_kernel
+    # taps over q, k and v, ONE unbounded decay a head and step, beta in
+    # (0, 2) where gdn_neg_eigval (`allow_neg_eigval`) else (0, 1), a gate
+    # a channel on the normed output, the delta rule in chunks of
+    # gdn_chunk); `a` softmax attention as the other sizes say.
+    gdn_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv_kernel: int = 4
+    gdn_neg_eigval: bool = False
+    gdn_chunk: int = 64
 
     def __post_init__(self):
         if self.norm not in ("rms", "layernorm"):
@@ -280,7 +301,7 @@ class TransformerConfig:
             if bool(set(EXPERT_KINDS) & set(self.layer_pattern)) \
                     != bool(self.moe_experts) or self.moe_dense_layers \
                     or (self.kv_lora_rank
-                        and set("*wfcW") & set(self.layer_pattern)):
+                        and set("*wfcWa") & set(self.layer_pattern)):
                 raise ValueError("a layer_pattern has expert layers where "
                                  "it says E, K, L or W, no leading dense "
                                  "run (moe_dense_layers) and latent "
@@ -288,6 +309,9 @@ class TransformerConfig:
             if set("kK") & set(self.layer_pattern) and not self.kda_heads:
                 raise ValueError("Kimi Delta Attention (k, K) needs "
                                  "kda_heads")
+            if "d" in self.layer_pattern and not self.gdn_heads:
+                raise ValueError("a Gated DeltaNet mixer (d) needs "
+                                 "gdn_heads")
         if self.moe_act not in ("silu", "relu2"):
             raise ValueError(f"unknown moe_act {self.moe_act!r}")
         if self.kv_lora_rank:
@@ -520,6 +544,17 @@ class TransformerConfig:
                 + d * h * hd + h + h * hd + 2 * d * h + hd + h * hd * d)
 
     @property
+    def _gdn_params(self) -> int:
+        """A Gated DeltaNet mixer without its norm: q, k, v and their
+        convolution, the decay's and beta's projections with A and dt, the
+        output gate's, the head norm's gain, W_o."""
+        d, h = self.d_model, self.gdn_heads
+        dk, dv = self.gdn_key_dim, self.gdn_value_dim
+        return (d * h * (2 * dk + dv) + h * (2 * dk + dv)
+                * self.gdn_conv_kernel + 2 * d * h + 2 * h + d * h * dv
+                + dv + h * dv * d)
+
+    @property
     def _norm_leaves(self) -> int:
         """Leaves of width d_model a norm has: a gain, and a bias."""
         return 2 if self.norm == "layernorm" else 1
@@ -547,15 +582,17 @@ class TransformerConfig:
         each = {"m": mixer, "s": mixer, "w": attn + bias + diff,
                 "f": attn + bias + diff, "g": 2 * d * inner,
                 "c": attn + bias + diff - kv, "k": self._kda_params,
-                "l": attn}
+                "l": attn, "d": self._gdn_params, "a": attn}
         return {kind: count + rest for kind, count in each.items()}
 
 
 # the kinds of `layer_pattern` that are followed by a dense MLP in the same
-# layer (TransformerConfig: `m`, `s`, `w`, `f`, `g`, `c`, `k`, `l`), and
-# those that are or end in an expert layer (`E` alone, `K`, `L`, `W`)
-FFN_KINDS = "msfwgckl"
+# layer (TransformerConfig: `m`, `s`, `w`, `f`, `g`, `c`, `k`, `l`, `d`,
+# `a`), those that are or end in an expert layer (`E` alone, `K`, `L`,
+# `W`), and those whose sublayers stand under the reordered norm
+FFN_KINDS = "msfwgcklda"
 EXPERT_KINDS = "EKLW"
+REORDERED_KINDS = "da"
 
 
 def pattern_runs(pattern: str):

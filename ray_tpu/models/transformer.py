@@ -21,8 +21,13 @@ a gated memory unit, `mlp_norm` an FFN or experts (`w_router` makes it
 experts), and the homogeneous layer is the one with both the first and
 the last; `w_x` makes the mixer Mamba-1, `lambda_q1` makes
 attention differential, no `wkv` makes it cross-attention, a
-`<norm>_bias` makes the norm a LayerNorm. What the leaves cannot say the
-layer's kind in `layer_pattern` does: a window, and which layer's
+`<norm>_bias` makes the norm a LayerNorm, `gdn_norm` brings a Gated
+DeltaNet mixer. A sublayer's residual is formed by ONE rule
+(`_make_layer_fn`'s `entering` and `residual`): `x + f(norm(x))` where the
+leaves have the norm `<name>_norm` that opens the sublayer, and under
+OLMo-2/3's reordered norm (the kinds `d` and `a`) `x + norm(f(x))`, the
+leaves holding `<name>_post_norm` in its place. What the leaves cannot
+say the layer's kind in `layer_pattern` does: a window, and which layer's
 tensors cross layers (`_stack`'s `shared`: the scan output `memory` of
 the mixer `s`, the `k` and `v` of the attention layer `f`).
 
@@ -50,8 +55,13 @@ Mamba-1 mixer `ssm/x_proj` and `ssm/gate` and no `ssm/gate_norm`), in a
 gated memory unit `gmu_norm` and `gmu/in_proj`, `gmu/gate`,
 `gmu/out_proj`, in Kimi Delta Attention `kda_norm` and `kda/qkv_proj`,
 `kda/conv`, `kda/gates`, `kda/delta`, `kda/out_norm`, `kda/out_proj`
-(ops/kda.py), `final_norm`, `head`, `loss` (the vocab head and the
-cross-entropy: models/head.py); a block-diffusion model's loss adds
+(ops/kda.py), in a Gated DeltaNet mixer `gdn/qkv_proj`, `gdn/conv`,
+`gdn/gates`, `gdn/delta`, `gdn/out_norm`, `gdn/out_proj` (ops/kda.py), under
+the reordered norm `attn_post_norm`, `gdn_post_norm`, `mlp_post_norm` (the
+norm on a sublayer's output, inside the scope that closes the sublayer:
+`attn_out/attn_post_norm`, `gdn/out_proj/gdn_post_norm`,
+`mlp/down/mlp_post_norm`), `final_norm`, `head`, `loss` (the vocab head
+and the cross-entropy: models/head.py); a block-diffusion model's loss adds
 `diffusion/noise` (the draws, the replacement, the weights and their
 counts: models/diffusion.py) and `diffusion/stream` (the doubled
 stream's concatenation and positions, the split before the final norm);
@@ -72,7 +82,7 @@ from typing import Any, Dict, Optional
 
 from ray_tpu.models import head
 from ray_tpu.models.configs import (EXPERT_KINDS, FFN_KINDS,
-                                       TransformerConfig)
+                                       REORDERED_KINDS, TransformerConfig)
 from ray_tpu.parallel.mesh import AXIS_SEQ
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
 
@@ -164,7 +174,19 @@ def _norm(x, leaves, name, eps):
 
 
 # the norms that open a sublayer, by their gain's leaf
-NORMS = ("attn_norm", "ssm_norm", "kda_norm", "gmu_norm", "mlp_norm")
+NORMS = ("attn_norm", "ssm_norm", "kda_norm", "gmu_norm", "gdn_norm",
+         "mlp_norm")
+
+
+def _reordered(sub):
+    """A sublayer's leaves (or specs) under the reordered norm: the norm
+    that would open it, `<name>_norm`, is the one on its output,
+    `<name>_post_norm`."""
+    return {name[:-len("norm")] + "post_norm" if name in NORMS else name:
+            leaf for name, leaf in sub.items()}
+
+
+POST_NORMS = tuple(_reordered(dict.fromkeys(NORMS)))
 # the tensors of `_stack`'s `shared` each kind of layer makes
 MAKES = {"s": ("memory",), "f": ("k", "v")}
 
@@ -381,6 +403,35 @@ class Transformer:
                                        (l, h_, hd_, d)),
             }
 
+        def gdn(l, key):
+            """One run of l Gated DeltaNet mixers' leaves (ops/kda.py), a
+            head's q, k and v columns side by side: A uniform in (0, 16]
+            and dt log-uniform in [0.001, 0.1] through softplus's inverse,
+            as a Mamba-2 mixer's; the convolution uniform within
+            1/sqrt(taps)."""
+            ks = jax.random.split(key, 7)
+            h_, taps = cfg.gdn_heads, cfg.gdn_conv_kernel
+            dv = cfg.gdn_value_dim
+            wide = 2 * cfg.gdn_key_dim + dv
+            bound = taps ** -0.5
+            dt = jnp.exp(jax.random.uniform(
+                ks[3], (l, h_), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+            return {
+                "gdn_norm": jnp.ones((l, d), pdt),
+                "w_gdn_qkv": norm_init(d ** -0.5, ks[0], (l, d, h_, wide)),
+                "gdn_conv": jax.random.uniform(
+                    ks[1], (l, h_, wide, taps), jnp.float32, -bound,
+                    bound).astype(pdt),
+                "w_gdn_ab": norm_init(d ** -0.5, ks[2], (l, d, 2, h_)),
+                "gdn_A_log": jnp.log(jax.random.uniform(
+                    ks[4], (l, h_), jnp.float32, 1e-4, 16.0)).astype(pdt),
+                "gdn_dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+                "w_gdn_g": norm_init(d ** -0.5, ks[5], (l, d, h_, dv)),
+                "gdn_out_norm": jnp.ones((l, dv), pdt),
+                "w_gdn_out": norm_init((h_ * dv) ** -0.5, ks[6],
+                                       (l, h_, dv, d)),
+            }
+
         def with_mlp(sub, l, keys):
             """A lower-case kind: the sublayer, then a dense MLP."""
             sub["mlp_norm"] = jnp.ones((l, d), pdt)
@@ -405,8 +456,10 @@ class Transformer:
                            if name not in ("wkv", "bkv")}
             elif kind == "k":
                 sub = with_mlp(kda(l, key), l, keys)
-            elif kind == "l":
+            elif kind in "la":
                 sub = with_mlp(attention(l, keys), l, keys)
+            elif kind == "d":
+                sub = with_mlp(gdn(l, key), l, keys)
             elif kind == "K":
                 sub = dict(kda(l, key), **experts(l, key, keys),
                            mlp_norm=jnp.ones((l, d), pdt))
@@ -425,9 +478,11 @@ class Transformer:
             if "lambda_q1" in sub:   # a buffer (Transformer.frozen)
                 sub["lambda_init"] = jnp.asarray(
                     [cfg.lambda_init(i) for i in places], jnp.float32)
+            if kind in REORDERED_KINDS:
+                sub = _reordered(sub)
             if cfg.norm == "layernorm":
                 sub.update({name + "_bias": jnp.zeros((l, d), pdt)
-                            for name in NORMS if name in sub})
+                            for name in NORMS + POST_NORMS if name in sub})
             return sub
 
         keys = jax.random.split(key, 8)
@@ -522,6 +577,17 @@ class Transformer:
                "kda_out_norm": ("layers", None),
                "w_kda_out": ("layers", None, None, "embed")}
 
+        # nor are a Gated DeltaNet mixer's
+        gdn = {"gdn_norm": ("layers", "norm"),
+               "w_gdn_qkv": ("layers", "embed", None, None),
+               "gdn_conv": ("layers", None, None, None),
+               "w_gdn_ab": ("layers", "embed", None, None),
+               "gdn_A_log": ("layers", None),
+               "gdn_dt_bias": ("layers", None),
+               "w_gdn_g": ("layers", "embed", None, None),
+               "gdn_out_norm": ("layers", None),
+               "w_gdn_out": ("layers", None, None, "embed")}
+
         def experts():
             # an expert's own d_model dimension has a name of its own
             # (parallel/sharding.py: `expert_embed`)
@@ -565,7 +631,9 @@ class Transformer:
                                w_dt=("layers", None, None))
             elif kind in "kK":
                 sub = dict(kda)
-            elif kind in "*wfclLW":
+            elif kind == "d":
+                sub = dict(gdn)
+            elif kind in "*wfclLWa":
                 sub = attention()
                 if kind == "*":
                     del sub["mlp_norm"]
@@ -582,9 +650,11 @@ class Transformer:
                 sub.update(experts(), mlp_norm=("layers", "norm"))
             if kind in FFN_KINDS:
                 sub.update(dense_ffn, mlp_norm=("layers", "norm"))
+            if kind in REORDERED_KINDS:
+                sub = _reordered(sub)
             if cfg.norm == "layernorm":
                 sub.update({name + "_bias": ("layers", "norm")
-                            for name in NORMS if name in sub})
+                            for name in NORMS + POST_NORMS if name in sub})
             return sub
 
         specs = {
@@ -854,10 +924,12 @@ class Transformer:
         """Build layer(x, lp, shared, kind) -> (x, routing, made), the body
         `_stack` scans (or, in a block, one of its sublayers): what lp's
         leaves say, attention under `attn_norm`, a mixer under `ssm_norm`,
-        a gated memory unit under `gmu_norm`, an FFN or experts under
-        `mlp_norm`, each `x + f(norm(x))`. `routing` is the MoE layer's
-        record (ops/moe.py `moe_ffn`), None without one; `shared` the
-        tensors earlier layers made for this one, `made` what this layer
+        a gated memory unit under `gmu_norm`, a Gated DeltaNet mixer under
+        `gdn_norm`, an FFN or experts under `mlp_norm`, each
+        `x + f(norm(x))`, or `x + norm(f(x))` where the leaves hold
+        `<name>_post_norm` instead (`entering`, `residual`). `routing` is
+        the MoE layer's record (ops/moe.py `moe_ffn`), None without one;
+        `shared` the tensors earlier layers made for this one, `made` what this layer
         makes for later ones (`MAKES[kind]`), `kind` the layer's character
         in `layer_pattern` (None outside one). cos and sin are None where
         the model has no rotary embedding; `window_rope`: the (cos, sin)
@@ -875,6 +947,27 @@ class Transformer:
             cfg, mesh, rules, seq_len=seq_len, window=cfg.attn_window) \
             if cfg.attn_window else None
         scale = cfg.head_dim ** -0.5
+
+        def entering(x, lp, name):
+            """What the sublayer `name` reads: `norm(x)` under the norm
+            that opens it, the stream itself where its leaves have none
+            (the reordered norm)."""
+            if name + "_norm" not in lp:
+                return x
+            with jax.named_scope(name + "_norm"):
+                return _norm(x, lp, name + "_norm", cfg.norm_eps)
+
+        def residual(x, out, lp, name):
+            """The stream after the sublayer `name`, `out` what it made of
+            `entering(x, lp, name)`: `x + out`, `out` under the norm on
+            the sublayer's output where its leaves have one. With
+            `entering`, the one rule of how a residual is formed."""
+            out = constrain(out, ("batch", "seq", "act_embed"))
+            post = name + "_post_norm"
+            if post in lp:
+                with jax.named_scope(post):
+                    out = _norm(out, lp, post, cfg.norm_eps)
+            return x + out
 
         def heads_constrained(q, k, v):
             # GQA: k/v keep their true kv_heads width end-to-end — the
@@ -957,8 +1050,7 @@ class Transformer:
         def attention(x, lp, shared, kind):
             # one jax.named_scope per block (module docstring): the names
             # reach every op's op_name, and so the device trace
-            with jax.named_scope("attn_norm"):
-                h = _norm(x, lp, "attn_norm", cfg.norm_eps)
+            h = entering(x, lp, "attn")
             made = {}
             windowed = kind in ("w", "W")
             with jax.named_scope("qkv"):
@@ -1015,14 +1107,13 @@ class Transformer:
                 o = jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(cdt))
                 if "bo" in lp:
                     o = o + lp["bo"].astype(cdt)
-                return x + constrain(o, ("batch", "seq", "act_embed")), made
+                return residual(x, o, lp, "attn"), made
 
         def mixer1(x, lp):
             """A Mamba-1 mixer -> (x, its scan output)."""
             from ray_tpu.ops.ssm import mamba1_mixer
 
-            with jax.named_scope("ssm_norm"):
-                h = _norm(x, lp, "ssm_norm", cfg.norm_eps)
+            h = entering(x, lp, "ssm")
             cast = dict(lp)
             for scope, name in (("in_proj", "w_in"), ("x_proj", "w_x"),
                                 ("x_proj", "w_dt"), ("out_proj", "w_out")):
@@ -1032,12 +1123,11 @@ class Transformer:
             out, y = mamba1_mixer(h, cast, chunk=min(cfg.ssm_chunk,
                                                      x.shape[1]), mesh=mesh)
             with jax.named_scope("ssm/out_proj"):
-                return x + constrain(out, ("batch", "seq", "act_embed")), y
+                return residual(x, out, lp, "ssm"), y
 
         def gmu(x, lp, memory):
             """A gated memory unit: the stream gates the memory."""
-            with jax.named_scope("gmu_norm"):
-                h = _norm(x, lp, "gmu_norm", cfg.norm_eps)
+            h = entering(x, lp, "gmu")
             with jax.named_scope("gmu/in_proj"):
                 gate = jnp.einsum("btd,de->bte", h,
                                   lp["w_gmu_in"].astype(cdt))
@@ -1046,14 +1136,13 @@ class Transformer:
             with jax.named_scope("gmu/out_proj"):
                 out = jnp.einsum("bte,ed->btd", gated,
                                  lp["w_gmu_out"].astype(cdt))
-                return x + constrain(out, ("batch", "seq", "act_embed"))
+                return residual(x, out, lp, "gmu")
 
         def kda(x, lp):
             """Kimi Delta Attention (ops/kda.py)."""
             from ray_tpu.ops.kda import kda_mixer
 
-            with jax.named_scope("kda_norm"):
-                h = _norm(x, lp, "kda_norm", cfg.norm_eps)
+            h = entering(x, lp, "kda")
             cast = dict(lp)
             for scope, name in (("qkv_proj", "w_kda_qkv"),
                                 ("gates", "w_kda_a"), ("gates", "w_kda_bg"),
@@ -1064,13 +1153,29 @@ class Transformer:
             out = kda_mixer(h, cast, chunk=cfg.kda_chunk, mesh=mesh,
                             lower=cfg.kda_gate_lower, eps=cfg.norm_eps)
             with jax.named_scope("kda/out_proj"):
-                return x + constrain(out, ("batch", "seq", "act_embed"))
+                return residual(x, out, lp, "kda")
+
+        def gdn(x, lp):
+            """A Gated DeltaNet mixer (ops/kda.py)."""
+            from ray_tpu.ops.kda import gdn_mixer
+
+            h = entering(x, lp, "gdn")
+            cast = dict(lp)
+            for scope, name in (("qkv_proj", "w_gdn_qkv"),
+                                ("gates", "w_gdn_ab"), ("gates", "w_gdn_g"),
+                                ("out_proj", "w_gdn_out")):
+                with jax.named_scope("gdn/" + scope):
+                    cast[name] = lp[name].astype(cdt)
+            # gdn_mixer names its own scopes under `gdn/`
+            out = gdn_mixer(h, cast, chunk=cfg.gdn_chunk, eps=cfg.norm_eps,
+                            beta_scale=2.0 if cfg.gdn_neg_eigval else 1.0)
+            with jax.named_scope("gdn/out_proj"):
+                return residual(x, out, lp, "gdn")
 
         def mixer(x, lp):
             from ray_tpu.ops.ssm import mamba2_mixer
 
-            with jax.named_scope("ssm_norm"):
-                h = _norm(x, lp, "ssm_norm", cfg.norm_eps)
+            h = entering(x, lp, "ssm")
             with jax.named_scope("ssm/in_proj"):
                 w_in = lp["w_in"].astype(cdt)
             with jax.named_scope("ssm/out_proj"):
@@ -1081,7 +1186,7 @@ class Transformer:
                 head_dim=cfg.ssm_head_dim, state=cfg.ssm_state,
                 chunk=cfg.ssm_chunk, eps=cfg.norm_eps, mesh=mesh)
             with jax.named_scope("ssm/out_proj"):
-                return x + constrain(out, ("batch", "seq", "act_embed"))
+                return residual(x, out, lp, "ssm")
 
         # an expert layer's leaves as `moe_ffn` names them, by the scope
         # their casts belong to
@@ -1092,8 +1197,7 @@ class Transformer:
             ("moe/latent", ("w_latent_down", "w_latent_up")))
 
         def ffn(x, lp):
-            with jax.named_scope("mlp_norm"):
-                h = _norm(x, lp, "mlp_norm", cfg.norm_eps)
+            h = entering(x, lp, "mlp")
             if "w_router" in lp:   # an expert layer, by its leaves
                 from ray_tpu.ops.moe import moe_ffn
 
@@ -1117,7 +1221,7 @@ class Transformer:
                     mesh=mesh, rules=rules)
                 with jax.named_scope("moe/combine"):
                     down = y.reshape(h.shape).astype(cdt)
-                    x = x + constrain(down, ("batch", "seq", "act_embed"))
+                    x = residual(x, down, lp, "mlp")
                 return x, routing
             with jax.named_scope("mlp/gate_up"):
                 gu = jnp.einsum("btd,dgf->btgf", h,
@@ -1127,24 +1231,30 @@ class Transformer:
             with jax.named_scope("mlp/down"):
                 down = jnp.einsum("btf,fd->btd", ff,
                                   lp["w_down"].astype(cdt))
-                x = x + constrain(down, ("batch", "seq", "act_embed"))
+                x = residual(x, down, lp, "mlp")
             return x, None
 
         def layer(x, lp, shared=None, kind=None):
             routing, made = None, {}
-            if "attn_norm" in lp:
+
+            def has(name):   # the sublayer's norm, before it or after
+                return name + "_norm" in lp or name + "_post_norm" in lp
+
+            if has("attn"):
                 x, made = attention(x, lp, shared, kind)
             if "w_x" in lp:       # a Mamba-1 mixer, by its leaves
                 x, y = mixer1(x, lp)
                 if kind == "s":
                     made = {"memory": y}
-            elif "ssm_norm" in lp:
+            elif has("ssm"):
                 x = mixer(x, lp)
-            if "kda_norm" in lp:
+            if has("kda"):
                 x = kda(x, lp)
-            if "gmu_norm" in lp:
+            if has("gdn"):
+                x = gdn(x, lp)
+            if has("gmu"):
                 x = gmu(x, lp, shared["memory"])
-            if "mlp_norm" in lp:
+            if has("mlp"):
                 x, routing = ffn(x, lp)
             return x, routing, made
 

@@ -1,9 +1,12 @@
-"""Kimi Delta Attention (KDA, arXiv 2510.26692): linear attention whose
-state is corrected by a delta rule behind a decay per channel.
+"""Linear attention whose state is corrected by a delta rule behind a
+decay: Kimi Delta Attention (KDA, arXiv 2510.26692), a decay per channel,
+and Gated DeltaNet (arXiv 2412.06464), one decay a head. One chunked
+delta rule (`gated_delta_rule`) serves both: the gate's shape says which.
 
 The reference has no linear attention of any kind; this fills that row
-beside `ops/attention.py`, `ops/ssm.py` and `ops/moe.py`. One sublayer, H
-heads of width D (keys and values alike), per token t and head:
+beside `ops/attention.py`, `ops/ssm.py` and `ops/moe.py`. A KDA sublayer
+(`kda_mixer`), H heads of width D (keys and values alike), per token t and
+head:
 
     q, k, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))
                                  depthwise, causal, no bias; then q and k
@@ -19,6 +22,21 @@ heads of width D (keys and values alike), per token t and head:
 Mamba-2's decay is one scalar a head and Mamba-1's state has no key-key
 interaction; here the state update is a rank-one correction behind a
 diagonal decay, so a chunk does not unroll into one `[C, C]` block product.
+
+A Gated DeltaNet sublayer (`gdn_mixer`) is the same recurrence with the
+decay constant over a head's channels, keys of width D_k beside values of
+another width D_v (the state `[D_k, D_v]`), beta up to 2 and a gate a
+channel:
+
+    q, k, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))
+                                 q and k L2-normed over the head's D_k
+    log a_t = -exp(A) * softplus(h w_a + dt)   ONE scalar a head, f32,
+                                 at or below 0 and NOT bounded below
+    beta_t  = 2 sigmoid(h w_beta)              in (0, 2) (`allow_neg_eigval`)
+    S_t = (I - beta_t k_t k_t^T) a_t S_{t-1} + beta_t k_t v_t^T
+    o_t = D_k^-1/2 S_t^T q_t
+    y_t = rmsnorm(o_t) * gain * silu(h W_g)    gate: a factor a channel
+    out = concat_h(y_t) W_o
 
 **The delta rule in chunks** (`gated_delta_rule`; the WY / UT form). With
 `G_r` the running sum of `log a` inside a chunk of C steps (the step r
@@ -57,6 +75,27 @@ weight `e^{G_r - G_i}` is of order 1; at +-40 a cotangent of 1e-20 still
 passes. Every factor is finite, and so is every cotangent autodiff forms
 from them.
 
+**A decay a head needs none of that** (`_head_blocks`): the decays leave
+the sums over the channels, `A = (K K^T) * L` and `B = (Q K^T) * L` with
+`L_ri = e^{G_r - G_i}` one `[C, C]` matrix a head and chunk, masked to
+i < r BEFORE the exponential and 1 on the diagonal (as
+`ops/ssm.ssd_scan`'s): every exponent is at or below 0 whatever the gate,
+so a gate of -30 a step is as safe as one of -0.01, and none of the
+channel path's `[H, T/C, C/16, C, D]` float32 factors goes through HBM.
+**Beta up to 2 changes the inverse** (`_unit_lower_inverse_by_halves`): the
+strictly lower block the inverse amplifies is twice as large, and on keys
+that share a direction (silu leaves them one) the power series' terms
+grow like `C(C, j) |N|^j` before they cancel: in float32 it misses the
+inverse by the inverse's own size, at any matmul precision. The gate a
+head's path inverts by halves, `[[A, 0], [X, B]]^-1 = [[A^-1, 0],
+[-B^-1 X A^-1, B^-1]]` six times for C = 64, which sums nothing larger
+than the result (1e-7 of it in float32). What that costs: two batched
+products a level of blocks 1 to 32 wide, 0.09M multiply-adds a chunk and
+head where the series' ten `[64, 64]` products are 2.6M, but in twelve
+small float32 products at the highest precision (six passes of the MXU
+each) where the series has ten large ones; A, B and the inverse stay
+float32 at the highest precision under either gate.
+
 The decays, their running sums, A, B, the inverse and the state are
 float32 (A, B and the inverse at the highest matmul precision: an error
 there is amplified by the inverse); the products with `S`, `U` and `T`
@@ -73,11 +112,12 @@ nothing (k = v = 0, beta = 0, no decay).
   chunks, autodiff makes the backward. It runs on the CPU, on a mesh above
   one device (GSPMD cannot partition a pallas call) and for every shape
   the kernels do not tile, and it is the oracle of the kernels' tests.
-- `"pallas"` (`gated_delta_rule_pallas`): on one TPU device where the
-  shapes tile (`delta_shape_ok`: chunks of 64, keys and values one lane
-  tile wide, the heads in pairs). Grid `(batch, head blocks, chunks)`, the
-  chunk axis sequential; a grid step takes one chunk of `DELTA_HEADS`
-  heads. It reads q, k, v (compute dtype) and g (float32) as lane-dense
+- `"pallas"` (`gated_delta_rule_pallas`): on one TPU device, under a decay
+  a channel, where the shapes tile (`delta_shape_ok`: chunks of 64, keys
+  and values one lane tile wide, the heads in pairs; a decay a head, keys
+  of 96 beside values of 192 or 15 heads take the XLA path). Grid
+  `(batch, head blocks, chunks)`, the chunk axis sequential; a grid step
+  takes one chunk of `DELTA_HEADS` heads. It reads q, k, v (compute dtype) and g (float32) as lane-dense
   `[64, heads·128]` blocks of the `[B, T, H·D]` layout the convolution
   and the gates leave (no `[B, H, T/C, C, D]` copy of any of them; beta
   `[B, T, H]`, 0.5 MB, is turned by XLA to two columns a pair of heads)
@@ -119,7 +159,8 @@ stays local. No code stands in for absent heads.
 
 Scopes (PERF.md section 3): `kda/qkv_proj`, `kda/conv`, `kda/gates`
 (log a, beta and the output gate), `kda/delta` (the L2 norms, the chunk
-inverses, the state), `kda/out_norm`, `kda/out_proj`.
+inverses, the state), `kda/out_norm`, `kda/out_proj`; a Gated DeltaNet
+sublayer's are the same six under `gdn/`.
 """
 
 from __future__ import annotations
@@ -171,8 +212,9 @@ def log_decay(a, a_log, bias, lower: float):
 
 
 def head_norm(o, gate, gain, eps: float):
-    """`rmsnorm(o_h) * gain * gate_h` a head: o `[B, T, H·D]`, gate
-    `[B, T, H]`, gain `[D]`, in float32. A head is a slice of the last
+    """`rmsnorm(o_h) * gain * gate_h` a head: o `[B, T, H·D]`, gain `[D]`,
+    gate `[B, T, H]` (a scalar a head, KDA's) or `[B, T, H·D]` (a gate a
+    channel, Gated DeltaNet's), in float32. A head is a slice of the last
     axis, not a `[.., H, D]` reshape: on the TPU that shape has another
     tiling, and the compiler copied `[B, T, H·D]` into it and back in
     every pass (`ops/ssm.gated_norm` met the same; PERF.md section 6,
@@ -181,13 +223,14 @@ def head_norm(o, gate, gain, eps: float):
     import jax.numpy as jnp
 
     f32 = jnp.float32
-    heads = gate.shape[-1]
-    d = o.shape[-1] // heads
+    d = gain.shape[-1]
+    heads = o.shape[-1] // d
+    per = gate.shape[-1] // heads        # 1: a head's scalar; d: a channel's
     gain = gain.astype(f32)
     runs = [o[..., h * d:(h + 1) * d].astype(f32) for h in range(heads)]
     return jnp.concatenate(
         [run * jax.lax.rsqrt(jnp.mean(run * run, axis=-1, keepdims=True)
-                             + eps) * gain * gate[..., h:h + 1]
+                             + eps) * gain * gate[..., h * per:(h + 1) * per]
          for h, run in enumerate(runs)], axis=-1)
 
 
@@ -209,37 +252,53 @@ def _unit_lower_inverse(n):
     return inv
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
-    """`S_t = (I - beta_t k_t k_t^T) diag(e^{g_t}) S_{t-1} + beta_t k_t
-    v_t^T`, `o_t = D^-1/2 S_t^T q_t` from a zero state, in chunks (module
-    docstring): q, k `[B, T, H, D]` as the convolutions leave them (L2-
-    normed here), v `[B, T, H, Dv]` (its dtype is the compute dtype), g
-    `[B, T, H, D]` float32 log-decays in (-5, 0], beta `[B, T, H]` -> o
-    `[B, T, H, Dv]` float32."""
+def _unit_lower_inverse_by_halves(n):
+    """`(I - n)^-1` of a strictly lower-triangular `[.., C, C]` n, C a
+    power of two, by halves: `[[A, 0], [X, B]]^-1 = [[A^-1, 0], [-B^-1 X
+    A^-1, B^-1]]`, the diagonal blocks' inverses doubled in size log2(C)
+    times, two batched products a level. Every product is with an inverse
+    already formed, as in a substitution, so nothing larger than the
+    result's own entries is summed: `_unit_lower_inverse`'s powers of n
+    grow like `C(C, j) |n|^j` before they cancel, which float32 survives
+    for beta in (0, 1) and not for beta up to 2 on keys that share a
+    direction (|n| about 0.3 a pair: the series misses the inverse by its
+    whole size, this form by 1e-7)."""
     import jax
     import jax.numpy as jnp
 
-    f32, cdt = jnp.float32, v.dtype
-    bsz, t, h, d = q.shape
-    if chunk % SUB:
-        raise ValueError(f"a chunk of {chunk} steps is no whole sub-blocks "
-                         f"of {SUB}")
-    pad = -t % chunk
-    q, k = l2norm(q) * d ** -0.5, l2norm(k)
-    g, beta = g.astype(f32), beta.astype(f32)
-    if pad:   # steps that change nothing
-        q, k, v, g, beta = (jnp.pad(
-            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in (q, k, v, g, beta))
-    nc, c, ns = (t + pad) // chunk, chunk, chunk // SUB
+    c = n.shape[-1]
+    lead = n.shape[:-2]
+    exact = dict(precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
+    inv = jnp.ones(lead + (c, 1, 1), n.dtype)     # the 1 x 1 blocks'
+    size = 1
+    while size < c:
+        pairs = c // (2 * size)
+        # the block under the diagonal of every pair of blocks: -X
+        under = jnp.moveaxis(jnp.diagonal(
+            n.reshape(lead + (pairs, 2 * size, pairs, 2 * size)),
+            axis1=-4, axis2=-2), -1, -3)[..., size:, :size]
+        a, b = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        low = jnp.einsum("...ij,...jk->...ik", jnp.einsum(
+            "...ij,...jk->...ik", b, under, **exact), a, **exact)
+        inv = jnp.concatenate([
+            jnp.concatenate([a, jnp.zeros_like(a)], axis=-1),
+            jnp.concatenate([low, b], axis=-1)], axis=-2)
+        size *= 2
+    return inv[..., 0, :, :]
 
-    def chunks(a):   # [B, T, H, W] -> [B, H, nc, c, W]
-        return jnp.moveaxis(a.reshape(bsz, nc, c, h, -1), 3, 1)
 
-    qc, kc, vc, gc = chunks(q), chunks(k), chunks(v), chunks(g)
-    bc = chunks(beta[..., None])                     # [B, H, nc, c, 1]
-    cum = jnp.cumsum(gc, axis=3)                     # G, the step included
-    # ---- A and B, relative to the rows' sub-blocks ------------------
+def _channel_blocks(qc, kc, cum):
+    """A and B of every chunk under a decay a CHANNEL: `A_ri = sum_c k_rc
+    k_ic e^{G_rc - G_ic}`, `B_ri` with q's row, both `[.., c, c]` float32
+    and unmasked above the diagonal's sub-block; qc, kc, cum `[B, H, nc, c,
+    D]`. The decays are formed relative to the middles of the rows'
+    sub-blocks (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+
+    bsz, h, nc, c, d = cum.shape
+    ns = c // SUB
     by_sub = cum.reshape(bsz, h, nc, ns, SUB, d)
     middle = by_sub[:, :, :, :, SUB // 2 - 1]        # Gm [B, H, nc, ns, D]
     row = jnp.exp(by_sub - middle[..., None, :])     # exponents in +-40
@@ -254,14 +313,82 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
         jnp.where(reached[..., None], to_row, -jnp.inf))
     scores = jnp.einsum("...srd,...sid->...sri", rows, cols,
                         precision=jax.lax.Precision.HIGHEST,
-                        preferred_element_type=f32)  # [.., ns, 2 SUB, c]
+                        preferred_element_type=jnp.float32)
+    return (scores[..., SUB:, :].reshape(bsz, h, nc, c, c),
+            scores[..., :SUB, :].reshape(bsz, h, nc, c, c))
+
+
+def _head_blocks(qc, kc, cum):
+    """A and B of every chunk under ONE decay a head: the decays leave the
+    sums, `A = (K K^T) * L`, `B = (Q K^T) * L` with `L_ri = e^{G_r - G_i}`
+    one `[c, c]` matrix a head and chunk, masked to i < r BEFORE the
+    exponential and 1 on the diagonal (as `ops/ssm.ssd_scan`'s masks
+    first): never `e^G` times `e^-G`, whose
+    second factor overflows float32 at the third step of a gate of -30 a
+    step. None of the channel path's `[H, T/C, C/16, C, D]` factors is
+    made. qc, kc `[B, H, nc, c, D]`, cum `[B, H, nc, c, 1]`."""
+    import jax
+    import jax.numpy as jnp
+
+    c = cum.shape[3]
+    since = cum - jnp.swapaxes(cum, -1, -2)          # G_r - G_i [.., c, c]
+    # the diagonal is 1 whatever G: left out of the differences, or its
+    # O(1) cotangent enters G's twice with unlike signs and what is left
+    # of a gradient of e^-30 beside it is rounding
+    decays = jnp.exp(jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), since,
+                               -jnp.inf)) + jnp.eye(c, dtype=cum.dtype)
+    scores = jnp.einsum("...rd,...id->...ri",
+                        jnp.concatenate([qc, kc], axis=3), kc,
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)  # [.., 2c, c]
+    return scores[..., c:, :] * decays, scores[..., :c, :] * decays
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
+    """`S_t = (I - beta_t k_t k_t^T) diag(e^{g_t}) S_{t-1} + beta_t k_t
+    v_t^T`, `o_t = D^-1/2 S_t^T q_t` from a zero state, in chunks (module
+    docstring): q, k `[B, T, H, D]` as the convolutions leave them (L2-
+    normed here), v `[B, T, H, Dv]` (its dtype is the compute dtype; Dv
+    need not be D), beta `[B, T, H]` in [0, 2], and g the float32
+    log-decays, whose shape says which gate: `[B, T, H, D]` a decay a
+    channel in (-5, 0] (KDA), `[B, T, H]` one decay a head, any value at
+    or below 0 (Gated DeltaNet) -> o `[B, T, H, Dv]` float32."""
+    import jax
+    import jax.numpy as jnp
+
+    f32, cdt = jnp.float32, v.dtype
+    bsz, t, h, d = q.shape
+    per_head = g.ndim == 3
+    if chunk % SUB:
+        raise ValueError(f"a chunk of {chunk} steps is no whole sub-blocks "
+                         f"of {SUB}")
+    pad = -t % chunk
+    q, k = l2norm(q) * d ** -0.5, l2norm(k)
+    g, beta = g.astype(f32), beta.astype(f32)
+    if per_head:
+        g = g[..., None]       # one channel, broadcast over the head's D
+    if pad:   # steps that change nothing
+        q, k, v, g, beta = (jnp.pad(
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, g, beta))
+    nc, c = (t + pad) // chunk, chunk
+
+    def chunks(a):   # [B, T, H, W] -> [B, H, nc, c, W]
+        return jnp.moveaxis(a.reshape(bsz, nc, c, h, -1), 3, 1)
+
+    qc, kc, vc, gc = chunks(q), chunks(k), chunks(v), chunks(g)
+    bc = chunks(beta[..., None])                     # [B, H, nc, c, 1]
+    cum = jnp.cumsum(gc, axis=3)                     # G, the step included
+    # ---- A and B under the chunk's decays ---------------------------
+    a_mat, b_mat = (_head_blocks if per_head else _channel_blocks)(
+        qc, kc, cum)
     lower = jnp.tril(jnp.ones((c, c), bool))
-    b_mat = jnp.where(lower, scores[..., :SUB, :].reshape(
-        bsz, h, nc, c, c), 0.0)
-    a_mat = jnp.where(lower & ~jnp.eye(c, dtype=bool),
-                      scores[..., SUB:, :].reshape(bsz, h, nc, c, c), 0.0)
+    b_mat = jnp.where(lower, b_mat, 0.0)
+    a_mat = jnp.where(lower & ~jnp.eye(c, dtype=bool), a_mat, 0.0)
     # ---- the chunk inverses, and what they make of V and K ----------
-    inv = _unit_lower_inverse(-bc * a_mat).astype(cdt)
+    # beta up to 2 (the gate a head's model) outgrows the power series
+    inv = (_unit_lower_inverse_by_halves if per_head
+           else _unit_lower_inverse)(-bc * a_mat).astype(cdt)
     decayed = jnp.exp(cum)
     rhs = jnp.concatenate([vc.astype(f32), kc * decayed], -1) * bc
     solved = jnp.einsum("...ri,...iw->...rw", inv, rhs.astype(cdt),
@@ -315,14 +442,15 @@ def delta_shape_ok(seq_len: int, heads: int, d_k: int, d_v: int,
 
 
 def kda_delta_impl(mesh, seq_len: int, heads: int, d_k: int, d_v: int,
-                   chunk: int) -> str:
+                   chunk: int, per_head: bool = False) -> str:
     """`"pallas"` (`gated_delta_rule_pallas`) where the program runs on one
-    TPU device and the kernels tile the shapes (`delta_shape_ok`), else
-    `"xla"` (`gated_delta_rule`: any platform, any length, and GSPMD can
-    partition it). Decided at trace time, as `ops/ssm.ssd_scan_impl`
-    decides for a Mamba-2 scan."""
-    return "pallas" if _one_tpu_device(mesh) and delta_shape_ok(
-        seq_len, heads, d_k, d_v, chunk) else "xla"
+    TPU device and the kernels tile the shapes (`delta_shape_ok`) under a
+    decay a channel, else `"xla"` (`gated_delta_rule`: any platform, any
+    length, either gate (`per_head`: one decay a head, which the kernels
+    do not take), and GSPMD can partition it). Decided at trace time, as
+    `ops/ssm.ssd_scan_impl` decides for a Mamba-2 scan."""
+    return "pallas" if not per_head and _one_tpu_device(mesh) \
+        and delta_shape_ok(seq_len, heads, d_k, d_v, chunk) else "xla"
 
 
 def _dot(a, b, dims, exact: bool = False):
@@ -786,3 +914,69 @@ def kda_mixer(h, lp: Dict[str, Any], *, chunk: int, lower: float,
     with jax.named_scope("kda/out_proj"):
         return jnp.einsum("bte,ed->btd", y,
                           lp["w_kda_out"].reshape(heads * d, -1))
+
+
+# ---- Gated DeltaNet: the same rule behind one decay a head ------------------
+
+
+def head_log_decay(a, a_log, dt_bias):
+    """Gated DeltaNet's gate: a `[B, T, H]`, a_log and dt_bias `[H]` ->
+    `-exp(a_log) * softplus(a + dt_bias)`, float32, one scalar a head and
+    step, at or below 0 and NOT bounded below."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    return -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+        a.astype(f32) + dt_bias.astype(f32))
+
+
+def gdn_mixer(h, lp: Dict[str, Any], *, chunk: int, beta_scale: float,
+              eps: float):
+    """A Gated DeltaNet sublayer (arXiv 2412.06464; module docstring): h
+    `[B, T, d]` (compute dtype; the stream itself under the reordered
+    norm) -> the sublayer's output before its norm and residual,
+    `[B, T, d]`. lp: `w_gdn_qkv [d, H, 2 Dk + Dv]` (a head's q, k and v
+    columns side by side), `w_gdn_g [d, H, Dv]` (the output gate, a
+    channel each), `w_gdn_ab [d, 2, H]` (the decay's input, beta's) and
+    `w_gdn_out [H, Dv, d]` in the compute dtype; `gdn_conv [H, 2 Dk + Dv,
+    K]`, `gdn_A_log [H]`, `gdn_dt_bias [H]`, `gdn_out_norm [Dv]`. The
+    heads and both widths are read off the leaves, so a share of the heads
+    is a slice of every leaf's head axis; `beta_scale` is 2 where the
+    model allows negative eigenvalues (`allow_neg_eigval`), else 1."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    bsz, t, _ = h.shape
+    heads, wide, _ = lp["gdn_conv"].shape
+    d_v = lp["w_gdn_g"].shape[-1]
+    d_k = (wide - d_v) // 2
+    with jax.named_scope("gdn/qkv_proj"):
+        qkv = jnp.einsum("btd,dhw->bthw", h, lp["w_gdn_qkv"])
+    with jax.named_scope("gdn/conv"):
+        # the three depthwise convolutions as one over every head's q, k
+        # and v channels
+        qkv = jax.nn.silu(causal_conv(
+            qkv.reshape(bsz, t, heads * wide),
+            lp["gdn_conv"].reshape(heads * wide, -1))).reshape(
+                bsz, t, heads, wide)
+        q, k, v = (qkv[..., :d_k], qkv[..., d_k:2 * d_k],
+                   qkv[..., 2 * d_k:])
+    with jax.named_scope("gdn/gates"):
+        ab = jnp.einsum("btd,dgh->btgh", h, lp["w_gdn_ab"]).astype(f32)
+        g = head_log_decay(ab[:, :, 0], lp["gdn_A_log"], lp["gdn_dt_bias"])
+        beta = beta_scale * jax.nn.sigmoid(ab[:, :, 1])
+        gate = jax.nn.silu(jnp.einsum(
+            "btd,de->bte", h,
+            lp["w_gdn_g"].reshape(-1, heads * d_v)).astype(f32))
+    with jax.named_scope("gdn/delta"):
+        # the XLA path, as `kda_delta_impl(..., per_head=True)` says: the
+        # kernels take a decay a channel at keys and values 128 wide
+        o = gated_delta_rule(q, k, v, g, beta, chunk=chunk).reshape(
+            bsz, t, heads * d_v)
+    with jax.named_scope("gdn/out_norm"):
+        y = head_norm(o, gate, lp["gdn_out_norm"], eps).astype(h.dtype)
+    with jax.named_scope("gdn/out_proj"):
+        return jnp.einsum("bte,ed->btd", y,
+                          lp["w_gdn_out"].reshape(heads * d_v, -1))

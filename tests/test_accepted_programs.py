@@ -1,0 +1,180 @@
+"""The accepted cells' train steps compile to the program they compiled to
+before PR 59 (Olmo-Hybrid-7B: a second gate in `ops/kda.py`'s one chunked
+delta rule, one rule of how a sublayer's residual is formed in
+`models/transformer.py`): each cell's step, built from `BENCHMARK.json` and
+its job's `transformer_config` at the cell's own sizes, is compiled for a
+described v5e and its text, with the metadata (source lines, scope names)
+and the pallas kernels' bodies blanked, hashed and held to the hash the
+PARENT's tree gave for the same cell (`.bench_scratch/program_text.py`,
+PRs 35, 50, 53; the nine cells 34,539 / 3,117 / 8,394 / 19,774 / 12,926 /
+18,945 / 10,853 / 4,821 / 12,139 lines). Ling's first: it shares the delta
+rule, the convolution and the head norm with the new model. Each text is
+made in a process of its own (`python tests/test_accepted_programs.py
+<cell>` prints its hash): inside a worker of the whole suite Ling's text
+came out another than alone (its one run there read a different hash, and
+took 259 s; what had run before it in that process is the one difference,
+not looked into further), and a pin must not depend on what ran before it. d2 (the dense MLP, splash, `_remat`, the
+chunked head, adamw: 15 s) runs with the suite; the other eight, Ling's 75 s
+first, are `slow`: `pytest tests/test_accepted_programs.py -m slow`, 5 min.
+
+A pin is the compiler's text: it holds for the jax and libtpu that made it
+(`MADE_WITH`) and the test skips under another. A PR that means to change
+an accepted cell's program makes the pins anew and says so.
+"""
+
+import hashlib
+import os
+import re
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(ROOT, "benchmark")
+if BENCH_DIR not in sys.path:
+    sys.path.insert(0, BENCH_DIR)
+
+from benchlib.spec import load_module, load_spec, resolve_cell  # noqa: E402
+
+MADE_WITH = {"jax": "0.9.0", "libtpu": "0.0.34"}
+# sha256 of the blanked text, first 16 hex digits: the parent's (PR 58)
+PINS = {
+    "train_ling3flash_ep64_d7": "cf8e4609a561e80a",
+    "train_mistral7b_d2": "dc53d3934bbf1705",
+    "train_olmoe_d1": "be5709d03a09969b",
+    "train_nemotron3super_ep64_d11": "60c0f721184e44fd",
+    "train_glm47flash_ep8_d5": "0f3660f11b7bcece",
+    "train_phi4miniflash_d6": "a8abf884315bc039",
+    "train_sdar30b_ep8_d4": "42e5e690d0c064cd",
+    "train_mistral7b_d8_fsdp4": "d58dfb898bcd436d",
+    "train_mellum2_ep4_d4": "4d1f85c1d30e0de0",
+}
+WITH_THE_SUITE = ("train_mistral7b_d2",)
+
+
+def described_host():
+    """The four described devices of a v5e host, or why there are none
+    to describe or the pins do not hold here."""
+    from importlib import metadata
+
+    from jax.experimental import topologies
+    try:
+        have = {"jax": jax.__version__, "libtpu": metadata.version("libtpu")}
+    except metadata.PackageNotFoundError as e:
+        return None, f"no TPU compiler installed: {e}"
+    if have != MADE_WITH:
+        return None, f"the pins were made with {MADE_WITH}, this is {have}"
+    try:
+        return list(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices), None
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+        return None, f"cannot describe a v5e topology here: {e}"
+
+
+def blank(text: str) -> str:
+    """The compiled text without what names a source line, a scope or a
+    kernel's body (which holds the paths of the kernel's call stack)."""
+    text = re.sub(r"kernel_metadata=\{\n[^\n]*\n\}", "kernel_metadata={}",
+                  text)
+    text = re.sub(r'(custom_call_target="tpu_custom_call"[^\n]*?)'
+                  r'backend_config=[^\n]*', r"\1backend_config=BLANK", text)
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    text = re.sub(r",? ?stack_frame_id=\d+", "", text)
+    return re.sub(r"(?ms)^FileNames$.*?^StackFrames$.*?\n\n", "", text)
+
+
+def step_text(cell: str, devices) -> str:
+    """The cell's train step as its job builds it, compiled for the
+    described chips."""
+    import optax
+
+    from ray_tpu.models import Transformer, diffusion
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.parallel.train_step import make_train_step
+
+    ctx = resolve_cell(load_spec(), cell)
+    model, mix = ctx["config"], ctx["traffic"]
+    seq, seqs = mix["tokens_per_sequence"], mix["sequences_per_step"]
+    cfg = load_module("jobs", model["job"]).transformer_config(
+        model, model["train"], seq)
+    mesh = make_mesh(MeshConfig(**model["layout"]["mesh"]),
+                     devices=devices[:ctx["cell"]["chips"]])
+    frozen = Transformer.frozen(cfg)
+    opt = model["train"]["optimizer"]
+    optimizer = optax.adamw(opt["learning_rate"],
+                            weight_decay=opt["weight_decay"])
+    if cfg.block_length:
+        def loss(p, b):
+            return Transformer.loss(p, diffusion.noised(b, cfg), cfg,
+                                    mesh=mesh, with_metrics=True)
+        batch = {"tokens": jax.ShapeDtypeStruct((seqs, seq), jnp.int32),
+                 "noise_key": jax.ShapeDtypeStruct((seqs, 2), jnp.uint32)}
+    else:
+        def loss(p, b):
+            return Transformer.loss(p, b, cfg, mesh=mesh,
+                                    **({"with_metrics": True}
+                                       if cfg.moe_experts else {}))
+        batch = {"tokens": jax.ShapeDtypeStruct((seqs, seq + 1), jnp.int32)}
+    _, train_step = make_train_step(
+        loss, Transformer.param_specs(cfg), mesh, optimizer=optimizer,
+        **({"frozen": frozen} if any(jax.tree.leaves(frozen)) else {}))
+
+    def init(key):
+        params = Transformer.init(key, cfg)
+        return {"params": params, "opt_state": optimizer.init(params),
+                "step": jnp.zeros((), jnp.int32)}
+
+    state = jax.eval_shape(init, jax.random.key(0))
+    return blank(train_step.lower(state, batch).compile().as_text())
+
+
+def text_hash(cell: str) -> str:
+    """`<sha256's first 16 digits> <lines>` of the cell's blanked text, or
+    `skip: <why>`; the persistent compile cache off (an entry written for
+    a described chip cannot be read back without one)."""
+    devices, why = described_host()
+    if devices is None:
+        return "skip: " + why
+    jax.config.update("jax_enable_compilation_cache", False)
+    text = step_text(cell, devices)
+    return (f"{hashlib.sha256(text.encode()).hexdigest()[:16]} "
+            f"{len(text.splitlines())}")
+
+
+@pytest.mark.parametrize("cell", [
+    pytest.param(cell, marks=() if cell in WITH_THE_SUITE
+                 else pytest.mark.slow) for cell in PINS])
+def test_an_accepted_cells_step_is_the_parents_program(cell):
+    done = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), cell],
+        capture_output=True, text=True, timeout=900, cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+            [ROOT, os.path.join(ROOT, "tests")]),
+            ALLOW_MULTIPLE_LIBTPU_LOAD="1"))
+    assert done.returncode == 0, done.stderr[-2000:]
+    said = done.stdout.strip().splitlines()[-1]
+    if said.startswith("skip: "):
+        pytest.skip(said)
+    assert said.split()[0] == PINS[cell], (cell, said)
+
+
+def test_blanking_leaves_the_program_and_takes_the_names():
+    text = ('%f = f32[2]{0} fusion(%a), kind=kLoop, metadata={op_name="x/'
+            'y" source_file="/a/b.py" source_line=3}, stack_frame_id=7\n'
+            '%k = f32[2]{0} custom-call(%f), custom_call_target='
+            '"tpu_custom_call", backend_config={"body": "/a/b.py"}\n')
+    assert blank(text) == (
+        '%f = f32[2]{0} fusion(%a), kind=kLoop\n'
+        '%k = f32[2]{0} custom-call(%f), custom_call_target='
+        '"tpu_custom_call", backend_config=BLANK\n')
+    assert blank(text) == blank(text.replace("/a/b.py", "/c/d/e.py")
+                                .replace("x/y", "z").replace("=7", "=9"))
+
+
+if __name__ == "__main__":
+    print(text_hash(sys.argv[1]))
