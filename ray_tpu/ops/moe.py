@@ -61,16 +61,20 @@ time (no option):
   shapes (`row_bound`: B, twice what H of E experts receive under uniform
   routing, in whole row tiles) and one `lax.cond` on `R <= B` a layer and
   step: the gather, the grouped matmuls (group sizes `[counts[:H]…,
-  B − R]`), the activation and `combine`'s backward run over B rows;
-  `combine`'s forward and `slots_of`'s backward read the rows past B as
-  zeros, which they are. A step whose held rows exceed B runs over all
-  N·min(k, H) rows, the path of a layer with every expert held with
-  `ragged_dot` for its grouped matmuls: nothing is ever dropped, and
+  B − R]`), the activation and `combine`'s backward run over B rows, and
+  the two sums that come back to the tokens (`combine`'s forward,
+  `slots_of`'s backward) are formed from those B rows (`_by_token`,
+  `_permutes`' `token_sums`: the run gathered once into token order, a
+  token's adjacent rows added, one row a token gathered from that; B + N
+  indices, where the inverse permutation's N·min(k, H) would mostly read
+  rows past B, which are zeros). A step whose held rows exceed B runs
+  over all N·min(k, H) rows, the path of a layer with every expert held
+  with `ragged_dot` for its grouped matmuls: nothing is ever dropped, and
   `rows_bounded` in the routing record says which ran. Where every
   expert is held or B would pass half the rows no `cond` is traced.
   The kernel's tiles follow from each call's shapes (`gmm_tiles`). Both
   permutations are gathers in the backward pass too (`_permutes`: a
-  permutation's transpose is its inverse), so the step has no scatter.
+  permutation's transpose is its inverse), so no row is scattered.
 - **sorted, dropless, exchanged** (an `expert` mesh axis above 1): the
   same path under `shard_map`, one shard a device (`_exchange_ffn`). The
   router runs outside it, row by row under GSPMD, as it does on one
@@ -344,6 +348,16 @@ def _permutes(padded: str = ""):
     appear; a gather from the `[N, d]` side costs half of one from the
     `[N·k, d]` side on the chip (PERF.md section 6, PR 27), which is why
     combine's backward gathers the token's cotangent, not the slots'.
+    A step moves rows six times a layer (each direction in the forward,
+    in remat's forward and in the backward), and a gather costs by its
+    indices, not by the rows that exist (PERF.md section 6, PR 60). Into
+    expert order (`slots_of`, twice, and `combine`'s backward): M indices
+    into the tokens' `[N, d]`, M the rows the path runs over. Back to the
+    tokens (`combine`, twice, and `slots_of`'s backward): with every row
+    (M = N·k) N·k indices into `[N·k, d]` by `inverse`; over a bounded run
+    (`by_token`, M = `row_bound`'s B) from the run's side, B + N indices
+    (`token_sums`), and `combine`'s backward makes a weight's cotangent as
+    a row dot on the sorted side where the other keeps `[N, k, d]`.
     `padded`: the exchange's buckets (`_exchange_ffn`), where `order` has
     rows of no slot, written as an index past the end. "clip": one round
     carries every slot, and what a row of no slot holds is never read (an
@@ -357,59 +371,117 @@ def _permutes(padded: str = ""):
     past_the_end = _past_the_end(padded)
     take = functools.partial(jnp.take, axis=0, **past_the_end)
 
-    def rows_of_slots(ys, inverse):
-        """Row `inverse[i]` of ys for every slot i. ys may stop after a
-        leading run of the sorted order (`row_bound`): the rows past it
-        belong to no held expert and read zero."""
-        # every row is there: no index is past it; or the padding's rule
-        if ys.shape[0] == inverse.size or padded:
-            return take(ys, inverse)
-        return jnp.take(ys, inverse, axis=0, fill_value=0)
+    def token_sums(rows, weights, by_token, k):
+        """rows `[m, d]`, a leading run of the sorted order (`row_bound`),
+        weights `[m]` in `by_token`'s order -> `[N, d]`: each token's rows
+        of the run, weighted and summed in float32. Read from the run's
+        side: its m rows gathered once into token order, where a token's
+        at most k rows are adjacent; to each the k - 1 rows behind it
+        added where they are the same token's (dense work over `[m, d]`,
+        one fusion); and one row a token gathered from that: m + N
+        indices, where a gather by `inverse` reads N·k that are mostly the
+        fill. Another token's row is kept out of a sum by a weight of 0,
+        not selected away (the compare is then made on m scalars, not on
+        `[m, d]`), and 0 x inf is nan: a row that is not finite reaches
+        the sums of the up to k - 1 tokens before it in the run as well
+        as its own, where a gather by `inverse` hands it to its own token
+        alone. The step's loss is nan either way; which tokens are is
+        not the same set."""
+        perm, slots, start = by_token
+        m = slots.size
+        tok = jnp.pad(slots // k, (0, k - 1), constant_values=-1)
+        weights = jnp.pad(weights, (0, k - 1))
+        by_tok = jnp.take(rows, perm, axis=0, mode="clip")  # [m + k - 1, d]
+        acc = by_tok[:m].astype(jnp.float32) * weights[:m, None]
+        for i in range(1, k):
+            # another token's row enters at weight 0
+            same = jnp.where(tok[i:m + i] == tok[:m], weights[i:m + i], 0)
+            acc = acc + by_tok[i:m + i].astype(jnp.float32) * same[:, None]
+        # a token with no row in the run starts past its end: zero
+        return jnp.take(acc.astype(rows.dtype), start, axis=0, fill_value=0)
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-    def slots_of(x, order, inverse, k):
+    def slots_of(x, order, inverse, k, by_token=None):
         """x `[N, d]` -> `[M, d]`: row s is the token of sorted slot s
         (slot i of the unsorted order belongs to token i // k). `order` is
-        the sorted order `[N·k]` or a leading run of it, `inverse` always
-        the whole inverse permutation."""
+        the sorted order `[N·k]` or, with `by_token` (`_by_token`), the
+        leading run of it that `by_token` describes; `inverse` always the
+        whole inverse permutation."""
         return take(x, order // k)
 
-    def slots_fwd(x, order, inverse, k):
-        return slots_of(x, order, inverse, k), (order, inverse)
+    def slots_fwd(x, order, inverse, k, by_token=None):
+        return slots_of(x, order, inverse, k), (inverse, by_token)
 
     def slots_bwd(k, res, g):
-        order, inverse = res
-        per_slot = rows_of_slots(g, inverse).reshape(-1, k, g.shape[-1])
-        dx = per_slot.astype(jnp.float32).sum(1).astype(g.dtype)
-        return dx, None, None     # the permutations are integers
+        inverse, by_token = res
+        if by_token is None:
+            per_slot = take(g, inverse).reshape(-1, k, g.shape[-1])
+            dx = per_slot.astype(jnp.float32).sum(1).astype(g.dtype)
+        else:
+            dx = token_sums(g, jnp.ones(g.shape[:1], jnp.float32), by_token,
+                            k)
+        return dx, None, None, None   # the permutations are integers
 
     slots_of.defvjp(slots_fwd, slots_bwd)
 
     @jax.custom_vjp
-    def combine(ys, top_w, order, inverse):
-        """ys `[M, d]` in expert order (all N·k slots, or the leading run
-        `order` names), top_w `[N, k]` -> `[N, d]`: each token's k expert
-        outputs, weighted and summed."""
-        return combine_fwd(ys, top_w, order, inverse)[0]
+    def combine(ys, top_w, order, inverse, by_token=None):
+        """ys `[M, d]` in expert order (all N·k slots, or with `by_token`
+        the leading run `order` names), top_w `[N, k]` -> `[N, d]`: each
+        token's k expert outputs, weighted and summed."""
+        return combine_fwd(ys, top_w, order, inverse, by_token)[0]
 
-    def combine_fwd(ys, top_w, order, inverse):
+    def combine_fwd(ys, top_w, order, inverse, by_token=None):
         n, k = top_w.shape
-        per_slot = rows_of_slots(ys, inverse).reshape(n, k, ys.shape[-1])
+        if by_token is not None:
+            weights = jnp.take(top_w.reshape(-1), by_token[1])
+            return token_sums(ys, weights, by_token, k), (
+                ys, top_w, order, by_token)
+        per_slot = take(ys, inverse).reshape(n, k, ys.shape[-1])
         y = jnp.einsum("nkd,nk->nd", per_slot.astype(jnp.float32), top_w)
-        return y.astype(ys.dtype), (per_slot, top_w, order, inverse)
+        return y.astype(ys.dtype), (per_slot, top_w, order, None)
 
     def combine_bwd(res, g):
-        per_slot, top_w, order, inverse = res
+        rows, top_w, order, by_token = res
         k = top_w.shape[1]
         w_sorted = jnp.take(top_w.reshape(-1), order, **past_the_end)
-        dys = (take(g, order // k).astype(jnp.float32)
-               * w_sorted[:, None]).astype(per_slot.dtype)
-        dw = jnp.einsum("nd,nkd->nk", g.astype(jnp.float32),
-                        per_slot.astype(jnp.float32))
-        return dys, dw, None, None
+        by_slot = take(g, order // k).astype(jnp.float32)
+        dys = (by_slot * w_sorted[:, None]).astype(rows.dtype)
+        if by_token is None:     # every slot's row, `[N, k, d]`
+            dw = jnp.einsum("nd,nkd->nk", g.astype(jnp.float32),
+                            rows.astype(jnp.float32))
+        else:
+            # the run's rows `[m, d]`: a weight's cotangent is its row's
+            # dot with its token's, made on the sorted side in the pass
+            # that makes dys and put at its slot: m scalars scattered
+            # (0.2 ms on the v5e where a gather of all N·k by `inverse`
+            # is 1.0); a slot past the run keeps zero
+            dots = jnp.einsum("md,md->m", by_slot, rows.astype(jnp.float32))
+            dw = jnp.zeros((top_w.size,), dots.dtype).at[order].set(
+                dots, unique_indices=True).reshape(top_w.shape)
+        return dys, dw, None, None, None
 
     combine.defvjp(combine_fwd, combine_bwd)
     return slots_of, combine
+
+
+def _by_token(order, inverse, m: int, k: int):
+    """The leading run of m rows of the sorted order as `_permutes`'
+    `token_sums` reads it, by token, all int32: `slots` `[m]`, the run's
+    slots in ascending order, so that a token's are adjacent (slot i is
+    token i // k: one sort of m keys); `perm` `[m + k - 1]`, the row of
+    the run that holds each, then k - 1 entries more for the rows read
+    behind the last; `start` `[N]`, where each token's rows begin in that
+    order, m for a token with none."""
+    import jax
+    import jax.numpy as jnp
+
+    slots, perm = jax.lax.sort(
+        (order[:m], jnp.arange(m, dtype=jnp.int32)), num_keys=1)
+    rows = (inverse < m).reshape(-1, k).sum(1, dtype=jnp.int32)
+    start = jnp.where(rows > 0, jnp.cumsum(rows) - rows, m)
+    return (jnp.concatenate([perm, jnp.full((k - 1,), m - 1, jnp.int32)]),
+            slots, start)
 
 
 # megablox tiles (rows, contraction, columns) of the grouped matmul. Rows
@@ -507,10 +579,11 @@ def row_bound(n_tokens: int, k: int, held: int, n_experts: int,
 
 
 def _rows_ffn(m: int, impl: str, x, top_w, w_first, w_down, order, inverse,
-              counts, *, k: int, act: str):
+              counts, by_token=None, *, k: int, act: str):
     """The path past the sort over the m leading rows of the sorted order
     (all of them, or `row_bound`'s run, which then holds every held
-    expert's rows): gather, the expert FFN, the weighted sum per token."""
+    expert's rows and is read back through `by_token`, `_by_token`):
+    gather, the expert FFN, the weighted sum per token."""
     import jax
     import jax.numpy as jnp
 
@@ -523,11 +596,13 @@ def _rows_ffn(m: int, impl: str, x, top_w, w_first, w_down, order, inverse,
             received = counts[:held]
             counts = jnp.concatenate([received, m - received.sum(
                 keepdims=True)])
-        xs = slots_of(x, order, inverse, k)              # [m, d]
+        else:
+            by_token = None       # every row: read back by `inverse`
+        xs = slots_of(x, order, inverse, k, by_token)    # [m, d]
     with jax.named_scope("moe/experts"):
         ys = experts_ffn(xs, w_first, w_down, counts, impl, act)
     with jax.named_scope("moe/combine"):
-        return combine(ys, top_w, order, inverse)
+        return combine(ys, top_w, order, inverse, by_token)
 
 
 def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset=0,
@@ -590,11 +665,16 @@ def _sorted_ffn(params, x, top_w, top_e, mesh, expert_offset=0,
         # anew, 8-11% of a job's warm set-up (PERF.md section 6, PR 36)
         with jax.named_scope("moe/dispatch"):
             fits = counts[:held].sum() <= bound
+            # the run by token, for the two sums that come back from it:
+            # made here and kept with the routing, not in the branch,
+            # which the backward pass runs again
+            by_token = checkpoint_name(_by_token(order, inverse, bound, k),
+                                       ROUTING_RESIDUALS)
             y = jax.lax.cond(
                 fits, functools.partial(over, bound, impl),
                 jax.checkpoint(functools.partial(over, order.size,
                                                  "ragged_dot")),
-                *past_the_sort)
+                *past_the_sort, by_token)
             bounded = fits.astype(jnp.int32)
     if held < n_experts:
         counts = counts[:held]

@@ -1,21 +1,24 @@
 """The accepted cells' train steps compile to the program they compiled to
-before PR 59 (Olmo-Hybrid-7B: a second gate in `ops/kda.py`'s one chunked
-delta rule, one rule of how a sublayer's residual is formed in
-`models/transformer.py`): each cell's step, built from `BENCHMARK.json` and
+at the pin's PR (`PINS` says which; PR 59, Olmo-Hybrid-7B: a second gate in
+`ops/kda.py`'s one chunked delta rule, one rule of how a sublayer's residual
+is formed in `models/transformer.py`, left all nine as PR 58 had them; PR 60
+changed the four cells that hold a share of the experts and no other):
+each cell's step, built from `BENCHMARK.json` and
 its job's `transformer_config` at the cell's own sizes, is compiled for a
 described v5e and its text, with the metadata (source lines, scope names)
 and the pallas kernels' bodies blanked, hashed and held to the hash the
 PARENT's tree gave for the same cell (`.bench_scratch/program_text.py`,
 PRs 35, 50, 53; the nine cells 34,539 / 3,117 / 8,394 / 19,774 / 12,926 /
-18,945 / 10,853 / 4,821 / 12,139 lines). Ling's first: it shares the delta
+18,945 / 10,853 / 4,821 / 12,139 lines before PR 60, Ling 36,382, Nemotron
+21,311, GLM 13,387 and SDAR 11,410 since). Ling's first: it shares the delta
 rule, the convolution and the head norm with the new model. Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
 took 259 s; what had run before it in that process is the one difference,
 not looked into further), and a pin must not depend on what ran before it. d2 (the dense MLP, splash, `_remat`, the
-chunked head, adamw: 15 s) runs with the suite; the other eight, Ling's 75 s
-first, are `slow`: `pytest tests/test_accepted_programs.py -m slow`, 5 min.
+chunked head, adamw: 15 s) runs with the suite; the other nine, Ling's 75 s
+first, are `slow`: `pytest tests/test_accepted_programs.py -m slow`, 6 min.
 
 A pin is the compiler's text: it holds for the jax and libtpu that made it
 (`MADE_WITH`) and the test skips under another. A PR that means to change
@@ -42,17 +45,24 @@ if BENCH_DIR not in sys.path:
 from benchlib.spec import load_module, load_spec, resolve_cell  # noqa: E402
 
 MADE_WITH = {"jax": "0.9.0", "libtpu": "0.0.34"}
-# sha256 of the blanked text, first 16 hex digits: the parent's (PR 58)
+# sha256 of the blanked text, first 16 hex digits. Which tree made each
+# pin: PR 58's (the parent of PR 59, whose programs PR 59 left as they
+# were) unless said. PR 60 changed the four share cells' steps (the sums
+# that come back from a bounded run of expert rows, `ops/moe._by_token`)
+# and made their pins anew from its own tree; the other six are PR 59's
+# programs and held PR 60's tree to them
 PINS = {
-    "train_ling3flash_ep64_d7": "cf8e4609a561e80a",
+    "train_ling3flash_ep64_d7": "0ddf0c554c6b0499",          # PR 60
     "train_mistral7b_d2": "dc53d3934bbf1705",
     "train_olmoe_d1": "be5709d03a09969b",
-    "train_nemotron3super_ep64_d11": "60c0f721184e44fd",
-    "train_glm47flash_ep8_d5": "0f3660f11b7bcece",
+    "train_nemotron3super_ep64_d11": "7c8cd0f49368c728",     # PR 60
+    "train_glm47flash_ep8_d5": "a536c17835e12988",           # PR 60
     "train_phi4miniflash_d6": "a8abf884315bc039",
-    "train_sdar30b_ep8_d4": "42e5e690d0c064cd",
+    "train_sdar30b_ep8_d4": "7cc6dbdd7647da51",              # PR 60
     "train_mistral7b_d8_fsdp4": "d58dfb898bcd436d",
     "train_mellum2_ep4_d4": "4d1f85c1d30e0de0",
+    # PR 59's own cell, pinned by PR 60 from PR 59's tree (15,029 lines)
+    "train_olmohybrid7b_tp2_d4": "1ebd73113b090dc6",
 }
 WITH_THE_SUITE = ("train_mistral7b_d2",)
 

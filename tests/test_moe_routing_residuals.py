@@ -1,7 +1,8 @@
 """What a rematerialised expert layer keeps of its routing
 (`ops/moe.ROUTING_RESIDUALS`, saved by `Transformer._remat`'s one policy):
-the router's logits, the chosen experts and their scores, `keep` and the
-sort's permutations and counts, so the gradient's program holds the f32
+the router's logits, the chosen experts and their scores, `keep`, the
+sort's permutations and counts and, where a held share's bound engages, the
+bounded run by token (`_by_token`), so the gradient's program holds the f32
 router product, each `top_k` and each `sort` once an expert layer where
 `remat_policy="full"` holds each twice. The chosen scores are read with no
 gather from the `[N, E]` scores (PR 52: `top_k`'s own values, or compares
@@ -167,18 +168,21 @@ PIECES = {
     "every_expert_softmax": {"sort": 2, "top_k": 1, "router_product": 1,
                              "score_gather": 0, "score_scatter": 1,
                              "named": 6, "scans": 1},
-    # with one, compares against the chosen ids both ways
-    "held_share_sigmoid": {"sort": 2, "top_k": 1, "router_product": 1,
+    # with one, compares against the chosen ids both ways. A held share
+    # whose bound engages (all three here) sorts a third time, the run's
+    # slots into token order, and keeps that too (`moe._by_token`, PR 60:
+    # three more named values)
+    "held_share_sigmoid": {"sort": 3, "top_k": 1, "router_product": 1,
                            "score_gather": 0, "score_scatter": 0,
-                           "named": 6, "scans": 1},
-    "held_below_picked_latent": {"sort": 2, "top_k": 2, "router_product": 1,
+                           "named": 9, "scans": 1},
+    "held_below_picked_latent": {"sort": 3, "top_k": 2, "router_product": 1,
                                  "score_gather": 0, "score_scatter": 0,
-                                 "named": 7, "scans": 2},
+                                 "named": 10, "scans": 2},
     # the group limit's two `top_k` are the forward's alone: the kept
     # `top_e` is all the backward reads of the choice
-    "group_limited_kda": {"sort": 2, "top_k": 3, "router_product": 1,
+    "group_limited_kda": {"sort": 3, "top_k": 3, "router_product": 1,
                           "score_gather": 0, "score_scatter": 0,
-                          "named": 6, "scans": 3},
+                          "named": 9, "scans": 3},
 }
 
 
@@ -186,8 +190,8 @@ PIECES = {
                                      "score_gather"])
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_the_gradient_runs_the_routing_once_a_layer(programs, kind, reading):
-    """The sort and the `argsort`, `route`'s `top_k` and `keep`'s, the f32
-    product: once an expert layer under the default policy, as in the
+    """The sort, the `argsort` and a bounded run's sort into token order,
+    `route`'s `top_k` and `keep`'s, the f32 product: once an expert layer under the default policy, as in the
     forward alone; twice under "full", whose backward makes them again.
     A gather of the chosen scores from `[N, E]`: in none."""
     assert KINDS[kind].remat_policy == "attention"     # the default
@@ -213,12 +217,13 @@ def test_the_chosen_cotangents_scatter_only_without_a_bias(programs, kind):
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_what_is_named_is_what_the_layer_has(programs, kind):
     """The logits, `top_e` and the scores read at them, the two
-    permutations and the counts; `keep` only where fewer experts are held
-    than a token picks."""
+    permutations and the counts; a bounded run by token (three) where a
+    share is held; `keep` only where fewer experts are held than a token
+    picks."""
     want = PIECES[kind]["named"] * PIECES[kind]["scans"]
     assert programs(kind)["forward"]["named"] == want
     cfg = KINDS[kind]
-    assert (want == 7 * PIECES[kind]["scans"]) == (
+    assert (want == 10 * PIECES[kind]["scans"]) == (
         0 < cfg.moe_experts_held < cfg.moe_top_k)
 
 
