@@ -21,6 +21,21 @@ def _pool():
     return worker_mod.global_worker().core_worker._pool
 
 
+def _nm_call(address, method: str, **kwargs):
+    """A query to a node manager over a connection of its own. The core
+    worker's client to its node manager is one connection served in order:
+    a listing queued there behind a lease's return waits for the node
+    manager's grant to its owner, this process, whose handler then waits
+    for that very client to return the next lease: both hang until the
+    client's timeout (120 s; tests/test_misc_parity.py::
+    test_idle_workers_reaped)."""
+    client = rpc_lib.RpcClient(tuple(address), timeout=30)
+    try:
+        return client.call(method, **kwargs)
+    finally:
+        client.close()
+
+
 def list_tasks(filters: Optional[Dict[str, Any]] = None,
                limit: int = 10000) -> List[Dict[str, Any]]:
     """Task records with state transitions + timestamps."""
@@ -66,7 +81,7 @@ def list_workers() -> List[Dict[str, Any]]:
         if not n.alive:
             continue
         try:
-            out.extend(_pool().get(tuple(n.address)).call("nm_list_workers"))
+            out.extend(_nm_call(n.address, "nm_list_workers"))
         except Exception:  # noqa: BLE001 - node died mid-listing
             pass
     return out
@@ -78,8 +93,7 @@ def _workers_by_node() -> Dict[Any, List[Dict[str, Any]]]:
         if not n.alive:
             continue
         try:
-            out[tuple(n.address)] = _pool().get(
-                tuple(n.address)).call("nm_list_workers")
+            out[tuple(n.address)] = _nm_call(n.address, "nm_list_workers")
         except Exception:  # noqa: BLE001 - node died mid-listing; treated as absent
             pass
     return out
@@ -93,9 +107,8 @@ def profile_worker_stack(worker_id: str,
     return the faulthandler dump."""
     for addr, workers in _workers_by_node().items():
         if any(w["worker_id"] == worker_id for w in workers):
-            return _pool().get(addr).call(
-                "nm_profile_worker", worker_id_hex=worker_id,
-                timeout=timeout)
+            return _nm_call(addr, "nm_profile_worker",
+                            worker_id_hex=worker_id, timeout=timeout)
     raise KeyError(f"worker {worker_id[:12]} not found on any "
                    f"alive node")
 

@@ -5,7 +5,10 @@ has an in-process fake; jax runs on an 8-device virtual CPU mesh so all
 sharding/collective code paths compile and execute without TPU hardware.
 """
 
+import contextlib
+import gc
 import os
+import signal
 
 # Must be set before jax is imported anywhere in the test process. Force,
 # don't setdefault: a machine with a chip presets JAX_PLATFORMS to it, and
@@ -36,6 +39,51 @@ jax.config.update("jax_platforms", "cpu")
 
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running test")
+
+
+# What one tier-1 test may take, in each of setup, call and teardown: more
+# than twice what the dearest test that passes takes beside five other
+# workers (a whole step compiled for the v5e, or a rehearsal under
+# tests/benchmark/: about two minutes) and far below the limit around the
+# whole run, so that a test that hangs costs five minutes and one red with
+# its name, not the run.
+TEST_LIMIT_S = 300.0
+
+
+@contextlib.contextmanager
+def time_limit(name):
+    """Fail `name` if the body runs past TEST_LIMIT_S: SIGALRM on the main
+    thread, where pytest and xdist's workers run tests. Leaves the handler
+    and the timer as it found them."""
+    def expired(signum, frame):
+        pytest.fail(f"{name} ran past the {TEST_LIMIT_S:g} s a tier-1 test "
+                    "may take (tests/conftest.py)", pytrace=False)
+
+    handler = signal.signal(signal.SIGALRM, expired)
+    timer = signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *timer)
+        signal.signal(signal.SIGALRM, handler)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    with time_limit(item.nodeid):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    with time_limit(item.nodeid):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    with time_limit(item.nodeid):
+        return (yield)
 
 
 @pytest.fixture(scope="session")
@@ -88,3 +136,16 @@ def assert_ownership_drains(timeout_s: float = 15.0) -> None:
         time.sleep(0.25)
     pytest.fail("ownership drains-to-zero canary failed: "
                 + "; ".join(leaks))
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _drop_what_the_module_compiled():
+    """A test file's compiled programs and traces end with it. A worker of
+    the whole run lives through twenty files, and everything jax keeps for
+    the files before (its jit and tracing caches, and what they hold alive
+    for the collector to walk) slowed the files after: op by op the same
+    test took 54 s behind three model files where it takes 37 s behind
+    them with this."""
+    yield
+    jax.clear_caches()
+    gc.collect()

@@ -168,22 +168,29 @@ def test_unsupported_runtime_env_rejected():
         A.options(runtime_env={"docker": {"image": "x"}}).remote()
 
 
-def test_named_lookup_carries_max_pending_calls():
-    import time
+def test_named_lookup_carries_max_pending_calls(tmp_path):
+    import os
+    import uuid
 
     import ray_tpu.exceptions as exc
 
     @ray_tpu.remote
     class Slow2:
-        def work(self):
-            time.sleep(1.5)
+        def work(self, release):
+            # pending until the test says so: the second submit below is
+            # refused whatever the machine's load, not inside 1.5 s of it
+            while not os.path.exists(release):
+                time.sleep(0.01)
             return 1
 
-    a = Slow2.options(name="bounded", max_pending_calls=1).remote()
-    b = ray_tpu.get_actor("bounded")
+    name = f"bounded-{uuid.uuid4().hex}"     # no neighbour's actor
+    release = str(tmp_path / "release")
+    a = Slow2.options(name=name, max_pending_calls=1).remote()
+    b = ray_tpu.get_actor(name)
     assert b._max_pending_calls == 1
-    r = b.work.remote()
+    r = b.work.remote(release)
     with pytest.raises(exc.PendingCallsLimitExceeded):
-        b.work.remote()
+        b.work.remote(release)
+    open(release, "w").close()
     assert ray_tpu.get(r, timeout=120) == 1
     ray_tpu.kill(a)

@@ -14,6 +14,7 @@ each compared array allows that and nothing else. Every published term
 has a case below that fails without it.
 """
 
+import functools
 import os
 import sys
 
@@ -33,6 +34,9 @@ if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
 from benchlib.spec import load_module  # noqa: E402
+
+from tests import _programs  # noqa: E402
+from tests._programs import programs  # noqa: E402
 
 ref = load_module("reference", "glm4_moe_lite_f32")
 job = load_module("jobs", "train_lm_mla_moe")
@@ -122,6 +126,13 @@ def rel_diff(got, want):
 SHARES = {"all_held": (0, 0), "share_4_of_16": (4, 8)}
 
 
+def reference(cfg):
+    """The reference at `cfg`'s published keys under `jax.jit`
+    (`tests/_programs.reference`): `.forward(w, tokens)` -> (logits,
+    chosen)."""
+    return _programs.reference(ref, published, cfg, with_routing=True)
+
+
 @pytest.mark.parametrize("share", SHARES)
 @pytest.mark.parametrize("seed", [0, 3])
 def test_logits_and_loss_match_the_reference(share, seed):
@@ -132,16 +143,15 @@ def test_logits_and_loss_match_the_reference(share, seed):
     if held:
         params = share_of(params, full, held, offset)
     tokens = batch(cfg, seed)
-    logits = Transformer.apply(params, tokens[:, :-1], cfg)
-    loss, metrics = Transformer.loss(params, {"tokens": tokens}, cfg,
-                                     with_metrics=True)
+    logits = programs(cfg).logits(params, tokens[:, :-1])
+    loss, metrics = programs(cfg).loss(params, {"tokens": tokens})
     w = job.to_reference_layout(params, cfg)
     assert sorted(w["layers"][1]["experts"]) == list(
         range(offset, offset + cfg.held_experts))
-    ref_logits, chosen = ref.forward(w, tokens[:, :-1], published(cfg),
-                                     with_routing=True)
+    ref_logits, chosen = reference(cfg).forward(w, tokens[:, :-1])
     assert_close(logits, ref_logits, "logits")
-    assert_close(loss, ref.loss(w, tokens, published(cfg)), "loss")
+    assert_close(loss, ref.next_token_loss(ref_logits, tokens[:, 1:]),
+                 "loss")
     # the counters: the held experts' columns of the reference's counts,
     # and every other slot counted as routed elsewhere
     counts = np.asarray(ref.tokens_per_expert(chosen, E))
@@ -211,10 +221,9 @@ def test_gradients_match_jax_grad_of_the_reference():
     cfg = config()
     params = weights(cfg, 1)
     tokens = batch(cfg, 1)
-    grads = jax.grad(lambda p: Transformer.loss(
-        p, {"tokens": tokens}, cfg))(params)
+    _, grads = programs(cfg).grads(params, {"tokens": tokens})
     w = job.to_reference_layout(params, cfg)
-    _, ref_grads = ref.loss_and_grads(w, tokens, published(cfg))
+    _, ref_grads = reference(cfg).loss_and_grads(w, tokens)
     # the bias enters the choice only: no gradient on either side
     assert not np.asarray(grads["layers"].pop("router_bias")).any()
     for g in ref_grads["layers"][cfg.moe_dense_layers:]:
@@ -309,14 +318,24 @@ TERMS = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def intact(seed):
+    """(weights, tokens, the program's logits, the reference's) of the
+    uncut model at a seed: what every changed term and every fault below
+    is read against."""
+    cfg = config()
+    params = weights(cfg, seed)
+    tokens = batch(cfg, seed)
+    w = job.to_reference_layout(params, cfg)
+    return (params, tokens, programs(cfg).logits(params, tokens[:, :-1]),
+            reference(cfg).forward(w, tokens[:, :-1])[0])
+
+
 @pytest.mark.parametrize("term", TERMS)
 def test_a_term_left_out_of_the_program_fails(term):
     """The program with one published term changed no longer matches the
     reference; with it, it does (the tests above)."""
-    cfg = config()
-    params = weights(cfg, 2)
-    tokens = batch(cfg, 2)
-    want = _reference_logits(cfg, params, tokens)
+    params, tokens, _, want = intact(2)
     change = TERMS[term]
     broken = config(**change.get("cfg", {}))
     if "moe_dense_ff" in change.get("cfg", {}):
@@ -328,7 +347,7 @@ def test_a_term_left_out_of_the_program_fails(term):
         run, name = change["zero"]
         params = dict(params, **{run: dict(
             params[run], **{name: jnp.zeros_like(params[run][name])})})
-    got = Transformer.apply(params, tokens[:, :-1], broken)
+    got = programs(broken).logits(params, tokens[:, :-1])
     assert rel_diff(got, want) > 30 * RTOL, term
 
 
@@ -365,10 +384,8 @@ def test_a_fault_in_latent_attention_fails(fault, monkeypatch):
     all heads (a head of its own per head instead), RoPE on the 64 rotary
     columns only (over the whole head instead), RoPE on the key head."""
     cfg = config()
-    params = weights(cfg, 6)
-    tokens = batch(cfg, 6)
-    got = Transformer.apply(params, tokens[:, :-1], cfg)
-    assert_close(got, _reference_logits(cfg, params, tokens), "intact")
+    params, tokens, got, want = intact(6)
+    assert_close(got, want, "intact")
     plain_norm, plain_rope = ref.rms_norm, ref.apply_rope
     nh, rope = cfg.n_heads, cfg.qk_rope_head_dim
     if fault in ("kv_a_norm", "q_a_norm"):
@@ -552,7 +569,7 @@ def test_param_specs_cover_every_leaf_and_shard_on_a_mesh():
                         is_leaf=lambda x: isinstance(x, tuple))
     assert all(jax.tree.leaves(flat)), flat
     tokens = batch(cfg, 8, rows=4)
-    want = Transformer.loss(params, {"tokens": tokens}, cfg)
+    want, _ = programs(cfg).loss(params, {"tokens": tokens})
     mesh = make_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
                      devices=jax.devices()[:4])
     init_state, step = make_train_step(

@@ -31,6 +31,16 @@ def _series(name):
     return out
 
 
+def _sentinel_records_since(ring, start):
+    """The sentinel's own records written after ring index `start`. The
+    ring is the process's: a collection (spans._on_gc) or a thread another
+    file left behind may write to it while a test runs, so `ring._i` alone
+    does not count what the sentinel wrote."""
+    new = ring._i - start
+    return [r for r in ring.snapshot_records()[-new:]
+            if r[1].startswith("jax.")] if new else []
+
+
 def _flat_series():
     """collect_wire() flattened to the harvest's `name{k=v,...}` keys
     (same shape Watchdog.evaluate receives from the cluster merge)."""
@@ -382,7 +392,8 @@ def test_short_events_fold_into_bounded_records_with_exact_sums(
     # one long one among them is a span of its own, and carries nothing
     jax_sentinel._on_event_duration(jax_sentinel.LOWER_EVENT, 0.25,
                                     fun_name="jit(big)")
-    assert ring._i - start == 1   # nothing else reached the ring yet
+    # nothing else of the sentinel's reached the ring yet
+    assert len(_sentinel_records_since(ring, start)) == 1
     snap = spans.snapshot()       # the snapshot writes the sums
     mine = [r for r in snap["spans"]
             if r[2] >= t0 - 1.0 and r[1].startswith("jax.")
@@ -418,13 +429,12 @@ def test_a_sum_is_written_once_it_spans_a_second(sentinel, monkeypatch):
     for _ in range(3):
         jax_sentinel._on_event_duration(jax_sentinel.TRACE_EVENT, 1e-4,
                                         fun_name="op")
-    assert ring._i == start
+    assert _sentinel_records_since(ring, start) == []
     import time
     time.sleep(0.06)
     jax_sentinel._on_event_duration(jax_sentinel.TRACE_EVENT, 2e-4,
                                     fun_name="op")
-    assert ring._i == start + 1
-    rec = ring.snapshot_records()[-1]
+    (rec,) = _sentinel_records_since(ring, start)
     assert rec[1] == "jax.trace" and rec[6]["folded_n"] == 3
     assert rec[6]["folded_s"] == pytest.approx(3e-4)
     (pending,) = jax_sentinel._folded.values()

@@ -9,6 +9,7 @@ recurrence of `benchmark/reference/ling3_f32.py`, at a cotangent of order
 import functools
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,8 @@ if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
 from benchlib.spec import load_module  # noqa: E402
+
+from tests._programs import value_and_grads  # noqa: E402
 
 ref = load_module("reference", "ling3_f32")
 
@@ -70,6 +73,7 @@ def xla(q, k, v, g, beta):
     return kda.gated_delta_rule(q, k, v, g, beta, chunk=CHUNK)
 
 
+@functools.lru_cache(maxsize=None)
 def kernel(head_block=None):
     """`gated_delta_rule_pallas` in interpret mode, on `[B, T, H, D]`
     operands like the other two."""
@@ -82,10 +86,21 @@ def kernel(head_block=None):
     return jax.jit(rule)
 
 
-def grads_of(fn, args, probe):
-    return jax.grad(
-        lambda *v: jnp.sum(fn(*v).astype(jnp.float32) * probe),
-        argnums=range(5))(*args)
+@functools.lru_cache(maxsize=None)
+def case(shape, seed, cotangent=1.0, dtype=jnp.float32, edge=None,
+         head_block=None):
+    """The inputs at (shape, seed, dtype, edge) and what the kernel, the
+    XLA path and the float32 recurrence give at them, outputs and the
+    gradients under `cotangent` times the probe: computed once, read by
+    the forward, the backward, the ends' and the bfloat16 tests."""
+    args, probe = delta_inputs(seed, *shape, dtype, **EDGES.get(edge, {}))
+    probe = probe * cotangent
+    o, grads = value_and_grads(kernel(head_block))(probe, *args)
+    xla_o, xla_grads = value_and_grads(xla)(probe, *args)
+    want_o, want_grads = value_and_grads(recurrence)(probe, *args)
+    return types.SimpleNamespace(
+        args=args, o=o, grads=grads, xla_o=xla_o, xla_grads=xla_grads,
+        want_o=want_o, want_grads=want_grads)
 
 
 def assert_close(got, want, what, rtol=RTOL):
@@ -97,13 +112,16 @@ def assert_close(got, want, what, rtol=RTOL):
         what, float(np.abs(got - want).max()), float(scale))
 
 
-def assert_grads_close(rule, args, probe):
-    """The five gradients of `rule` against the XLA path's and the float32
+def assert_forward_close(at):
+    assert at.o.dtype == jnp.float32 and at.o.shape == at.args[2].shape
+    assert_close(at.o, at.xla_o, "o against gated_delta_rule")
+    assert_close(at.o, at.want_o, "o against the recurrence")
+
+
+def assert_grads_close(at):
+    """The kernel's five gradients against the XLA path's and the float32
     recurrence's, shapes and dtypes the XLA path's."""
-    got = grads_of(rule, args, probe)
-    other = grads_of(xla, args, probe)
-    want = grads_of(recurrence, args, probe)
-    for name, g, o, w in zip(GRADS, got, other, want):
+    for name, g, o, w in zip(GRADS, at.grads, at.xla_grads, at.want_grads):
         assert g.shape == o.shape and g.dtype == o.dtype, name
         assert_close(g, o, f"d{name} against gated_delta_rule")
         assert_close(g, w, f"d{name} against the recurrence")
@@ -112,11 +130,7 @@ def assert_grads_close(rule, args, probe):
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("shape", SHAPES, ids=IDS)
 def test_kernel_forward_is_the_xla_rule_and_the_recurrence(shape, seed):
-    args, _ = delta_inputs(seed, *shape)
-    o = kernel()(*args)
-    assert o.dtype == jnp.float32 and o.shape == args[2].shape
-    assert_close(o, xla(*args), "o against gated_delta_rule")
-    assert_close(o, recurrence(*args), "o against the recurrence")
+    assert_forward_close(case(shape, seed))
 
 
 @pytest.mark.parametrize("cotangent", COTANGENTS)
@@ -124,8 +138,7 @@ def test_kernel_forward_is_the_xla_rule_and_the_recurrence(shape, seed):
 @pytest.mark.parametrize("shape", SHAPES, ids=IDS)
 def test_kernel_backward_is_the_xla_rules_and_the_recurrences(
         shape, seed, cotangent):
-    args, probe = delta_inputs(seed, *shape)
-    assert_grads_close(kernel(), args, probe * cotangent)
+    assert_grads_close(case(shape, seed, cotangent))
 
 
 # the gate at its bound at every step and channel (a chunk's running sum
@@ -139,17 +152,12 @@ EDGES = {"gate_at_the_bound": dict(gate=-5.0), "gate_at_0": dict(gate=0.0),
 @pytest.mark.parametrize("cotangent", COTANGENTS)
 @pytest.mark.parametrize("edge", EDGES)
 def test_the_gates_and_betas_ends(edge, cotangent):
-    args, probe = delta_inputs(2, 1, 192, 2, **EDGES[edge])
-    o, want = kernel()(*args), recurrence(*args)
+    at = case((1, 192, 2), 2, cotangent, edge=edge)
     if edge == "beta_0":
-        assert not np.asarray(o).any() and not np.asarray(want).any()
+        assert not np.asarray(at.o).any() and not np.asarray(at.want_o).any()
     else:
-        assert_close(o, xla(*args), "o against gated_delta_rule")
-        assert_close(o, want, "o against the recurrence")
-    got = grads_of(kernel(), args, probe * cotangent)
-    other = grads_of(xla, args, probe * cotangent)
-    want = grads_of(recurrence, args, probe * cotangent)
-    for name, g, o, w in zip(GRADS, got, other, want):
+        assert_forward_close(at)
+    for name, g, o, w in zip(GRADS, at.grads, at.xla_grads, at.want_grads):
         if not np.asarray(w).any():      # beta 0: nothing reaches k, v, g
             assert not np.asarray(g).any(), name
             continue
@@ -164,18 +172,15 @@ def test_batch_rows_and_head_blocks(head_block):
     row's and block's first chunk. Four heads are one grid step by
     default and two at a block of two."""
     assert kda.DELTA_HEADS == 4
-    args, probe = delta_inputs(3, 2, 128, 4)
-    rule = kernel(head_block)
-    o = rule(*args)
-    assert_close(o, xla(*args), "o against gated_delta_rule")
-    assert_close(o, recurrence(*args), "o against the recurrence")
+    at = case((2, 128, 4), 3, 1e-6, head_block=head_block)
+    assert_forward_close(at)
     # a row alone, and a pair of heads alone, give what they give in the
     # batch
-    alone = rule(*(a[-1:] for a in args))
-    assert_close(alone, o[-1:], "the last row alone", 1e-6)
-    pair = kernel()(*(a[:, :, 2:] for a in args))
-    assert_close(pair, o[:, :, 2:], "the second pair alone", 1e-6)
-    assert_grads_close(rule, args, probe * 1e-6)
+    alone = kernel(head_block)(*(a[-1:] for a in at.args))
+    assert_close(alone, at.o[-1:], "the last row alone", 1e-6)
+    pair = kernel()(*(a[:, :, 2:] for a in at.args))
+    assert_close(pair, at.o[:, :, 2:], "the second pair alone", 1e-6)
+    assert_grads_close(at)
 
 
 def states_entering(q, k, v, g, beta, every):
@@ -238,16 +243,11 @@ BF16_RTOL = {"o": 2e-2, "q": 3e-2, "k": 3e-2, "v": 3e-2, "g": 3e-2,
 
 @pytest.mark.parametrize("seed", [0, 1])
 def test_bf16_operands_stay_in_the_xla_paths_band(seed):
-    args, probe = delta_inputs(seed, 1, 256, 2, jnp.bfloat16)
-    rule = kernel()
-    o, want = rule(*args), recurrence(*args)
-    assert o.dtype == jnp.float32
-    assert_close(o, want, "o", BF16_RTOL["o"])
-    assert_close(xla(*args), want, "gated_delta_rule's o", BF16_RTOL["o"])
-    got = grads_of(rule, args, probe)
-    other = grads_of(xla, args, probe)
-    want = grads_of(recurrence, args, probe)
-    for name, g, o, w in zip(GRADS, got, other, want):
+    at = case((1, 256, 2), seed, dtype=jnp.bfloat16)
+    assert at.o.dtype == jnp.float32
+    assert_close(at.o, at.want_o, "o", BF16_RTOL["o"])
+    assert_close(at.xla_o, at.want_o, "gated_delta_rule's o", BF16_RTOL["o"])
+    for name, g, o, w in zip(GRADS, at.grads, at.xla_grads, at.want_grads):
         assert g.shape == o.shape and g.dtype == o.dtype, name
         assert_close(g, w, "d" + name, BF16_RTOL[name])
         assert_close(o, w, "gated_delta_rule's d" + name, BF16_RTOL[name])

@@ -17,119 +17,22 @@ fault of `benchmark/reference/mellum2_faults.py` has a case below that
 moves logits or loss by far more.
 """
 
-import contextlib
 import math
-import os
-import re
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import Transformer, TransformerConfig, head
+from ray_tpu.models import Transformer, head
 from ray_tpu.models.transformer import _rope_tables
-from ray_tpu.ops import attention, moe
+from ray_tpu.ops import attention
 from ray_tpu.parallel import MeshConfig, make_mesh
-from ray_tpu.parallel.sharding import ShardingRules
 from ray_tpu.parallel.train_step import make_train_step
 
-BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.insert(0, BENCH_DIR)
-
-from benchlib.spec import load_json, load_module  # noqa: E402
-
-ref = load_module("reference", "mellum2_f32")
-faults = load_module("reference", "mellum2_faults")
-job = load_module("jobs", "train_lm_ep_moe")
-
-RTOL = 1e-4
-E = 8
-VOCAB = 128
-WINDOW = 16
-ROPE = {"full_attention": {
-    "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
-    "original_max_position_embeddings": 32, "beta_fast": 32, "beta_slow": 1,
-    "attention_factor": 1.2772588722239782},
-    "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
-RULES = ShardingRules().replace(expert="fsdp", expert_embed=None)
-
-
-def config(k=2, **kw):
-    base = dict(
-        vocab_size=VOCAB, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2,
-        attn_head_dim=16, d_ff=32, max_seq_len=64, dtype="float32",
-        rope_theta=5e5, norm_eps=1e-6, loss_chunk=0, qk_norm=True,
-        qk_norm_per_head=True, moe_experts=E, moe_top_k=k,
-        moe_norm_topk=True, moe_scoring="softmax", moe_aux_coeff=0.0,
-        layer_pattern="WWWL", attn_window=WINDOW, rope_yarn_factor=16.0,
-        rope_yarn_original_len=32,
-        rope_yarn_attention_factor=1.2772588722239782)
-    base.update(kw)
-    return TransformerConfig(**base)
-
-
-def published(cfg, **over):
-    """The config.json keys the reference reads."""
-    kinds = {"W": "sliding_attention", "L": "full_attention"}
-    out = {"hidden_act": "silu", "attention_bias": False,
-           "hidden_size": cfg.d_model, "head_dim": cfg.head_dim,
-           "num_attention_heads": cfg.n_heads,
-           "num_key_value_heads": cfg.kv_heads,
-           "num_hidden_layers": cfg.n_layers,
-           "layer_types": [kinds[c] for c in cfg.layer_pattern],
-           "mlp_layer_types": ["sparse"] * cfg.n_layers,
-           "num_experts": cfg.moe_experts,
-           "num_experts_per_tok": cfg.moe_top_k,
-           "norm_topk_prob": cfg.moe_norm_topk,
-           "rms_norm_eps": cfg.norm_eps, "sliding_window": cfg.attn_window,
-           "rope_parameters": ROPE}
-    out.update(over)
-    return out
-
-
-def weights(cfg, seed):
-    """Random weights with every gain off 1 (a gain of exactly 1 hides a
-    norm applied in the wrong place or left out), heads of unlike scale (a
-    QK-norm over the whole projection then differs from one a head) and
-    router logits of order 1 as at the published width."""
-    params = Transformer.init(jax.random.key(seed), cfg)
-    keys = iter(jax.random.split(jax.random.key(seed + 1), 64))
-    for block in params["runs"]:
-        for lay in block:
-            n = lay["wq"].shape[0]
-            for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
-                lay[name] = 1.0 + 0.3 * jax.random.normal(next(keys),
-                                                          lay[name].shape)
-            lay["wq"] = lay["wq"] * jnp.exp(0.5 * jax.random.normal(
-                next(keys), (n, 1, cfg.n_heads, 1)))
-            lay["wkv"] = lay["wkv"] * jnp.exp(0.5 * jax.random.normal(
-                next(keys), (n, 1, 1, cfg.kv_heads, 1)))
-            lay["w_router"] = lay["w_router"] * 6.0
-    params["final_norm"] = 1.0 + 0.3 * jax.random.normal(
-        next(keys), params["final_norm"].shape)
-    return params
-
-
-def mesh_of(devices):
-    return None if devices == 1 else make_mesh(
-        MeshConfig(data=1, fsdp=devices), devices=jax.devices()[:devices])
-
-
-def tokens_of(seed, rows=4, length=64):
-    return jax.random.randint(jax.random.key(100 + seed), (rows, length + 1),
-                              0, VOCAB)
-
-
-def assert_close(got, want, what, rtol=RTOL):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    scale = np.abs(want).max()
-    assert scale > 0, what
-    err = np.abs(got - want).max() / scale
-    assert err <= rtol, f"{what}: {err:.2e} of the largest entry"
+from tests._mellum2 import (E, ROPE, RTOL, RULES, VOCAB, WINDOW, assert_close,
+                            config, job, mesh_of, published, ref, tokens_of,
+                            weights)
 
 
 # ---- the model against the reference --------------------------------------
@@ -154,7 +57,8 @@ def test_logits_loss_and_gradients_against_the_reference(devices, k,
         p, tokens, cfg, mesh=mesh, rules=RULES), cfg, mesh=mesh,
         rules=RULES))(params)
     ref_w = job.to_reference_layout(params, cfg)
-    want, chosen = ref.forward(ref_w, tokens, model, with_routing=True)
+    want, chosen = jax.jit(lambda w: ref.forward(
+        w, tokens, model, with_routing=True))(ref_w)
     assert_close(got, want, "logits")
     (loss, metrics), grads = jax.jit(jax.value_and_grad(
         lambda p: Transformer.loss(p, {"tokens": sample}, cfg, mesh=mesh,
@@ -368,333 +272,3 @@ def test_window_of_the_pattern_is_the_references_mask():
                          - last).max()) == 0.0
     assert float(jnp.abs(Transformer.hidden(params, inside, cfg)[0, -1]
                          - last).max()) > 1e-4
-
-
-# ---- the exchange -------------------------------------------------------------
-
-N, D, F, K = 256, 16, 32, 2
-
-
-def layer_and_rows(seed=0):
-    params = moe.init_moe_params(jax.random.key(seed), D, F, E)
-    x = jax.random.normal(jax.random.key(seed + 1), (N, D))
-    top_w = jax.nn.softmax(jax.random.normal(jax.random.key(seed + 2),
-                                             (N, K)))
-    return params, x, top_w
-
-
-def spread_choice(seed=3):
-    """Two distinct experts a token, near uniform."""
-    first = jax.random.randint(jax.random.key(seed), (N, 1), 0, E)
-    step = 1 + jax.random.randint(jax.random.key(seed + 1), (N, 1), 0, E - 1)
-    return jnp.concatenate([first, (first + step) % E], axis=1)
-
-
-def loop_reference(params, x, top_w, top_e):
-    """The uncut layer as the reference computes it, given the choice."""
-    y = jnp.zeros_like(x)
-    for e in range(E):
-        weight = jnp.sum(jnp.where(top_e == e, top_w, 0.0), axis=-1)
-        y = y + weight[:, None] * ref.expert_mlp(
-            x, params["w_gateup"][e][:, 0].T, params["w_gateup"][e][:, 1].T,
-            params["w_down"][e].T)
-    return y
-
-
-LOADS = {
-    # every token of every chip to chip 0's experts: the worst load
-    "all_to_one_chip": lambda: jnp.tile(jnp.array([[0, 1]]), (N, 1)),
-    # chip 0's tokens (the first N / 4) all to chip 3: one chip overflows
-    "one_chip_overflows": lambda: spread_choice().at[:N // 4].set(
-        jnp.array([6, 7])),
-    "spread": spread_choice,
-    # chip 0 sends chip 1 exactly the bucket's 64 rows: just under
-    "at_the_bound": lambda: spread_choice().at[:N // 4].set(
-        jnp.array([0, 4])).at[:N // 8].set(jnp.array([2, 3])),
-}
-BOUNDED = {"all_to_one_chip": 0, "one_chip_overflows": 0, "spread": 1,
-           "at_the_bound": 1}
-
-
-@pytest.mark.parametrize("load", sorted(LOADS))
-def test_dropless_at_any_load_and_the_branch_is_agreed(load):
-    """The exchange against the one-device sorted path and the reference's
-    loop, output and gradients, under loads that take the fast branch and
-    loads that take the slow one; a step in which ONE chip overflows takes
-    the slow branch on all four (it returns: nothing hangs)."""
-    with jax.default_matmul_precision("highest"):
-        params, x, top_w = layer_and_rows()
-        top_e = LOADS[load]()
-        mesh = mesh_of(4)
-        assert moe.exchange_bound(N // 4 * K, 4) == 64
-
-        def weigh(y):
-            return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
-
-        def one_device(p, x, w):
-            y = moe._sorted_ffn(p, x, w, top_e, None)[0]
-            return weigh(y), y
-
-        def exchanged(p, x, w):
-            y, record = moe._exchange_ffn(p, x, w, top_e, mesh, RULES)
-            return weigh(y), (y, record)
-
-        (_, want), want_grads = jax.jit(jax.value_and_grad(
-            one_device, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
-        (_, (got, record)), grads = jax.jit(jax.value_and_grad(
-            exchanged, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
-        assert_close(got, want, "y")
-        assert_close(got, loop_reference(params, x, top_w, top_e),
-                     "y against the loop")
-        for a, b in zip(jax.tree.leaves(grads),
-                        jax.tree.leaves(want_grads)):
-            if np.abs(np.asarray(b)).max() > 0:
-                assert_close(a, b, "gradient")
-    assert np.asarray(record["exchange_bounded"]).tolist() == \
-        [BOUNDED[load]] * 4
-    counts = np.asarray(record["tokens_per_expert"])
-    assert counts.sum() == N * K
-    np.testing.assert_array_equal(
-        record["rows_received"], counts.reshape(4, -1).sum(-1))
-    rounds = 1 if BOUNDED[load] else 2
-    assert np.asarray(record["exchange_rows_sent"]).tolist() == \
-        [rounds * 3 * 64] * 4
-    if load == "all_to_one_chip":
-        assert np.asarray(record["rows_received"]).tolist() == \
-            [N * K, 0, 0, 0]
-        assert np.asarray(record["exchange_rows_needed"]).tolist() == \
-            [0, 128, 128, 128]
-        assert np.asarray(record["exchange_pairs"]).tolist() == \
-            [0, 64, 64, 64]
-
-
-def ragged_all_to_all_from_gathers(operand, output, input_offsets,
-                                   send_sizes, output_offsets, recv_sizes,
-                                   *, axis_name, axis_index_groups=None):
-    """`jax.lax.ragged_all_to_all` as its documentation defines it, for
-    XLA:CPU, which has none: every shard gathers every shard's operand and
-    offsets, and row q of its output is the row of the source whose run
-    covers q (`recv_sizes`: the receiver's own word for how long each run
-    is), or `output`'s where nothing lands."""
-    me = jax.lax.axis_index(axis_name)
-    runs = recv_sizes.size // jax.lax.axis_size(axis_name)   # a pair
-
-    def for_me(told):       # [P, P * runs] -> the senders' runs for me
-        return jax.lax.dynamic_slice_in_dim(
-            jax.lax.all_gather(told, axis_name), me * runs, runs,
-            axis=1).reshape(-1)
-
-    operands = jax.lax.all_gather(operand, axis_name)        # [P, rows, d]
-    starts, lands = for_me(input_offsets), for_me(output_offsets)
-    q = jnp.arange(output.shape[0])
-    covers = (q >= lands[:, None]) & (q < (lands + recv_sizes)[:, None])
-    run = covers.argmax(0)
-    row = jnp.clip(starts[run] + q - lands[run], 0, operand.shape[0] - 1)
-    return jnp.where(covers.any(0)[:, None], operands[run // runs, row],
-                     output)
-
-
-RAGGED_BOUNDED = {"all_to_one_chip": 0, "one_chip_overflows": 1, "spread": 1,
-                  "at_the_bound": 1}
-
-
-@pytest.mark.parametrize("load", sorted(LOADS))
-def test_the_ragged_exchange_sends_the_rows_it_has(load, monkeypatch):
-    """The TPU's lowering of the one bounded round (`moe.exchange_impl`:
-    each shard's sorted rows through `ragged_all_to_all`) forced onto the
-    CPU's mesh with the collective emulated: output and gradients against
-    the one-device sorted path and the reference's loop, the rows sent
-    are the rows needed, and the branch by what a chip RECEIVES (chip 3's
-    1.75 of the mean fits the receive buffer of twice the mean; every row
-    to one chip does not)."""
-    monkeypatch.setattr(moe, "exchange_impl", lambda mesh: "ragged")
-    monkeypatch.setattr(jax.lax, "ragged_all_to_all",
-                        ragged_all_to_all_from_gathers)
-    with jax.default_matmul_precision("highest"):
-        params, x, top_w = layer_and_rows()
-        top_e = LOADS[load]()
-        mesh = mesh_of(4)
-
-        def weigh(y):
-            return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
-
-        def one_device(p, x, w):
-            y = moe._sorted_ffn(p, x, w, top_e, None)[0]
-            return weigh(y), y
-
-        def exchanged(p, x, w):
-            y, record = moe._exchange_ffn(p, x, w, top_e, mesh, RULES)
-            return weigh(y), (y, record)
-
-        (_, want), want_grads = jax.jit(jax.value_and_grad(
-            one_device, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
-        jaxpr = str(jax.make_jaxpr(jax.grad(
-            lambda *a: exchanged(*a)[0], argnums=(0, 1, 2)))(
-                params, x, top_w))
-        (_, (got, record)), grads = jax.jit(jax.value_and_grad(
-            exchanged, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
-        assert_close(got, want, "y")
-        assert_close(got, loop_reference(params, x, top_w, top_e),
-                     "y against the loop")
-        for a, b in zip(jax.tree.leaves(grads),
-                        jax.tree.leaves(want_grads)):
-            if np.abs(np.asarray(b)).max() > 0:
-                assert_close(a, b, "gradient")
-    # every permutation a gather in the backward pass too
-    assert "scatter" not in jaxpr
-    bounded = RAGGED_BOUNDED[load]
-    assert np.asarray(record["exchange_bounded"]).tolist() == [bounded] * 4
-    counts = np.asarray(record["tokens_per_expert"])
-    assert counts.sum() == N * K
-    np.testing.assert_array_equal(
-        record["rows_received"], counts.reshape(4, -1).sum(-1))
-    needed = np.asarray(record["exchange_rows_needed"])
-    # chip c's slots for its own experts stay: its tokens are rows c*64..
-    own = [int(((np.asarray(top_e)[c * 64:(c + 1) * 64] // 2) == c).sum())
-           for c in range(4)]
-    assert needed.tolist() == [N // 4 * K - o for o in own]
-    sent = np.asarray(record["exchange_rows_sent"])
-    if bounded:
-        np.testing.assert_array_equal(sent, needed)
-    else:       # the dense rounds that take any load, as on the CPU
-        assert sent.tolist() == [2 * 3 * 64] * 4
-    if load == "one_chip_overflows":
-        # chip 0's 128 slots on top of its near-uniform share of the rest
-        assert 192 < np.asarray(record["rows_received"])[3] <= 256
-
-
-def test_exchange_impl_by_what_the_mesh_says():
-    assert moe.exchange_impl(mesh_of(4)) == "buckets"
-
-
-def test_the_held_shares_add_up_to_the_exchange_and_the_uncut_layer():
-    """`moe_ffn` as a held share at each of the four offsets (the one-chip
-    path of the share cells) sums to what the exchange gives and to the
-    reference's uncut layer."""
-    with jax.default_matmul_precision("highest"):
-        params, x, _ = layer_and_rows(4)
-        params["w_router"] = params["w_router"] * 40.0
-        def ffn(p, x, **kw):
-            return jax.jit(lambda p, x: moe.moe_ffn(p, x, **kw))(p, x)
-
-        whole, routing = ffn(params, x, num_selected=K)
-        held = E // 4
-        shares = []
-        for c in range(4):
-            share = dict(params,
-                         w_gateup=params["w_gateup"][c * held:(c + 1) * held],
-                         w_down=params["w_down"][c * held:(c + 1) * held])
-            y, r = ffn(share, x, num_selected=K, expert_offset=c * held)
-            shares.append(y)
-            np.testing.assert_array_equal(
-                r["tokens_per_expert"],
-                routing["tokens_per_expert"][c * held:(c + 1) * held])
-        exchanged, record = ffn(params, x, num_selected=K, mesh=mesh_of(4),
-                                rules=RULES)
-        assert_close(sum(shares), exchanged, "shares against the exchange")
-        assert_close(exchanged, whole, "exchange against one device")
-        _, top_w, top_e = moe.route(params["w_router"], x, K, True)
-        assert_close(exchanged, loop_reference(params, x, top_w, top_e),
-                     "exchange against the uncut layer")
-    assert int(record["dropped"]) == 0
-    np.testing.assert_array_equal(record["tokens_per_expert"],
-                                  routing["tokens_per_expert"])
-
-
-def test_exchange_bound_is_twice_the_uniform_share_in_tiles():
-    assert moe.exchange_bound(8192 * 8, 4) == 32768          # the cell's
-    assert 4 * moe.exchange_bound(8192 * 8, 4) % moe.GMM_ROWS == 0
-    assert moe.exchange_bound(256, 4) == 128
-    assert moe.exchange_bound(128, 4) == 64
-    assert moe.exchange_bound(128, 2) is None     # would hold every slot
-    assert moe.exchange_bound(100, 4) == 56       # whole sublane tiles
-
-
-def test_an_expert_mesh_refuses_what_it_cannot_lay_out():
-    params, x, _ = layer_and_rows()
-    mesh = make_mesh(MeshConfig(data=2, fsdp=2), devices=jax.devices()[:4])
-    with pytest.raises(ValueError, match="partly"):
-        moe.moe_ffn(params, x, num_selected=K, mesh=mesh,
-                    rules=ShardingRules().replace(
-                        expert=("data", "fsdp"), batch="fsdp",
-                        expert_embed=None))
-    share = dict(params, w_gateup=params["w_gateup"][:2],
-                 w_down=params["w_down"][:2])
-    with pytest.raises(ValueError, match="held share"):
-        moe.moe_ffn(share, x, num_selected=K, mesh=mesh_of(4), rules=RULES)
-
-
-# ---- scopes ---------------------------------------------------------------------
-
-
-def stripped(hlo_text):
-    """`tests/test_model_scopes.stripped`, and every instruction's name by
-    its first place in the text: inside a `shard_map` an instruction is
-    named after its op_name (`%jvp_jit_take_along_axis__` with the scopes,
-    `%jit_take_along_axis_` without), so the names themselves differ where
-    the programs do not."""
-    text = re.sub(r",? ?metadata=\{[^}]*\}", "", hlo_text)
-    text = re.sub(r"(?ms)^FileNames$.*?^StackFrames$.*?\n\n", "", text)
-    places = {}
-    return re.sub(r"%[\w\-.]+", lambda m: places.setdefault(
-        m.group(0), f"%{len(places)}"), text)
-
-
-def test_the_new_scopes_are_metadata_only(monkeypatch):
-    cfg = config(remat=True, loss_chunk=16, layer_pattern="WL", n_layers=2)
-    mesh = mesh_of(4)
-
-    def lowered():
-        params = jax.eval_shape(
-            lambda: Transformer.init(jax.random.key(0), cfg))
-        return jax.jit(jax.grad(lambda p, b: Transformer.loss(
-            p, b, cfg, mesh=mesh, rules=RULES))).lower(
-                params, {"tokens": jax.ShapeDtypeStruct((4, 65), jnp.int32)})
-
-    def compiled():
-        return lowered().compile().as_text()
-
-    # the tables of constant positions are folded by XLA:CPU: their
-    # scopes are read off the lowered module
-    names = lowered().as_text(debug_info=True)
-    for scope in ("rope/plain", "rope/yarn"):
-        assert re.search(rf"[/(]{scope}[/)]", names), scope
-    with_scopes = compiled()
-    for scope in ("moe/exchange", "moe/dispatch", "moe/experts",
-                  "moe/combine", "attention/window", "attention/full"):
-        assert f"/{scope}/" in with_scopes, scope
-    exchanges = [line for line in with_scopes.splitlines()
-                 if re.search(r" all-to-all(-start)?\(", line)]
-    assert exchanges and all("moe/exchange" in line for line in exchanges)
-
-    @contextlib.contextmanager
-    def no_scope(name):
-        yield
-
-    monkeypatch.setattr(jax, "named_scope", no_scope)
-    without = compiled()
-    assert "moe/exchange" not in without
-    assert stripped(with_scopes) == stripped(without)
-
-
-# ---- the faults -------------------------------------------------------------------
-
-TINY = load_json(os.path.join(BENCH_DIR, "rehearsal", "configs",
-                              "tiny-mellum2.json"))
-MIX = load_json(os.path.join(BENCH_DIR, "traffic", "rehearsal_tiny.json"))
-
-
-@pytest.fixture(scope="module")
-def fault_rows():
-    return {row["variant"]: row for row in faults.read(TINY, MIX, 3)}
-
-
-@pytest.mark.parametrize("name", faults.FAULTS + faults.PRECISIONS)
-def test_every_fault_is_caught_at_the_small_size(fault_rows, name):
-    """Each fault moves logits or loss by more than the limits (the
-    rehearsal's are looser than the cell's: what passes them passes the
-    cell's); bf16 operands pass."""
-    row = fault_rows[name]
-    assert row["correct"] == (name == "bfloat16"), row
-    if name != "bfloat16":
-        assert row["rel_l2"] > 0.05 or row["loss_diff"] > 0.05, row

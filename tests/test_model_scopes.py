@@ -61,7 +61,9 @@ def lower_step(cfg):
     init_state, train_step = make_train_step(
         lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh),
         Transformer.param_specs(cfg), mesh)
-    state = init_state(Transformer.init(jax.random.key(0), cfg))
+    # shapes and shardings are all a lowering reads: no weights are drawn
+    state = jax.eval_shape(
+        lambda: init_state(Transformer.init(jax.random.key(0), cfg)))
     return train_step.lower(state, batch_for(cfg, len(jax.devices())))
 
 

@@ -13,6 +13,8 @@ configuration of each kind of the benchmark's expert cells; a dense
 configuration names nothing and lowers to the same text with and without
 the name in the policy."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -64,9 +66,11 @@ BATCH = {"tokens": jax.random.randint(jax.random.key(1), (2, SEQ + 1), 0,
                                       BASE["vocab_size"])}
 
 
+@functools.lru_cache(maxsize=None)
 def weights(cfg, seed=0):
     """`Transformer.init` with a choice bias that is not zero and an
-    embedding of order 1, so the router's logits differ by token."""
+    embedding of order 1, so the router's logits differ by token. Made
+    once a (configuration, seed): nothing below writes into them."""
     params = Transformer.init(jax.random.key(seed), cfg)
     params["embed"] = jax.random.normal(
         jax.random.key(seed + 1), params["embed"].shape, jnp.float32)
@@ -147,7 +151,8 @@ def programs():
     def of(kind):
         if kind not in cache:
             cfg = KINDS[kind]
-            params = weights(cfg)
+            # shapes are all a trace reads: no weights are drawn
+            params = jax.eval_shape(lambda: weights.__wrapped__(cfg))
             forward = jax.make_jaxpr(
                 lambda p: Transformer.loss(p, BATCH, cfg))(params).jaxpr
             cache[kind] = {

@@ -15,6 +15,7 @@ nothing else. Every published term has a case below that fails without
 it.
 """
 
+import functools
 import os
 import sys
 
@@ -33,6 +34,9 @@ if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
 from benchlib.spec import load_module  # noqa: E402
+
+from tests import _programs  # noqa: E402
+from tests._programs import programs, value_and_grads  # noqa: E402
 
 ref = load_module("reference", "nemotron_h_f32")
 job = load_module("jobs", "train_lm_ssm_moe")
@@ -71,6 +75,13 @@ def published(cfg, **over):
            "routed_scaling_factor": cfg.moe_routed_scale}
     out.update(over)
     return out
+
+
+def reference(cfg):
+    """The reference at `cfg`'s published keys under `jax.jit`
+    (`tests/_programs.reference`): `.forward(w, tokens)` -> (logits,
+    chosen)."""
+    return _programs.reference(ref, published, cfg, with_routing=True)
 
 
 def subs_of(params):
@@ -140,17 +151,16 @@ def test_logits_and_loss_match_the_reference(share, seed):
     if held:
         params = share_of(params, held, offset)
     tokens = batch(cfg, seed)
-    logits = Transformer.apply(params, tokens[:, :-1], cfg)
-    loss, metrics = Transformer.loss(params, {"tokens": tokens}, cfg,
-                                     with_metrics=True)
+    logits = programs(cfg).logits(params, tokens[:, :-1])
+    loss, metrics = programs(cfg).loss(params, {"tokens": tokens})
     w = job.to_reference_layout(params, cfg)
     experts = [lw for lw in w["layers"] if "experts" in lw]
     assert sorted(experts[0]["experts"]) == list(
         range(offset, offset + cfg.held_experts))
-    pub = published(cfg)
-    want, chosen = ref.forward(w, tokens[:, :-1], pub, with_routing=True)
+    want, chosen = reference(cfg).forward(w, tokens[:, :-1])
     assert_close(logits, want, "logits")
-    assert abs(float(loss) - float(ref.loss(w, tokens, pub))) < RTOL
+    assert abs(float(loss) - float(ref.next_token_loss(
+        want, tokens[:, 1:]))) < RTOL
     # the counters are the reference's choices of the held experts
     counts = np.asarray(ref.tokens_per_expert(chosen, E))
     got = np.asarray(metrics["moe_tokens_per_expert"])
@@ -212,15 +222,22 @@ def from_reference_layout(grads, cfg):
             "lm_head": grads["lm_head"].T, "runs": runs}
 
 
+@functools.lru_cache(maxsize=None)
+def gradient_case():
+    """(weights, tokens, the reference's gradients): remat changes the
+    program, not what it is held to."""
+    cfg = config()
+    params = weights(cfg, 1)
+    tokens = batch(cfg, 1)
+    w = job.to_reference_layout(params, cfg)
+    return params, tokens, reference(cfg).loss_and_grads(w, tokens)[1]
+
+
 @pytest.mark.parametrize("remat", [False, True])
 def test_gradients_match_jax_grad_of_the_reference(remat):
     cfg = config(remat=remat)
-    params = weights(cfg, 1)
-    tokens = batch(cfg, 1)
-    grads = jax.grad(lambda p: Transformer.loss(
-        p, {"tokens": tokens}, cfg))(params)
-    w = job.to_reference_layout(params, cfg)
-    _, ref_grads = ref.loss_and_grads(w, tokens, published(cfg))
+    params, tokens, ref_grads = gradient_case()
+    _, grads = programs(cfg).grads(params, {"tokens": tokens})
     # the bias enters the choice only: no gradient on either side
     for sub in subs_of(grads):
         if "router_bias" in sub:
@@ -249,6 +266,13 @@ def scan_inputs(seed, t=64, h=8, p=4, g=4, n=8):
     return x, dt, a, b, c
 
 
+@functools.lru_cache(maxsize=None)
+def chunked(chunk):
+    """`ssd_scan` under one `jax.jit` a chunk length."""
+    return jax.jit(lambda *v: ssm.ssd_scan(*v, chunk))
+
+
+@jax.jit
 def recurrence(x, dt, a, b, c):
     """The reference's step-by-step scan, without the D skip."""
     rep = x.shape[2] // b.shape[2]
@@ -262,17 +286,15 @@ def recurrence(x, dt, a, b, c):
 @pytest.mark.parametrize("seed", [0, 1])
 def test_chunked_scan_is_the_recurrence_forward(chunk, seed):
     args = scan_inputs(seed)
-    assert_close(ssm.ssd_scan(*args, chunk), recurrence(*args), "y")
+    assert_close(chunked(chunk)(*args), recurrence(*args), "y")
 
 
 @pytest.mark.parametrize("chunk", [8, 32])
 def test_chunked_scan_is_the_recurrence_backward(chunk):
     args = scan_inputs(2)
     probe = jax.random.normal(jax.random.key(7), args[0].shape)
-    got = jax.grad(lambda *v: jnp.sum(ssm.ssd_scan(*v, chunk) * probe),
-                   argnums=(0, 1, 2, 3, 4))(*args)
-    want = jax.grad(lambda *v: jnp.sum(recurrence(*v) * probe),
-                    argnums=(0, 1, 2, 3, 4))(*args)
+    _, got = value_and_grads(chunked(chunk))(probe, *args)
+    _, want = value_and_grads(recurrence)(probe, *args)
     for name, g, w in zip(("x", "dt", "a", "b", "c"), got, want):
         assert_close(g, w, "d" + name, rtol=2e-4)
 
@@ -282,10 +304,10 @@ def test_a_long_decay_does_not_overflow_above_the_diagonal():
     and inf x 0 a NaN in the value or the gradient."""
     x, dt, a, b, c = scan_inputs(3)
     dt, a = dt * 0 + 5.0, a * 0 - 8.0
-    y, grads = jax.value_and_grad(
-        lambda x: jnp.sum(ssm.ssd_scan(x, dt, a, b, c, 16)))(x)
+    y, grads = jax.jit(jax.value_and_grad(
+        lambda x: jnp.sum(ssm.ssd_scan(x, dt, a, b, c, 16))))(x)
     assert np.isfinite(float(y)) and np.isfinite(np.asarray(grads)).all()
-    assert_close(ssm.ssd_scan(x, dt, a, b, c, 16),
+    assert_close(chunked(16)(x, dt, a, b, c),
                  recurrence(x, dt, a, b, c), "y")
 
 
@@ -523,16 +545,23 @@ TERMS = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def term_case():
+    """(weights, tokens, the reference's logits) every term is read at."""
+    cfg = config()
+    params = weights(cfg, 2)
+    tokens = batch(cfg, 2)
+    w = job.to_reference_layout(params, cfg)
+    return params, tokens, reference(cfg).forward(w, tokens[:, :-1])[0]
+
+
 @pytest.mark.parametrize("term", TERMS)
 def test_a_term_left_out_of_the_program_fails(term):
     """The comparison sees each term: the program with the term changed
     is far from the reference with it."""
     how = TERMS[term]
     cfg = config()
-    params = weights(cfg, 2)
-    tokens = batch(cfg, 2)
-    w = job.to_reference_layout(params, cfg)
-    want = ref.forward(w, tokens[:, :-1], published(cfg))
+    params, tokens, want = term_case()
     changed = jax.tree.map(lambda x: x, params)
     for sub in subs_of(changed):
         if how.get("zero") in sub:
@@ -540,8 +569,8 @@ def test_a_term_left_out_of_the_program_fails(term):
         if "scale" in how and how["scale"][0] in sub:
             name, factor, *shift = how["scale"]
             sub[name] = sub[name] * factor + (shift[0] if shift else 0.0)
-    got = Transformer.apply(changed, tokens[:, :-1],
-                            cfg.replace(**how.get("cfg", {})))
+    got = programs(cfg.replace(**how.get("cfg", {}))).logits(
+        changed, tokens[:, :-1])
     assert rel_diff(got, want) > 30 * RTOL, term
 
 

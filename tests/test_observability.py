@@ -31,10 +31,13 @@ def test_list_tasks_records_lifecycle(ray_start):
     while time.time() < deadline:
         recs = [r for r in state_api.list_tasks()
                 if r.get("name") == "traced_task"]
-        # owner-side FINISHED and the executing worker's RUNNING timestamps
-        # flush on independent 1s cadences — wait for the merged record
+        # owner-side FINISHED and the executing worker's timestamps flush
+        # on independent 1s cadences, and the worker stamps ts_exec_end
+        # after its report (a flush may fall between its two stamps) —
+        # wait for the merged, whole record
         if recs and recs[-1].get("state") == "FINISHED" \
-                and "ts_running" in recs[-1]:
+                and "ts_running" in recs[-1] \
+                and "ts_exec_end" in recs[-1]:
             rec = recs[-1]
             break
         time.sleep(0.2)
@@ -85,8 +88,17 @@ def test_list_actors_and_workers(ray_start):
 def test_list_objects_and_store_stats(ray_start):
     import numpy as np
     ref = ray_tpu.put(np.zeros(64 * 1024))  # > inline threshold
+    # the put registers with the store one-way: wait for the listing to
+    # show it, not for a fixed time
+    def listed(listing):
+        return any(o["object_id"] == ref.hex() for o in listing["objects"])
+
+    deadline = time.time() + 10
     listing = state_api.list_objects()
-    assert any(o["object_id"] == ref.hex() for o in listing["objects"])
+    while not listed(listing) and time.time() < deadline:
+        time.sleep(0.1)
+        listing = state_api.list_objects()
+    assert listed(listing)
     # every alive node answered → the unreachable list is empty (the
     # logs_query-style contract: silent absence is not allowed)
     assert listing["unreachable"] == []

@@ -15,6 +15,7 @@ allows that and nothing else. Every published term has a case below that
 fails without it.
 """
 
+import functools
 import os
 import sys
 
@@ -32,6 +33,9 @@ if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
 from benchlib.spec import load_module  # noqa: E402
+
+from tests import _programs  # noqa: E402
+from tests._programs import programs  # noqa: E402
 
 ref = load_module("reference", "olmo_hybrid_f32")
 faults = load_module("reference", "olmo_hybrid_faults")
@@ -67,6 +71,12 @@ def published(cfg, **over):
     return out
 
 
+def reference(cfg):
+    """The reference at `cfg`'s published keys under `jax.jit`
+    (`tests/_programs.reference`)."""
+    return _programs.reference(ref, published, cfg)
+
+
 def weights(cfg, seed):
     """The job's stand-in weights (every gain off 1, the decays spread)
     with the final norm's gain off 1 too."""
@@ -93,10 +103,9 @@ def both_sides():
     cfg = config()
     params, toks = weights(cfg, 0), tokens(1)
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(lambda p: Transformer.loss(
-            p, {"tokens": toks}, cfg))(params)
-        ref_loss, ref_grads = ref.loss_and_grads(
-            job.to_reference_layout(params, cfg), toks, published(cfg))
+        loss, grads = programs(cfg).grads(params, {"tokens": toks})
+        ref_loss, ref_grads = reference(cfg).loss_and_grads(
+            job.to_reference_layout(params, cfg), toks)
     return cfg, params, toks, (loss, job.to_reference_layout(grads, cfg)), \
         (ref_loss, ref_grads)
 
@@ -106,9 +115,9 @@ def test_logits_match_the_reference(seed):
     cfg = config()
     params, toks = weights(cfg, seed), tokens(seed + 10)
     with jax.default_matmul_precision("highest"):
-        got = Transformer.apply(params, toks[:, :-1], cfg)
-        want = ref.forward(job.to_reference_layout(params, cfg),
-                           toks[:, :-1], published(cfg))
+        got = programs(cfg).logits(params, toks[:, :-1])
+        want = reference(cfg).forward(job.to_reference_layout(params, cfg),
+                                      toks[:, :-1])
     close(got, want)
 
 
@@ -145,22 +154,32 @@ def test_an_outer_gradient_matches_the_references(both_sides, name):
 def test_gradients_match_under_remat(both_sides):
     cfg, params, toks, (_, grads), _ = both_sides
     with jax.default_matmul_precision("highest"):
-        again = jax.grad(lambda p: Transformer.loss(
-            p, {"tokens": toks}, cfg.replace(remat=True)))(params)
+        _, again = programs(cfg.replace(remat=True)).grads(
+            params, {"tokens": toks})
     for got, want in zip(jax.tree.leaves(job.to_reference_layout(again, cfg)),
                          jax.tree.leaves(grads)):
         close(got, want)
+
+
+@functools.lru_cache(maxsize=None)
+def variants_base():
+    """(configuration, tokens, weights in the reference's layout, its
+    logits) every variant below is read against: op by op, as the
+    variants run."""
+    cfg = config()
+    params, toks = weights(cfg, 3), tokens(4)
+    layout = job.to_reference_layout(params, cfg)
+    with jax.default_matmul_precision("highest"):
+        return cfg, toks, layout, ref.forward(layout, toks[:, :-1],
+                                              published(cfg))
 
 
 @pytest.mark.parametrize("name", faults.FAULTS)
 def test_each_fault_moves_the_logits(name):
     """What the system matches to 1e-4 a term left out misses by a
     hundred times that or more: every term is in the comparison."""
-    cfg = config()
-    params, toks = weights(cfg, 3), tokens(4)
-    layout = job.to_reference_layout(params, cfg)
+    cfg, toks, layout, base = variants_base()
     with jax.default_matmul_precision("highest"):
-        base = ref.forward(layout, toks[:, :-1], published(cfg))
         module, model, w = faults.variant(name, published(cfg), layout)
         moved = module.forward(w, toks[:, :-1], model)
     rel = float(jnp.sqrt(jnp.sum((moved - base) ** 2) / jnp.sum(base ** 2)))
@@ -174,11 +193,8 @@ def test_each_fault_moves_the_logits(name):
                                          ("float8_e4m3fn", False),
                                          ("float8_e5m2", False)])
 def test_narrower_operands_read_as_they_should(name, passes):
-    cfg = config()
-    params, toks = weights(cfg, 3), tokens(4)
-    layout = job.to_reference_layout(params, cfg)
+    cfg, toks, layout, base = variants_base()
     with jax.default_matmul_precision("highest"):
-        base = ref.forward(layout, toks[:, :-1], published(cfg))
         module, model, w = faults.variant(name, published(cfg), layout)
         moved = module.forward(w, toks[:, :-1], model)
     rel = float(jnp.sqrt(jnp.sum((moved - base) ** 2) / jnp.sum(base ** 2)))
@@ -238,10 +254,10 @@ def test_a_share_of_the_heads_is_the_reference_given_the_same_share():
                                 for sub in run] for run in params["runs"]])
     toks = tokens(8)
     with jax.default_matmul_precision("highest"):
-        got = Transformer.apply(share, toks[:, :-1], held)
-        want = ref.forward(job.to_reference_layout(share, held),
-                           toks[:, :-1], published(held))
-        full = Transformer.apply(params, toks[:, :-1], whole)
+        got = programs(held).logits(share, toks[:, :-1])
+        want = reference(held).forward(job.to_reference_layout(share, held),
+                                       toks[:, :-1])
+        full = programs(whole).logits(params, toks[:, :-1])
     close(got, want)
     assert float(jnp.abs(got - full).max()) > 0.01   # a share is no whole
 

@@ -13,6 +13,7 @@ a missing QK-norm or an ungated expert by more, a per-layer instead of an
 all-layers aux loss by 1e-2 of it.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -146,6 +147,13 @@ def system(params, tokens, cfg, **kw):
     return loss, metrics, grads
 
 
+@functools.lru_cache(maxsize=None)
+def compiled_system(cfg):
+    """`system` at `cfg` under one `jax.jit` a process: the five variants
+    below are held to one base, compiled once."""
+    return jax.jit(lambda p, t: system(p, t, cfg))
+
+
 @pytest.mark.parametrize("skew", [False, True], ids=["uniform", "skewed"])
 @pytest.mark.parametrize("routing", ROUTINGS)
 def test_loss_logits_and_every_gradient_match_the_reference(routing, skew):
@@ -160,8 +168,7 @@ def test_loss_logits_and_every_gradient_match_the_reference(routing, skew):
         w, t, hf, with_router_logits=True))(ref_w, tokens[:, :-1])
     assert_close(logits, ref_logits, "logits")
 
-    loss, metrics, grads = jax.jit(
-        lambda p, t: system(p, t, cfg))(params, tokens)
+    loss, metrics, grads = compiled_system(cfg)(params, tokens)
     (ref_total, ref_ce, ref_aux), ref_grads = jax.jit(
         lambda w, t: ref.loss_and_grads(w, t, hf))(ref_w, tokens)
     assert_close(metrics["moe_aux_loss"], ref_aux, "aux loss")
@@ -218,10 +225,8 @@ def test_remat_scan_and_chunked_head_change_nothing(variant):
            "remat_unrolled": base.replace(remat=True, scan_unroll=2),
            "chunked": base.replace(remat=True, loss_chunk=16)}[variant]
     params, tokens = weights(base, seed=7, skew=True), batch(base, seed=7)
-    loss, metrics, grads = jax.jit(
-        lambda p, t: system(p, t, base))(params, tokens)
-    loss2, metrics2, grads2 = jax.jit(
-        lambda p, t: system(p, t, cfg))(params, tokens)
+    loss, metrics, grads = compiled_system(base)(params, tokens)
+    loss2, metrics2, grads2 = compiled_system(cfg)(params, tokens)
     assert_close(loss2, loss, "loss", rtol=1e-6)
     np.testing.assert_array_equal(
         np.asarray(metrics2["moe_tokens_per_expert"]),

@@ -36,6 +36,8 @@ if BENCH_DIR not in sys.path:
 
 from benchlib.spec import load_module  # noqa: E402
 
+from tests._programs import programs, value_and_grads  # noqa: E402
+
 ref = load_module("reference", "phi4flash_f32")
 job = load_module("jobs", "train_lm_sambay")
 
@@ -92,9 +94,16 @@ def scan_inputs(key, b=2, t=SEQ, c=24, n=4):
             jax.random.normal(ks[5], (b, t, c)))
 
 
+@jax.jit
 def recurrence(x, dt, a, b, c):
     return jnp.stack([ref.recurrence(x[i], dt[i], a, b[i], c[i])
                       for i in range(x.shape[0])])
+
+
+@functools.lru_cache(maxsize=None)
+def chunked(chunk):
+    """`selective_scan` under one `jax.jit` a chunk length."""
+    return jax.jit(lambda *a: ssm.selective_scan(*a, chunk))
 
 
 @pytest.mark.parametrize("chunk", [96, 32, 24, 16])
@@ -102,7 +111,7 @@ def test_selective_scan_is_the_recurrence(chunk):
     """T of one to six chunks, sub-chunks of 32, 8 and 16 steps; steps as
     large as 9 (decays down to e^-100): nothing overflows."""
     *args, _ = scan_inputs(jax.random.key(0))
-    close(ssm.selective_scan(*args, chunk), recurrence(*args))
+    close(chunked(chunk)(*args), recurrence(*args))
 
 
 def test_a_bfloat16_state_fails_this_comparison():
@@ -114,7 +123,7 @@ def test_a_bfloat16_state_fails_this_comparison():
     x, dt, a, b, c = args
     narrow = jnp.stack([ref.recurrence(x[i], dt[i], a, b[i], c[i],
                                        dtype="bfloat16") for i in range(2)])
-    got = ssm.selective_scan(*args, 32)
+    got = chunked(32)(*args)
     assert float(jnp.abs(got - narrow).max()) > 10 * RTOL * float(
         jnp.abs(got).max())
 
@@ -128,10 +137,8 @@ def test_selective_scan_refuses_a_ragged_chunk():
 @pytest.mark.parametrize("chunk", [32, 48])
 def test_selective_scan_gradients_are_the_recurrences(chunk):
     *args, w = scan_inputs(jax.random.key(1))
-    got = jax.grad(lambda *a: jnp.sum(ssm.selective_scan(*a, chunk) * w),
-                   argnums=range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(recurrence(*a) * w),
-                    argnums=range(5))(*args)
+    _, got = value_and_grads(chunked(chunk))(w, *args)
+    _, want = value_and_grads(recurrence)(w, *args)
     for g, r in zip(got, want):
         close(g, r)
 
@@ -150,13 +157,22 @@ def test_mamba1_mixer_and_its_gradients_against_the_reference():
     def theirs(h, lw):
         f, y, _ = ref.mamba(h, lw, m)
         return f, y
-    for a, b in zip(mine(h, lp), theirs(h, lw)):
-        close(a, b)
     w = jax.random.normal(jax.random.key(4), (2, SEQ, 64))
-    g_mine = jax.grad(lambda h, lp: jnp.sum(mine(h, lp)[0] * w),
-                      argnums=(0, 1))(h, lp)
-    g_ref = jax.grad(lambda h, lw: jnp.sum(theirs(h, lw)[0] * w),
-                     argnums=(0, 1))(h, lw)
+
+    def with_grads(mixer):
+        """((_, both outputs), the gradients of the first under w): one
+        program."""
+        def loss(h, leaves):
+            out = mixer(h, leaves)
+            return jnp.sum(out[0] * w), out
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))
+
+    (_, out_mine), g_mine = with_grads(mine)(h, lp)
+    (_, out_ref), g_ref = with_grads(theirs)(h, lw)
+    for a, b in zip(out_mine, out_ref):
+        close(a, b)
     close(g_mine[0], g_ref[0])
     for mine_name, ref_name, turned in (
             ("w_in", "in_proj", True), ("w_x", "x_proj", True),
@@ -253,7 +269,8 @@ def test_differential_attention_layer_against_dense_masked(
     # the attention sublayer alone: without the MLP's leaves
     sub = {n: leaf for n, leaf in lp.items()
            if n not in ("mlp_norm", "mlp_norm_bias", "w_gateup", "w_down")}
-    out, _, made = layer(x, sub, shared, kind)
+    out, _, made = jax.jit(lambda x, sub, shared: layer(
+        x, sub, shared, kind))(x, sub, shared)
 
     n = ref.layer_norm(x, lw["input_layernorm"], 1e-5)
     if kind == "c":
@@ -319,9 +336,9 @@ def test_shared_tensors_gradient_is_the_sum_over_their_readers(remat):
                           copy, kind)[0]
         return jnp.sum(h * w)
 
-    total = jax.grad(through_stack)(shared)
-    each = jax.grad(one_by_one)([shared] * 4)
-    close(through_stack(shared), one_by_one([shared] * 4))
+    whole, total = jax.jit(jax.value_and_grad(through_stack))(shared)
+    apart, each = jax.jit(jax.value_and_grad(one_by_one))([shared] * 4)
+    close(whole, apart)
     for name in shared:
         close(total[name], sum(g[name] for g in each))
     # a GMU reads the memory alone, a cross layer K and V alone
@@ -352,26 +369,41 @@ def test_remat_does_not_recompute_the_shared_tensors():
 # ---- the model -----------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def reference_case(kinds, first):
+    """(weights, tokens, the reference's logits, loss and gradients) at a
+    pattern: remat changes the program, not what it is held to. The
+    reference under one `jax.jit`, its attention in three blocks of
+    queries, one ragged."""
+    m = model(kinds, first)
+    cfg = config(m)
+    params = job.init_params(jax.random.key(14), cfg, INIT)
+    toks = jax.random.randint(jax.random.key(15), (2, SEQ + 1), 0, 128)
+    weights = job.to_reference_layout(params, cfg)
+
+    def loss(w):
+        logits = ref.forward(w, toks[:, :-1], m)
+        return ref.next_token_loss(logits, toks[:, 1:]), logits
+
+    with pytest.MonkeyPatch.context() as patch, \
+            jax.default_matmul_precision("highest"):
+        patch.setattr(ref, "QUERY_BLOCK", 40)
+        (ref_loss, want), ref_grads = jax.jit(
+            jax.value_and_grad(loss, has_aux=True))(weights)
+    return params, toks, want, ref_loss, ref_grads
+
+
 @pytest.mark.parametrize("kinds,first,runs", [
     ("mwsfgc", 14, [("mwsfgc", 1)]),
     ("mwmwsfgcgc", 12, [("mw", 2), ("sf", 1), ("gc", 2)]),
 ])
 @pytest.mark.parametrize("remat", [False, True])
-def test_model_against_the_reference(kinds, first, runs, remat, monkeypatch):
-    monkeypatch.setattr(ref, "QUERY_BLOCK", 40)   # three blocks, one ragged
-    m = model(kinds, first)
-    cfg = config(m, remat=remat)
+def test_model_against_the_reference(kinds, first, runs, remat):
+    cfg = config(model(kinds, first), remat=remat)
     assert cfg.pattern_runs == runs
-    params = job.init_params(jax.random.key(14), cfg, INIT)
-    toks = jax.random.randint(jax.random.key(15), (2, SEQ + 1), 0, 128)
-    weights = job.to_reference_layout(params, cfg)
-    want = ref.forward(weights, toks[:, :-1], m)
-    close(Transformer.apply(params, toks[:, :-1], cfg), want)
-    loss, grads = jax.value_and_grad(lambda p: Transformer.loss(
-        p, {"tokens": toks}, cfg))(params)
-    ref_loss, ref_grads = jax.value_and_grad(
-        lambda w: ref.next_token_loss(ref.forward(w, toks[:, :-1], m),
-                                      toks[:, 1:]))(weights)
+    params, toks, want, ref_loss, ref_grads = reference_case(kinds, first)
+    close(programs(cfg).logits(params, toks[:, :-1]), want)
+    loss, grads = programs(cfg).grads(params, {"tokens": toks})
     assert abs(float(loss) - float(ref_loss)) < 1e-5
     # the tied embedding's gradient: the lookup's and the head's together
     close(grads["embed"], ref_grads["embed_tokens"])

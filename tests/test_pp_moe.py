@@ -132,8 +132,10 @@ class TestMoE:
         key = jax.random.PRNGKey(0)
         params = init_moe_params(key, d_model=16, d_ff=32, n_experts=4)
         x = jax.random.normal(jax.random.PRNGKey(1), (24, 16))
-        y, routing = moe_ffn(params, x, num_selected=2, mesh=mesh,
-                             rules=rules)
+        # one program: op by op every op under the `shard_map` compiles
+        # alone for the eight devices
+        y, routing = jax.jit(lambda p, x: moe_ffn(
+            p, x, num_selected=2, mesh=mesh, rules=rules))(params, x)
         y_ref = moe_ffn_dense_reference(params, x, num_selected=2)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-5)
@@ -154,9 +156,10 @@ class TestMoE:
         key = jax.random.PRNGKey(2)
         params = init_moe_params(key, d_model=8, d_ff=16, n_experts=2)
         x = jax.random.normal(jax.random.PRNGKey(3), (16, 8))
-        y_mesh, r_mesh = moe_ffn(params, x, num_selected=1, mesh=mesh,
-                                 rules=rules)
-        y_sorted, r_sorted = moe_ffn(params, x, num_selected=1)
+        y_mesh, r_mesh = jax.jit(lambda p, x: moe_ffn(
+            p, x, num_selected=1, mesh=mesh, rules=rules))(params, x)
+        y_sorted, r_sorted = jax.jit(lambda p, x: moe_ffn(
+            p, x, num_selected=1))(params, x)
         counts = np.asarray(r_sorted["tokens_per_expert"])
         # the capacity path's buffer at its tight factor 0.25 held
         # int(0.25 * 16 * 1 / 2) = 2 slots an expert, and dropped the rest

@@ -194,3 +194,28 @@ class TestObjectStore:
             finally:
                 s1.shutdown()
                 s2.shutdown()
+
+
+def test_a_test_that_runs_past_its_limit_fails_by_name(monkeypatch):
+    """The limit tests/conftest.py arms around every test: a sleep past it
+    ends as a failure that names the test, and the timer and the handler of
+    the test around it (this one's) are back afterwards."""
+    import signal
+    import time
+
+    from tests import conftest
+
+    outer = signal.getsignal(signal.SIGALRM)
+    assert signal.getitimer(signal.ITIMER_REAL)[0] > 0   # this test's own
+    monkeypatch.setattr(conftest, "TEST_LIMIT_S", 0.05)
+    began = time.monotonic()
+    with pytest.raises(pytest.fail.Exception,
+                       match=r"tests/test_x\.py::test_sleeps ran past"):
+        with conftest.time_limit("tests/test_x.py::test_sleeps"):
+            time.sleep(30)
+    assert time.monotonic() - began < 5
+    assert signal.getsignal(signal.SIGALRM) is outer
+    assert signal.getitimer(signal.ITIMER_REAL)[0] > 60
+    with conftest.time_limit("tests/test_x.py::test_returns"):
+        pass                                  # inside the limit: nothing
+    time.sleep(0.1)                           # and no alarm left behind

@@ -15,6 +15,7 @@ entry of each compared array allows that and nothing else. Every fault of
 loss by far more.
 """
 
+import functools
 import os
 import sys
 
@@ -33,6 +34,9 @@ if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
 from benchlib.spec import load_module  # noqa: E402
+
+from tests import _programs  # noqa: E402
+from tests._programs import programs  # noqa: E402
 
 ref = load_module("reference", "sdar_f32")
 faults = load_module("reference", "sdar_faults")
@@ -110,9 +114,32 @@ def noisy_batch(cfg, seed, length, rows=2):
                              "noise_key": jnp.asarray(keys)}, cfg), keys
 
 
+@functools.lru_cache(maxsize=None)
+def _system_logits(cfg):
+    return jax.jit(lambda params, batch: head.logits(
+        params, Transformer.block_diffusion_hidden(params, batch, cfg)[0],
+        cfg))
+
+
 def system_logits(params, batch, cfg):
-    return head.logits(params, Transformer.block_diffusion_hidden(
-        params, batch, cfg)[0], cfg)
+    """The logits at the L noised positions of the 2L stream: one
+    `jax.jit` a configuration, as `programs`' are."""
+    return _system_logits(cfg)(params, batch)
+
+
+def reference(cfg):
+    """The reference at `cfg`'s published keys under `jax.jit`
+    (`tests/_programs.reference`): `.forward(w, noised, clean)` -> (logits,
+    chosen), attention in query blocks of 64."""
+    return _programs.reference(ref, published, cfg, with_routing=True,
+                               query_block=64)
+
+
+@functools.lru_cache(maxsize=None)
+def reference_plain(cfg):
+    """`ref.forward_plain(w, tokens)` at `cfg`, one program a length."""
+    pub = published(cfg)
+    return jax.jit(lambda w, tokens: ref.forward_plain(w, tokens, pub))
 
 
 def assert_close(got, want, what, rtol=RTOL):
@@ -138,13 +165,12 @@ def test_logits_and_loss_match_the_reference(share, shape):
         params = share_of(params, held, offset)
     batch, _ = noisy_batch(cfg, 3, length)
     logits = system_logits(params, batch, cfg)
-    loss, metrics = Transformer.loss(params, batch, cfg, with_metrics=True)
+    loss, metrics = programs(cfg).loss(params, batch)
     w = job.to_reference_layout(params, cfg)
     assert sorted(w["layers"][1]["experts"]) == list(
         range(offset, offset + cfg.held_experts))
-    ref_logits, chosen = ref.forward(
-        w, batch["tokens"], batch["targets"], published(cfg),
-        with_routing=True, query_block=64)
+    ref_logits, chosen = reference(cfg).forward(
+        w, batch["tokens"], batch["targets"])
     assert logits.shape == (2, length, VOCAB)
     assert_close(logits, ref_logits, "logits")
     assert_close(loss, ref.masked_diffusion_loss(
@@ -207,10 +233,10 @@ def test_gradients_match_jax_grad_of_the_reference(share):
     if held:
         params = share_of(params, held, offset)
     batch, _ = noisy_batch(cfg, 1, 64)
-    grads = jax.grad(lambda p: Transformer.loss(p, batch, cfg))(params)
+    _, grads = programs(cfg).grads(params, batch)
     w = job.to_reference_layout(params, cfg)
-    _, ref_grads = ref.loss_and_grads(
-        w, batch["tokens"], batch["targets"], batch["mask"], published(cfg))
+    _, ref_grads = reference(cfg).loss_and_grads(
+        w, batch["tokens"], batch["targets"], batch["mask"])
     want = from_reference_layout(ref_grads, cfg)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     assert jax.tree.structure(grads) == jax.tree.structure(want)
@@ -242,9 +268,9 @@ def test_the_doubled_stream_is_the_objective_block_by_block(block, length,
         lo, hi = b * block, (b + 1) * block
         plain = jnp.concatenate([batch["targets"][:, :lo],
                                  batch["tokens"][:, lo:hi]], axis=1)
-        assert_close(Transformer.apply(params, plain, cfg)[:, lo:],
+        assert_close(programs(cfg).logits(params, plain)[:, lo:],
                      stream[:, lo:hi], f"block {b} by the program")
-        assert_close(ref.forward_plain(w, plain, published(cfg))[:, lo:],
+        assert_close(reference_plain(cfg)(w, plain)[:, lo:],
                      stream[:, lo:hi], f"block {b} by the reference")
 
 
@@ -475,7 +501,7 @@ def fault_rows():
     diff = np.asarray(system_logits(params, batch, cfg) - base, np.float64)
     own = float(np.sqrt((diff ** 2).sum()
                         / (np.asarray(base, np.float64) ** 2).sum()))
-    own_loss = abs(float(Transformer.loss(params, batch, cfg))
+    own_loss = abs(float(programs(cfg).loss(params, batch)[0])
                    - float(ref.masked_diffusion_loss(
                        base, batch["targets"], batch["mask"])))
     return rows, (own, own_loss), job.loss_weight_norm(batch["mask"])

@@ -9,6 +9,7 @@ kernels is `tests/test_chip_compile.py`'s."""
 import functools
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,8 @@ if BENCH_DIR not in sys.path:
 
 from benchlib.spec import load_module  # noqa: E402
 
+from tests._programs import value_and_grads  # noqa: E402
+
 ref = load_module("reference", "phi4flash_f32")
 
 RTOL = 1e-4                   # tests/test_phi4flash_reference.py's
@@ -32,10 +35,17 @@ N = 16
 CHUNK = 64                    # what T must divide by, no tiling
 # [T, channels], tiled as on the chip (`scan1_channel_block`,
 # `_scan1_group`): one lane tile; three time blocks and three channel
-# blocks of 896, each walked in seven groups of a tile; the cell's two
-# blocks of 2,560 in four groups of 640; a group of three lane tiles, and
-# of two
-SHAPES = [(128, 128), (384, 2688), (256, 5120), (128, 384), (256, 256)]
+# blocks of 896, each walked in seven groups of a tile (2,688 is the least
+# channel count above `SCAN1_CHANNELS`, so the least with several blocks);
+# two blocks of 1,536 in three groups of four tiles (two blocks against
+# three, a group of several tiles walked several times; the cell's own
+# 5,120, blocks and groups at `SCAN1_CHANNELS` and `SCAN1_GROUP`, is
+# compiled for the v5e at the cell's size by
+# `tests/test_chip_compile.py::test_mamba1_scan_kernels_fwd_bwd`); a group
+# of three lane tiles, and of two. Interpreted, a kernel costs by the
+# channel: what needs no second channel block (a batch above one,
+# bfloat16 operands) runs at the small shapes
+SHAPES = [(128, 128), (384, 2688), (256, 3072), (128, 384), (256, 256)]
 IDS = ["t{}-c{}".format(*shape) for shape in SHAPES]
 
 
@@ -65,15 +75,26 @@ def xla(x, dt, a, b, c, d):
         + x.astype(jnp.float32) * d
 
 
+@functools.lru_cache(maxsize=None)
 def kernel(chunk=CHUNK):
     """`selective_scan_pallas` in interpret mode."""
     return jax.jit(lambda *v: ssm.selective_scan_pallas(
         *v, chunk, interpret=True))
 
 
-def grads_of(fn, args, probe):
-    return jax.grad(lambda *v: jnp.sum(fn(*v) * probe),
-                    argnums=range(6))(*args)
+@functools.lru_cache(maxsize=None)
+def case(shape, seed, dtype=jnp.float32, batch=1):
+    """The inputs at (shape, seed, dtype, batch) and what the kernel, the
+    XLA path and the float32 recurrence give at them, outputs and
+    gradients: computed once, read by the forward, the backward, the
+    batch's and the bfloat16 tests."""
+    args, probe = scan_inputs(seed, *shape, dtype, batch)
+    y, grads = value_and_grads(kernel())(probe, *args)
+    xla_y, xla_grads = value_and_grads(xla)(probe, *args)
+    want_y, want_grads = value_and_grads(recurrence)(probe, *args)
+    return types.SimpleNamespace(
+        args=args, probe=probe, y=y, grads=grads, xla_y=xla_y,
+        xla_grads=xla_grads, want_y=want_y, want_grads=want_grads)
 
 
 def assert_close(got, want, what, rtol=RTOL):
@@ -84,13 +105,16 @@ def assert_close(got, want, what, rtol=RTOL):
         what, float(np.abs(got - want).max()), float(scale))
 
 
-def assert_grads_close(scan, args, probe):
-    """The six gradients of `scan` against the XLA path's and the float32
+def assert_forward_close(at):
+    assert at.y.dtype == jnp.float32 and at.y.shape == at.args[0].shape
+    assert_close(at.y, at.xla_y, "y against selective_scan")
+    assert_close(at.y, at.want_y, "y against the recurrence")
+
+
+def assert_grads_close(at):
+    """The kernel's six gradients against the XLA path's and the float32
     recurrence's, shapes and dtypes the XLA path's."""
-    got = grads_of(scan, args, probe)
-    other = grads_of(xla, args, probe)
-    want = grads_of(recurrence, args, probe)
-    for name, g, o, w in zip(GRADS, got, other, want):
+    for name, g, o, w in zip(GRADS, at.grads, at.xla_grads, at.want_grads):
         assert g.shape == o.shape and g.dtype == o.dtype, name
         assert_close(g, o, f"d{name} against selective_scan")
         assert_close(g, w, f"d{name} against the recurrence")
@@ -99,39 +123,29 @@ def assert_grads_close(scan, args, probe):
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("shape", SHAPES, ids=IDS)
 def test_kernel_forward_is_the_xla_scan_and_the_recurrence(shape, seed):
-    t, ch = shape
-    args, _ = scan_inputs(seed, t, ch)
-    y = kernel()(*args)
-    assert y.dtype == jnp.float32 and y.shape == args[0].shape
-    assert_close(y, xla(*args), "y against selective_scan")
-    assert_close(y, recurrence(*args), "y against the recurrence")
+    assert_forward_close(case(shape, seed))
 
 
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("shape", SHAPES, ids=IDS)
 def test_kernel_backward_is_the_xla_scans_and_the_recurrences(shape, seed):
-    t, ch = shape
-    args, probe = scan_inputs(seed, t, ch)
-    assert_grads_close(kernel(), args, probe)
+    assert_grads_close(case(shape, seed))
 
 
-@pytest.mark.parametrize("shape,batch", [(SHAPES[1], 3), (SHAPES[4], 2)],
-                         ids=[IDS[1] + "-b3", IDS[4] + "-b2"])
+@pytest.mark.parametrize("shape,batch", [(SHAPES[3], 3), (SHAPES[4], 2)],
+                         ids=[IDS[3] + "-b3", IDS[4] + "-b2"])
 def test_kernel_with_a_batch_above_one(shape, batch):
     """Every row of a batch starts from a zero state and keeps its own:
     the state's scratch is set to zero at each row's first time block, and
     a and D (the operands without a batch axis) gather their gradients
-    over the rows."""
-    t, ch = shape
-    args, probe = scan_inputs(batch, t, ch, batch=batch)
-    scan = kernel()
-    y = scan(*args)
-    assert_close(y, xla(*args), "y against selective_scan")
-    assert_close(y, recurrence(*args), "y against the recurrence")
+    over the rows. (Two rows over three channel blocks:
+    `test_the_forwards_residual_is_the_state_entering_each_block`.)"""
+    at = case(shape, batch, batch=batch)
+    assert_forward_close(at)
     # a row alone gives what it gives in the batch
-    alone = scan(*(v[-1:] if v.ndim == 3 else v for v in args))
-    assert_close(alone, y[-1:], "the last row alone", 1e-6)
-    assert_grads_close(scan, args, probe)
+    alone = kernel()(*(v[-1:] if v.ndim == 3 else v for v in at.args))
+    assert_close(alone, at.y[-1:], "the last row alone", 1e-6)
+    assert_grads_close(at)
 
 
 def states_entering(x, dt, a, b, every):
@@ -190,25 +204,14 @@ BF16_RTOL = {"y": 1e-2, "x": 2e-2, "dt": 2e-2, "a": 2e-2, "b": 2e-2,
 
 
 @pytest.mark.parametrize("seed", [0, 1])
-@pytest.mark.parametrize("shape", [SHAPES[1], SHAPES[4]],
-                         ids=[IDS[1], IDS[4]])
+@pytest.mark.parametrize("shape", [SHAPES[3], SHAPES[4]],
+                         ids=[IDS[3], IDS[4]])
 def test_bf16_operands_are_held_to_the_f32_recurrence(shape, seed):
-    t, ch = shape
-    args, probe = scan_inputs(seed, t, ch, jnp.bfloat16)
-    scan = kernel()
-    y = scan(*args)
-    want_y = recurrence(*args)
-    assert y.dtype == jnp.bfloat16          # rounded once, in the kernel
-    assert_close(y, want_y, "y", BF16_RTOL["y"])
-    assert_close(xla(*args), want_y, "selective_scan's y", BF16_RTOL["y"])
-
-    def widened(*v):
-        return scan(*v).astype(jnp.float32)
-
-    got = grads_of(widened, args, probe)
-    other = grads_of(xla, args, probe)
-    want = grads_of(recurrence, args, probe)
-    for name, g, o, w in zip(GRADS, got, other, want):
+    at = case(shape, seed, jnp.bfloat16)
+    assert at.y.dtype == jnp.bfloat16       # rounded once, in the kernel
+    assert_close(at.y, at.want_y, "y", BF16_RTOL["y"])
+    assert_close(at.xla_y, at.want_y, "selective_scan's y", BF16_RTOL["y"])
+    for name, g, o, w in zip(GRADS, at.grads, at.xla_grads, at.want_grads):
         assert g.shape == o.shape and g.dtype == o.dtype, name
         assert_close(g, w, "d" + name, BF16_RTOL[name])
         assert_close(o, w, "selective_scan's d" + name, BF16_RTOL[name])
@@ -220,14 +223,12 @@ def test_a_long_decay_does_not_overflow():
     inf x 0 a NaN in the value or a gradient."""
     (x, dt, a, b, c, d), probe = scan_inputs(3, 256, 128)
     dt, a = dt * 0 + 5.0, a * 0 - 8.0
-    scan = kernel()
-    y = scan(x, dt, a, b, c, d)
-    grads = grads_of(scan, (x, dt, a, b, c, d), probe)
+    y, grads = value_and_grads(kernel())(probe, x, dt, a, b, c, d)
     assert np.isfinite(np.asarray(y)).all()
     assert all(np.isfinite(np.asarray(g)).all() for g in grads)
-    assert_close(y, recurrence(x, dt, a, b, c, d), "y")
-    assert_close(grads[0], grads_of(recurrence, (x, dt, a, b, c, d),
-                                    probe)[0], "dx")
+    want_y, want = value_and_grads(recurrence)(probe, x, dt, a, b, c, d)
+    assert_close(y, want_y, "y")
+    assert_close(grads[0], want[0], "dx")
 
 
 @pytest.mark.parametrize("t,chunk", [(192, 64), (256, 96)],

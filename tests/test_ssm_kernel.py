@@ -5,8 +5,10 @@ interpret mode on the CPU: forward and all five gradients against
 between the two (`ssd_scan_impl`). What the chip's compiler makes of the
 kernels is `tests/test_chip_compile.py`'s."""
 
+import functools
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -22,17 +24,22 @@ if BENCH_DIR not in sys.path:
 
 from benchlib.spec import load_module  # noqa: E402
 
+from tests._programs import value_and_grads  # noqa: E402
+
 ref = load_module("reference", "nemotron_h_f32")
 
 GRADS = ("x", "dt", "a", "b", "c")
 # [chunk, heads a group, groups, chunks, head width, head block]: every
-# chunk, group size, group count and length of the issue's list, a head
-# that is a whole lane tile, and a group in two head blocks (whose parts
-# of dB and dC are summed outside the kernel). The head block is given
-# where `scan_head_block` has none for the chip (4 heads a group)
+# chunk, group size and group count of the issue's list, a state carried
+# over one chunk's end and over two, a head that is a whole lane tile, and
+# a group in two head blocks (whose parts of dB and dC are summed outside
+# the kernel), each at the smallest size that has it: interpreted, a kernel
+# costs by the step. The head block is given where `scan_head_block` has
+# none for the chip (4 heads a group). The cell's own size is compiled for
+# the v5e by `tests/test_chip_compile.py::test_scan_kernels_fwd_bwd`
 SHAPES = [(64, 4, 1, 2, 64, 4), (64, 16, 2, 2, 64, None),
-          (64, 4, 2, 8, 64, 4), (128, 4, 2, 2, 64, 4),
-          (128, 16, 1, 2, 64, None), (128, 4, 1, 8, 64, 4),
+          (64, 4, 2, 3, 64, 4), (128, 4, 2, 2, 64, 4),
+          (128, 16, 1, 2, 64, None), (128, 4, 1, 3, 64, 4),
           (128, 4, 1, 2, 128, 4), (128, 4, 2, 2, 64, 2)]
 IDS = ["q{}-r{}-g{}-c{}-p{}-hb{}".format(*shape) for shape in SHAPES]
 
@@ -60,6 +67,7 @@ def recurrence(x, dt, a, b, c):
             jnp.repeat(c.astype(f32), rep, axis=2), jnp.zeros(x.shape[2]))
 
 
+@functools.lru_cache(maxsize=None)
 def kernel(chunk, head_block=None):
     """`ssd_scan_pallas` in interpret mode behind `ssd_scan`'s shapes: it
     takes and gives the convolution's layouts, `[B, T, H·P]` and
@@ -72,18 +80,38 @@ def kernel(chunk, head_block=None):
         head_block=head_block, interpret=True).reshape(x.shape))
 
 
-def grads_of(fn, args, probe):
-    return jax.grad(lambda *v: jnp.sum(fn(*v) * probe),
-                    argnums=(0, 1, 2, 3, 4))(*args)
+@functools.lru_cache(maxsize=None)
+def xla_scan(chunk):
+    return jax.jit(lambda *v: ssm.ssd_scan(*v, chunk))
 
 
-def assert_grads_close(scan, args, probe, chunk):
-    """The five gradients of `scan` against `ssd_scan`'s and the float32
+@functools.lru_cache(maxsize=None)
+def case(shape, seed, dtype=jnp.float32, batch=1):
+    """The inputs at (shape, seed, dtype, batch) and what the kernel, the
+    XLA path and the float32 recurrence give at them, outputs and
+    gradients: computed once, read by the forward, the backward, the
+    batch's and the bfloat16 tests."""
+    chunk, per_group, groups, chunks, p, head_block = shape
+    args, probe = scan_inputs(seed, chunk, per_group, groups, chunks, p,
+                              dtype, batch)
+    y, grads = value_and_grads(kernel(chunk, head_block))(probe, *args)
+    xla_y, xla_grads = value_and_grads(xla_scan(chunk))(probe, *args)
+    want_y, want_grads = value_and_grads(recurrence)(probe, *args)
+    return types.SimpleNamespace(
+        args=args, probe=probe, y=y, grads=grads, xla_y=xla_y,
+        xla_grads=xla_grads, want_y=want_y, want_grads=want_grads)
+
+
+def assert_forward_close(at):
+    assert at.y.dtype == jnp.float32 and at.y.shape == at.args[0].shape
+    assert_close(at.y, at.xla_y, "y against ssd_scan", 1e-5)
+    assert_close(at.y, at.want_y, "y against the recurrence", 1e-4)
+
+
+def assert_grads_close(at):
+    """The kernel's five gradients against `ssd_scan`'s and the float32
     recurrence's, shapes and dtypes `ssd_scan`'s."""
-    got = grads_of(scan, args, probe)
-    xla = grads_of(lambda *v: ssm.ssd_scan(*v, chunk), args, probe)
-    want = grads_of(recurrence, args, probe)
-    for name, g, o, w in zip(GRADS, got, xla, want):
+    for name, g, o, w in zip(GRADS, at.grads, at.xla_grads, at.want_grads):
         assert g.shape == o.shape and g.dtype == o.dtype, name
         assert_close(g, o, f"d{name} against ssd_scan", 1e-4)
         assert_close(g, w, f"d{name} against the recurrence", 2e-4)
@@ -99,20 +127,13 @@ def assert_close(got, want, what, rtol):
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("shape", SHAPES, ids=IDS)
 def test_kernel_forward_is_the_xla_scan_and_the_recurrence(shape, seed):
-    chunk, per_group, groups, chunks, p, head_block = shape
-    args, _ = scan_inputs(seed, chunk, per_group, groups, chunks, p)
-    y = kernel(chunk, head_block)(*args)
-    assert y.dtype == jnp.float32 and y.shape == args[0].shape
-    assert_close(y, ssm.ssd_scan(*args, chunk), "y against ssd_scan", 1e-5)
-    assert_close(y, recurrence(*args), "y against the recurrence", 1e-4)
+    assert_forward_close(case(shape, seed))
 
 
 @pytest.mark.parametrize("seed", [0, 1])
 @pytest.mark.parametrize("shape", SHAPES, ids=IDS)
 def test_kernel_backward_is_the_xla_scans_and_the_recurrences(shape, seed):
-    chunk, per_group, groups, chunks, p, head_block = shape
-    args, probe = scan_inputs(seed, chunk, per_group, groups, chunks, p)
-    assert_grads_close(kernel(chunk, head_block), args, probe, chunk)
+    assert_grads_close(case(shape, seed))
 
 
 @pytest.mark.parametrize("shape,batch", [(SHAPES[0], 3), (SHAPES[7], 2)],
@@ -122,18 +143,13 @@ def test_kernel_with_a_batch_above_one(shape, batch):
     the state's scratch is set to zero at each row's first chunk, and a
     (the one operand without a batch axis) gathers its gradient over the
     rows."""
-    chunk, per_group, groups, chunks, p, head_block = shape
-    args, probe = scan_inputs(batch, chunk, per_group, groups, chunks, p,
-                              batch=batch)
-    scan = kernel(chunk, head_block)
-    y = scan(*args)
-    assert y.shape == args[0].shape
-    assert_close(y, ssm.ssd_scan(*args, chunk), "y against ssd_scan", 1e-5)
-    assert_close(y, recurrence(*args), "y against the recurrence", 1e-4)
+    at = case(shape, batch, batch=batch)
+    assert_forward_close(at)
     # a row alone gives what it gives in the batch
-    alone = scan(*(v[-1:] if v.ndim > 1 else v for v in args))
-    assert_close(alone, y[-1:], "the last row alone", 1e-6)
-    assert_grads_close(scan, args, probe, chunk)
+    alone = kernel(shape[0], shape[5])(
+        *(v[-1:] if v.ndim > 1 else v for v in at.args))
+    assert_close(alone, at.y[-1:], "the last row alone", 1e-6)
+    assert_grads_close(at)
 
 
 # bfloat16 operands, float32 decays and accumulation: the two paths round
@@ -148,19 +164,11 @@ BF16_RTOL = {"y": 1e-2, "x": 2e-2, "dt": 2e-2, "a": 2e-2, "b": 2e-2,
 @pytest.mark.parametrize("shape", [SHAPES[1], SHAPES[3], SHAPES[7]],
                          ids=[IDS[1], IDS[3], IDS[7]])
 def test_bf16_operands_are_held_to_the_f32_recurrence(shape, seed):
-    chunk, per_group, groups, chunks, p, head_block = shape
-    args, probe = scan_inputs(seed, chunk, per_group, groups, chunks, p,
-                              jnp.bfloat16)
-    y = kernel(chunk, head_block)(*args)
-    want_y = recurrence(*args)
-    assert y.dtype == jnp.float32
-    assert_close(y, want_y, "y", BF16_RTOL["y"])
-    assert_close(ssm.ssd_scan(*args, chunk), want_y, "ssd_scan's y",
-                 BF16_RTOL["y"])
-    got = grads_of(kernel(chunk, head_block), args, probe)
-    xla = grads_of(lambda *v: ssm.ssd_scan(*v, chunk), args, probe)
-    want = grads_of(recurrence, args, probe)
-    for name, g, o, w in zip(GRADS, got, xla, want):
+    at = case(shape, seed, jnp.bfloat16)
+    assert at.y.dtype == jnp.float32
+    assert_close(at.y, at.want_y, "y", BF16_RTOL["y"])
+    assert_close(at.xla_y, at.want_y, "ssd_scan's y", BF16_RTOL["y"])
+    for name, g, o, w in zip(GRADS, at.grads, at.xla_grads, at.want_grads):
         assert g.shape == o.shape and g.dtype == o.dtype, name
         assert_close(g, w, "d" + name, BF16_RTOL[name])
         assert_close(o, w, "ssd_scan's d" + name, BF16_RTOL[name])
@@ -172,14 +180,12 @@ def test_a_long_decay_does_not_overflow_above_the_diagonal():
     (`tests/test_nemotron_h_reference.py` holds the XLA path to it)."""
     (x, dt, a, b, c), probe = scan_inputs(3, 64, 4, 2, 2, 64)
     dt, a = dt * 0 + 5.0, a * 0 - 8.0
-    scan = kernel(64, 4)
-    y = scan(x, dt, a, b, c)
-    grads = grads_of(scan, (x, dt, a, b, c), probe)
+    y, grads = value_and_grads(kernel(64, 4))(probe, x, dt, a, b, c)
     assert np.isfinite(np.asarray(y)).all()
     assert all(np.isfinite(np.asarray(g)).all() for g in grads)
-    assert_close(y, recurrence(x, dt, a, b, c), "y", 1e-4)
-    assert_close(grads[0], grads_of(recurrence, (x, dt, a, b, c), probe)[0],
-                 "dx", 2e-4)
+    want_y, want = value_and_grads(recurrence)(probe, x, dt, a, b, c)
+    assert_close(y, want_y, "y", 1e-4)
+    assert_close(grads[0], want[0], "dx", 2e-4)
 
 
 def test_a_length_that_is_no_whole_chunks_is_refused_not_padded():
