@@ -72,7 +72,9 @@ time (no option):
   with `ragged_dot` for its grouped matmuls: nothing is ever dropped, and
   `rows_bounded` in the routing record says which ran. Where every
   expert is held or B would pass half the rows no `cond` is traced.
-  The kernel's tiles follow from each call's shapes (`gmm_tiles`). Both
+  The kernel's tiles follow from each call's shapes (`gmm_tiles`: for
+  the contraction and for the columns the largest multiple of 128 that
+  divides the width, up to what the chip's VMEM takes, `GMM_WIDEST`). Both
   permutations are gathers in the backward pass too (`_permutes`: a
   permutation's transpose is its inverse), so no row is scattered.
 - **sorted, dropless, exchanged** (an `expert` mesh axis above 1): the
@@ -484,13 +486,22 @@ def _by_token(order, inverse, m: int, k: int):
             slots, start)
 
 
-# megablox tiles (rows, contraction, columns) of the grouped matmul. Rows
-# and the widest tile are the fastest of those tried on the v5e that fit
-# its VMEM (PERF.md section 6, PR 27); a call whose contraction or columns
-# 1024 does not divide (an expert width of 1536) takes the largest
-# multiple of 128 below it that does.
+# megablox tiles (rows, contraction, columns) of the grouped matmul. The
+# row tile is the one `row_bound` and `exchange_bound` round to. The other
+# two are, each for its own width, the LARGEST multiple of 128 up to
+# `GMM_WIDEST` that divides it, whatever the width: 1,024 for 2,048 and
+# 3,072, 768 for 1,536, 1,152 for 2,304, 896 for an expert width of
+# 7 x 128 (896, 1,792, 2,688), 640 for 2,560: on the v5e a wider tile in
+# the place of a narrower one was the faster in every call timed (PERF.md
+# section 6, PRs 27 and 62). `GMM_WIDEST` is what that chip's VMEM takes:
+# megablox hands one look-up to `gmm`, its transpose and `tgmm` alike, the
+# compiler gives a kernel's scope 16 MiB, and `tgmm` needs most (its output
+# and accumulator are `[tk, tn]`): in bf16, two buffers of each operand and
+# of the output and one f32 accumulator are 4,096 t + 8 t^2 bytes at
+# (512, t, t), 14.6 MiB at 1,152, which the compiler takes in all three
+# kernels with their temporaries, and 17.5 MiB at 1,280, which it refuses.
 GMM_ROWS = 512
-GMM_TILES = (1024, 768, 512, 384, 256, 128)
+GMM_WIDEST = 1152
 
 
 def gmm_tiles(m: int, k: int, n: int):
@@ -498,7 +509,8 @@ def gmm_tiles(m: int, k: int, n: int):
     forward and its two transposes each look theirs up), None where no
     tile divides."""
     def tile(width: int):
-        return next((t for t in GMM_TILES if width % t == 0), None)
+        return max((t for t in range(128, min(width, GMM_WIDEST) + 1, 128)
+                    if width % t == 0), default=None)
 
     tk, tn = tile(k), tile(n)
     return (GMM_ROWS, tk, tn) if m % GMM_ROWS == 0 and tk and tn else None

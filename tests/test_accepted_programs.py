@@ -10,7 +10,8 @@ and the pallas kernels' bodies blanked, hashed and held to the hash the
 PARENT's tree gave for the same cell (`.bench_scratch/program_text.py`,
 PRs 35, 50, 53; the nine cells 34,539 / 3,117 / 8,394 / 19,774 / 12,926 /
 18,945 / 10,853 / 4,821 / 12,139 lines before PR 60, Ling 36,382, Nemotron
-21,311, GLM 13,387 and SDAR 11,410 since). Ling's first: it shares the delta
+21,311, GLM 13,387 and SDAR 11,410 since; PR 62's tiles: Ling 36,409,
+Nemotron 21,301). Ling's first: it shares the delta
 rule, the convolution and the head norm with the new model. Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
@@ -50,12 +51,18 @@ MADE_WITH = {"jax": "0.9.0", "libtpu": "0.0.34"}
 # were) unless said. PR 60 changed the four share cells' steps (the sums
 # that come back from a bounded run of expert rows, `ops/moe._by_token`)
 # and made their pins anew from its own tree; the other six are PR 59's
-# programs and held PR 60's tree to them
+# programs and held PR 60's tree to them. PR 62 (`ops/moe.gmm_tiles`: a
+# tile of 896 for a width of 2,688, of 640 for 2,560) made Nemotron's and
+# Ling's anew from its own tree; Mellum2's tiles
+# changed too (896 for 896 and 1,792, 1,152 for 2,304), but they live in the kernels'
+# bodies, which `blank` blanks: its text is PR 58's line for line, and its
+# tiles are pinned in `tests/test_chip_compile_ep_moe.py`; OLMoE's, GLM's
+# and SDAR's tiles and texts are as they were
 PINS = {
-    "train_ling3flash_ep64_d7": "0ddf0c554c6b0499",          # PR 60
+    "train_ling3flash_ep64_d7": "eee8e09273ef454d",          # PR 62
     "train_mistral7b_d2": "dc53d3934bbf1705",
     "train_olmoe_d1": "be5709d03a09969b",
-    "train_nemotron3super_ep64_d11": "7c8cd0f49368c728",     # PR 60
+    "train_nemotron3super_ep64_d11": "9c13b178970bd1b8",     # PR 62
     "train_glm47flash_ep8_d5": "a536c17835e12988",           # PR 60
     "train_phi4miniflash_d6": "a8abf884315bc039",
     "train_sdar30b_ep8_d4": "7cc6dbdd7647da51",              # PR 60

@@ -71,7 +71,8 @@ def test_expert_parallel_step_compiles_and_fits_the_v5e_host(v5e_host):
     assert Transformer.resolve_attention_impl(cfg, mesh, seq) == "flash"
     bucket = exchange_bound(seq * 8, 4)
     assert bucket == 32768
-    assert gmm_tiles(4 * bucket, 2304, 2 * 896) == (512, 768, 256)
+    assert gmm_tiles(4 * bucket, 2304, 2 * 896) == (512, 1152, 896)
+    assert gmm_tiles(4 * bucket, 896, 2304) == (512, 896, 1152)
     optimizer = optax.adamw(1e-5, weight_decay=0.01)
     _, train_step = make_train_step(
         lambda p, b: Transformer.loss(p, b, cfg, mesh=mesh, rules=rules,

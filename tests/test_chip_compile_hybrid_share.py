@@ -27,7 +27,7 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     kernel in the forward and in remat's forward, the backward kernel in
     the backward, and no `[T/Q, H, Q, Q]` block left there), splash's
     kernels at GQA 8 / 1, `megablox` with tiles from each call's shapes
-    (2688 = 7 x 384), past the sort tokens x min(22, 8) rows and never
+    (2688 = 3 x 896), past the sort tokens x min(22, 8) rows and never
     tokens x 22, and the new scopes on what the compiler leaves."""
     import re
 
@@ -55,8 +55,8 @@ def test_hybrid_share_step_compiles_for_the_v5e(v5e):
     mesh = make_mesh(MeshConfig(data=-1), devices=[v5e])
     assert Transformer.resolve_attention_impl(cfg, mesh, seq) == "flash"
     held_rows = rows * seq * 8
-    assert gmm_tiles(held_rows, 1024, 2688) == (512, 1024, 384)
-    assert gmm_tiles(held_rows, 2688, 1024) == (512, 384, 1024)
+    assert gmm_tiles(held_rows, 1024, 2688) == (512, 1024, 896)
+    assert gmm_tiles(held_rows, 2688, 1024) == (512, 896, 1024)
     assert grouped_matmul_impl(mesh, held_rows, cfg.moe_latent, cfg.ff_dim,
                                gated=False) == "megablox"
     scan_shape = (seq, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,

@@ -64,8 +64,8 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     assert _splash_block_sizes(seq, 256).block_kv == 512     # GLM's
     bound = row_bound(seq, 8, 8, 512, seq * 8)
     assert bound == 4096
-    assert gmm_tiles(bound, 2560, 2 * 768) == (512, 512, 768)
-    assert gmm_tiles(bound, 768, 2560) == (512, 768, 512)
+    assert gmm_tiles(bound, 2560, 2 * 768) == (512, 640, 768)
+    assert gmm_tiles(bound, 768, 2560) == (512, 768, 640)
     assert grouped_matmul_impl(mesh, bound, 2560, 768) == "megablox"
     assert kda_delta_impl(mesh, seq, 8, 128, 128, cfg.kda_chunk) == "pallas"
     optimizer = optax.adamw(3e-7, weight_decay=0.01)

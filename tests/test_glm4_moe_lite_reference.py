@@ -15,6 +15,7 @@ has a case below that fails without it.
 """
 
 import functools
+import json
 import os
 import sys
 
@@ -507,16 +508,68 @@ def test_grouped_matmul_impl_by_shape(shape, want):
     assert moe.grouped_matmul_impl(None, *shape) == "ragged_dot"  # the CPU
 
 
-def test_gmm_tiles_follow_each_calls_shapes():
+# the two matmuls of the six expert configurations, their widths read from
+# the benchmark's files: (the rows' width, the expert width, gated)
+EXPERT_WIDTHS = {
+    "olmoe-1b-7b-0125-d1": ("hidden_size", "intermediate_size", True),
+    "glm-4.7-flash-ep8-d5": ("hidden_size", "moe_intermediate_size", True),
+    "nemotron-3-super-ep64-tp4-d11": (
+        "moe_latent_size", "moe_intermediate_size", False),
+    "ling-3.0-flash-ep64-tp4-d7": (
+        "hidden_size", "moe_intermediate_size", True),
+    "sdar-30b-a3b-chat-ep8-d4": ("hidden_size", "moe_intermediate_size", True),
+    "mellum2-12b-a2.5b-ep4-d4": ("hidden_size", "moe_intermediate_size", True),
+}
+
+
+def _expert_calls():
+    for name, (rows, expert, gated) in EXPERT_WIDTHS.items():
+        with open(os.path.join(BENCH_DIR, "configs", name + ".json")) as f:
+            published = json.load(f)
+        d, width = published[rows], published[expert]
+        yield pytest.param(65536, d, (1 + gated) * width, ..., id=name + "-first")
+        yield pytest.param(65536, width, d, ..., id=name + "-down")
+
+
+@pytest.mark.parametrize("m, k, n, want", [
     # OLMoE keeps the tiles it had, in every call
-    for k, n in ((2048, 2048), (1024, 2048), (2048, 1024)):
-        assert moe.gmm_tiles(131072, k, n) == (512, 1024, 1024)
+    (131072, 2048, 2048, (512, 1024, 1024)),
+    (131072, 1024, 2048, (512, 1024, 1024)),
+    (131072, 2048, 1024, (512, 1024, 1024)),
     # width 1536: the largest multiple of 128 that divides
-    assert moe.gmm_tiles(65536, 2048, 3072) == (512, 1024, 1024)
-    assert moe.gmm_tiles(65536, 1536, 2048) == (512, 768, 1024)
-    assert moe.gmm_tiles(65536, 2048, 1536) == (512, 1024, 768)
-    assert moe.gmm_tiles(65536 + 8, 2048, 1536) is None
-    assert moe.gmm_tiles(65536, 2048, 1000) is None
+    (65536, 2048, 3072, (512, 1024, 1024)),
+    (65536, 1536, 2048, (512, 768, 1024)),
+    (65536, 2048, 1536, (512, 1024, 768)),
+    (65536 + 8, 2048, 1536, None),
+    (65536, 2048, 1000, None),
+    # PR 62, widths of 7 x 128, 9 x 128 and 5 x 128: Mellum2's four
+    # look-ups, Nemotron's latent experts, Ling's stream
+    (131072, 2304, 1792, (512, 1152, 896)),
+    (131072, 896, 2304, (512, 896, 1152)),
+    (131072, 1792, 2304, (512, 896, 1152)),
+    (131072, 2304, 896, (512, 1152, 896)),
+    (5632, 1024, 2688, (512, 1024, 896)),
+    (4096, 2560, 1536, (512, 640, 768)),
+    *_expert_calls(),
+])
+def test_gmm_tiles_follow_each_calls_shapes(m, k, n, want):
+    """A call's contraction and column tiles are the largest multiple of
+    128 up to `GMM_WIDEST` that divides its width, whatever the width; a
+    pinned case holds the tiles themselves, `...` the rule alone."""
+    got = moe.gmm_tiles(m, k, n)
+    if want is not ...:
+        assert got == want
+    if got is None:
+        return
+    assert got[0] == moe.GMM_ROWS and m % got[0] == 0
+    for tile, width in zip(got[1:], (k, n)):
+        assert tile % 128 == 0 and width % tile == 0
+        assert tile <= moe.GMM_WIDEST
+        assert not any(width % t == 0 for t in range(
+            tile + 128, min(width, moe.GMM_WIDEST) + 1, 128))
+    assert moe.gmm_tiles(m + 8, k, n) is None
+    assert moe.gmm_tiles(m, k + 8, n) is None     # no multiple of 128 divides
+    assert moe.gmm_tiles(m, k, n + 8) is None
 
 
 # ---- config ----------------------------------------------------------------
