@@ -32,7 +32,13 @@ under a causal window of `attn_window` keys FOLLOWED by experts, beside
 full layers only. `d` is a Gated DeltaNet mixer (ops/kda.py: the delta
 rule behind one decay a head) and `a` softmax attention, each FOLLOWED by
 a dense MLP and each sublayer under OLMo-2/3's reordered norm,
-`x + norm(f(x))` with no norm before the sublayer.
+`x + norm(f(x))` with no norm before the sublayer. A looped stack
+(`loops` > 1; Ouro's `total_ut_steps`) passes the stream that many times
+through the SAME layers, the final norm closing every pass, with one head
+and (`exit_gate`) one exit gate after every pass and the loss an
+expectation over the pass a token leaves at (`Transformer.loss`); its
+layers stand under a sandwich norm, `x + norm(f(norm(x)))`
+(`norm_placement` "both": where the homogeneous stack's norms sit).
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -278,6 +284,24 @@ class TransformerConfig:
     gdn_conv_kernel: int = 4
     gdn_neg_eigval: bool = False
     gdn_chunk: int = 64
+    # Where the homogeneous stack's norms sit (a `layer_pattern`'s kinds
+    # say it themselves): "pre" `x + f(norm(x))`, the leaves
+    # `<name>_norm`; "post" `x + norm(f(x))`, the leaves
+    # `<name>_post_norm`; "both" the sandwich norm `x + norm(f(norm(x)))`,
+    # both leaves, four gains a layer.
+    norm_placement: str = "pre"
+    # A looped stack (arXiv 2510.25741; `total_ut_steps`): the stream
+    # passes `loops` times through the same layers, the final norm closes
+    # every pass and the next pass reads the normed stream; the position
+    # ids are the same in every pass. 1: the layers run once. With
+    # `exit_gate` a linear map of each pass's normed hidden state to one
+    # scalar (a gain of d_model and a bias: 2,049 parameters at 2,048)
+    # says with what probability a token leaves after that pass, and
+    # `Transformer.loss` is the expected next-token loss under that exit
+    # distribution less `exit_entropy_coeff` times its entropy.
+    loops: int = 1
+    exit_gate: bool = False
+    exit_entropy_coeff: float = 0.05
 
     def __post_init__(self):
         if self.norm not in ("rms", "layernorm"):
@@ -369,6 +393,22 @@ class TransformerConfig:
             raise ValueError(
                 "YaRN: rope_yarn_factor 1 or above (0: none), with "
                 "rope_yarn_original_len, on a model with RoPE")
+        if self.norm_placement not in ("pre", "post", "both") or (
+                self.norm_placement != "pre" and self.layer_pattern):
+            raise ValueError(
+                f"norm_placement {self.norm_placement!r}: pre, post or "
+                f"both, on the homogeneous stack (a layer_pattern's kinds "
+                f"say where their norms sit)")
+        if self.loops < 1 or (self.exit_gate and self.loops == 1):
+            raise ValueError("loops is 1 or above, and an exit gate is a "
+                             "looped stack's (loops above 1)")
+        if self.loops > 1 and (
+                self.layer_pattern or self.moe_experts or self.block_length
+                or self.attention_impl in ("ring", "ulysses")):
+            raise ValueError(
+                "a looped stack (loops above 1) is the homogeneous dense "
+                "layer under dense or flash attention: no layer_pattern, "
+                "no experts, no block diffusion, no ring or ulysses")
         if self.block_length and (
                 self.attention_impl in ("ring", "ulysses")
                 or self.layer_pattern or self.attn_window):
@@ -494,7 +534,7 @@ class TransformerConfig:
                 else nh * hd + nkv * hd
         if self.layer_pattern:
             return self._pattern_params(attn)
-        norms = 2 * d
+        norms = (4 if self.norm_placement == "both" else 2) * d
         mlp = 3 * d * f
         dense = 0
         if self.moe_experts:
@@ -506,7 +546,8 @@ class TransformerConfig:
                 attn + norms + 3 * d * (self.moe_dense_ff or f))
             l -= self.moe_dense_layers
         head = 0 if self.tie_embeddings else d * v
-        return v * d + dense + l * (attn + mlp + norms) + d + head
+        gate = d + 1 if self.exit_gate else 0
+        return v * d + dense + l * (attn + mlp + norms) + d + gate + head
 
     def _pattern_params(self, attn: int) -> int:
         """num_params of a hybrid: each sublayer with its one norm."""

@@ -57,9 +57,9 @@ def logits(params, x, cfg: TransformerConfig, *, mesh=None,
                     mesh, rules)
 
 
-def _token_nll_sum(head, x, targets, mask, cfg, seq_axis, mesh, rules):
-    """The (masked) token cross-entropy of x [b, t, d] against targets
-    [b, t], summed: f32 scalar."""
+def _token_nll(head, x, targets, cfg, seq_axis, mesh, rules):
+    """The token cross-entropy of x [b, t, d] against targets [b, t]:
+    f32 [b, t]."""
     import jax
     import jax.numpy as jnp
 
@@ -68,59 +68,104 @@ def _token_nll_sum(head, x, targets, mask, cfg, seq_axis, mesh, rules):
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
             logits, targets[..., None], axis=-1)[..., 0]
-        nll = logz - gold  # [b, t] f32
-        return jnp.sum(nll if mask is None else nll * mask)
+        return logz - gold
 
 
-def _chunked_nll_sum(chunk_nll_sum, head, x, targets, mask, chunk, unroll):
-    """Sum of `chunk_nll_sum(head, x_c, t_c, m_c)` over the `chunk`-token
-    slices of these sequences, so only one [b, chunk, vocab] f32 logits
-    block lives in HBM at a time. Each chunk's gradient is taken in that
-    same scan (`nll_sum_fwd`), while its logits exist: nothing is saved
-    for, or computed again in, the backward pass. A custom_vjp: reverse
-    mode only (nothing in the tree takes a forward-mode or a second
-    derivative of the loss)."""
+def _token_nll_sum(head, x, targets, mask, weights, cfg, seq_axis, mesh,
+                   rules):
+    """`_token_nll` summed under `mask` and `weights` (each [b, t] or
+    None): the f32 scalar, and with `weights` the pair of it and the
+    tokens' own f32 [b, t] cross-entropy, which is d sum / d weights under
+    the mask."""
+    import jax
+    import jax.numpy as jnp
+
+    nll = _token_nll(head, x, targets, cfg, seq_axis, mesh, rules)
+    with jax.named_scope("loss"):
+        if mask is not None:
+            nll = nll * mask
+        if weights is None:
+            return jnp.sum(nll)
+        return jnp.sum(nll * weights), nll
+
+
+def _chunked_nll_sum(chunk_nll_sum, head, x, targets, mask, weights,
+                     chunk, unroll):
+    """Sum of `chunk_nll_sum(head, x_c, t_c, m_c, w_c)` over the
+    `chunk`-token slices of these sequences, so only one [b, chunk, vocab]
+    f32 logits block lives in HBM at a time. Each chunk's gradient is
+    taken in that same scan (`nll_sum_fwd`), while its logits exist:
+    nothing is saved for, or computed again in, the backward pass. A
+    custom_vjp: reverse mode only (nothing in the tree takes a
+    forward-mode or a second derivative of the loss).
+
+    `mask` gets no cotangent (`None`: it is data). `weights` [b, t] do:
+    d sum / d weights is the tokens' own (masked) cross-entropy, which the
+    forward scan has in hand, so with `weights` the scan stacks that f32
+    [b, t] array besides, the function returns it beside the sum (a
+    reading: its own cotangent is dropped), and the backward pass hands
+    `weights` the sum's cotangent times it."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     n = x.shape[1] // chunk
+    weighted = weights is not None
 
-    def scan_chunks(step, init, x, targets, mask):
-        """`step(carry, (x_c, t_c, m_c))` over the n chunks; m_c is None
-        for a batch without a mask"""
-        def split(a):  # [b, t, ...] -> [n, b, chunk, ...]
-            return jnp.swapaxes(
-                a.reshape(a.shape[0], n, chunk, *a.shape[2:]), 0, 1)
+    def split(a):  # [b, t, ...] -> [n, b, chunk, ...]
+        return jnp.swapaxes(
+            a.reshape(a.shape[0], n, chunk, *a.shape[2:]), 0, 1)
+
+    def join(a):   # back
+        return jnp.swapaxes(a, 0, 1).reshape(
+            a.shape[1], n * chunk, *a.shape[3:])
+
+    def scan_chunks(step, init, x, targets, mask, weights):
+        """`step(carry, (x_c, t_c, m_c, w_c))` over the n chunks; m_c and
+        w_c are None for a batch without them"""
         ms = None if mask is None else split(mask)
-        return lax.scan(step, init, (split(x), split(targets), ms),
+        ws = None if weights is None else split(weights)
+        return lax.scan(step, init, (split(x), split(targets), ms, ws),
                         unroll=unroll)
 
     @jax.custom_vjp
-    def nll_sum(head, x, targets, mask):
-        def step(total, xtm):
-            return total + chunk_nll_sum(head, *xtm), None
-        return scan_chunks(step, jnp.zeros((), jnp.float32),
-                           x, targets, mask)[0]
+    def nll_sum(head, x, targets, mask, weights):
+        def step(total, xtmw):
+            out = chunk_nll_sum(head, *xtmw)
+            if weighted:
+                return total + out[0], out[1]
+            return total + out, None
+        total, nll = scan_chunks(step, jnp.zeros((), jnp.float32),
+                                 x, targets, mask, weights)
+        return (total, join(nll)) if weighted else total
 
-    def nll_sum_fwd(head, x, targets, mask):
+    def nll_sum_fwd(head, x, targets, mask, weights):
         # the sum's incoming cotangent is one scalar, so d head and dx
         # are complete here but for that factor; d head is carried in
         # the head's dtype, as autodiff's backward scan carried it
-        def step(carry, xtm):
+        def step(carry, xtmw):
             total, d_head = carry
-            nll, (dh_c, dx_c) = jax.value_and_grad(
-                chunk_nll_sum, argnums=(0, 1))(head, *xtm)
-            return (total + nll, d_head + dh_c), dx_c
-        (total, d_head), dxs = scan_chunks(
+            out, (dh_c, dx_c) = jax.value_and_grad(
+                chunk_nll_sum, argnums=(0, 1), has_aux=weighted)(
+                    head, *xtmw)
+            if weighted:
+                return (total + out[0], d_head + dh_c), (dx_c, out[1])
+            return (total + out, d_head + dh_c), dx_c
+        (total, d_head), out = scan_chunks(
             step, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
-            x, targets, mask)
-        return total, (d_head, jnp.swapaxes(dxs, 0, 1).reshape(x.shape))
+            x, targets, mask, weights)
+        if weighted:
+            nll = join(out[1])
+            return (total, nll), (d_head, join(out[0]), nll)
+        return total, (d_head, join(out))
 
     def nll_sum_bwd(res, g):
         with jax.named_scope("loss"):
-            d_head, dx = ((g * r).astype(r.dtype) for r in res)
-        return d_head, dx, None, None
+            if weighted:
+                g = g[0]   # the reading's own cotangent is dropped
+            d_head, dx = ((g * r).astype(r.dtype) for r in res[:2])
+            d_weights = g * res[2] if weighted else None
+        return d_head, dx, None, None, d_weights
 
     nll_sum.defvjp(nll_sum_fwd, nll_sum_bwd)
 
@@ -128,15 +173,24 @@ def _chunked_nll_sum(chunk_nll_sum, head, x, targets, mask, chunk, unroll):
     # gradients, the running sums) is "loss"; the projection inside
     # chunk_nll_sum names itself "head"
     with jax.named_scope("loss"):
-        return nll_sum(head, x, targets, mask)
+        return nll_sum(head, x, targets, mask, weights)
 
 
-def nll_sum(w, x, targets, cfg: TransformerConfig, *, mask=None, mesh=None,
+def nll_sum(w, x, targets, cfg: TransformerConfig, *, mask=None,
+            weights=None, mesh=None,
             rules: Optional[ShardingRules] = None):
     """Sum over the tokens of hidden states x [B, T, d] of the (masked)
     negative log-likelihood of targets [B, T] under the head `w`
     (`weight`): f32 scalar. Chunked over T where `cfg.loss_chunk` divides
-    a longer T, else plain autodiff through whole-sequence logits."""
+    a longer T, else plain autodiff through whole-sequence logits.
+
+    `mask` [B, T] is data: it multiplies each token's term and gets no
+    cotangent. `weights` [B, T] f32 are part of the model (a looped
+    stack's exit distribution): they multiply each token's (masked) term,
+    the result is the pair (the weighted sum, the tokens' own masked f32
+    [B, T] cross-entropy under a stopped gradient: a reading for
+    metrics), and the weights' cotangent is the sum's times that
+    cross-entropy, chunked or not."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -147,9 +201,15 @@ def nll_sum(w, x, targets, cfg: TransformerConfig, *, mask=None, mesh=None,
         mask = mask.astype(jnp.float32)
     b, t = targets.shape
     chunk = cfg.loss_chunk
+
+    def with_reading(out):
+        if weights is None:
+            return out
+        return out[0], lax.stop_gradient(out[1])
+
     if not (chunk and t > chunk and t % chunk == 0):
-        return _token_nll_sum(head, x, targets, mask, cfg, "seq", mesh,
-                              rules)
+        return with_reading(_token_nll_sum(
+            head, x, targets, mask, weights, cfg, "seq", mesh, rules))
 
     # GSPMD cannot carry an unreduced sum through a loop: it reduces the
     # whole [vocab, d] dW, and gathers the head, once per chunk. So where
@@ -166,30 +226,41 @@ def nll_sum(w, x, targets, cfg: TransformerConfig, *, mask=None, mesh=None,
     # inside the map the chip owns its layout: no GSPMD constraint
     c_mesh = None if per_chip else mesh
 
-    def chunk_nll_sum(head, x_c, t_c, m_c):
-        return _token_nll_sum(head, x_c, t_c, m_c, cfg, None, c_mesh, rules)
+    def chunk_nll_sum(head, x_c, t_c, m_c, w_c):
+        return _token_nll_sum(head, x_c, t_c, m_c, w_c, cfg, None, c_mesh,
+                              rules)
 
-    def local_nll_sum(head, x, targets, mask=None):
-        return _chunked_nll_sum(chunk_nll_sum, head, x, targets, mask,
-                                chunk, cfg.scan_unroll > 1)
+    # what the batch has of mask and weights, by name, after x and targets
+    given = [name for name, a in (("mask", mask), ("weights", weights))
+             if a is not None]
 
-    args = (x, targets) if mask is None else (x, targets, mask)
+    def local_nll_sum(head, x, targets, *rest):
+        rest = dict(zip(given, rest))
+        return _chunked_nll_sum(
+            chunk_nll_sum, head, x, targets, rest.get("mask"),
+            rest.get("weights"), chunk, cfg.scan_unroll > 1)
+
+    args = (x, targets) + tuple(
+        a for a in (mask, weights) if a is not None)
     if not per_chip:
-        return local_nll_sum(head, *args)
+        return with_reading(local_nll_sum(head, *args))
 
     def per_chip_nll_sum(head, *local):
         with jax.named_scope("head"):
             for dim, axes in enumerate(head_spec):
                 if axes is not None:
                     head = lax.all_gather(head, axes, axis=dim, tiled=True)
-        total = local_nll_sum(head, *local)
+        out = local_nll_sum(head, *local)
         with jax.named_scope("loss"):
-            return lax.psum(total, batch_axes)
+            if weights is None:
+                return lax.psum(out, batch_axes)
+            return lax.psum(out[0], batch_axes), out[1]
 
     from jax.sharding import PartitionSpec as P
     # check_vma=False as in Transformer._make_attention: the checker types
     # the gathered head as varying and puts a psum of dW in every chunk
-    return jax.shard_map(
+    return with_reading(jax.shard_map(
         per_chip_nll_sum, mesh=mesh,
         in_specs=(head_spec,) + (P(batch_axes),) * len(args),
-        out_specs=P(), check_vma=False)(head, *args)
+        out_specs=P() if weights is None else (P(), P(batch_axes)),
+        check_vma=False)(head, *args))

@@ -26,10 +26,16 @@ DeltaNet mixer. A sublayer's residual is formed by ONE rule
 (`_make_layer_fn`'s `entering` and `residual`): `x + f(norm(x))` where the
 leaves have the norm `<name>_norm` that opens the sublayer, and under
 OLMo-2/3's reordered norm (the kinds `d` and `a`) `x + norm(f(x))`, the
-leaves holding `<name>_post_norm` in its place. What the leaves cannot
-say the layer's kind in `layer_pattern` does: a window, and which layer's
-tensors cross layers (`_stack`'s `shared`: the scan output `memory` of
-the mixer `s`, the `k` and `v` of the attention layer `f`).
+leaves holding `<name>_post_norm` in its place, and under a sandwich norm
+`x + norm(f(norm(x)))`, the leaves holding both
+(`TransformerConfig.norm_placement` says which the homogeneous stack's
+have). A looped stack (`cfg.loops` > 1) passes the stream that many
+times through the same stacked leaves, the final norm closing every pass
+(`_looped_hidden`), and where `params` hold `exit_gate` each pass's
+hidden state gives one f32 scalar a token, the exit gate's. What the
+leaves cannot say the layer's kind in `layer_pattern` does: a window, and
+which layer's tensors cross layers (`_stack`'s `shared`: the scan output
+`memory` of the mixer `s`, the `k` and `v` of the attention layer `f`).
 
 Every block names itself with `jax.named_scope`, and the names are an
 interface (PERF.md section 3; the benchmark's per-layer metrics and an
@@ -61,7 +67,13 @@ the reordered norm `attn_post_norm`, `gdn_post_norm`, `mlp_post_norm` (the
 norm on a sublayer's output, inside the scope that closes the sublayer:
 `attn_out/attn_post_norm`, `gdn/out_proj/gdn_post_norm`,
 `mlp/down/mlp_post_norm`), `final_norm`, `head`, `loss` (the vocab head
-and the cross-entropy: models/head.py); a block-diffusion model's loss adds
+and the cross-entropy: models/head.py); a looped stack adds `loops` (the
+loop over the passes: stacking and slicing what the passes save, the sum
+of the passes' weight gradients; `layers`, `final_norm` and the rest lie
+inside it), `loop/exit_gate` (the product with the gate's gain) and
+`loop/exit_loss` (the log-sigmoids, the exit distribution, its entropy,
+laying the passes' hidden states out as rows for the head, the
+weighting); a block-diffusion model's loss adds
 `diffusion/noise` (the draws, the replacement, the weights and their
 counts: models/diffusion.py) and `diffusion/stream` (the doubled
 stream's concatenation and positions, the split before the final norm);
@@ -187,6 +199,20 @@ def _reordered(sub):
 
 
 POST_NORMS = tuple(_reordered(dict.fromkeys(NORMS)))
+
+
+def _placed(sub, placement):
+    """The homogeneous layer's leaves (or specs), made with the norms
+    that open its sublayers, with its norms where `placement`
+    (`TransformerConfig.norm_placement`) says: "pre" as they are, "post"
+    `_reordered`, "both" each `<name>_norm` and a `<name>_post_norm` like
+    it (the sandwich norm)."""
+    if placement == "pre":
+        return sub
+    after = _reordered(sub)
+    return after if placement == "post" else {**sub, **after}
+
+
 # the tensors of `_stack`'s `shared` each kind of layer makes
 MAKES = {"s": ("memory",), "f": ("k", "v")}
 
@@ -509,13 +535,18 @@ class Transformer:
                 layers.update(experts(l, key, keys))
             else:
                 layers["w_gateup"], layers["w_down"] = gated(keys, (l,), f)
-            params["layers"] = layers
+            params["layers"] = _placed(layers, cfg.norm_placement)
+        if cfg.exit_gate:
+            # the exit gate: a gain over the normed hidden state, a bias
+            params["exit_gate"] = norm_init(
+                d ** -0.5, jax.random.fold_in(key, 94), (d,))
+            params["exit_gate_bias"] = jnp.zeros((1,), pdt)
         if cfg.moe_dense_layers:
             lead = jax.random.split(jax.random.fold_in(key, 96), 8)
             dense = attention(cfg.moe_dense_layers, lead)
             dense["w_gateup"], dense["w_down"] = gated(
                 lead, (cfg.moe_dense_layers,), cfg.moe_dense_ff or f)
-            params["dense_layers"] = dense
+            params["dense_layers"] = _placed(dense, cfg.norm_placement)
         if not cfg.tie_embeddings:
             params["lm_head"] = norm_init(
                 d ** -0.5, jax.random.fold_in(key, 99), (d, cfg.vocab_size))
@@ -669,9 +700,13 @@ class Transformer:
         else:
             layers = attention()
             layers.update(experts() if cfg.moe_experts else dense_ffn)
-            specs["layers"] = layers
+            specs["layers"] = _placed(layers, cfg.norm_placement)
+        if cfg.exit_gate:
+            specs["exit_gate"] = ("norm",)
+            specs["exit_gate_bias"] = (None,)
         if cfg.moe_dense_layers:
-            specs["dense_layers"] = dict(attention(), **dense_ffn)
+            specs["dense_layers"] = _placed(dict(attention(), **dense_ffn),
+                                            cfg.norm_placement)
         if not cfg.tie_embeddings:
             specs["lm_head"] = ("embed", "vocab")
         return specs
@@ -867,6 +902,11 @@ class Transformer:
         under the block-diffusion mask; only the noised half is read, so
         the final norm runs over it alone: -> [B, L, d].
 
+        A looped stack (`cfg.loops` = R > 1) gives every pass's hidden
+        state, [R, B, T, d], and with_aux=True (hidden, 0, None, z): the
+        exit gate's f32 `z` [R, B, T], None where `params` hold no
+        `exit_gate` (`_looped_hidden`).
+
         When `mesh` is provided and cfg.attention_impl is ring/ulysses, the
         attention op runs inside shard_map over the "seq" axis; everything
         else is GSPMD via logical sharding constraints.
@@ -876,6 +916,9 @@ class Transformer:
 
         rules = rules or ShardingRules()
         x = Transformer.embed(params, tokens, cfg, mesh=mesh, rules=rules)
+        if cfg.loops > 1:
+            return Transformer._looped_hidden(
+                params, x, cfg, mesh, rules, positions, with_aux)
         if "dense_layers" in params:   # the leading run with a dense FFN
             x = Transformer._stack(
                 params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
@@ -916,6 +959,54 @@ class Transformer:
         if with_aux:
             return out, aux_total, routing
         return out
+
+    @staticmethod
+    def _looped_hidden(params, x, cfg: TransformerConfig, mesh,
+                       rules: ShardingRules, positions, with_aux: bool):
+        """The embedded stream x [B, T, d] `cfg.loops` = R times through
+        the same stacked layers, one `lax.scan` over the passes around
+        `_stack` with the layers' leaves closed over: the final norm closes
+        every pass, what it gives is that pass's hidden state and what the
+        next pass reads, and the position ids are the same in every pass
+        -> [R, B, T, d]. The backward pass sums the passes' weight
+        gradients in the scan's f32 carry, and under `_remat` keeps
+        R x n_layers carries and flash residuals. (A scan and not R
+        unrolled calls: at Ouro-2.6B's widths, six layers and 8,192
+        tokens the v5e ran the scan 0.7% faster in 1.6 GB less memory and
+        compiled it in half the time; unrolled, XLA keeps bf16 copies of
+        whole stacked leaves and eight layers do not fit: PERF.md
+        section 6, PR 63.) with_aux=True:
+        (hidden, 0, None, z), `z` [R, B, T] f32 the exit gate's scalar a
+        token and pass, the normed hidden state times the gain `exit_gate`
+        plus `exit_gate_bias` in float32 (an elementwise product and a sum,
+        no matmul: a float32 matmul runs in bf16 passes on a TPU), None
+        where `params` hold no gate."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        def one_pass(x, _):
+            x = Transformer._stack(params["layers"], x, cfg, mesh=mesh,
+                                   rules=rules, positions=positions)[0]
+            with jax.named_scope("final_norm"):
+                x = _norm(x, params, "final_norm", cfg.norm_eps)
+            return x, x
+
+        # the loop's own work (stacking and slicing what the passes save,
+        # the sum of the passes' weight gradients) is "loops"
+        with jax.named_scope("loops"):
+            out = lax.scan(one_pass, x, None, length=cfg.loops)[1]
+        out = with_logical_constraint(
+            out, (None, "batch", "seq", "act_embed"), mesh=mesh, rules=rules)
+        if not with_aux:
+            return out
+        z = None
+        if "exit_gate" in params:
+            with jax.named_scope("loop/exit_gate"):
+                f32 = jnp.float32
+                z = jnp.sum(out.astype(f32) * params["exit_gate"].astype(f32),
+                            axis=-1) + params["exit_gate_bias"].astype(f32)
+        return out, jnp.zeros((), jnp.float32), None, z
 
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
@@ -1264,10 +1355,13 @@ class Transformer:
     def apply(params, tokens, cfg: TransformerConfig, *,
               mesh=None, rules: Optional[ShardingRules] = None,
               positions=None):
-        """tokens [B, T] int32 -> logits [B, T, vocab] (f32 accum)."""
+        """tokens [B, T] int32 -> logits [B, T, vocab] (f32 accum); a
+        looped stack's are its last pass's (no token leaves early)."""
         rules = rules or ShardingRules()
         x = Transformer.hidden(params, tokens, cfg, mesh=mesh, rules=rules,
                                positions=positions)
+        if cfg.loops > 1:
+            x = x[-1]
         return head.logits(params, x, cfg, mesh=mesh, rules=rules)
 
     @staticmethod
@@ -1304,6 +1398,10 @@ class Transformer:
             raise ValueError("pipeline_loss is next-token training: a "
                              "block-diffusion model (block_length) trains "
                              "through Transformer.loss")
+        if cfg.loops > 1:
+            raise ValueError("pipeline_loss runs its stages once: a looped "
+                             "stack (loops above 1) trains through "
+                             "Transformer.loss")
         if cfg.moe_experts or cfg.layer_pattern:
             raise ValueError(
                 "pipeline_loss takes one homogeneous run of layers and "
@@ -1453,6 +1551,9 @@ class Transformer:
 
         if window:
             raise ValueError("ring and ulysses attention take no window")
+        if cfg.loops > 1:
+            raise ValueError("ring and ulysses attention run no looped "
+                             "stack (loops above 1) yet")
         if cfg.block_length:
             raise ValueError("ring and ulysses attention take no "
                              "block-diffusion mask (block_length)")
@@ -1485,7 +1586,14 @@ class Transformer:
         scalar mean loss (f32), for a MoE config plus `moe_aux_coeff` x the
         load-balancing loss. A block-diffusion model (`cfg.block_length`)
         has another objective, the masked-token loss of
-        `_block_diffusion_loss`, over the batch `diffusion.noised` makes.
+        `_block_diffusion_loss`, over the batch `diffusion.noised` makes,
+        and a looped stack with an exit gate (`cfg.exit_gate`) a third,
+        the expected next-token loss under the distribution of the pass a
+        token leaves at less `exit_entropy_coeff` x that distribution's
+        entropy (`_exit_loss`; its metrics `loop_exit_mass` f32 [loops],
+        `loop_exit_entropy` f32 and `loop_pass_nll` f32 [loops]). A looped
+        stack without the gate trains its last pass's hidden state on
+        the next-token loss.
 
         with_metrics=True returns (loss, metrics), the pair
         `make_train_step` takes: its step's metrics then carry, from the
@@ -1511,10 +1619,16 @@ class Transformer:
         if cfg.block_length:
             return Transformer._block_diffusion_loss(
                 params, batch, cfg, mesh, rules, with_metrics)
+        if cfg.exit_gate:
+            return Transformer._exit_loss(params, batch, cfg, mesh, rules,
+                                          with_metrics)
         tokens, targets = Transformer._tokens_and_targets(batch)
         mask = batch.get("mask")
+        # a looped stack hands its gate's z (None here) as a fourth
         x, aux, routing = Transformer.hidden(
-            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
+            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)[:3]
+        if cfg.loops > 1:   # no gate: the last pass alone is trained
+            x = x[-1]
         total = head.nll_sum(head.weight(params, cfg), x, targets, cfg,
                              mask=mask, mesh=mesh, rules=rules)
         with jax.named_scope("loss"):
@@ -1600,6 +1714,78 @@ class Transformer:
         loss_val, metrics = Transformer._loss_out(loss_val, aux, routing,
                                                   cfg, True)
         return loss_val, dict(metrics, **counts)
+
+    @staticmethod
+    def exit_log_probs(z):
+        """The exit gate's z [R, ...] f32 -> log p [R, ...], the
+        distribution over the pass a token leaves at, from log-sigmoids
+        (never from products of probabilities): a token leaves after pass
+        t < R with probability sigmoid(z_t) if it stayed through the
+        passes before, `log p_t = log sigmoid(z_t) + sum_{j<t} log
+        sigmoid(-z_j)`, and the last pass takes what is left, `log p_R =
+        sum_{j<R} log sigmoid(-z_j)`: z_R is read by nothing."""
+        import jax
+        import jax.numpy as jnp
+
+        stayed = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)
+        before = jnp.concatenate([jnp.zeros_like(z[:1]), stayed[:-1]])
+        return jnp.concatenate(
+            [jax.nn.log_sigmoid(z[:-1]) + before, stayed[-1:]])
+
+    @staticmethod
+    def _exit_loss(params, batch, cfg: TransformerConfig, mesh,
+                   rules: ShardingRules, with_metrics: bool):
+        """The loss of a looped stack with an exit gate (arXiv 2510.25741,
+        the first-stage objective): with p_t(i) the exit distribution of
+        token i (`exit_log_probs`) and nll_t(i) the next-token
+        cross-entropy of pass t's logits, the mean over the (masked)
+        tokens of `sum_t p_t nll_t - exit_entropy_coeff * H(p)`, `H(p) =
+        -sum_t p_t log p_t` (a uniform prior over the exit step), all in
+        float32. The R passes' hidden states go through ONE call of the
+        head as B*R rows (a sequence's passes side by side), the targets
+        repeated, p in the place of the head's `weights`, which hands
+        them their cotangent (`head.nll_sum`). Metrics, from the same
+        forward pass: `loop_exit_mass` f32 [R] (the mean of p_t over the
+        tokens: sums to 1), `loop_exit_entropy` (the mean of H) and
+        `loop_pass_nll` f32 [R] (the mean of nll_t)."""
+        import jax
+        import jax.numpy as jnp
+
+        tokens, targets = Transformer._tokens_and_targets(batch)
+        mask = batch.get("mask")
+        hs, _, _, z = Transformer.hidden(
+            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
+        r, b, t, d = hs.shape
+
+        def rows(a):   # [R, B, T, ...] -> [B * R, T, ...]
+            return jnp.swapaxes(a, 0, 1).reshape((b * r,) + a.shape[2:])
+
+        with jax.named_scope("loop/exit_loss"):
+            log_p = Transformer.exit_log_probs(z)
+            p = jnp.exp(log_p)
+            entropy = -jnp.sum(p * log_p, axis=0)                # [B, T]
+            if mask is not None:
+                mask = mask.astype(jnp.float32)
+                entropy = entropy * mask
+            count = targets.size if mask is None \
+                else jnp.maximum(jnp.sum(mask), 1.0)
+            x, weights = rows(hs), rows(p)
+            repeated = jnp.repeat(targets, r, axis=0)
+            row_mask = None if mask is None else jnp.repeat(mask, r, axis=0)
+        total, nll = head.nll_sum(
+            head.weight(params, cfg), x, repeated, cfg, mask=row_mask,
+            weights=weights, mesh=mesh, rules=rules)
+        with jax.named_scope("loop/exit_loss"):
+            loss_val = (total - cfg.exit_entropy_coeff
+                        * jnp.sum(entropy)) / count
+            if not with_metrics:
+                return loss_val
+            seen = p if mask is None else p * mask
+            return loss_val, {
+                "loop_exit_mass": jnp.sum(seen, axis=(1, 2)) / count,
+                "loop_exit_entropy": jnp.sum(entropy) / count,
+                "loop_pass_nll": jnp.sum(nll.reshape(b, r, t),
+                                         axis=(0, 2)) / count}
 
     @staticmethod
     def _loss_out(loss_val, aux, routing, cfg: TransformerConfig,

@@ -12,14 +12,19 @@ PRs 35, 50, 53; the nine cells 34,539 / 3,117 / 8,394 / 19,774 / 12,926 /
 18,945 / 10,853 / 4,821 / 12,139 lines before PR 60, Ling 36,382, Nemotron
 21,311, GLM 13,387 and SDAR 11,410 since; PR 62's tiles: Ling 36,409,
 Nemotron 21,301). Ling's first: it shares the delta
-rule, the convolution and the head norm with the new model. Each text is
+rule, the convolution and the head norm with the new model. PR 63
+(Ouro-2.6B: a looped stack in `Transformer.hidden`, the sandwich norm by
+the leaves, per-token weights with a gradient in `models/head.py` under
+their own argument, `mask` as it was) left all ten as they were, SDAR's
+(the weighted head), Olmo-Hybrid's (`entering` / `residual`) and d2's
+(`hidden`, `_stack`) first, and pins its own cell. Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
 took 259 s; what had run before it in that process is the one difference,
 not looked into further), and a pin must not depend on what ran before it. d2 (the dense MLP, splash, `_remat`, the
-chunked head, adamw: 15 s) runs with the suite; the other nine, Ling's 75 s
-first, are `slow`: `pytest tests/test_accepted_programs.py -m slow`, 6 min.
+chunked head, adamw: 15 s) runs with the suite; the other ten, Ling's 75 s
+first, are `slow`: `pytest tests/test_accepted_programs.py -m slow`, 7 min.
 
 A pin is the compiler's text: it holds for the jax and libtpu that made it
 (`MADE_WITH`) and the test skips under another. A PR that means to change
@@ -70,6 +75,8 @@ PINS = {
     "train_mellum2_ep4_d4": "4d1f85c1d30e0de0",
     # PR 59's own cell, pinned by PR 60 from PR 59's tree (15,029 lines)
     "train_olmohybrid7b_tp2_d4": "1ebd73113b090dc6",
+    # PR 63's own cell, pinned from its own tree (4,739 lines)
+    "train_ouro26b_d8": "079113b2a5d63a41",
 }
 WITH_THE_SUITE = ("train_mistral7b_d2",)
 
@@ -135,7 +142,8 @@ def step_text(cell: str, devices) -> str:
         def loss(p, b):
             return Transformer.loss(p, b, cfg, mesh=mesh,
                                     **({"with_metrics": True}
-                                       if cfg.moe_experts else {}))
+                                       if cfg.moe_experts or cfg.exit_gate
+                                       else {}))
         batch = {"tokens": jax.ShapeDtypeStruct((seqs, seq + 1), jnp.int32)}
     _, train_step = make_train_step(
         loss, Transformer.param_specs(cfg), mesh, optimizer=optimizer,
