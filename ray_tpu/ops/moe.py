@@ -100,6 +100,13 @@ time (no option):
   all-to-all, `[P, 2, E/P]`: the places on its two sides). The rows that
   leave a shard are the rows routed elsewhere, nothing is gathered on the
   receiving side, and what the bound bounds is a shard's RECEIVED total.
+  The buffer's rows past the received belong to no group: the grouped
+  matmuls are handed the held experts' groups and nothing else, so under
+  `megablox` no kernel visits those rows and no pass zeroes them (the
+  buffer is `lax.empty`, and so in effect is every buffer behind it, the
+  cotangents' too: on the v5e the zeroing was two selects over the whole
+  static buffer a `gmm`, 33.6 ms a step, PERF.md section 6, PR 64); the
+  exchange back moves the received runs alone.
   **Buckets**, elsewhere (XLA:CPU has no ragged all-to-all): one bucket
   of `exchange_bound` rows a (from, to) pair (twice the uniform share
   n·k/P, in whole tiles) through a fixed-shape `all_to_all`, regrouped by
@@ -548,9 +555,18 @@ def exchange_impl(mesh) -> str:
 def experts_ffn(xs, w_gateup, w_down, group_sizes, impl: str = "ragged_dot",
                 act: str = "silu"):
     """The expert FFN over rows already in expert order: xs `[M, d]`,
-    group_sizes `[G]` summing to M; w_gateup `[H, d, 2, f]` (gated) or
-    `[H, d, f]` (plain), w_down `[H, f, d]` for the H <= G experts whose
-    groups come first. Rows of the other groups come back zero."""
+    group_sizes `[G]` summing to M at most; w_gateup `[H, d, 2, f]`
+    (gated) or `[H, d, f]` (plain), w_down `[H, f, d]` for the H <= G
+    experts whose groups come first. What the rows past the held groups
+    come back as is the caller's choice. A caller that names them, a group
+    more than it has weights (G > H, sizes summing to M), gets zeros:
+    `ragged_dot` masks them, `megablox` selects them away, one pass over
+    the whole output after each `gmm`, forward and transposed. A caller
+    whose sizes stop at the held groups (G = H, summing to less than M)
+    gets zeros from `ragged_dot` and from `megablox` whatever the memory
+    held: its kernels visit the row tiles of the groups alone and no pass
+    follows them, so such a caller reads none of those rows, nor their
+    cotangents (`_exchange_ffn`'s ragged round)."""
     import jax
 
     held, d, f = w_gateup.shape[0], w_gateup.shape[1], w_gateup.shape[-1]
@@ -970,8 +986,6 @@ def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
             with jax.named_scope("moe/exchange"):
                 lands = jax.lax.all_to_all(
                     jnp.stack([target, starts], 1), ep, 0, 0)    # [P,2,held]
-            groups = jnp.concatenate(
-                [groups, size - groups.sum(keepdims=True)]).astype(jnp.int32)
             return (groups,
                     (starts.reshape(-1), counts, lands[:, 0].reshape(-1)),
                     (target.reshape(-1), recv_counts.reshape(-1),
@@ -984,9 +998,15 @@ def _exchange_ffn(params, x, top_w, top_e, mesh, rules: ShardingRules,
             no regrouping on the receiving one."""
             slots_of, combine = _permutes("clip")
             sorted_side, received_side = sides[:3], sides[3:]
-            # the receive buffer's rows past the received: `megablox`
-            # masks the rows of no held group by selects, forward and
-            # transposes, so what they hold is never used
+            # the receive buffer's rows past the received are no group's
+            # (`groups` stops at the held experts): `megablox` visits no
+            # row tile past them, forward and transposes, `tgmm` keeps a
+            # boundary tile's foreign rows out by a select in the kernel,
+            # and both exchanges move the received runs alone, so what
+            # those rows hold, here and in every buffer down to the
+            # cotangents, is never read and nothing writes it. Under
+            # `ragged_dot`, whose lowering may multiply a row it masks,
+            # the buffer starts as zeros
             filled = impl != "megablox"
             with jax.named_scope("moe/dispatch"):
                 sent = slots_of(x, order, inverse, k)            # [m, d]

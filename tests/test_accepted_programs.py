@@ -17,7 +17,16 @@ rule, the convolution and the head norm with the new model. PR 63
 the leaves, per-token weights with a gradient in `models/head.py` under
 their own argument, `mask` as it was) left all ten as they were, SDAR's
 (the weighted head), Olmo-Hybrid's (`entering` / `residual`) and d2's
-(`hidden`, `_stack`) first, and pins its own cell. Each text is
+(`hidden`, `_stack`) first, and pins its own cell. PR 64 (`ops/moe.
+_exchange_ffn`'s ragged round hands the grouped matmuls the held experts'
+groups alone) means to change Mellum2's program and no other, and found
+its pin, `4d1f85c1d30e0de0` (12,139 lines), to be of a step that exchanges
+nothing: `step_text` built every step under the default sharding rules,
+under which that cell's experts' axis is on no mesh axis, and the text
+held no `ragged-all-to-all` and came out the same from both trees. Since
+PR 64 `step_text` reads `layout.rules` where a configuration states them
+(Mellum2's alone does: the other ten steps are built by the calls they
+were built by) and the pin is of the cell's own program. Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
@@ -62,7 +71,8 @@ MADE_WITH = {"jax": "0.9.0", "libtpu": "0.0.34"}
 # changed too (896 for 896 and 1,792, 1,152 for 2,304), but they live in the kernels'
 # bodies, which `blank` blanks: its text is PR 58's line for line, and its
 # tiles are pinned in `tests/test_chip_compile_ep_moe.py`; OLMoE's, GLM's
-# and SDAR's tiles and texts are as they were
+# and SDAR's tiles and texts are as they were. PR 64 made Mellum2's anew
+# from its own tree, built under its layout's rules for the first time
 PINS = {
     "train_ling3flash_ep64_d7": "eee8e09273ef454d",          # PR 62
     "train_mistral7b_d2": "dc53d3934bbf1705",
@@ -72,7 +82,9 @@ PINS = {
     "train_phi4miniflash_d6": "a8abf884315bc039",
     "train_sdar30b_ep8_d4": "7cc6dbdd7647da51",              # PR 60
     "train_mistral7b_d8_fsdp4": "d58dfb898bcd436d",
-    "train_mellum2_ep4_d4": "4d1f85c1d30e0de0",
+    # PR 64's tree, with the layout's rules (23,869 lines; its parent's
+    # with them 849734f4a1c00937, 24,195 lines)
+    "train_mellum2_ep4_d4": "3553e1c05d2e9563",
     # PR 59's own cell, pinned by PR 60 from PR 59's tree (15,029 lines)
     "train_olmohybrid7b_tp2_d4": "1ebd73113b090dc6",
     # PR 63's own cell, pinned from its own tree (4,739 lines)
@@ -124,10 +136,14 @@ def step_text(cell: str, devices) -> str:
     ctx = resolve_cell(load_spec(), cell)
     model, mix = ctx["config"], ctx["traffic"]
     seq, seqs = mix["tokens_per_sequence"], mix["sequences_per_step"]
-    cfg = load_module("jobs", model["job"]).transformer_config(
-        model, model["train"], seq)
+    job = load_module("jobs", model["job"])
+    cfg = job.transformer_config(model, model["train"], seq)
     mesh = make_mesh(MeshConfig(**model["layout"]["mesh"]),
                      devices=devices[:ctx["cell"]["chips"]])
+    # the layout's sharding rules where it states any (Mellum2's lay the
+    # experts' axis on the mesh: without them no row is exchanged)
+    rules = {"rules": job.sharding_rules(model["layout"])} \
+        if model["layout"].get("rules") else {}
     frozen = Transformer.frozen(cfg)
     opt = model["train"]["optimizer"]
     optimizer = optax.adamw(opt["learning_rate"],
@@ -140,13 +156,14 @@ def step_text(cell: str, devices) -> str:
                  "noise_key": jax.ShapeDtypeStruct((seqs, 2), jnp.uint32)}
     else:
         def loss(p, b):
-            return Transformer.loss(p, b, cfg, mesh=mesh,
+            return Transformer.loss(p, b, cfg, mesh=mesh, **rules,
                                     **({"with_metrics": True}
                                        if cfg.moe_experts or cfg.exit_gate
                                        else {}))
         batch = {"tokens": jax.ShapeDtypeStruct((seqs, seq + 1), jnp.int32)}
     _, train_step = make_train_step(
         loss, Transformer.param_specs(cfg), mesh, optimizer=optimizer,
+        **rules,
         **({"frozen": frozen} if any(jax.tree.leaves(frozen)) else {}))
 
     def init(key):

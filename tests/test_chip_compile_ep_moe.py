@@ -41,7 +41,8 @@ def test_expert_parallel_step_compiles_and_fits_the_v5e_host(v5e_host):
     `train_mellum2_ep4_d4`): the experts 16 a device behind the exchange
     (`ops/moe._exchange_ffn`: the rows through `ragged-all-to-all`, the
     counts through all-to-alls in the compiled step, `megablox` inside the
-    `shard_map` over the 131,072 rows of the receive buffer),
+    `shard_map` over the 131,072 rows of the receive buffer and no pass
+    behind its kernels),
     everything else sharded four ways and gathered for use, splash under
     a window and under the causal mask by their scopes, and the compile's
     memory report: whether one sequence a chip fits, at no chip time."""
@@ -101,6 +102,20 @@ def test_expert_parallel_step_compiles_and_fits_the_v5e_host(v5e_host):
         + ["splash_mha_fwd_residuals"] * 2 + ["tgmm"] * 4, names
     assert all("moe/experts" in op for n, op in kernels
                if re.match(r"t?gmm", n))
+    # the grouped matmuls are handed the held experts' groups and nothing
+    # else, so `megablox` follows no kernel with its zeroing select over
+    # the receive buffer's static 131,072 rows (PR 64: five passes a layer
+    # of `[131072, 1792]` / `[131072, 2304]` and a sixth fused into a
+    # neighbour, `moe/experts/jit(gmm)/jit(_where)/select_n`). The selects
+    # over those rows that stay are the dense fallback's fills
+    whole_selects = [
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in hlo.splitlines()
+        if re.search(r"= bf16\[131072,\d+\]\S* select\(", line)]
+    assert whole_selects and not [
+        op for op in whole_selects if "moe/experts" in op], whole_selects
+    assert all("branch_0_fun" in op and "jit(_take)" in op
+               for op in whole_selects), whole_selects
     splash = sorted({re.search(r"attention/(\w+)", op).group(1)
                      for n, op in kernels if "splash" in n})
     assert splash == ["full", "window"], splash

@@ -5,7 +5,9 @@ shape that takes the decision: k = 896 and n = 1,792 in the gated first
 matmul, two row tiles, three held groups of which one is empty and the
 trailing group of no expert: the forward `gmm`, its transpose and `tgmm`
 (which visits the empty group and writes its zeros) each look their tiles
-up by their own shapes inside `megablox`'s `custom_vjp`."""
+up by their own shapes inside `megablox`'s `custom_vjp`. And, as traces
+(PR 64): where `megablox` puts its zeroing select behind a kernel and
+where it does not, which `ops/moe._exchange_ffn`'s ragged round leans on."""
 
 import functools
 
@@ -70,3 +72,56 @@ def test_megablox_at_896_agrees_with_ragged_dot(interpreted, at):
     else:                                   # the empty group's weights: zero
         assert not np.asarray(got[1]).any()
         assert np.asarray(got[0]).any() and np.asarray(got[2]).any()
+
+
+def equations(jaxpr):
+    """The trace's equations in order, those of the jaxprs they call
+    behind them, the kernels' own bodies apart."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+@pytest.mark.parametrize("named", [False, True],
+                         ids=["held_groups_only", "a_group_of_no_expert"])
+def test_megablox_zeroes_behind_its_kernels_only_for_a_group_it_is_told_of(
+        named, pass_):
+    """The library behaviour `_exchange_ffn`'s ragged round leans on (PR
+    64), by name, so that a JAX that changes it fails here: `megablox.gmm`
+    follows its kernel with a select over the WHOLE output, forward and in
+    its transpose, exactly where it is handed more groups than weights
+    (`_rows_ffn`'s run, whose `token_sums` needs those zeros); handed the
+    held groups alone, which may sum to fewer rows than there are, it
+    traces no pass behind the kernel, and the rows past the groups are
+    whatever the memory held."""
+    sizes = jax.ShapeDtypeStruct((HELD + named,), jnp.int32)
+    rows = jax.ShapeDtypeStruct((M, D), jnp.bfloat16)
+    weights = jax.ShapeDtypeStruct((HELD, D, 2 * F), jnp.bfloat16)
+
+    def gmm(rows, weights, sizes):
+        return ops.gmm(rows, weights, sizes, rows.dtype, moe.gmm_tiles)
+
+    def summed(rows, weights, sizes):
+        return gmm(rows, weights, sizes).astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(
+        gmm if pass_ == "forward" else jax.grad(summed, argnums=(0, 1)))(
+            rows, weights, sizes)
+    eqns = list(equations(traced.jaxpr))
+    kernels = [i for i, eqn in enumerate(eqns)
+               if eqn.primitive.name == "pallas_call"]
+    # the forward `gmm`; behind it in the gradient its transpose and `tgmm`
+    assert len(kernels) == (1 if pass_ == "forward" else 3)
+    # a select over a whole `[rows, n]` array behind the first kernel
+    selects = [eqn for eqn in eqns[kernels[0]:]
+               if eqn.primitive.name == "select_n"
+               and eqn.outvars[0].aval.ndim == 2]
+    # one behind the forward `gmm`, one behind its transpose; `tgmm` none
+    assert len(selects) == named * (1 if pass_ == "forward" else 2)

@@ -1,11 +1,13 @@
 """Mellum 2's expert exchange (`ops/moe._exchange_ffn`) under loads that
 take the one bounded round and loads that take the rounds for any load,
-the held shares adding up to it and to the uncut layer, the new scopes as
+the ragged round with every row that nothing writes poisoned (PR 64), the
+held shares adding up to it and to the uncut layer, the new scopes as
 metadata only, and each fault of `benchmark/reference/mellum2_faults.py`
 at the small size. The model against its reference is
 `tests/test_mellum2_reference.py`'s; both read `tests/_mellum2.py`."""
 
 import contextlib
+import functools
 import os
 import re
 
@@ -28,18 +30,18 @@ from tests._mellum2 import (BENCH_DIR, E, RULES, assert_close, config, faults,
 N, D, F, K = 256, 16, 32, 2
 
 
-def layer_and_rows(seed=0):
-    params = moe.init_moe_params(jax.random.key(seed), D, F, E)
-    x = jax.random.normal(jax.random.key(seed + 1), (N, D))
+def layer_and_rows(seed=0, n=N, d=D, f=F):
+    params = moe.init_moe_params(jax.random.key(seed), d, f, E)
+    x = jax.random.normal(jax.random.key(seed + 1), (n, d))
     top_w = jax.nn.softmax(jax.random.normal(jax.random.key(seed + 2),
-                                             (N, K)))
+                                             (n, K)))
     return params, x, top_w
 
 
-def spread_choice(seed=3):
+def spread_choice(n=N, seed=3):
     """Two distinct experts a token, near uniform."""
-    first = jax.random.randint(jax.random.key(seed), (N, 1), 0, E)
-    step = 1 + jax.random.randint(jax.random.key(seed + 1), (N, 1), 0, E - 1)
+    first = jax.random.randint(jax.random.key(seed), (n, 1), 0, E)
+    step = 1 + jax.random.randint(jax.random.key(seed + 1), (n, 1), 0, E - 1)
     return jnp.concatenate([first, (first + step) % E], axis=1)
 
 
@@ -54,16 +56,39 @@ def loop_reference(params, x, top_w, top_e):
     return y
 
 
-LOADS = {
+def weigh(y):
+    return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
+
+
+def both_paths(top_e, mesh):
+    """(one_device, exchanged): the layer past the router as a weighted
+    sum of its output, through the one-device sorted path and through the
+    exchange over `mesh`; each also returns y (and the routing record)."""
+    def one_device(p, x, w):
+        y = moe._sorted_ffn(p, x, w, top_e, None)[0]
+        return weigh(y), y
+
+    def exchanged(p, x, w):
+        y, record = moe._exchange_ffn(p, x, w, top_e, mesh, RULES)
+        return weigh(y), (y, record)
+
+    return one_device, exchanged
+
+
+def value_and_grads(fn):
+    return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2), has_aux=True))
+
+
+LOADS = {     # by the tokens of all four chips, N where not said
     # every token of every chip to chip 0's experts: the worst load
-    "all_to_one_chip": lambda: jnp.tile(jnp.array([[0, 1]]), (N, 1)),
-    # chip 0's tokens (the first N / 4) all to chip 3: one chip overflows
-    "one_chip_overflows": lambda: spread_choice().at[:N // 4].set(
+    "all_to_one_chip": lambda n=N: jnp.tile(jnp.array([[0, 1]]), (n, 1)),
+    # chip 0's tokens (the first n / 4) all to chip 3: one chip overflows
+    "one_chip_overflows": lambda n=N: spread_choice(n).at[:n // 4].set(
         jnp.array([6, 7])),
     "spread": spread_choice,
-    # chip 0 sends chip 1 exactly the bucket's 64 rows: just under
-    "at_the_bound": lambda: spread_choice().at[:N // 4].set(
-        jnp.array([0, 4])).at[:N // 8].set(jnp.array([2, 3])),
+    # chip 0 sends chip 1 exactly the bucket's n / 4 rows: just under
+    "at_the_bound": lambda n=N: spread_choice(n).at[:n // 4].set(
+        jnp.array([0, 4])).at[:n // 8].set(jnp.array([2, 3])),
 }
 BOUNDED = {"all_to_one_chip": 0, "one_chip_overflows": 0, "spread": 1,
            "at_the_bound": 1}
@@ -80,22 +105,12 @@ def test_dropless_at_any_load_and_the_branch_is_agreed(load):
         top_e = LOADS[load]()
         mesh = mesh_of(4)
         assert moe.exchange_bound(N // 4 * K, 4) == 64
+        one_device, exchanged = both_paths(top_e, mesh)
 
-        def weigh(y):
-            return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
-
-        def one_device(p, x, w):
-            y = moe._sorted_ffn(p, x, w, top_e, None)[0]
-            return weigh(y), y
-
-        def exchanged(p, x, w):
-            y, record = moe._exchange_ffn(p, x, w, top_e, mesh, RULES)
-            return weigh(y), (y, record)
-
-        (_, want), want_grads = jax.jit(jax.value_and_grad(
-            one_device, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
-        (_, (got, record)), grads = jax.jit(jax.value_and_grad(
-            exchanged, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
+        (_, want), want_grads = value_and_grads(one_device)(
+            params, x, top_w)
+        (_, (got, record)), grads = value_and_grads(exchanged)(
+            params, x, top_w)
         assert_close(got, want, "y")
         assert_close(got, loop_reference(params, x, top_w, top_e),
                      "y against the loop")
@@ -167,25 +182,15 @@ def test_the_ragged_exchange_sends_the_rows_it_has(load, monkeypatch):
         params, x, top_w = layer_and_rows()
         top_e = LOADS[load]()
         mesh = mesh_of(4)
+        one_device, exchanged = both_paths(top_e, mesh)
 
-        def weigh(y):
-            return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum()
-
-        def one_device(p, x, w):
-            y = moe._sorted_ffn(p, x, w, top_e, None)[0]
-            return weigh(y), y
-
-        def exchanged(p, x, w):
-            y, record = moe._exchange_ffn(p, x, w, top_e, mesh, RULES)
-            return weigh(y), (y, record)
-
-        (_, want), want_grads = jax.jit(jax.value_and_grad(
-            one_device, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
+        (_, want), want_grads = value_and_grads(one_device)(
+            params, x, top_w)
         jaxpr = str(jax.make_jaxpr(jax.grad(
             lambda *a: exchanged(*a)[0], argnums=(0, 1, 2)))(
                 params, x, top_w))
-        (_, (got, record)), grads = jax.jit(jax.value_and_grad(
-            exchanged, argnums=(0, 1, 2), has_aux=True))(params, x, top_w)
+        (_, (got, record)), grads = value_and_grads(exchanged)(
+            params, x, top_w)
         assert_close(got, want, "y")
         assert_close(got, loop_reference(params, x, top_w, top_e),
                      "y against the loop")
@@ -214,6 +219,86 @@ def test_the_ragged_exchange_sends_the_rows_it_has(load, monkeypatch):
     if load == "one_chip_overflows":
         # chip 0's 128 slots on top of its near-uniform share of the rest
         assert 192 < np.asarray(record["rows_received"])[3] <= 256
+
+
+# The smallest layer whose receive buffer `megablox` takes: 4 x 128 rows
+# (`exchange_bound(256, 4)`, one row tile of 512 a chip) of widths that are
+# one lane tile, 128 tokens a chip.
+POISONED_N, POISONED_D = 512, 128
+RESULTS = ("y", "d_x", "d_top_w", "d_w_first", "d_w_down")
+
+
+@functools.lru_cache(maxsize=None)
+def behind_a_poisoned_exchange(load):
+    """The ragged round as the chip runs it, on the CPU's mesh, with every
+    row that nothing writes holding nan: the collective emulated
+    (`ragged_all_to_all_from_gathers` leaves `output` where no run lands),
+    `jax.lax.empty` a nan fill (the receive buffer's rows past the
+    received, forward, and the cotangent buffer's, backward) and
+    `megablox` the grouped matmul, its kernels interpreted (the
+    interpreter starts a kernel's output as nan, so the rows no grid step
+    visits stay so, as the chip leaves them whatever the memory held).
+    -> (`RESULTS` of the exchange, of the one-device sorted path, y of the
+    reference's loop, the routing record)."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops
+
+    n, d = POISONED_N, POISONED_D
+    assert moe.exchange_bound(n // 4 * K, 4) * 4 == moe.GMM_ROWS
+    assert moe.gmm_tiles(moe.GMM_ROWS, d, 2 * d) and moe.gmm_tiles(
+        moe.GMM_ROWS, d, d)
+    params, x, top_w = layer_and_rows(n=n, d=d, f=d)
+    top_e = LOADS[load](n)
+    mesh = mesh_of(4)
+    one_device, exchanged = both_paths(top_e, mesh)
+
+    def results(y, grads):
+        p, dx, dw = grads
+        return y, dx, dw, p["w_gateup"], p["w_down"]
+
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = value_and_grads(one_device)(
+            params, x, top_w)
+        looped = loop_reference(params, x, top_w, top_e)
+        gmm = ops.gmm
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(moe, "exchange_impl", lambda mesh: "ragged")
+            patch.setattr(jax.lax, "ragged_all_to_all",
+                          ragged_all_to_all_from_gathers)
+            patch.setattr(moe, "grouped_matmul_impl",
+                          lambda *a, **kw: "megablox")
+            patch.setattr(ops, "gmm",
+                          lambda *args: gmm(*args, interpret=True))
+            patch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
+                shape, jnp.nan, dtype))
+            moe._ragged_exchange.cache_clear()   # it keeps the fill it saw
+            try:
+                (_, (got, record)), grads = value_and_grads(exchanged)(
+                    params, x, top_w)
+            finally:
+                moe._ragged_exchange.cache_clear()
+    return (results(got, grads), results(want, want_grads), looped,
+            jax.tree.map(np.asarray, record))
+
+
+@pytest.mark.parametrize("at", range(len(RESULTS)), ids=RESULTS)
+@pytest.mark.parametrize("load", ["spread", "at_the_bound"])
+def test_the_rows_past_the_received_are_never_read(load, at):
+    """The grouped matmuls behind the exchange are handed the held
+    experts' groups and nothing else, so under `megablox` nothing zeroes
+    the receive buffer's rows past the received, nor those rows of any
+    buffer behind it, forward or backward. With all of them poisoned the
+    layer's output and every gradient is finite and is the one-device
+    sorted path's and the reference's: no reader reaches them."""
+    got, want, looped, record = behind_a_poisoned_exchange(load)
+    # the ragged round ran, and left rows that nothing wrote on every chip
+    assert record["exchange_bounded"].tolist() == [1] * 4
+    assert record["rows_received"].sum() == POISONED_N * K
+    assert 0 < record["rows_received"].min()
+    assert record["rows_received"].max() < moe.GMM_ROWS
+    assert np.isfinite(np.asarray(got[at])).all(), RESULTS[at]
+    assert_close(got[at], want[at], RESULTS[at])
+    if RESULTS[at] == "y":
+        assert_close(got[at], looped, "y against the loop")
 
 
 def test_exchange_impl_by_what_the_mesh_says():
