@@ -765,29 +765,39 @@ class Transformer:
         carry, what the layer names: what the flash forward kernel hands
         its backward (`ops.attention.FLASH_RESIDUALS`: the output
         [B, H, T, Dv] in the compute dtype and the logsumexp [B, H, T] in
-        f32) and an expert layer's routing (`ops.moe.ROUTING_RESIDUALS`:
+        f32), an expert layer's routing (`ops.moe.ROUTING_RESIDUALS`:
         the router's logits [N, E] f32, the chosen experts [N, k] int32
         and their scores f32, `keep` [N, held] where held < k, the sort's
-        two permutations [N·min(k, held)] and counts [E], int32). It
-        recomputes the rest (norms, projections, RoPE, the MLP or the
+        two permutations [N·min(k, held)] and counts [E], int32) and what
+        the delta rule's forward kernel hands its backward
+        (`ops.kda.DELTA_RESIDUALS`, all f32: the output [B, T, H·D], the
+        states entering the chunks [B, T/64, H·Dv, D] and the chunks'
+        inverses [B, T/64, H/2·64, 128]). It recomputes the rest (norms,
+        projections, RoPE, the convolutions and gates, the MLP or the
         experts past the sort). The backward pass then runs the forward
-        kernel, the f32 router product, the top-k and the sorts once a
-        step, not twice. The rule adapts by what the traced layer holds:
-        only the flash path and a router name those values, so under
-        `dense`, `ring` or `ulysses` attention with a dense FFN nothing is
-        named, nothing is saved and the program is "full"'s. At many
-        layers it costs L x B*T*H*Dv x 2 bytes (+ L x B*H*T x 4) and, an
-        expert layer, N*E x 4 bytes (+ the `[N, k]`s) more than "full",
-        which saves nothing but the carry and is there for whoever needs
-        those bytes. "dots" saves every matmul's output besides."""
+        kernels, the doubling of a chunk's inverse, the f32 router
+        product, the top-k and the sorts once a step, not twice. The rule
+        adapts by what the traced layer holds: only the flash path, a
+        router and the delta rule's pallas kernels name those values, so
+        under `dense`, `ring` or `ulysses` attention with a dense FFN, and
+        under the delta rule in plain XLA (`ops.kda.gated_delta_rule`: the
+        CPU, a mesh above one device, a decay a head), nothing is named,
+        nothing is saved and the program is "full"'s. At many layers it
+        costs L x B*T*H*Dv x 2 bytes (+ L x B*H*T x 4), an expert layer
+        N*E x 4 bytes (+ the `[N, k]`s) and a KDA layer B*T*H x (4 D +
+        8 Dv + 256) bytes (201 + 33.5 MB at 16,384 tokens of 8 heads of
+        128) more than "full", which saves nothing but the carry and is
+        there for whoever needs those bytes. "dots" saves every matmul's
+        output besides."""
         import jax
 
         from ray_tpu.ops.attention import FLASH_RESIDUALS
+        from ray_tpu.ops.kda import DELTA_RESIDUALS
         from ray_tpu.ops.moe import ROUTING_RESIDUALS
 
         policies = jax.checkpoint_policies
-        residuals = policies.save_only_these_names(FLASH_RESIDUALS,
-                                                   ROUTING_RESIDUALS)
+        residuals = policies.save_only_these_names(
+            FLASH_RESIDUALS, ROUTING_RESIDUALS, DELTA_RESIDUALS)
         known = {"attention": residuals, "full": None,
                  "dots": policies.save_from_both_policies(
                      policies.checkpoint_dots, residuals)}

@@ -136,12 +136,22 @@ nothing (k = v = 0, beta = 0, no decay).
   and the carried state, transposed `[Dv, D]` float32 a head (a decay lies
   along its lanes) in a scratch that persists over the chunk axis. The
   forward (`kda_delta_fwd`) also writes the state entering each chunk,
-  `[T/C, H·Dv, D]` float32: its one residual, alive between remat's
-  forward and the backward of the same layer. The backward
-  (`kda_delta_bwd`, under `jax.custom_vjp`) walks the chunks in reverse
-  with the state's cotangent carried in VMEM, makes a chunk's factors,
-  blocks, inverse and U again, and returns dq, dk, dv (compute dtype,
-  through the L2 norms), dg and dbeta; the inverse's cotangent is
+  `[T/C, H·Dv, D]` float32, and each chunk's inverse, float32 as made, a
+  pair's two `[64, 64]` diagonal blocks side by side: `[T/C, H/2·64,
+  128]`. These two and o are what the rule's forward hands its backward,
+  under one `checkpoint_name`, `DELTA_RESIDUALS`: a layer under
+  `jax.checkpoint` whose policy saves that name (`Transformer._remat`'s)
+  keeps all three from its forward pass, 201 + 33.5 MB a layer at 16,384
+  tokens of 8 heads, and its backward pass runs neither the forward
+  kernel a second time (the head norm's derivative reads the kept o) nor
+  the doubling a third; under a policy that does not, remat's forward
+  runs the kernel again and they live between it and the backward of
+  the same layer. The backward (`kda_delta_bwd`, under `jax.custom_vjp`)
+  walks the chunks in reverse with the state's cotangent carried in
+  VMEM, reads a chunk's inverse where the forward made it (the same
+  float32 array: the same bits), makes its factors, blocks and U again,
+  and returns dq, dk, dv (compute dtype, through the L2 norms), dg and
+  dbeta; the inverse's cotangent is
   `T^T dT T^T` on the strictly lower part (two products, no second
   solve), what reaches G through the factors' reference rows is added
   to those rows, and dg is the transpose of the running sum (one more
@@ -429,6 +439,11 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
 DELTA_CHUNK = 64
 DELTA_LANES = 128
 DELTA_HEADS = 4          # heads a grid step takes (PERF.md section 6, PR 51)
+# The `checkpoint_name` of what the forward kernel hands the backward one
+# (`_delta_calls.rule_fwd`; module docstring), for a `jax.checkpoint`
+# policy to save, as `ops.attention.FLASH_RESIDUALS` is. Only the kernels
+# name anything: `gated_delta_rule` keeps what autodiff keeps.
+DELTA_RESIDUALS = "delta_residuals"
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 
@@ -509,13 +524,16 @@ def _by_sub(of_head):
     return jnp.concatenate(of_head(0) + of_head(1), axis=0)
 
 
-def _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p: int):
+def _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p: int,
+               inv=None):
     """The part of a chunk that is independent of the state, for the pair
     p of a grid step's heads: the normed q and k, the running sums G (and
     the rows of them the factors refer to, read off `g_scr`), A and B
     relative to the middles of the rows' sub-blocks, the unit lower
-    triangular inverse by doubling, `T beta V` and `T beta (K e^G)`: a
-    namespace of `[128, 128]` values of the pair."""
+    triangular inverse by doubling (or `inv`, the `[128, 128]` float32
+    one the forward kernel made of these operands: the powers are then
+    not formed), `T beta V` and `T beta (K e^G)`: a namespace of
+    `[128, 128]` values of the pair."""
     import types
 
     import jax
@@ -586,18 +604,20 @@ def _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p: int):
         sc[2 * h * SUB:(2 * h + 1) * SUB] for sc in scores]), 0.0)
     ch.a_mat = jnp.where(ch.strict, _by_sub(lambda h: [
         sc[(2 * h + 1) * SUB:(2 * h + 2) * SUB] for sc in scores]), 0.0)
-    # (I - N)^-1 by doubling, N = -beta A: N^64 = 0
-    nil = -ch.beta * ch.a_mat
+    if inv is None:
+        # (I - N)^-1 by doubling, N = -beta A: N^64 = 0
+        nil = -ch.beta * ch.a_mat
 
-    def double(_, carry):
-        inv, power = carry       # the powers below `reach`, N^reach
-        return (inv + _dot(inv, power, _NN, exact=True),
-                _dot(power, power, _NN, exact=True))
+        def double(_, carry):
+            inv, power = carry       # the powers below `reach`, N^reach
+            return (inv + _dot(inv, power, _NN, exact=True),
+                    _dot(power, power, _NN, exact=True))
 
-    inv, power = jax.lax.fori_loop(
-        0, 4, double, ((row == col).astype(f32) + nil,
-                       _dot(nil, nil, _NN, exact=True)))
-    ch.inv = inv + _dot(inv, power, _NN, exact=True)
+        inv, power = jax.lax.fori_loop(
+            0, 4, double, ((row == col).astype(f32) + nil,
+                           _dot(nil, nil, _NN, exact=True)))
+        inv = inv + _dot(inv, power, _NN, exact=True)
+    ch.inv = inv
     ch.inv_c = ch.inv.astype(cdt)
     ch.rhs = jnp.concatenate(
         [ch.v.astype(f32) * ch.beta, ch.k * ch.decayed * ch.beta],
@@ -611,6 +631,28 @@ def _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p: int):
     return ch
 
 
+def _diagonal_blocks(inv):
+    """A pair's `[128, 128]` inverse -> its heads' two `[64, 64]` blocks
+    side by side, `[64, 128]`: what lies between the heads is zero."""
+    import jax
+    import jax.numpy as jnp
+
+    c = DELTA_CHUNK
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    return jnp.where(lane < c, inv[:c], inv[c:])
+
+
+def _block_diagonal(blocks):
+    """`_diagonal_blocks` back: `[64, 128]` -> the pair's `[128, 128]`."""
+    import jax
+    import jax.numpy as jnp
+
+    c = DELTA_CHUNK
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    return jnp.concatenate([jnp.where(lane < c, blocks, 0.0),
+                            jnp.where(lane < c, 0.0, blocks)], axis=0)
+
+
 def _corrected(ch, states):
     """U = T beta V - (T beta (K e^G)) S_0 of a pair, in the compute
     dtype: `states` the two heads' entering states `[Dv, D]`, rounded."""
@@ -619,11 +661,13 @@ def _corrected(ch, states):
 
 
 def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
-                      state, g_scr, *, pairs: int):
+                      inv_ref, state, g_scr, *, pairs: int):
     """One chunk of one block of heads: q, k, v, g `[64, hb·128]`, beta
-    `[hb/2, 64, 2]` -> o `[64, hb·128]` float32 and the states that
-    entered the chunk, transposed `[hb·Dv, D]` (a decay lies along the
-    lanes); `state` carries them over the chunks."""
+    `[hb/2, 64, 2]` -> o `[64, hb·128]` float32, the states that entered
+    the chunk, transposed `[hb·Dv, D]` (a decay lies along the lanes), and
+    the chunk's inverses, float32 as made, a pair's two `[64, 64]` blocks
+    side by side: `[hb/2·64, 128]`; `state` carries the states over the
+    chunks."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -635,6 +679,7 @@ def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
 
     for p in range(pairs):
         ch = _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p)
+        inv_ref[0, 0, p * c:(p + 1) * c, :] = _diagonal_blocks(ch.inv)
         at = _states_at(p)
         entering = [state[at[h], :] for h in range(2)]
         for h in range(2):
@@ -650,15 +695,16 @@ def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
                 u[rows], ch.k_out[rows], _TN)
 
 
-def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
-                      dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate,
-                      g_scr, *, pairs: int):
+def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, inv_ref,
+                      do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                      dstate, g_scr, *, pairs: int):
     """The same chunk going backward (the chunks last to first): besides
-    the forward's operands the entering states and do `[64, hb·128]`
-    float32 -> dq, dk, dv, dg and dbeta `[hb/2, 64, 2]`. A chunk's
-    factors, blocks and inverse are made again; `dstate` carries the
-    cotangent of the state a chunk hands on. The inverse's cotangent is
-    `T^T dT T^T` on the strictly lower part."""
+    the forward's operands the entering states, the inverses as the
+    forward wrote them and do `[64, hb·128]` float32 -> dq, dk, dv, dg
+    and dbeta `[hb/2, 64, 2]`. A chunk's factors and blocks are made
+    again, its inverse is read; `dstate` carries the cotangent of the
+    state a chunk hands on. The inverse's cotangent is `T^T dT T^T` on
+    the strictly lower part."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -672,7 +718,8 @@ def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
         dstate[...] = jnp.zeros_like(dstate)
 
     for p in range(pairs):
-        ch = _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p)
+        ch = _per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, g_scr, p,
+                        _block_diagonal(inv_ref[0, 0, p * c:(p + 1) * c, :]))
         at = _states_at(p)
         entering = [st_ref[0, 0, at[h], :] for h in range(2)]
         rounded = [s.astype(cdt) for s in entering]
@@ -769,6 +816,7 @@ def _delta_calls(bsz: int, t: int, heads: int, hb: int, interpret: bool):
     traced anew by every call, in every program of a job."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -782,7 +830,7 @@ def _delta_calls(bsz: int, t: int, heads: int, hb: int, interpret: bool):
     def specs(reverse):
         """The block specs of a walk over the chunks, first to last or
         last to first: q-wide, beta-like pairs of columns, the entering
-        states."""
+        states, the inverses."""
         def at(ci):
             return nc - 1 - ci if reverse else ci
         return (
@@ -790,30 +838,35 @@ def _delta_calls(bsz: int, t: int, heads: int, hb: int, interpret: bool):
             pl.BlockSpec((1, pairs, c, 2),
                          lambda bi, hi, ci: (bi, hi, at(ci), 0)),
             pl.BlockSpec((1, 1, hb * w, w),
+                         lambda bi, hi, ci: (bi, at(ci), hi, 0)),
+            pl.BlockSpec((1, 1, pairs * c, 2 * c),
                          lambda bi, hi, ci: (bi, at(ci), hi, 0)))
 
     scratch = [pltpu.VMEM((hb * w, w), f32), pltpu.VMEM((2 * c, w), f32)]
 
     @functools.partial(jax.jit, inline=True)
     def forward(q, k, v, g, beta):
-        wide, cols, states = specs(False)
+        wide, cols, states, inverses = specs(False)
         return pl.pallas_call(
             functools.partial(_delta_fwd_kernel, pairs=pairs),
             grid=(bsz, blocks, nc),
             in_specs=[wide, wide, wide, wide, cols],
-            out_specs=[wide, states],
+            out_specs=[wide, states, inverses],
             out_shape=[jax.ShapeDtypeStruct((bsz, t, heads * w), f32),
-                       jax.ShapeDtypeStruct((bsz, nc, heads * w, w), f32)],
+                       jax.ShapeDtypeStruct((bsz, nc, heads * w, w), f32),
+                       jax.ShapeDtypeStruct(
+                           (bsz, nc, heads // 2 * c, 2 * c), f32)],
             scratch_shapes=scratch, compiler_params=params,
             interpret=interpret, name="kda_delta_fwd")(q, k, v, g, beta)
 
     @functools.partial(jax.jit, inline=True)
-    def backward(q, k, v, g, beta, entering, d_o):
-        wide, cols, states = specs(True)
+    def backward(q, k, v, g, beta, entering, inverses, d_o):
+        wide, cols, states, inverse_blocks = specs(True)
         return pl.pallas_call(
             functools.partial(_delta_bwd_kernel, pairs=pairs),
             grid=(bsz, blocks, nc),
-            in_specs=[wide, wide, wide, wide, cols, states, wide],
+            in_specs=[wide, wide, wide, wide, cols, states, inverse_blocks,
+                      wide],
             out_specs=[wide, wide, wide, wide, cols],
             out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                        jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -822,19 +875,22 @@ def _delta_calls(bsz: int, t: int, heads: int, hb: int, interpret: bool):
                        jax.ShapeDtypeStruct(beta.shape, f32)],
             scratch_shapes=scratch, compiler_params=params,
             interpret=interpret, name="kda_delta_bwd")(
-                q, k, v, g, beta, entering, d_o)
+                q, k, v, g, beta, entering, inverses, d_o)
 
     @jax.custom_vjp
     def rule(q, k, v, g, beta):
         return forward(q, k, v, g, beta)[0]
 
     def rule_fwd(*operands):
-        o, entering = forward(*operands)
-        return o, (operands, entering)
+        # named, the output too: under a policy that saves the name the
+        # layer's backward pass reads all three and not the kernel again
+        o, entering, inverses = checkpoint_name(forward(*operands),
+                                                DELTA_RESIDUALS)
+        return o, (operands, entering, inverses)
 
     def rule_bwd(res, d_o):
-        operands, entering = res
-        return tuple(backward(*operands, entering, d_o))
+        operands, entering, inverses = res
+        return tuple(backward(*operands, entering, inverses, d_o))
 
     rule.defvjp(rule_fwd, rule_bwd)
     return rule

@@ -26,7 +26,11 @@ under which that cell's experts' axis is on no mesh axis, and the text
 held no `ragged-all-to-all` and came out the same from both trees. Since
 PR 64 `step_text` reads `layout.rules` where a configuration states them
 (Mellum2's alone does: the other ten steps are built by the calls they
-were built by) and the pin is of the cell's own program. Each text is
+were built by) and the pin is of the cell's own program. PR 65
+(`ops.kda.DELTA_RESIDUALS`: one more name in `Transformer._remat`'s
+policy, which wraps every layer of every cell, and a third output of the
+delta rule's forward kernel) means to change Ling's program and no other:
+only the pallas rule names anything, so the other ten hold. Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
@@ -74,7 +78,11 @@ MADE_WITH = {"jax": "0.9.0", "libtpu": "0.0.34"}
 # and SDAR's tiles and texts are as they were. PR 64 made Mellum2's anew
 # from its own tree, built under its layout's rules for the first time
 PINS = {
-    "train_ling3flash_ep64_d7": "eee8e09273ef454d",          # PR 62
+    # PR 65's tree: the delta rule's forward kernel writes the chunks'
+    # inverses, `Transformer._remat` keeps them with o and the entering
+    # states and remat's forward holds no `kda_delta_fwd` (36,582 lines;
+    # its parent's, PR 62's pin, eee8e09273ef454d, 36,409)
+    "train_ling3flash_ep64_d7": "878ed1082b2b7da8",
     "train_mistral7b_d2": "dc53d3934bbf1705",
     "train_olmoe_d1": "be5709d03a09969b",
     "train_nemotron3super_ep64_d11": "9c13b178970bd1b8",     # PR 62

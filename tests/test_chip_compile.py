@@ -397,9 +397,11 @@ def test_kda_kernels_fwd_bwd(v5e, b, t, h):
     k, v and g reaching them as the program's arguments hold them, in the
     `[B, T, H·D]` layout of the convolution and the gates (no head-major
     copy before the call), dq, dk, dv leaving in the compute dtype, the
-    entering states `[T/64, H·Dv, D]` float32 the only residual the
-    forward writes, nothing of a sub-block's factors (`[.., 4, 64, 128]`)
-    in the compiled text, and `delta_shape_ok` said yes to what compiled."""
+    entering states `[T/64, H·Dv, D]` and the chunks' inverses
+    `[T/64, H/2·64, 128]`, float32, all the forward writes beside o and
+    both read by the backward (PR 65), nothing of a sub-block's factors
+    (`[.., 4, 64, 128]`) in the compiled text, and `delta_shape_ok` said
+    yes to what compiled."""
     import re
 
     from ray_tpu.ops.kda import (DELTA_CHUNK, delta_shape_ok,
@@ -429,8 +431,12 @@ def test_kda_kernels_fwd_bwd(v5e, b, t, h):
     fwd_out, fwd_in = calls["kda_delta_fwd"]
     bwd_out, bwd_in = calls["kda_delta_bwd"]
     states = f"f32[{b},{t // c},{h * d},{d}]"
+    inverses = f"f32[{b},{t // c},{h // 2 * c},{2 * c}]"
     assert f"f32[{b},{t},{h * d}]" in fwd_out and states in fwd_out
-    assert fwd_out.count("[") == 2, fwd_out             # o and the states
+    assert inverses in fwd_out
+    assert fwd_out.count("[") == 3, fwd_out    # o, the states, the inverses
+    # the backward's operands: the forward's five, the two it kept, do
+    assert bwd_in.count("%") == 8 and fwd_in.count("%") == 5, bwd_in
     assert bwd_out.count(f"bf16[{b},{t},{h * d}]") == 3, bwd_out
     assert bwd_out.count(f"f32[{b},{t},{h * d}]") == 1, bwd_out
     # q, k, v, g as the program's arguments hold them (small ones the

@@ -28,9 +28,11 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     splash takes keys 192 wide beside values 128 wide, unpadded, in blocks
     of 1,024; `megablox` over `row_bound`'s run of 4,096 rows; the delta
     rule is the pallas kernels under `kda/delta` (`kda_delta_impl` says
-    "pallas" for this mesh and these shapes: per KDA layer a
-    `kda_delta_fwd` in the forward, one in remat's forward and a
-    `kda_delta_bwd`) and nothing there is as large as a sub-block's
+    "pallas" for this mesh and these shapes: per KDA layer one
+    `kda_delta_fwd`, whose output, entering states and chunk inverses the
+    layer's remat keeps (`ops.kda.DELTA_RESIDUALS`, PR 65: none in remat's
+    forward), and one `kda_delta_bwd` that reads them, both inside
+    `vmem_limit_bytes`) and nothing there is as large as a sub-block's
     factors; the new scopes are on what the compiler leaves."""
     import re
 
@@ -97,16 +99,28 @@ def test_kda_share_step_compiles_and_fits_the_v5e(v5e):
     assert names == ["gmm"] * 12 + ["splash_mha_dkv_no_residuals",
                                     "splash_mha_fwd_residuals"] \
         + ["tgmm"] * 4, names
-    # two KDA layers: each a forward, remat's forward and a backward of
-    # the delta rule, all under `kda/delta` and nowhere else
+    # two KDA layers: each a forward and a backward of the delta rule,
+    # under `kda/delta` and nowhere else, and no forward in remat's
     under_kda = sorted(
         (re.search(r"kda_delta_(fwd|bwd)", n).group(0),
          "rematted_computation" in op, "transpose(jvp" in op)
         for n, op in kernels if "kda" in n or "kda/" in op)
     assert under_kda == [("kda_delta_bwd", False, True)] * 2 \
-        + [("kda_delta_fwd", False, False)] * 2 \
-        + [("kda_delta_fwd", True, True)] * 2, under_kda
+        + [("kda_delta_fwd", False, False)] * 2, under_kda
     assert all("/kda/delta/" in op for n, op in kernels if "kda" in n)
+    # what the forward kernel writes and the backward one reads: o, the
+    # states entering the 256 chunks and the chunks' inverses, a pair of
+    # heads' two `[64, 64]` blocks side by side, all float32
+    kept = ["f32[1,16384,1024]", "f32[1,256,1024,128]", "f32[1,256,256,128]"]
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*%kda_delta_(fwd|bwd)[\w.]* = ", line)]
+    assert len(calls) == 4
+    for line in calls:
+        result, operands = line.split("custom-call(", 1)
+        if "kda_delta_fwd" in result:
+            assert all(shape in result for shape in kept), line[:400]
+        else:
+            assert all(shape in operands for shape in kept[1:]), line[:400]
     assert not [op for n, op in kernels
                 if "rematted_computation" in op and "splash" in n]
     assert_chosen_scores_read_off_the_selection(hlo, seq, 8, 512)

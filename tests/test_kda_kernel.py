@@ -233,6 +233,117 @@ def test_the_forwards_residual_is_the_state_entering_each_chunk(monkeypatch):
                  "the entering states")
 
 
+def flat_operands(q, k, v, g, beta):
+    """The kernels' own operands: `[B, T, H·D]` and beta's two columns a
+    pair of heads (`gated_delta_rule_pallas`'s turn)."""
+    b, t, h, d = q.shape
+    return (*(a.reshape(b, t, h * d) for a in (q, k, v, g)),
+            jnp.swapaxes(beta.reshape(b, t, h // 2, 2), 1, 2))
+
+
+def kernels_in(jaxpr):
+    """name -> the primitives of each pallas call's body in a jaxpr."""
+    return {eqn.params["name"]: [e.primitive.name
+                                 for e in eqn.params["jaxpr"].eqns]
+            for eqn in jaxpr.eqns if eqn.primitive.name == "pallas_call"}
+
+
+def whole_inverses(q, k, v, g, beta):
+    """`_per_chunk`'s `inv` of every chunk of ONE pair of heads, whole:
+    `[B, T/C, 128, 128]` float32, by a kernel of this file that calls it
+    as the delta rule's own kernels do."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, pair = beta.shape[0], beta.shape[2], 2 * CHUNK
+
+    def kernel_body(q_ref, k_ref, v_ref, g_ref, beta_ref, out_ref, g_scr):
+        out_ref[0, 0] = kda._per_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                                       g_scr, 0).inv
+
+    wide = pl.BlockSpec((1, CHUNK, 2 * D), lambda bi, ci: (bi, ci, 0))
+    return pl.pallas_call(
+        kernel_body, grid=(b, t // CHUNK),
+        in_specs=[wide] * 4 + [pl.BlockSpec((1, 1, CHUNK, 2),
+                                            lambda bi, ci: (bi, 0, ci, 0))],
+        out_specs=pl.BlockSpec((1, 1, pair, pair),
+                               lambda bi, ci: (bi, ci, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, t // CHUNK, pair, pair),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((pair, D), jnp.float32)],
+        interpret=True)(q, k, v, g, beta)
+
+
+def test_the_forward_keeps_each_chunks_inverse():
+    """The forward kernel's third output: a pair's two `[64, 64]` diagonal
+    blocks of `_per_chunk`'s `[128, 128]` inverse, side by side, float32
+    and bit for bit; what lies between the heads, which is not kept, is
+    zero; and it is the inverse the XLA path makes of the same chunk."""
+    args, _ = delta_inputs(6, 2, 192, 2)
+    flat = flat_operands(*args)
+    b, t, h = args[4].shape
+    _, (_, _, kept) = kda._delta_calls(b, t, h, 2, True).fwd(*flat)
+    assert kept.dtype == jnp.float32
+    assert kept.shape == (b, t // CHUNK, h // 2 * CHUNK, 2 * CHUNK)
+    whole = np.asarray(whole_inverses(*flat))
+    np.testing.assert_array_equal(kept[..., :CHUNK],
+                                  whole[..., :CHUNK, :CHUNK])
+    np.testing.assert_array_equal(kept[..., CHUNK:],
+                                  whole[..., CHUNK:, CHUNK:])
+    assert not whole[..., :CHUNK, CHUNK:].any()
+    assert not whole[..., CHUNK:, :CHUNK].any()
+    # the XLA path's, from `_channel_blocks`: [B, H, nc, c, c]
+    q, k, _, g, beta = args
+
+    def chunks(a):
+        return jnp.moveaxis(a.reshape(b, t // CHUNK, CHUNK, h, -1), 3, 1)
+
+    kc = chunks(kda.l2norm(k))
+    a_mat, _ = kda._channel_blocks(chunks(kda.l2norm(q)), kc,
+                                   jnp.cumsum(chunks(g), axis=3))
+    strict = jnp.tril(jnp.ones((CHUNK, CHUNK), bool), -1)
+    want = kda._unit_lower_inverse(
+        -chunks(beta[..., None]) * jnp.where(strict, a_mat, 0.0))
+    for head in range(h):
+        assert_close(kept[..., head * CHUNK:(head + 1) * CHUNK],
+                     want[:, head], f"head {head}'s inverses", 1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_backward_handed_the_inverse_is_the_one_that_makes_it(
+        monkeypatch, dtype):
+    """`kda_delta_bwd` reads the inverse the forward wrote where it made
+    one by doubling (`_per_chunk(..., inv)`): its body holds no loop, the
+    forward's holds one a pair, and its five outputs are those of the
+    backward that makes the inverse again, bit for bit."""
+    args, probe = delta_inputs(7, 1, 256, 4, dtype)
+    flat = flat_operands(*args)
+    d_o = 1e-6 * probe.reshape(flat[0].shape)
+    made, per_chunk, handed = kda._delta_calls.__wrapped__, kda._per_chunk, []
+
+    def gradients():
+        rule = made(1, 256, 4, kda.DELTA_HEADS, True)
+        jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(rule, *a)[1](d_o))(*flat)
+        return jax.vjp(rule, *flat)[1](d_o), kernels_in(jaxpr.jaxpr)
+
+    got, bodies = gradients()
+    assert bodies["kda_delta_fwd"].count("scan") == kda.DELTA_HEADS // 2
+    assert "scan" not in bodies["kda_delta_bwd"]
+
+    def parents(*operands):          # the chunk with the inverse left out
+        handed.append(len(operands) == 8)
+        return per_chunk(*operands[:7])
+
+    monkeypatch.setattr(kda, "_per_chunk", parents)
+    want, bodies = gradients()
+    assert any(handed)
+    assert bodies["kda_delta_bwd"].count("scan") == kda.DELTA_HEADS // 2
+    for name, g, w in zip(GRADS, got, want):
+        assert g.dtype == w.dtype and np.asarray(w).any(), name
+        np.testing.assert_array_equal(g, w, err_msg="d" + name)
+
+
 # bfloat16 q, k and v (and so bfloat16 dq, dk, dv), float32 gates, sums,
 # blocks, inverse and state: the two paths round the same operands of the
 # same products (T, T beta V, T beta K e^G, U, the state), so each is held

@@ -21,7 +21,7 @@ import pytest
 from jax.extend import core as jex_core
 
 from ray_tpu.models import TINY, Transformer, TransformerConfig
-from ray_tpu.ops import moe
+from ray_tpu.ops import kda, moe
 
 SEQ = 256
 BASE = dict(vocab_size=128, d_model=32, n_heads=2, d_ff=16, max_seq_len=SEQ,
@@ -232,9 +232,13 @@ def test_what_is_named_is_what_the_layer_has(programs, kind):
         0 < cfg.moe_experts_held < cfg.moe_top_k)
 
 
-def test_a_dense_layer_names_nothing_and_its_program_stays(monkeypatch):
-    """No router, nothing named: the default policy with the routing's
-    name in it lowers to the text of the policy without, and of "full"."""
+@pytest.mark.parametrize("name", [moe.ROUTING_RESIDUALS,
+                                  kda.DELTA_RESIDUALS])
+def test_a_dense_layer_names_nothing_and_its_program_stays(monkeypatch,
+                                                           name):
+    """No router and no delta rule, nothing named: the default policy
+    with the routing's name in it, or the delta rule's (PR 65), lowers to
+    the text of the policy without, and of "full"."""
     cfg = TINY.replace(remat=True, attention_impl="dense")
     params = Transformer.init(jax.random.key(0), cfg)
     batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
@@ -252,11 +256,11 @@ def test_a_dense_layer_names_nothing_and_its_program_stays(monkeypatch):
     saved = []
     names = jax.checkpoint_policies.save_only_these_names
 
-    def without_routing(*given):
+    def without_the_name(*given):
         saved.append(given)
-        return names(*(n for n in given if n != moe.ROUTING_RESIDUALS))
+        return names(*(n for n in given if n != name))
 
     monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
-                        without_routing)
+                        without_the_name)
     assert lowered(cfg) == with_name
-    assert moe.ROUTING_RESIDUALS in saved[-1]      # the policy did ask
+    assert name in saved[-1]                       # the policy did ask
