@@ -38,7 +38,16 @@ through the SAME layers, the final norm closing every pass, with one head
 and (`exit_gate`) one exit gate after every pass and the loss an
 expectation over the pass a token leaves at (`Transformer.loss`); its
 layers stand under a sandwich norm, `x + norm(f(norm(x)))`
-(`norm_placement` "both": where the homogeneous stack's norms sit).
+(`norm_placement` "both": where the homogeneous stack's norms sit). A
+residual path of several streams (`residual_streams` = n > 1;
+manifold-constrained hyper-connections, arXiv 2512.24880 after 2409.19606)
+carries n copies of the stream from the embedding to the final norm: every
+sublayer reads one mix of them and writes back through two more, the three
+maps computed from the stream itself, the n x n one made doubly stochastic
+by `hc_sinkhorn_iters` Sinkhorn rounds (ops/mhc.py). YaRN on latent
+attention is DeepSeek-V3's reading (`rope_yarn_mscale_all_dim`): a factor
+on the WHOLE softmax scale, all of a head's columns, and none on the
+rotary tables, which touch 64 of 192 of them.
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -302,6 +311,33 @@ class TransformerConfig:
     loops: int = 1
     exit_gate: bool = False
     exit_entropy_coeff: float = 0.05
+    # A residual path of several streams (`hc_mult`; ops/mhc.py). 1: the
+    # one stream `x + f(norm(x))` of every other model, and nothing of
+    # this is traced. n > 1: the embedding is repeated into n streams
+    # X [n, d] a token, and a sublayer with its own phi [n*d, n*n + 2n],
+    # b [n*n + 2n] and alpha [3] forms, in float32, u = vec(X) /
+    # sqrt(mean(vec(X)^2) + norm_eps) (one statistic over all n*d values,
+    # no gain), m = u phi, H_pre = sigmoid(alpha_0 m[:n] + b[:n]),
+    # H_post = 2 sigmoid(alpha_1 m[n:2n] + b[n:2n]) and H_res from
+    # A = clamp(alpha_2 mat(m[2n:]) + b[2n:], -hc_res_clamp, hc_res_clamp)
+    # (row-major) by M = exp(A) and hc_sinkhorn_iters times M = M /
+    # (rowsum + hc_eps), M = M / (colsum + hc_eps); it reads
+    # h = sum_i H_pre[i] X[i] under its own norm and leaves
+    # X'[i] = sum_j H_res[i, j] X[j] + H_post[i] f(norm(h)). The streams
+    # are summed before the final norm. The homogeneous stack's (with its
+    # leading dense run) under dense or flash attention; a layer_pattern,
+    # `loops` above 1, `block_length`, ring, ulysses and `pipeline_loss`
+    # refuse it by name until someone needs them.
+    residual_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # YaRN as DeepSeek-V3's family reads `mscale_all_dim`: the softmax
+    # scale head_dim^-1/2 times (0.1 * this * ln(rope_yarn_factor) + 1)^2,
+    # over ALL of a head's columns (0: the scale as it is). With `mscale`
+    # = `mscale_all_dim` the rotary tables stay unscaled:
+    # rope_yarn_attention_factor 1.0.
+    rope_yarn_mscale_all_dim: float = 0.0
 
     def __post_init__(self):
         if self.norm not in ("rms", "layernorm"):
@@ -409,6 +445,24 @@ class TransformerConfig:
                 "a looped stack (loops above 1) is the homogeneous dense "
                 "layer under dense or flash attention: no layer_pattern, "
                 "no experts, no block diffusion, no ring or ulysses")
+        if self.residual_streams < 1 or self.hc_sinkhorn_iters < 0:
+            raise ValueError("residual_streams is 1 or above, "
+                             "hc_sinkhorn_iters 0 or above")
+        if self.residual_streams > 1:
+            for what, has in (
+                    ("a layer_pattern", self.layer_pattern),
+                    ("a looped stack (loops above 1)", self.loops > 1),
+                    ("block diffusion (block_length)", self.block_length),
+                    ("ring or ulysses attention",
+                     self.attention_impl in ("ring", "ulysses"))):
+                if has:
+                    raise ValueError(
+                        f"a residual path of several streams "
+                        f"(residual_streams above 1) does not run under "
+                        f"{what} yet")
+        if self.rope_yarn_mscale_all_dim and not self.rope_yarn_factor:
+            raise ValueError("rope_yarn_mscale_all_dim scales the softmax "
+                             "under YaRN: it needs rope_yarn_factor")
         if self.block_length and (
                 self.attention_impl in ("ring", "ulysses")
                 or self.layer_pattern or self.attn_window):
@@ -482,6 +536,29 @@ class TransformerConfig:
             0.1 * math.log(self.rope_yarn_factor) + 1.0)
 
     @property
+    def softmax_scale(self) -> float:
+        """What attention's scores are multiplied by: head_dim^-1/2, under
+        `rope_yarn_mscale_all_dim` times YaRN's factor squared."""
+        scale = self.head_dim ** -0.5
+        if self.rope_yarn_mscale_all_dim:
+            scale *= (0.1 * self.rope_yarn_mscale_all_dim
+                      * math.log(self.rope_yarn_factor) + 1.0) ** 2
+        return scale
+
+    @property
+    def hc_maps(self) -> int:
+        """Numbers a sublayer's three maps hold a token: n, n and n x n."""
+        n = self.residual_streams
+        return n * n + 2 * n
+
+    @property
+    def _hc_params(self) -> int:
+        """A sublayer's phi, b and its three alphas (0 on one stream)."""
+        if self.residual_streams == 1:
+            return 0
+        return (self.residual_streams * self.d_model + 1) * self.hc_maps + 3
+
+    @property
     def mask_token(self) -> int:
         """The id a noised token is replaced by."""
         return self.mask_token_id % self.vocab_size
@@ -534,7 +611,9 @@ class TransformerConfig:
                 else nh * hd + nkv * hd
         if self.layer_pattern:
             return self._pattern_params(attn)
-        norms = (4 if self.norm_placement == "both" else 2) * d
+        # a sublayer's norms and, on several streams, its maps' leaves
+        norms = (4 if self.norm_placement == "both" else 2) * d \
+            + 2 * self._hc_params
         mlp = 3 * d * f
         dense = 0
         if self.moe_experts:

@@ -29,8 +29,13 @@ OLMo-2/3's reordered norm (the kinds `d` and `a`) `x + norm(f(x))`, the
 leaves holding `<name>_post_norm` in its place, and under a sandwich norm
 `x + norm(f(norm(x)))`, the leaves holding both
 (`TransformerConfig.norm_placement` says which the homogeneous stack's
-have). A looped stack (`cfg.loops` > 1) passes the stream that many
-times through the same stacked leaves, the final norm closing every pass
+have), and on a residual path of several streams
+(`cfg.residual_streams` = n > 1, the leaves holding `<name>_hc_phi`,
+`_hc_b`, `_hc_alpha`: ops/mhc.py) the stream is `[B, T, n, d]` from
+`embed` to the final norm and the sublayer leaves
+`H_res X + H_post (x) f(norm(sum_i H_pre[i] X[i]))`, the three maps made of
+the stream itself. A looped stack (`cfg.loops` > 1) passes the stream that
+many times through the same stacked leaves, the final norm closing every pass
 (`_looped_hidden`), and where `params` hold `exit_gate` each pass's
 hidden state gives one f32 scalar a token, the exit gate's. What the
 leaves cannot say the layer's kind in `layer_pattern` does: a window, and
@@ -77,6 +82,13 @@ weighting); a block-diffusion model's loss adds
 `diffusion/noise` (the draws, the replacement, the weights and their
 counts: models/diffusion.py) and `diffusion/stream` (the doubled
 stream's concatenation and positions, the split before the final norm);
+a residual path of several streams adds `mhc/maps` (a
+sublayer's RMS statistic over all n*d values, the product with phi, the
+sigmoids, the Sinkhorn rounds), `mhc/pre` (what the sublayer reads),
+`mhc/post` (what it leaves, inside the scope that closes the sublayer:
+`attn_out/mhc/post`, `mlp/down/mhc/post`, `moe/combine/mhc/post`),
+`mhc/expand` and `mhc/collapse` (the entry after `embed`, the exit before
+`final_norm`: ops/mhc.py);
 the train step adds `optimizer`
 (parallel/train_step.py). Scopes are metadata only. Forward, backward
 and recomputation need none: JAX wraps the path in `jvp(...)`,
@@ -216,6 +228,9 @@ def _placed(sub, placement):
 # the tensors of `_stack`'s `shared` each kind of layer makes
 MAKES = {"s": ("memory",), "f": ("k", "v")}
 
+# the logical axes of a residual path of several streams, [B, T, n, d]
+STREAMS = ("batch", "seq", None, "act_embed")
+
 
 class Transformer:
     """Namespace for init / param_specs / apply / loss."""
@@ -223,6 +238,8 @@ class Transformer:
     # ---- parameter construction ------------------------------------
     @staticmethod
     def init(key, cfg: TransformerConfig) -> Dict[str, Any]:
+        import math
+
         import jax
         import jax.numpy as jnp
 
@@ -458,6 +475,25 @@ class Transformer:
                                        (l, h_, dv, d)),
             }
 
+        def streams(l, key):
+            """One run of l layers' hyper-connection leaves (ops/mhc.py),
+            both sublayers': the published kind of start, every map all
+            but static (alpha 0.01) and the layer the one-stream layer on
+            n equal streams: H_pre 1/n, H_post 1, H_res near the
+            identity (off the diagonal e^-8 before the rounds)."""
+            n, maps = cfg.residual_streams, cfg.hc_maps
+            eye = jnp.where(jnp.eye(n, dtype=bool), 0.0, -8.0).reshape(-1)
+            b = jnp.concatenate([jnp.full((n,), -math.log(n - 1)),
+                                 jnp.zeros((n,)), eye]).astype(pdt)
+            leaves = {}
+            for i, name in enumerate(("attn", "mlp")):
+                leaves[name + "_hc_phi"] = norm_init(
+                    (n * d) ** -0.5, jax.random.fold_in(key, 90 + i),
+                    (l, n * d, maps))
+                leaves[name + "_hc_b"] = jnp.broadcast_to(b, (l, maps))
+                leaves[name + "_hc_alpha"] = jnp.full((l, 3), 0.01, pdt)
+            return leaves
+
         def with_mlp(sub, l, keys):
             """A lower-case kind: the sublayer, then a dense MLP."""
             sub["mlp_norm"] = jnp.ones((l, d), pdt)
@@ -535,6 +571,8 @@ class Transformer:
                 layers.update(experts(l, key, keys))
             else:
                 layers["w_gateup"], layers["w_down"] = gated(keys, (l,), f)
+            if cfg.residual_streams > 1:
+                layers.update(streams(l, key))
             params["layers"] = _placed(layers, cfg.norm_placement)
         if cfg.exit_gate:
             # the exit gate: a gain over the normed hidden state, a bias
@@ -546,6 +584,9 @@ class Transformer:
             dense = attention(cfg.moe_dense_layers, lead)
             dense["w_gateup"], dense["w_down"] = gated(
                 lead, (cfg.moe_dense_layers,), cfg.moe_dense_ff or f)
+            if cfg.residual_streams > 1:
+                dense.update(streams(cfg.moe_dense_layers,
+                                     jax.random.fold_in(key, 96)))
             params["dense_layers"] = _placed(dense, cfg.norm_placement)
         if not cfg.tie_embeddings:
             params["lm_head"] = norm_init(
@@ -597,6 +638,12 @@ class Transformer:
 
         dense_ffn = {"w_gateup": ("layers", "embed", None, "mlp"),
                      "w_down": ("layers", "mlp", "embed")}
+        # the hyper-connections' leaves of both sublayers (ops/mhc.py)
+        streams = {} if cfg.residual_streams == 1 else {
+            name + leaf: spec for name in ("attn", "mlp")
+            for leaf, spec in (("_hc_phi", ("layers", "embed", None)),
+                               ("_hc_b", ("layers", None)),
+                               ("_hc_alpha", ("layers", None)))}
         # Kimi Delta Attention's heads are not sharded here
         kda = {"kda_norm": ("layers", "norm"),
                "w_kda_qkv": ("layers", "embed", None, None, None),
@@ -700,13 +747,15 @@ class Transformer:
         else:
             layers = attention()
             layers.update(experts() if cfg.moe_experts else dense_ffn)
-            specs["layers"] = _placed(layers, cfg.norm_placement)
+            specs["layers"] = _placed(dict(layers, **streams),
+                                      cfg.norm_placement)
         if cfg.exit_gate:
             specs["exit_gate"] = ("norm",)
             specs["exit_gate_bias"] = (None,)
         if cfg.moe_dense_layers:
-            specs["dense_layers"] = _placed(dict(attention(), **dense_ffn),
-                                            cfg.norm_placement)
+            specs["dense_layers"] = _placed(
+                dict(attention(), **dense_ffn, **streams),
+                cfg.norm_placement)
         if not cfg.tie_embeddings:
             specs["lm_head"] = ("embed", "vocab")
         return specs
@@ -735,7 +784,10 @@ class Transformer:
     @staticmethod
     def embed(params, tokens, cfg: TransformerConfig, *,
               mesh=None, rules: Optional[ShardingRules] = None):
-        """tokens [B, T] int32 -> embeddings [B, T, d] (compute dtype)."""
+        """tokens [B, T] int32 -> embeddings [B, T, d] (compute dtype); on
+        a residual path of several streams (`cfg.residual_streams` = n >
+        1) the embedding repeated into every stream, [B, T, n, d]
+        (`ops/mhc.expand`, under `mhc/expand`)."""
         import jax
         import jax.numpy as jnp
 
@@ -754,7 +806,11 @@ class Transformer:
         with jax.named_scope("embed"):
             emb = constrain(params["embed"], ("vocab", "act_embed"))
             x = jnp.take(emb, tokens, axis=0).astype(jnp.dtype(cfg.dtype))
-            return constrain(x, ("batch", "seq", "act_embed"))
+            x = constrain(x, ("batch", "seq", "act_embed"))
+        if cfg.residual_streams > 1:
+            from ray_tpu.ops import mhc
+            x = constrain(mhc.expand(x, cfg.residual_streams), STREAMS)
+        return x
 
     @staticmethod
     def _remat(layer, cfg: TransformerConfig):
@@ -788,7 +844,14 @@ class Transformer:
         8 Dv + 256) bytes (201 + 33.5 MB at 16,384 tokens of 8 heads of
         128) more than "full", which saves nothing but the carry and is
         there for whoever needs those bytes. "dots" saves every matmul's
-        output besides."""
+        output besides. The carry is the stream between layers: B*T*d x 2
+        bytes a layer in bf16, and on a residual path of n streams
+        (`cfg.residual_streams`) n times that, `[B, T, n, d]` (235 MB a
+        layer at 8,192 tokens of 4 x 3,584), kept a LAYER and not a
+        sublayer; a sublayer's maps (`[B, T, n*n + 2n]` f32, 0.8 MB there)
+        are not named: the backward makes them again from the recomputed
+        stream, the statistic, the product with phi and the 20 rounds
+        among remat's forward."""
         import jax
 
         from ray_tpu.ops.attention import FLASH_RESIDUALS
@@ -917,6 +980,14 @@ class Transformer:
         exit gate's f32 `z` [R, B, T], None where `params` hold no
         `exit_gate` (`_looped_hidden`).
 
+        A residual path of several streams (`cfg.residual_streams` = n >
+        1) runs the layers on [B, T, n, d] and sums the streams (in
+        float32, `ops/mhc.collapse` under `mhc/collapse`) before the final
+        norm, and with_aux=True gives a fourth, the dict `maps` (f32
+        [layers, 2, B, T, n*n + 2n]: every sublayer's H_pre, H_post and
+        H_res row by row, `ops/mhc.maps_by_token`) and `gain` (f32: the
+        RMS of the summed streams / n over the RMS of the embedding).
+
         When `mesh` is provided and cfg.attention_impl is ring/ulysses, the
         attention op runs inside shard_map over the "seq" axis; everything
         else is GSPMD via logical sharding constraints.
@@ -929,10 +1000,26 @@ class Transformer:
         if cfg.loops > 1:
             return Transformer._looped_hidden(
                 params, x, cfg, mesh, rules, positions, with_aux)
+        wide = cfg.residual_streams > 1
+        maps = []   # on several streams, run by run: the sublayers' maps
+
+        def without_maps(record):
+            """A run's record as one stream's run gives it."""
+            if not wide:
+                return record
+            record = dict(record)
+            maps.append(record.pop("mhc_maps"))
+            return record or None
+
+        if wide:
+            from ray_tpu.ops import mhc
+            with jax.named_scope("mhc/expand"):
+                entered = jnp.mean(jnp.square(x[:, :, 0].astype(jnp.float32)))
         if "dense_layers" in params:   # the leading run with a dense FFN
-            x = Transformer._stack(
+            x, found, _ = Transformer._stack(
                 params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
-                positions=positions, noised=noised)[0]
+                positions=positions, noised=noised)
+            without_maps(found)
         if "runs" in params:
             records = []   # per run and expert sublayer: [repeats, ...]
             shared = {}    # the tensors that cross layers (`_stack`)
@@ -950,6 +1037,14 @@ class Transformer:
             x, routing, _ = Transformer._stack(
                 params["layers"], x, cfg, mesh=mesh, rules=rules,
                 positions=positions, noised=noised)
+            routing = without_maps(routing)
+        if wide:
+            x = mhc.collapse(x)
+            with jax.named_scope("mhc/collapse"):
+                left = jnp.mean(jnp.square(
+                    x.astype(jnp.float32) / cfg.residual_streams))
+                streams = {"maps": jnp.concatenate(maps),
+                           "gain": jnp.sqrt(left / entered)}
         aux_total = jnp.zeros((), jnp.float32)
         if cfg.moe_experts and cfg.moe_scoring == "softmax" \
                 and cfg.held_experts == cfg.moe_experts:
@@ -966,6 +1061,8 @@ class Transformer:
                 x = x[:, :noised]
         with jax.named_scope("final_norm"):
             out = _norm(x, params, "final_norm", cfg.norm_eps)
+        if with_aux and wide:
+            return out, aux_total, routing, streams
         if with_aux:
             return out, aux_total, routing
         return out
@@ -1028,8 +1125,15 @@ class Transformer:
         a gated memory unit under `gmu_norm`, a Gated DeltaNet mixer under
         `gdn_norm`, an FFN or experts under `mlp_norm`, each
         `x + f(norm(x))`, or `x + norm(f(x))` where the leaves hold
-        `<name>_post_norm` instead (`entering`, `residual`). `routing` is
-        the MoE layer's record (ops/moe.py `moe_ffn`), None without one;
+        `<name>_post_norm` instead, or the sandwich with both, or, the
+        fourth form of the one rule, on n streams (the leaves hold
+        `<name>_hc_phi`: x is `[B, T, n, d]`) `H_res X + H_post (x)
+        f(norm(sum_i H_pre[i] X[i]))` with the sublayer's three maps made
+        once, in `entering`, and handed to `residual` (`entering`,
+        `residual`; no sublayer function knows of the streams). `routing`
+        is the MoE layer's record (ops/moe.py `moe_ffn`), None without
+        one, on n streams with `mhc_maps` f32 [sublayers, B, T, n*n + 2n]
+        beside it (`ops/mhc.maps_by_token`);
         `shared` the tensors earlier layers made for this one, `made` what this layer
         makes for later ones (`MAKES[kind]`), `kind` the layer's character
         in `layer_pattern` (None outside one). cos and sin are None where
@@ -1047,12 +1151,32 @@ class Transformer:
         window_fn = Transformer._make_attention(
             cfg, mesh, rules, seq_len=seq_len, window=cfg.attn_window) \
             if cfg.attn_window else None
-        scale = cfg.head_dim ** -0.5
+        scale = cfg.softmax_scale
+        # on several streams: the maps `entering` made of the stream, by
+        # sublayer, until `residual` takes them; and every sublayer's maps
+        # by token, for the layer's record
+        open_maps, layer_maps = {}, []
 
         def entering(x, lp, name):
             """What the sublayer `name` reads: `norm(x)` under the norm
             that opens it, the stream itself where its leaves have none
-            (the reordered norm)."""
+            (the reordered norm). Where its leaves hold `<name>_hc_phi`, x
+            is n streams [B, T, n, d]: the sublayer's three maps are made
+            of it here, once (`ops/mhc.stream_maps`), what is normed is
+            the streams' mix under H_pre, and `residual` is handed the
+            other two."""
+            if name + "_hc_phi" in lp:
+                from ray_tpu.ops import mhc
+                pre, post, res = mhc.stream_maps(
+                    x, lp[name + "_hc_phi"], lp[name + "_hc_b"],
+                    lp[name + "_hc_alpha"], rounds=cfg.hc_sinkhorn_iters,
+                    norm_eps=cfg.norm_eps, hc_eps=cfg.hc_eps,
+                    clamp=cfg.hc_res_clamp)
+                open_maps[name] = (post, res)
+                with jax.named_scope("mhc/maps"):
+                    layer_maps.append(mhc.maps_by_token(pre, post, res))
+                x = constrain(mhc.read(x, pre),
+                              ("batch", "seq", "act_embed"))
             if name + "_norm" not in lp:
                 return x
             with jax.named_scope(name + "_norm"):
@@ -1061,13 +1185,19 @@ class Transformer:
         def residual(x, out, lp, name):
             """The stream after the sublayer `name`, `out` what it made of
             `entering(x, lp, name)`: `x + out`, `out` under the norm on
-            the sublayer's output where its leaves have one. With
-            `entering`, the one rule of how a residual is formed."""
+            the sublayer's output where its leaves have one; on n streams
+            (the maps `entering` left for it) `H_res X + H_post out`
+            (`ops/mhc.write`). With `entering`, the one rule of how a
+            residual is formed."""
             out = constrain(out, ("batch", "seq", "act_embed"))
             post = name + "_post_norm"
             if post in lp:
                 with jax.named_scope(post):
                     out = _norm(out, lp, post, cfg.norm_eps)
+            if name in open_maps:
+                from ray_tpu.ops import mhc
+                return constrain(mhc.write(x, out, *open_maps.pop(name)),
+                                 STREAMS)
             return x + out
 
         def heads_constrained(q, k, v):
@@ -1337,6 +1467,8 @@ class Transformer:
 
         def layer(x, lp, shared=None, kind=None):
             routing, made = None, {}
+            open_maps.clear()
+            layer_maps.clear()
 
             def has(name):   # the sublayer's norm, before it or after
                 return name + "_norm" in lp or name + "_post_norm" in lp
@@ -1357,6 +1489,8 @@ class Transformer:
                 x = gmu(x, lp, shared["memory"])
             if has("mlp"):
                 x, routing = ffn(x, lp)
+            if layer_maps:   # [sublayers, B, T, n*n + 2n] f32
+                routing = dict(routing or {}, mhc_maps=jnp.stack(layer_maps))
             return x, routing, made
 
         return layer
@@ -1411,6 +1545,11 @@ class Transformer:
         if cfg.loops > 1:
             raise ValueError("pipeline_loss runs its stages once: a looped "
                              "stack (loops above 1) trains through "
+                             "Transformer.loss")
+        if cfg.residual_streams > 1:
+            raise ValueError("pipeline_loss passes one stream between its "
+                             "stages: a residual path of several streams "
+                             "(residual_streams above 1) trains through "
                              "Transformer.loss")
         if cfg.moe_experts or cfg.layer_pattern:
             raise ValueError(
@@ -1621,7 +1760,12 @@ class Transformer:
         (int32 [expert layers, shards]; `ops/moe._exchange_ffn`),
         `moe_rows_received`, `moe_exchange_rows_sent`,
         `moe_exchange_rows_needed`, `moe_exchange_pairs` and
-        `moe_exchange_bounded`; an empty dict for a dense config."""
+        `moe_exchange_bounded`; an empty dict for a dense config. On a
+        residual path of several streams also `mhc_res_marginal_err` (f32:
+        the largest |rowsum - 1| and |colsum - 1| of H_res over tokens and
+        sublayers; under 1e-3 after 20 rounds or the step is unsound) and
+        `mhc_stream_gain` (f32: the RMS of the summed streams / n over the
+        RMS of the embedding, what the constraint is there to bound)."""
         import jax
         import jax.numpy as jnp
 
@@ -1634,9 +1778,10 @@ class Transformer:
                                           with_metrics)
         tokens, targets = Transformer._tokens_and_targets(batch)
         mask = batch.get("mask")
-        # a looped stack hands its gate's z (None here) as a fourth
-        x, aux, routing = Transformer.hidden(
-            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)[:3]
+        # a looped stack hands its gate's z (None here) as a fourth,
+        # several streams their maps and gain
+        x, aux, routing, *streams = Transformer.hidden(
+            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
         if cfg.loops > 1:   # no gate: the last pass alone is trained
             x = x[-1]
         total = head.nll_sum(head.weight(params, cfg), x, targets, cfg,
@@ -1646,8 +1791,16 @@ class Transformer:
                                 else jnp.maximum(jnp.sum(mask), 1.0))
         if cfg.moe_experts and cfg.moe_scoring == "softmax":
             loss_val = loss_val + cfg.moe_aux_coeff * aux
-        return Transformer._loss_out(loss_val, aux, routing, cfg,
-                                     with_metrics)
+        out = Transformer._loss_out(loss_val, aux, routing, cfg,
+                                    with_metrics)
+        if with_metrics and cfg.residual_streams > 1:
+            from ray_tpu.ops import mhc
+            with jax.named_scope("mhc/maps"):
+                out[1].update(
+                    mhc_res_marginal_err=mhc.marginal_error(
+                        streams[0]["maps"], cfg.residual_streams),
+                    mhc_stream_gain=streams[0]["gain"])
+        return out
 
     @staticmethod
     def block_diffusion_hidden(params, batch, cfg: TransformerConfig, *,

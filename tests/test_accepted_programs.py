@@ -30,14 +30,20 @@ were built by) and the pin is of the cell's own program. PR 65
 (`ops.kda.DELTA_RESIDUALS`: one more name in `Transformer._remat`'s
 policy, which wraps every layer of every cell, and a third output of the
 delta rule's forward kernel) means to change Ling's program and no other:
-only the pallas rule names anything, so the other ten hold. Each text is
+only the pallas rule names anything, so the other ten hold. PR 66
+(Xing4.0-29B-A4B: a residual path of several streams in `entering` /
+`residual`, `embed` and `hidden`, a multiplier on latent attention's
+softmax scale) left all eleven as they were, GLM's and Ling's first
+(`latent_qkv`, the softmax scale), then d2's, Olmo-Hybrid's and Ouro's
+(`entering` / `residual`, `_remat`, `embed`, `hidden`), and pins its own
+cell: with `residual_streams` 1 nothing of it is traced. Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
 took 259 s; what had run before it in that process is the one difference,
 not looked into further), and a pin must not depend on what ran before it. d2 (the dense MLP, splash, `_remat`, the
-chunked head, adamw: 15 s) runs with the suite; the other ten, Ling's 75 s
-first, are `slow`: `pytest tests/test_accepted_programs.py -m slow`, 7 min.
+chunked head, adamw: 15 s) runs with the suite; the other eleven, Ling's 75 s
+first, are `slow`: `pytest tests/test_accepted_programs.py -m slow`, 9 min.
 
 A pin is the compiler's text: it holds for the jax and libtpu that made it
 (`MADE_WITH`) and the test skips under another. A PR that means to change
@@ -97,6 +103,8 @@ PINS = {
     "train_olmohybrid7b_tp2_d4": "1ebd73113b090dc6",
     # PR 63's own cell, pinned from its own tree (4,739 lines)
     "train_ouro26b_d8": "079113b2a5d63a41",
+    # PR 66's own cell, pinned from its own tree (47,363 lines)
+    "train_xing4_ep8_d5": "907116026b0cc671",
 }
 WITH_THE_SUITE = ("train_mistral7b_d2",)
 
