@@ -31,8 +31,8 @@ leaves holding `<name>_post_norm` in its place, and under a sandwich norm
 (`TransformerConfig.norm_placement` says which the homogeneous stack's
 have), and on a residual path of several streams
 (`cfg.residual_streams` = n > 1, the leaves holding `<name>_hc_phi`,
-`_hc_b`, `_hc_alpha`: ops/mhc.py) the stream is `[B, T, n, d]` from
-`embed` to the final norm and the sublayer leaves
+`_hc_b`, `_hc_alpha`: ops/mhc.py) the stream is the n streams side by
+side, `[B, T, n*d]`, from `embed` to the final norm and the sublayer leaves
 `H_res X + H_post (x) f(norm(sum_i H_pre[i] X[i]))`, the three maps made of
 the stream itself. A looped stack (`cfg.loops` > 1) passes the stream that
 many times through the same stacked leaves, the final norm closing every pass
@@ -228,7 +228,8 @@ def _placed(sub, placement):
 # the tensors of `_stack`'s `shared` each kind of layer makes
 MAKES = {"s": ("memory",), "f": ("k", "v")}
 
-# the logical axes of a residual path of several streams, [B, T, n, d]
+# the logical axes of several streams as [B, T, n, d] (a layer function
+# takes that form too; the model carries them flat, [B, T, n*d])
 STREAMS = ("batch", "seq", None, "act_embed")
 
 
@@ -786,8 +787,10 @@ class Transformer:
               mesh=None, rules: Optional[ShardingRules] = None):
         """tokens [B, T] int32 -> embeddings [B, T, d] (compute dtype); on
         a residual path of several streams (`cfg.residual_streams` = n >
-        1) the embedding repeated into every stream, [B, T, n, d]
-        (`ops/mhc.expand`, under `mhc/expand`)."""
+        1) the embedding repeated into every stream, flat: [B, T, n*d],
+        stream i the columns i*d:(i+1)*d (`ops/mhc.expand`, under
+        `mhc/expand`; what the mixing's kernels tile, and no [.., n, d]
+        array, whose minor axes the TPU pads or lays out tokens-minor)."""
         import jax
         import jax.numpy as jnp
 
@@ -809,7 +812,8 @@ class Transformer:
             x = constrain(x, ("batch", "seq", "act_embed"))
         if cfg.residual_streams > 1:
             from ray_tpu.ops import mhc
-            x = constrain(mhc.expand(x, cfg.residual_streams), STREAMS)
+            x = constrain(mhc.expand(x, cfg.residual_streams, flat=True),
+                          ("batch", "seq", "act_embed"))
         return x
 
     @staticmethod
@@ -846,21 +850,25 @@ class Transformer:
         there for whoever needs those bytes. "dots" saves every matmul's
         output besides. The carry is the stream between layers: B*T*d x 2
         bytes a layer in bf16, and on a residual path of n streams
-        (`cfg.residual_streams`) n times that, `[B, T, n, d]` (235 MB a
+        (`cfg.residual_streams`) n times that, `[B, T, n*d]` (235 MB a
         layer at 8,192 tokens of 4 x 3,584), kept a LAYER and not a
-        sublayer; a sublayer's maps (`[B, T, n*n + 2n]` f32, 0.8 MB there)
-        are not named: the backward makes them again from the recomputed
-        stream, the statistic, the product with phi and the 20 rounds
-        among remat's forward."""
+        sublayer; of a sublayer's mixing what `ops/mhc.enter`'s forward
+        hands its backward is kept (`ops.mhc.MAPS_RESIDUALS`: the product
+        with phi under the statistic `[n*n + 2n, B, T]` and the statistic
+        `[B, T]`, f32, 0.8 MB there): remat's forward makes neither
+        again, only the 20 rounds on the kept product and, from the
+        recomputed stream, what the sublayer reads."""
         import jax
 
         from ray_tpu.ops.attention import FLASH_RESIDUALS
         from ray_tpu.ops.kda import DELTA_RESIDUALS
+        from ray_tpu.ops.mhc import MAPS_RESIDUALS
         from ray_tpu.ops.moe import ROUTING_RESIDUALS
 
         policies = jax.checkpoint_policies
         residuals = policies.save_only_these_names(
-            FLASH_RESIDUALS, ROUTING_RESIDUALS, DELTA_RESIDUALS)
+            FLASH_RESIDUALS, ROUTING_RESIDUALS, DELTA_RESIDUALS,
+            MAPS_RESIDUALS)
         known = {"attention": residuals, "full": None,
                  "dots": policies.save_from_both_policies(
                      policies.checkpoint_dots, residuals)}
@@ -981,7 +989,7 @@ class Transformer:
         `exit_gate` (`_looped_hidden`).
 
         A residual path of several streams (`cfg.residual_streams` = n >
-        1) runs the layers on [B, T, n, d] and sums the streams (in
+        1) runs the layers on [B, T, n*d] and sums the streams (in
         float32, `ops/mhc.collapse` under `mhc/collapse`) before the final
         norm, and with_aux=True gives a fourth, the dict `maps` (f32
         [layers, 2, B, T, n*n + 2n]: every sublayer's H_pre, H_post and
@@ -1014,7 +1022,8 @@ class Transformer:
         if wide:
             from ray_tpu.ops import mhc
             with jax.named_scope("mhc/expand"):
-                entered = jnp.mean(jnp.square(x[:, :, 0].astype(jnp.float32)))
+                entered = jnp.mean(jnp.square(
+                    x[:, :, :cfg.d_model].astype(jnp.float32)))
         if "dense_layers" in params:   # the leading run with a dense FFN
             x, found, _ = Transformer._stack(
                 params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
@@ -1039,7 +1048,7 @@ class Transformer:
                 positions=positions, noised=noised)
             routing = without_maps(routing)
         if wide:
-            x = mhc.collapse(x)
+            x = mhc.collapse(x, cfg.residual_streams)
             with jax.named_scope("mhc/collapse"):
                 left = jnp.mean(jnp.square(
                     x.astype(jnp.float32) / cfg.residual_streams))
@@ -1127,7 +1136,7 @@ class Transformer:
         `x + f(norm(x))`, or `x + norm(f(x))` where the leaves hold
         `<name>_post_norm` instead, or the sandwich with both, or, the
         fourth form of the one rule, on n streams (the leaves hold
-        `<name>_hc_phi`: x is `[B, T, n, d]`) `H_res X + H_post (x)
+        `<name>_hc_phi`: x is `[B, T, n*d]`) `H_res X + H_post (x)
         f(norm(sum_i H_pre[i] X[i]))` with the sublayer's three maps made
         once, in `entering`, and handed to `residual` (`entering`,
         `residual`; no sublayer function knows of the streams). `routing`
@@ -1161,22 +1170,22 @@ class Transformer:
             """What the sublayer `name` reads: `norm(x)` under the norm
             that opens it, the stream itself where its leaves have none
             (the reordered norm). Where its leaves hold `<name>_hc_phi`, x
-            is n streams [B, T, n, d]: the sublayer's three maps are made
-            of it here, once (`ops/mhc.stream_maps`), what is normed is
-            the streams' mix under H_pre, and `residual` is handed the
-            other two."""
+            is n streams ([B, T, n*d], or [B, T, n, d]): the sublayer's
+            three maps are made of it here, once (`ops/mhc.enter`), what
+            is normed is the streams' mix under H_pre, and `residual` is
+            handed the other two with the stream as `enter` gave it
+            back."""
             if name + "_hc_phi" in lp:
                 from ray_tpu.ops import mhc
-                pre, post, res = mhc.stream_maps(
+                h, (pre, post, res), x = mhc.enter(
                     x, lp[name + "_hc_phi"], lp[name + "_hc_b"],
                     lp[name + "_hc_alpha"], rounds=cfg.hc_sinkhorn_iters,
                     norm_eps=cfg.norm_eps, hc_eps=cfg.hc_eps,
-                    clamp=cfg.hc_res_clamp)
-                open_maps[name] = (post, res)
+                    clamp=cfg.hc_res_clamp, mesh=mesh)
+                open_maps[name] = (x, post, res)
                 with jax.named_scope("mhc/maps"):
                     layer_maps.append(mhc.maps_by_token(pre, post, res))
-                x = constrain(mhc.read(x, pre),
-                              ("batch", "seq", "act_embed"))
+                x = constrain(h, ("batch", "seq", "act_embed"))
             if name + "_norm" not in lp:
                 return x
             with jax.named_scope(name + "_norm"):
@@ -1187,7 +1196,7 @@ class Transformer:
             `entering(x, lp, name)`: `x + out`, `out` under the norm on
             the sublayer's output where its leaves have one; on n streams
             (the maps `entering` left for it) `H_res X + H_post out`
-            (`ops/mhc.write`). With `entering`, the one rule of how a
+            (`ops/mhc.leave`). With `entering`, the one rule of how a
             residual is formed."""
             out = constrain(out, ("batch", "seq", "act_embed"))
             post = name + "_post_norm"
@@ -1196,8 +1205,12 @@ class Transformer:
                     out = _norm(out, lp, post, cfg.norm_eps)
             if name in open_maps:
                 from ray_tpu.ops import mhc
-                return constrain(mhc.write(x, out, *open_maps.pop(name)),
-                                 STREAMS)
+                # the stream as `enter` handed it back, not x: its
+                # cotangent then arrives in `enter`'s backward
+                x, *maps = open_maps.pop(name)
+                return constrain(
+                    mhc.leave(x, out, *maps, mesh=mesh),
+                    STREAMS if x.ndim == 4 else ("batch", "seq", "act_embed"))
             return x + out
 
         def heads_constrained(q, k, v):

@@ -36,7 +36,15 @@ only the pallas rule names anything, so the other ten hold. PR 66
 softmax scale) left all eleven as they were, GLM's and Ling's first
 (`latent_qkv`, the softmax scale), then d2's, Olmo-Hybrid's and Ouro's
 (`entering` / `residual`, `_remat`, `embed`, `hidden`), and pins its own
-cell: with `residual_streams` 1 nothing of it is traced. Each text is
+cell: with `residual_streams` 1 nothing of it is traced. PR 67 (the
+streams' mixing as two `jax.custom_vjp`s with pallas kernels,
+`ops/mhc.enter` / `leave`; in `models/transformer.py` the two calls in
+`entering` / `residual`, one more name in `_remat`'s policy,
+`ops.mhc.MAPS_RESIDUALS`, and the stream carried flat from `embed` to the
+exit) means to change Xing4's program and no other and makes its pin anew
+from its own tree: only `enter` names anything and only a configuration
+with `residual_streams` above 1 reaches it, so the other eleven hold (all
+twelve run, PR 67: eleven green as they were). Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
@@ -103,8 +111,10 @@ PINS = {
     "train_olmohybrid7b_tp2_d4": "1ebd73113b090dc6",
     # PR 63's own cell, pinned from its own tree (4,739 lines)
     "train_ouro26b_d8": "079113b2a5d63a41",
-    # PR 66's own cell, pinned from its own tree (47,363 lines)
-    "train_xing4_ep8_d5": "907116026b0cc671",
+    # PR 67's tree: the mixing of the streams is `ops/mhc.enter` /
+    # `leave`, four pallas kernels on a flat stream (45,373 lines; its
+    # parent's, PR 66's pin of its own cell, 907116026b0cc671, 47,363)
+    "train_xing4_ep8_d5": "cad71bc5e10b5a59",
 }
 WITH_THE_SUITE = ("train_mistral7b_d2",)
 

@@ -1,6 +1,9 @@
 """The Xing4.0-29B-A4B step compiled for a described v5e (PR 66): a file
 of its own, so that it runs beside the other step files on another worker
-(the fixture stays in `tests/test_chip_compile.py`)."""
+(the fixture stays in `tests/test_chip_compile.py`). Since PR 67 the
+mixing of the streams is `ops/mhc.enter` / `leave`: four pallas kernels
+under the `mhc/*` scopes, the stream flat and in bf16 wherever it is
+written, the product with phi kept for the backward."""
 
 import os
 import sys
@@ -28,16 +31,20 @@ def test_streams_share_step_compiles_and_fits_the_v5e(v5e):
     its job's mapping): splash once each way in each run's scan and not
     again under `_remat`, `megablox` over the held experts' run of rows,
     the five `mhc/*` scopes with the write inside the scope that closes
-    its sublayer, the carry a layer `[1, 8192, 4, 3584]` in bf16 and
-    unpadded, no `[tokens, 4, 4]` array of the maps, and the compiler's
-    memory report what it was when the cell's first chip run read
-    `peak_hbm_gb` under the chip's 16.91."""
+    its sublayer, the carry a layer flat, `[1, 8192, 14336]` in bf16
+    (until PR 67 `[1, 8192, 4, 3584]`, which XLA laid out tokens-minor:
+    the kernels tile whole token rows), no `[tokens, 4, 4]` array of the
+    maps, and the compiler's memory report under the chip's 16.91. Since
+    PR 67: the mixing's four kernels, each under an `mhc/*` scope in the
+    forward, under remat and in the backward; no float32 array of the
+    stream's shape; no product with phi under remat."""
     import re
 
     import optax
 
     from benchlib.spec import load_json, load_module
     from ray_tpu.models import Transformer
+    from ray_tpu.ops import mhc
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.train_step import make_train_step
 
@@ -75,8 +82,38 @@ def test_streams_share_step_compiles_and_fits_the_v5e(v5e):
     # `ragged-dot-*`: the path over every row, the other branch of
     # `row_bound`'s one `cond` a pass
     assert [n for n in names if not n.startswith("ragged-dot")] == [
-        "gmm", "splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals",
-        "tgmm"], names
+        "gmm", "mhc_enter_bwd", "mhc_enter_fwd", "mhc_leave_bwd",
+        "mhc_leave_fwd", "splash_mha_dkv_no_residuals",
+        "splash_mha_fwd_residuals", "tgmm"], names
+    # the mixing's kernels (`ops/mhc.stream_mix_impl` said "pallas"): a
+    # scan's body is one layer of two sublayers, and the step has two
+    # scans each way (the dense run's and the expert run's). Forward:
+    # `enter`'s product and `leave`, twice a body. Backward: both backward
+    # kernels twice a body, and under remat `leave`'s forward ONCE (the
+    # second sublayer's X' is the next layer's carry, which is kept) and
+    # `enter`'s forward kernel never: the layer's remat keeps m and r
+    # (`mhc.MAPS_RESIDUALS`). Every one under the scope the readers book
+    # it by (`benchlib/mhc_reduce.py`): `enter`'s under `mhc/maps`,
+    # `leave`'s under `mhc/post` inside the scope that closes the sublayer
+    assert mhc.stream_mix_impl(mesh, seq, 4, 3584, jnp.bfloat16) == "pallas"
+    calls = {}
+    for name, op in kernels:
+        if not name.startswith("mhc_"):
+            continue
+        phase = "remat" if "rematted_computation" in op \
+            else "backward" if "transpose(" in op else "forward"
+        kernel = re.sub(r"\.\d+$", "", name)
+        calls[kernel, phase] = calls.get((kernel, phase), 0) + 1
+        scope = "mhc/maps" if "enter" in kernel else "mhc/post"
+        assert re.search(rf"[/(]{scope}[/)]", op), (name, op)
+        if "leave" in kernel:
+            assert re.search(r"(attn_out|mlp/down|moe/combine)\)?/mhc/post",
+                             op), (name, op)
+    assert calls == {("mhc_enter_fwd", "forward"): 4,
+                     ("mhc_leave_fwd", "forward"): 4,
+                     ("mhc_leave_fwd", "remat"): 2,
+                     ("mhc_enter_bwd", "backward"): 4,
+                     ("mhc_leave_bwd", "backward"): 4}, calls
     # the forward attention kernel is not run again under remat: one call
     # each way in the dense run's scan and in the expert run's
     splash = [op for n, op in kernels if n.startswith("splash")]
@@ -90,16 +127,44 @@ def test_streams_share_step_compiles_and_fits_the_v5e(v5e):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)"]', hlo), scope
     for inside in ("attn_out/mhc/post", "mlp/down/mhc/post",
                    "moe/combine/mhc/post",
-                   "rematted_computation/mhc/maps"):
+                   "rematted_computation/mhc/maps",
+                   "rematted_computation/mhc/maps/mhc/pre"):
         assert inside in hlo, inside
-    # the carry a layer, unpadded: four bytes a token and column in bf16
-    assert re.search(r"bf16\[4,1,8192,4,3584\]", hlo)
-    assert not re.search(r"f32\[4,1,8192,4,3584\]", hlo)
+    # until PR 67 remat's forward made the product with phi again (a
+    # `dot_general` under `rematted_computation/mhc/maps`); now m and r
+    # are kept, what is left there are the rounds on the kept m and the
+    # read of `h`; the product itself is inside `mhc_enter_fwd`, and the
+    # backward's two (`(dm r) phi^T`, `dphi`) inside `mhc_enter_bwd`
+    op_names = re.findall(r'op_name="([^"]+)"', hlo)
+    assert [n for n in op_names if "rematted_computation/mhc/maps" in n
+            and n.endswith("/div")]
+    assert not [n for n in op_names if "mhc/maps" in n
+                and "dot_general" in n]
+    # the carry a layer, flat and unpadded: two bytes a token and column
+    assert re.search(r"bf16\[4,1,8192,14336\]", hlo)
+    # nowhere the stream in float32 (the parent's backward held `[8192, 4,
+    # 3584]` f32 transients, 470 MB) and nowhere as `[.., 4, 3584]`, the
+    # shape XLA lays out tokens-minor or pads (a relayout at every
+    # kernel's door; the MoE's `[8192 tokens, 4 chosen, 3584]` is not the
+    # stream). Arrays, that is: what an instruction outside a fusion's
+    # body gives (inside one a value is registers: the entry's
+    # concatenate and the exit's backward pass through float32 there)
+    written, fused = [], False
+    for line in hlo.splitlines():
+        if line.endswith("{") and "->" in line:
+            fused = "fused_computation" in line.split("(")[0]
+        elif not fused:
+            written.append(line)
+    assert not re.search(r"= f32\[(\d+,)*8192,14336\]", "\n".join(written))
+    assert not re.search(r"\[(\d+,)*1,8192,4,3584\]", hlo)
     # the maps live with the tokens last: no [.., 8192, 4, 4] array
     assert not re.search(r"f32\[(1,)?8192,4,4\]", hlo)
     ma = compiled.memory_analysis()
     # 12 B a parameter resident (and the choice bias, a buffer)
     assert abs(ma.argument_size_in_bytes - 670_872_590 * 12) < 1e6
-    # 11.07 GB where the compiler's own usage report read 15.44 GB and the
-    # chip `peak_hbm_gb` under 16.91
-    assert ma.temp_size_in_bytes < 11.3e9, ma.temp_size_in_bytes
+    # 10.82 GB where the compiler's own usage report reads 15.39 GB
+    # (`XLA_FLAGS=--xla_dump_to`, `Total bytes used`); PR 66's step read
+    # 11.07 and 15.44, and the chip `peak_hbm_gb` 15.435 of 16.91. (With
+    # the kernels' records handed over by token, `[B*T, rows]`, XLA laid
+    # the maps' own arithmetic out rows-minor and the report read 15.80.)
+    assert ma.temp_size_in_bytes < 10.9e9, ma.temp_size_in_bytes

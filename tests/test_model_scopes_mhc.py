@@ -4,8 +4,14 @@ vocabulary of models/transformer.py (PERF.md section 3: `mhc/maps`,
 tests/test_model_scopes.py holds the others: every name reaches the lowered
 module's `op_name`s, the write lies inside the scope that closes its
 sublayer, forward, backward and recomputation are read off JAX's own
-wrappers, and one stream has none of the new names."""
+wrappers, and one stream has none of the new names. Since PR 67 the mixing
+is two `jax.custom_vjp`s (`ops/mhc.enter`, `leave`), whose backward rules
+carry no scope unless given one: every equation of the gradient's program
+that `ops/mhc.py` wrote, the backward rules' and the kernels' among them,
+lies under an `mhc/*` scope, and remat's forward holds `h` and the rounds
+but not the product with phi (`mhc.MAPS_RESIDUALS` keeps it)."""
 
+import functools
 import importlib.util
 import os
 import re
@@ -71,7 +77,10 @@ def test_the_new_scopes_reach_the_lowered_op_names(program, chunk):
     for inside in ("attn_out/mhc/post", "mlp/down/mhc/post",
                    "moe/combine/mhc/post"):
         assert any(body in n and inside in n for n in cleaned), inside
-    for scope in ("mhc/maps", "mhc/pre"):
+    # (since PR 67 the read is written inside `enter`, whose call lies
+    # under `mhc/maps`: `mhc/maps/mhc/pre`, and the readers book an op by
+    # the LAST of the names in its path)
+    for scope in ("mhc/maps", "mhc/maps/mhc/pre"):
         assert any(body + scope in n or body + "checkpoint/" + scope in n
                    for n in cleaned), scope
         assert any(body + "checkpoint/rematted_computation/" + scope in n
@@ -79,6 +88,12 @@ def test_the_new_scopes_reach_the_lowered_op_names(program, chunk):
     # the product with phi and the rounds' divisions are the maps'
     assert any("mhc/maps" in n and "dot_general" in n for n in cleaned)
     assert any("mhc/maps" in n and "div" in n for n in cleaned)
+    # until PR 67 remat's forward made the product again and this line
+    # held that there was one; the layer's remat now keeps it (m and r,
+    # `mhc.MAPS_RESIDUALS`) and makes the rounds again on what it kept
+    remat = [n for n in cleaned if "rematted_computation/mhc/maps" in n]
+    assert any("div" in n for n in remat)
+    assert not [n for n in remat if "dot_general" in n]
     assert not any("mhc/pre" in n and "dot_general" in n for n in cleaned)
     # entry and exit lie outside the layers' scans
     assert not any("layers" in n and ("mhc/expand" in n
@@ -97,3 +112,61 @@ def test_one_stream_has_none_of_them():
     assert not mhc_scopes(hlo)
     assert {"layers", "final_norm", "attn_norm", "mlp_norm"} <= \
         base.scopes_in(hlo)
+
+
+def test_every_equation_of_the_mixing_lies_under_one_of_them():
+    """The gradient's jaxpr of a step, by who wrote each equation: what
+    `ops/mhc.py` wrote (the two forward rules, the two backward rules,
+    the passes in `jax.numpy`, the kernels under interpretation) carries
+    an `mhc/*` scope in its name stack; `benchmark/benchlib/mhc_reduce.py`
+    reads those scopes and nothing else."""
+    from jax._src import source_info_util
+
+    from ray_tpu.ops import mhc
+    from tests.test_moe_routing_residuals import subjaxprs
+
+    n, d = 4, 128
+    keys = jax.random.split(jax.random.key(0), 5)
+    operands = (jax.random.normal(keys[0], (1, 128, n * d)).astype(
+        jnp.bfloat16), jax.random.normal(keys[1], (n * d, n * n + 2 * n)),
+        jnp.zeros((n * n + 2 * n,)), jnp.ones((3,)),
+        jax.random.normal(keys[2], (d, d)).astype(jnp.bfloat16))
+
+    def sublayer(interpret, x, phi, b, alpha, w):
+        h, (_, post, res), x = mhc.enter(
+            x, phi, b, alpha, rounds=2, norm_eps=1e-6, hc_eps=1e-6,
+            clamp=30.0, interpret=interpret)
+        out = mhc.leave(x, jnp.tanh(h @ w), post, res, interpret=interpret)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def walk(jaxpr, outer):
+        """(equation, its name stack under the equations that hold its
+        jaxpr: a `pjit`'s, a `custom_vjp_call`'s or a kernel's body names
+        itself from its caller on)."""
+        for eqn in jaxpr.eqns:
+            stack = outer + "/" + str(eqn.source_info.name_stack)
+            yield eqn, stack
+            for value in eqn.params.values():
+                for sub in subjaxprs(value):
+                    yield from walk(sub, stack)
+
+    for interpret in (False, True):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            functools.partial(sublayer, interpret), argnums=(0, 1, 2, 3, 4)))(
+                *operands)
+        written, kernels = 0, set()
+        for eqn, stack in walk(jaxpr.jaxpr, ""):
+            frame = source_info_util.user_frame(eqn.source_info.traceback)
+            if frame is None or not frame.file_name.endswith("ops/mhc.py"):
+                continue
+            written += 1
+            while base.TRANSFORMS.search(stack):
+                stack = base.TRANSFORMS.sub(r"\1", stack)
+            assert SCOPE.search(stack), (eqn.primitive.name, stack,
+                                         frame.function_name)
+            if eqn.primitive.name == "pallas_call":
+                kernels.add(eqn.params["name"])
+        assert written > 100
+        assert kernels == ({"mhc_enter_fwd", "mhc_enter_bwd",
+                            "mhc_leave_fwd", "mhc_leave_bwd"}
+                           if interpret else set())
