@@ -47,7 +47,17 @@ maps computed from the stream itself, the n x n one made doubly stochastic
 by `hc_sinkhorn_iters` Sinkhorn rounds (ops/mhc.py). YaRN on latent
 attention is DeepSeek-V3's reading (`rope_yarn_mscale_all_dim`): a factor
 on the WHOLE softmax scale, all of a head's columns, and none on the
-rotary tables, which touch 64 of 192 of them.
+rotary tables, which touch 64 of 192 of them. `n` is a Mamba-2 mixer
+FOLLOWED by a dense MLP (Granite 4.0-H's layer beside `l` without RoPE),
+under four muP scalars (`embed_scale`, `residual_scale`, `attn_scale`,
+`logit_divisor`).
+
+Packed documents (`segment_ids` [B, T] beside the tokens; no field here)
+are an input of the step: attention sees a query's own document, a
+Mamba-2 mixer's convolution and scan start anew at a document's first
+position, the loss drops the labels that cross a boundary; every other
+kind that mixes positions refuses them by name (the comment above
+`__post_init__`, `Transformer.untaught_by_packing`).
 
 Named scales: GPT-2 125M (BASELINE.json's data-parallel config),
 Llama-2 7B (its FSDP config) and OLMoE-1B-7B (the sparse-expert decoder of
@@ -338,6 +348,36 @@ class TransformerConfig:
     # = `mscale_all_dim` the rotary tables stay unscaled:
     # rope_yarn_attention_factor 1.0.
     rope_yarn_mscale_all_dim: float = 0.0
+    # One more lower-case kind of `layer_pattern`: `n`, a Mamba-2 mixer
+    # (the `ssm_*` sizes of `M`) FOLLOWED by a dense gated MLP of width
+    # moe_dense_ff or d_ff, each under its own pre-norm (Granite 4.0-H's
+    # layer; its attention layer is `l` with `rope` off). And the four muP
+    # scalars such a model publishes, each 1 (0 for the softmax's) where a
+    # model has none and then not traced: the embedding's output times
+    # embed_scale (`embedding_multiplier`), every sublayer's output times
+    # residual_scale before it joins the stream (`residual_multiplier`),
+    # attn_scale as the softmax scale in place of head_dim^-1/2
+    # (`attention_multiplier`), the logits divided by logit_divisor
+    # (`logits_scaling`).
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0
+    logit_divisor: float = 1.0
+    # Packed documents are no field of this configuration: `segment_ids`
+    # [B, T] int32 is an input of `Transformer.apply` / `hidden` / `loss`
+    # (a batch's "segment_ids" beside its "tokens"), one id a position,
+    # each document one run of equal ids. A boundary does this in each
+    # sublayer that mixes positions: softmax attention (dense and flash;
+    # causal or under a window) sees the keys of the query's document
+    # only; a Mamba-2 mixer's (`M`, `n`) convolution reads zero for a tap
+    # in another document and its scan starts every document from a zero
+    # state; the loss leaves out a label that lies in another document
+    # than its position. A document's outputs are those of the document
+    # run alone. `Transformer.untaught_by_packing` names what refuses
+    # `segment_ids`: Mamba-1 (`m`, `s`), Kimi Delta Attention (`k`, `K`),
+    # Gated DeltaNet (`d`), differential and cross attention, the
+    # block-diffusion mask, ring and ulysses attention, a looped stack, a
+    # residual path of several streams, `pipeline_loss`.
 
     def __post_init__(self):
         if self.norm not in ("rms", "layernorm"):
@@ -354,7 +394,7 @@ class TransformerConfig:
                     f"characters of M, *, {', '.join(EXPERT_KINDS)}, "
                     f"{', '.join(FFN_KINDS)} (got {sorted(unknown)})")
             self._check_shared_tensors()
-            if "M" in self.layer_pattern and (
+            if set("Mn") & set(self.layer_pattern) and (
                     not self.ssm_heads or self.ssm_heads % self.ssm_groups):
                 raise ValueError("a mixer needs ssm_heads, a multiple of "
                                  "ssm_groups")
@@ -537,9 +577,10 @@ class TransformerConfig:
 
     @property
     def softmax_scale(self) -> float:
-        """What attention's scores are multiplied by: head_dim^-1/2, under
+        """What attention's scores are multiplied by: head_dim^-1/2 (or
+        `attn_scale` where the model publishes one), under
         `rope_yarn_mscale_all_dim` times YaRN's factor squared."""
-        scale = self.head_dim ** -0.5
+        scale = self.attn_scale or self.head_dim ** -0.5
         if self.rope_yarn_mscale_all_dim:
             scale *= (0.1 * self.rope_yarn_mscale_all_dim
                       * math.log(self.rope_yarn_factor) + 1.0) ** 2
@@ -631,11 +672,7 @@ class TransformerConfig:
     def _pattern_params(self, attn: int) -> int:
         """num_params of a hybrid: each sublayer with its one norm."""
         d, v = self.d_model, self.vocab_size
-        inner, conv = self.ssm_inner, self.ssm_conv_dim
-        mixer = (d * (inner + conv + self.ssm_heads)       # W_in
-                 + conv * self.ssm_conv_kernel + conv      # conv, its bias
-                 + 3 * self.ssm_heads                      # dt_bias, A_log, D
-                 + inner + inner * d)                      # gated norm, W_out
+        mixer = self._mixer_params
         mats = 3 if self.moe_gated else 2
         width = self.moe_latent or d
         expert = (d * self.moe_experts                     # router
@@ -653,6 +690,15 @@ class TransformerConfig:
         layers = sum(each[c] + norm for c in self.layer_pattern)
         head = 0 if self.tie_embeddings else d * v
         return v * d + layers + norm + head
+
+    @property
+    def _mixer_params(self) -> int:
+        """A Mamba-2 mixer without its norm."""
+        d, inner, conv = self.d_model, self.ssm_inner, self.ssm_conv_dim
+        return (d * (inner + conv + self.ssm_heads)        # W_in
+                + conv * self.ssm_conv_kernel + conv       # conv, its bias
+                + 3 * self.ssm_heads                       # dt_bias, A_log, D
+                + inner + inner * d)                       # gated norm, W_out
 
     @property
     def _kda_params(self) -> int:
@@ -702,15 +748,16 @@ class TransformerConfig:
         each = {"m": mixer, "s": mixer, "w": attn + bias + diff,
                 "f": attn + bias + diff, "g": 2 * d * inner,
                 "c": attn + bias + diff - kv, "k": self._kda_params,
-                "l": attn, "d": self._gdn_params, "a": attn}
+                "l": attn, "d": self._gdn_params, "a": attn,
+                "n": self._mixer_params}
         return {kind: count + rest for kind, count in each.items()}
 
 
 # the kinds of `layer_pattern` that are followed by a dense MLP in the same
 # layer (TransformerConfig: `m`, `s`, `w`, `f`, `g`, `c`, `k`, `l`, `d`,
-# `a`), those that are or end in an expert layer (`E` alone, `K`, `L`,
-# `W`), and those whose sublayers stand under the reordered norm
-FFN_KINDS = "msfwgcklda"
+# `a`, `n`), those that are or end in an expert layer (`E` alone, `K`,
+# `L`, `W`), and those whose sublayers stand under the reordered norm
+FFN_KINDS = "msfwgckldan"
 EXPERT_KINDS = "EKLW"
 REORDERED_KINDS = "da"
 

@@ -2,7 +2,8 @@
 
 The one module that knows the head's weight and its orientation (a tied
 `[vocab, d]` embedding contracted as `vd`, an untied `[d, vocab]`
-`lm_head`), the logits einsum with its f32 accumulation, the cross-entropy
+`lm_head`), the logits einsum with its f32 accumulation (divided by
+`cfg.logit_divisor` where a model publishes one), the cross-entropy
 (`logsumexp` less the gold logit), and how a training step keeps the
 `[B, T, vocab]` f32 logits out of HBM: T is scanned in `cfg.loss_chunk`
 slices, each chunk's gradient is taken in that same scan while its logits
@@ -46,6 +47,8 @@ def _project(head, x, cfg: TransformerConfig, seq_axis, mesh, rules):
     with jax.named_scope("head"):
         eq = "bcd,vd->bcv" if cfg.tie_embeddings else "bcd,dv->bcv"
         logits = jnp.einsum(eq, x, head, preferred_element_type=jnp.float32)
+        if cfg.logit_divisor != 1.0:   # muP's `logits_scaling`
+            logits = logits / cfg.logit_divisor
         return with_logical_constraint(
             logits, ("batch", seq_axis, "act_vocab"), mesh=mesh, rules=rules)
 

@@ -41,6 +41,23 @@ hidden state gives one f32 scalar a token, the exit gate's. What the
 leaves cannot say the layer's kind in `layer_pattern` does: a window, and
 which layer's tensors cross layers (`_stack`'s `shared`: the scan output
 `memory` of the mixer `s`, the `k` and `v` of the attention layer `f`).
+The kind `n` is a Mamba-2 mixer's leaves with an MLP's (a mixer FOLLOWED by
+a dense MLP, each under its own pre-norm: Granite 4.0-H's layer, beside `l`
+with `rope` off), and a model's four muP scalars (`cfg.embed_scale`,
+`residual_scale`, `attn_scale`, `logit_divisor`) multiply the embedding's
+output, every sublayer's output in `residual`, the scores, and divide the
+logits (models/head.py); at their neutral values nothing is traced.
+
+Packed documents: `segment_ids` [B, T] int32 (a batch's "segment_ids"
+beside its "tokens") are an input of `apply` / `hidden` / `loss`, data and
+not shape, a constant of the layer scan as cos and sin are. Attention
+(dense and flash, causal or windowed) sees a query's own document; a
+Mamba-2 mixer's convolution and scan start anew at a document's first
+position (ops/ssm.py); `loss` leaves out the labels that cross a boundary
+and its metrics gain `packed_docs`, `packed_labels` and
+`packed_attn_pairs_needed` (int32: `_packed_labels`). Whatever else mixes
+positions refuses them by name, in one place (`untaught_by_packing`); None
+traces none of this.
 
 Every block names itself with `jax.named_scope`, and the names are an
 interface (PERF.md section 3; the benchmark's per-layer metrics and an
@@ -90,7 +107,10 @@ sigmoids, the Sinkhorn rounds), `mhc/pre` (what the sublayer reads),
 `mhc/expand` and `mhc/collapse` (the entry after `embed`, the exit before
 `final_norm`: ops/mhc.py);
 the train step adds `optimizer`
-(parallel/train_step.py). Scopes are metadata only. Forward, backward
+(parallel/train_step.py); packed documents add `segments`, nested in the
+scope whose work it is: `loss/segments` (the labels' mask and the step's
+counters), `ssm/conv/segments` and `ssm/scan/segments` (the masks and the
+chunks' marks, outside the kernels). Scopes are metadata only. Forward, backward
 and recomputation need none: JAX wraps the path in `jvp(...)`,
 `transpose(jvp(...))` and remat's `rematted_computation`.
 
@@ -512,6 +532,8 @@ class Transformer:
                 del sub["mlp_norm"]
             elif kind in "ms":
                 sub = with_mlp(mixer1(l, key), l, keys)
+            elif kind == "n":
+                sub = with_mlp(mixer(l, key), l, keys)
             elif kind in "wfc":
                 sub = with_mlp(attention(l, keys), l, keys)
                 if kind == "c":   # the keys and values are the layer f's
@@ -693,7 +715,7 @@ class Transformer:
             return layers
 
         def sublayer(kind):
-            if kind in "Mms":   # a mixer's channels are not sharded here
+            if kind in "Mnms":   # a mixer's channels are not sharded here
                 sub = {"ssm_norm": ("layers", "norm"),
                        "w_in": ("layers", "embed", None),
                        "conv_w": ("layers", None, None),
@@ -701,7 +723,7 @@ class Transformer:
                        "dt_bias": ("layers", None),
                        "D": ("layers", None),
                        "w_out": ("layers", None, "embed")}
-                if kind == "M":
+                if kind in "Mn":
                     sub.update(A_log=("layers", None),
                                gate_norm=("layers", None))
                 else:
@@ -808,8 +830,11 @@ class Transformer:
         # reduce-scatters the grad.
         with jax.named_scope("embed"):
             emb = constrain(params["embed"], ("vocab", "act_embed"))
-            x = jnp.take(emb, tokens, axis=0).astype(jnp.dtype(cfg.dtype))
-            x = constrain(x, ("batch", "seq", "act_embed"))
+            x = jnp.take(emb, tokens, axis=0)
+            if cfg.embed_scale != 1.0:   # muP's `embedding_multiplier`
+                x = x.astype(jnp.float32) * cfg.embed_scale
+            x = constrain(x.astype(jnp.dtype(cfg.dtype)),
+                          ("batch", "seq", "act_embed"))
         if cfg.residual_streams > 1:
             from ray_tpu.ops import mhc
             x = constrain(mhc.expand(x, cfg.residual_streams, flat=True),
@@ -881,7 +906,7 @@ class Transformer:
     @staticmethod
     def _stack(layers, x, cfg: TransformerConfig, *, mesh,
                rules: ShardingRules, positions=None, kinds=None,
-               shared=None, noised: int = 0):
+               shared=None, noised: int = 0, segment_ids=None):
         """x [B, T, d] through a run of stacked layers (leaves
         [n, ...]: all of them in hidden(), one stage's in pipeline_loss())
         -> (x, routing, shared), `routing` the layers' stacked MoE records
@@ -899,7 +924,9 @@ class Transformer:
         without a scan; the returned `shared` has what it made. `noised`:
         the leading positions of x that are the noised copy of the ones
         behind them (a block-diffusion model's doubled stream; 0: a plain
-        stream)."""
+        stream). `segment_ids` [B, T] int32 or None: packed documents, a
+        constant of the scan as cos and sin are, handed to every sublayer
+        that mixes positions."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -927,7 +954,8 @@ class Transformer:
         layer_fn = Transformer._make_layer_fn(cfg, mesh, rules, cos, sin,
                                               seq_len=x.shape[1],
                                               noised=noised,
-                                              window_rope=window_rope)
+                                              window_rope=window_rope,
+                                              segment_ids=segment_ids)
         shared = dict(shared or {})
 
         @functools.cache
@@ -963,7 +991,8 @@ class Transformer:
     @staticmethod
     def hidden(params, tokens, cfg: TransformerConfig, *,
                mesh=None, rules: Optional[ShardingRules] = None,
-               positions=None, with_aux: bool = False, noised: int = 0):
+               positions=None, with_aux: bool = False, noised: int = 0,
+               segment_ids=None):
         """tokens [B, T] int32 -> final-norm hidden states [B, T, d]
         (compute dtype) — apply() stopping before the lm head, so the
         loss can chunk head+softmax over T (the f32 [B,T,vocab] logits
@@ -999,11 +1028,23 @@ class Transformer:
         When `mesh` is provided and cfg.attention_impl is ring/ulysses, the
         attention op runs inside shard_map over the "seq" axis; everything
         else is GSPMD via logical sharding constraints.
+
+        `segment_ids` [B, T] int32: packed documents, one id a position
+        and each document one run of equal ids (data, not shape: other
+        boundaries compile nothing). Every sublayer that mixes positions
+        is handed them (`TransformerConfig`'s header says what a boundary
+        does in each), and what has not been taught them refuses by name
+        (`untaught_by_packing`). None: one document a sequence, and
+        nothing of this is traced.
         """
         import jax
         import jax.numpy as jnp
 
         rules = rules or ShardingRules()
+        if segment_ids is not None:
+            Transformer.refuse_untaught_packing(
+                cfg, Transformer.resolve_attention_impl(
+                    cfg, mesh, tokens.shape[1]))
         x = Transformer.embed(params, tokens, cfg, mesh=mesh, rules=rules)
         if cfg.loops > 1:
             return Transformer._looped_hidden(
@@ -1027,7 +1068,8 @@ class Transformer:
         if "dense_layers" in params:   # the leading run with a dense FFN
             x, found, _ = Transformer._stack(
                 params["dense_layers"], x, cfg, mesh=mesh, rules=rules,
-                positions=positions, noised=noised)
+                positions=positions, noised=noised,
+                segment_ids=segment_ids)
             without_maps(found)
         if "runs" in params:
             records = []   # per run and expert sublayer: [repeats, ...]
@@ -1035,7 +1077,7 @@ class Transformer:
             for (kinds, _), run in zip(cfg.pattern_runs, params["runs"]):
                 x, found, shared = Transformer._stack(
                     run, x, cfg, mesh=mesh, rules=rules, positions=positions,
-                    kinds=kinds, shared=shared)
+                    kinds=kinds, shared=shared, segment_ids=segment_ids)
                 if found:   # into the layers' order: [repeats * found, ...]
                     records.append(jax.tree.map(
                         lambda *r: jnp.stack(r, 1).reshape(
@@ -1045,7 +1087,8 @@ class Transformer:
         else:
             x, routing, _ = Transformer._stack(
                 params["layers"], x, cfg, mesh=mesh, rules=rules,
-                positions=positions, noised=noised)
+                positions=positions, noised=noised,
+                segment_ids=segment_ids)
             routing = without_maps(routing)
         if wide:
             x = mhc.collapse(x, cfg.residual_streams)
@@ -1127,7 +1170,8 @@ class Transformer:
     @staticmethod
     def _make_layer_fn(cfg: TransformerConfig, mesh,
                        rules: ShardingRules, cos, sin, seq_len: int,
-                       noised: int = 0, window_rope=None):
+                       noised: int = 0, window_rope=None,
+                       segment_ids=None):
         """Build layer(x, lp, shared, kind) -> (x, routing, made), the body
         `_stack` scans (or, in a block, one of its sublayers): what lp's
         leaves say, attention under `attn_norm`, a mixer under `ssm_norm`,
@@ -1148,18 +1192,20 @@ class Transformer:
         in `layer_pattern` (None outside one). cos and sin are None where
         the model has no rotary embedding; `window_rope`: the (cos, sin)
         the window kinds (`w`, `W`) take where the model has two tables
-        (YaRN on the other layers)."""
+        (YaRN on the other layers). `segment_ids`: packed documents
+        (`hidden`), for attention's mask and a Mamba-2 mixer's resets."""
         import jax
         import jax.numpy as jnp
 
         cdt = jnp.dtype(cfg.dtype)
         constrain = functools.partial(
             with_logical_constraint, mesh=mesh, rules=rules)
-        attn_fn = Transformer._make_attention(cfg, mesh, rules,
-                                              seq_len=seq_len, noised=noised)
+        attn_fn = Transformer._make_attention(
+            cfg, mesh, rules, seq_len=seq_len, noised=noised,
+            segment_ids=segment_ids)
         window_fn = Transformer._make_attention(
-            cfg, mesh, rules, seq_len=seq_len, window=cfg.attn_window) \
-            if cfg.attn_window else None
+            cfg, mesh, rules, seq_len=seq_len, window=cfg.attn_window,
+            segment_ids=segment_ids) if cfg.attn_window else None
         scale = cfg.softmax_scale
         # on several streams: the maps `entering` made of the stream, by
         # sublayer, until `residual` takes them; and every sublayer's maps
@@ -1211,6 +1257,8 @@ class Transformer:
                 return constrain(
                     mhc.leave(x, out, *maps, mesh=mesh),
                     STREAMS if x.ndim == 4 else ("batch", "seq", "act_embed"))
+            if cfg.residual_scale != 1.0:   # muP's `residual_multiplier`
+                out = out * jnp.asarray(cfg.residual_scale, out.dtype)
             return x + out
 
         def heads_constrained(q, k, v):
@@ -1428,7 +1476,8 @@ class Transformer:
             out = mamba2_mixer(
                 h, dict(lp, w_in=w_in, w_out=w_out),
                 head_dim=cfg.ssm_head_dim, state=cfg.ssm_state,
-                chunk=cfg.ssm_chunk, eps=cfg.norm_eps, mesh=mesh)
+                chunk=cfg.ssm_chunk, eps=cfg.norm_eps, mesh=mesh,
+                segment_ids=segment_ids)
             with jax.named_scope("ssm/out_proj"):
                 return residual(x, out, lp, "ssm")
 
@@ -1511,12 +1560,13 @@ class Transformer:
     @staticmethod
     def apply(params, tokens, cfg: TransformerConfig, *,
               mesh=None, rules: Optional[ShardingRules] = None,
-              positions=None):
+              positions=None, segment_ids=None):
         """tokens [B, T] int32 -> logits [B, T, vocab] (f32 accum); a
-        looped stack's are its last pass's (no token leaves early)."""
+        looped stack's are its last pass's (no token leaves early).
+        `segment_ids` [B, T]: packed documents (`hidden`)."""
         rules = rules or ShardingRules()
         x = Transformer.hidden(params, tokens, cfg, mesh=mesh, rules=rules,
-                               positions=positions)
+                               positions=positions, segment_ids=segment_ids)
         if cfg.loops > 1:
             x = x[-1]
         return head.logits(params, x, cfg, mesh=mesh, rules=rules)
@@ -1542,6 +1592,9 @@ class Transformer:
         from ray_tpu.parallel.pipeline import make_pipeline_fn
 
         rules = rules or ShardingRules()
+        if "segment_ids" in batch:
+            Transformer.refuse_untaught_packing(cfg, "dense",
+                                                pipeline=True)
         tokens, targets = Transformer._tokens_and_targets(batch)
         b, t = tokens.shape
         if b % n_micro or cfg.n_layers % n_stages:
@@ -1635,13 +1688,15 @@ class Transformer:
     @staticmethod
     def _make_attention(cfg: TransformerConfig, mesh, rules: ShardingRules,
                         seq_len: Optional[int] = None, window: int = 0,
-                        noised: int = 0):
+                        noised: int = 0, segment_ids=None):
         """attention(q, k, v, scale) under a causal mask, with `window` > 0
         one that also ends `window` keys back, with `cfg.block_length` the
         block-diffusion mask (over a doubled stream of `noised` noised
         positions and their clean copies, or causal by block over a plain
         one); dense and flash take all three, ring and ulysses the first
-        alone."""
+        alone. `segment_ids` [B, T] (packed documents): dense and flash
+        see a query's own document only, the ids sharded as the batch is
+        where the kernel runs per shard; None adds no operand."""
         import jax
         from jax.sharding import PartitionSpec as P
 
@@ -1686,12 +1741,20 @@ class Transformer:
                      rules.mesh_axes(head_axis), None)
 
         def shard_mapped(body, spec, kv_spec, **shard_map_kw):
+            # packed documents: the ids are one operand more, sharded as
+            # the batch is; without them the call is what it always was
+            ids = () if segment_ids is None else (segment_ids,)
+            ids_spec = (P(rules.mesh_axes("batch"), None),) * len(ids)
+
             def wrapped(q, k, v, scale):
+                def local(q, k, v, *ids):
+                    return body(q, k, v, scale=scale,
+                                **({"segment_ids": ids[0]} if ids else {}))
                 fn = jax.shard_map(
-                    functools.partial(body, scale=scale), mesh=mesh,
-                    in_specs=(spec, kv_spec, kv_spec), out_specs=spec,
-                    **shard_map_kw)
-                return fn(q, k, v)
+                    local, mesh=mesh,
+                    in_specs=(spec, kv_spec, kv_spec) + ids_spec,
+                    out_specs=spec, **shard_map_kw)
+                return fn(q, k, v, *ids)
             return maybe_widen(wrapped)
 
         if impl in ("dense", "flash") or seq_unsharded:
@@ -1706,6 +1769,8 @@ class Transformer:
                 return shard_mapped(body, qkv_spec(None),
                                     qkv_spec(None, kv_axis),
                                     check_vma=False)
+            if segment_ids is not None:
+                body = functools.partial(body, segment_ids=segment_ids)
             return lambda q, k, v, scale: body(q, k, v, scale=scale)
 
         from ray_tpu.parallel.ring import ring_attention
@@ -1727,6 +1792,80 @@ class Transformer:
                             qkv_spec(AXIS_SEQ),
                             qkv_spec(AXIS_SEQ, kv_axis))
 
+    # ---- packed documents -------------------------------------------
+    @staticmethod
+    def untaught_by_packing(cfg: TransformerConfig, impl: str,
+                            pipeline: bool = False):
+        """What of this configuration mixes positions and has not been
+        taught document boundaries, by name: the one list behind every
+        refusal of `segment_ids`. Taught: dense and flash attention under
+        the causal mask or a window (the homogeneous stack, the kinds `*`,
+        `l`, `L`, `a`, `w`, `W`), a Mamba-2 mixer (`M`, `n`), the loss.
+        `impl`: what attention resolved to."""
+        kinds = set(cfg.layer_pattern)
+        return [what for what, has in (
+            ("a Mamba-1 mixer (layer_pattern m, s)", kinds & set("ms")),
+            ("Kimi Delta Attention (layer_pattern k, K)", kinds & set("kK")),
+            ("a Gated DeltaNet mixer (layer_pattern d)", "d" in kinds),
+            ("differential attention (diff_attention)", cfg.diff_attention),
+            ("cross-attention (layer_pattern c)", "c" in kinds),
+            ("the block-diffusion mask (block_length)", cfg.block_length),
+            (impl + " attention", impl in ("ring", "ulysses")),
+            ("a looped stack (loops)", cfg.loops > 1),
+            ("a residual path of several streams (residual_streams)",
+             cfg.residual_streams > 1),
+            ("pipeline_loss", pipeline)) if has]
+
+    @staticmethod
+    def refuse_untaught_packing(cfg: TransformerConfig, impl: str,
+                                pipeline: bool = False) -> None:
+        untaught = Transformer.untaught_by_packing(cfg, impl, pipeline)
+        if untaught:
+            raise ValueError(
+                "segment_ids (packed documents): " + "; ".join(untaught)
+                + " mix positions and have not been taught document "
+                "boundaries: they would carry one document into the next")
+
+    @staticmethod
+    def _packed_labels(segment_ids, tokens, mask):
+        """A batch's `segment_ids` -> (the ids of the positions `hidden`
+        runs [B, T], the labels' mask with the labels that cross a
+        boundary left out, the step's counters). The ids come as the
+        tokens do: [B, T + 1] beside tokens to be shifted by one (label t
+        is position t + 1: its document is known), or [B, T] beside
+        explicit targets (the last label's document is not: the caller's
+        `mask` says). Counters, int32: `packed_docs` the documents of the
+        batch, `packed_labels` the labels trained on, and
+        `packed_attn_pairs_needed` the (query, key) pairs a head's
+        attention needs under the document mask, the sum over documents
+        of n (n + 1) / 2 (`ops/attention.causal_block_pairs` has what the
+        kernels compute)."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        with jax.named_scope("loss"), jax.named_scope("segments"):
+            t = tokens.shape[1]
+            if segment_ids.shape[1] not in (t, t + 1):
+                raise ValueError(
+                    f"segment_ids {segment_ids.shape} beside {t} positions: "
+                    f"one id a token of the batch")
+            ids = segment_ids[:, :t]
+            follows = segment_ids[:, 1:] == segment_ids[:, :-1]
+            if segment_ids.shape[1] == t:   # the last label's is unknown
+                follows = jnp.pad(follows, ((0, 0), (0, 1)),
+                                  constant_values=True)
+            same = follows.astype(jnp.float32)
+            mask = same if mask is None else mask.astype(jnp.float32) * same
+            place = jnp.arange(t, dtype=jnp.int32)[None, :]
+            starts = jnp.pad(ids[:, 1:] != ids[:, :-1], ((0, 0), (1, 0)),
+                             constant_values=True)
+            first = lax.cummax(jnp.where(starts, place, 0), axis=1)
+            return ids, mask, {
+                "packed_docs": jnp.sum(starts.astype(jnp.int32)),
+                "packed_labels": jnp.sum(mask).astype(jnp.int32),
+                "packed_attn_pairs_needed": jnp.sum(place - first + 1)}
+
     # ---- loss -------------------------------------------------------
     @staticmethod
     def _tokens_and_targets(batch):
@@ -1744,7 +1883,11 @@ class Transformer:
              mesh=None, rules: Optional[ShardingRules] = None,
              with_metrics: bool = False):
         """Next-token cross-entropy. batch = {"tokens": [B,T+1] or
-        ("tokens","targets") pair}, optionally a "mask" [B,T]; returns
+        ("tokens","targets") pair}, optionally a "mask" [B,T] and
+        "segment_ids" (packed documents, shaped as "tokens": every
+        sublayer that mixes positions is handed them, the labels that
+        cross a boundary are left out, and the metrics gain
+        `_packed_labels`' three counters); returns
         scalar mean loss (f32), for a MoE config plus `moe_aux_coeff` x the
         load-balancing loss. A block-diffusion model (`cfg.block_length`)
         has another objective, the masked-token loss of
@@ -1783,6 +1926,11 @@ class Transformer:
         import jax.numpy as jnp
 
         rules = rules or ShardingRules()
+        segment_ids, packed = batch.get("segment_ids"), {}
+        if segment_ids is not None:   # before the losses that take none
+            Transformer.refuse_untaught_packing(
+                cfg, Transformer.resolve_attention_impl(
+                    cfg, mesh, batch["tokens"].shape[1]))
         if cfg.block_length:
             return Transformer._block_diffusion_loss(
                 params, batch, cfg, mesh, rules, with_metrics)
@@ -1791,10 +1939,14 @@ class Transformer:
                                           with_metrics)
         tokens, targets = Transformer._tokens_and_targets(batch)
         mask = batch.get("mask")
+        if segment_ids is not None:
+            segment_ids, mask, packed = Transformer._packed_labels(
+                segment_ids, tokens, mask)
         # a looped stack hands its gate's z (None here) as a fourth,
         # several streams their maps and gain
         x, aux, routing, *streams = Transformer.hidden(
-            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True)
+            params, tokens, cfg, mesh=mesh, rules=rules, with_aux=True,
+            segment_ids=segment_ids)
         if cfg.loops > 1:   # no gate: the last pass alone is trained
             x = x[-1]
         total = head.nll_sum(head.weight(params, cfg), x, targets, cfg,
@@ -1806,6 +1958,8 @@ class Transformer:
             loss_val = loss_val + cfg.moe_aux_coeff * aux
         out = Transformer._loss_out(loss_val, aux, routing, cfg,
                                     with_metrics)
+        if with_metrics:
+            out[1].update(packed)
         if with_metrics and cfg.residual_streams > 1:
             from ray_tpu.ops import mhc
             with jax.named_scope("mhc/maps"):

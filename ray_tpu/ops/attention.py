@@ -21,6 +21,16 @@ block sees itself in both directions and the clean blocks strictly before
 it, a clean block the clean blocks up to itself, and nothing clean sees
 anything noised: L^2 + L*block_length pairs of the 4 L^2.
 
+Packed documents: `segment_ids` `[batch, seq]` int32 (one id a position,
+each document one run of equal ids) join the causal mask or a window on
+both paths: a query sees the keys of its own document and no others, so a
+document's outputs are those of the document attended alone. They are
+data: `dense_attention` compares them, `flash_attention` hands them to
+the kernels as splash's `SegmentIds`, which mask inside a block and skip
+none (`causal_block_pairs` counts what is computed all the same). None
+hands the kernels no operand; the block-diffusion mask refuses them, and
+ring and ulysses attention (parallel/) take none.
+
 `block_visible` is that mask's definition: `dense_attention`'s predicate
 and the tests' oracle. The splash kernels get another form of it. The
 library calls a computed mask's function on every `[block_q,
@@ -115,21 +125,37 @@ def _check_block_mask(t: int, block_length: int, noised: int, window: int):
             f"{t} positions")
 
 
+def _check_segments(segment_ids, q, k, block_length: int):
+    if segment_ids is None:
+        return
+    if block_length:
+        raise ValueError("the block-diffusion mask takes no segment_ids: "
+                         "packed documents under it are not taught yet")
+    if segment_ids.shape != q.shape[:2] or k.shape[1] != q.shape[1]:
+        raise ValueError(
+            f"segment_ids {segment_ids.shape} are one id a position of "
+            f"self-attention over {q.shape[:2]}")
+
+
 def dense_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, window: int = 0,
-                    block_length: int = 0, noised: int = 0):
+                    block_length: int = 0, noised: int = 0,
+                    segment_ids=None):
     """Multi-head / grouped-query attention on [batch, seq, heads,
     head_dim] arrays; k/v may carry fewer (kv) heads than q, and v
     another width than k. `window` > 0 (with `causal`): query i sees the
     keys j with i - j < window. `block_length` > 0: the block-diffusion
     mask (`block_visible`) in place of the causal one, over a doubled
-    stream where `noised` > 0."""
+    stream where `noised` > 0. `segment_ids` `[batch, seq]` int32 (packed
+    documents): besides, a query sees the keys of its own document
+    only."""
     import jax
     import jax.numpy as jnp
 
     if window and not causal:
         raise ValueError("a window is a causal window")
     _check_block_mask(q.shape[1], block_length, noised, window)
+    _check_segments(segment_ids, q, k, block_length)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s = gqa_scores(q, k, scale)
@@ -144,6 +170,9 @@ def dense_attention(q, k, v, *, causal: bool = True,
         if window:
             mask &= ~jnp.tril(jnp.ones((tq, tk), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
+    if segment_ids is not None:
+        same = segment_ids[:, :, None] == segment_ids[:, None, :]
+        s = jnp.where(same[:, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return gqa_pv(p, v).astype(q.dtype)
 
@@ -286,13 +315,15 @@ def block_table(t: int, head_dim: int, block_length: int, noised: int = 0):
 
 def _splash_attention(q, k, v, *, causal: bool, scale: float,
                       window: int = 0, block_length: int = 0,
-                      noised: int = 0, interpret: bool = False):
+                      noised: int = 0, segment_ids=None,
+                      interpret: bool = False):
     """`flash_attention`'s body; `interpret` runs the kernels in pallas
     interpret mode, which is how the CPU tests read their numerics."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.pallas.ops.tpu.splash_attention import (
-        CausalMask, FullMask, LocalMask, MultiHeadMask, make_splash_mha)
+        CausalMask, FullMask, LocalMask, MultiHeadMask, SegmentIds,
+        make_splash_mha)
 
     t, h, d = q.shape[1:]
     if not flash_shape_ok(t, d):
@@ -308,6 +339,7 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
     if window and not causal:
         raise ValueError("a window is a causal window")
     _check_block_mask(t, block_length, noised, window)
+    _check_segments(segment_ids, q, k, block_length)
     if block_length:   # computed from indices; empty blocks are skipped
         head_mask = _block_diffusion_mask(t, block_length, noised)
     elif window:   # i - j < window and j <= i; blocks outside are skipped
@@ -323,14 +355,36 @@ def _splash_attention(q, k, v, *, causal: bool, scale: float,
     # sequence [H, T, D] at a time; K and V keep their own head count,
     # q head i reading kv head i // (H // Hkv) as gqa_scores does
     scaled = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    o = jax.vmap(kernel)(jnp.swapaxes(scaled, 1, 2), jnp.swapaxes(k, 1, 2),
-                         jnp.swapaxes(v, 1, 2))
+    operands = (jnp.swapaxes(scaled, 1, 2), jnp.swapaxes(k, 1, 2),
+                jnp.swapaxes(v, 1, 2))
+    if segment_ids is None:
+        o = jax.vmap(kernel)(*operands)
+    else:
+        # the kernels' own operand: a pair of one document passes, every
+        # block the static mask keeps is still computed
+        # (`causal_block_pairs`)
+        ids = segment_ids.astype(jnp.int32)
+        o = jax.vmap(lambda q, k, v, ids: kernel(
+            q, k, v, segment_ids=SegmentIds(q=ids, kv=ids)))(*operands, ids)
     return jnp.swapaxes(o, 1, 2)
+
+
+def causal_block_pairs(t: int, head_dim: int) -> int:
+    """The (query, key) pairs `flash_attention`'s kernels compute a head
+    under the causal mask at this shape: the area of the blocks
+    (`_splash_block_sizes`) on and under the diagonal. Packed documents do
+    not lessen it: `SegmentIds` mask inside a block and skip none, so what
+    a document mask needs (the sum over documents of n (n + 1) / 2) over
+    this is the share of the kernels' work that is read."""
+    sizes = _splash_block_sizes(t, head_dim)
+    blocks = t // sizes.block_q
+    return blocks * (blocks + 1) // 2 * sizes.block_q * sizes.block_kv
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, window: int = 0,
-                    block_length: int = 0, noised: int = 0):
+                    block_length: int = 0, noised: int = 0,
+                    segment_ids=None):
     """Fused attention on [batch, seq, heads, head_dim] (k, v may carry
     fewer heads, v another width than k): JAX's pallas splash attention
     kernels. O(T) memory (the
@@ -348,7 +402,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
     on every block it runs, a full one too (the library exempts none from
     a computed mask). So it is handed each query's bounds as `q_sequence`
     and a predicate of a few compares (`_block_diffusion_mask`), held to
-    `block_visible`, the definition.
+    `block_visible`, the definition. `segment_ids` `[batch, seq]` int32
+    (packed documents) reach the kernels as splash's `SegmentIds`: data,
+    not shape, so other boundaries compile nothing; None hands the
+    kernels no such operand.
 
     Raises ValueError for a shape the kernel cannot tile; off the TPU
     the pallas lowering itself refuses. There is no dense fallback:
@@ -358,4 +415,4 @@ def flash_attention(q, k, v, *, causal: bool = True,
         scale = q.shape[-1] ** -0.5
     return _splash_attention(q, k, v, causal=causal, scale=scale,
                              window=window, block_length=block_length,
-                             noised=noised)
+                             noised=noised, segment_ids=segment_ids)

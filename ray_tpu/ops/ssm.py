@@ -62,6 +62,32 @@ manner of `ops/moe.grouped_matmul_impl`):
   multiplies two rounded factors, and `cum`'s sums are added in the
   MXU's order, not `cumsum`'s.
 
+**Packed documents.** `segment_ids` `[B, T]` int32 (one id a position,
+each document one run of equal ids; None: one document a sequence, and
+nothing below is traced) reach `mamba2_mixer`, `causal_conv` and both
+scans, and a document's outputs and gradients are those of the document
+run alone, exactly: no large negative decay, a masked term is 0.
+
+- `causal_conv`: a tap that lies in another document reads zero, as a
+  tap before t = 0 does.
+- the scans: `S_t` starts from zero at a document's first step.
+  Boundaries fall anywhere, so inside a chunk the pairs (i, j) of two
+  documents are masked with the pairs above the diagonal, before the
+  exponential; the state that enters a chunk reaches the steps of the
+  document the chunk before ended in and no others (`carry`); of a
+  chunk's steps those of its last document alone add to the state that
+  leaves it, and the entering state passes through only where the whole
+  chunk is that one document (`keep`, and `carry` of the last step:
+  `chunk_marks`). The backward masks the same three terms, so the state's
+  cotangent stops where the state did. `ssd_scan` multiplies by the
+  marks; the kernels take one operand more, `[B, 8, T]` float32 rows
+  (ids, `carry`, `keep`) in blocks of `[8, Q]`, which ride the float32
+  turn that lays `dt` down the sublanes (`_decays`), so a chunk costs no
+  product more. The marks and masks outside the kernels are built under
+  the scope `segments` (`ssm/conv/segments`, `ssm/scan/segments`).
+- Mamba-1 (`selective_scan`, its kernels, `mamba1_mixer`) takes no
+  `segment_ids`: `models/transformer.py` refuses them for its kinds.
+
 **A share of the heads.** The mixer is told its heads and groups by the
 weights it is given (`A_log` has H entries, the convolution H·P + 2·G·N
 channels): given the columns of `W_in`, the channels of the convolution
@@ -81,18 +107,52 @@ import functools
 from typing import Any, Dict
 
 
-def causal_conv(x, w, b):
+def causal_conv(x, w, b, segment_ids=None):
     """Depthwise causal convolution over time: x `[B, T, C]`, w `[C, K]`,
     b `[C]` -> `y_t = b + sum_j w[:, j] x_{t-K+1+j}` (zeros before t = 0).
-    K shifted multiply-adds: K is 4."""
+    K shifted multiply-adds: K is 4. With `segment_ids` `[B, T]` (packed
+    documents, module docstring) a tap that would read another document
+    reads zero, as it does before t = 0."""
+    import jax
     import jax.numpy as jnp
 
     k, t = w.shape[1], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    if segment_ids is not None:
+        with jax.named_scope("segments"):
+            ids = jnp.pad(segment_ids, ((0, 0), (k - 1, 0)),
+                          constant_values=-1)
+            same = [(ids[:, j:j + t] == segment_ids)[..., None]
+                    for j in range(k - 1)]
     y = b.astype(x.dtype)
     for j in range(k):
-        y = y + padded[:, j:j + t] * w[:, j].astype(x.dtype)
+        tap = padded[:, j:j + t]
+        if segment_ids is not None and j < k - 1:
+            tap = jnp.where(same[j], tap, 0)
+        y = y + tap * w[:, j].astype(x.dtype)
     return y
+
+
+def chunk_marks(segment_ids, chunk: int):
+    """What a chunked scan needs of packed documents (module docstring),
+    from segment_ids `[B, T]`: `carry` and `keep`, bool `[B, T]`. `carry`:
+    the step lies in the document of the last step of the chunk before
+    (the state that enters the chunk reaches it; False all through the
+    first chunk, which nothing enters). `keep`: it lies in the document of
+    its own chunk's last step (what it adds to the state leaves the
+    chunk)."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("segments"):
+        bsz, t = segment_ids.shape
+        by_chunk = segment_ids.reshape(bsz, t // chunk, chunk)
+        last = by_chunk[:, :, -1:]
+        before = jnp.concatenate(
+            [jnp.full((bsz, 1, 1), -1, segment_ids.dtype), last[:, :-1]],
+            axis=1)
+        return ((by_chunk == before).reshape(bsz, t),
+                (by_chunk == last).reshape(bsz, t))
 
 
 def _whole_chunks(t: int, chunk: int) -> int:
@@ -103,12 +163,14 @@ def _whole_chunks(t: int, chunk: int) -> int:
     return t // chunk
 
 
-def ssd_scan(x, dt, a, b, c, chunk: int):
+def ssd_scan(x, dt, a, b, c, chunk: int, segment_ids=None):
     """The selective scan `S_t = exp(dt_t a) S_{t-1} + dt_t x_t b_t^T`,
     `y_t = S_t c_t` in chunks: x `[B, T, H, P]`, dt `[B, T, H]` (after
     softplus, float32), a `[H]` (negative, float32), b and c
     `[B, T, G, N]` with head h in group h // (H / G) -> y `[B, T, H, P]`
-    float32. T % chunk != 0 is refused."""
+    float32. T % chunk != 0 is refused. With `segment_ids` `[B, T]` the
+    state starts from zero at every document's first step (module
+    docstring)."""
     import jax
     import jax.numpy as jnp
 
@@ -124,10 +186,16 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
     cum = jnp.cumsum(dtc * a.astype(f32).reshape(g, r), axis=2)
     # ---- within a chunk: the quadratic form ------------------------
     lower = jnp.tril(jnp.ones((q, q), bool))
+    if segment_ids is not None:
+        carried, kept = (m.reshape(bsz, nc, q, 1, 1).astype(f32)
+                         for m in chunk_marks(segment_ids, q))
+        with jax.named_scope("segments"):
+            ids = segment_ids.reshape(bsz, nc, q)
+            lower = lower & (ids[:, :, :, None] == ids[:, :, None, :])
     diff = cum[:, :, :, None] - cum[:, :, None, :]        # [.., i, j, g, r]
     # masked before the exponential: above the diagonal the sum is
     # positive and would overflow
-    decay = jnp.exp(jnp.where(lower[:, :, None, None], diff, -jnp.inf))
+    decay = jnp.exp(jnp.where(lower[..., None, None], diff, -jnp.inf))
     scores = jnp.einsum("zcign,zcjgn->zcijg", cc, bc,
                         preferred_element_type=f32)
     weights = (scores[..., None] * decay
@@ -136,11 +204,17 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
                    preferred_element_type=f32)
     # ---- each chunk's own contribution to the state at its end ------
     to_end = jnp.exp(cum[:, :, -1:] - cum) * dtc           # [z, c, j, g, r]
+    if segment_ids is not None:
+        to_end = to_end * kept
     states = jnp.einsum("zcjgn,zcjgrp->zcgrpn", bc,
                         xc * to_end[..., None].astype(x.dtype),
                         preferred_element_type=f32)
     # ---- the chunk states chained: T/Q steps of a scan --------------
     chunk_decay = jnp.exp(cum[:, :, -1])                   # [z, c, g, r]
+    entered = jnp.exp(cum)         # the entering state's decay to step i
+    if segment_ids is not None:
+        entered = entered * carried
+        chunk_decay = entered[:, :, -1]
 
     def step(carry, inp):
         state, decay_c = inp
@@ -153,7 +227,7 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
     # ---- what the state entering a chunk adds to its steps ----------
     y = y + jnp.einsum("zcign,zcgrpn->zcigrp", cc,
                        entering.astype(x.dtype),
-                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+                       preferred_element_type=f32) * entered[..., None]
     return y.reshape(bsz, t, h, p)
 
 
@@ -164,16 +238,24 @@ def ssd_scan(x, dt, a, b, c, chunk: int):
 SCAN_LANES = 128
 SCAN_WIDTH = 1024
 SCAN_CHUNKS = (128, 256)
+# ... and at most SCAN_HEAD_STEPS heads x steps: a head's `[Q, Q]` decays
+# and weights live on the kernel's stack once a head of the block, and at
+# chunk 256 sixteen heads' overrun the v5e's 16 MiB of scoped VMEM in the
+# backward kernel (by 16 KiB; PERF.md section 6, PR 68), eight fit
+SCAN_HEAD_STEPS = 2048
+_SCAN_MARKS = 8     # a sublane tile of rows: ids, carry, keep, five unused
 
 
-def scan_head_block(heads_per_group: int, head_dim: int):
+def scan_head_block(heads_per_group: int, head_dim: int, chunk: int = 128):
     """Heads one grid step of the kernel takes: the most that divide a
     B/C group, are whole sublane tiles of 8 (dt reaches the kernel with
-    the heads on the sublanes) and stay inside `SCAN_WIDTH` lanes of x;
-    None where no count does."""
+    the heads on the sublanes), stay inside `SCAN_WIDTH` lanes of x and,
+    times the chunk's steps, inside `SCAN_HEAD_STEPS`; None where no
+    count does."""
     return next((hb for hb in range(heads_per_group, 0, -1)
                  if heads_per_group % hb == 0 and hb % 8 == 0
-                 and hb * head_dim <= SCAN_WIDTH), None)
+                 and hb * head_dim <= SCAN_WIDTH
+                 and hb * chunk <= SCAN_HEAD_STEPS), None)
 
 
 def scan_shape_ok(seq_len: int, heads: int, head_dim: int, groups: int,
@@ -184,7 +266,8 @@ def scan_shape_ok(seq_len: int, heads: int, head_dim: int, groups: int,
     return (chunk in SCAN_CHUNKS and seq_len % chunk == 0
             and head_dim in (64, 128) and state % SCAN_LANES == 0
             and heads % groups == 0
-            and scan_head_block(heads // groups, head_dim) is not None)
+            and scan_head_block(heads // groups, head_dim, chunk)
+            is not None)
 
 
 def _one_tpu_device(mesh) -> bool:
@@ -252,14 +335,18 @@ def _by_head(heads, column):
     return out
 
 
-def _decays(dt_ref, a_ref):
+def _decays(dt_ref, a_ref, marks_ref=None):
     """dt `[hb, Q]` (the steps on the lanes) and a `[hb, 1]` of a block's
     heads -> dt and `cum`, the log-decay up to and including each step of
     the chunk, both ways round: `[Q, 128]` with head h in lane h, and
     `[hb, Q]`; and the `[Q, Q]` 0/1 matrix of the steps j <= i. The
     running sum and the turn are float32 products with a 0/1 matrix at
     the highest precision: the MXU adds what a `cumsum` adds, and turns
-    exactly."""
+    exactly. With `marks_ref` (`_SCAN_MARKS` rows of `[Q]`: the steps'
+    document ids, `carry` and `keep` of `chunk_marks`, as float32) the
+    marks ride the turn in the lanes behind the heads', and a sixth
+    value comes back: `same [Q, Q]` (steps i and j of one document) with
+    `carry` and `keep` as `[Q, 1]` columns, all bool."""
     import jax
     import jax.numpy as jnp
 
@@ -274,23 +361,36 @@ def _decays(dt_ref, a_ref):
     dt_r = dt_ref[0, 0]
     dta_r = dt_r * a_ref[0]
     pad = jnp.zeros((SCAN_LANES - hb, q), f32)
+    turned = [dt_r, pad] if marks_ref is None else [
+        dt_r, marks_ref[0], pad[_SCAN_MARKS:]]
     dt_c = jax.lax.dot_general(
-        (row == col).astype(f32), jnp.concatenate([dt_r, pad]), nt, **exact)
+        (row == col).astype(f32), jnp.concatenate(turned), nt, **exact)
     cum_c = jax.lax.dot_general(upto, jnp.concatenate([dta_r, pad]), nt,
                                 **exact)
     cum_r = jax.lax.dot_general(dta_r, upto, nt, **exact)
-    return dt_c, cum_c, dt_r, cum_r, upto
+    if marks_ref is None:
+        return dt_c, cum_c, dt_r, cum_r, upto
+    # whole numbers and 0/1 through an exact turn: compared with room
+    same = jnp.abs(dt_c[:, hb:hb + 1] - marks_ref[0, 0:1, :]) < 0.5
+    return dt_c, cum_c, dt_r, cum_r, upto, (
+        same, dt_c[:, hb + 1:hb + 2] > 0.5, dt_c[:, hb + 2:hb + 3] > 0.5)
 
 
-def _scan_fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, st_ref,
-                     state, *, hb: int, p: int):
+def _scan_fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, *rest, hb: int,
+                     p: int):
     """One chunk of one head block: x `[Q, hb·P]`, B and C `[Q, N]`, dt
     `[hb, Q]`, a `[hb, 1]` -> y `[Q, hb·P]` float32 and the state that
-    entered the chunk, `[N, hb·P]`; `state` carries it over the chunks."""
+    entered the chunk, `[N, hb·P]`; `state` carries it over the chunks.
+    Packed documents bring one more operand behind `a`, the chunk's marks
+    (`_decays`): a pair of steps of two documents has no weight, the
+    entering state reaches the steps of the document it belongs to, and
+    the steps of the chunk's last document alone reach the leaving
+    state."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    *marks, y_ref, st_ref, state = rest
     f32 = jnp.float32
     cdt = x_ref.dtype
 
@@ -302,10 +402,15 @@ def _scan_fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, st_ref,
     scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                                  preferred_element_type=f32)     # [i, j]
     b_t = bmat.T                                                 # [N, Q]
-    dt_c, cum_c, dt_r, cum_r, upto = _decays(dt_ref, a_ref)
+    dt_c, cum_c, dt_r, cum_r, upto, *docs = _decays(dt_ref, a_ref, *marks)
     lower = upto > 0
     entered = jnp.exp(cum_c)              # the entering state's decay to i
     to_end = jnp.exp(_last_row(cum_c) - cum_c) * dt_c
+    if docs:
+        same, carry, keep = docs[0]
+        lower = lower & same
+        entered = jnp.where(carry, entered, 0.0)
+        to_end = jnp.where(keep, to_end, 0.0)
     for lanes, heads in _head_lanes(hb, p):
         xt = x_ref[0, :, lanes]
         s_in = state[:, lanes]
@@ -331,8 +436,7 @@ def _scan_fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, st_ref,
 
 
 def _scan_bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, st_ref, g_ref,
-                     dx_ref, db_ref, dc_ref, ddt_ref, ddta_ref, dstate,
-                     later, *, hb: int, p: int):
+                     *rest, hb: int, p: int):
     """The same chunk going backward: besides the forward's operands the
     state that entered `[N, hb·P]` and dy `[Q, hb·P]` float32 -> dx, this
     head block's part of dB and dC `[Q, N]`, and `[hb, Q]` each the
@@ -340,11 +444,13 @@ def _scan_bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, st_ref, g_ref,
     of `cum`. `dstate` carries the cotangent of the state a chunk hands
     on; `later` gathers what reaches `cum_i` as the later step of a pair.
     Everything `[Q, Q]` is held transposed (`[j, i]`), so that no product
-    takes a transposed operand but dC's."""
+    takes a transposed operand but dC's. Packed documents bring the
+    chunk's marks behind dy, and mask what the forward masks."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    *marks, dx_ref, db_ref, dc_ref, ddt_ref, ddta_ref, dstate, later = rest
     f32 = jnp.float32
     q = x_ref.shape[1]
     cdt = x_ref.dtype
@@ -358,12 +464,17 @@ def _scan_bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, st_ref, g_ref,
     scores_t = jax.lax.dot_general(bmat, cmat, nt,
                                    preferred_element_type=f32)   # [j, i]
     c_t = cmat.T                                                 # [N, Q]
-    dt_c, cum_c, _, cum_r, upto = _decays(dt_ref, a_ref)
+    dt_c, cum_c, _, cum_r, upto, *docs = _decays(dt_ref, a_ref, *marks)
     upper = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1) >= \
         jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)            # [j, i]
     head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, SCAN_LANES), 1)
     entered = jnp.exp(cum_c)
     decay_to_end = jnp.exp(_last_row(cum_c) - cum_c)
+    if docs:
+        same, carry, keep = docs[0]     # `same` is its own transpose
+        upper = upper & same
+        entered = jnp.where(carry, entered, 0.0)
+        decay_to_end = jnp.where(keep, decay_to_end, 0.0)
     to_end = decay_to_end * dt_c
     dscores_t = jnp.zeros((q, q), f32)
     db = jnp.zeros(db_ref.shape[1:], f32)
@@ -455,13 +566,15 @@ def _scan_bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, st_ref, g_ref,
 
 @functools.lru_cache(maxsize=None)
 def _scan_calls(bsz: int, t: int, h: int, p: int, groups: int, n: int,
-                chunk: int, hb: int, interpret: bool):
+                chunk: int, hb: int, interpret: bool, segmented: bool):
     """The scan of one set of shapes under `jax.custom_vjp`, built once a
     process (`functools.lru_cache`) with each `pallas_call` behind a
     `jax.jit` of its own: a pallas kernel's body is traced anew by every
     call, in every program of a job (two scans of layers, the forward,
     remat's forward and the backward of each), and jit's cache hands the
-    first trace to all of them."""
+    first trace to all of them. `segmented`: the scan takes one operand
+    more, the marks of packed documents `[B, _SCAN_MARKS, T]` float32
+    (data: no cotangent), and every kernel call is handed them."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -488,32 +601,35 @@ def _scan_calls(bsz: int, t: int, h: int, p: int, groups: int, n: int,
                          lambda bi, hi, ci: (bi, at(ci), 0, hi)),
             pl.BlockSpec((1, 1, hb, q),
                          lambda bi, hi, ci: (bi, hi, 0, at(ci))),
-            pl.BlockSpec((1, hb, 1), lambda bi, hi, ci: (hi, 0, 0)))
+            pl.BlockSpec((1, hb, 1), lambda bi, hi, ci: (hi, 0, 0)),
+            [pl.BlockSpec((1, _SCAN_MARKS, q),
+                          lambda bi, hi, ci: (bi, 0, at(ci)))] * segmented)
 
     @functools.partial(jax.jit, inline=True)
-    def forward(x, dt_rows, a_col, b, c):
-        wide, grouped, _, states, rows, heads = specs(False)
+    def forward(x, dt_rows, a_col, b, c, *marks):
+        wide, grouped, _, states, rows, heads, marked = specs(False)
         return pl.pallas_call(
             functools.partial(_scan_fwd_kernel, hb=hb, p=p),
             grid=(bsz, blocks, nc),
-            in_specs=[wide, grouped, grouped, rows, heads],
+            in_specs=[wide, grouped, grouped, rows, heads] + marked,
             out_specs=[wide, states],
             out_shape=[jax.ShapeDtypeStruct((bsz, t, h * p), f32),
                        jax.ShapeDtypeStruct((bsz, nc, n, h * p), f32)],
             scratch_shapes=[pltpu.VMEM((n, width), f32)],
             compiler_params=params, interpret=interpret,
-            name="ssd_scan_fwd")(x, b, c, dt_rows, a_col)
+            name="ssd_scan_fwd")(x, b, c, dt_rows, a_col, *marks)
 
     @functools.partial(jax.jit, inline=True)
-    def backward(x, dt_rows, a_col, b, c, entering, dy):
-        wide, grouped, per_block, states, rows, heads = specs(True)
+    def backward(x, dt_rows, a_col, b, c, marks, entering, dy):
+        wide, grouped, per_block, states, rows, heads, marked = specs(True)
         # a group's head blocks each see its B and C: their parts of dB
         # and dC are summed in float32
         part = b.dtype if per_group == 1 else f32
         return pl.pallas_call(
             functools.partial(_scan_bwd_kernel, hb=hb, p=p),
             grid=(bsz, blocks, nc),
-            in_specs=[wide, grouped, grouped, rows, heads, states, wide],
+            in_specs=[wide, grouped, grouped, rows, heads, states, wide]
+            + marked,
             out_specs=[wide, per_block, per_block, rows, rows],
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                        jax.ShapeDtypeStruct((bsz, t, blocks * n), part),
@@ -523,18 +639,19 @@ def _scan_calls(bsz: int, t: int, h: int, p: int, groups: int, n: int,
             scratch_shapes=[pltpu.VMEM((n, width), f32),
                             pltpu.VMEM((SCAN_LANES, q), f32)],
             compiler_params=params, interpret=interpret,
-            name="ssd_scan_bwd")(x, b, c, dt_rows, a_col, entering, dy)
+            name="ssd_scan_bwd")(x, b, c, dt_rows, a_col, entering, dy,
+                                 *marks)
 
     @jax.custom_vjp
-    def scan(x, dt_rows, a_col, b, c):
-        return forward(x, dt_rows, a_col, b, c)[0]
+    def scan(x, dt_rows, a_col, b, c, *marks):
+        return forward(x, dt_rows, a_col, b, c, *marks)[0]
 
-    def scan_fwd(x, dt_rows, a_col, b, c):
-        y, entering = forward(x, dt_rows, a_col, b, c)
-        return y, (x, dt_rows, a_col, b, c, entering)
+    def scan_fwd(x, dt_rows, a_col, b, c, *marks):
+        y, entering = forward(x, dt_rows, a_col, b, c, *marks)
+        return y, (x, dt_rows, a_col, b, c, marks, entering)
 
     def scan_bwd(res, dy):
-        _, dt_rows, a_col, b, _, _ = res
+        _, dt_rows, a_col, b, _, marks, _ = res
         dx, db, dc, ddt, ddta = backward(*res, dy)
 
         def over_blocks(d):
@@ -542,33 +659,47 @@ def _scan_calls(bsz: int, t: int, h: int, p: int, groups: int, n: int,
                 bsz, t, groups, per_group, n).sum(axis=3).reshape(
                     bsz, t, groups * n).astype(b.dtype)
         da = jnp.sum(ddta * dt_rows, axis=(0, 3)).reshape(a_col.shape)
-        return dx, ddt, da, over_blocks(db), over_blocks(dc)
+        return (dx, ddt, da, over_blocks(db), over_blocks(dc)) \
+            + (None,) * len(marks)
 
     scan.defvjp(scan_fwd, scan_bwd)
     return scan
 
 
 def ssd_scan_pallas(x, dt, a, b, c, chunk: int, groups: int, *,
-                    head_block=None, interpret: bool = False):
+                    segment_ids=None, head_block=None,
+                    interpret: bool = False):
     """`ssd_scan` as a pallas TPU kernel with a backward kernel of its
     own (module docstring), on the layouts the convolution leaves: x
     `[B, T, H·P]`, dt `[B, T, H]` (after softplus, float32), a `[H]`, b
-    and c `[B, T, G·N]` -> y `[B, T, H·P]` float32. The shapes are
-    `scan_shape_ok`'s to vouch for; `head_block` and `interpret` are the
-    tests' (a block smaller than `scan_head_block`'s, the kernel on the
-    CPU)."""
+    and c `[B, T, G·N]` -> y `[B, T, H·P]` float32; `segment_ids`
+    `[B, T]` as `ssd_scan` takes them, None: no operand for them. The
+    shapes are `scan_shape_ok`'s to vouch for; `head_block` and
+    `interpret` are the tests' (a block smaller than `scan_head_block`'s,
+    the kernel on the CPU)."""
+    import jax
     import jax.numpy as jnp
 
     bsz, t, h = dt.shape
     p, n = x.shape[2] // h, b.shape[2] // groups
     _whole_chunks(t, chunk)
-    hb = head_block or scan_head_block(h // groups, p)
-    scan = _scan_calls(bsz, t, h, p, groups, n, chunk, hb, interpret)
+    hb = head_block or scan_head_block(h // groups, p, chunk)
+    marks = ()
+    if segment_ids is not None:
+        carry_keep = chunk_marks(segment_ids, chunk)
+        with jax.named_scope("segments"):
+            # the steps on the lanes, a sublane tile of rows: [B, 8, T]
+            rows = jnp.stack((segment_ids,) + carry_keep,
+                             axis=1).astype(jnp.float32)
+            marks = (jnp.pad(rows,
+                             ((0, 0), (0, _SCAN_MARKS - 3), (0, 0))),)
+    scan = _scan_calls(bsz, t, h, p, groups, n, chunk, hb, interpret,
+                       segment_ids is not None)
     # the steps on the lanes: [B, blocks, hb, T]
     dt_rows = jnp.swapaxes(dt.astype(jnp.float32), 1, 2).reshape(
         bsz, h // hb, hb, t)
     return scan(x, dt_rows, a.astype(jnp.float32).reshape(h // hb, hb, 1),
-                b, c)
+                b, c, *marks)
 
 
 def gated_norm(y, z, gain, groups: int, eps: float):
@@ -591,13 +722,15 @@ def gated_norm(y, z, gain, groups: int, eps: float):
 
 
 def mamba2_mixer(h, lp: Dict[str, Any], *, head_dim: int, state: int,
-                 chunk: int, eps: float, mesh=None):
+                 chunk: int, eps: float, mesh=None, segment_ids=None):
     """h `[B, T, d]` (normed, compute dtype) -> the mixer's output before
     the residual, `[B, T, d]`. lp: `w_in [d, 2·H·P + 2·G·N + H]` and
     `w_out [H·P, d]` in the compute dtype; `conv_w [H·P + 2·G·N, K]`,
     `conv_b`, `dt_bias [H]`, `A_log [H]`, `D [H]`, `gate_norm [H·P]`.
     Heads and groups are read off the leaves (module docstring); `mesh`
-    is what the program runs on, for `ssd_scan_impl`'s choice."""
+    is what the program runs on, for `ssd_scan_impl`'s choice;
+    `segment_ids` `[B, T]` int32 or None: packed documents (module
+    docstring), handed to the convolution and the scan."""
     import jax
     import jax.numpy as jnp
 
@@ -613,7 +746,8 @@ def mamba2_mixer(h, lp: Dict[str, Any], *, head_dim: int, state: int,
         xbc = zxbcdt[..., inner:inner + conv_dim]
         dt = zxbcdt[..., inner + conv_dim:]
     with jax.named_scope("ssm/conv"):
-        xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp["conv_b"]))
+        xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp["conv_b"],
+                                      segment_ids))
         x, b, c = (xbc[..., :inner], xbc[..., inner:inner + groups * state],
                    xbc[..., inner + groups * state:])
     with jax.named_scope("ssm/scan"):
@@ -622,12 +756,13 @@ def mamba2_mixer(h, lp: Dict[str, Any], *, head_dim: int, state: int,
         if ssd_scan_impl(mesh, t, heads, head_dim, groups, state,
                          chunk) == "pallas":
             # the kernel reads the convolution's own layouts
-            y = ssd_scan_pallas(x, dt, a, b, c, chunk, groups)
+            y = ssd_scan_pallas(x, dt, a, b, c, chunk, groups,
+                                segment_ids=segment_ids)
         else:
             y = ssd_scan(x.reshape(bsz, t, heads, head_dim), dt, a,
                          b.reshape(bsz, t, groups, state),
                          c.reshape(bsz, t, groups, state),
-                         chunk).reshape(bsz, t, inner)
+                         chunk, segment_ids).reshape(bsz, t, inner)
         y = y + x.astype(f32) * jnp.repeat(skip, head_dim)
     with jax.named_scope("ssm/gate_norm"):
         y = gated_norm(y, z, lp["gate_norm"], groups, eps).astype(h.dtype)

@@ -44,7 +44,15 @@ streams' mixing as two `jax.custom_vjp`s with pallas kernels,
 exit) means to change Xing4's program and no other and makes its pin anew
 from its own tree: only `enter` names anything and only a configuration
 with `residual_streams` above 1 reaches it, so the other eleven hold (all
-twelve run, PR 67: eleven green as they were). Each text is
+twelve run, PR 67: eleven green as they were). PR 68
+(granite-4.0-h-micro on packed documents: `segment_ids` through `hidden`,
+`_stack`, `_make_layer_fn`, `_make_attention`, `ops/ssm.py`'s convolution,
+scan and kernels and `ops/attention.py`, the four muP scalars in `embed`,
+`residual` and `models/head.py`, the scan kernels' head block a function
+of the chunk too) left all twelve as they were (all thirteen run, PR 68:
+without `segment_ids` nothing of it is traced, the scalars at their
+neutral values neither, and at chunk 128 the head block is the sixteen it
+was) and pins its own cell, whose batch holds `segment_ids`. Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
@@ -115,6 +123,9 @@ PINS = {
     # `leave`, four pallas kernels on a flat stream (45,373 lines; its
     # parent's, PR 66's pin of its own cell, 907116026b0cc671, 47,363)
     "train_xing4_ep8_d5": "cad71bc5e10b5a59",
+    # PR 68's own cell, pinned from its own tree: the packed step, the
+    # ids in the batch (9,063 lines)
+    "train_granite4hmicro_d10_packed": "ae723346c45a0d36",
 }
 WITH_THE_SUITE = ("train_mistral7b_d2",)
 
@@ -187,6 +198,8 @@ def step_text(cell: str, devices) -> str:
                                        if cfg.moe_experts or cfg.exit_gate
                                        else {}))
         batch = {"tokens": jax.ShapeDtypeStruct((seqs, seq + 1), jnp.int32)}
+        if mix.get("documents"):   # a packed cell: the ids beside the tokens
+            batch["segment_ids"] = batch["tokens"]
     _, train_step = make_train_step(
         loss, Transformer.param_specs(cfg), mesh, optimizer=optimizer,
         **rules,

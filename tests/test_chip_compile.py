@@ -281,20 +281,27 @@ def test_olmoe_layer_step_compiles_with_the_grouped_matmul_kernels(v5e):
     assert not bad, bad
 
 
+# the last: Granite 4.0-H's mixer, 64 heads of 64 in ONE group at chunk 256
+# (8 heads a grid step: sixteen overran the scoped VMEM in the backward)
 SCAN_CALLS = [(1, 8192, 32, 64, 2, 128, 128), (2, 1024, 128, 64, 8, 128, 128),
-              (1, 512, 8, 128, 1, 128, 256), (1, 128, 8, 64, 1, 128, 128)]
+              (1, 512, 8, 128, 1, 128, 256), (1, 128, 8, 64, 1, 128, 128),
+              (1, 8192, 64, 64, 1, 128, 256)]
+SCAN_CASES = [call + (False,) for call in SCAN_CALLS] + [
+    call + (True,) for call in (SCAN_CALLS[0], SCAN_CALLS[4])]
 
 
 @pytest.mark.parametrize(
-    "b,t,h,p,g,n,q", SCAN_CALLS,
-    ids=["x".join(map(str, call)) for call in SCAN_CALLS])
-def test_scan_kernels_fwd_bwd(v5e, b, t, h, p, g, n, q):
+    "b,t,h,p,g,n,q,packed", SCAN_CASES,
+    ids=["x".join(map(str, call[:-1])) + "-packed" * call[-1]
+         for call in SCAN_CASES])
+def test_scan_kernels_fwd_bwd(v5e, b, t, h, p, g, n, q, packed):
     """Forward and backward of `ops/ssm.ssd_scan_pallas` alone for the v5e
     compiler: one `ssd_scan_fwd` and one `ssd_scan_bwd` call, x and dy
     reaching them in the convolution's `[B, T, H·P]` layout (no four-way
     copy of either around the calls), the entering states `[T/Q, N, H·P]`
     float32 the only residual the forward writes, and `scan_shape_ok` said
-    yes to what compiled."""
+    yes to what compiled. `packed`: with `segment_ids`, whose marks
+    `[B, 8, T]` float32 are one operand more of both calls."""
     import re
 
     from ray_tpu.ops.ssm import scan_shape_ok, ssd_scan_pallas
@@ -305,13 +312,16 @@ def test_scan_kernels_fwd_bwd(v5e, b, t, h, p, g, n, q):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    def loss(x, dt, a, bm, cm):
-        return ssd_scan_pallas(x, dt, a, bm, cm, q, g).sum()
+    def loss(x, dt, a, bm, cm, *ids):
+        return ssd_scan_pallas(x, dt, a, bm, cm, q, g,
+                               segment_ids=ids[0] if ids else None).sum()
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         arg((b, t, h * p), jnp.bfloat16), arg((b, t, h), jnp.float32),
         arg((h,), jnp.float32), arg((b, t, g * n), jnp.bfloat16),
-        arg((b, t, g * n), jnp.bfloat16)).compile().as_text()
+        arg((b, t, g * n), jnp.bfloat16),
+        *([arg((b, t), jnp.int32)] * packed)).compile().as_text()
+    assert (f"f32[{b},8,{t}]" in hlo) == packed
     calls = {re.search(r"ssd_scan_(fwd|bwd)", name).group(0): (out, operands)
              for name, out, operands in re.findall(
                  r'%([\w.\-]+) = (\([^\n]*?\)) custom-call\(([^\n]*?)\), '
