@@ -109,9 +109,23 @@ nothing (k = v = 0, beta = 0, no decay).
 - `"xla"` (`gated_delta_rule`): the factors, blocks, inverses and the six
   per-chunk tensors go through HBM between every two passes (the column
   factors alone `[H, T/C, C/16, C, D]` float32), a `lax.scan` chains the
-  chunks, autodiff makes the backward. It runs on the CPU, on a mesh above
-  one device (GSPMD cannot partition a pallas call) and for every shape
-  the kernels do not tile, and it is the oracle of the kernels' tests.
+  chunks, autodiff makes the backward. What that backward is handed: the
+  chain's six per-chunk tensors (`T beta V` float32, `T beta (K e^G)`,
+  `Q e^G`, `tril(B)` and `K e^{G_C - G}` in the compute dtype, `e^{G_C}`),
+  the state entering each chunk, and the chunked q, k, v, G and beta. The
+  batched stage between those operands and the chain (A and B, the two
+  masks, the chunks' inverses, the solve) is under one `jax.checkpoint`
+  and made again in the backward, the same ops on the same operands.
+  Kept, it is most of what autodiff holds (nine float32 `[H, T/C, C, C]`
+  blocks, every level of the inverse five times, under a decay a channel
+  the column factors three times: 1,190 MB a layer at Olmo-Hybrid's 8,192
+  tokens of 15 heads against 562 without), and it is held across the rest
+  of the layer at the step's peak, where that cell has under 1 GB of the
+  chip's 16.91 to spare: the compiler then runs the layer's gate/up matmul
+  and q/k/v projection a third time to fit (PERF.md section 6, PR 70). It
+  runs on the CPU, on a mesh above one device (GSPMD cannot partition a
+  pallas call) and for every shape the kernels do not tile, and it is the
+  oracle of the kernels' tests.
 - `"pallas"` (`gated_delta_rule_pallas`): on one TPU device, under a decay
   a channel, where the shapes tile (`delta_shape_ok`: chunks of 64, keys
   and values one lane tile wide, the heads in pairs; a decay a head, keys
@@ -389,25 +403,31 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64):
     qc, kc, vc, gc = chunks(q), chunks(k), chunks(v), chunks(g)
     bc = chunks(beta[..., None])                     # [B, H, nc, c, 1]
     cum = jnp.cumsum(gc, axis=3)                     # G, the step included
-    # ---- A and B under the chunk's decays ---------------------------
-    a_mat, b_mat = (_head_blocks if per_head else _channel_blocks)(
-        qc, kc, cum)
-    lower = jnp.tril(jnp.ones((c, c), bool))
-    b_mat = jnp.where(lower, b_mat, 0.0)
-    a_mat = jnp.where(lower & ~jnp.eye(c, dtype=bool), a_mat, 0.0)
-    # ---- the chunk inverses, and what they make of V and K ----------
-    # beta up to 2 (the gate a head's model) outgrows the power series
-    inv = (_unit_lower_inverse_by_halves if per_head
-           else _unit_lower_inverse)(-bc * a_mat).astype(cdt)
-    decayed = jnp.exp(cum)
-    rhs = jnp.concatenate([vc.astype(f32), kc * decayed], -1) * bc
-    solved = jnp.einsum("...ri,...iw->...rw", inv, rhs.astype(cdt),
-                        preferred_element_type=f32)
+    # ---- the batched stage, made again in the backward --------------
+    @jax.checkpoint
+    def batched(qc, kc, vc, cum, bc):
+        # A and B under the chunk's decays
+        a_mat, b_mat = (_head_blocks if per_head else _channel_blocks)(
+            qc, kc, cum)
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        b_mat = jnp.where(lower, b_mat, 0.0)
+        a_mat = jnp.where(lower & ~jnp.eye(c, dtype=bool), a_mat, 0.0)
+        # the chunk inverses, and what they make of V and K; beta up to 2
+        # (the gate a head's model) outgrows the power series
+        inv = (_unit_lower_inverse_by_halves if per_head
+               else _unit_lower_inverse)(-bc * a_mat).astype(cdt)
+        decayed = jnp.exp(cum)
+        rhs = jnp.concatenate([vc.astype(f32), kc * decayed], -1) * bc
+        solved = jnp.einsum("...ri,...iw->...rw", inv, rhs.astype(cdt),
+                            preferred_element_type=f32)
+        return b_mat.astype(cdt), solved, decayed
+
+    b_mat, solved, decayed = batched(qc, kc, vc, cum, bc)
     dv = vc.shape[-1]
     to_end = jnp.exp(cum[:, :, :, -1:] - cum)        # e^{G_C - G}, <= 1
     per_chunk = tuple(jnp.moveaxis(a, 2, 0) for a in (
         solved[..., :dv], solved[..., dv:].astype(cdt),
-        (qc * decayed).astype(cdt), b_mat.astype(cdt),
+        (qc * decayed).astype(cdt), b_mat,
         (kc * to_end).astype(cdt), decayed[:, :, :, -1]))
 
     # ---- the chunks chained: the state carried ----------------------

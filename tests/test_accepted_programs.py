@@ -52,7 +52,13 @@ scan and kernels and `ops/attention.py`, the four muP scalars in `embed`,
 of the chunk too) left all twelve as they were (all thirteen run, PR 68:
 without `segment_ids` nothing of it is traced, the scalars at their
 neutral values neither, and at chunk 128 the head block is the sixteen it
-was) and pins its own cell, whose batch holds `segment_ids`. Each text is
+was) and pins its own cell, whose batch holds `segment_ids`. PR 70
+(`ops/kda.gated_delta_rule`, the XLA rule, keeps for its backward the
+chain's per-chunk tensors and the entering states and makes the batched
+stage before them again, under one `jax.checkpoint`) means to change
+Olmo-Hybrid's program and no other and makes its pin anew from its own
+tree: no other cell reaches the XLA rule on the chip (Ling's takes the
+kernels), so the other twelve hold (all thirteen run, PR 70). Each text is
 made in a process of its own (`python tests/test_accepted_programs.py
 <cell>` prints its hash): inside a worker of the whole suite Ling's text
 came out another than alone (its one run there read a different hash, and
@@ -115,8 +121,11 @@ PINS = {
     # PR 64's tree, with the layout's rules (23,869 lines; its parent's
     # with them 849734f4a1c00937, 24,195 lines)
     "train_mellum2_ep4_d4": "3553e1c05d2e9563",
-    # PR 59's own cell, pinned by PR 60 from PR 59's tree (15,029 lines)
-    "train_olmohybrid7b_tp2_d4": "1ebd73113b090dc6",
+    # PR 70's tree: the XLA delta rule makes its batched stage again in
+    # its backward, and the text holds no compiler `.remat` clone (15,789
+    # lines; its parent's, PR 60's pin of PR 59's tree, 1ebd73113b090dc6,
+    # 15,029)
+    "train_olmohybrid7b_tp2_d4": "270c7b96cd9ead7e",
     # PR 63's own cell, pinned from its own tree (4,739 lines)
     "train_ouro26b_d8": "079113b2a5d63a41",
     # PR 67's tree: the mixing of the streams is `ops/mhc.enter` /

@@ -25,8 +25,12 @@ def test_gdn_share_step_compiles_and_fits_the_v5e(v5e):
     says "xla" whatever the mesh: no `kda_delta_*` kernel anywhere),
     splash runs once forward and once backward for the one attention
     layer, the norms on the sublayers' outputs lie inside the scopes that
-    close them, and the compiler's memory report is no higher than it was
-    when the cell's first chip run read `peak_hbm_gb` 16.06 of 16.91."""
+    close them, the compiler's temporaries are no more than PR 70 left
+    them (9.265 GB, where the chip reads `peak_hbm_gb` 15.94 of 16.91), and
+    no instruction carries the `.remat` name XLA gives what it clones to
+    fit the memory: a step that holds more at its peak shows there first
+    (with 1.19 GB a layer of the rule's residuals held, the gate/up matmul
+    ran a third time, 23.4 ms a step: PERF.md section 6, PR 70)."""
     import re
 
     import optax
@@ -94,6 +98,10 @@ def test_gdn_share_step_compiles_and_fits_the_v5e(v5e):
         for dims in re.findall(r"\b(?:f32|bf16|s32)\[([\d,]+)\]", line):
             assert np.prod([int(v) for v in dims.split(",")]) \
                 <= 15 * seq * 2 * 192, line[:300]
+    # XLA's own clones are `<name>.remat`, `.remat2`, ...: JAX's remat
+    # lives in `op_name`, not in an instruction's name
+    clones = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+\.remat\d*) = ", hlo, re.M)
+    assert not clones, clones
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes < 9.2e9
-    assert ma.temp_size_in_bytes < 9.6e9, ma.temp_size_in_bytes
+    assert ma.temp_size_in_bytes < 9.30e9, ma.temp_size_in_bytes

@@ -205,3 +205,42 @@ def test_the_mixer_reads_its_heads_off_its_leaves():
 
     with jax.default_matmul_precision("highest"):
         close(share(0, 1) + share(1, 4), share(0, 4))
+
+
+@pytest.mark.parametrize("t,h,dk,dv,per_head,limit_mb", [
+    # Olmo-Hybrid's cell, `train_olmohybrid7b_tp2_d4`: 562 MB (1,190 with
+    # the batched stage kept: nine float32 `[15, 128, 64, 64]` blocks,
+    # `rhs` twice, every level of the inverse five times over)
+    pytest.param(8192, 15, 96, 192, True, 600, id="a_head_olmo_hybrid"),
+    # Ling's shape on the XLA path (a mesh, the CPU): 826 MB (2,705 with
+    # three column factors `[8, 256, 4, 64, 128]` of 268 MB each)
+    pytest.param(16384, 8, 128, 128, False, 900, id="a_channel_ling"),
+])
+def test_the_backward_is_not_handed_the_batched_stage(t, h, dk, dv,
+                                                      per_head, limit_mb):
+    """`gated_delta_rule` makes its batched stage (A and B, the masks, the
+    chunks' inverses, the solve) again in its backward (PR 70): what
+    autodiff keeps of one bfloat16 sequence of t steps is the chain's six
+    per-chunk tensors, the entering states and the chunked operands, none
+    of it a `[.., C, C]` float32 block or a decay a channel's `[.., C/16,
+    C, D]` column factor. Read from the jaxpr of abstract inputs: no array
+    is made."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    c = 64
+    kept = [a for a, _ in saved_residuals(
+        lambda *a: kda.gated_delta_rule(*a, chunk=c),
+        of(1, t, h, dk), of(1, t, h, dk), of(1, t, h, dv),
+        of(*((1, t, h) if per_head else (1, t, h, dk)), dtype=jnp.float32),
+        of(1, t, h, dtype=jnp.float32))]
+    total_mb = sum(a.size * a.dtype.itemsize for a in kept) / 1e6
+    assert total_mb <= limit_mb, total_mb
+    for a in kept:
+        if a.dtype == jnp.float32:
+            assert a.shape[-2:] != (c, c), a.shape    # a block, a mask's where
+            assert a.shape[-3:] != (c // kda.SUB, c, dk), a.shape   # factors
+    # the entering states are what the chain's checkpoint keeps
+    assert (t // c, 1, h, dk, dv) in [a.shape for a in kept]
